@@ -25,7 +25,8 @@ constructing the objects under test).
 **Retrace sentinel** — :func:`install_retrace_sentinel` registers a jax
 monitoring listener counting backend compiles (the
 ``/jax/core/compile/backend_compile_duration`` event fires per real
-compile and never on a cache hit — verified on this image's jax 0.4.37).
+compile and never on an in-memory executable-cache hit; the sentinel's own
+tests in tests/test_analysis.py hold that on the installed jax).
 After :meth:`RetraceSentinel.mark_steady`, any compile is a violation:
 :func:`steady_point` hook sites in the server round loop and the serve
 scheduler attribute it to the iteration that compiled, and
@@ -309,7 +310,7 @@ def lock_order_active() -> LockOrderRecorder | None:
 # ---------------------------------------------------------------------------
 
 #: fires once per REAL backend compile, never on an executable-cache hit
-#: (probed on jax 0.4.37; newer jax keeps the event name)
+#: (tests/test_analysis.py pins both halves on the installed jax)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -400,7 +401,7 @@ def install_retrace_sentinel() -> RetraceSentinel:
     global _SENTINEL
     if _SENTINEL is not None:
         uninstall_retrace_sentinel()
-    from jax._src import monitoring  # lazy: runtime.py must import jax-free
+    from jax import monitoring  # lazy: runtime.py must import jax-free
 
     s = RetraceSentinel()
     monitoring.register_event_duration_secs_listener(s._on_event)
@@ -412,9 +413,9 @@ def uninstall_retrace_sentinel() -> None:
     global _SENTINEL
     s = _SENTINEL
     if s is not None:
-        from jax._src import monitoring
+        from jax import monitoring
 
-        monitoring._unregister_event_duration_listener_by_callback(s._on_event)
+        monitoring.unregister_event_duration_listener(s._on_event)
     _SENTINEL = None
 
 

@@ -163,6 +163,9 @@ def main(argv: list[str] | None = None) -> None:
                     help="dotted config override, repeatable, e.g. --set model.n_layers=2")
     args = ap.parse_args(argv)
 
+    from photon_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     cfg = Config.from_yaml(args.config) if args.config else Config()
     for kv in args.set:
         key, _, value = kv.partition("=")

@@ -69,6 +69,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--tokenizer", default="byte-fallback")
     args = ap.parse_args(argv)
 
+    from photon_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from photon_tpu.config import load_preset
     from photon_tpu.config.schema import Config
     from photon_tpu.models.mpt import MPTModel, init_params

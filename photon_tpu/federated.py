@@ -129,6 +129,17 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--set", action="append", default=[], metavar="KEY=VALUE")
     args = ap.parse_args(argv)
 
+    from photon_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    if args.multiprocess or args.tcp_listen:
+        # a chip belongs to one process at a time, and here the node
+        # processes own it: this process only aggregates on the host, so its
+        # own JAX (model init, strategy state) stays on the CPU
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
+
     if args.config:
         cfg = Config.from_yaml(args.config)
     elif args.preset:

@@ -1772,6 +1772,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--rounds", type=int, default=None)
     args = ap.parse_args(argv)
 
+    from photon_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     jax.distributed.initialize(
         args.coordinator, num_processes=args.num_processes, process_id=args.process_id
     )

@@ -179,9 +179,10 @@ def node_process_main(cfg_json: str, node_id: str, conn, platform: str | None, n
     if platform:
         jax.config.update("jax_platforms", platform)
         if platform == "cpu" and n_cpu_devices > 1:
-            from photon_tpu.utils.compat import set_cpu_device_count
+            jax.config.update("jax_num_cpu_devices", n_cpu_devices)
+    from photon_tpu.utils.compile_cache import use_compile_cache
 
-            set_cpu_device_count(n_cpu_devices)
+    use_compile_cache()
 
     cfg = Config.from_json(cfg_json)
     chaos.install(cfg.photon.chaos, scope=node_id)

@@ -536,7 +536,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--config", required=True, help="resolved config YAML")
     args = ap.parse_args(argv)
     from photon_tpu.config.schema import Config
+    from photon_tpu.utils.compile_cache import use_compile_cache
 
+    use_compile_cache()
     cfg = Config.from_yaml(args.config)
     run_node(args.connect, args.node_id, cfg.to_json())
 

@@ -84,7 +84,12 @@ def multihead_attention(
     block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Dispatch on ``impl`` ∈ {pallas, xla, ring}. Falls back to XLA off-TPU;
+    """Dispatch on ``impl`` ∈ {pallas, xla, ring}. ``pallas`` runs the kernel
+    on a TPU (or anywhere under ``interpret``); on the CPU backend the tests
+    use — and on no other — it steps down to ``xla_attention`` without a word
+    (``flash_attention.pallas_supported``), so a CPU run never shows that the
+    kernel is in the program: ``chip_smoke.py`` asserts ``tpu_custom_call`` in
+    the compiled train step on the chip.
     ``ring`` = context parallelism over the ambient mesh's ``sequence`` axis
     (``photon_tpu/ops/ring_attention.py``), degrading to pallas/xla when the
     axis is trivial. ALiBi runs in-kernel on the pallas path (per-head slope
@@ -130,7 +135,7 @@ def multihead_attention(
             pallas_supported,
         )
 
-        if pallas_supported(q) or interpret:
+        if interpret or pallas_supported(q):
             bq = block_q or DEFAULT_BLOCK_Q
             bk = block_k or DEFAULT_BLOCK_K
 

@@ -37,14 +37,25 @@ DEFAULT_BLOCK_K = 256
 NEG_INF = -1.0e30
 
 
-def pallas_supported(x: jax.Array) -> bool:
-    """Pallas TPU kernels need a TPU backend; tests on CPU fall back to XLA."""
+def pallas_supported(x: jax.Array | None) -> bool:
+    """Whether the Pallas TPU kernels can run where ``x`` lives (``None``, or
+    a tracer: the default backend). True on a TPU. False on the CPU backend —
+    the one the tests use — where callers step down to their XLA reference
+    path in silence. Any other backend raises: a quiet step down there would
+    hide that the kernel is not in the program. ``chip_smoke.py`` is what
+    proves the kernels are in the programs that ran on the chip."""
     try:
         platform = x.devices().pop().platform if hasattr(x, "devices") else None
     except Exception:
         platform = None
     if platform is None:
         platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"Pallas TPU kernels cannot run on the {platform!r} backend, and "
+            "only the CPU backend steps down to the XLA path; ask for "
+            "attn_impl='xla' (serve.attention_impl='gather') explicitly"
+        )
     return platform == "tpu"
 
 

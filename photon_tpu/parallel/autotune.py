@@ -74,7 +74,11 @@ class HardwareModel:
 
     @classmethod
     def for_device_kind(cls, kind: str) -> "HardwareModel":
-        return cls(peak_flops=peak_flops_for_device_kind(kind))
+        # a ranking aid, not a report: a kind without a published peak (the
+        # CPU the tests plan on) is priced at the documented v5e planning
+        # value, passed explicitly
+        return cls(peak_flops=peak_flops_for_device_kind(
+            kind, default=TPU_V5E_PEAK_FLOPS))
 
 
 def model_param_count(cfg: ModelConfig) -> int:

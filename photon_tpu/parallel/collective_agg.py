@@ -70,17 +70,10 @@ REPLICA_AXIS = "replica"
 
 
 def _full_shard_map(f: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
-    """Full-manual shard_map across jax versions (all mesh axes manual — the
-    partial-manual spelling aborts on this image's jax 0.4.37, see
-    ``parallel/context.partial_shard_map``; full-manual is safe on both)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False,
+    """Full-manual shard_map (every mesh axis manual), replication checking
+    off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )
 
 

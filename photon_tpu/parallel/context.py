@@ -10,39 +10,11 @@ module ``__call__``.
 from __future__ import annotations
 
 import contextlib
-from typing import Callable, Iterator
+from typing import Iterator
 
-import jax
 from jax.sharding import Mesh
 
 _CURRENT: list[Mesh] = []
-
-
-def partial_shard_map(
-    f: Callable,
-    mesh: Mesh,
-    in_specs,
-    out_specs,
-    axis_names: set[str],
-) -> Callable:
-    """Partial-manual shard_map over ``axis_names`` only, across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=...)``; older releases
-    spell the same thing ``jax.experimental.shard_map.shard_map(...,
-    auto=<complement>)``. Replication checking is disabled in both spellings
-    (the pipeline's per-stage losses are deliberately device-varying).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=frozenset(mesh.axis_names) - set(axis_names),
-    )
 
 
 def current_mesh() -> Mesh | None:

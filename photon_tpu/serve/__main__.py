@@ -64,7 +64,9 @@ def main(argv: list[str] | None = None) -> None:
     from photon_tpu.serve.engine import PagedEngine
     from photon_tpu.serve.frontend import ServeFrontend
     from photon_tpu.serve.scheduler import ContinuousBatcher
+    from photon_tpu.utils.compile_cache import use_compile_cache
 
+    use_compile_cache()
     cfg = Config.from_yaml(args.config) if args.config else load_preset(args.preset)
     if args.run:
         cfg.run_uuid = args.run

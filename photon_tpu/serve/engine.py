@@ -222,9 +222,10 @@ class PagedEngine:
         # -- attention impl resolution (ISSUE 12; validated in schema.py) --
         # "gather": the PR 5 full-width dense gather — the bit-exact
         #   oracle whose cost scales with POOL capacity;
-        # "auto": the ragged live-block walk — fused Pallas kernel where
-        #   Pallas runs (TPU), the bit-exact gather REFERENCE math over
-        #   the live slice elsewhere;
+        # "auto": the ragged live-block walk — fused Pallas kernel on a
+        #   TPU ("ragged"); on the CPU backend the tests use, and on no
+        #   other, the bit-exact gather REFERENCE math over the live slice
+        #   ("ragged-ref"). chip_smoke.py asserts "ragged" on the chip;
         # "ragged": the fused kernel, explicitly — schema validation
         #   already rejected it on a non-Pallas backend unless
         #   attention_interpret opted into the Pallas interpreter.
@@ -238,7 +239,7 @@ class PagedEngine:
             from photon_tpu.ops.flash_attention import pallas_supported
 
             self._ctx_full = False
-            self._use_kernel = pallas_supported(None) or interpret
+            self._use_kernel = interpret or pallas_supported(None)
         self._interpret = interpret
         self.attn_impl = "gather" if self._ctx_full else (
             "ragged" if self._use_kernel else "ragged-ref"

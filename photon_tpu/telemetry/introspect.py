@@ -33,7 +33,7 @@ import time
 from typing import Any
 
 #: the jax monitoring event that fires once per real backend compile
-#: (shared with analysis/runtime.py's RetraceSentinel; probed on 0.4.37)
+#: (shared with analysis/runtime.py's RetraceSentinel)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -81,7 +81,7 @@ def install_compile_counter() -> CompileCounter | None:
     global _COMPILE_COUNTER
     uninstall_compile_counter()
     try:
-        from jax._src import monitoring
+        from jax import monitoring
     except ImportError:
         return None
     c = CompileCounter()
@@ -94,12 +94,9 @@ def uninstall_compile_counter() -> None:
     global _COMPILE_COUNTER
     c = _COMPILE_COUNTER
     if c is not None:
-        try:
-            from jax._src import monitoring
+        from jax import monitoring
 
-            monitoring._unregister_event_duration_listener_by_callback(c._on_event)
-        except (ImportError, ValueError):
-            pass
+        monitoring.unregister_event_duration_listener(c._on_event)
     _COMPILE_COUNTER = None
 
 

@@ -1,11 +1,10 @@
 """Periodic stderr heartbeat for long, silent blocking calls.
 
-TPU compiles (remote-service RPCs or local libtpu AOT) can block the main
-thread for minutes with zero output; a wedge looks identical from outside.
-Wrapping the call in :func:`heartbeat` makes the difference visible: a
-legit compile shows bounded "still compiling…" ticks and then a result, a
-wedge shows unbounded ticks with zero client CPU. Used by
-``scripts/tpu_probe.py`` and ``scripts/aot_compile_check.py``.
+A TPU compile through the local libtpu can block the main thread for minutes
+with zero output; a hang looks identical from outside. Wrapping the call in
+:func:`heartbeat` makes the difference visible: a legit compile shows bounded
+"still compiling…" ticks and then a result. Used by
+``scripts/aot_compile_check.py``.
 """
 
 from __future__ import annotations

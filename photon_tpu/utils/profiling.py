@@ -563,10 +563,10 @@ TPU_V4_PEAK_FLOPS = 275e12
 A100_PEAK_FLOPS = 312e12
 
 # bf16 peak FLOPs by device_kind substring (first match wins; most-specific
-# first). Used to turn tokens/sec into MFU for whatever chip the bench lands
-# on — including GPU hosts (jax device_kind is e.g. "NVIDIA A100-SXM4-40GB"),
-# so SpeedMonitor's auto-detect doesn't quietly score a GPU against a TPU
-# peak. Unknown kinds (CPU, emulators) fall back to the documented default.
+# first). Used to turn tokens/sec into MFU for whatever chip the run lands
+# on — including GPU hosts (jax device_kind is e.g. "NVIDIA A100-SXM4-40GB").
+# A kind that is not in the table (CPU, emulators) has no peak: it is an
+# error, not a default.
 PEAK_FLOPS_BY_DEVICE_KIND: list[tuple[str, float]] = [
     ("v6", 918e12),
     ("v5p", 459e12),
@@ -582,9 +582,17 @@ PEAK_FLOPS_BY_DEVICE_KIND: list[tuple[str, float]] = [
 ]
 
 
-def peak_flops_for_device_kind(kind: str, default: float = TPU_V5E_PEAK_FLOPS) -> float:
-    kind = kind.lower()
-    return next((p for sub, p in PEAK_FLOPS_BY_DEVICE_KIND if sub in kind), default)
+def peak_flops_for_device_kind(kind: str, default: float | None = None) -> float:
+    """bf16 peak FLOP/s of one chip of ``kind``. Raises on a kind the table
+    does not hold unless the caller passes a ``default`` of its own."""
+    low = kind.lower()
+    peak = next((p for sub, p in PEAK_FLOPS_BY_DEVICE_KIND if sub in low), default)
+    if peak is None:
+        raise ValueError(
+            f"no bf16 peak known for device_kind {kind!r} "
+            "(see PEAK_FLOPS_BY_DEVICE_KIND)"
+        )
+    return peak
 
 
 def is_oom(e: BaseException) -> bool:
@@ -670,13 +678,14 @@ class SpeedMonitor:
     """EMA tokens/sec + MFU (reference: llm-foundry ``speed_monitor``
     callback, ``mpt-125m.yaml:98-109``).
 
-    ``peak_flops=None`` (the default) auto-detects the bf16 peak from
+    ``peak_flops=None`` (the default) looks the bf16 peak up from
     ``device_kind`` — or, when that is also None, from
-    ``jax.devices()[0].device_kind`` — via :func:`peak_flops_for_device_kind`
-    (ISSUE 4 satellite: the old hardcoded-v5e default silently mis-scaled
-    MFU on every other chip). The resolved kind/peak are kept on
-    :attr:`device_kind` / :attr:`peak_flops_per_chip` so callers can record
-    the choice as a run attribute/event."""
+    ``jax.devices()[0].device_kind`` — via :func:`peak_flops_for_device_kind`.
+    A device the table does not know has no peak: the monitor then reports
+    throughput and NO ``throughput/mfu`` (``peak_flops_per_chip`` is None).
+    The resolved kind/peak are kept on :attr:`device_kind` /
+    :attr:`peak_flops_per_chip` so callers can record the choice as a run
+    attribute/event."""
 
     def __init__(self, cfg: ModelConfig, peak_flops: float | None = None,
                  n_chips: int = 1, alpha: float = 0.9,
@@ -684,17 +693,17 @@ class SpeedMonitor:
         self.flops_per_token = model_flops_per_token(cfg)
         if peak_flops is None:
             if device_kind is None:
-                try:
-                    import jax
+                import jax
 
-                    device_kind = jax.devices()[0].device_kind
-                except Exception:  # noqa: BLE001 — no backend yet: fall back
-                    device_kind = ""
-            peak_flops = peak_flops_for_device_kind(device_kind or "")
+                device_kind = jax.devices()[0].device_kind
+            try:
+                peak_flops = peak_flops_for_device_kind(device_kind)
+            except ValueError:
+                peak_flops = None
         self.device_kind = device_kind or ""
-        self.peak_flops_per_chip = float(peak_flops)
+        self.peak_flops_per_chip = None if peak_flops is None else float(peak_flops)
         self.n_chips = n_chips
-        self.peak = peak_flops * n_chips
+        self.peak = None if peak_flops is None else peak_flops * n_chips
         self.alpha = alpha
         self._ema = 0.0
         self._t = 0
@@ -706,8 +715,10 @@ class SpeedMonitor:
         self._t += 1
         self._ema = self.alpha * self._ema + (1 - self.alpha) * tps
         ema = self._ema / (1 - self.alpha**self._t)
-        return {
+        out = {
             "throughput/tokens_per_sec": tps,
             "throughput/tokens_per_sec_ema": ema,
-            "throughput/mfu": tps * self.flops_per_token / self.peak,
         }
+        if self.peak is not None:
+            out["throughput/mfu"] = tps * self.flops_per_token / self.peak
+        return out

@@ -139,8 +139,8 @@ def test_conv_slice_persists_params_for_cross_process_gauntlet(
 ):
     """In stage-orchestration mode (--stage conv) the trained params are
     serialized atomically for the gauntlet stage's separate process, and
-    _load_slice_params round-trips them; without the env flag (inline
-    --run mode, in-memory handoff) nothing is written."""
+    _load_slice_params round-trips them; without the env flag (a direct
+    caller, in-memory handoff) nothing is written."""
     import photon_tpu.config.schema as schema
 
     monkeypatch.setattr(schema, "Config", _tiny_byte_cfg)

@@ -20,8 +20,7 @@ import jax
 
 pid = int(sys.argv[1]); port = sys.argv[2]; out_path = sys.argv[3]
 jax.config.update("jax_platforms", "cpu")
-from photon_tpu.utils.compat import set_cpu_device_count
-set_cpu_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 jax.distributed.initialize(f"127.0.0.1:{port}", num_processes=2, process_id=pid)
 
 import jax.numpy as jnp

@@ -26,8 +26,7 @@ import jax
 
 pid = int(sys.argv[1]); port = sys.argv[2]; cfg_path = sys.argv[3]; out_path = sys.argv[4]
 jax.config.update("jax_platforms", "cpu")
-from photon_tpu.utils.compat import set_cpu_device_count
-set_cpu_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 jax.distributed.initialize(f"127.0.0.1:{port}", num_processes=2, process_id=pid)
 
 import numpy as np
@@ -87,12 +86,6 @@ def _cfg(tmp_path, strategy="fedavg", momenta=False) -> Config:
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax 0.4.37 CPU backend can't run multiprocess computations "
-    "(XLA: 'Multiprocess computations aren't implemented on the CPU "
-    "backend') — the single-controller e2es below cover the plane here",
-)
 @pytest.mark.parametrize(
     "strategy,momenta",
     [("fedavg", False), ("fedadam", True)],

@@ -14,16 +14,6 @@ import optax
 import pytest
 from jax.sharding import NamedSharding
 
-# the pipeline's partial-manual shard_map (manual over `pipe` only) needs
-# the jax.shard_map era of partial-manual lowering; the older
-# experimental-shard_map + auto-axes spelling hits an XLA "PartitionId is
-# not supported for SPMD partitioning" abort on EVERY pipe mesh. Equivalence
-# tests only run where the capability exists; validation tests always run.
-_PARTIAL_MANUAL = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map unsupported on this jax/XLA",
-)
-
 from photon_tpu.config.schema import Config, MeshConfig
 from photon_tpu.models.mpt import MPTModel, init_params
 from photon_tpu.parallel.mesh import make_mesh
@@ -86,7 +76,6 @@ def _reference_grads(cfg, params, tokens, n_micro, chunk):
     return jax.grad(loss)(params), float(loss(params))
 
 
-@_PARTIAL_MANUAL
 @pytest.mark.parametrize(
     "mesh,chunk",
     [
@@ -108,7 +97,6 @@ def test_pipeline_matches_reference_grads(mesh, chunk):
     )
 
 
-@_PARTIAL_MANUAL
 def test_pipeline_matches_with_remat_and_llama_family():
     """Remat inside stages + the llama knobs (RoPE/RMSNorm/SwiGLU/GQA)
     flow through MPTBlock reuse unchanged."""
@@ -127,7 +115,6 @@ def test_pipeline_matches_with_remat_and_llama_family():
     )
 
 
-@_PARTIAL_MANUAL
 def test_pipeline_matches_with_moe():
     """MoE stages through the pipeline: the per-layer Switch aux losses
     are collected through the stage scan (bubble ticks excluded) and the
@@ -179,7 +166,6 @@ def test_trainer_defers_pallas_pipe_fallback():
     assert trainer.model.cfg.attn_impl == "xla"
 
 
-@_PARTIAL_MANUAL
 def test_trainer_runs_pipelined():
     """Trainer picks the pipeline step for pipe>1 meshes; loss falls on a
     repeated batch and the state layout (checkpoint format) is unchanged."""

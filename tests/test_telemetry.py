@@ -246,16 +246,20 @@ def test_speed_monitor_auto_detects_peak_from_device_kind():
     from photon_tpu.config.schema import ModelConfig
     from photon_tpu.utils.profiling import (
         TPU_V4_PEAK_FLOPS,
-        TPU_V5E_PEAK_FLOPS,
         SpeedMonitor,
+        peak_flops_for_device_kind,
     )
 
     sm = SpeedMonitor(ModelConfig(), device_kind="TPU v4", n_chips=2)
     assert sm.peak_flops_per_chip == TPU_V4_PEAK_FLOPS
     assert sm.peak == 2 * TPU_V4_PEAK_FLOPS
-    # unknown kinds keep the documented default
-    assert SpeedMonitor(ModelConfig(), device_kind="cpu").peak_flops_per_chip \
-        == TPU_V5E_PEAK_FLOPS
+    # a kind the table does not hold has no peak: throughput, and no MFU
+    unknown = SpeedMonitor(ModelConfig(), device_kind="cpu")
+    assert unknown.peak_flops_per_chip is None
+    assert "throughput/mfu" not in unknown.update(tokens=1000, seconds=0.5)
+    with pytest.raises(ValueError):
+        peak_flops_for_device_kind("cpu")
+    assert peak_flops_for_device_kind("cpu", default=1e12) == 1e12
     # explicit peak still wins
     assert SpeedMonitor(ModelConfig(), peak_flops=1e12).peak == 1e12
     out = sm.update(tokens=1000, seconds=0.5)

@@ -1,0 +1,340 @@
+"""The main path's programs compile for a described TPU v5e — no chip attached.
+
+The TPU compiler is installed here and compiles for a topology that is only
+described (``jax.experimental.topologies``): what Mosaic or XLA:TPU would
+refuse on the chip — a tile that overflows VMEM, a misaligned slice, a step
+that does not fit 16 GiB — it refuses here, at no chip time. Nothing runs, so
+these say nothing about results or speed.
+
+This is the ONE test file that loads the TPU library, and it does so inside a
+module-scoped fixture: only one process may hold the library, every xdist
+worker imports every test file, and a second file would land on another worker
+and skip there in silence. The persistent compile cache is off around these
+tests (a described-device executable can be written to it but never read
+back).
+"""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+import chip_smoke
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo_devices():
+    """``abstract_devices(shape)`` for v5e topologies, or skip the file."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from photon_tpu.parallel.topo import abstract_tpu_devices
+
+    try:
+        first = abstract_tpu_devices("v5e:2x2x1")
+    except RuntimeError as e:
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    described = {"2x2": first}
+
+    def get(shape: str = "2x2"):
+        if shape not in described:
+            described[shape] = abstract_tpu_devices(f"v5e:{shape}x1")
+        return described[shape]
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield get
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo_devices):
+    return SingleDeviceSharding(topo_devices()[0])
+
+
+def _abstract(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _hlo(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# flash attention (training)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize(
+    "h,h_kv,d,block",
+    [
+        (12, 12, 64, None),   # mpt-125m, the config's default tile
+        (12, 12, 64, 1024),   # mpt-125m, the tile the July record used
+        (16, 16, 128, 512),   # mpt-1b widths
+        (32, 8, 128, 512),    # grouped-query, 4 q heads per kv head
+    ],
+    ids=["125m-default", "125m-b1024", "1b-b512", "gqa32x8-b512"],
+)
+def test_flash_attention_compiles(one_chip, h, h_kv, d, block, grad):
+    from photon_tpu.config.schema import ModelConfig
+    from photon_tpu.ops.flash_attention import flash_attention
+
+    block = block or ModelConfig().flash_block_q
+    b, s = 2, 2048
+    q = _abstract((b, s, h, d), jnp.bfloat16, one_chip)
+    kv = _abstract((b, s, h_kv, d), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, alibi=True,
+                               block_q=block, block_k=block)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    hlo = _hlo(fn, q, kv, kv)
+    # fwd is one kernel; bwd re-runs it and adds the dq and dk/dv kernels
+    assert hlo.count(KERNEL) >= (3 if grad else 1)
+
+
+def test_flash_attention_with_lse_compiles(one_chip):
+    """The (o, lse) variant ring attention merges chunks with: forward is the
+    kernel, backward recomputes through XLA."""
+    from photon_tpu.ops.flash_attention import flash_attention_with_lse
+
+    q = _abstract((2, 1024, 12, 64), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention_with_lse(q, k, v, causal=True, q_start=1024,
+                                        k_start=0)
+
+    assert KERNEL in _hlo(fwd, q, q, q)
+
+    def loss(q, k, v):
+        o, lse = fwd(q, k, v)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+# ---------------------------------------------------------------------------
+# ragged paged attention (serving) at the shapes the engine passes
+# ---------------------------------------------------------------------------
+
+
+def _serve_shapes():
+    """(mpt-125m model config, photon.serve defaults, max_blocks)."""
+    from photon_tpu.config import load_preset
+
+    cfg = load_preset("mpt-125m")
+    sc = cfg.photon.serve
+    return cfg.model, sc, -(-cfg.model.max_seq_len // sc.block_size)
+
+
+def _buckets(prompt_len: int, max_new: int = chip_smoke.MAX_NEW) -> tuple[int, int]:
+    """(chunk width Tq, live context width in blocks) ``engine._bucket`` and
+    ``engine._ctx_width`` give a lone request of this size."""
+    from photon_tpu.serve.engine import _pow2_bucket
+
+    _, sc, max_blocks = _serve_shapes()
+    bs = sc.block_size
+    tq = min(_pow2_bucket(-(-prompt_len // bs)), max_blocks) * bs
+    n_ctx = min(_pow2_bucket(-(-(prompt_len + max_new) // bs)), max_blocks)
+    return tq, n_ctx
+
+
+# chip_smoke.py's prompts, and the decode step at every width they reach
+SMOKE_PROMPTS = chip_smoke.PROMPT_LENS
+
+
+@pytest.mark.parametrize(
+    "rows,prompt_len",
+    [("decode", n) for n in SMOKE_PROMPTS] + [("chunk", n) for n in SMOKE_PROMPTS],
+)
+def test_ragged_paged_attention_compiles_at_engine_shapes(one_chip, rows, prompt_len):
+    from photon_tpu.ops.attention import alibi_slopes
+    from photon_tpu.ops.ragged_paged_attention import ragged_paged_attention
+
+    mc, sc, max_blocks = _serve_shapes()
+    tq, n_ctx = _buckets(prompt_len)
+    # decode: every slot, one token; chunk: the one prefilling slot, Tq tokens
+    b, t = (sc.n_slots, 1) if rows == "decode" else (1, tq)
+    n_blocks = sc.n_slots * max_blocks
+    dtype = jnp.dtype(mc.compute_dtype)  # the pool's dtype
+    assert dtype == jnp.bfloat16
+    q = _abstract((b, t, mc.n_heads, mc.d_head), dtype, one_chip)
+    pool = _abstract((n_blocks + 1, sc.block_size, mc.n_heads, mc.d_head),
+                     dtype, one_chip)
+    table = _abstract((b, n_ctx), jnp.int32, one_chip)
+    pos = _abstract((b, t), jnp.int32, one_chip)
+
+    def fn(q, k, v, table, pos):
+        return ragged_paged_attention(
+            q, k, v, table, pos, scale=1.0 / math.sqrt(mc.d_head),
+            slopes=alibi_slopes(mc.n_heads),
+        )
+
+    assert KERNEL in _hlo(fn, q, pool, pool, table, pos)
+
+
+def test_engine_mixed_step_compiles(one_chip):
+    """The whole serving step — decode rows riding the widest prefill chunk
+    the smoke sends — as ``PagedEngine`` traces it for the ragged kernel."""
+    from photon_tpu.models.mpt import init_params
+    from photon_tpu.serve.cache import init_paged_state, mixed_chunk_step
+
+    mc, sc, max_blocks = _serve_shapes()
+    tq, n_ctx = _buckets(max(SMOKE_PROMPTS))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _abstract(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params(mc, seed=0)))
+    state = on_chip(jax.eval_shape(lambda: init_paged_state(
+        mc, sc.n_slots, sc.n_slots * max_blocks, sc.block_size, max_blocks)))
+    grid = (sc.n_slots, tq)
+    slots = (sc.n_slots,)
+
+    def step(params, state, tokens, positions, q_valid, emit_off, lengths, slot):
+        return mixed_chunk_step(params, state, tokens, positions, q_valid,
+                                emit_off, lengths, slot, mc, n_ctx=n_ctx,
+                                has_chunk=True, impl="ragged")
+
+    hlo = _hlo(
+        step, params, state, _abstract(grid, jnp.int32, one_chip),
+        _abstract(grid, jnp.int32, one_chip), _abstract(grid, jnp.bool_, one_chip),
+        _abstract(slots, jnp.int32, one_chip), _abstract(slots, jnp.int32, one_chip),
+        _abstract((), jnp.int32, one_chip),
+    )
+    assert KERNEL in hlo
+
+
+# ---------------------------------------------------------------------------
+# whole train steps
+# ---------------------------------------------------------------------------
+
+
+def _compile_train_step(cfg, devices, monkeypatch=None):
+    """The jitted train step the Trainer would build for ``cfg``, compiled
+    against described devices from shapes alone."""
+    from photon_tpu.config.schema import effective_model_config
+    from photon_tpu.models.mpt import MPTModel, init_params
+    from photon_tpu.optim import build_optimizer
+    from photon_tpu.parallel.context import use_mesh
+    from photon_tpu.parallel.mesh import make_mesh
+    from photon_tpu.parallel.sharding import batch_spec, state_shardings
+    from photon_tpu.train.train_step import init_train_state, make_train_step
+
+    if monkeypatch is not None:
+        # tracing here sees the CPU as the default backend, where the
+        # attention dispatcher steps down to XLA; steer it to the kernel the
+        # chip would run (in the test, not through an option of the program)
+        import photon_tpu.ops.flash_attention as fa
+
+        monkeypatch.setattr(fa, "pallas_supported", lambda x: True)
+    cfg.validate()
+    mesh = make_mesh(cfg.mesh, devices=devices)
+    model_cfg = effective_model_config(cfg.model, cfg.mesh)
+    model = MPTModel(model_cfg)
+    tx, _ = build_optimizer(cfg.optimizer, cfg.scheduler)
+    state = jax.eval_shape(
+        lambda: init_train_state(model, tx, init_params(model_cfg, seed=0))
+    )
+    dp = cfg.mesh.data * cfg.mesh.fsdp * cfg.mesh.expert
+    n_micro = max(
+        cfg.train.global_batch_size // (cfg.train.device_microbatch_size * dp), 1
+    )
+    step = make_train_step(model, tx, n_microbatches=n_micro,
+                           loss_chunk_tokens=cfg.train.loss_chunk_tokens)
+    shardings = state_shardings(state, mesh)
+    batch_sh = NamedSharding(mesh, batch_spec(mesh))
+    tokens = jax.ShapeDtypeStruct(
+        (cfg.train.global_batch_size, cfg.model.max_seq_len), np.int32,
+        sharding=batch_sh,
+    )
+    jitted = jax.jit(step, in_shardings=(shardings, batch_sh),
+                     out_shardings=(shardings, None), donate_argnums=0)
+    with use_mesh(mesh):
+        return jitted.lower(state, tokens).compile(), state
+
+
+def _live_gib(compiled) -> float:
+    mem = compiled.memory_analysis()
+    # donated state aliases into the output (alias_size covers it), so live
+    # bytes = args + temps + any non-aliased output
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) / 2**30
+
+
+def _smoke_cfg():
+    """mpt-125m under ``chip_smoke.TRAIN_SETS`` (full width, small job)."""
+    import pathlib
+
+    return chip_smoke.load_config("mpt-125m", chip_smoke.TRAIN_SETS,
+                                  pathlib.Path("unused"))
+
+
+@pytest.mark.parametrize("mesh_kw", [{}, {"fsdp": 2, "tensor": 2}],
+                         ids=["one-chip", "fsdp2xtensor2"])
+def test_chip_smoke_train_step_compiles(topo_devices, monkeypatch, mesh_kw):
+    """The mpt-125m train step ``chip_smoke.py`` runs: on one chip, and under
+    the fsdp=2 x tensor=2 mesh of its ``--chips 4`` phase (the flash kernel
+    under ``shard_map``)."""
+    cfg = _smoke_cfg()
+    cfg.mesh = dataclasses.replace(cfg.mesh, **mesh_kw)
+    n_dev = cfg.mesh.size
+    compiled, _ = _compile_train_step(cfg, topo_devices()[:n_dev], monkeypatch)
+    assert compiled.as_text().count(KERNEL) >= 3
+    assert _live_gib(compiled) < 14.0
+
+
+@pytest.mark.slow  # real-TPU-compiler compile of a 32-device program, ~2 min
+def test_mpt_7b_train_step_compiles_on_32_chips(topo_devices):
+    """7B needs 32 chips; fsdp8 x tensor4 fits where fsdp16 x tensor2
+    (36 GiB) won't (the 8-device cases live in tests/test_1b_compile.py)."""
+    from photon_tpu.config import load_preset
+    from photon_tpu.config.schema import MeshConfig
+
+    cfg = load_preset("mpt-7b")
+    cfg.mesh = MeshConfig(fsdp=8, tensor=4)
+    cfg.model.attn_impl = "xla"
+    cfg.train.device_microbatch_size = 2
+    compiled, state = _compile_train_step(cfg, topo_devices("4x8"))
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(state.params))
+    assert 6.2e9 < n_params < 7.2e9, f"{n_params:,} params is not the mpt-7b recipe"
+    assert _live_gib(compiled) < 14.0
+
+
+def test_autotune_hbm_estimate_brackets_tpu_memory_analysis(topo_devices):
+    """The layout tuner's HBM estimate and the TPU compiler's memory analysis
+    must agree within a loose factor for the 1B recipe at a layout the tuner
+    marks as fitting — the estimate is a ranking device, not an allocator, but
+    it must not be fantasy."""
+    from photon_tpu.config import load_preset
+    from photon_tpu.config.schema import MeshConfig
+    from photon_tpu.parallel.autotune import HardwareModel, estimate_layout
+
+    cfg = load_preset("mpt-1b")
+    micro = 2
+    best = estimate_layout(cfg.model, MeshConfig(fsdp=4),
+                           cfg.train.global_batch_size, microbatch=micro)
+    assert best.fits
+    cfg.mesh = dataclasses.replace(best.mesh)
+    cfg.model.attn_impl = "xla"
+    cfg.train.device_microbatch_size = micro
+    compiled, _ = _compile_train_step(cfg, topo_devices())
+    live = _live_gib(compiled) * 2**30
+    est = best.hbm_bytes_per_device
+    assert est / 4 < live < est * 4, (
+        f"estimate {est / 2**30:.2f} GiB vs AOT {live / 2**30:.2f} GiB"
+    )
+    # and both respect the chip the tuner said it fits
+    assert live < HardwareModel().hbm_bytes
