@@ -1,7 +1,7 @@
 # Every target but `bench` runs on the CPU (JAX_PLATFORMS=cpu): tests and the
 # functional reports never take a chip. The chip is reached through the chip
 # tool, one process per chip: `python chip_smoke.py`, `python bench.py`.
-.PHONY: test test-all verify bench bench-host bench-telemetry bench-collective bench-zero1 bench-ragged bench-compare chaos chaos-collective telemetry-smoke serve-smoke spec-smoke fleet-smoke adapters-smoke async-smoke autopilot-smoke lint lint-tests native clean
+.PHONY: test test-all verify bench bench-host bench-collective bench-zero1 bench-ragged bench-compare chaos chaos-collective telemetry-smoke serve-smoke spec-smoke fleet-smoke adapters-smoke async-smoke autopilot-smoke lint lint-tests native clean
 # native build is best-effort: the package degrades to numpy fallbacks when
 # the .so is absent, so tests must run even without a C++ toolchain
 test:
@@ -27,11 +27,6 @@ bench:
 # CPU-runnable, takes no chip
 bench-host:
 	JAX_PLATFORMS=cpu python bench.py --host-plane
-
-# tracing-plane cost report only (tiny fed rounds, spans on vs off, plus
-# the disabled hook-site ns); CPU-runnable, takes no chip
-bench-telemetry:
-	JAX_PLATFORMS=cpu python bench.py --telemetry-overhead
 
 # device-collective aggregation report only (ISSUE 7: flat fp32 psum vs
 # hierarchical q8 on an emulated 8-device CPU client mesh); exit code

@@ -26,7 +26,7 @@ Everything else is its own invocation with its own exit code (0 only when
 its gate holds): ``--kernel-parity`` (Pallas vs XLA, writes
 KERNEL_PARITY.json), ``--stage parity|conv|gauntlet|1b`` (on-chip evidence
 artifacts; conv persists its trained params for gauntlet), and the CPU-only
-functional reports ``--host-plane --telemetry-overhead --serving --ragged
+functional reports ``--host-plane --serving --ragged
 --speculative --fleet --autopilot --adapters --zero1 --async --collective``
 (tiny models, ``JAX_PLATFORMS=cpu``; counts and functional gates, never
 speeds), plus ``--compare OLD NEW``.
@@ -830,127 +830,6 @@ def host_plane_report(model_cfg=None, n_clients: int = 8,
         return report
     except Exception as e:  # noqa: BLE001 — never cost the round its numbers
         log(f"host plane report failed: {type(e).__name__}: {e}")
-        return None
-
-
-def telemetry_overhead_report(n_rounds: int = 12, spin_calls: int = 200_000) -> dict | None:
-    """Round-time cost of the tracing plane (ISSUE 4 satellite): the same
-    tiny in-process federated run with ``photon.telemetry`` off vs on,
-    plus a microbench of the disabled hook site itself.
-
-    Two numbers matter:
-
-    - ``disabled_span_ns`` / ``disabled_event_ns``: cost of one
-      ``telemetry.span()`` / ``emit_event()`` call with no tracer installed
-      — the price every hook site pays in production configs (should be
-      ~100ns: a module-global load + None check);
-    - ``overhead_pct``: median round-time delta of spans ON vs OFF on a
-      deliberately tiny model (worst case for relative overhead — real
-      rounds bury the tracer under minutes of client compute; the
-      acceptance bar is <2%).
-
-    Round 1 of each mode is excluded (it carries the jit compile)."""
-    try:
-        import tempfile
-
-        from photon_tpu import telemetry
-        from photon_tpu.config.schema import Config
-        from photon_tpu.federation import InProcessDriver, NodeAgent, ParamTransport, ServerApp
-        from photon_tpu.utils.profiling import ROUND_TIME
-
-        def run_mode(enabled: bool) -> list[float]:
-            tmp = tempfile.mkdtemp(prefix="photon-bench-telemetry-")
-            cfg = Config()
-            cfg.model.d_model = 32
-            cfg.model.n_layers = 2
-            cfg.model.n_heads = 2
-            cfg.model.max_seq_len = 16
-            cfg.model.vocab_size = 64
-            cfg.model.attn_impl = "xla"
-            cfg.model.compute_dtype = "float32"
-            cfg.train.global_batch_size = 4
-            cfg.train.device_microbatch_size = 4
-            cfg.fl.n_total_clients = 2
-            cfg.fl.n_clients_per_round = 2
-            cfg.fl.n_rounds = n_rounds
-            cfg.fl.local_steps = 2
-            cfg.fl.eval_interval_rounds = 0
-            cfg.dataset.synthetic = True
-            cfg.photon.save_path = tmp
-            cfg.photon.checkpoint = False
-            cfg.photon.telemetry.enabled = enabled
-            cfg.validate()
-            driver = InProcessDriver(
-                cfg,
-                lambda nid: NodeAgent(cfg, nid, lambda: ParamTransport("inline")),
-                n_nodes=1,
-            )
-            app = ServerApp(cfg, driver, ParamTransport("inline"))
-            try:
-                history = app.run()
-            finally:
-                driver.shutdown()
-            return [v for _, v in history.series(ROUND_TIME)]
-
-        # disabled-path microbench FIRST (nothing installed yet)
-        telemetry.uninstall()
-        t0 = time.perf_counter()
-        for _ in range(spin_calls):
-            with telemetry.span("bench/noop"):
-                pass
-        disabled_span_ns = (time.perf_counter() - t0) / spin_calls * 1e9
-        t0 = time.perf_counter()
-        for _ in range(spin_calls):
-            telemetry.emit_event("bench/noop")
-        disabled_event_ns = (time.perf_counter() - t0) / spin_calls * 1e9
-        # the typed-metric hook (ISSUE 10): same one-None-check contract
-        t0 = time.perf_counter()
-        for _ in range(spin_calls):
-            telemetry.metric_observe("bench/noop", 0.0)
-        disabled_metric_ns = (time.perf_counter() - t0) / spin_calls * 1e9
-        # the profiling unit-boundary hook (server round loop / serve tick)
-        t0 = time.perf_counter()
-        for _ in range(spin_calls):
-            telemetry.profile_tick("bench/noop")
-        disabled_profile_tick_ns = (time.perf_counter() - t0) / spin_calls * 1e9
-
-        # ABBA mode order: balanced against linear drift (page cache growth,
-        # allocator warm-up, background compile-cache writes) — measured on
-        # this 1-core host, a naive off,on order shows ±20% phantom deltas
-        # that flip sign under the reversed order. The uninstall is a
-        # finally: a failed middle run must not leave an ENABLED tracer
-        # perturbing every later bench section in this process.
-        rounds_off: list[list[float]] = []
-        rounds_on: list[list[float]] = []
-        try:
-            for enabled in (False, True, True, False):
-                (rounds_on if enabled else rounds_off).append(run_mode(enabled)[1:])
-        finally:
-            telemetry.uninstall()
-        # best-of per mode (same convention as host_plane_report): on a
-        # 1-core host the MEDIAN round carries scheduler noise an order of
-        # magnitude above the tracer's real cost — the fastest round is the
-        # least-perturbed observation of each mode, and genuine overhead
-        # would show up in the minimum too
-        off = min(v for run in rounds_off for v in run)
-        on = min(v for run in rounds_on for v in run)
-        # same-mode repeat spread = the measurement's noise floor on this
-        # host; an |overhead_pct| below it is indistinguishable from zero
-        off_mins = [min(r) for r in rounds_off]
-        noise_pct = abs(off_mins[0] - off_mins[1]) / off * 100.0 if off > 0 else None
-        return {
-            "n_rounds": n_rounds,
-            "round_time_off_s": round(off, 5),
-            "round_time_on_s": round(on, 5),
-            "overhead_pct": round((on - off) / off * 100.0, 2) if off > 0 else None,
-            "noise_pct": round(noise_pct, 2) if noise_pct is not None else None,
-            "disabled_span_ns": round(disabled_span_ns, 1),
-            "disabled_event_ns": round(disabled_event_ns, 1),
-            "disabled_metric_ns": round(disabled_metric_ns, 1),
-            "disabled_profile_tick_ns": round(disabled_profile_tick_ns, 1),
-        }
-    except Exception as e:  # noqa: BLE001 — never cost the round its numbers
-        log(f"telemetry overhead report failed: {type(e).__name__}: {e}")
         return None
 
 
@@ -1928,9 +1807,8 @@ def collective_report(n_clients: int = 4, replica: int = 2,
       ``tests/test_collective_agg.py``; reported here for provenance).
 
     ``q8_codec_roundtrip_s`` times the jnp quantize→dequantize round trip
-    out-of-line on the same payload (``server/collective_quant_time`` —
-    inside the round the codec is fused into the exchange program and
-    can't be timed separately)."""
+    out-of-line on the same payload (inside the round the codec is fused
+    into the exchange program and can't be timed separately)."""
     try:
         import numpy as np
 
@@ -2049,11 +1927,6 @@ def collective_report(n_clients: int = 4, replica: int = 2,
             dt = time.perf_counter() - t0
             best = dt if best is None else min(best, dt)
         report["q8_codec_roundtrip_s"] = round(best, 5)
-        # also under the registered KPI name, so the metric registry entry
-        # resolves to a real measurement in the BENCH_r*.json artifacts
-        from photon_tpu.utils.profiling import COLLECTIVE_QUANT_TIME
-
-        report[COLLECTIVE_QUANT_TIME] = report["q8_codec_roundtrip_s"]
         return report
     except Exception as e:  # noqa: BLE001 — never cost the round its numbers
         log(f"collective report failed: {type(e).__name__}: {e}")
@@ -3147,10 +3020,6 @@ def main() -> int:
     ap.add_argument("--host-plane", action="store_true",
                     help="run only the host-plane aggregation report (CPU, "
                          "no device) and print {'host_plane': ...}")
-    ap.add_argument("--telemetry-overhead", action="store_true",
-                    help="run only the telemetry-overhead report (tiny CPU "
-                         "fed rounds, spans on vs off) and print "
-                         "{'telemetry_overhead': ...}")
     ap.add_argument("--serving", action="store_true",
                     help="run only the serving report (continuous batching "
                          "vs batch-synchronous, tiny CPU model) and print "
@@ -3233,12 +3102,6 @@ def main() -> int:
         hp = host_plane_report()
         emit({"host_plane": hp})
         return 0 if hp is not None else 1
-    if args.telemetry_overhead:
-        # tiny fed rounds — pin to CPU so the report never claims a chip
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        to = telemetry_overhead_report()
-        emit({"telemetry_overhead": to})
-        return 0 if to is not None else 1
     if args.serving:
         # host+CPU-jax work only — never claims a chip; the exit code is
         # the serve-smoke acceptance gate: continuous must beat batch-sync,

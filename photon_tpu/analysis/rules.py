@@ -76,7 +76,7 @@ def _functions(tree: ast.AST) -> Iterator[ast.AST]:
 
 #: method / function names whose first positional argument is a KPI, span,
 #: or event name (the registry vocabulary)
-_NAME_SITES = frozenset({"span", "add_span", "timed_add", "emit_event", "emit"})
+_NAME_SITES = frozenset({"span", "add_span", "emit_event", "emit"})
 
 
 def _name_arg_finding(ctx: FileContext, call: ast.Call, arg: ast.expr,

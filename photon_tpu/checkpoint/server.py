@@ -120,18 +120,19 @@ class ServerCheckpointManager:
         t_enqueue = time.monotonic()
 
         def _write() -> None:
-            t0 = time.monotonic()
+            # the span's own timer is the ckpt_async_write_s KPI: the window
+            # is the same, so it is measured once
+            span = telemetry.span(CKPT_ASYNC_WRITE_S, parent=trace_ctx,
+                                  round=server_round)
             try:
-                with telemetry.span(CKPT_ASYNC_WRITE_S, parent=trace_ctx,
-                                    round=server_round):
+                with span as sp:
                     self.save_round(server_round, metadata, params, state, server)
                     if cleanup_keep is not None:
                         keep, keys = cleanup_keep
                         self.cleanup(keep, keys)
             except BaseException as e:  # noqa: BLE001 — re-raised at the barrier
                 self._pending_error = e
-            finally:
-                self._last_async_write_s = time.monotonic() - t0
+            self._last_async_write_s = sp.seconds
 
         th = threading.Thread(
             target=_write, name=f"ckpt-write-r{server_round}", daemon=True

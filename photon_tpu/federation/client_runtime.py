@@ -51,6 +51,7 @@ from photon_tpu.utils.profiling import (
     CLIENT_PACKAGE_SPAN,
     CLIENT_PARAM_NORM,
     CLIENT_PSEUDO_GRAD_NORM,
+    CLIENT_PSEUDO_GRAD_NORM_SPAN,
     CLIENT_RESOLVE_PARAMS_SPAN,
     CLIENT_SKIPPED_ROUND,
     CLIENT_TRAIN_SPAN,
@@ -201,7 +202,8 @@ class ClientRuntime:
                 t_start=t_start,
             )
 
-        with telemetry.span(CLIENT_RESOLVE_PARAMS_SPAN, cid=cid):
+        with telemetry.span(CLIENT_RESOLVE_PARAMS_SPAN, cid=cid,
+                            round=ins.server_round):
             meta, arrays = self._resolve_params(ins.params)
 
         # momenta piggybacking: [params|m1|m2] payloads (reference
@@ -267,7 +269,7 @@ class ClientRuntime:
         from photon_tpu.chaos import crash_point
 
         crash_point("mid-fit", ins.server_round, self.node_id)
-        with telemetry.span(CLIENT_TRAIN_SPAN, cid=cid,
+        with telemetry.span(CLIENT_TRAIN_SPAN, cid=cid, round=ins.server_round,
                             local_steps=ins.local_steps):
             fit_metrics = self.trainer.fit(
                 loader, ins.local_steps, log_every=cfg.train.log_interval
@@ -285,9 +287,12 @@ class ClientRuntime:
 
         # pseudo-gradient telemetry (reference: ``post_process_client_result``
         # L2 norms, ``clients/utils.py:599-619``)
-        delta = [o - i for o, i in zip(out_arrays, initial)]
-        fit_metrics[CLIENT_PSEUDO_GRAD_NORM] = _l2(delta)
-        fit_metrics[CLIENT_PARAM_NORM] = _l2(out_arrays)
+        with telemetry.span(CLIENT_PSEUDO_GRAD_NORM_SPAN, cid=cid,
+                            round=ins.server_round):
+            delta = [o - i for o, i in zip(out_arrays, initial)]
+            fit_metrics[CLIENT_PSEUDO_GRAD_NORM] = _l2(delta)
+            fit_metrics[CLIENT_PARAM_NORM] = _l2(out_arrays)
+            del delta
 
         if knobs.personalize_patterns:
             self._personal[cid] = [a.copy() for a in out_arrays]
@@ -339,12 +344,12 @@ class ClientRuntime:
         # (delta against this round's broadcast, EF residuals keyed by cid);
         # the encode span covers codec + plane write — the upload leg of the
         # client timeline
-        with telemetry.span(CLIENT_ENCODE_SPAN, cid=cid):
+        with telemetry.span(CLIENT_ENCODE_SPAN, cid=cid, round=ins.server_round):
             ptr = self.transport.put(
                 f"fit-r{ins.server_round}-c{cid}-{self.node_id}", meta, arrays,
                 compress=True, key=cid,
             )
-        with telemetry.span(CLIENT_PACKAGE_SPAN, cid=cid):
+        with telemetry.span(CLIENT_PACKAGE_SPAN, cid=cid, round=ins.server_round):
             new_state = ClientState(
                 cid=cid,
                 steps_cumulative=state_in.steps_cumulative + ins.local_steps,

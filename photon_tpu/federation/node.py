@@ -30,6 +30,7 @@ from photon_tpu.federation.messages import (
     Query,
 )
 from photon_tpu.federation.transport import ParamTransport
+from photon_tpu.utils.profiling import NODE_SET_BROADCAST_SPAN
 
 
 class NodeAgent:
@@ -63,7 +64,9 @@ class NodeAgent:
             return [self.runtime.evaluate(msg, cid) for cid in msg.cids]
         if isinstance(msg, Broadcast):
             try:
-                self.runtime.set_broadcast_params(msg.params)
+                with telemetry.span(NODE_SET_BROADCAST_SPAN,
+                                    round=msg.server_round, node=self.node_id):
+                    self.runtime.set_broadcast_params(msg.params)
                 return Ack(ok=True, node_id=self.node_id)
             except Exception as e:  # noqa: BLE001
                 return Ack(ok=False, detail=f"{type(e).__name__}: {e}", node_id=self.node_id)

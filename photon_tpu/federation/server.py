@@ -281,40 +281,44 @@ class ServerApp:
             self._rng.sample(range(self.cfg.fl.n_total_clients), self.cfg.fl.n_clients_per_round)
         )
 
-    def broadcast_parameters(self, server_round: int) -> float:
+    def broadcast_parameters(self, server_round: int,
+                             phase: str = BROADCAST_PRE_TIME) -> float:
         """Push current global params to every node; returns elapsed seconds
-        (reference: ``broadcast_parameters_to_nodes``, ``broadcast_utils.py:60-201``)."""
-        t0 = time.monotonic()
-        assert self.strategy.current_parameters is not None
-        ptr = self.transport.put(
-            f"bcast-r{server_round}-{uuid_mod.uuid4().hex[:8]}",
-            self.metadata,
-            self.strategy.current_parameters,
-        )
-        # the broadcast IS the round's delta base — pin it so compressed
-        # client results (w_new − w_global) decode against the right arrays
-        self.transport.set_reference(self.strategy.current_parameters)
-        msg = Broadcast(server_round, ptr)
-        acks = self.driver.broadcast(msg, on_stale=self._free_stale_reply)
-        for a in acks.values():
-            self._ingest_result_telemetry(a)
-        # a node dying AT broadcast time is an elasticity event, not a fatal
-        # error: it leaves the registry (TCP) or respawns paramless
-        # (multiprocess) and the rejoin scan re-broadcasts when it returns.
-        # Only a LIVE node rejecting the payload is a real failure.
-        bad = [
-            nid for nid, a in acks.items()
-            if not a.ok and "node died" not in (a.detail or "")
-        ]
-        if bad:
-            raise RuntimeError(f"broadcast failed on nodes {bad}: {[acks[n].detail for n in bad]}")
-        # free the PREVIOUS round's segment only now: nodes have copied the
-        # new payload (ack'd), nothing references the old one (reference:
-        # Ray GC thread / per-round shm unlink, ``utils.py:73-144``)
-        if self._last_broadcast is not None:
-            self.transport.free(self._last_broadcast.params)
-        self._last_broadcast = msg
-        return time.monotonic() - t0
+        (reference: ``broadcast_parameters_to_nodes``, ``broadcast_utils.py:60-201``).
+        ``phase`` names the span (``BROADCAST_PRE_TIME`` before the fits,
+        ``BROADCAST_POST_TIME`` before a federated eval); its timer is the
+        KPI of the same name."""
+        with telemetry.span(phase, round=server_round) as sp:
+            assert self.strategy.current_parameters is not None
+            ptr = self.transport.put(
+                f"bcast-r{server_round}-{uuid_mod.uuid4().hex[:8]}",
+                self.metadata,
+                self.strategy.current_parameters,
+            )
+            # the broadcast IS the round's delta base — pin it so compressed
+            # client results (w_new − w_global) decode against the right arrays
+            self.transport.set_reference(self.strategy.current_parameters)
+            msg = Broadcast(server_round, ptr)
+            acks = self.driver.broadcast(msg, on_stale=self._free_stale_reply)
+            for a in acks.values():
+                self._ingest_result_telemetry(a)
+            # a node dying AT broadcast time is an elasticity event, not a fatal
+            # error: it leaves the registry (TCP) or respawns paramless
+            # (multiprocess) and the rejoin scan re-broadcasts when it returns.
+            # Only a LIVE node rejecting the payload is a real failure.
+            bad = [
+                nid for nid, a in acks.items()
+                if not a.ok and "node died" not in (a.detail or "")
+            ]
+            if bad:
+                raise RuntimeError(f"broadcast failed on nodes {bad}: {[acks[n].detail for n in bad]}")
+            # free the PREVIOUS round's segment only now: nodes have copied the
+            # new payload (ack'd), nothing references the old one (reference:
+            # Ray GC thread / per-round shm unlink, ``utils.py:73-144``)
+            if self._last_broadcast is not None:
+                self.transport.free(self._last_broadcast.params)
+            self._last_broadcast = msg
+        return sp.seconds
 
     def free_transport(self) -> None:
         """Release the live broadcast segment + any transport leftovers; call
@@ -573,13 +577,12 @@ class ServerApp:
                 yield ClientResult(res.cid, arrays, res.n_samples, res.metrics)
                 self.transport.free(res.params)
 
-        t_fit = time.monotonic()
         # the fit-wait span covers scheduling + client fits + streaming
-        # aggregation — the same window as the fit_round_time KPI
+        # aggregation; its timer is the fit_round_time KPI
         with telemetry.span(FIT_ROUND_TIME, round=server_round,
-                            n_cids=len(cids)):
+                            n_cids=len(cids)) as sp:
             new_params, metrics = self.strategy.aggregate_fit(server_round, results())
-        metrics[FIT_ROUND_TIME] = time.monotonic() - t_fit
+        metrics[FIT_ROUND_TIME] = sp.seconds
         del new_params  # strategy.current_parameters already updated
 
         agg_sq = metrics.get(PSEUDO_GRAD_NORM, 0.0) ** 2
@@ -737,22 +740,31 @@ class ServerApp:
 
     def _round_loop(self, cfg: Config, n_rounds: int) -> None:
         for rnd in range(self.start_round, n_rounds + 1):
-            # on-demand profiling unit boundary (telemetry/introspect.py):
-            # an armed capture starts at the next round start and stops N
-            # round starts later — one None check when nothing is armed
-            telemetry.profile_tick("server/round")
-            # one umbrella span per round (server/round — NOT the
-            # round_time KPI name, which measures a narrower window): every
-            # phase span below — and, via Envelope.trace, every client-side
-            # fit/eval span — parents under it in the merged timeline
-            with telemetry.span(ROUND_SPAN, round=rnd):
-                self._one_round(cfg, rnd)
-            # retrace-sentinel hook (analysis/runtime.py): a None check
-            # when disabled; under the e2e fixture a steady-state round
-            # that recompiles is billed to its round boundary
-            steady_point("server/round")
+            self.run_round(rnd)
         # close an armed-for-more-rounds-than-the-run-had capture cleanly
-        telemetry.profile_tick("server/round")
+        telemetry.profile_tick(ROUND_SPAN)
+
+    def run_round(self, rnd: int) -> None:
+        """One whole round, as :meth:`run`'s loop runs it: broadcast, fits,
+        aggregation, server update, eval and checkpoint where due, the
+        round's History line. A caller that drives rounds itself owns what
+        :meth:`run` does around the loop: the round-0 checkpoint before the
+        first round, and ``ckpt_mgr.wait_pending()`` + ``free_transport()``
+        after the last."""
+        # on-demand profiling unit boundary (telemetry/introspect.py):
+        # an armed capture starts at the next round start and stops N
+        # round starts later — one None check when nothing is armed
+        telemetry.profile_tick(ROUND_SPAN)
+        # one umbrella span per round (server/round — NOT the
+        # round_time KPI name, which measures a narrower window): every
+        # phase span below — and, via Envelope.trace, every client-side
+        # fit/eval span — parents under it in the merged timeline
+        with telemetry.span(ROUND_SPAN, round=rnd):
+            self._one_round(self.cfg, rnd)
+        # retrace-sentinel hook (analysis/runtime.py): a None check
+        # when disabled; under the e2e fixture a steady-state round
+        # that recompiles is billed to its round boundary
+        steady_point(ROUND_SPAN)
 
     def _one_round(self, cfg: Config, rnd: int) -> None:
         if cfg.photon.refresh_period and rnd > 1 and (rnd - 1) % cfg.photon.refresh_period == 0:
@@ -763,8 +775,7 @@ class ServerApp:
         # in the registry when broadcast_parameters fans out, so a
         # crash-and-rejoin between rounds needs no special re-send
         self._membership_round_start(rnd)
-        with telemetry.span(BROADCAST_PRE_TIME, round=rnd):
-            t_pre = self.broadcast_parameters(rnd)
+        t_pre = self.broadcast_parameters(rnd)
         try:
             metrics = self.fit_round(rnd)
         except TooManyFailuresError:
@@ -779,8 +790,7 @@ class ServerApp:
         metrics.update(self._membership_metrics())
 
         if cfg.fl.eval_interval_rounds and rnd % cfg.fl.eval_interval_rounds == 0:
-            with telemetry.span(BROADCAST_POST_TIME, round=rnd):
-                t_post = self.broadcast_parameters(rnd)
+            t_post = self.broadcast_parameters(rnd, BROADCAST_POST_TIME)
             try:
                 metrics.update(self.evaluate_round(rnd))
             except TooManyFailuresError:
@@ -797,19 +807,18 @@ class ServerApp:
             and cfg.photon.checkpoint
             and rnd % cfg.photon.checkpoint_interval == 0
         ):
-            t_ck = time.monotonic()
             # the span covers only what the round loop BLOCKS on (snapshot +
             # enqueue + barrier); the background write itself renders as a
             # separate ckpt_async_write_s span overlapping the next round
-            with telemetry.span(CHECKPOINT_TIME, round=rnd):
+            with telemetry.span(CHECKPOINT_TIME, round=rnd) as sp:
                 self.save_checkpoint(rnd)
-            # checkpoint_time = what the round loop was BLOCKED on:
-            # snapshot + enqueue, plus — when the store is slower than a
-            # round — the barrier wait for round N-1's write, reported
-            # separately below so slow-store regimes are visible. The
-            # write itself overlaps the next round and reports as
+            # checkpoint_time (the span's own timer) = what the round loop
+            # was BLOCKED on: snapshot + enqueue, plus — when the store is
+            # slower than a round — the barrier wait for round N-1's write,
+            # reported separately below so slow-store regimes are visible.
+            # The write itself overlaps the next round and reports as
             # CKPT_ASYNC_WRITE_S one round later.
-            metrics[CHECKPOINT_TIME] = time.monotonic() - t_ck
+            metrics[CHECKPOINT_TIME] = sp.seconds
             metrics[CKPT_ASYNC_WRITE_S] = float(self.ckpt_mgr.last_async_write_s)
             if self.cfg.photon.async_checkpoint:
                 metrics[CKPT_BARRIER_WAIT_S] = float(

@@ -111,7 +111,7 @@ class SocketConn:
         # the syscall path, so a slow/buffer-bound control-plane write is
         # attributable on the timeline. Measured around the lock + sendall
         # — contention IS part of the leg the caller experiences.
-        with telemetry.timed_add(TCP_SEND_SPAN, nbytes=len(data)):
+        with telemetry.span(TCP_SEND_SPAN, push=False, nbytes=len(data)):
             with self._wlock:
                 for _ in range(repeat):
                     self.sock.sendall(header + data)
@@ -140,7 +140,7 @@ class SocketConn:
             # the recv leg span starts AFTER the header lands: everything
             # before it is idle wait for the peer, which would drown the
             # actual transport cost (payload read + unpickle) on a timeline
-            with telemetry.timed_add(TCP_RECV_SPAN, nbytes=n):
+            with telemetry.span(TCP_RECV_SPAN, push=False, nbytes=n):
                 data = self._read_exact(n)
             telemetry.metric_observe(TCP_RECV_BYTES, n)
         if zlib.crc32(data) != crc:

@@ -27,6 +27,7 @@ import json
 
 import numpy as np
 
+from photon_tpu import telemetry
 from photon_tpu.checkpoint.store import ObjectStore
 from photon_tpu.checkpoint.serialization import arrays_to_npz, npz_to_arrays
 from photon_tpu.codec import ParamsMetadata
@@ -34,7 +35,12 @@ from photon_tpu.compression import CompressedPayload, make_codec
 from photon_tpu.federation.messages import ParamPointer
 from photon_tpu.shm import plane as shm
 from photon_tpu.utils.hostpool import HostPool
-from photon_tpu.utils.profiling import WireStats
+from photon_tpu.utils.profiling import (
+    TRANSPORT_FREE_SPAN,
+    TRANSPORT_GET_SPAN,
+    TRANSPORT_PUT_SPAN,
+    WireStats,
+)
 
 #: reserved layer name carrying a serialized CompressedPayload through the
 #: planes (never collides with model paths, which are "/"-joined pytree keys)
@@ -115,13 +121,25 @@ class ParamTransport:
                 "version": payload.version,
                 "wire_nbytes": int(blob.nbytes),
             }
-            ptr = self._put_raw(tag, _blob_metadata(blob.nbytes), [blob])
+            ptr = self._put_raw(tag, _blob_metadata(blob.nbytes), [blob],
+                                metadata.total_bytes)
             return ParamPointer(ptr.kind, ptr.locator, json.dumps(meta_d),
                                 inline=ptr.inline)
         self.stats.record_sent(metadata.total_bytes, metadata.total_bytes)
-        return self._put_raw(tag, metadata, arrays)
+        return self._put_raw(tag, metadata, arrays, metadata.total_bytes)
 
     def _put_raw(
+        self, tag: str, metadata: ParamsMetadata, arrays: list[np.ndarray],
+        nbytes: int,
+    ) -> ParamPointer:
+        """The plane write alone (the codec's encode, when there is one, is
+        the caller's span): ``nbytes`` is the payload before the codec,
+        ``wire_nbytes`` what is written."""
+        with telemetry.span(TRANSPORT_PUT_SPAN, push=False, mode=self.mode,
+                            nbytes=nbytes, wire_nbytes=metadata.total_bytes):
+            return self._write(tag, metadata, arrays)
+
+    def _write(
         self, tag: str, metadata: ParamsMetadata, arrays: list[np.ndarray]
     ) -> ParamPointer:
         if self.mode == "shm":
@@ -181,6 +199,15 @@ class ParamTransport:
     def _get_raw(
         self, ptr: ParamPointer, metadata: ParamsMetadata, copy: bool, timeout: float
     ) -> tuple[ParamsMetadata, list[np.ndarray]]:
+        """The plane read alone (waiting for the object included; decoding a
+        compressed payload is the caller's span)."""
+        with telemetry.span(TRANSPORT_GET_SPAN, push=False, mode=ptr.kind,
+                            copy=copy, wire_nbytes=metadata.total_bytes):
+            return self._read(ptr, metadata, copy, timeout)
+
+    def _read(
+        self, ptr: ParamPointer, metadata: ParamsMetadata, copy: bool, timeout: float
+    ) -> tuple[ParamsMetadata, list[np.ndarray]]:
         if ptr.kind == "shm":
             shm.wait_for(ptr.locator, timeout=timeout)
             got_meta, arrays = shm.read_params(ptr.locator, copy=copy)
@@ -202,10 +229,11 @@ class ParamTransport:
     def free(self, ptr: ParamPointer) -> None:
         """Release the payload behind a pointer (reference: Ray GC thread /
         shm unlink after round, ``utils.py:73-144``)."""
-        if ptr.kind == "shm":
-            shm.unlink(ptr.locator)
-        elif ptr.kind == "objstore" and self.store is not None:
-            self.store.delete(ptr.locator)
+        with telemetry.span(TRANSPORT_FREE_SPAN, push=False, mode=ptr.kind):
+            if ptr.kind == "shm":
+                shm.unlink(ptr.locator)
+            elif ptr.kind == "objstore" and self.store is not None:
+                self.store.delete(ptr.locator)
 
     def cleanup(self) -> None:
         for name in self._owned:

@@ -254,6 +254,7 @@ def ragged_paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         ],
         out_shape=jax.ShapeDtypeStruct((b * n_kv, tg, d_pad), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(*inputs)
 
     out = out[..., :d].reshape(b, n_kv, t, group, d).transpose(0, 2, 1, 3, 4)

@@ -26,7 +26,6 @@ the averaged result is BIT-IDENTICAL across configurations.
 
 from __future__ import annotations
 
-import time
 from typing import Callable, Iterable, Iterator
 
 import numpy as np
@@ -107,13 +106,15 @@ def aggregate_inplace(
     t_decode = [0.0]
     t_fold = [0.0]
     it: Iterator = iter(results)
-    # per-client decode/fold windows render as spans under whatever round
-    # span is open on the CALLING thread: decode-ahead runs on a pool
-    # worker with an empty context stack, so the parent is captured here
-    # (span names = the KPI names the same seconds accumulate into)
-    tracer = telemetry.active()
-    trace_parent = telemetry.current_context() if tracer is not None else None
+    # per-client decode/fold windows are spans under whatever round span is
+    # open on the CALLING thread: decode-ahead runs on a pool worker with an
+    # empty context stack, so the parent is captured here. Each span's own
+    # timer is what accumulates into the KPI of the same name.
+    trace_parent = telemetry.current_context()
     n_seen = [0]
+
+    def fold_span():
+        return telemetry.span(AGG_FOLD_TIME, parent=trace_parent)
 
     def _fetch_decode() -> tuple[list[np.ndarray], int] | None:
         """Pull + decode the next result (runs on the pool when pipelined;
@@ -126,18 +127,14 @@ def aggregate_inplace(
             item, n_cur = next(it)
         except StopIteration:
             return None
-        t_wall = time.time()
-        t0 = time.monotonic()
-        arrays = _arrays(item)
-        dt = time.monotonic() - t0
-        t_decode[0] += dt
-        if tracer is not None:
-            tracer.add_span(AGG_DECODE_TIME, t_wall, dt, parent=trace_parent,
-                            client_index=n_seen[0])
+        with telemetry.span(AGG_DECODE_TIME, parent=trace_parent,
+                            client_index=n_seen[0]) as sp:
+            arrays = _arrays(item)
+        t_decode[0] += sp.seconds
         # per-client decode seconds as a DISTRIBUTION (typed hub): a fat
         # tail here is one slow client's payload, invisible in the summed
         # KPI the same seconds accumulate into
-        telemetry.metric_observe(AGG_DECODE_TIME, dt)
+        telemetry.metric_observe(AGG_DECODE_TIME, sp.seconds)
         n_seen[0] += 1
         return arrays, n_cur
 
@@ -148,15 +145,15 @@ def aggregate_inplace(
     if n_total <= 0:
         raise ValueError(f"non-positive n_samples {n_total}")
 
-    t0 = time.monotonic()
     # order="C": _fold_into relies on acc.reshape(-1) being a VIEW — an
     # already-fp64 non-contiguous first payload would otherwise pass through
     # asarray unchanged and every later fold would land in a discarded copy
-    if pool is not None:
-        acc = pool.map(lambda a: np.asarray(a, dtype=np.float64, order="C"), arrays)
-    else:
-        acc = [np.asarray(a, dtype=np.float64, order="C") for a in arrays]
-    t_fold[0] += time.monotonic() - t0
+    with fold_span() as sp:
+        if pool is not None:
+            acc = pool.map(lambda a: np.asarray(a, dtype=np.float64, order="C"), arrays)
+        else:
+            acc = [np.asarray(a, dtype=np.float64, order="C") for a in arrays]
+    t_fold[0] += sp.seconds
 
     pipelined = pool is not None and pool.pipelined
     pending = pool.submit(_fetch_decode) if pipelined else None
@@ -184,23 +181,19 @@ def aggregate_inplace(
             n_new = n_total + n_cur
             w_prev = n_total / n_new
             w_cur = n_cur / n_new
-            t_wall = time.time()
-            t0 = time.monotonic()
-            if pool is not None:
-                pool.map(
-                    lambda i, _a=arrays, _wp=w_prev, _wc=w_cur: _fold_into(
-                        acc[i], _a[i], _wp, _wc
-                    ),
-                    range(len(acc)),
-                )
-            else:
-                for a, y in zip(acc, arrays):
-                    _fold_into(a, y, w_prev, w_cur)
-            dt = time.monotonic() - t0
-            t_fold[0] += dt
-            if tracer is not None:
-                tracer.add_span(AGG_FOLD_TIME, t_wall, dt, parent=trace_parent)
-            telemetry.metric_observe(AGG_FOLD_TIME, dt)
+            with fold_span() as sp:
+                if pool is not None:
+                    pool.map(
+                        lambda i, _a=arrays, _wp=w_prev, _wc=w_cur: _fold_into(
+                            acc[i], _a[i], _wp, _wc
+                        ),
+                        range(len(acc)),
+                    )
+                else:
+                    for a, y in zip(acc, arrays):
+                        _fold_into(a, y, w_prev, w_cur)
+            t_fold[0] += sp.seconds
+            telemetry.metric_observe(AGG_FOLD_TIME, sp.seconds)
             n_total = n_new
     except BaseException:
         if pending is not None:
@@ -210,12 +203,12 @@ def aggregate_inplace(
             pending.cancel()
         raise
 
-    t0 = time.monotonic()
-    if pool is not None:
-        out = pool.map(lambda a: a.astype(np.float32), acc)
-    else:
-        out = [a.astype(np.float32) for a in acc]
-    t_fold[0] += time.monotonic() - t0
+    with fold_span() as sp:
+        if pool is not None:
+            out = pool.map(lambda a: a.astype(np.float32), acc)
+        else:
+            out = [a.astype(np.float32) for a in acc]
+    t_fold[0] += sp.seconds
     if timings is not None:
         timings["decode_s"] = timings.get("decode_s", 0.0) + t_decode[0]
         timings["fold_s"] = timings.get("fold_s", 0.0) + t_fold[0]

@@ -20,6 +20,7 @@ from typing import Any, Iterable
 
 import numpy as np
 
+from photon_tpu import telemetry as _telemetry  # __init__ has a `telemetry` flag
 from photon_tpu.strategy.aggregation import aggregate_inplace, weighted_average_metrics
 from photon_tpu.utils.profiling import (
     AGG_DECODE_TIME,
@@ -30,6 +31,7 @@ from photon_tpu.utils.profiling import (
     N_SAMPLES,
     PARAM_NORM,
     PSEUDO_GRAD_NORM,
+    SERVER_UPDATE_SPAN,
 )
 
 
@@ -166,18 +168,19 @@ class Strategy:
                 "aggregate_momenta is on)"
             )
         self.server_round = server_round
-        pseudo_grad = [x - a for x, a in zip(self.current_parameters, avg)]
-        lr = self.effective_lr(n_clients)
-        new_params = self.server_update(pseudo_grad, lr)
+        with _telemetry.span(SERVER_UPDATE_SPAN, round=server_round):
+            pseudo_grad = [x - a for x, a in zip(self.current_parameters, avg)]
+            lr = self.effective_lr(n_clients)
+            new_params = self.server_update(pseudo_grad, lr)
 
-        metrics: dict[str, float] = {
-            N_CLIENTS: float(n_clients),
-            N_SAMPLES: float(n_total),
-            EFFECTIVE_LR: lr,
-        }
-        if self.telemetry:
-            metrics.update(self.norm_telemetry(pseudo_grad))
-        self.current_parameters = new_params
+            metrics: dict[str, float] = {
+                N_CLIENTS: float(n_clients),
+                N_SAMPLES: float(n_total),
+                EFFECTIVE_LR: lr,
+            }
+            if self.telemetry:
+                metrics.update(self.norm_telemetry(pseudo_grad))
+            self.current_parameters = new_params
         return metrics
 
     def aggregate_evaluate(

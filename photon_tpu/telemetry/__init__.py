@@ -20,22 +20,31 @@ seconds to phases, nodes, and rounds:
 
 Installation discipline matches ``photon_tpu.chaos``: hook sites read one
 module global and do nothing when it is ``None`` — with
-``photon.telemetry.enabled=false`` (the default) the whole plane costs a
-``None`` check per site, no rng, no locks, no I/O.
+``photon.telemetry.enabled=false`` (the default) the plane costs a ``None``
+check per site, no rng, no locks, no I/O. The one exception is
+:func:`span`: installed or not, a span is a ``jax.profiler.TraceAnnotation``
+and a timer, so that any profiler capture shows the program's phases on the
+device trace's clock and a History KPI can read the span's own seconds.
 """
 
 from __future__ import annotations
 
 import contextlib
 import pathlib
-from typing import Any, Iterator
+from typing import Any
 
 from photon_tpu.telemetry import introspect
 from photon_tpu.telemetry.events import EventLog, read_events_jsonl
 from photon_tpu.telemetry.health import HealthMonitor
 from photon_tpu.telemetry.introspect import ProfileController
 from photon_tpu.telemetry.metrics import MetricsHub
-from photon_tpu.telemetry.spans import Span, TraceContext, Tracer, new_id
+from photon_tpu.telemetry.spans import (
+    ProfilerSpan,
+    Span,
+    TraceContext,
+    Tracer,
+    new_id,
+)
 from photon_tpu.utils.profiling import SPANS_DROPPED
 
 __all__ = [
@@ -75,7 +84,7 @@ _HEALTH: HealthMonitor | None = None
 _PROFILER: ProfileController | None = None
 _AUTOPILOT = None  # telemetry.autopilot.Autopilot | None (lazy import)
 
-#: shared do-nothing context manager — the disabled-path ``span()`` return
+#: shared do-nothing context manager — the disabled-path ``attach()`` return
 #: value, allocated once so the hook sites stay allocation-free
 _NULL_CM = contextlib.nullcontext()
 
@@ -187,13 +196,17 @@ def autopilot_active():
 
 # -- hook-site helpers (each is a None check when disabled) ---------------
 
-def span(name: str, parent: TraceContext | None = None, **attrs: Any):
-    """Context manager: a span under the installed tracer, or a shared
-    no-op when telemetry is off."""
+def span(name: str, parent: TraceContext | None = None, push: bool = True,
+         **attrs: Any):
+    """Context manager around one phase: always a host event in any open
+    profiler session (``attrs`` as its stats), and a span under the
+    installed tracer when telemetry is on. ``with ... as sp`` yields an
+    object whose ``seconds`` is the window once closed. ``push=False``
+    (transport legs) keeps it from parenting the spans opened inside."""
     tr = _TRACER
     if tr is None:
-        return _NULL_CM
-    return tr.span(name, parent=parent, **attrs)
+        return ProfilerSpan(name, attrs)
+    return tr.span(name, parent=parent, push=push, **attrs)
 
 
 def current_context() -> TraceContext | None:
@@ -234,29 +247,6 @@ def ingest(spans: list[dict] | None = None,
     log = _EVENTS
     if log is not None and events:
         log.ingest(events)
-
-
-def timed_add(name: str, **attrs: Any):
-    """Measure a block and record it as a completed span WITHOUT pushing it
-    on the context stack (transport legs: children should not parent to
-    them). Returns the shared no-op context when disabled — a single None
-    check, no generator allocation on the hot path."""
-    tr = _TRACER
-    if tr is None:
-        return _NULL_CM
-    return _timed_add_cm(tr, name, attrs)
-
-
-@contextlib.contextmanager
-def _timed_add_cm(tr: Tracer, name: str, attrs: dict) -> Iterator[None]:
-    import time as _time
-
-    t_wall = _time.time()
-    t0 = _time.perf_counter()
-    try:
-        yield
-    finally:
-        tr.add_span(name, t_wall, _time.perf_counter() - t0, **attrs)
 
 
 # -- typed-metric hook helpers (each a single None check when disabled) ----

@@ -24,9 +24,17 @@ Model (a deliberately tiny subset of OpenTelemetry's):
   :meth:`ingest`\\ s them, so ONE process holds the merged per-run
   timeline.
 
-Timestamps: ``t_start`` is ``time.time()`` (wall epoch — the only clock
-processes on one host share well enough for a merged timeline);
-``duration_s`` is measured with ``time.perf_counter`` deltas.
+Two sinks, one call site. Every span is ALSO a
+``jax.profiler.TraceAnnotation``: with a profiler session open (the
+benchmark's traced run, ``profile_rounds``, ``/debug/profile``) it is a host
+event on the device trace's clock, on the line of the thread that ran it,
+its attrs as stats — whether a :class:`Tracer` is installed or not
+(:class:`ProfilerSpan` is the tracer-less form). With no session the
+annotation is the TraceMe's inactive path.
+
+Timestamps in the Tracer's buffer: ``t_start`` is ``time.time()`` (wall
+epoch — the only clock processes on one host share well enough for a merged
+timeline); ``duration_s`` is measured with ``time.perf_counter`` deltas.
 
 Span names reuse the KPI constants in ``utils/profiling.py``
 (``server/round_time``, ``client/fit_time``, ...) so the metrics plane and
@@ -42,6 +50,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation
 
 #: wire form of a span context: ``(trace_id, span_id)`` — small enough to
 #: ride every Envelope, stable under pickle across versions
@@ -89,6 +99,12 @@ class Span:
     # threads of one process (decode-ahead pool workers, the async
     # checkpoint writer) partially overlap — each thread gets its own row
     tid: int = 0
+
+    @property
+    def seconds(self) -> float:
+        """The measured window (what :class:`ProfilerSpan` yields too), so a
+        call site feeds its History KPI from the span's own timer."""
+        return self.duration_s
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -168,13 +184,15 @@ class Tracer:
 
     # -- spans -----------------------------------------------------------
     def span(self, name: str, parent: TraceContext | None = None,
-             **attrs: Any) -> "_OpenSpan":
+             push: bool = True, **attrs: Any) -> "_OpenSpan":
         """Context manager opening a span; ``with ... as sp`` yields the
         (mutable) :class:`Span` so callers can add attrs mid-flight.
         ``parent`` overrides the thread's context stack (used by background
-        threads that captured a context at enqueue time). A plain class CM
-        rather than a generator: span() sits on per-round hot paths and
-        the generator machinery roughly doubles its cost."""
+        threads that captured a context at enqueue time). ``push=False``
+        keeps the span off the context stack (transport legs: nothing should
+        parent to them). A plain class CM rather than a generator: span()
+        sits on per-round hot paths and the generator machinery roughly
+        doubles its cost."""
         ctx = parent if parent is not None else self.current_context()
         sp = Span(
             name=name,
@@ -187,13 +205,14 @@ class Tracer:
             attrs=attrs,  # **kwargs is already a fresh dict — no copy
             tid=threading.get_ident(),
         )
-        return _OpenSpan(self, sp)
+        return _OpenSpan(self, sp, push)
 
     def add_span(self, name: str, t_start: float, duration_s: float,
                  parent: TraceContext | None = None, **attrs: Any) -> Span:
-        """Record an already-measured window (transport legs, pool workers
-        — places where a context-manager around the hot path would be
-        noise). ``t_start`` is wall epoch seconds."""
+        """Record an already-measured window (the serve scheduler's request
+        phases, known only at request completion). Such a span reaches the
+        Tracer's buffer alone: the profiler sees live spans only.
+        ``t_start`` is wall epoch seconds."""
         ctx = parent if parent is not None else self.current_context()
         sp = Span(
             name=name,
@@ -270,20 +289,47 @@ class _OpenSpan:
     buffers the span on exit (including the exception path, so a failing
     phase still shows its true cost on the timeline)."""
 
-    __slots__ = ("_tracer", "span", "_t0")
+    __slots__ = ("_tracer", "span", "_push", "_t0", "_annotation")
 
-    def __init__(self, tracer: Tracer, span: Span) -> None:
+    def __init__(self, tracer: Tracer, span: Span, push: bool = True) -> None:
         self._tracer = tracer
         self.span = span
+        self._push = push
 
     def __enter__(self) -> Span:
         sp = self.span
-        self._tracer._stack().append((sp.trace_id, sp.span_id))
+        if self._push:
+            self._tracer._stack().append((sp.trace_id, sp.span_id))
+        self._annotation = TraceAnnotation(sp.name, **sp.attrs)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> None:
         sp = self.span
         sp.duration_s = time.perf_counter() - self._t0
-        self._tracer._stack().pop()
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self._push:
+            self._tracer._stack().pop()
         self._tracer._append(sp)
+
+
+class ProfilerSpan:
+    """A span with no :class:`Tracer` behind it: the profiler's annotation
+    and a timer. ``with ... as sp`` yields this object; ``sp.seconds`` is
+    the window once it has closed."""
+
+    __slots__ = ("_annotation", "_t0", "seconds")
+
+    def __init__(self, name: str, attrs: dict[str, Any]) -> None:
+        self._annotation = TraceAnnotation(name, **attrs)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "ProfilerSpan":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)

@@ -46,6 +46,11 @@ from photon_tpu.utils.profiling import (
     CLIENT_STEPS,
     CLIENT_TOKENS_PER_SEC,
     EVENT_SPEED_MONITOR_PEAK,
+    TRAINER_FENCE_SPAN,
+    TRAINER_GET_PARAMETERS_SPAN,
+    TRAINER_NEXT_BATCH_SPAN,
+    TRAINER_SET_PARAMETERS_SPAN,
+    TRAINER_STEPS_SPAN,
     SpeedMonitor,
 )
 
@@ -384,29 +389,39 @@ class Trainer:
         losses: list[float] = []
         last_metrics: dict[str, float] = {}
         tokens_seen = 0
+
+        def log(i: int, metrics: dict) -> None:
+            nonlocal last_metrics
+            last_metrics = {k: float(v) for k, v in metrics.items()}
+            losses.append(last_metrics["loss"])
+            if callback:
+                callback(i, last_metrics)
+
+        metrics: dict = {}
         try:
-            for i in range(duration_steps):
-                try:
-                    batch = next(it)
-                except StopIteration:
-                    raise ValueError(
-                        f"batch stream exhausted at step {i}/{duration_steps}"
-                    ) from None
-                tokens_seen += int(np.prod(batch.shape))
-                self.state, metrics = self._train_step(self.state, batch)
-                if (log_every and (i + 1) % log_every == 0) or i == duration_steps - 1:
-                    metrics = {k: float(v) for k, v in metrics.items()}
-                    losses.append(metrics["loss"])
-                    last_metrics = metrics
-                    if callback:
-                        callback(i, metrics)
+            with telemetry.span(TRAINER_STEPS_SPAN, steps=duration_steps):
+                for i in range(duration_steps):
+                    try:
+                        with telemetry.span(TRAINER_NEXT_BATCH_SPAN):
+                            batch = next(it)
+                    except StopIteration:
+                        raise ValueError(
+                            f"batch stream exhausted at step {i}/{duration_steps}"
+                        ) from None
+                    tokens_seen += int(np.prod(batch.shape))
+                    self.state, metrics = self._train_step(self.state, batch)
+                    if log_every and (i + 1) % log_every == 0 and i + 1 < duration_steps:
+                        log(i, metrics)
         finally:
             it.close()
         # the timed window closes on the WHOLE state plus the host fetch of
-        # the last step's loss above: dispatch is asynchronous and buffers
-        # become ready one by one, so blocking on .step alone would return
-        # before params/opt_state finish and wall-time would undercount
-        jax.block_until_ready(self.state)
+        # the last step's loss: dispatch is asynchronous and buffers become
+        # ready one by one, so blocking on .step alone would return before
+        # params/opt_state finish and wall-time would undercount
+        with telemetry.span(TRAINER_FENCE_SPAN):
+            jax.block_until_ready(self.state)
+            if duration_steps:
+                log(duration_steps - 1, metrics)
         dt = time.monotonic() - t0
         return {
             **last_metrics,
@@ -453,20 +468,23 @@ class Trainer:
         """Gather sharded params to host as the canonical flat list
         (reference: ``get_trainable_params_dict`` with summon_full_params,
         ``photon/utils.py:247-319`` — here XLA gathers, codec orders)."""
-        return params_to_ndarrays(self.state.params)
+        with telemetry.span(TRAINER_GET_PARAMETERS_SPAN):
+            return params_to_ndarrays(self.state.params)
 
     def set_parameters(self, metadata: ParamsMetadata, arrays: list[np.ndarray]) -> None:
         """Scatter a flat ndarray list into the sharded state (reference:
         ``set_trainer_params_from_ndarrays``, ``photon/utils.py:481-540``)."""
-        t0 = time.monotonic()
-        new_params = params_from_ndarrays(self.state.params, metadata, arrays)
-        new_params = jax.tree.map(
-            lambda leaf, sh: jax.device_put(np.asarray(leaf), sh),
-            new_params,
-            self._shardings.params,
-        )
-        self.state = self.state.replace(params=new_params)
-        self._last_set_time = time.monotonic() - t0
+        with telemetry.span(TRAINER_SET_PARAMETERS_SPAN,
+                            nbytes=metadata.total_bytes) as sp:
+            new_params = params_from_ndarrays(self.state.params, metadata, arrays)
+            new_params = jax.tree.map(
+                lambda leaf, sh: jax.device_put(np.asarray(leaf), sh),
+                new_params,
+                self._shardings.params,
+            )
+            self.state = self.state.replace(params=new_params)
+        # the span's own timer is the client/fit_set_parameters_time KPI
+        self._last_set_time = sp.seconds
 
     def get_opt_state_arrays(self) -> tuple[ParamsMetadata, list[np.ndarray]]:
         """Flatten optimizer state to the canonical (metadata, arrays) form —

@@ -45,6 +45,8 @@ EVAL_ROUND_FAILED = "server/eval_round_failed"
 # decomposition already carried by the spans)
 SAMPLE_CLIENTS_SPAN = "server/sample_clients"
 EVAL_ROUND_SPAN = "server/eval_round"
+#: Strategy.apply_average: pseudo-gradient, server rule, norms
+SERVER_UPDATE_SPAN = "server/update"
 # whole-unit umbrella spans: deliberately NOT the KPI names — the KPI
 # server/round_time is measured from fit_round entry (excludes broadcast/
 # eval/checkpoint) and client/fit_time is the train loop alone, while these
@@ -80,10 +82,6 @@ COLLECTIVE_UPDATE_TIME = "server/collective_update_time"
 #: ``collective_agg.modeled_cross_slice_bytes`` — the fp32-vs-q8 ratio is
 #: the number that matters, not the absolute)
 COLLECTIVE_WIRE_BYTES = "server/collective_wire_bytes"
-#: q8 encode+decode seconds, measured OUT-OF-LINE by ``bench.py
-#: --collective`` (inside the round the codec is fused into the exchange
-#: program and cannot be timed separately)
-COLLECTIVE_QUANT_TIME = "server/collective_quant_time"
 
 # -- elastic collective rounds (ISSUE 8, federation/collective_round.py) --
 #: clients missing from this round's surviving cohort (failed fits +
@@ -170,10 +168,28 @@ CLIENT_TRAIN_SPAN = "client/train"
 CLIENT_ENCODE_SPAN = "client/encode"
 CLIENT_PACKAGE_SPAN = "client/package"
 CLIENT_EVALUATE_SPAN = "client/evaluate"
+#: the pseudo-gradient difference and its two L2 norms, on the host
+CLIENT_PSEUDO_GRAD_NORM_SPAN = "client/pseudo_grad_norm_time"
+#: a node caching a round's broadcast (federation/node.py)
+NODE_SET_BROADCAST_SPAN = "node/set_broadcast"
+# -- trainer span names (train/trainer.py; spans only) --------------------
+#: flat host arrays -> the sharded device state, and back
+TRAINER_SET_PARAMETERS_SPAN = "trainer/set_parameters"
+TRAINER_GET_PARAMETERS_SPAN = "trainer/get_parameters"
+#: inside Trainer.fit: the dispatch loop, each wait on the prefetcher, and
+#: the closing block_until_ready
+TRAINER_STEPS_SPAN = "trainer/steps"
+TRAINER_NEXT_BATCH_SPAN = "trainer/next_batch"
+TRAINER_FENCE_SPAN = "trainer/fence"
 
 # -- transport-leg span names (federation/tcp.py; spans only, never KPIs) --
 TCP_SEND_SPAN = "tcp/send"
 TCP_RECV_SPAN = "tcp/recv"
+# -- parameter-plane span names (federation/transport.py): the plane write,
+# read and release alone, whoever calls; attrs mode / nbytes / wire_nbytes
+TRANSPORT_PUT_SPAN = "transport/put"
+TRANSPORT_GET_SPAN = "transport/get"
+TRANSPORT_FREE_SPAN = "transport/free"
 
 # -- serving plane (photon_tpu/serve, ISSUE 5) ----------------------------
 # KPIs the continuous batcher records into its own History (exported via
@@ -433,7 +449,6 @@ COLLECTIVE_STRAGGLER_FRAC = "server/collective_straggler_frac"
 # -- structured alert kinds (telemetry/health.py, ISSUE 10) ---------------
 # Health watchers emit these as events (same registry discipline) AND
 # record them on the monitor's alert tail rolled up into /statusz.
-ALERT_EVENT_PREFIX = "alert/"
 #: NaN/Inf in the round's aggregated KPI dict (delta norm, server loss)
 ALERT_NONFINITE = "alert/nonfinite"
 #: straggler-percentile watcher over the collective cohort
@@ -542,7 +557,7 @@ class WireStats:
     def snapshot(self) -> "WireStats":
         return dataclasses.replace(self)
 
-    def metrics_since(self, prev: "WireStats", prefix: str = "server/") -> dict[str, float]:
+    def metrics_since(self, prev: "WireStats") -> dict[str, float]:
         """Round-delta metrics (recorded into History by the round loop):
         uplink raw/wire bytes + compression ratio, downlink (broadcast)
         wire bytes."""
@@ -550,12 +565,12 @@ class WireStats:
         up_wire = self.recv_wire_bytes - prev.recv_wire_bytes
         down_wire = self.sent_wire_bytes - prev.sent_wire_bytes
         out = {
-            f"{prefix}wire_uplink_raw_bytes": float(up_raw),
-            f"{prefix}wire_uplink_bytes": float(up_wire),
-            f"{prefix}wire_broadcast_bytes": float(down_wire),
+            WIRE_UPLINK_RAW_BYTES: float(up_raw),
+            WIRE_UPLINK_BYTES: float(up_wire),
+            WIRE_BROADCAST_BYTES: float(down_wire),
         }
         if up_wire > 0:
-            out[f"{prefix}wire_compression_ratio"] = up_raw / up_wire
+            out[WIRE_COMPRESSION_RATIO] = up_raw / up_wire
         return out
 
 TPU_V5E_PEAK_FLOPS = 197e12  # bf16

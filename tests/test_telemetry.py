@@ -469,6 +469,53 @@ def test_registry_constants_are_unique():
     assert len(names) == len(set(names)), "duplicate KPI constants"
 
 
+def _registry_constants() -> dict[str, str]:
+    """``IDENT -> "plane/name"`` of every name constant in the registry."""
+    import ast
+
+    from photon_tpu.utils import profiling
+
+    out = {}
+    for node in ast.parse(pathlib.Path(profiling.__file__).read_text()).body:
+        if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                and isinstance(node.targets[0], ast.Name)
+                and isinstance(node.value, ast.Constant)
+                and isinstance(node.value.value, str) and "/" in node.value.value):
+            out[node.targets[0].id] = node.value.value
+    return out
+
+
+def test_every_registry_name_is_used_by_the_program():
+    """A name nothing produces is deleted with its hook (ISSUE 27): every
+    constant is referenced somewhere in ``photon_tpu/`` beyond its own
+    definition."""
+    import re
+
+    root = pathlib.Path(telemetry.__file__).resolve().parents[1]
+    text = "\n".join(p.read_text() for p in root.rglob("*.py"))
+    unused = [ident for ident in _registry_constants()
+              if len(re.findall(rf"\b{ident}\b", text)) < 2]
+    assert not unused, f"registry names nothing in the program uses: {unused}"
+
+
+def test_registry_audit_table_matches_the_registry():
+    """``docs/observability.md`` has one row per registry name — producer
+    and reader — and no row for a name that is gone."""
+    import re
+
+    doc = pathlib.Path(telemetry.__file__).resolve().parents[2] / "docs" / "observability.md"
+    audit = doc.read_text().split("## Registry audit", 1)[1].split("\n## ", 1)[0]
+    rows = {}
+    for line in audit.splitlines():
+        m = re.match(r"\| `([^`]+)` \| ([^|]+) \| ([^|]+) \| ([^|]+) \|$", line)
+        if m:
+            rows[m.group(1)] = m.groups()[1:]
+    names = set(_registry_constants().values())
+    assert set(rows) == names, (sorted(names - set(rows)), sorted(set(rows) - names))
+    for name, (kind, producer, reader) in rows.items():
+        assert kind.strip() and "`" in producer and reader.strip(), name
+
+
 def test_registry_covers_serve_names():
     """The serving plane's KPI vocabulary (ISSUE 5 satellite) is declared
     in the same registry as the training plane's."""

@@ -16,6 +16,7 @@ back).
 
 import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +104,15 @@ def test_flash_attention_compiles(one_chip, h, h_kv, d, block, grad):
     hlo = _hlo(fn, q, kv, kv)
     # fwd is one kernel; bwd re-runs it and adds the dq and dk/dv kernels
     assert hlo.count(KERNEL) >= (3 if grad else 1)
+    # each launch keeps the instruction name the benchmark's
+    # flash_attention_roofline anchors on, and carries its own kernel's name
+    # in the op_name (what flash_fwd_ms_train / flash_bwd_ms_train find)
+    launches = [ln.strip() for ln in hlo.splitlines() if KERNEL in ln]
+    assert all(ln.startswith("%multihead_attention") or
+               ln.startswith("ROOT %multihead_attention") for ln in launches)
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv") if grad else ("flash_fwd",):
+        assert any(re.search(rf"\b{kernel}/multihead_attention\b", ln)
+                   for ln in launches), kernel
 
 
 def test_flash_attention_with_lse_compiles(one_chip):
