@@ -299,6 +299,7 @@ def tpu_convergence_slice(dev) -> dict | None:
         toks = _corpus_tokens()
         cfg = Config()
         cfg.model.attn_impl = "pallas"
+        # nothing reads flash_block_q/k since PR 28 (ops/flash_attention.pick_tiles derives the tile): this sets a dead field (ROADMAP S3)
         blk = int(os.environ.get("PHOTON_BENCH_FLASH_BLOCK", "0"))
         if blk:
             cfg.model.flash_block_q = blk
@@ -2729,6 +2730,7 @@ def run() -> None:
         # "0"/garbage is NOT a disable switch (that's PHOTON_BENCH_NO_CHUNK):
         # treat it as no-pin so the trial default stays active
         pin_chunk = ""
+    # nothing reads flash_block_q/k since PR 28 (ops/flash_attention.pick_tiles derives the tile): this sets a dead field (ROADMAP S3)
     tuned_block = int(pin("PHOTON_BENCH_FLASH_BLOCK", "flash_block") or 0)
     if tuned_block:
         cfg.model.flash_block_q = tuned_block
@@ -2902,6 +2904,7 @@ def run() -> None:
     # When the tuned config already pins a measured-winner tile, default the
     # trial OFF (256→512→1024 was measured on-chip, July 2026;
     # 2048 is compile-rejected: scoped-vmem 23M > 16M)
+    # nothing reads flash_block_q/k since PR 28 (ops/flash_attention.pick_tiles derives the tile): this sets a dead field (ROADMAP S3)
     block = int(os.environ.get("PHOTON_BENCH_TRY_BLOCK",
                                "0" if tuned_block else "512"))
     if block and cfg.model.attn_impl == "pallas" \
@@ -2937,6 +2940,7 @@ def run() -> None:
     # config; 0 or "" disables.
     # default off when an asymmetric k pin exists (a measured winner or
     # loser is already encoded in bench_tuned.json, like TRY_BLOCK/TRY_CHUNK)
+    # nothing reads flash_block_q/k since PR 28 (ops/flash_attention.pick_tiles derives the tile): this sets a dead field (ROADMAP S3)
     qk = os.environ.get("PHOTON_BENCH_TRY_BLOCK_QK",
                         "0" if tuned_block_k else "2048,1024")
     if qk and qk != "0" and cfg.model.attn_impl == "pallas":

@@ -93,9 +93,12 @@ class ModelConfig:
     emb_init_std: float = 0.02
     resid_pdrop: float = 0.0
     remat: bool = False  # activation checkpointing (reference: fsdp_config.activation_checkpointing)
-    # Pallas flash-attention tile sizes (PERF.md lever 2: block sweep at seq
-    # 2048). Config-tunable so a chip session can sweep without code edits;
-    # ignored by the xla fallback.
+    # Nothing reads these two since PR 28: the flash kernel derives its tiles
+    # from the shapes it is handed (ops/flash_attention.pick_tiles). They keep
+    # their name and their 256 only because benchmark/program.py holds this
+    # preset to every key of benchmark/configs/mpt-125m.json's `model` block,
+    # which states both, and only a `benchmark` issue may edit that file:
+    # once it drops the two keys, delete the fields (ROADMAP S3).
     flash_block_q: int = 256
     flash_block_k: int = 256
     # Run pallas kernels in the Pallas interpreter (CPU-executable). Test /

@@ -211,7 +211,6 @@ def prefill(params: dict, tokens: jax.Array, lengths: jax.Array,
             q, k, v,
             impl=cfg.attn_impl if cfg.attn_impl != "ring" else "xla",
             causal=True, alibi=cfg.alibi,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
         )
         x = x + _dense(lp, "out_proj", attn.reshape(b, s, cfg.d_model),
                        la, lora_scale)

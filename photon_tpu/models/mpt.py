@@ -201,7 +201,6 @@ class MPTBlock(nn.Module):
         attn_out = multihead_attention(
             q, k, v,
             impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
             interpret=cfg.attn_interpret,
         )
         attn_out = attn_out.reshape(b, s, cfg.d_model)

@@ -128,16 +128,11 @@ def multihead_attention(
                                   impl=inner, alibi=alibi)
         impl = inner
     if impl == "pallas":
-        from photon_tpu.ops.flash_attention import (
-            DEFAULT_BLOCK_K,
-            DEFAULT_BLOCK_Q,
-            flash_attention,
-            pallas_supported,
-        )
+        from photon_tpu.ops.flash_attention import flash_attention, pallas_supported
 
         if interpret or pallas_supported(q):
-            bq = block_q or DEFAULT_BLOCK_Q
-            bk = block_k or DEFAULT_BLOCK_K
+            # block_q / block_k None: the kernel derives its tiles from the
+            # shapes it is handed (under shard_map: each shard's local ones)
 
             # Mosaic kernels cannot be auto-partitioned by GSPMD: on a
             # multi-device mesh the pallas call must be wrapped in
@@ -155,7 +150,7 @@ def multihead_attention(
                             if mesh is not None and mesh.shape.get(a, 1) > 1]
             if not sharded_axes:
                 return flash_attention(q, k, v, causal=causal, alibi=alibi,
-                                       block_q=bq, block_k=bk,
+                                       block_q=block_q, block_k=block_k,
                                        interpret=interpret)
             if h_kv % mesh.shape.get("tensor", 1):
                 # kv heads don't split over the tensor axis — replicate up
@@ -177,7 +172,7 @@ def multihead_attention(
                     sl = jax.lax.dynamic_slice(global_slopes, (start,), (h_loc,))
                 return flash_attention(q_s, k_s, v_s, causal=causal,
                                        alibi=alibi, alibi_slopes=sl,
-                                       block_q=bq, block_k=bk,
+                                       block_q=block_q, block_k=block_k,
                                        interpret=interpret)
 
             spec = P(("data", "fsdp", "expert"), None, "tensor", None)

@@ -55,6 +55,23 @@ from photon_tpu.utils.profiling import (
 )
 
 
+def _flash_tile_attrs(model_cfg) -> dict[str, str]:
+    """The tiles the flash kernel takes in this trainer's compiled step, and
+    how many of its grid steps are live, as span attributes: static per
+    shape, so they are told here, where the shapes are known, once for the
+    step and not per launch. Empty unless the step holds the kernel."""
+    from photon_tpu.ops.flash_attention import lane_padded, pallas_supported, pick_tiles
+
+    if model_cfg.attn_impl != "pallas" or not (
+            model_cfg.attn_interpret or pallas_supported(None)):
+        return {}
+    s = model_cfg.max_seq_len
+    return pick_tiles(
+        s, s, lane_padded(model_cfg.d_head), jnp.dtype(model_cfg.compute_dtype).itemsize,
+        model_cfg.n_heads // (model_cfg.n_kv_heads or model_cfg.n_heads),
+    ).attrs()
+
+
 def _set_opt_count(opt_state: Any, step: int) -> Any:
     """Return ``opt_state`` with every ``count`` field (optax's step counter
     in AdoptState / ScaleByAdamState / ...) set to ``step``."""
@@ -116,6 +133,7 @@ class Trainer:
             mesh = make_mesh(mesh_cfg, devices=jax.local_devices())
 
         self.model = MPTModel(effective_model_config(cfg.model, mesh_cfg))
+        self._kernel_attrs = _flash_tile_attrs(self.model.cfg)
         self.tx, self.lr_schedule = build_optimizer(cfg.optimizer, cfg.scheduler)
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)
 
@@ -399,7 +417,8 @@ class Trainer:
 
         metrics: dict = {}
         try:
-            with telemetry.span(TRAINER_STEPS_SPAN, steps=duration_steps):
+            with telemetry.span(TRAINER_STEPS_SPAN, steps=duration_steps,
+                                **self._kernel_attrs):
                 for i in range(duration_steps):
                     try:
                         with telemetry.span(TRAINER_NEXT_BATCH_SPAN):

@@ -110,6 +110,7 @@ def main() -> int:
     cfg = load_preset(args.preset) if args.preset else Config()
     cfg.model.attn_impl = args.impl
     cfg.model.remat = args.remat
+    # nothing reads flash_block_q/k since PR 28 (ops/flash_attention.pick_tiles derives the tile): this sets a dead field (ROADMAP S3)
     if args.block:
         cfg.model.flash_block_q = args.block
         cfg.model.flash_block_k = args.block

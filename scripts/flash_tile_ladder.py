@@ -1,0 +1,195 @@
+"""The flash kernel alone, on the chip, over a ladder of tiles: what
+``ops/flash_attention.pick_tiles`` is held to (PERF.md has the readings).
+
+Each of the three launches (forward, dq, dk/dv) is timed by itself at each
+tile, in the kernels' own ``[batch*heads, seq, d_pad]`` layout, bf16, causal:
+``--calls`` launches are queued back to back and waited for once, ``--rounds``
+times, and the median round is kept. With ``--no-clamp`` the dead-tile index
+clamps are replaced by the identity maps (every grid step fetches its own
+block, as before PR 28), which is how the clamp's share is read. With
+``--parent FILE`` (a copy of an older ``flash_attention.py``) outputs and
+gradients at fixed explicit tiles are compared bit for bit with that file's.
+
+    chiprun -- python scripts/flash_tile_ladder.py --out chiprun_out/ladder
+
+Needs the chip (``--interpret`` runs the bit-identity part alone on the CPU).
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import pathlib
+import statistics
+import sys
+import time
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+SHAPES = "4,12,2048,64;4,16,2048,128"
+TILES = ("256x256,512x512,1024x1024,512x1024,1024x512,2048x1024,1024x2048,"
+         "2048x512,512x2048,2048x2048")
+
+
+def _time(fn, args, calls: int, rounds: int) -> float:
+    """Median milliseconds of one launch."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    per_call = []
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        per_call.append((time.perf_counter() - t0) / calls * 1e3)
+    return statistics.median(per_call)
+
+
+def ladder(fa, shapes, tiles, calls, rounds, alibi):
+    import jax
+    import jax.numpy as jnp
+
+    rows = []
+    for b, h, s, d in shapes:
+        d_pad = fa.lane_padded(d)
+        bh = b * h
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, k, v, do = (jnp.pad(jax.random.normal(kk, (bh, s, d), jnp.bfloat16),
+                               ((0, 0), (0, 0), (0, d_pad - d))) for kk in keys)
+        slopes = None
+        if alibi:
+            from photon_tpu.ops.attention import alibi_slopes
+
+            slopes = fa._bh_slopes(alibi_slopes(h), bh)
+        scale = 1.0 / d**0.5
+        o, lse = jax.jit(lambda q, k, v: fa._fwd(
+            q, k, v, scale=scale, causal=True, block_q=256, block_k=256,
+            slopes=slopes))(q, k, v)
+        plan = fa.pick_tiles(s, s, d_pad, 2)
+        # what every dq / dkv reading holds besides its kernel: _bwd's delta
+        # and the sublane-replicated lse / delta, the same at every tile
+        prologue = _time(jax.jit(lambda o, lse, do: (
+            jnp.broadcast_to(jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                                     axis=-1)[:, None, :], (bh, fa.SUBLANE, s)),
+            jnp.broadcast_to(lse[:, None, :], (bh, fa.SUBLANE, s)))),
+            (o, lse, do), calls, rounds)
+        print(json.dumps({"shape": [b, h, s, d], "bwd_prologue_ms": prologue}), flush=True)
+        rows.append({"shape": [b, h, s, d], "bwd_prologue_ms": prologue})
+        for bq, bk in tiles:
+            if s % bq or s % bk:
+                continue
+            row = {"shape": [b, h, s, d], "tile": [bq, bk], "picked": [
+                name for name, t in zip(("fwd", "dq", "dkv"), plan)
+                if (t.block_q, t.block_k) == (bq, bk)]}
+            launches = {
+                "fwd": (lambda q, k, v, o, lse, do: fa._fwd(
+                    q, k, v, scale=scale, causal=True, block_q=bq, block_k=bk,
+                    slopes=slopes)[0]),
+                "dq": (lambda q, k, v, o, lse, do: fa._bwd(
+                    scale, True, (bq, bk), (bq, bk), (q, k, v, o, lse), do,
+                    slopes=slopes)[0]),
+                "dkv": (lambda q, k, v, o, lse, do: fa._bwd(
+                    scale, True, (bq, bk), (bq, bk), (q, k, v, o, lse), do,
+                    slopes=slopes)[1:]),
+            }
+            for name, fn in launches.items():
+                try:
+                    row[name + "_ms"] = _time(jax.jit(fn), (q, k, v, o, lse, do),
+                                              calls, rounds)
+                except Exception as e:  # the compiler refusing a tile is a reading
+                    row[name + "_ms"] = None
+                    row[name + "_error"] = str(e).strip().splitlines()[-1][:200]
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
+
+
+def bit_identity(fa, parent_file, interpret):
+    """Outputs and all three gradients at fixed explicit tiles, this tree's
+    kernel against ``parent_file``'s: the number of arrays that differ."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    spec = importlib.util.spec_from_file_location("parent_flash_attention", parent_file)
+    parent = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(parent)
+    s = 512 if interpret else 2048
+    cases = [  # (h, h_kv, d, s_q, alibi, tile)
+        (4, 4, 64, s, False, 128 if interpret else 256),
+        (4, 4, 64, s, True, 256 if interpret else 512),
+        (4, 2, 128, s, False, 128 if interpret else 512),
+        (4, 4, 64, s // 2, False, 128 if interpret else 256),  # s_q != s_k
+    ]
+    rows = []
+    for h, h_kv, d, s_q, alibi, tile in cases:
+        dtype = jnp.float32 if interpret else jnp.bfloat16
+        keys = jax.random.split(jax.random.PRNGKey(d + s_q + h_kv), 4)
+        q = jax.random.normal(keys[0], (2, s_q, h, d), dtype)
+        k = jax.random.normal(keys[1], (2, s, h_kv, d), dtype)
+        v = jax.random.normal(keys[2], (2, s, h_kv, d), dtype)
+        w = jax.random.normal(keys[3], (2, s_q, h, d), jnp.float32)
+
+        def both(mod):
+            def f(q, k, v):
+                return mod.flash_attention(q, k, v, causal=True, alibi=alibi,
+                                           block_q=tile, block_k=tile,
+                                           interpret=interpret)
+
+            o = jax.jit(f)(q, k, v)
+            g = jax.jit(jax.grad(lambda q, k, v: (f(q, k, v).astype(jnp.float32) * w).sum(),
+                                 argnums=(0, 1, 2)))(q, k, v)
+            return [np.asarray(x.astype(jnp.float32)) for x in (o, *g)]
+
+        differ = sum(not np.array_equal(a, b) for a, b in zip(both(fa), both(parent)))
+        row = {"bit_identity": [h, h_kv, d, s_q, s, alibi, tile], "arrays_differ": differ}
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    return rows
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default=SHAPES, help="b,h,s,d;...")
+    ap.add_argument("--tiles", default=TILES, help="QxK,...")
+    ap.add_argument("--calls", type=int, default=40)
+    ap.add_argument("--rounds", type=int, default=5)
+    ap.add_argument("--alibi", action="store_true")
+    ap.add_argument("--no-clamp", action="store_true")
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--interpret", action="store_true")
+    ap.add_argument("--out", default=None)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from photon_tpu.ops import flash_attention as fa
+
+    if not args.interpret and jax.default_backend() != "tpu":
+        print("flash_tile_ladder: no TPU here; a CPU time is not a reading", file=sys.stderr)
+        return 2
+    if args.no_clamp:
+        fa._kv_block = lambda i, j, **kw: j
+        fa._q_block = lambda i, j, **kw: i
+    result = {"device": jax.devices()[0].device_kind, "jax": jax.__version__,
+              "clamp": not args.no_clamp, "alibi": args.alibi}
+    if args.parent:
+        result["bit_identity"] = bit_identity(fa, args.parent, args.interpret)
+    if not args.interpret:
+        shapes = [tuple(int(x) for x in sh.split(",")) for sh in args.shapes.split(";")]
+        tiles = [tuple(int(x) for x in t.split("x")) for t in args.tiles.split(",")]
+        result["ladder"] = ladder(fa, shapes, tiles, args.calls, args.rounds, args.alibi)
+    if args.out:
+        out = pathlib.Path(args.out)
+        out.mkdir(parents=True, exist_ok=True)
+        name = "ladder" + ("_noclamp" if args.no_clamp else "") + ".json"
+        (out / name).write_text(json.dumps(result, indent=1))
+    return 1 if any(r["arrays_differ"] for r in result.get("bit_identity", [])) else 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -218,3 +218,108 @@ def test_lse_path_gqa_parity():
     for a, ref in zip(gk, gx):
         assert a.shape == ref.shape
         assert _rel(a, ref) < 2e-4
+
+
+# ---------------------------------------------------------------------------
+# clamped index maps (PR 28): dead causal tiles repeat a live block's index
+# ---------------------------------------------------------------------------
+
+
+def _clamp_case(case):
+    """q, k, v and the oracle's kv for one case at seq 512, tile 128: a 4 x 4
+    grid with 6 dead tiles whose fetches the clamped maps drop."""
+    s_k = 512
+    s_q = 256 if case == "s_q!=s_k" else 512
+    h_kv = H // 2 if case == "gqa2" else H
+    ks = jax.random.split(jax.random.PRNGKey(31), 4)
+    q = jax.random.normal(ks[0], (B, s_q, H, D))
+    k = jax.random.normal(ks[1], (B, s_k, h_kv, D))
+    v = jax.random.normal(ks[2], (B, s_k, h_kv, D))
+    w = jax.random.normal(ks[3], (B, s_q, H, D))
+    rep = (lambda x: jnp.repeat(x, H // h_kv, axis=2)) if h_kv != H else (lambda x: x)
+    return q, k, v, w, rep, case == "alibi"
+
+
+@pytest.mark.parametrize("case", ["plain", "alibi", "gqa2", "s_q!=s_k"])
+def test_clamped_maps_forward_and_lse(case):
+    q, k, v, _, rep, alibi = _clamp_case(case)
+    o_k = flash_attention(q, k, v, causal=True, alibi=alibi,
+                          block_q=128, block_k=128, interpret=True)
+    o_x = xla_attention(q, rep(k), rep(v), causal=True, alibi=alibi)
+    assert _rel(o_k, o_x) < 2e-5, case
+    if not alibi:  # the lse variant takes no bias
+        s_q, s_k = q.shape[1], k.shape[1]
+        _, lse_k = flash_attention_with_lse(
+            q, k, v, causal=True, q_start=s_k - s_q, k_start=0,
+            block_q=128, block_k=128, interpret=True)
+        _, lse_x = xla_chunk_attention(q, rep(k), rep(v), q_start=s_k - s_q,
+                                       k_start=0, causal=True)
+        assert _rel(lse_k, lse_x) < 2e-5, case
+
+
+@pytest.mark.parametrize("case", ["plain", "alibi", "gqa2", "s_q!=s_k"])
+def test_clamped_maps_gradients(case):
+    q, k, v, w, rep, alibi = _clamp_case(case)
+    gk = jax.grad(
+        lambda q, k, v: (flash_attention(
+            q, k, v, causal=True, alibi=alibi, block_q=128, block_k=128,
+            interpret=True) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    gx = jax.grad(
+        lambda q, k, v: (xla_attention(
+            q, rep(k), rep(v), causal=True, alibi=alibi) * w).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    for name, a, ref in zip(("dq", "dk", "dv"), gk, gx):
+        assert a.shape == ref.shape
+        assert _rel(a, ref) < 2e-4, (case, name)
+
+
+def test_asymmetric_tiles_parity():
+    """The three launches may run different, non-square tiles: forward and
+    gradients at q 256 x k 128 and q 128 x k 256 on seq 512."""
+    q, k, v, w, _, _ = _clamp_case("plain")
+    gx = jax.grad(lambda q, k, v: (xla_attention(q, k, v, causal=True) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for bq, bk in ((256, 128), (128, 256)):
+        gk = jax.grad(
+            lambda q, k, v: (flash_attention(
+                q, k, v, causal=True, block_q=bq, block_k=bk,
+                interpret=True) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+        for a, ref in zip(gk, gx):
+            assert _rel(a, ref) < 2e-4, (bq, bk)
+
+
+def test_derived_tiles_parity():
+    """``block_q=None`` (what the models pass): the tiles come from
+    ``pick_tiles`` and the result is the oracle's."""
+    from photon_tpu.ops.flash_attention import LANE, pick_tiles
+
+    q, k, v, w, _, _ = _clamp_case("plain")
+    assert pick_tiles(512, 512, LANE, 4).blocks == ((512, 512),) * 3
+    o_k = flash_attention(q, k, v, causal=True, interpret=True)
+    assert _rel(o_k, xla_attention(q, k, v, causal=True)) < 2e-5
+    gk = jax.grad(lambda q, k, v: (flash_attention(
+        q, k, v, causal=True, interpret=True) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    gx = jax.grad(lambda q, k, v: (xla_attention(q, k, v, causal=True) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, ref in zip(gk, gx):
+        assert _rel(a, ref) < 2e-4
+
+
+def test_non_causal_call_keeps_the_identity_maps():
+    """Without a mask every tile is live: the clamped maps hand every grid
+    index back untouched, and the 4 x 4 grid gives the oracle's output and
+    gradients."""
+    from photon_tpu.ops.flash_attention import _kv_block, _q_block
+
+    kw = dict(causal=False, block_q=128, block_k=128, offset=0)
+    assert all(_kv_block(i, j, n_k=4, **kw) == j and _q_block(i, j, n_q=4, **kw) == i
+               for i in range(4) for j in range(4))
+    q, k, v, w, _, _ = _clamp_case("plain")
+    gk = jax.grad(
+        lambda q, k, v: (flash_attention(
+            q, k, v, causal=False, block_q=128, block_k=128,
+            interpret=True) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    gx = jax.grad(lambda q, k, v: (xla_attention(q, k, v, causal=False) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, ref in zip(gk, gx):
+        assert _rel(a, ref) < 2e-4

@@ -1,0 +1,225 @@
+"""``pick_tiles``: the flash kernel's tile is a function of the shapes.
+
+For every shape the program hands the kernel (training at 2,048, the serve
+prefill buckets 16 * 2^k, a short query block against a long key sequence,
+grouped-query attention) the rule returns tiles that divide both sequences,
+sit inside the rule's own VMEM estimate, grow with the budget and never
+shrink, and report live / grid tile counts equal to a brute-force count of
+the kernels' ``live`` predicate. CPU only: nothing here runs a kernel.
+"""
+
+import jax.numpy as jnp
+import pytest
+
+from photon_tpu.ops import flash_attention as fa
+from photon_tpu.ops.flash_attention import (
+    VMEM_BUDGET,
+    _kv_block,
+    _q_block,
+    launch_vmem_bytes,
+    live_tiles,
+    pick_tiles,
+)
+
+LAUNCHES = ("fwd", "dq", "dkv")
+
+# (s_q, s_k, d_pad, itemsize, n_kv_group)
+SHAPES = [
+    pytest.param(2048, 2048, 128, 2, 1, id="125m-d64-padded"),
+    pytest.param(2048, 2048, 128, 4, 1, id="1b-d128-fp32"),
+    pytest.param(2048, 2048, 256, 2, 1, id="d256"),
+    pytest.param(512, 512, 128, 2, 1, id="s512"),
+    pytest.param(48, 48, 128, 2, 1, id="s48"),
+    *[pytest.param(16 * 2**k, 16 * 2**k, 128, 2, 1, id=f"bucket{16 * 2**k}")
+      for k in range(8)],
+    pytest.param(1, 2048, 128, 2, 1, id="1x2048"),
+    pytest.param(256, 2048, 128, 2, 1, id="256x2048"),
+    pytest.param(2048, 2048, 128, 2, 4, id="gqa4"),
+    pytest.param(1536, 1536, 128, 2, 1, id="s1536"),
+    pytest.param(8192, 8192, 128, 2, 1, id="s8192"),
+]
+
+
+def _brute_live(s_q, s_k, bq, bk, offset):
+    """The kernels' predicate, tile by tile."""
+    return sum(j * bk <= i * bq + (bq - 1) + offset
+               for i in range(s_q // bq) for j in range(s_k // bk))
+
+
+@pytest.mark.parametrize("s_q,s_k,d_pad,itemsize,group", SHAPES)
+def test_pick_tiles_divides_fits_and_counts(s_q, s_k, d_pad, itemsize, group):
+    plan = pick_tiles(s_q, s_k, d_pad, itemsize, group)
+    assert plan._fields == LAUNCHES
+    for launch, t in zip(LAUNCHES, plan):
+        assert s_q % t.block_q == 0 and s_k % t.block_k == 0, (launch, t)
+        # a block is whole lane widths, or the whole axis
+        assert t.block_q % fa.LANE == 0 or t.block_q == s_q
+        assert t.block_k % fa.LANE == 0 or t.block_k == s_k
+        assert t.vmem_bytes == launch_vmem_bytes(launch, t.block_q, t.block_k,
+                                                 d_pad, itemsize)
+        assert t.vmem_bytes <= VMEM_BUDGET, (launch, t)
+        mult = group if launch == "dkv" else 1
+        assert t.grid_tiles == mult * (s_q // t.block_q) * (s_k // t.block_k)
+        assert t.live_tiles == mult * _brute_live(s_q, s_k, t.block_q, t.block_k,
+                                                  s_k - s_q)
+        assert 0 < t.live_tiles <= t.grid_tiles
+    assert plan.blocks == tuple((t.block_q, t.block_k) for t in plan)
+    attrs = plan.attrs()
+    assert set(attrs) == {"flash_tiles", "flash_live_tiles"}
+    assert f"fwd={plan.fwd.block_q}x{plan.fwd.block_k}" in attrs["flash_tiles"]
+
+
+@pytest.mark.parametrize("s_q,s_k,d_pad,itemsize,group", SHAPES)
+def test_pick_tiles_monotone_in_budget(s_q, s_k, d_pad, itemsize, group):
+    """More VMEM never buys a smaller tile, and an answer always comes, also
+    from a budget nothing fits."""
+    budgets = [2**18, 2**20, 2**21, 2**22, 2**23, 2**24, 2**25, 2**26, 2**27]
+    area = {launch: 0 for launch in LAUNCHES}
+    for budget in budgets:
+        plan = pick_tiles(s_q, s_k, d_pad, itemsize, group, vmem_budget=budget)
+        for launch, t in zip(LAUNCHES, plan):
+            assert s_q % t.block_q == 0 and s_k % t.block_k == 0
+            fits = t.vmem_bytes <= budget
+            if fits:
+                assert t.block_q * t.block_k >= area[launch], (launch, budget)
+                area[launch] = t.block_q * t.block_k
+
+
+def test_pick_tiles_leaves_the_old_default():
+    """The training shape of both presets: no launch stays at 256 x 256."""
+    for t in pick_tiles(2048, 2048, 128, 2):
+        assert t.block_q * t.block_k > 256 * 256
+        assert t.live_tiles / t.grid_tiles > 36 / 64
+
+
+@pytest.mark.parametrize("pin_q,pin_k", [(256, None), (None, 512), (128, 128), (4096, 4096)])
+def test_explicit_tile_wins(pin_q, pin_k):
+    plan = pick_tiles(2048, 2048, 128, 2, block_q=pin_q, block_k=pin_k)
+    free = pick_tiles(2048, 2048, 128, 2)
+    for t, f in zip(plan, free):
+        assert t.block_q == (min(pin_q, 2048) if pin_q else f.block_q)
+        assert t.block_k == (min(pin_k, 2048) if pin_k else f.block_k)
+
+
+def test_explicit_tile_must_divide():
+    with pytest.raises(ValueError, match="must divide"):
+        pick_tiles(2048, 2048, 128, 2, block_q=768)
+    with pytest.raises(ValueError, match="must divide"):
+        pick_tiles(512, 2048, 128, 2, block_k=384)
+
+
+@pytest.mark.parametrize("launch", LAUNCHES)
+def test_vmem_estimate_grows_with_the_tile(launch):
+    sizes = [128, 256, 512, 1024, 2048]
+    for d, itemsize in ((128, 2), (128, 4), (256, 2)):
+        square = [launch_vmem_bytes(launch, b, b, d, itemsize) for b in sizes]
+        assert square == sorted(square) and len(set(square)) == len(square)
+        assert launch_vmem_bytes(launch, 512, 1024, d, itemsize) > square[2]
+        assert launch_vmem_bytes(launch, 1024, 512, d, itemsize) > square[2]
+    with pytest.raises(ValueError):
+        launch_vmem_bytes("dv", 128, 128, 128, 2)
+
+
+@pytest.mark.parametrize("s_q,s_k,bq,bk", [
+    (2048, 2048, 256, 256), (2048, 2048, 512, 512), (2048, 2048, 1024, 1024),
+    (2048, 2048, 1024, 512), (2048, 2048, 512, 1024), (512, 512, 128, 128),
+    (256, 2048, 128, 256), (2048, 512, 256, 128),
+])
+def test_clamped_maps_fetch_live_blocks_only(s_q, s_k, bq, bk):
+    """Every live step maps to its own block; every dead step repeats the
+    block of the step before it in the sweep (so no copy is issued); and the
+    old defaults' counts are what the issue quotes."""
+    offset = s_k - s_q
+    n_q, n_k = s_q // bq, s_k // bk
+    kw = dict(causal=True, block_q=bq, block_k=bk, offset=offset)
+    live = lambda i, j: j * bk <= i * bq + (bq - 1) + offset  # noqa: E731
+    for i in range(n_q):
+        row = [int(_kv_block(i, j, n_k=n_k, **kw)) for j in range(n_k)]
+        for j in range(n_k):
+            if live(i, j):
+                assert row[j] == j
+            elif j:
+                assert row[j] == row[j - 1]
+            assert 0 <= row[j] < n_k
+    for j in range(n_k):
+        col = [int(_q_block(i, j, n_q=n_q, **kw)) for i in range(n_q)]
+        for i in range(n_q):
+            if live(i, j):
+                assert col[i] == i
+            elif i + 1 < n_q:
+                assert col[i] == col[i + 1]
+            assert 0 <= col[i] < n_q
+    assert live_tiles(s_q, s_k, bq, bk) == (
+        sum(live(i, j) for i in range(n_q) for j in range(n_k)), n_q * n_k)
+
+
+def test_live_tiles_of_the_old_default():
+    assert live_tiles(2048, 2048, 256, 256) == (36, 64)
+    assert live_tiles(2048, 2048, 512, 512) == (10, 16)
+    assert live_tiles(2048, 2048, 1024, 1024) == (3, 4)
+    assert live_tiles(2048, 2048, 256, 256, causal=False) == (64, 64)
+    # a chunk wholly in the past (ring attention's off-diagonal): all live
+    assert live_tiles(512, 512, 128, 128, offset=512) == (16, 16)
+
+
+def test_maps_are_the_identity_without_a_mask():
+    for i in range(4):
+        for j in range(4):
+            assert _kv_block(i, j, causal=False, block_q=128, block_k=128,
+                             offset=0, n_k=4) == j
+            assert _q_block(i, j, causal=False, block_q=128, block_k=128,
+                            offset=0, n_q=4) == i
+    # and traced indices come back untouched, not as new values
+    i, j = jnp.int32(1), jnp.int32(3)
+    assert _kv_block(i, j, causal=False, block_q=128, block_k=128, offset=0, n_k=4) is j
+    assert _q_block(i, j, causal=False, block_q=128, block_k=128, offset=0, n_q=4) is i
+
+
+# ---------------------------------------------------------------------------
+# the program says which tiles its compiled step took
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("impl,interpret,told", [
+    ("pallas", True, True),    # the kernel is in the step (the interpreter runs it)
+    ("pallas", False, False),  # the CPU backend steps down to XLA: nothing to tell
+    ("xla", False, False),
+])
+def test_trainer_steps_span_carries_the_tile_plan(impl, interpret, told, monkeypatch):
+    import numpy as np
+
+    from photon_tpu import telemetry
+    from photon_tpu.config.schema import (
+        Config, MeshConfig, ModelConfig, OptimizerConfig, SchedulerConfig, TrainConfig,
+    )
+    from photon_tpu.train import trainer as trainer_mod
+    from photon_tpu.utils.profiling import TRAINER_STEPS_SPAN
+
+    cfg = Config(
+        model=ModelConfig(d_model=64, n_layers=1, n_heads=2, max_seq_len=128,
+                          vocab_size=64, attn_impl=impl, attn_interpret=interpret,
+                          compute_dtype="float32"),
+        mesh=MeshConfig(),
+        optimizer=OptimizerConfig(name="adopt", lr=1e-3),
+        scheduler=SchedulerConfig(t_warmup=2, t_max=50),
+        train=TrainConfig(global_batch_size=2, device_microbatch_size=2),
+    )
+    seen = {}
+    real_span = telemetry.span
+
+    def spy(name, *a, **attrs):
+        seen.setdefault(name, attrs)
+        return real_span(name, *a, **attrs)
+
+    monkeypatch.setattr(trainer_mod.telemetry, "span", spy)
+    t = trainer_mod.Trainer(cfg, init_seed=0)
+    t.fit([np.zeros((2, 128), np.int64)], duration_steps=1)
+    attrs = seen[TRAINER_STEPS_SPAN]
+    assert attrs["steps"] == 1
+    if told:
+        plan = pick_tiles(128, 128, fa.LANE, 4)
+        assert plan.blocks == ((128, 128),) * 3
+        assert {k: attrs[k] for k in plan.attrs()} == plan.attrs()
+        assert attrs["flash_live_tiles"] == "fwd=1/1 dq=1/1 dkv=1/1"
+    else:
+        assert set(attrs) == {"steps"}

@@ -75,26 +75,31 @@ def _hlo(fn, *args) -> str:
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
 @pytest.mark.parametrize(
-    "h,h_kv,d,block",
+    "h,h_kv,d,block,alibi",
     [
-        (12, 12, 64, None),   # mpt-125m, the config's default tile
-        (12, 12, 64, 1024),   # mpt-125m, the tile the July record used
-        (16, 16, 128, 512),   # mpt-1b widths
-        (32, 8, 128, 512),    # grouped-query, 4 q heads per kv head
+        (12, 12, 64, 256, True),    # mpt-125m, the tile every run had before PR 28
+        (12, 12, 64, 1024, True),   # mpt-125m, the tile the July record used
+        (16, 16, 128, 512, True),   # mpt-1b widths
+        (32, 8, 128, 512, True),    # grouped-query, 4 q heads per kv head
+        # block None is what the models pass: the tiles pick_tiles derives,
+        # with the vmem_limit_bytes its estimate asks for, are what ships
+        (12, 12, 64, None, False),  # mpt-125m as the preset runs it
+        (12, 12, 64, None, True),
+        (16, 16, 128, None, True),
+        (32, 8, 128, None, True),
     ],
-    ids=["125m-default", "125m-b1024", "1b-b512", "gqa32x8-b512"],
+    ids=["125m-default", "125m-b1024", "1b-b512", "gqa32x8-b512",
+         "125m-derived", "125m-alibi-derived", "1b-derived", "gqa32x8-derived"],
 )
-def test_flash_attention_compiles(one_chip, h, h_kv, d, block, grad):
-    from photon_tpu.config.schema import ModelConfig
+def test_flash_attention_compiles(one_chip, h, h_kv, d, block, alibi, grad):
     from photon_tpu.ops.flash_attention import flash_attention
 
-    block = block or ModelConfig().flash_block_q
     b, s = 2, 2048
     q = _abstract((b, s, h, d), jnp.bfloat16, one_chip)
     kv = _abstract((b, s, h_kv, d), jnp.bfloat16, one_chip)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, alibi=True,
+        return flash_attention(q, k, v, causal=True, alibi=alibi,
                                block_q=block, block_k=block)
 
     def loss(q, k, v):
@@ -113,6 +118,32 @@ def test_flash_attention_compiles(one_chip, h, h_kv, d, block, grad):
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv") if grad else ("flash_fwd",):
         assert any(re.search(rf"\b{kernel}/multihead_attention\b", ln)
                    for ln in launches), kernel
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+@pytest.mark.parametrize(
+    "d,block_q,block_k",
+    [(64, 512, 512), (128, 1024, 1024), (128, 2048, 1024), (128, 1024, 2048),
+     (256, 2048, 512), (256, 1024, 1024)],
+    ids=lambda v: str(v),
+)
+def test_flash_vmem_estimate_is_enough(one_chip, monkeypatch, d, block_q, block_k, dtype):
+    """``launch_vmem_bytes`` is what ``pick_tiles`` fits tiles by and what a
+    launch asks the compiler for, so it may never be under what the compiler
+    needs: with the unasked-for 16 MiB taken away, every launch compiles
+    inside its own estimate (tall, wide and square tiles; the widths and
+    dtypes whose buffers differ)."""
+    from photon_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "VMEM_SCOPED_DEFAULT", 0)
+    q = _abstract((1, 2048, 4, d), dtype, one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, alibi=True, block_q=block_q,
+                                  block_k=block_k).astype(jnp.float32).sum()
+
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert hlo.count(KERNEL) >= 3
 
 
 def test_flash_attention_with_lse_compiles(one_chip):
