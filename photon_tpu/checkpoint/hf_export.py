@@ -37,7 +37,7 @@ from typing import Any
 
 import numpy as np
 
-from photon_tpu.config.schema import Config, ModelConfig
+from photon_tpu.config.schema import Config, ModelConfig, refuse_training_only_family
 
 
 def _t(arr: np.ndarray) -> "Any":
@@ -98,6 +98,7 @@ def _hf_llama_family_common(params: Any, cfg: ModelConfig, kind: str,
 
 def llama_state_dict(params: Any, cfg: ModelConfig) -> dict:
     """HF ``LlamaForCausalLM`` state dict from a llama-family param tree."""
+    refuse_training_only_family(cfg, "HF llama export (no export map)")
     if cfg.mlp != "swiglu":
         raise ValueError(f"llama export needs mlp=swiglu (got mlp={cfg.mlp})")
     blocks = params["blocks"]["block"]
@@ -150,6 +151,7 @@ def mixtral_state_dict(params: Any, cfg: ModelConfig) -> dict:
     needs a capacity_factor ≥ E/top_k (drop-free routing) — the exporter
     does not enforce that, it is a property of the eval batch.
     """
+    refuse_training_only_family(cfg, "HF mixtral export (no export map)")
     if cfg.mlp != "moe" or cfg.moe_mlp_act != "swiglu":
         raise ValueError(
             "mixtral export needs mlp='moe' with moe_mlp_act='swiglu' "
@@ -223,6 +225,7 @@ def save_hf_mixtral(params: Any, cfg: ModelConfig, out_dir: str,
 
 def foundry_mpt_state_dict(params: Any, cfg: ModelConfig) -> dict:
     """llm-foundry MPT naming (the reference's checkpoint module tree)."""
+    refuse_training_only_family(cfg, "HF mpt-foundry export (no export map)")
     if cfg.rope or cfg.norm != "layernorm" or cfg.mlp != "gelu":
         raise ValueError("mpt-foundry export is for the MPT family config")
     blocks = params["blocks"]["block"]

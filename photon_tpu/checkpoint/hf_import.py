@@ -28,7 +28,7 @@ from typing import Any
 
 import numpy as np
 
-from photon_tpu.config.schema import Config, ModelConfig
+from photon_tpu.config.schema import Config, ModelConfig, refuse_training_only_family
 
 
 def model_config_from_hf(hf_cfg: dict) -> ModelConfig:
@@ -120,6 +120,7 @@ def _load_state_dict(hf_dir: pathlib.Path) -> dict:
 
 def llama_params_from_hf(sd: dict, cfg: ModelConfig) -> Any:
     """HF llama state dict → photon-tpu param tree (fp32 numpy leaves)."""
+    refuse_training_only_family(cfg, "HF import (no import map)")
 
     def t(key: str) -> np.ndarray:  # torch [out, in] -> jax [in, out]
         return np.ascontiguousarray(np.asarray(sd[key]).T.astype(np.float32))

@@ -84,6 +84,39 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01  # Switch load-balance loss weight
     moe_mlp_act: str = "gelu"  # gelu | swiglu (Mixtral-style gated experts)
+    # The dropless expert layer (``ops/moe.dropless_moe_mlp``, training
+    # only): ``moe_router: sigmoid`` scores every one of ``moe_num_experts``
+    # with a sigmoid, selects ``moe_top_k`` by score + a selection bias that
+    # takes no gradient, renormalises the selected scores and scales them by
+    # ``moe_routed_scale``; no capacity, no dropped token, no aux loss. This
+    # slice of an expert-parallel deployment HOLDS ``moe_experts_held``
+    # experts (0 -> all) from ``moe_first_expert`` on: it routes over all of
+    # them and computes its own experts' part of the result.
+    # ``moe_shared_experts`` SwiGLU experts of the same width see every token.
+    # After every optimizer step the selection bias moves against each
+    # expert's load by ``moe_bias_update_speed`` at most
+    # (``ops/moe.balanced_router_bias``); 0 holds it constant.
+    moe_router: str = "softmax"  # softmax (capacity path) | sigmoid (dropless)
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    moe_shared_experts: int = 0
+    moe_routed_scale: float = 1.0
+    moe_bias_update_speed: float = 0.0
+    # Leading dense blocks before the expert stack (HF ``first_k_dense_replace``):
+    # the first ``first_k_dense`` layers are SwiGLU blocks of width
+    # ``dense_mlp_hidden_size``, under a scan of their own (``dense_blocks``).
+    first_k_dense: int = 0
+    dense_mlp_hidden_size: int = 0
+    # Latent attention (MLA, training form: keys and values expanded from the
+    # latent; ``kv_lora_rank > 0`` turns it on). q and kv each go through a
+    # low-rank pair with an RMSNorm between; a ``qk_rope_head_dim``-wide
+    # rotary key is shared by all heads; a head's q/k is
+    # [``qk_nope_head_dim`` | ``qk_rope_head_dim``] wide, its v ``v_head_dim``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     attn_impl: str = AttnImpl.PALLAS.value
     # Numerics: params kept fp32, compute in bf16 (reference: amp_bf16 + FSDP
     # PURE mixed precision, ``mpt-125m.yaml:85-92``).
@@ -108,7 +141,28 @@ class ModelConfig:
     attn_interpret: bool = False
 
     @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def dropless_moe(self) -> bool:
+        return self.mlp == "moe" and self.moe_router == "sigmoid"
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_num_experts
+
+    @property
+    def training_path_only(self) -> bool:
+        """Latent attention, the dropless expert layer or leading dense
+        blocks: what serving, cached decode, LoRA and the HF maps lack."""
+        return self.latent_attention or self.dropless_moe or self.first_k_dense > 0
+
+    @property
     def d_head(self) -> int:
+        if self.latent_attention:
+            # heads are as wide as the projections make them, not d_model / n_heads
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.d_model % self.n_heads:
             raise ValueError(f"d_model {self.d_model} not divisible by n_heads {self.n_heads}")
         return self.d_model // self.n_heads
@@ -870,6 +924,85 @@ class Config:
     def from_json(cls, s: str) -> "Config":
         return cls.from_dict(json.loads(s))
 
+    def _validate_latent_moe_family(self) -> None:
+        """Latent attention, the dropless sigmoid router, the experts held
+        here and the leading dense blocks (preset ``glm-4.7-flash-ep8``)."""
+        m = self.model
+        if m.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"bad model.moe_router {m.moe_router!r}")
+        if m.moe_router == "sigmoid":
+            if m.mlp != "moe" or m.moe_mlp_act != "swiglu":
+                raise ValueError(
+                    "moe_router='sigmoid' needs mlp='moe' with moe_mlp_act='swiglu'")
+            held = m.experts_held
+            if held > m.moe_num_experts:
+                raise ValueError(
+                    f"moe_experts_held={held} exceeds the {m.moe_num_experts} "
+                    "routed experts (moe_num_experts)")
+            if held < 1 or m.moe_num_experts % held:
+                raise ValueError(
+                    f"moe_experts_held={held} does not divide the "
+                    f"{m.moe_num_experts} routed experts: an expert-parallel "
+                    "deployment gives every chip the same number")
+            if m.moe_first_expert % held or not (
+                    0 <= m.moe_first_expert <= m.moe_num_experts - held):
+                raise ValueError(
+                    f"moe_first_expert={m.moe_first_expert} is not the start of "
+                    f"one of the {m.moe_num_experts // held} shares of {held}")
+            if self.mesh.expert > 1:
+                raise ValueError(
+                    "mesh.expert > 1 with moe_router='sigmoid' is not supported "
+                    "yet: the dropless layer has no expert exchange across "
+                    "chips (it computes the share it is told it holds)")
+            if m.moe_shared_experts < 0 or m.moe_bias_update_speed < 0:
+                raise ValueError(
+                    "moe_shared_experts and moe_bias_update_speed must be >= 0")
+        elif (m.moe_experts_held or m.moe_first_expert or m.moe_shared_experts
+              or m.moe_routed_scale != 1.0 or m.moe_bias_update_speed):
+            raise ValueError(
+                "moe_experts_held / moe_first_expert / moe_shared_experts / "
+                "moe_routed_scale / moe_bias_update_speed belong to "
+                "moe_router='sigmoid'")
+        if m.first_k_dense:
+            if not 0 < m.first_k_dense < m.n_layers or m.dense_mlp_hidden_size <= 0:
+                raise ValueError(
+                    f"first_k_dense={m.first_k_dense} needs 0 < first_k_dense < "
+                    f"n_layers={m.n_layers} and dense_mlp_hidden_size > 0")
+            if self.mesh.pipe > 1:
+                raise ValueError(
+                    "mesh.pipe > 1 with first_k_dense > 0 is not supported: the "
+                    "pipeline schedule scans one uniform stack of blocks")
+        latent = (m.q_lora_rank, m.kv_lora_rank, m.qk_nope_head_dim,
+                  m.qk_rope_head_dim, m.v_head_dim)
+        if any(latent):
+            if min(latent) <= 0:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim all > 0")
+            if not m.rope or m.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs rope=true and an even qk_rope_head_dim")
+            if m.n_kv_heads:
+                raise ValueError("latent attention has no grouped kv heads (n_kv_heads)")
+            if m.v_head_dim != m.qk_nope_head_dim + m.qk_rope_head_dim:
+                raise ValueError(
+                    f"v_head_dim={m.v_head_dim} differs from qk_nope_head_dim + "
+                    f"qk_rope_head_dim={m.qk_nope_head_dim + m.qk_rope_head_dim}: "
+                    "the attention kernels take one head width for q, k and v")
+        if m.training_path_only:
+            if m.lora_rank or self.photon.adapters.enabled:
+                raise ValueError(
+                    "LoRA adapters (model.lora_rank / photon.adapters) are not "
+                    "supported with latent attention, the dropless expert layer "
+                    "or leading dense blocks: their projections are not adaptable "
+                    "modules yet")
+            if self.photon.serve.prefix_cache or self.photon.serve.enabled:
+                raise ValueError(
+                    "photon.serve (and its prefix cache) is not supported with "
+                    "latent attention, the dropless expert layer or leading "
+                    "dense blocks: there is no latent paged cache or decode "
+                    "step for them yet")
+
     def validate(self) -> "Config":
         if self.fl.n_clients_per_round > self.fl.n_total_clients:
             raise ValueError("n_clients_per_round > n_total_clients")
@@ -952,6 +1085,7 @@ class Config:
 
         elif self.mesh.expert > 1:
             raise ValueError("mesh.expert > 1 requires model.mlp='moe'")
+        self._validate_latent_moe_family()
         if self.model.rope and self.model.d_head % 2:
             raise ValueError("rope needs an even d_head")
         if self.model.n_kv_heads < 0 or self.model.mlp_hidden_size < 0:
@@ -1493,6 +1627,23 @@ class Config:
             )
         _ = self.model.d_head
         return self
+
+
+def refuse_training_only_family(model: ModelConfig, what: str) -> None:
+    """Raise where ``what`` (serving, cached decode, HF import / export) meets
+    a model only the training path computes: latent attention has no latent
+    paged cache or absorbed decode yet, the dropless expert layer no decode
+    step, the leading dense blocks no place in the one-stack cache geometry
+    or the import maps. Refusing beats running it wrong."""
+    has = [name for name, on in (
+        ("latent attention (kv_lora_rank > 0)", model.latent_attention),
+        ("the dropless sigmoid router (moe_router='sigmoid')", model.dropless_moe),
+        ("leading dense blocks (first_k_dense > 0)", model.first_k_dense > 0),
+    ) if on]
+    if has:
+        raise NotImplementedError(
+            f"{what} does not support {', '.join(has)}: model "
+            f"{model.name!r} runs on the training path only")
 
 
 def effective_model_config(model: ModelConfig, mesh: MeshConfig) -> ModelConfig:

@@ -31,7 +31,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
-from photon_tpu.config.schema import ModelConfig
+from photon_tpu.config.schema import ModelConfig, refuse_training_only_family
 from photon_tpu.ops.attention import alibi_slopes, multihead_attention
 
 
@@ -188,6 +188,7 @@ def prefill(params: dict, tokens: jax.Array, lengths: jax.Array,
     runs with row b's adapter (a mixed-cohort batch in one pass), scaled
     by ``lora_scale``. None keeps the graph byte-identical to the
     adapter-free build."""
+    refuse_training_only_family(cfg, "cached decode (models/decode.py)")
     b, s = tokens.shape
     n_kv = cfg.n_kv_heads or cfg.n_heads
     pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
@@ -232,6 +233,7 @@ def decode_step(params: dict, state: DecodeState, token: jax.Array,
     """Place ``token [B]`` at each row's cursor, attend into the caches,
     return (logits for the FOLLOWING position, advanced state).
     ``adapters``: per-row LoRA factors as in :func:`prefill`."""
+    refuse_training_only_family(cfg, "cached decode (models/decode.py)")
     n_kv = cfg.n_kv_heads or cfg.n_heads
     group = cfg.n_heads // n_kv
     s = state.cache_k.shape[2]

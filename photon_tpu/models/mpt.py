@@ -11,6 +11,15 @@ embeddings — preset ``llama-1b``), the shape of llm-foundry's
 attn_config/ffn_config switches; every trainer, sharding, checkpoint, and
 federation path is shared because the parameter tree keeps the same names.
 
+A latent-attention / expert family composes the same way (preset
+``glm-4.7-flash-ep8``, training path only): ``kv_lora_rank > 0`` swaps the
+QKV projection for MLA's two low-rank paths (``MPTBlock._latent_qkv``; scores
+still go through ``multihead_attention``), ``first_k_dense`` puts leading
+dense blocks under a scan of their own (``dense_blocks``) before the stack,
+and ``moe_router: sigmoid`` makes the stack's MLP the dropless expert layer of
+``ops/moe.py`` with a shared expert. Norms, residuals and the attention
+dispatch are the one ``MPTBlock``'s.
+
 TPU-first design choices (not in the reference):
 - Layers are stacked with ``nn.scan`` → one traced block, params carry a
   leading ``[n_layers, ...]`` axis. This keeps compile time flat in depth and
@@ -130,8 +139,90 @@ def apply_rope(q: jax.Array, k: jax.Array, theta: float) -> tuple[jax.Array, jax
     return rot(q), rot(k)
 
 
+#: latent attention's projections (both low-rank paths, their norms, RoPE,
+#: the key assembly, ``out_proj``) as a ``jax.named_scope``; the score and
+#: value products between them keep ``multihead_attention``'s own names
+MLA_PROJ_SCOPE = "mla/proj"
+
+
 class MPTBlock(nn.Module):
     cfg: ModelConfig
+    #: a leading dense block of an expert model (``cfg.first_k_dense``): the
+    #: same norms, residuals and attention, a SwiGLU of
+    #: ``cfg.dense_mlp_hidden_size`` where the stack's blocks have experts
+    dense_mlp: bool = False
+
+    def _latent_qkv(self, h: jax.Array, dense):
+        """MLA in its training form: ``h [B, S, D]`` -> q, k, v
+        ``[B, S, H, d_head]``. q through a low-rank pair with an RMSNorm
+        between; one projection gives the kv latent and a rotary key that all
+        heads share; the normed latent expands to per-head ``k_nope | v``.
+        RoPE turns the rope part of q and the shared key; a head's key is
+        ``[k_nope_h | k_rope]``."""
+        cfg = self.cfg
+        b, s, _ = h.shape
+        nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        std = cfg.emb_init_std
+        c_q = FP32RMSNorm(eps=cfg.norm_eps, name="q_a_norm")(
+            dense(cfg.q_lora_rank, "q_a_proj", std)(h))
+        q = dense(cfg.n_heads * (nope + rope), "q_b_proj", std)(c_q)
+        q = q.reshape(b, s, cfg.n_heads, nope + rope)
+        kv_a = dense(cfg.kv_lora_rank + rope, "kv_a_proj", std)(h)
+        c_kv = FP32RMSNorm(eps=cfg.norm_eps, name="kv_a_norm")(
+            kv_a[..., :cfg.kv_lora_rank])
+        kv = dense(cfg.n_heads * (nope + dv), "kv_b_proj", std)(c_kv)
+        kv = kv.reshape(b, s, cfg.n_heads, nope + dv)
+        q_rope, k_rope = apply_rope(
+            q[..., nope:], kv_a[..., None, cfg.kv_lora_rank:], cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, cfg.n_heads, rope))],
+            axis=-1)
+        return q, k, kv[..., nope:]
+
+    def _dropless_moe(self, x: jax.Array, dense, hidden: int, resid_std: float):
+        """The dropless expert layer's residual branch (``ops/moe.py``):
+        shared experts on every token plus this chip's part of the routed
+        sum. The router reads the norm in float32."""
+        from photon_tpu.ops import moe
+
+        cfg = self.cfg
+        compute = _dtype(cfg.compute_dtype)
+        pd = _dtype(cfg.param_dtype)
+        init = nn.initializers.normal(stddev=cfg.emb_init_std)
+        h32 = _norm(cfg, "ln_2")(x.astype(jnp.float32))
+        h = h32.astype(compute)
+        held = cfg.experts_held
+        router_w = self.param("router", init, (cfg.d_model, cfg.moe_num_experts), pd)
+        # HF's e_score_correction_bias: selects only and takes no gradient;
+        # the train step moves it by the balancing rule
+        # (``moe.balanced_router_bias``, ``cfg.moe_bias_update_speed``) from
+        # the rows sown below; seeded small and non-zero so that selection
+        # and weights differ
+        router_bias = self.param(
+            "router_bias", nn.initializers.normal(stddev=0.01),
+            (cfg.moe_num_experts,), jnp.float32)
+        w_gate = self.param("moe_gate", init, (held, cfg.d_model, hidden), pd)
+        w_up = self.param("moe_up", init, (held, cfg.d_model, hidden), pd)
+        w_down = self.param(
+            "moe_down", nn.initializers.normal(stddev=resid_std),
+            (held, hidden, cfg.d_model), pd)
+        out, counters = moe.dropless_moe_mlp(
+            h32, router_w, router_bias, w_gate, w_up, w_down,
+            top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
+            routed_scale=cfg.moe_routed_scale, compute_dtype=compute,
+            interpret=cfg.attn_interpret)
+        self.sow("intermediates", "moe_rows_held", counters["rows_held"])
+        self.sow("intermediates", "moe_max_expert_load", counters["max_expert_load"])
+        self.sow("intermediates", "moe_expert_rows", counters["expert_rows"])
+        if cfg.moe_shared_experts:
+            with jax.named_scope(moe.SHARED_EXPERT_SCOPE):
+                width = cfg.moe_shared_experts * hidden
+                gate = dense(width, "shared_gate_proj", cfg.emb_init_std)(h)
+                up = dense(width, "shared_up_proj", cfg.emb_init_std)(h)
+                out = out + dense(cfg.d_model, "shared_down_proj", resid_std)(
+                    nn.silu(gate) * up)
+        return out
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -176,7 +267,10 @@ class MPTBlock(nn.Module):
         h = _norm(cfg, "ln_1")(x)
         n_kv = cfg.n_kv_heads or cfg.n_heads
         b, s, _ = h.shape
-        if n_kv == cfg.n_heads:
+        if cfg.latent_attention:
+            with jax.named_scope(MLA_PROJ_SCOPE):
+                q, k, v = self._latent_qkv(h, dense)
+        elif n_kv == cfg.n_heads:
             qkv = adapted(3 * cfg.d_model, "wqkv", cfg.emb_init_std, h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
@@ -190,7 +284,7 @@ class MPTBlock(nn.Module):
         q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
         k = k.reshape(b, s, n_kv, cfg.d_head)
         v = v.reshape(b, s, n_kv, cfg.d_head)
-        if cfg.rope:
+        if cfg.rope and not cfg.latent_attention:
             # before the kv repeat: the rotation is per-head-identical, so
             # rotating n_kv heads then replicating equals the reverse order
             q, k = apply_rope(q, k, cfg.rope_theta)
@@ -203,13 +297,22 @@ class MPTBlock(nn.Module):
             impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
             interpret=cfg.attn_interpret,
         )
-        attn_out = attn_out.reshape(b, s, cfg.d_model)
-        x = x + adapted(cfg.d_model, "out_proj", resid_std, attn_out)
+        if cfg.latent_attention:
+            with jax.named_scope(MLA_PROJ_SCOPE):
+                x = x + dense(cfg.d_model, "out_proj", resid_std)(
+                    attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim))
+        else:
+            attn_out = attn_out.reshape(b, s, cfg.d_model)
+            x = x + adapted(cfg.d_model, "out_proj", resid_std, attn_out)
 
         # --- MLP ---
-        h = _norm(cfg, "ln_2")(x)
         hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * cfg.d_model
-        if cfg.mlp == "moe":
+        if self.dense_mlp:
+            hidden = cfg.dense_mlp_hidden_size
+        elif cfg.dropless_moe:
+            return x + self._dropless_moe(x, dense, hidden, resid_std)
+        h = _norm(cfg, "ln_2")(x)
+        if cfg.mlp == "moe" and not self.dense_mlp:
             # expert-parallel MLP (ops/moe.py): router + E expert FFNs,
             # GShard dense dispatch. Expert weights carry a leading [E]
             # axis sharded over the `expert` mesh axis
@@ -250,7 +353,7 @@ class MPTBlock(nn.Module):
                 moe_out, P(("data", "fsdp", "expert"), "sequence", None)
             )
             return x + moe_out
-        if cfg.mlp == "swiglu":
+        if cfg.mlp == "swiglu" or self.dense_mlp:
             # separate gate/up projections (standard llama layout): each is
             # column-parallel under the same sharding rule, so silu(gate)*up
             # is shard-local — a fused gate||up matrix would put ALL of gate
@@ -271,10 +374,11 @@ class _ScanBlock(nn.Module):
     signature ``nn.scan`` expects."""
 
     cfg: ModelConfig
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(self, carry: jax.Array, _: None):
-        return MPTBlock(self.cfg, name="block")(carry), None
+        return MPTBlock(self.cfg, self.dense_mlp, name="block")(carry), None
 
 
 class MPTModel(nn.Module):
@@ -320,16 +424,22 @@ class MPTModel(nn.Module):
                 prevent_cse=False,
             )
         # stack layers: params get a leading [n_layers] axis; single trace
-        stack = nn.scan(
-            block_cls,
-            # intermediates: per-layer MoE aux losses stack to [n_layers]
-            # (empty when nothing is sown / the collection is immutable)
-            variable_axes={"params": 0, "intermediates": 0},
-            split_rngs={"params": True},
-            length=cfg.n_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, name="blocks")
-        x, _ = stack(x, None)
+        def stack(length: int, name: str, dense_mlp: bool = False):
+            return nn.scan(
+                block_cls,
+                # intermediates: per-layer MoE aux losses and counters stack
+                # to [length] (empty when nothing is sown / the collection
+                # is immutable)
+                variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True},
+                length=length,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )(cfg, dense_mlp, name=name)
+
+        if cfg.first_k_dense:
+            # leading dense blocks under a scan of their own, beside the stack
+            x, _ = stack(cfg.first_k_dense, "dense_blocks", dense_mlp=True)(x, None)
+        x, _ = stack(cfg.n_layers - cfg.first_k_dense, "blocks")(x, None)
 
         x = _norm(cfg, "ln_f")(x)
         if return_hidden:

@@ -27,9 +27,21 @@ Design notes:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# The dropless layer's stages as ``jax.named_scope``s (they reach every
+# operation's ``op_name``, forward, transpose and recomputation alike; the
+# benchmark's ``moe_*`` readers sum device time by them).
+ROUTER_SCOPE = "moe/router"
+#: sort by expert, permute, un-permute, combine
+DISPATCH_SCOPE = "moe/dispatch"
+#: the grouped products of the experts held here and the activation between
+EXPERTS_SCOPE = "moe/experts"
+SHARED_EXPERT_SCOPE = "moe/shared_expert"
 
 
 def expert_capacity(n_tokens: int, n_experts: int, top_k: int,
@@ -129,3 +141,223 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, w_up: jax.Array,
     expert_out = jnp.einsum("ech,ehd->ecd", h, w_down.astype(x.dtype))
     out = jnp.einsum("nec,ecd->nd", combine, expert_out)
     return out.reshape(*lead, d), aux
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer: sort by expert, grouped products over the experts held
+# here. No [N, E, C] tensor, no capacity, no dropped token, no aux loss.
+# ---------------------------------------------------------------------------
+
+#: caps of the grouped products' (rows, contraction, output) tile on the chip;
+#: picked on a v5e over ``scripts/moe_grouped_ladder.py`` (PERF.md, PR 29)
+GMM_TILING = (512, 1024, 1024)
+
+
+def sigmoid_route(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+                  top_k: int, routed_scale: float):
+    """``noaux_tc`` routing with one group: float32 sigmoid scores of every
+    routed expert, the ``top_k`` picked by score + ``router_bias`` (which only
+    selects and takes no gradient), the picked scores renormalised to sum to
+    ``routed_scale``. ``h32 [N, D]`` float32 -> ``(idx [N, k] int32,
+    gates [N, k] float32)``."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        h32.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    biased = scores + jax.lax.stop_gradient(router_bias.astype(jnp.float32))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = routed_scale * picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_by_expert(x: jax.Array, order: jax.Array, inv: jax.Array, k: int):
+    """``x [N, D]`` -> ``[N k, D]``: row ``i`` is the token of the ``i``-th
+    assignment in expert order. Its transpose is written as a gather too (a
+    token's ``k`` assignments sit at ``inv[n k : n k + k]``): XLA's own
+    transpose of a gather is a scatter-add, slow on a TPU."""
+    return x[order // k]
+
+
+def _rows_by_expert_fwd(x, order, inv, k):
+    return _rows_by_expert(x, order, inv, k), (order, inv, x.shape[0])
+
+
+def _rows_by_expert_bwd(k, res, g):
+    order, inv, n = res
+    dx = jnp.sum(g[inv].reshape(n, k, g.shape[-1]).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+_rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
+
+
+@jax.custom_vjp
+def _rows_by_token(rows: jax.Array, order: jax.Array, inv: jax.Array):
+    """Expert-ordered rows back in assignment order (``[N k, D]``, token
+    ``n``'s at ``n k : n k + k``); the transpose is the gather by ``order``."""
+    return rows[inv]
+
+
+def _rows_by_token_fwd(rows, order, inv):
+    return _rows_by_token(rows, order, inv), order
+
+
+def _rows_by_token_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
+
+
+def _megablox():
+    # the package's __init__ rebinds the name `gmm` to its own custom_vjp
+    # function, so `from ...megablox import gmm` is not the module
+    import importlib
+
+    return importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _tiles(caps: tuple[int, int, int], m: int, k: int, n: int) -> tuple[int, int, int]:
+    """The kernel's (rows, contraction, output) tile for one product: under
+    each cap, the largest multiple of 128 that divides the dimension (a tile
+    that does not divide is padded and masked: 1,024 over 1,536 wastes a
+    third), or the whole dimension where none does."""
+    def fit(dim: int, cap: int) -> int:
+        return next((t for t in range(min(cap, dim) // 128 * 128, 0, -128)
+                     if dim % t == 0), dim)
+
+    return fit(m, caps[0]), fit(k, caps[1]), fit(n, caps[2])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(lhs, rhs, group_sizes, caps, interpret):
+    """Megablox's grouped product with its transposes: ``lhs [M, K]`` rows
+    sorted by group, ``rhs [G, K, N]``, ``group_sizes [G + 1]`` whose last
+    entry counts the rows of no group here (the kernel's sharded-groups form:
+    it visits the tiles of the first ``G`` groups only, its grid as long as
+    their rows, and the rows past them come out zero)."""
+    (m, k), n = lhs.shape, rhs.shape[2]
+    return _megablox().gmm(lhs, rhs, group_sizes, lhs.dtype, _tiles(caps, m, k, n),
+                           jnp.zeros((), jnp.int32), interpret=interpret)
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, caps, interpret):
+    return _gmm(lhs, rhs, group_sizes, caps, interpret), (lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(caps, interpret, res, g):
+    lhs, rhs, group_sizes = res
+    (m, k), n = lhs.shape, rhs.shape[2]
+    zero = jnp.zeros((), jnp.int32)
+    d_lhs = _megablox().gmm(g, rhs, group_sizes, lhs.dtype, _tiles(caps, m, n, k),
+                            zero, transpose_rhs=True, interpret=interpret)
+    d_rhs = _megablox().tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+                             _tiles(caps, m, k, n), zero, rhs.shape[0],
+                             interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+                   impl: str = "pallas", tiling: tuple[int, int, int] = GMM_TILING,
+                   interpret: bool = False) -> jax.Array:
+    """``out[i] = lhs[i] @ rhs[g(i)]`` for rows sorted by group.
+
+    ``lhs [M, K]``, ``rhs [G, K, N]``, ``group_sizes [G + 1] int32`` summing
+    to ``M``: ``G`` groups in order, then the rows of no group here, which
+    give zeros. ``impl='pallas'`` is the megablox kernel, whose grid (and
+    device time) follows the rows that have a group, under tiles no larger
+    than ``tiling``; off the TPU it steps down to ``impl='xla'``
+    (``jax.lax.ragged_dot``) like the flash kernel."""
+    from photon_tpu.ops.flash_attention import pallas_supported
+
+    if impl == "pallas" and (interpret or pallas_supported(lhs)):
+        return _gmm(lhs, rhs, group_sizes, tuple(tiling), interpret)
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"unknown grouped matmul impl {impl!r}")
+    out = jax.lax.ragged_dot(lhs, rhs, group_sizes[:-1])
+    grouped = jnp.arange(lhs.shape[0]) < lhs.shape[0] - group_sizes[-1]
+    return jnp.where(grouped[:, None], out, 0).astype(lhs.dtype)
+
+
+def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+                     top_k: int, first_expert: int, routed_scale: float,
+                     compute_dtype=jnp.bfloat16, interpret: bool = False):
+    """The routed part of a dropless expert layer, for the experts held here.
+
+    ``h32 [..., D]`` float32 normed activations; ``router_w [D, E]`` and
+    ``router_bias [E]`` over ALL ``E`` routed experts; ``w_gate`` / ``w_up``
+    ``[E_held, D, H]`` and ``w_down [E_held, H, D]`` the SwiGLU experts
+    ``first_expert .. first_expert + E_held`` this chip holds. Every token is
+    routed over all ``E``; its assignments to experts held here are computed
+    (sorted by expert, three grouped products), the others add nothing: what
+    the absent experts would have given is another chip's part of the sum.
+
+    Shapes are static for the worst case (``N k`` rows, all routed here); the
+    grouped products' device time follows the rows that were. Returns
+    ``(out [..., D] compute_dtype, counters)`` with ``rows_held`` (assignments
+    to experts held here) and ``max_expert_load`` (the busiest held expert's
+    rows over their mean), both float32 scalars, and ``expert_rows [E]``
+    float32, the assignments to each of ALL the routed experts (what
+    :func:`balanced_router_bias` steers by).
+    """
+    lead, d = h32.shape[:-1], h32.shape[-1]
+    n = int(np.prod(lead))
+    e_held = w_up.shape[0]
+    hf32 = h32.reshape(n, d)
+    with jax.named_scope(ROUTER_SCOPE):
+        idx, gates = sigmoid_route(hf32, router_w, router_bias, top_k, routed_scale)
+        expert_rows = jnp.sum(
+            idx.reshape(n * top_k, 1) == jnp.arange(router_w.shape[-1], dtype=jnp.int32),
+            axis=0, dtype=jnp.float32)
+    with jax.named_scope(DISPATCH_SCOPE):
+        local = idx - first_expert
+        held = (local >= 0) & (local < e_held)
+        # assignments to absent experts sort last, under a group of their own
+        key = jnp.where(held, local, e_held).reshape(n * top_k)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(e_held + 1, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32)
+        rows = _rows_by_expert(hf32.astype(compute_dtype), order, inv, top_k)
+    with jax.named_scope(EXPERTS_SCOPE):
+        mm = functools.partial(grouped_matmul, group_sizes=group_sizes,
+                               interpret=interpret)
+        gate = mm(rows, w_gate.astype(compute_dtype))
+        up = mm(rows, w_up.astype(compute_dtype))
+        rows = mm(jax.nn.silu(gate) * up, w_down.astype(compute_dtype))
+    with jax.named_scope(DISPATCH_SCOPE):
+        per_slot = _rows_by_token(rows, order, inv).reshape(n, top_k, d)
+        out = jnp.einsum("nk,nkd->nd", jnp.where(held, gates, 0.0), per_slot,
+                         preferred_element_type=jnp.float32)
+        held_sizes = group_sizes[:e_held].astype(jnp.float32)
+        rows_held = jnp.sum(held_sizes)
+        counters = {
+            "rows_held": rows_held,
+            "max_expert_load": jnp.max(held_sizes) / jnp.maximum(rows_held / e_held, 1.0),
+            "expert_rows": expert_rows,
+        }
+    return out.astype(compute_dtype).reshape(*lead, d), counters
+
+
+def balanced_router_bias(router_bias: jax.Array, expert_rows: jax.Array,
+                         speed: float) -> jax.Array:
+    """One step of the selection bias's balancing rule (the aux-loss-free
+    rule of ``noaux_tc``: the bias of an expert over the mean load falls, of
+    one under it rises; no gradient is involved). ``expert_rows [..., E]`` are
+    the step's assignments to each routed expert. The step is ``speed`` times
+    the load's relative error cut to [-1, 1]: an expert at twice the mean or
+    more, or with no row at all, moves by ``speed`` as under the published
+    sign rule; nearer the mean the step shrinks with the error instead of
+    keeping its size. So the rule has a fixed point to settle on where the
+    bare sign chatters by ``speed`` every step, and two precisions of one
+    model, whose counts differ by a few rows, keep one bias where the sign
+    would flip for an expert near the mean. All float32."""
+    rows = expert_rows.astype(jnp.float32)
+    mean = jnp.maximum(jnp.mean(rows, axis=-1, keepdims=True), 1.0)
+    return router_bias.astype(jnp.float32) - speed * jnp.clip((rows - mean) / mean, -1.0, 1.0)

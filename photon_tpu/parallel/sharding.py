@@ -39,8 +39,16 @@ _RULES: list[tuple[str, P]] = [
     # MoE (ops/moe.py): experts shard over `expert`; inner dims follow the
     # dense column/row-parallel convention
     (r"router$", P("pipe", "fsdp", None)),
+    (r"router_bias$", P("pipe")),
     (r"(moe_up|moe_gate)$", P("pipe", "expert", "fsdp", "tensor")),
     (r"moe_down$", P("pipe", "expert", "tensor", "fsdp")),
+    # latent attention: the down-projections' small outputs (a rank, the
+    # shared rotary key) stay whole; the up-projections split their heads
+    (r"(q_a_proj|kv_a_proj)/kernel$", P("pipe", "fsdp", None)),
+    (r"(q_b_proj|kv_b_proj)/kernel$", P("pipe", None, "tensor")),
+    (r"(q_a_norm|kv_a_norm)/scale$", P("pipe")),
+    # `up_proj` etc. also match the dropless layer's shared expert
+    # (`shared_up_proj`, ...): the same column / row convention
     (r"(wqkv|up_proj|gate_proj|q_proj|k_proj|v_proj)/kernel$", P("pipe", "fsdp", "tensor")),
     (r"(out_proj|down_proj)/kernel$", P("pipe", "tensor", "fsdp")),
     (r"(wqkv|up_proj|gate_proj|q_proj|k_proj|v_proj)/bias$", P("pipe", "tensor")),

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_tpu.config.schema import Config, ModelConfig
+from photon_tpu.config.schema import Config, ModelConfig, refuse_training_only_family
 from photon_tpu.serve.cache import (
     BlockAllocator,
     PagedState,
@@ -208,6 +208,7 @@ class PagedEngine:
     def __init__(self, cfg: Config, params: Any, *,
                  loaded_round: int | None = None,
                  adapter_bank: dict | None = None) -> None:
+        refuse_training_only_family(cfg.model, "the serving engine (photon_tpu/serve)")
         self.cfg = cfg
         self.mc: ModelConfig = cfg.model
         sc = cfg.photon.serve

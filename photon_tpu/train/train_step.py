@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import optax
 
 from photon_tpu.models.mpt import MPTModel
+from photon_tpu.utils.profiling import MOE_MAX_EXPERT_LOAD, MOE_ROWS_HELD
 
 # The step's stages as ``jax.named_scope``s: they reach every operation's
 # ``op_name`` metadata (forward, transpose and recomputation alike), which is
@@ -100,42 +101,98 @@ def collect_moe_aux(variables: Any) -> jax.Array:
     return aux
 
 
+#: the step's rows by expert layer and routed expert, ``[layers, E]``: beside
+#: the counters until the step has moved the selection bias by them; no metric
+_EXPERT_ROWS = "moe/expert_rows"
+
+
+def collect_moe_counters(variables: Any) -> dict[str, jax.Array]:
+    """The dropless expert layers' per-layer sows as the step's counters:
+    rows routed to the experts held here summed over layers, and the busiest
+    held expert's rows over the mean, worst layer; and the rows of every
+    routed expert by layer, for the balancing rule. Empty for every other
+    model."""
+    rows, worst, by_expert = [], [], []
+    for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
+        keys = {getattr(k, "key", None) for k in path}
+        if "moe_rows_held" in keys:
+            rows.append(jnp.sum(jnp.asarray(leaf, jnp.float32)))
+        elif "moe_max_expert_load" in keys:
+            worst.append(jnp.max(jnp.asarray(leaf, jnp.float32)))
+        elif "moe_expert_rows" in keys:
+            by_expert.append(jnp.asarray(leaf, jnp.float32).reshape(-1, leaf.shape[-1]))
+    if not rows:
+        return {}
+    return {MOE_ROWS_HELD: sum(rows), MOE_MAX_EXPERT_LOAD: jnp.max(jnp.stack(worst)),
+            _EXPERT_ROWS: jnp.concatenate(by_expert)}
+
+
+def _merge_counters(a: dict, b: dict) -> dict:
+    """Two microbatches' counters as one step's: rows add, the load is the worst."""
+    return {k: jnp.maximum(a[k], b[k]) if k == MOE_MAX_EXPERT_LOAD else a[k] + b[k]
+            for k in a}
+
+
 def _apply_collecting_aux(model: MPTModel, params, tokens, **kwargs):
     """``model.apply`` that also returns the summed MoE aux loss (0.0 for
-    dense models). The MoE blocks sow per-layer Switch load-balance terms
-    into ``intermediates`` (``models/mpt.py``); plain inference applies
-    leave the collection immutable, so sow is a no-op there."""
+    dense models and for the dropless router, which has none) and the
+    dropless layers' counters. The MoE blocks sow per-layer terms into
+    ``intermediates`` (``models/mpt.py``); plain inference applies leave the
+    collection immutable, so sow is a no-op there."""
     if model.cfg.mlp != "moe":
-        return model.apply({"params": params}, tokens, **kwargs), jnp.zeros([], jnp.float32)
+        return (model.apply({"params": params}, tokens, **kwargs),
+                jnp.zeros([], jnp.float32), {})
     out, variables = model.apply(
         {"params": params}, tokens, mutable=["intermediates"], **kwargs
     )
-    aux = collect_moe_aux(variables.get("intermediates", {}))
-    return out, model.cfg.moe_aux_weight * aux
+    sown = variables.get("intermediates", {})
+    return (out, model.cfg.moe_aux_weight * collect_moe_aux(sown),
+            collect_moe_counters(sown))
 
 
-def make_loss_fn(model: MPTModel, loss_chunk_tokens: int = 2048) -> Callable:
+def _make_loss_and_counters_fn(model: MPTModel, loss_chunk_tokens: int) -> Callable:
     def loss_fn(params, tokens: jax.Array):
-        """Mean next-token cross entropy over ``[B, S] int32`` tokens
-        (+ the weighted MoE load-balance aux loss when mlp='moe')."""
+        """``(loss, counters)``: mean next-token cross entropy over
+        ``[B, S] int32`` tokens (+ the weighted MoE load-balance aux loss of
+        the capacity router), and the dropless layers' counters."""
         if loss_chunk_tokens:
-            hidden, aux = _apply_collecting_aux(
+            hidden, aux, counters = _apply_collecting_aux(
                 model, params, tokens, return_hidden=True
             )
             with jax.named_scope(LOSS_HEAD_SCOPE):
                 ce_sum = _chunked_ce_sum(
                     model, params, hidden[:, :-1], tokens[:, 1:], loss_chunk_tokens
                 )
-            return ce_sum / (tokens.shape[0] * (tokens.shape[1] - 1)) + aux
-        logits, aux = _apply_collecting_aux(model, params, tokens)
+            return ce_sum / (tokens.shape[0] * (tokens.shape[1] - 1)) + aux, counters
+        logits, aux, counters = _apply_collecting_aux(model, params, tokens)
         targets = tokens[:, 1:]
         logits = logits[:, :-1]
         ce = optax.softmax_cross_entropy_with_integer_labels(
             logits.astype(jnp.float32), targets
         )
-        return jnp.mean(ce) + aux
+        return jnp.mean(ce) + aux, counters
 
     return loss_fn
+
+
+def make_loss_fn(model: MPTModel, loss_chunk_tokens: int = 2048) -> Callable:
+    """``(params, tokens) -> loss`` (the step's own loss without its counters)."""
+    fn = _make_loss_and_counters_fn(model, loss_chunk_tokens)
+    return lambda params, tokens: fn(params, tokens)[0]
+
+
+def _balance_router_bias(params, expert_rows: jax.Array, speed: float):
+    """The expert stack's selection bias (``router_bias [layers, E]``, which
+    the optimizer leaves alone: it has no gradient) moved one step against
+    the loads this step routed."""
+    from photon_tpu.ops.moe import balanced_router_bias
+
+    def move(path, leaf):
+        if getattr(path[-1], "key", None) != "router_bias":
+            return leaf
+        return balanced_router_bias(leaf, expert_rows, speed).astype(leaf.dtype)
+
+    return jax.tree_util.tree_map_with_path(move, params)
 
 
 def make_train_step(
@@ -151,8 +208,8 @@ def make_train_step(
     analog of the reference's ``device_train_microbatch_size`` grad
     accumulation (``conf/llm_config/mpt-125m.yaml:80-81``).
     """
-    loss_fn = make_loss_fn(model, loss_chunk_tokens)
-    grad_fn = jax.value_and_grad(loss_fn)
+    grad_fn = jax.value_and_grad(
+        _make_loss_and_counters_fn(model, loss_chunk_tokens), has_aux=True)
 
     def forward_backward(state: TrainState, tokens: jax.Array):
         if n_microbatches > 1:
@@ -162,30 +219,47 @@ def make_train_step(
             micro = tokens.reshape(n_microbatches, b // n_microbatches, tokens.shape[1])
 
             def body(carry, mb):
-                loss_acc, grad_acc = carry
-                loss, grads = grad_fn(state.params, mb)
-                return (loss_acc + loss, jax.tree.map(jnp.add, grad_acc, grads)), None
+                loss_acc, grad_acc, counters_acc = carry
+                (loss, counters), grads = grad_fn(state.params, mb)
+                return (loss_acc + loss, jax.tree.map(jnp.add, grad_acc, grads),
+                        _merge_counters(counters_acc, counters)), None
 
             zero_grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            (loss_sum, grad_sum), _ = jax.lax.scan(body, (jnp.zeros([], jnp.float32), zero_grads), micro)
+            zero_counters = {}
+            if model.cfg.dropless_moe:
+                zero_counters = {
+                    MOE_ROWS_HELD: jnp.zeros([], jnp.float32),
+                    MOE_MAX_EXPERT_LOAD: jnp.zeros([], jnp.float32),
+                    _EXPERT_ROWS: jnp.zeros(
+                        (model.cfg.n_layers - model.cfg.first_k_dense,
+                         model.cfg.moe_num_experts), jnp.float32),
+                }
+            (loss_sum, grad_sum, counters), _ = jax.lax.scan(
+                body, (jnp.zeros([], jnp.float32), zero_grads, zero_counters), micro)
             loss = loss_sum / n_microbatches
             grads = jax.tree.map(lambda g: g / n_microbatches, grad_sum)
         else:
-            loss, grads = grad_fn(state.params, tokens)
-        return loss, grads
+            (loss, counters), grads = grad_fn(state.params, tokens)
+        return loss, grads, counters
 
     def train_step(state: TrainState, tokens: jax.Array):
         with jax.named_scope(FORWARD_BACKWARD_SCOPE):
-            loss, grads = forward_backward(state, tokens)
+            loss, grads, counters = forward_backward(state, tokens)
         grad_norm = optax.global_norm(grads)
         with jax.named_scope(OPTIMIZER_SCOPE):
             updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
+            counters = dict(counters)
+            expert_rows = counters.pop(_EXPERT_ROWS, None)
+            if expert_rows is not None and model.cfg.moe_bias_update_speed:
+                new_params = _balance_router_bias(
+                    new_params, expert_rows, model.cfg.moe_bias_update_speed)
         new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt_state)
         metrics = {
             "loss": loss,
             "grad_norm": grad_norm,
             "param_norm": optax.global_norm(new_params),
+            **counters,
         }
         return new_state, metrics
 

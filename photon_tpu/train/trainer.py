@@ -46,7 +46,10 @@ from photon_tpu.utils.profiling import (
     CLIENT_STEPS,
     CLIENT_TOKENS_PER_SEC,
     EVENT_SPEED_MONITOR_PEAK,
+    MOE_MAX_EXPERT_LOAD,
+    MOE_ROWS_HELD,
     TRAINER_FENCE_SPAN,
+    TRAINER_MOE_LOAD_SPAN,
     TRAINER_GET_PARAMETERS_SPAN,
     TRAINER_NEXT_BATCH_SPAN,
     TRAINER_SET_PARAMETERS_SPAN,
@@ -441,6 +444,14 @@ class Trainer:
             jax.block_until_ready(self.state)
             if duration_steps:
                 log(duration_steps - 1, metrics)
+                if MOE_ROWS_HELD in last_metrics:
+                    # the last step's routing counters, where a trace's
+                    # reader finds them (they came with the loss: no new sync)
+                    with telemetry.span(
+                            TRAINER_MOE_LOAD_SPAN,
+                            rows_held=last_metrics[MOE_ROWS_HELD],
+                            max_expert_load=last_metrics[MOE_MAX_EXPERT_LOAD]):
+                        pass
         dt = time.monotonic() - t0
         return {
             **last_metrics,

@@ -181,6 +181,16 @@ TRAINER_GET_PARAMETERS_SPAN = "trainer/get_parameters"
 TRAINER_STEPS_SPAN = "trainer/steps"
 TRAINER_NEXT_BATCH_SPAN = "trainer/next_batch"
 TRAINER_FENCE_SPAN = "trainer/fence"
+#: opened and closed inside the fence once the last step's metrics are on the
+#: host, only when the step has dropless expert layers: attrs ``rows_held``
+#: and ``max_expert_load`` (the two counters below), for a trace's reader
+TRAINER_MOE_LOAD_SPAN = "trainer/moe_load"
+# -- dropless expert layers (ops/moe.py): counters in the train step's
+# metrics, fetched with the loss at the trainer's fence -------------------
+#: assignments routed to the experts held here, summed over the layers
+MOE_ROWS_HELD = "moe/rows_held"
+#: the busiest held expert's rows over the held experts' mean, worst layer
+MOE_MAX_EXPERT_LOAD = "moe/max_expert_load"
 
 # -- transport-leg span names (federation/tcp.py; spans only, never KPIs) --
 TCP_SEND_SPAN = "tcp/send"
@@ -675,16 +685,35 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     """Training FLOPs/token ≈ 6·N_nonemb + 12·L·d·s (attention) + 6·d·V
     (lm_head, tied or not). Matches the estimate used for BASELINE
     vs_baseline; honors the llama-family knobs (``mlp_hidden_size``
-    override, SwiGLU's third projection)."""
+    override, SwiGLU's third projection). Latent attention counts its
+    low-rank pairs and the causal half of its 256-wide heads; leading dense
+    blocks their own width; the dropless expert layer its router, shared
+    experts and the routed experts at this chip's expected share (``top_k *
+    held / routed`` experts a token): what the step computes here, not what
+    the whole model would."""
     d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
     hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * d
-    # gelu: up+down = 2·d·F weights; swiglu adds the gate = 3·d·F
-    mlp_w = (3 if cfg.mlp == "swiglu" else 2) * d * hidden
-    # GQA shrinks the kv projections: q + 2·kv groups + out_proj
-    n_kv = cfg.n_kv_heads or cfg.n_heads
-    attn_w = d * (cfg.n_heads + 2 * n_kv) * cfg.d_head + d * d
-    n_block = L * (attn_w + mlp_w)
-    attn = 12 * L * d * s  # score + value matmuls, fwd+bwd
+    if cfg.dropless_moe:
+        experts = cfg.moe_top_k * cfg.experts_held / cfg.moe_num_experts
+        mlp_w = (experts + cfg.moe_shared_experts) * 3 * d * hidden + d * cfg.moe_num_experts
+    else:
+        # gelu: up+down = 2·d·F weights; swiglu adds the gate = 3·d·F
+        mlp_w = (3 if cfg.mlp == "swiglu" else 2) * d * hidden
+    if cfg.latent_attention:
+        qk, dv = cfg.d_head, cfg.v_head_dim
+        attn_w = (d * cfg.q_lora_rank + cfg.q_lora_rank * cfg.n_heads * qk
+                  + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                  + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_head_dim + dv)
+                  + cfg.n_heads * dv * d)
+        attn = 6 * L * s * cfg.n_heads * (qk + dv) / 2  # causal half, fwd+bwd
+    else:
+        # GQA shrinks the kv projections: q + 2·kv groups + out_proj
+        n_kv = cfg.n_kv_heads or cfg.n_heads
+        attn_w = d * (cfg.n_heads + 2 * n_kv) * cfg.d_head + d * d
+        attn = 12 * L * d * s  # score + value matmuls, fwd+bwd
+    n_dense = cfg.first_k_dense  # leading SwiGLU blocks of their own width
+    n_block = (L * attn_w + n_dense * 3 * d * cfg.dense_mlp_hidden_size
+               + (L - n_dense) * mlp_w)
     head = 6 * d * v
     return 6.0 * n_block + attn + head
 

@@ -259,6 +259,49 @@ def test_engine_mixed_step_compiles(one_chip):
 
 
 # ---------------------------------------------------------------------------
+# glm-4.7-flash-ep8: latent attention's head shapes, the grouped products
+# ---------------------------------------------------------------------------
+
+
+def test_flash_attention_compiles_at_latent_attention_shapes(one_chip):
+    """20 heads of 256 at 4,096 tokens, 4 rows (the ``glm47flash-train``
+    cell), forward and backward at the tiles ``pick_tiles`` derives."""
+    from photon_tpu.ops.flash_attention import flash_attention
+
+    qkv = _abstract((4, 4096, 20, 256), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    assert _hlo(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv).count(KERNEL) >= 3
+
+
+def test_grouped_expert_products_compile(one_chip, monkeypatch):
+    """The dropless layer's three grouped products at the cell's widths
+    (65,536 static rows of 2,048, 8 experts of 1,536), forward and backward:
+    megablox's kernel, its transposed form and the weight-gradient kernel at
+    ``ops/moe.GMM_TILING``."""
+    import photon_tpu.ops.flash_attention as fa
+    from photon_tpu.ops import moe
+
+    monkeypatch.setattr(fa, "pallas_supported", lambda x: True)
+    rows = _abstract((65536, 2048), jnp.bfloat16, one_chip)
+    w_in = _abstract((8, 2048, 1536), jnp.bfloat16, one_chip)
+    w_out = _abstract((8, 1536, 2048), jnp.bfloat16, one_chip)
+    sizes = _abstract((9,), jnp.int32, one_chip)
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        mm = lambda a, b: moe.grouped_matmul(a, b, sizes)  # noqa: E731
+        out = mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2, 3)), rows, w_in, w_in, w_out, sizes)
+    # gate and up forward (the down product's output is dead under a sum),
+    # and for each of the three a transposed product and a weight gradient
+    assert hlo.count(KERNEL) >= 8
+
+
+# ---------------------------------------------------------------------------
 # whole train steps
 # ---------------------------------------------------------------------------
 
