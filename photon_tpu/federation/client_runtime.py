@@ -20,6 +20,7 @@ Role parity with the reference's Worker + client train entry
 
 from __future__ import annotations
 
+import math
 import pathlib
 import time
 import zlib
@@ -41,6 +42,7 @@ from photon_tpu.data import LoaderState, ShardedDataset, StreamingLoader, make_s
 from photon_tpu.federation.configs import EvaluateRoundConfig, FitRoundConfig
 from photon_tpu.federation.messages import ClientState, EvaluateIns, EvaluateRes, FitIns, FitRes
 from photon_tpu.federation.transport import ParamTransport
+from photon_tpu.strategy.aggregation import diff_sumsq
 from photon_tpu.train.trainer import Trainer
 from photon_tpu.utils.profiling import (
     CLIENT_ENCODE_SPAN,
@@ -56,10 +58,6 @@ from photon_tpu.utils.profiling import (
     CLIENT_SKIPPED_ROUND,
     CLIENT_TRAIN_SPAN,
 )
-
-
-def _l2(arrays: list[np.ndarray]) -> float:
-    return float(np.sqrt(sum(float(np.sum(np.square(a, dtype=np.float64))) for a in arrays)))
 
 
 class ClientRuntime:
@@ -287,12 +285,12 @@ class ClientRuntime:
 
         # pseudo-gradient telemetry (reference: ``post_process_client_result``
         # L2 norms, ``clients/utils.py:599-619``)
+        pool = self.transport.host_pool
         with telemetry.span(CLIENT_PSEUDO_GRAD_NORM_SPAN, cid=cid,
-                            round=ins.server_round):
-            delta = [o - i for o, i in zip(out_arrays, initial)]
-            fit_metrics[CLIENT_PSEUDO_GRAD_NORM] = _l2(delta)
-            fit_metrics[CLIENT_PARAM_NORM] = _l2(out_arrays)
-            del delta
+                            round=ins.server_round, threads=pool.threads):
+            delta_sq, out_sq = diff_sumsq(out_arrays, initial, pool)
+            fit_metrics[CLIENT_PSEUDO_GRAD_NORM] = math.sqrt(delta_sq)
+            fit_metrics[CLIENT_PARAM_NORM] = math.sqrt(out_sq)
 
         if knobs.personalize_patterns:
             self._personal[cid] = [a.copy() for a in out_arrays]

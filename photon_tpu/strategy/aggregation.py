@@ -26,7 +26,8 @@ the averaged result is BIT-IDENTICAL across configurations.
 
 from __future__ import annotations
 
-from typing import Callable, Iterable, Iterator
+import math
+from typing import Callable, Iterable, Iterator, Sequence
 
 import numpy as np
 
@@ -61,6 +62,76 @@ def _fold_into(acc: np.ndarray, y: np.ndarray, w_prev: float, w_cur: float) -> N
         t *= w_cur
         a += t
         del t  # else two chunk temps coexist across the loop boundary
+
+
+def flat_views(arrays: Iterable[np.ndarray]) -> list[np.ndarray]:
+    """1-d views for reading (a non-contiguous array is copied, so never
+    write through these)."""
+    return [np.asarray(a).reshape(-1) for a in arrays]
+
+
+def map_chunks(fn: Callable[[int, slice], object], sizes: Sequence[int],
+               pool: HostPool) -> list:
+    """``fn(i, sl)`` for every ``_FOLD_CHUNK``-element chunk ``sl`` of every
+    array ``i`` (``sizes`` are element counts), over ``pool`` as the fold
+    is: inline with ``threads == 1``. Results come back in (array, chunk)
+    order whatever ran where, so a sum over them does not depend on
+    scheduling. A chunk and not an array is the unit of work: the embedding
+    is a third of mpt-125m and would be one worker's task."""
+    return pool.map(lambda piece: fn(*piece), [
+        (i, slice(off, off + _FOLD_CHUNK))
+        for i, n in enumerate(sizes) for off in range(0, n, _FOLD_CHUNK)])
+
+
+def chunk_buffers(pool: HostPool, dtype, count: int) -> list[np.ndarray]:
+    """``count`` chunk-sized scratch arrays of ``dtype`` that belong to the
+    calling thread and to ``pool``, and outlive the call: a pass over the
+    model then maps no fresh pages for its temporaries (first touch of
+    fresh pages, not arithmetic, was the cost of the whole-model
+    expressions this replaces)."""
+    held = pool.scratch.__dict__.setdefault(np.dtype(dtype).str, [])
+    while len(held) < count:
+        held.append(np.empty(_FOLD_CHUNK, dtype))
+    return held[:count]
+
+
+def _sumsq_chunk(pool: HostPool, chunk: np.ndarray) -> float:
+    """Sum of squares of one chunk, accumulated in float64."""
+    (wide,) = chunk_buffers(pool, np.float64, 1)
+    # not np.dot: BLAS threads of its own under the pool's cost 5x here
+    return float(np.sum(np.square(chunk, out=wide[:chunk.size], dtype=np.float64)))
+
+
+def sumsq(arrays: Iterable[np.ndarray], pool: HostPool | None = None) -> float:
+    """Float64 sum of squares over a list of arrays, chunk by chunk: no
+    float64 copy of an array ever exists. No ``pool`` is an inline one."""
+    pool = pool or HostPool(1)
+    flats = flat_views(arrays)
+    return math.fsum(map_chunks(
+        lambda i, sl: _sumsq_chunk(pool, flats[i][sl]), [f.size for f in flats], pool))
+
+
+def diff_sumsq(xs: Sequence[np.ndarray], ys: Sequence[np.ndarray],
+               pool: HostPool | None = None) -> tuple[float, float]:
+    """``(sum((x - y)**2), sum(x**2))`` in one pass over both lists: the
+    difference in the arrays' own precision, as ``x - y`` gives it, and both
+    sums in float64. The whole difference is never held. Used for the
+    server's pseudo-gradient and parameter norms (``x`` the global weights,
+    ``y`` the average) and for the client's (``x`` what it trained, ``y``
+    what it was sent)."""
+    if len(xs) != len(ys):
+        raise ValueError(f"{len(xs)} arrays against {len(ys)}")
+    pool = pool or HostPool(1)
+    fx, fy = flat_views(xs), flat_views(ys)
+
+    def one(i: int, sl: slice) -> tuple[float, float]:
+        x, y = fx[i][sl], fy[i][sl]
+        (d,) = chunk_buffers(pool, np.result_type(x, y), 1)
+        return (_sumsq_chunk(pool, np.subtract(x, y, out=d[:x.size])),
+                _sumsq_chunk(pool, x))
+
+    parts = map_chunks(one, [f.size for f in fx], pool)
+    return math.fsum(p[0] for p in parts), math.fsum(p[1] for p in parts)
 
 
 def aggregate_inplace(

@@ -16,12 +16,22 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from collections.abc import Sequence
 from typing import Any, Iterable
 
 import numpy as np
 
 from photon_tpu import telemetry as _telemetry  # __init__ has a `telemetry` flag
-from photon_tpu.strategy.aggregation import aggregate_inplace, weighted_average_metrics
+from photon_tpu.strategy.aggregation import (
+    aggregate_inplace,
+    chunk_buffers,
+    diff_sumsq,
+    flat_views,
+    map_chunks,
+    sumsq,
+    weighted_average_metrics,
+)
+from photon_tpu.utils.hostpool import HostPool
 from photon_tpu.utils.profiling import (
     AGG_DECODE_TIME,
     AGG_FOLD_TIME,
@@ -50,8 +60,27 @@ class ClientResult:
     metrics: dict[str, float] = dataclasses.field(default_factory=dict)
 
 
-def l2_norm(arrays: Iterable[np.ndarray]) -> float:
-    return math.sqrt(sum(float(np.sum(np.square(a, dtype=np.float64))) for a in arrays))
+def l2_norm(arrays: Iterable[np.ndarray], pool=None) -> float:
+    return math.sqrt(sumsq(arrays, pool))
+
+
+class PseudoGrad(Sequence):
+    """The pseudo-gradient ``x - avg`` as :meth:`Strategy.apply_average`
+    hands it to :meth:`Strategy.server_update`: a sequence of arrays that
+    holds only its two operands. The rules and the norms take the
+    difference chunk by chunk, so a model-sized array of it never exists;
+    indexing gives one array's difference whole, for code that wants plain
+    arrays."""
+
+    def __init__(self, params: list[np.ndarray], avg: list[np.ndarray]) -> None:
+        self.params = params
+        self.avg = avg
+
+    def __len__(self) -> int:
+        return len(self.params)
+
+    def __getitem__(self, i: int) -> np.ndarray:
+        return self.params[i] - self.avg[i]
 
 
 class Strategy:
@@ -155,7 +184,15 @@ class Strategy:
         (``photon_tpu/federation/collective_round.py``), where the weighted
         average arrives from a DCN/ICI psum instead of ``aggregate_inplace``
         — every controller applies this identical deterministic update to its
-        strategy replica."""
+        strategy replica.
+
+        ``avg`` is only read (callers pass read-only views of device arrays).
+        Nothing reachable before the call is written: the new parameters and
+        the new optimizer state are fresh arrays that ``current_parameters``
+        and ``state`` are rebound to, so a checkpoint writer or a pinned
+        broadcast that still holds the old ones sees them unchanged. Those
+        are the call's only model-sized allocations: pseudo-gradient, rule
+        and norms run chunk by chunk over ``host_pool``."""
         if self.current_parameters is None:
             raise RuntimeError("strategy not initialized with parameters")
         if len(avg) != len(self.current_parameters):
@@ -168,8 +205,9 @@ class Strategy:
                 "aggregate_momenta is on)"
             )
         self.server_round = server_round
-        with _telemetry.span(SERVER_UPDATE_SPAN, round=server_round):
-            pseudo_grad = [x - a for x, a in zip(self.current_parameters, avg)]
+        with _telemetry.span(SERVER_UPDATE_SPAN, round=server_round,
+                             threads=self.host_pool.threads if self.host_pool else 1):
+            pseudo_grad = PseudoGrad(self.current_parameters, avg)
             lr = self.effective_lr(n_clients)
             new_params = self.server_update(pseudo_grad, lr)
 
@@ -197,19 +235,62 @@ class Strategy:
         return loss, metrics
 
     # ------------------------------------------------------------------
-    def server_update(self, pseudo_grad: list[np.ndarray], lr: float) -> list[np.ndarray]:
+    def server_update(self, pseudo_grad: Sequence[np.ndarray], lr: float) -> list[np.ndarray]:
+        """The rule over the whole model: new parameters from
+        ``current_parameters``, the pseudo-gradient (a :class:`PseudoGrad` or
+        plain arrays) and the state named by ``state_keys``, which is rebound
+        to its new value. One pass of :meth:`_rule` over every chunk of every
+        array, spread over ``host_pool``; the chunks are disjoint and each
+        is written once, so the result is the same on any number of
+        threads."""
+        assert self.current_parameters is not None
+        params = self.current_parameters
+        if len(pseudo_grad) != len(params):
+            raise ValueError(f"{len(pseudo_grad)} pseudo-gradient arrays, {len(params)} parameters")
+        pool = self.host_pool or HostPool(1)
+        lazy = isinstance(pseudo_grad, PseudoGrad)
+        x = flat_views(params)
+        g = flat_views(pseudo_grad.avg if lazy else pseudo_grad)
+        old = [flat_views(self.state[k]) for k in self.state_keys]
+        # C order, whatever the old array's: reshape(-1) below must be a view
+        new_params = [np.empty(p.shape, p.dtype) for p in params]
+        new_state = [[np.empty(a.shape, a.dtype) for a in self.state[k]]
+                     for k in self.state_keys]
+        new_x = [a.reshape(-1) for a in new_params]
+        new = [[a.reshape(-1) for a in tensors] for tensors in new_state]
+
+        def one(i: int, sl: slice) -> None:
+            xc, gc = x[i][sl], g[i][sl]
+            diff, *tmp = (b[:xc.size] for b in chunk_buffers(pool, xc.dtype, 3))
+            self._rule(
+                xc, np.subtract(xc, gc, out=diff) if lazy else gc,
+                [s[i][sl] for s in old], lr,
+                new_x[i][sl], [s[i][sl] for s in new], tmp,
+            )
+
+        map_chunks(one, [f.size for f in x], pool)
+        self.state.update(zip(self.state_keys, new_state))
+        return new_params
+
+    def _rule(self, x, g, state, lr, new_x, new_state, tmp) -> None:
+        """The rule on one chunk: from parameters ``x``, pseudo-gradient
+        ``g`` and the ``state`` chunks (in ``state_keys`` order), all only
+        read, write ``new_x`` and ``new_state``. ``tmp`` is two scratch
+        chunks. Float32 operations in the order the rule is written in the
+        module docstring of ``optimizers``: the goldens and the device
+        plane's port are held to the bits."""
         raise NotImplementedError
 
-    def norm_telemetry(self, pseudo_grad: list[np.ndarray]) -> dict[str, float]:
+    def norm_telemetry(self, pseudo_grad: PseudoGrad) -> dict[str, float]:
         """Global L2 norms of pseudo-grad / params / momenta (reference
         per-layer + global norms, ``fedadam.py:333-381``; per-layer norms are
-        computed on demand by callers to keep round metrics compact)."""
-        out = {
-            PSEUDO_GRAD_NORM: l2_norm(pseudo_grad),
-            PARAM_NORM: l2_norm(self.current_parameters or []),
-        }
+        computed on demand by callers to keep round metrics compact).
+        Pseudo-gradient and parameters (those the pseudo-gradient was taken
+        from) share one pass."""
+        g2, x2 = diff_sumsq(pseudo_grad.params, pseudo_grad.avg, self.host_pool)
+        out = {PSEUDO_GRAD_NORM: math.sqrt(g2), PARAM_NORM: math.sqrt(x2)}
         for key, tensors in self.state.items():
-            out[f"server/{key}_norm"] = l2_norm(tensors)
+            out[f"server/{key}_norm"] = l2_norm(tensors, self.host_pool)
         return out
 
     def per_layer_norms(self, names: list[str], arrays: list[np.ndarray], prefix: str) -> dict[str, float]:

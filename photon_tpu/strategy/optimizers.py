@@ -1,7 +1,10 @@
 """Server-side optimizers on the pseudo-gradient, layer by layer.
 
-Update rules match the reference strategies (all operate in-place over the
-flat ndarray list; ``g`` is the pseudo-gradient ``x - avg``):
+Update rules match the reference strategies (``g`` is the pseudo-gradient
+``x - avg``). Each class writes its rule once, for one chunk of one array
+(``_rule``: numpy calls with ``out=``, in the order of the formula, so that
+the float32 result is the formula's to the bit);
+``Strategy.server_update`` runs it over every chunk of the model:
 
 - FedAvgEff   (``fedavg_eff.py:291-330``):   x ← x − η·g
 - FedNesterov (``fednestorov.py:323-331``):  m ← μm + g;  x ← x − η·(g + μm)
@@ -43,9 +46,10 @@ class FedAvgEff(Strategy):
 
     name = "fedavg"
 
-    def server_update(self, pseudo_grad, lr):
-        assert self.current_parameters is not None
-        return [x - lr * g for x, g in zip(self.current_parameters, pseudo_grad)]
+    def _rule(self, x, g, state, lr, new_x, new_state, tmp):
+        t, _ = tmp
+        np.multiply(g, lr, out=t)
+        np.subtract(x, t, out=new_x)
 
 
 class FedNesterov(Strategy):
@@ -55,15 +59,14 @@ class FedNesterov(Strategy):
     name = "nesterov"
     state_keys = ("momentum",)
 
-    def server_update(self, pseudo_grad, lr):
-        assert self.current_parameters is not None
-        m = self.state["momentum"]
-        out = []
-        for i, (x, g) in enumerate(zip(self.current_parameters, pseudo_grad)):
-            m[i] = self.momentum * m[i] + g
-            step = g + self.momentum * m[i]
-            out.append(x - lr * step)
-        return out
+    def _rule(self, x, g, state, lr, new_x, new_state, tmp):
+        (m,), (m_new,), (t, _) = state, new_state, tmp
+        np.multiply(m, self.momentum, out=m_new)
+        m_new += g
+        np.multiply(m_new, self.momentum, out=t)
+        np.add(g, t, out=t)
+        t *= lr
+        np.subtract(x, t, out=new_x)
 
 
 class FedMom(Strategy):
@@ -72,14 +75,12 @@ class FedMom(Strategy):
     name = "fedmom"
     state_keys = ("momentum",)
 
-    def server_update(self, pseudo_grad, lr):
-        assert self.current_parameters is not None
-        m = self.state["momentum"]
-        out = []
-        for i, (x, g) in enumerate(zip(self.current_parameters, pseudo_grad)):
-            m[i] = self.momentum * m[i] + g
-            out.append(x - lr * m[i])
-        return out
+    def _rule(self, x, g, state, lr, new_x, new_state, tmp):
+        (m,), (m_new,), (t, _) = state, new_state, tmp
+        np.multiply(m, self.momentum, out=m_new)
+        m_new += g
+        np.multiply(m_new, lr, out=t)
+        np.subtract(x, t, out=new_x)
 
 
 class _AdaptiveBase(Strategy):
@@ -99,24 +100,27 @@ class _AdaptiveBase(Strategy):
         self.tau = server_tau
         self._t = 0
 
-    def _second_moment(self, v: np.ndarray, g: np.ndarray) -> np.ndarray:
+    def _second_moment(self, v, g, v_new, tmp) -> None:
+        """Write the new second moment of one chunk into ``v_new``."""
         raise NotImplementedError
 
     def server_update(self, pseudo_grad, lr):
-        assert self.current_parameters is not None
         self._t += 1
-        b1t = 1.0 - self.beta_1**self._t
-        b2t = 1.0 - self.beta_2**self._t
-        m1 = self.state["momentum_1"]
-        m2 = self.state["momentum_2"]
-        out = []
-        for i, (x, g) in enumerate(zip(self.current_parameters, pseudo_grad)):
-            m1[i] = self.beta_1 * m1[i] + (1.0 - self.beta_1) * g
-            m2[i] = self._second_moment(m2[i], g)
-            m_hat = m1[i] / b1t
-            v_hat = m2[i] / b2t
-            out.append(x - lr * m_hat / (np.sqrt(v_hat) + self.tau))
-        return out
+        return super().server_update(pseudo_grad, lr)
+
+    def _rule(self, x, g, state, lr, new_x, new_state, tmp):
+        (m, v), (m_new, v_new), (t, u) = state, new_state, tmp
+        np.multiply(m, self.beta_1, out=m_new)
+        np.multiply(g, 1.0 - self.beta_1, out=t)
+        m_new += t
+        self._second_moment(v, g, v_new, tmp)
+        np.divide(m_new, 1.0 - self.beta_1**self._t, out=t)  # bias-corrected
+        np.divide(v_new, 1.0 - self.beta_2**self._t, out=u)
+        np.sqrt(u, out=u)
+        u += self.tau
+        t *= lr
+        t /= u
+        np.subtract(x, t, out=new_x)
 
     # step counter must survive resume (bias correction continuity; the
     # reference persists it via strategy state_keys round indexing)
@@ -146,13 +150,22 @@ class _AdaptiveBase(Strategy):
 class FedAdam(_AdaptiveBase):
     name = "fedadam"
 
-    def _second_moment(self, v, g):
-        return self.beta_2 * v + (1.0 - self.beta_2) * np.square(g)
+    def _second_moment(self, v, g, v_new, tmp):
+        t, _ = tmp
+        np.multiply(v, self.beta_2, out=v_new)
+        np.square(g, out=t)
+        t *= 1.0 - self.beta_2
+        v_new += t
 
 
 class FedYogi(_AdaptiveBase):
     name = "fedyogi"
 
-    def _second_moment(self, v, g):
-        g2 = np.square(g)
-        return v - (1.0 - self.beta_2) * g2 * np.sign(v - g2)
+    def _second_moment(self, v, g, v_new, tmp):
+        g2, s = tmp
+        np.square(g, out=g2)
+        np.subtract(v, g2, out=s)
+        np.sign(s, out=s)
+        g2 *= 1.0 - self.beta_2
+        g2 *= s
+        np.subtract(v, g2, out=v_new)

@@ -89,6 +89,10 @@ class HostPool:
         self.threads = resolve_host_threads(threads)
         self._ex: concurrent.futures.ThreadPoolExecutor | None = None
         self._lock = threading.Lock()
+        #: per-thread scratch arrays of the chunked passes that run on this
+        #: pool (``strategy/aggregation.chunk_buffers``); they live as long
+        #: as the pool, so a pass maps no fresh pages for its temporaries
+        self.scratch = threading.local()
 
     @property
     def pipelined(self) -> bool:

@@ -89,7 +89,7 @@ SPAN_TABLE = [
     (P.NODE_SET_BROADCAST_SPAN, {P.BROADCAST_PRE_TIME}, {"round", "node"}),
     (P.TRAINER_SET_PARAMETERS_SPAN, {P.CLIENT_FIT_SPAN}, {"nbytes"}),
     (P.TRAINER_GET_PARAMETERS_SPAN, {P.CLIENT_FIT_SPAN}, set()),
-    (P.CLIENT_PSEUDO_GRAD_NORM_SPAN, {P.CLIENT_FIT_SPAN}, {"round", "cid"}),
+    (P.CLIENT_PSEUDO_GRAD_NORM_SPAN, {P.CLIENT_FIT_SPAN}, {"round", "cid", "threads"}),
     (P.CLIENT_TRAIN_SPAN, {P.CLIENT_FIT_SPAN}, {"round", "cid"}),
     (P.TRAINER_STEPS_SPAN, {P.CLIENT_TRAIN_SPAN}, {"steps"}),
     (P.TRAINER_NEXT_BATCH_SPAN, {P.TRAINER_STEPS_SPAN}, set()),
@@ -97,7 +97,7 @@ SPAN_TABLE = [
     (P.CLIENT_FIT_SPAN, {P.FIT_ROUND_TIME, None}, {"round", "cid"}),
     (P.AGG_DECODE_TIME, {P.FIT_ROUND_TIME, None}, {"client_index"}),
     (P.AGG_FOLD_TIME, {P.FIT_ROUND_TIME}, set()),
-    (P.SERVER_UPDATE_SPAN, {P.FIT_ROUND_TIME}, {"round"}),
+    (P.SERVER_UPDATE_SPAN, {P.FIT_ROUND_TIME}, {"round", "threads"}),
     (P.FIT_ROUND_TIME, {P.ROUND_SPAN}, {"round"}),
     (P.BROADCAST_PRE_TIME, {P.ROUND_SPAN}, {"round"}),
     (P.CHECKPOINT_TIME, {P.ROUND_SPAN}, {"round"}),

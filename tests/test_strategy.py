@@ -1,6 +1,10 @@
 """Golden-value tests for aggregation + server optimizers (SURVEY.md §7.5:
 "golden-value unit tests against hand-computed rounds")."""
 
+import math
+import sys
+import tracemalloc
+
 import numpy as np
 import pytest
 
@@ -16,7 +20,9 @@ from photon_tpu.strategy import (
     dispatch_strategy,
     weighted_loss_avg,
 )
+from photon_tpu.strategy import aggregation
 from photon_tpu.strategy.metrics import GradientNoiseScale
+from photon_tpu.utils.hostpool import HostPool
 
 
 def arrs(*vals):
@@ -271,3 +277,284 @@ def test_adaptive_descends_toward_client_average():
         for rnd in range(1, 6):
             v, _ = _round(s, [2.0, 2.0], rnd=rnd)
         assert abs(v - 2.0) < dist0, f"{cls.__name__} moved away from the client average"
+
+
+# ---------------------------------------------------------------------------
+# the chunked, pooled server update against the plain whole-array rules
+# ---------------------------------------------------------------------------
+
+CHUNK = 256
+HYPER = dict(server_learning_rate=0.7, server_momentum=0.9, server_beta_1=0.9,
+             server_beta_2=0.99, server_tau=1e-3)
+RULES = {"fedavg": FedAvgEff, "nesterov": FedNesterov, "fedmom": FedMom,
+         "fedadam": FedAdam, "fedyogi": FedYogi}
+
+
+def _traced_peak(fn):
+    """``fn()`` and the bytes tracemalloc saw allocated at its peak, numpy's
+    buffers included."""
+    tracemalloc.start()
+    try:
+        before, _ = tracemalloc.get_traced_memory()
+        tracemalloc.reset_peak()
+        out = fn()
+        return out, tracemalloc.get_traced_memory()[1] - before
+    finally:
+        tracemalloc.stop()
+
+
+def _plain_l2(arrays):
+    return math.sqrt(sum(float(np.sum(np.square(a, dtype=np.float64))) for a in arrays))
+
+
+class PlainRules:
+    """The five rules as whole-array numpy expressions, one temporary an
+    operation, as ``strategy/optimizers.py`` had them before the chunked
+    pass: the oracle that pass is held to, bit for bit."""
+
+    def __init__(self, name, params):
+        self.name, self.x, self.t = name, [p.copy() for p in params], 0
+        keys = {"fedavg": (), "nesterov": ("momentum",), "fedmom": ("momentum",)}.get(
+            name, ("momentum_1", "momentum_2"))
+        self.state = {k: [np.zeros_like(p) for p in params] for k in keys}
+
+    def apply_average(self, avg, lr):
+        mu, b1, b2, tau = (HYPER[k] for k in ("server_momentum", "server_beta_1",
+                                              "server_beta_2", "server_tau"))
+        g = [x - a for x, a in zip(self.x, avg)]
+        out = []
+        if self.name == "fedavg":
+            out = [x - lr * gi for x, gi in zip(self.x, g)]
+        elif self.name in ("nesterov", "fedmom"):
+            m = self.state["momentum"]
+            for i, (x, gi) in enumerate(zip(self.x, g)):
+                m[i] = mu * m[i] + gi
+                out.append(x - lr * (gi + mu * m[i] if self.name == "nesterov" else m[i]))
+        else:
+            self.t += 1
+            m1, m2 = self.state["momentum_1"], self.state["momentum_2"]
+            for i, (x, gi) in enumerate(zip(self.x, g)):
+                m1[i] = b1 * m1[i] + (1.0 - b1) * gi
+                g2 = np.square(gi)
+                if self.name == "fedadam":
+                    m2[i] = b2 * m2[i] + (1.0 - b2) * g2
+                else:
+                    m2[i] = m2[i] - (1.0 - b2) * g2 * np.sign(m2[i] - g2)
+                m_hat = m1[i] / (1.0 - b1 ** self.t)
+                v_hat = m2[i] / (1.0 - b2 ** self.t)
+                out.append(x - lr * m_hat / (np.sqrt(v_hat) + tau))
+        norms = {"server/pseudo_grad_norm": _plain_l2(g), "server/param_norm": _plain_l2(self.x)}
+        norms.update({f"server/{k}_norm": _plain_l2(v) for k, v in self.state.items()})
+        self.x = out
+        return norms
+
+
+def _model(rng, first_shape):
+    # the array under test, then a matrix of 1.5 chunks and a vector
+    return [rng.standard_normal(s).astype(np.float32) * np.float32(0.1)
+            for s in (first_shape, (24, 16), (7,))]
+
+
+@pytest.mark.parametrize("shape", [(1,), (CHUNK - 1,), (CHUNK,), (CHUNK + 1,),
+                                   (5, CHUNK // 2), ()],
+                         ids=["1", "chunk-1", "chunk", "chunk+1", "2.5chunks", "0-d"])
+@pytest.mark.parametrize("threads", [1, 4])
+@pytest.mark.parametrize("name", sorted(RULES))
+def test_chunked_update_matches_plain_rules(monkeypatch, name, threads, shape):
+    monkeypatch.setattr(aggregation, "_FOLD_CHUNK", CHUNK)
+    rng = np.random.default_rng(3)
+    init = _model(rng, shape)
+    s = RULES[name](**HYPER)
+    s.initialize([p.copy() for p in init])
+    s.host_pool = HostPool(threads)
+    plain = PlainRules(name, init)
+    try:
+        for rnd in (1, 2):
+            avg = [x - g for x, g in zip(plain.x, _model(rng, shape))]
+            got = s.apply_average(rnd, [a.copy() for a in avg], 10, 2)
+            want = plain.apply_average(avg, s.effective_lr(2))
+            for a, b in zip(s.current_parameters, plain.x):
+                assert a.shape == b.shape and a.dtype == b.dtype
+                np.testing.assert_array_equal(a, b)
+            assert set(s.state) == set(plain.state)
+            for key in plain.state:
+                for a, b in zip(s.state[key], plain.state[key]):
+                    np.testing.assert_array_equal(a, b)
+            for key, value in want.items():
+                assert got[key] == pytest.approx(value, rel=1e-12), key
+    finally:
+        s.host_pool.close()
+
+
+def _reachable(s, avg):
+    """Every array a caller could hold before ``apply_average``."""
+    return (list(s.current_parameters) + list(avg)
+            + [a for v in s.state.values() for a in v]
+            + [a for v in s.state_for_checkpoint().values() for a in v])
+
+
+@pytest.mark.parametrize("name", sorted(RULES))
+def test_apply_average_rebinds_and_mutates_nothing(name):
+    """The ``save_round_async`` contract: what a checkpoint writer or a
+    pinned broadcast was handed before the call reads the same after it."""
+    rng = np.random.default_rng(5)
+    s = RULES[name](**HYPER)
+    s.initialize(_model(rng, (40,)))
+    s.host_pool = HostPool(4)
+    s.apply_average(1, _model(rng, (40,)), 10, 2)  # state is no longer zeros
+    avg = _model(rng, (40,))
+    for a in avg:  # as np.asarray of a device array is
+        a.setflags(write=False)
+    params_list, state_lists = s.current_parameters, dict(s.state)
+    ckpt = s.state_for_checkpoint()
+    ckpt_members = {k: list(v) for k, v in ckpt.items()}
+    held = _reachable(s, avg)
+    copies = [a.copy() for a in held]
+    s.apply_average(2, avg, 10, 2)
+    s.host_pool.close()
+    for a, c in zip(held, copies):
+        np.testing.assert_array_equal(a, c)
+    assert all(ckpt[k][i] is a for k, v in ckpt_members.items() for i, a in enumerate(v))
+    assert s.current_parameters is not params_list
+    for key in s.state_keys:
+        assert s.state[key] is not state_lists[key]
+    fresh = list(s.current_parameters) + [a for k in s.state_keys for a in s.state[k]]
+    assert not any(np.shares_memory(n, o) for n in fresh for o in held)
+    assert all(n.flags.writeable for n in fresh)  # the q8 clamp writes them
+
+
+@pytest.mark.parametrize("name", sorted(RULES))
+def test_apply_average_peak_memory_is_its_outputs(monkeypatch, name):
+    """tracemalloc sees numpy's buffers: inside ``apply_average`` only the
+    new parameters and the new state tensors are model-sized (the
+    whole-array rules peaked at several models: a float32 pseudo-gradient,
+    one temporary an operation, a float64 copy a norm)."""
+    chunk = 1 << 16
+    monkeypatch.setattr(aggregation, "_FOLD_CHUNK", chunk)
+    rng = np.random.default_rng(7)
+    shapes = [(2048, 1024), (1024, 1024), (1 << 20,), (1024,)]  # 4.2 M elements
+    s = RULES[name](**HYPER)
+    s.initialize([rng.standard_normal(sh, dtype=np.float32) for sh in shapes])
+    avg = [rng.standard_normal(sh, dtype=np.float32) for sh in shapes]
+    model_bytes = sum(a.nbytes for a in avg)
+    s.host_pool = HostPool(4)
+    _, peak = _traced_peak(lambda: s.apply_average(1, avg, 10, 2))
+    s.host_pool.close()
+    assert peak <= (1 + len(s.state_keys)) * model_bytes + 16 * chunk * 8
+
+
+def test_diff_sumsq_reads_only_and_holds_no_array(monkeypatch):
+    chunk = 1 << 12
+    monkeypatch.setattr(aggregation, "_FOLD_CHUNK", chunk)
+    rng = np.random.default_rng(9)
+    xs = [rng.standard_normal(sh, dtype=np.float32) for sh in ((300, 1000), (chunk,), ())]
+    ys = [rng.standard_normal(x.shape, dtype=np.float32) for x in xs]
+    for a in xs + ys:
+        a.setflags(write=False)
+    want = (sum(float(np.sum(np.square(x - y, dtype=np.float64))) for x, y in zip(xs, ys)),
+            sum(float(np.sum(np.square(x, dtype=np.float64))) for x in xs))
+    for pool in (None, HostPool(1), HostPool(4)):
+        got, peak = _traced_peak(lambda: aggregation.diff_sumsq(xs, ys, pool))
+        assert got == pytest.approx(want, rel=1e-12)
+        assert peak <= 16 * chunk * 8 < xs[0].nbytes
+        assert aggregation.sumsq(xs, pool) == pytest.approx(want[1], rel=1e-12)
+    with pytest.raises(ValueError):
+        aggregation.diff_sumsq(xs, ys[:-1])
+
+
+def test_server_update_takes_plain_arrays_and_is_the_seam():
+    """``server_update(pseudo_grad, lr)`` on plain arrays (the device plane's
+    oracle calls it so) equals the pass ``apply_average`` makes, and a
+    strategy whose ``server_update`` is replaced uses the replacement."""
+    rng = np.random.default_rng(11)
+    init, avg = _model(rng, (40,)), _model(rng, (40,))
+    a, b = (FedNesterov(**HYPER) for _ in range(2))
+    a.initialize([p.copy() for p in init])
+    b.initialize([p.copy() for p in init])
+    a.apply_average(1, avg, 10, 2)
+    b.current_parameters = b.server_update([x - y for x, y in zip(init, avg)], a.effective_lr(2))
+    for x, y in zip(a.current_parameters + a.state["momentum"],
+                    b.current_parameters + b.state["momentum"]):
+        np.testing.assert_array_equal(x, y)
+
+    class Kept(FedNesterov):
+        def server_update(self, pseudo_grad, lr):
+            assert len(pseudo_grad) == len(init)
+            np.testing.assert_array_equal(pseudo_grad[0], init[0] - avg[0])
+            return self.current_parameters
+
+    k = Kept(**HYPER)
+    k.initialize([p.copy() for p in init])
+    metrics = k.apply_average(1, avg, 10, 2)
+    np.testing.assert_array_equal(k.current_parameters[0], init[0])
+    assert metrics["server/pseudo_grad_norm"] == pytest.approx(
+        _plain_l2([x - y for x, y in zip(init, avg)]), rel=1e-12)
+
+
+def test_client_norm_pass_reads_only_and_holds_no_array(tmp_path, monkeypatch):
+    """The client's half of the same pass, inside a real ``fit``: both norms
+    equal the whole-array expressions', what the client was sent
+    (``initial``) and what it trained (``out_arrays``) are left as they were,
+    the pass goes over the transport's pool, and it holds no array."""
+    from photon_tpu.codec import params_to_ndarrays
+    from photon_tpu.federation import ParamTransport, client_runtime
+    from photon_tpu.federation.messages import FitIns
+    from tests.test_federation import make_cfg
+
+    chunk = 1 << 12
+    monkeypatch.setattr(aggregation, "_FOLD_CHUNK", chunk)
+    cfg = make_cfg(tmp_path)
+    cfg.model.vocab_size = 8192  # an embedding of 64 chunks
+    seen = {}
+
+    def spy(xs, ys, pool):
+        copies = [a.copy() for a in list(xs) + list(ys)]
+        got, seen["peak"] = _traced_peak(lambda: aggregation.diff_sumsq(xs, ys, pool))
+        for a, c in zip(list(xs) + list(ys), copies):
+            np.testing.assert_array_equal(a, c)
+        seen.update(xs=copies[:len(xs)], ys=copies[len(xs):], pool=pool)
+        return got
+
+    monkeypatch.setattr(client_runtime, "diff_sumsq", spy)
+    rt = client_runtime.ClientRuntime(cfg.validate(), ParamTransport("inline", host_threads=4))
+    meta, arrays = params_to_ndarrays(rt.trainer.state.params)
+    sent = [a.copy() for a in arrays]
+    rt.set_broadcast_params(rt.transport.put("init", meta, arrays))
+    res = rt.fit(FitIns(server_round=1, cids=[0], params=None, local_steps=2,
+                        server_steps_cumulative=0, config={}), cid=0)
+    rt.close()
+    assert res.error is None, res.error
+    assert seen["pool"] is rt.transport.host_pool and seen["pool"].threads == 4
+    for a, b in zip(seen["ys"], sent):  # the difference is against what was sent
+        np.testing.assert_array_equal(a, b)
+    assert seen["peak"] <= 16 * chunk * 8 < max(a.nbytes for a in sent)
+    assert res.metrics["client/pseudo_grad_norm"] == pytest.approx(
+        _plain_l2([o - i for o, i in zip(seen["xs"], seen["ys"])]), rel=1e-12)
+    assert res.metrics["client/pseudo_grad_norm"] > 0
+    assert res.metrics["client/param_norm"] == pytest.approx(_plain_l2(seen["xs"]), rel=1e-12)
+
+
+def test_chunked_update_under_thread_pressure(monkeypatch):
+    """More workers than cores and a short switch interval: chunks are
+    disjoint and scratch is per thread, so twenty rounds on sixteen threads
+    leave the bits an inline strategy leaves."""
+    monkeypatch.setattr(aggregation, "_FOLD_CHUNK", 64)
+    rng = np.random.default_rng(13)
+    init = _model(rng, (40, 50))
+    inline, pooled = (FedYogi(**HYPER) for _ in range(2))
+    for s in (inline, pooled):
+        s.initialize([p.copy() for p in init])
+    pooled.host_pool = HostPool(16)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for rnd in range(1, 21):
+            avg = _model(rng, (40, 50))
+            want = inline.apply_average(rnd, avg, 10, 2)
+            assert pooled.apply_average(rnd, avg, 10, 2) == want
+    finally:
+        sys.setswitchinterval(interval)
+        pooled.host_pool.close()
+    for a, b in zip(pooled.current_parameters + pooled.state["momentum_2"],
+                    inline.current_parameters + inline.state["momentum_2"]):
+        np.testing.assert_array_equal(a, b)
