@@ -1,7 +1,7 @@
-# Every target but `bench` runs on the CPU (JAX_PLATFORMS=cpu): tests and the
-# functional reports never take a chip. The chip is reached through the chip
-# tool, one process per chip: `python chip_smoke.py`, `python bench.py`.
-.PHONY: test test-all verify bench bench-host bench-collective bench-zero1 bench-ragged bench-compare chaos chaos-collective telemetry-smoke serve-smoke spec-smoke fleet-smoke adapters-smoke async-smoke autopilot-smoke lint lint-tests native clean
+# Every target runs on the CPU (JAX_PLATFORMS=cpu): tests never take a chip.
+# The chip is reached through the chip tool, one process per chip:
+# `python chip_smoke.py`, `python3 benchmark/run.py` (`make benchmark`).
+.PHONY: test test-all verify benchmark chaos chaos-collective telemetry-smoke serve-smoke spec-smoke fleet-smoke adapters-smoke async-smoke autopilot-smoke lint lint-tests native clean
 # native build is best-effort: the package degrades to numpy fallbacks when
 # the .so is absent, so tests must run even without a C++ toolchain
 test:
@@ -19,46 +19,14 @@ verify:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "not slow" \
 		--continue-on-collection-errors -p no:cacheprovider
 
-bench:
-	-$(MAKE) native
-	python bench.py
-
-# host-plane aggregation report only (serial vs pipelined fold+decode);
-# CPU-runnable, takes no chip
-bench-host:
-	JAX_PLATFORMS=cpu python bench.py --host-plane
-
-# device-collective aggregation report only (ISSUE 7: flat fp32 psum vs
-# hierarchical q8 on an emulated 8-device CPU client mesh); exit code
-# asserts the >=3.5x modeled cross-slice byte reduction at q8
-bench-collective:
-	JAX_PLATFORMS=cpu python bench.py --collective
-
-# ZeRO-1 sharded server update + layout auto-tuner gate (ISSUE 14):
-# replicated vs sharded plane on an emulated (2 clients, 4 replica) CPU
-# mesh with a 125M-shaped [params|m1|m2] FedAdam payload — exit code
-# asserts per-rank server-state bytes <= (1/R + eps) of replicated at
-# R=4, update-leg wall no worse, bit-exact params, and the auto-tuner's
-# top-ranked layout matching the measured-fastest on >= 2 mesh shapes.
-# Lint preflight like the other smoke targets.
-bench-zero1: lint
-	JAX_PLATFORMS=cpu python bench.py --zero1
-
-# ragged-paged-attention serving gate (ISSUE 12): tokens/s vs live-KV
-# fraction (ragged walk vs the PR 5 full-width gather — ragged must win
-# at low occupancy) plus the chunked-vs-interleaved worst-decode-gap
-# ratio. Lint preflight like the other smoke targets.
-bench-ragged: lint
-	JAX_PLATFORMS=cpu python bench.py --ragged
-
-# bench regression gate (ISSUE 10): diff two BENCH_r*.json artifacts'
-# shared report keys; exit nonzero on a >15% regression in train
-# tokens/sec or serving throughput. Usage:
-#   make bench-compare A=BENCH_r02.json B=BENCH_r03.json
-A ?= $(shell ls BENCH_r*.json 2>/dev/null | tail -2 | head -1)
-B ?= $(shell ls BENCH_r*.json 2>/dev/null | tail -1)
-bench-compare:
-	python bench.py --compare $(A) $(B)
+# how a cell of BENCHMARK.json is measured (benchmark/README.md; the driver
+# runs every cell on the parent commit and on the change and writes
+# PERF_LEDGER.jsonl)
+benchmark:
+	@echo "one cell, once, on the chip (a TPU v5e; no CPU fallback):"
+	@echo "  chiprun -- python3 benchmark/run.py --workload <cell> --seed 7 --seconds 40 --trace 0"
+	@python3 -c "import json; print('cells:', *(w['name'] for w in json.load(open('BENCHMARK.json'))['workloads']))"
+	@echo "--trace 1 adds the breakdown; python3 benchmark/tools/span_table.py --workload <cell> splits it by span"
 
 # telemetry smoke (ISSUE 4): the whole tracing/event/registry suite — the
 # fast half (in-process 1-round run → merged Perfetto trace parses with
@@ -84,26 +52,21 @@ lint-tests:
 		tests/test_analysis.py -q
 
 # serving smoke (ISSUE 5 + 11 + 12): the whole serving-plane suite —
-# mixed-step bit-parity with the contiguous decoder, the ragged
+# mixed-step parity with the contiguous decoder, the ragged
 # paged-attention kernel's epsilon tier, scheduler invariants incl.
 # decode cadence under a 4x-budget chunked prompt, HTTP round-trips
 # (blocking + chunked streaming) against a real round checkpoint, the
 # content-addressed prefix cache (refcounts, chain hashes, cached-vs-cold
-# per-step bit-parity, LRU pressure) and the live checkpoint hot-swap
+# per-step parity, LRU pressure) and the live checkpoint hot-swap
 # (watcher state machine incl. the chaos corrupt-candidate skip,
-# zero-dropped-across-swap e2e) — then the serving bench, whose exit code
-# asserts continuous batching beats batch-sync at 16 concurrent, the
-# prefix cache cuts mean TTFT at 90% shared-prefix traffic, a live swap
-# drops zero requests, ragged attention beats the full-width gather at
-# low pool occupancy, and chunked prefill cuts the worst decode gap. All
-# of it rides tier-1 too (none is slow). photon-lint preflight first: a
+# zero-dropped-across-swap e2e). All of it rides tier-1 too (none is
+# slow). photon-lint preflight first: a
 # rule regression (or a fresh violation in serve/) fails the smoke before
 # any engine compile burns minutes
 serve-smoke: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_serve.py tests/test_serve_prefix.py tests/test_hotswap.py \
 		tests/test_ragged_attention.py -q -m "slow or not slow"
-	JAX_PLATFORMS=cpu python bench.py --serving
 
 # speculative decoding (ISSUE 15): the draft-and-verify suite — the
 # generalized grid's bitwise parity with K sequential single-token steps
@@ -111,23 +74,17 @@ serve-smoke: lint
 # through the batcher (prefix hits, recycled blocks, EOS mid-burst),
 # rejection-sampling distribution pins, the n-gram drafter + accept-rate
 # throttle, and the retrace sentinel over warm speculative bursts with
-# the full-idle high-water reset — then the bench gate: speculative must
-# beat plain decode on templated traffic AND not regress on random
-# traffic with drafting auto-throttled off. Rides tier-1 too (none is
-# slow); lint preflight first like the other smoke targets.
+# the full-idle high-water reset. Rides tier-1 too (none is slow); lint
+# preflight first like the other smoke targets.
 spec-smoke: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_speculative.py -q -m "slow or not slow"
-	JAX_PLATFORMS=cpu python bench.py --speculative
 
 # fleet router (ISSUE 16): placement policy + control plane + failover
-# suite, then the bench gate — affinity routing must beat random on both
-# aggregate tokens/s and mean TTFT over 4 emulated replicas, and a
-# mid-traffic replica kill must drop zero requests on the survivors
+# suite (a mid-traffic replica kill drops zero requests on the survivors)
 fleet-smoke: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_router.py -q -m "slow or not slow"
-	JAX_PLATFORMS=cpu python bench.py --fleet
 
 # per-cohort LoRA personalization plane (ISSUE 13): the train-side suite
 # (config validation, LoRA payload algebra, fused multi-cohort reduction
@@ -136,15 +93,12 @@ fleet-smoke: lint
 # pins) and the serve-side suite (adapter-pool refcounts, mixed-cohort
 # bit-parity vs the contiguous base+adapter oracle incl. recycled pages,
 # cohort over HTTP, retrace sentinel over cohort churn, and the
-# train→checkpoint→hot-swap e2e with zero dropped requests) — then the
-# bench gate: modeled adapter wire bytes >= 50x below a full-model
-# exchange and the fused K-cohort reduction beating K sequential
-# reductions. Both suites ride tier-1 too (none is slow); lint preflight
-# first like the other smoke targets.
+# train→checkpoint→hot-swap e2e with zero dropped requests). Both suites
+# ride tier-1 too (none is slow); lint preflight first like the other
+# smoke targets.
 adapters-smoke: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_adapters.py tests/test_adapter_serve.py -q -m "slow or not slow"
-	JAX_PLATFORMS=cpu python bench.py --adapters
 
 # asynchronous federated rounds (ISSUE 18): the version-clock suite —
 # zero-staleness bit-parity with the synchronous runner (all five server
@@ -152,29 +106,22 @@ adapters-smoke: lint
 # weight math, the max-staleness reject / min-arrivals stall / liveness
 # in-flight-drop ladder, deterministic chaos fit delays, the retrace
 # sentinel over the event loop, and the SIGKILL+4x-skew chaos e2e with
-# the hot-swap watcher consuming streamed versions mid-traffic — then
-# the bench gate: async must reach the sync run's final eval loss
-# strictly faster on the modeled wall clock at 4x induced skew AND the
-# K=cohort zero-staleness run must be bit-identical to sync. Lint
+# the hot-swap watcher consuming streamed versions mid-traffic. Lint
 # preflight first like the other smoke targets.
 async-smoke: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_async_round.py -q -m "slow or not slow"
-	JAX_PLATFORMS=cpu python bench.py --async
 
 # SLO autopilot (ISSUE 19): the feedback-controller suite — windowed
 # reducer exact-value pins, runtime-knob loud rejects, breach/cooldown/
 # saturation/relax state machine on an injected clock, the HBM
 # alert-latch reclaim, per-replica restart cooldown, /statusz decision
-# surfacing, and the seeded chaos-storm e2e through the real scheduler —
-# then the bench gate: through one seeded storm the controlled arm must
-# converge (zero queue rejects AND TPOT p50 inside the declared SLO via
-# real budget actuations) where the uncontrolled arm misses. The fast
-# half rides tier-1 too; lint preflight first like the other smokes.
+# surfacing, and the seeded chaos-storm e2e through the real scheduler
+# (zero queue rejects, the budget actuated). The fast half rides tier-1
+# too; lint preflight first like the other smokes.
 autopilot-smoke: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_autopilot.py -q -m "slow or not slow"
-	JAX_PLATFORMS=cpu python bench.py --autopilot
 
 # the chaos-marked fault-injection + elasticity suite (incl. the slow
 # SIGKILL/rejoin e2es): deterministic — every test pins

@@ -8,7 +8,7 @@ nondeterministically with each other.
 
 Hook sites call :func:`active` (a module-global read) and do nothing when it
 returns ``None`` — the disabled path is provably a no-op, which is what lets
-``photon.chaos`` exist in the tree without taxing the bench host-plane path.
+``photon.chaos`` exist in the tree without taxing the round's host path.
 """
 
 from __future__ import annotations
@@ -199,8 +199,9 @@ class FaultInjector:
         Deterministic — no sequential draw: the factor is a pure function
         of ``(seed, scope, cid)``, independent of hook-call order, so the
         async runner's induced 4x skew replays identically across runs and
-        across sync-vs-async bench arms. ``fit_delay_cid`` pins the full
-        factor on exactly one client (the "one 4x-slow client" scenario);
+        across a synchronous and an asynchronous run. ``fit_delay_cid``
+        pins the full factor on exactly one client (the "one 4x-slow
+        client" scenario);
         -1 gives every client a seeded factor in [1, factor].
         """
         c = self.cfg
@@ -226,7 +227,7 @@ class FaultInjector:
         times the tokens the tick's engine step carried (chunk + emitted).
         Deterministic — no probability draw: the SLO-autopilot storm needs
         the slowdown proportional to the work the controller's budget knob
-        actually bounds, every tick, both bench arms identical."""
+        actually bounds, every tick, identical with the controller on or off."""
         c = self.cfg
         per = float(getattr(c, "serve_stall_per_token_s", 0.0) or 0.0)
         if per <= 0.0 or tokens <= 0:

@@ -416,7 +416,7 @@ class ChaosConfig:
     replica_kill_id: str = ""  # "" = seeded pick among the live fleet
     # deterministic per-client fit slowdown (ISSUE 18): scale a client's
     # fit duration so heterogeneous-hardware skew is reproducible in the
-    # async bench/tests. 0 = off; >= 1 = the slowdown ceiling. With
+    # async tests. 0 = off; >= 1 = the slowdown ceiling. With
     # ``fit_delay_cid`` >= 0 exactly that client runs at the full factor
     # (the "one 4x-slow client" scenario); with -1 every client draws a
     # seeded factor in [1, factor] from its (seed, scope)-keyed stream —
@@ -829,7 +829,7 @@ class AsyncRoundsConfig:
     n_versions: int = 0
     #: baseline simulated seconds per client fit in the async round
     #: simulator (scaled per-client by chaos ``fit_delay_factor``); the
-    #: DES clock is what the bench's wall-clock-to-target-loss measures
+    #: DES clock is what time-to-target-loss is compared on
     fit_time_s: float = 1.0
 
 

@@ -397,7 +397,7 @@ def run_gauntlet(
     ``eval_gauntlet_v0.3.yaml`` ``subtract_random_baseline/rescale``).
 
     ``on_task(task, result, partial_out)`` fires after each task — callers
-    with wall-clock budgets (bench evidence stages) flush partial artifacts
+    with wall-clock budgets flush partial artifacts
     there and may raise to stop early; the exception propagates with
     ``partial_out`` already populated for everything scored so far."""
     out: dict[str, float] = {}

@@ -37,10 +37,10 @@ client trains on are exactly the version it was dispatched from, so no
 parameter history is needed), and the resulting delta is *delivered* on a
 discrete-event simulated clock at ``fit_time_s × fit_delay_factor(cid)``
 (the chaos plane's deterministic per-client slowdown) — which is what
-lets ``bench.py --async`` measure wall-clock-to-target-loss under induced
-4x skew without sleeping. Staleness is assessed at arrival and frozen on
-the buffered entry (the server "folds it on arrival" into the buffer; the
-version fold is the commit).
+lets ``tests/test_async_round.py`` compare time-to-target-loss with the
+synchronous round clock under induced 4x skew without sleeping. Staleness
+is assessed at arrival and frozen on the buffered entry (the server "folds
+it on arrival" into the buffer; the version fold is the commit).
 
 Scope: single-controller (one process, many local clients) — the
 multi-controller gang would need an arrival-consensus plane this PR does
@@ -155,7 +155,7 @@ class AsyncFedRunner(CollectiveFedRunner):
         self.fit_time_s = float(ar.fit_time_s)
         #: the version clock: strategy.current_parameters IS version v
         self.version = 0
-        #: simulated seconds elapsed (the DES clock the bench measures)
+        #: simulated seconds elapsed (the DES clock)
         self.sim_time = 0.0
         # streamed-arrival state
         self._heap: list[tuple[float, int]] = []  # (finish_time, seq)

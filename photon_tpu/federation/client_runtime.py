@@ -324,8 +324,8 @@ class ClientRuntime:
         inj = chaos.active()
         if inj is not None:
             # chaos fit slowdown (ISSUE 18): report the deterministic
-            # per-client factor so the async runner's simulated clock (and
-            # the bench's sync baseline) scale this fit's duration by it —
+            # per-client factor so the async runner's simulated clock
+            # scales this fit's duration by it —
             # heterogeneous-hardware skew without actually sleeping
             f = inj.fit_delay_plan(cid)
             if f != 1.0:

@@ -40,8 +40,7 @@ Two implementations share this contract:
   including ``interpret=`` so the CPU sandbox executes the same kernel
   logic through the Pallas interpreter. Numerics: EPSILON-tier vs the
   dense softmax (the online rescaling reorders the fp32 accumulation);
-  the pinned thresholds live in ``tests/test_ragged_attention.py``,
-  mirroring the KERNEL_PARITY.json discipline.
+  the pinned thresholds live in ``tests/test_ragged_attention.py``.
 - :func:`ragged_reference_attention` — the XLA reference over the same
   live view: one dense softmax over ``n_ctx * bs`` masked scores.
   BIT-EXACT with the contiguous ``models/decode.py`` math (masked

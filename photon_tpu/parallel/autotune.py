@@ -11,14 +11,15 @@ entry point (:func:`autotune_mesh`) instead of hand-tuning ``MeshConfig``.
 The pjit/TPUv4 scaling literature grounds the cost terms; the federated
 DCN term reuses the PR 7 modeled-bytes machinery
 (``collective_agg.modeled_cross_slice_bytes``) so the exchange leg is
-priced with exactly the model the aggregation plane's bench gates pin.
+priced with exactly the model the aggregation plane's tests pin
+(``tests/test_collective_agg.py``).
 
 The model is deliberately coarse — its job is the *ranking*, not absolute
-seconds. Two external validations keep it honest (``bench.py --zero1``,
-exit-gated): the top-ranked layout must match the measured-fastest layout
-on emulated mesh shapes, and the HBM estimate must bracket the AOT
-compiler's ``memory_analysis`` on the abstract v5e topologies
-(``parallel/topo.py``) where libtpu is available (``tests/test_autotune``).
+seconds. ``tests/test_autotune.py`` holds the rankings it must give (a
+small model prefers data parallelism, a big one shards its state to fit)
+and that the HBM estimate brackets the AOT compiler's ``memory_analysis``
+on the abstract v5e topologies (``parallel/topo.py``) where libtpu is
+available. Its top pick against a step measured on the chip: not measured.
 
 Cost terms per optimizer step (see :func:`estimate_layout`):
 

@@ -1156,9 +1156,9 @@ class DeviceAggregationPlane:
     def server_state_bytes_per_rank(self) -> int:
         """Persistent server-state bytes ONE ICI rank holds between rounds
         (params + every optimizer-state tensor, fp32): each leaf counts its
-        per-rank chunk on the sharded plane, its full size replicated. The
-        ``bench.py --zero1`` gate pins sharded ≤ (1/replica + ε) ×
-        replicated."""
+        per-rank chunk on the sharded plane, its full size replicated.
+        ``tests/test_collective_agg.py`` pins sharded ≤ (1/replica + 0.05)
+        × replicated."""
         per_leaf = self._chunks if self.sharded else self._sizes
         return 4 * sum(per_leaf) * (1 + len(self.state_keys))
 

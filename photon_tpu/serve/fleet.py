@@ -22,10 +22,10 @@ events) — the PR 3/8 machinery IS the control plane, not new code.
 
 Two fleet shapes:
 
-- :class:`InProcessFleet` — N replicas as threads in one process (tests
-  and ``bench.py --fleet``'s emulated fleet: one jax compile cache, no
-  port races). ``kill_replica`` emulates SIGKILL: both planes go silent
-  mid-flight, nothing is drained.
+- :class:`InProcessFleet` — N replicas as threads in one process (the
+  tests' emulated fleet: one jax compile cache, no port races).
+  ``kill_replica`` emulates SIGKILL: both planes go silent mid-flight,
+  nothing is drained.
 - :class:`FleetSupervisor` — N real daemon subprocesses
   (``python -m photon_tpu.serve --fleet-connect``), SIGKILL-able for the
   chaos e2e, SIGTERM-drained on close.
@@ -249,7 +249,7 @@ class ReplicaAgent:
 class InProcessFleet:
     """N replica engines as threads behind one router, one process.
 
-    The emulated fleet tests and ``bench.py --fleet`` run on: every
+    The emulated fleet ``tests/test_router.py`` runs on: every
     replica is a full engine + batcher + HTTP frontend + control agent —
     only the process boundary is emulated. Same-config replicas share
     the jax compile cache, so N engines compile once.

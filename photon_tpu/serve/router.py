@@ -130,8 +130,8 @@ class AffinityRouter:
 
     Callers pass the CURRENT live set and load snapshot; the only state
     held here is the sticky cohort → replica pin map. ``mode="random"``
-    is the bench baseline: uniform placement, affinity machinery bypassed
-    (the control for the locality win ``bench.py --fleet`` gates on).
+    is the control: uniform placement, affinity machinery bypassed (what a
+    serving cell would measure affinity against; ROADMAP R8 iii).
     """
 
     def __init__(self, *, block_size: int, prefix_affinity_blocks: int = 4,

@@ -18,11 +18,11 @@ The serving loop (one driver thread) interleaves two phases forever:
    full prefill — ``tests/test_ragged_attention.py`` pins the fix). Rows
    that hit their EOS or ``max_new_tokens`` are evicted immediately and
    their blocks/slot recycled, so the next iteration's admit phase refills
-   mid-flight. That refill is the whole tokens/s win over batch-synchronous
-   serving (``bench.py --serving`` measures it). With speculative decoding
-   on (``serve.speculative``, ISSUE 15), every decoding row may
-   additionally carry up to K drafter-proposed tokens, verified in the
-   SAME step — the accepted prefix plus one model token all emit at once,
+   mid-flight: the refill is what continuous batching has over serving in
+   whole waves. With speculative decoding on (``serve.speculative``,
+   ISSUE 15), every decoding row may additionally carry up to K
+   drafter-proposed tokens, verified in the SAME step — the accepted
+   prefix plus one model token all emit at once,
    under a per-tick draft budget composed with ``prefill_token_budget``
    and an accept-rate EWMA that throttles K down to plain decode on
    incompressible traffic (``serve/draft.py``).
@@ -154,25 +154,18 @@ class ServeRequest:
 
 
 class ContinuousBatcher:
-    """Single-driver-thread scheduler over a :class:`PagedEngine`.
-
-    ``batch_synchronous=True`` is the BASELINE policy for the serving
-    bench: admission waits until every slot is empty, then fills all slots
-    and runs the wave to completion (classic static batching). Continuous
-    mode (default) refills freed slots mid-flight.
-    """
+    """Single-driver-thread scheduler over a :class:`PagedEngine`: freed
+    slots are refilled mid-flight."""
 
     def __init__(self, engine: PagedEngine, *, max_queue: int = 64,
                  prefill_token_budget: int = 2048,
                  default_eos_id: int | None = None,
-                 batch_synchronous: bool = False,
                  history: History | None = None,
                  speculative=None, drafter=None) -> None:
         self.engine = engine
         self.max_queue = max_queue
         self.prefill_token_budget = prefill_token_budget
         self.default_eos_id = default_eos_id
-        self.batch_synchronous = batch_synchronous
         # self-drafted speculative decoding (ISSUE 15, serve/draft.py):
         # `speculative` is a SpeculativeConfig (photon.serve.speculative);
         # `drafter` overrides the default NGramDrafter (tests, learned
@@ -600,18 +593,11 @@ class ContinuousBatcher:
             # about to be replaced; queued requests wait (never dropped)
             # and running slots drain through the step phase
             return
-        # batch-sync baseline: a wave may only START from an empty engine,
-        # but once open it fills EVERY slot this phase (admissions made
-        # here keep n_active > 0 — checking n_active per iteration would
-        # degrade the baseline to one-request-at-a-time serial serving)
-        wave_open = self.engine.n_active == 0
         while True:
             with self._lock:
                 head = self._queue[0] if self._queue else None
             if head is None:
                 return
-            if self.batch_synchronous and not wave_open:
-                return  # baseline: wait for the whole wave to drain
             slot = self.engine.free_slot()
             # cohort kwarg only when the request names one: fake/minimal
             # engines (tests, alternative backends) need not grow the

@@ -139,7 +139,7 @@ ASYNC_STALLS = "server/async_stalls_total"
 #: buffered deltas awaiting the next advance, sampled after each arrival
 ASYNC_BUFFER_FILL = "server/async_buffer_fill"
 #: simulated seconds elapsed when this version committed — the modeled
-#: wall clock ``bench.py --async`` measures time-to-target-loss on
+#: clock time-to-target-loss is compared on (``tests/test_async_round.py``)
 ASYNC_SIM_TIME = "server/async_sim_time"
 #: the chaos fit_delay_plan slowdown factor this fit ran under (1.0 =
 #: no injected skew; the async runner scales simulated durations by it)
@@ -255,8 +255,7 @@ SERVE_HOTSWAP_SWAP_SPAN = "serve/hotswap_swap"
 #: the live attention walk width in BLOCKS (the monotone high-water
 #: pow2 bucket; == the full table width under attention_impl=gather)
 SERVE_ATTN_CTX_BLOCKS = "serve/attn_ctx_blocks"
-#: fraction of the paged pool's blocks currently allocated (live KV —
-#: the x-axis of the bench's tokens/s-vs-occupancy curve)
+#: fraction of the paged pool's blocks currently allocated (live KV)
 SERVE_ATTN_LIVE_FRAC = "serve/attn_live_frac"
 #: 1.0 when the ragged live-block walk is active, 0.0 under the
 #: full-width dense-gather oracle path (attention_impl=gather)

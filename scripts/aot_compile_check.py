@@ -257,10 +257,10 @@ def main() -> int:
 
 def _compile_decode(args, cfg, topo, dev) -> int:
     """Compile the gauntlet's inference pair (prefill + decode_step) for
-    the TPU topology — the on-chip gauntlet stage compiles exactly these
-    jits (models/decode.py:make_cached_generate_fn), so verifying them
-    offline de-risks GAUNTLET_TPU.json the same way the train-step matrix
-    de-risks the headline bench."""
+    the TPU topology — an eval on the chip compiles exactly these jits
+    (models/decode.py:make_cached_generate_fn), so verifying them offline
+    de-risks it the same way the train-step matrix de-risks a training
+    cell."""
     import jax.numpy as jnp
 
     from jax.sharding import NamedSharding, PartitionSpec

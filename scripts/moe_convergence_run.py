@@ -21,15 +21,27 @@ import sys
 import time
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(REPO))  # photon_tpu + bench importable when not installed
+sys.path.insert(0, str(REPO))  # photon_tpu importable when not installed
 
 
 def build_corpus() -> "np.ndarray":
-    # the bench's corpus builder owns the shared .bench_corpus_v1 cache —
-    # one recipe, one cache, comparable numbers across consumers
-    import bench
+    """Real-English byte tokens (site-packages docstrings — the zero-egress
+    corpus recipe from scripts/make_local_corpus.py), cached as uint8."""
+    import numpy as np
 
-    return bench._corpus_tokens()
+    cache = REPO / ".bench_corpus_v1.npy"
+    if cache.exists():
+        return np.load(cache)
+    print("generating real-text corpus (site-packages docstrings, ~35s)...",
+          file=sys.stderr, flush=True)
+    import make_local_corpus  # a sibling in scripts/, the script's own directory
+
+    tmp_txt = REPO / ".bench_corpus_v1.txt"
+    make_local_corpus.main(["--out", str(tmp_txt), "--max-mb", "24"])
+    toks = np.frombuffer(tmp_txt.read_bytes(), np.uint8).copy()
+    tmp_txt.unlink()
+    np.save(cache, toks)
+    return toks
 
 
 def run(kind: str, steps: int, toks) -> dict:

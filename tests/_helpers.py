@@ -1,4 +1,5 @@
-"""Shared helpers for subprocess-spawning tests.
+"""Shared helpers: subprocess environments, the tiny llama config, and the
+tolerance at which two serving programs' logits are held to each other.
 
 A plain module (NOT conftest) so test files can import it without
 re-executing conftest's module-level jax.config setup under a second module
@@ -15,6 +16,28 @@ import socket
 TEST_JAX_CACHE = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
     pathlib.Path(__file__).parent / ".jax_cache"
 )
+
+
+#: How far the fp32 logits of two serving programs that compute the same
+#: function (chunked vs one-shot prefill, cached vs cold admission, paged vs
+#: contiguous decode) may sit apart. Read under jax 0.9.0 on the CPU backend:
+#: <= 9e-8 absolute on logits of order 1, i.e. a last-place difference from
+#: the order XLA sums a dot in, which it may choose per program. Bits are
+#: promised on no backend (the TPU's fp32 dots go through bf16 passes and sit
+#: ~1e-2 from an fp64 oracle), so the pin is this tolerance AND the served
+#: token: the argmax must not move.
+SERVE_LOGITS_ATOL = 1e-6
+
+
+def assert_logits_match(got, want, err_msg: str = "") -> None:
+    """``got`` and ``want`` within :data:`SERVE_LOGITS_ATOL` and picking the
+    same token."""
+    import numpy as np
+
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=0, atol=SERVE_LOGITS_ATOL,
+                               err_msg=err_msg)
+    assert int(got.argmax(-1)) == int(want.argmax(-1)), err_msg
 
 
 def free_port() -> int:

@@ -210,6 +210,36 @@ def test_spec_resolves_model_family_and_roundtrips(llama):
     assert mm.shapes == full_meta.shapes
 
 
+def test_adapter_exchange_modeled_bytes_50x_below_full_model():
+    """ISSUE 13's count: on the 125M recipe at the default targets and
+    rank 8, one adapter exchange is priced at least 50x below one
+    full-model exchange for the same clients, by the model the collective
+    plane reports as ``server/adapter_wire_bytes``."""
+    from photon_tpu.adapters.lora import adapter_metadata, spec_from_base
+    from photon_tpu.codec import ParamsMetadata, flatten_params
+    from photon_tpu.config.schema import AdaptersConfig, ModelConfig
+    from photon_tpu.models.mpt import init_params
+    from photon_tpu.parallel.collective_agg import modeled_cross_slice_bytes
+
+    names, leaves = flatten_params(
+        jax.eval_shape(lambda: init_params(ModelConfig(), seed=0)))
+    base = ParamsMetadata(
+        names=tuple(names),
+        shapes=tuple(tuple(int(d) for d in leaf.shape) for leaf in leaves),
+        dtypes=("float32",) * len(names),
+    )
+    acfg = AdaptersConfig()
+    spec = spec_from_base(base, 8, acfg.alpha, tuple(acfg.targets))
+
+    def sizes(meta):
+        return [int(np.prod(s, dtype=np.int64)) for s in meta.shapes]
+
+    full = modeled_cross_slice_bytes(sizes(base), 8)
+    adapter = modeled_cross_slice_bytes(sizes(adapter_metadata(spec)), 8)
+    assert sum(sizes(adapter_metadata(spec))) == spec.n_params
+    assert full / adapter >= 50.0, full / adapter
+
+
 def test_fresh_adapter_is_identity_and_merge_math():
     from photon_tpu.adapters.lora import (
         init_adapter_arrays, merge_adapter_into_base, spec_from_base,

@@ -442,6 +442,43 @@ def test_fit_delay_rides_fit_metrics(tmp_path):
     assert times[1] == pytest.approx(4.0 * times[0])
 
 
+def test_async_reaches_sync_loss_sooner_on_the_modeled_clock(tmp_path):
+    """ISSUE 18's count, on the deterministic clock and never the wall
+    clock: with one of four clients pinned 4x slow, the synchronous round
+    pays the straggler every round (``fit_time_s x 4`` each), the buffered
+    server (K=2) folds the fast clients' deltas as they land — and reaches
+    the sync run's final eval loss at a strictly earlier
+    ``server/async_sim_time``."""
+    from photon_tpu.utils.profiling import ASYNC_SIM_TIME, EVAL_LOSS
+
+    skew, sync_rounds = 4.0, 3
+
+    def skewed(cfg):
+        cfg.photon.chaos.enabled = True
+        cfg.photon.chaos.fit_delay_factor = skew
+        cfg.photon.chaos.fit_delay_cid = 3
+        return cfg.validate()
+
+    sync_cfg = skewed(_cfg(tmp_path / "sync", n_clients=4))
+    chaos.install(sync_cfg.photon.chaos, scope="collective0")
+    sync = CollectiveFedRunner(sync_cfg, [0, 1, 2, 3])
+    for r in range(1, sync_rounds + 1):
+        sync.run_round(r)
+    target = float(sync.evaluate_round(sync_rounds)[EVAL_LOSS])
+    sync_time = sync_rounds * sync_cfg.photon.async_rounds.fit_time_s * skew
+    chaos.uninstall()
+
+    async_cfg = skewed(_async_cfg(tmp_path / "async", n_clients=4, K=2))
+    chaos.install(async_cfg.photon.chaos, scope="collective0")
+    runner = AsyncFedRunner(async_cfg, [0, 1, 2, 3])
+    runner.run_versions(16, eval_every=1)
+    sims = dict(runner.history.series(ASYNC_SIM_TIME))
+    reached = [sims[v] for v, loss in runner.history.series(EVAL_LOSS)
+               if v > 0 and loss <= target and v in sims]
+    assert reached, f"16 versions never reached the sync loss {target}"
+    assert reached[0] < sync_time, (reached[0], sync_time)
+
+
 # ---------------------------------------------------------------------------
 # 5. config plumbing
 # ---------------------------------------------------------------------------

@@ -1,9 +1,8 @@
 """Layout auto-tuner (ISSUE 14b): enumeration legality, cost-model
 monotonicity, ranking sanity on real presets and the federated DCN term (the
 memory-analysis cross-check against the TPU compiler lives in
-``tests/test_tpu_compile.py``). The rank-vs-MEASURED validation lives in
-``bench.py --zero1`` (exit-gated): the cost model's top pick must match
-the measured-fastest layout on >= 2 emulated mesh shapes."""
+``tests/test_tpu_compile.py``). The top pick against a step measured on
+the chip: not measured."""
 
 import dataclasses
 

@@ -244,17 +244,38 @@ def test_bad_quantization_rejected():
 # ---------------------------------------------------------------------------
 
 
-def test_modeled_cross_slice_bytes_q8_ratio():
+def _mpt125m_leaf_sizes():
+    """Every leaf of the 125M recipe (big matrices AND the ragged
+    layernorm / bias leaves whose block padding q8 pays for), by shape
+    alone."""
+    from photon_tpu.config.schema import ModelConfig
+    from photon_tpu.models.mpt import init_params
+
+    abstract = jax.eval_shape(lambda: init_params(ModelConfig(), seed=0))
+    return [int(np.prod(leaf.shape, dtype=np.int64))
+            for leaf in jax.tree_util.tree_leaves(abstract)]
+
+
+@pytest.mark.parametrize("sizes", [
     # one 1M-element leaf at block 256: 4 bytes/val → 1 + 4/256 bytes/val
-    n = 1 << 20
-    fp32 = modeled_cross_slice_bytes([n], 4, quantization="off")
-    q8 = modeled_cross_slice_bytes([n], 4, quantization="q8", block=256)
-    assert fp32 == 4 * n * 4
+    pytest.param(lambda: [1 << 20], id="one-1M-leaf"),
+    # the whole recipe: the ratio survives the ragged leaves' padding
+    pytest.param(_mpt125m_leaf_sizes, id="mpt-125m-leaves"),
+])
+def test_modeled_cross_slice_bytes_q8_ratio(sizes):
+    sizes = sizes()
+    fp32 = modeled_cross_slice_bytes(sizes, 4, quantization="off")
+    q8 = modeled_cross_slice_bytes(sizes, 4, quantization="q8", block=256)
+    assert fp32 == 4 * sum(sizes) * 4
     ratio = fp32 / q8
     assert 3.5 <= ratio <= 4.0, ratio
-    # hierarchy splits, never grows, the modeled total
-    assert modeled_cross_slice_bytes([n], 4, replica=4, quantization="q8",
-                                     block=256) == q8
+    # hierarchy splits the modeled total and adds only the block padding
+    # of each rank's chunk (nothing where the blocks divide evenly)
+    hier = modeled_cross_slice_bytes(sizes, 4, replica=4, quantization="q8",
+                                     block=256)
+    assert q8 <= hier <= q8 * 1.001 and fp32 / hier >= 3.5
+    if len(sizes) == 1:
+        assert hier == q8
 
 
 def test_modeled_cross_slice_bytes_padding_accounted():
@@ -605,6 +626,31 @@ def test_sharded_checkpoint_bit_exact_across_resharding(
     for key in ("momentum_1", "momentum_2"):
         for a, b in zip(plane_c.state_host()[key], plane_b.state_host()[key]):
             np.testing.assert_array_equal(a, b)
+
+
+def test_sharded_state_bytes_per_rank_divide_by_replica():
+    """ISSUE 14's count: under FedAdam ([params|m1|m2]) at R=4 a rank of
+    the sharded plane holds <= (1/R + 0.05) of what a rank of the
+    replicated plane holds, and never less than 1/R — the 0.05 is the
+    chunk padding of leaves that R does not divide."""
+    replica = 4
+    rng = np.random.default_rng(3)
+    init = [rng.normal(size=s).astype(np.float32)
+            for s in [(512, 768), (768,), (256, 3), (5,)]]
+    mesh = make_hierarchical_mesh(2, replica)
+
+    def state_bytes(sharded):
+        strat = _strategy("fedadam")
+        strat.initialize([p.copy() for p in init])
+        plane = DeviceAggregationPlane(mesh, strat, sharded=sharded)
+        assert set(plane.state_keys) == {"momentum_1", "momentum_2"}
+        return plane.server_state_bytes_per_rank(), plane.shard_fraction()
+
+    full, frac_r = state_bytes(False)
+    shard, frac_s = state_bytes(True)
+    assert full == 4 * sum(p.size for p in init) * 3 and frac_r == 1.0
+    assert 1 / replica <= shard / full <= 1 / replica + 0.05
+    assert frac_s == pytest.approx(shard / full)
 
 
 def test_sharded_seeding_peak_host_rss_bounded():

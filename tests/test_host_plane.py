@@ -411,25 +411,3 @@ def test_resume_after_async_checkpoint_matches_uninterrupted(tmp_path):
     for a, b in zip(p_full, resumed.strategy.current_parameters):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
     resumed.driver.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# bench host_plane section
-# ---------------------------------------------------------------------------
-
-
-def test_bench_host_plane_report_smoke():
-    import bench
-
-    report = bench.host_plane_report(budget_bytes=1 << 20, n_clients=3, repeats=1)
-    assert report is not None
-    assert report["cpu_count"] >= 1 and report["threads"] >= 1
-    assert report["raw_bytes_full_model"] > report["payload_bytes_per_client"]
-    for kind in ("raw", "compressed"):
-        sec = report[kind]
-        assert sec["bit_exact"] is True
-        assert sec["serial_gb_s"] > 0 and sec["pipelined_gb_s"] > 0
-        if report["threads"] == 1:
-            # degenerate pool: the pipelined path IS the serial path and the
-            # report must say so exactly (never-slower holds by construction)
-            assert sec["pipelined_s"] == sec["serial_s"]

@@ -314,7 +314,7 @@ def test_inflight_finish_on_old_params_and_cache_flushes(served):
 
 
 def test_zero_dropped_requests_across_live_swap(served, tmp_path):
-    """The bench gate's unit twin: continuous HTTP traffic across a
+    """Continuous HTTP traffic across a
     watcher-driven swap — every response is a 200 whose tokens equal the
     old OR the new round's oracle (each request ran on exactly one), and
     the daemon ends on the new round."""

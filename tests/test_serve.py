@@ -334,30 +334,6 @@ def test_oversized_request_rejected_for_small_pool():
         batcher.close()
 
 
-def test_batch_synchronous_baseline_waves():
-    """The bench baseline: admission waits for the whole wave to finish, so
-    the second wave's admit time is after the first wave's completions."""
-    from photon_tpu.models.mpt import init_params
-    from photon_tpu.serve.engine import PagedEngine
-    from photon_tpu.serve.scheduler import ContinuousBatcher
-
-    cfg = _serve_cfg(n_slots=2, block_size=4, max_seq=32, max_new=8)
-    engine = PagedEngine(cfg, init_params(cfg.model, seed=0))
-    batcher = ContinuousBatcher(engine, max_queue=16, batch_synchronous=True).start()
-    try:
-        reqs = [batcher.submit([1 + i, 2, 3], 4) for i in range(4)]
-        for r in reqs:
-            r.result(timeout=60)
-        # a wave fills ALL slots before decoding (not one-at-a-time serial):
-        # both wave-1 members are admitted before either finishes
-        assert max(r.t_admit for r in reqs[:2]) <= min(r.t_done for r in reqs[:2])
-        wave1_done = max(r.t_done for r in reqs[:2])
-        assert min(r.t_admit for r in reqs[2:]) >= wave1_done
-        _assert_drained(engine, batcher)
-    finally:
-        batcher.close()
-
-
 # ---------------------------------------------------------------------------
 # 3. checkpoint → engine → HTTP e2e
 # ---------------------------------------------------------------------------
@@ -513,10 +489,21 @@ def test_graceful_drain_zero_dropped_inflight(tmp_path):
                    for i in range(len(prompts))]
         for t in threads:
             t.start()
+        # the drain may start only once all four are ACCEPTED: a client
+        # thread that posts after the flag gets the 503 this test asserts
+        # only the fifth request sees. A request leaves the queue before
+        # it counts as active and leaves the slots before it counts as
+        # completed, so the sum undercounts in passing and never overcounts
+        base = batcher.completed
         deadline = time.monotonic() + 30.0
-        while time.monotonic() < deadline and engine.n_active == 0:
-            time.sleep(0.005)
-        assert engine.n_active > 0  # requests genuinely in flight
+
+        def accepted() -> int:
+            return (batcher.completed - base + engine.n_active
+                    + batcher.queue_depth)
+
+        while time.monotonic() < deadline and accepted() < len(prompts):
+            time.sleep(0.002)
+        assert accepted() == len(prompts)
 
         # the __main__ SIGTERM sequence: flag the edge, then drain the plane
         fe.mark_draining()

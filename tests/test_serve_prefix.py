@@ -5,10 +5,10 @@ Contract layers:
 1. refcounted :class:`BlockAllocator` — double-map / double-free /
    evict-while-pinned accounting stays exact under sharing;
 2. chain hashes — a hash identifies the WHOLE prefix, not one block;
-3. **bit-parity** — a request admitted through a cached prefix produces
-   per-step logits IDENTICAL (assert_array_equal) to the same request
-   prefilled cold, including after the shared blocks' original owner was
-   evicted;
+3. **parity** — a request admitted through a cached prefix produces the
+   same request's cold-prefilled per-step logits to ``SERVE_LOGITS_ATOL``
+   and the same tokens (``tests/_helpers.py``), including after the shared
+   blocks' original owner was evicted;
 4. scheduler invariants with the cache on (no leaks, LRU eviction under
    pool pressure, outputs == offline oracle), and the retrace sentinel
    stays green across warm ragged bursts with hits, misses and one live
@@ -21,7 +21,7 @@ import pytest
 
 from photon_tpu.config.schema import Config
 
-from tests._helpers import tiny_llama_config
+from tests._helpers import assert_logits_match, tiny_llama_config
 
 
 def _serve_cfg(*, alibi=False, llama=False, n_slots=2, block_size=4,
@@ -181,16 +181,17 @@ def test_prefix_cache_cap_eviction_prefers_unpinned():
 
 
 # ---------------------------------------------------------------------------
-# 3. bit-parity: cached admission == cold admission, per step
+# 3. parity: cached admission against cold admission, per step
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("name", ["mpt-wpe", "mpt-alibi", "llama-gqa"])
-def test_cached_admission_bitexact_per_step(name):
+def test_cached_admission_matches_cold_per_step(name):
     """The acceptance pin: admit a donor (cold), evict it, admit a second
     request re-using its cached prefix blocks; drive BOTH that engine and
-    a cache-less twin step by step — every step's logits must be identical
-    bitwise, starting from the first sampled token."""
+    a cache-less twin step by step — every step's logits within
+    ``SERVE_LOGITS_ATOL`` and the same token, starting from the first
+    sampled one."""
     from photon_tpu.models.mpt import init_params
     from photon_tpu.serve.cache import paged_decode_step
     from photon_tpu.serve.engine import PagedEngine
@@ -212,15 +213,15 @@ def test_cached_admission_bitexact_per_step(name):
     first_w = warm.admit(0, probe, 8)
     assert warm.prefix_cache.tokens_cached == 12  # the hit actually happened
     first_c = cold.admit(0, probe, 8)
-    assert first_w == first_c  # first token: argmax of identical logits
+    assert first_w == first_c  # first token: the two prefills' argmax
     tok = first_w
     active = jnp.asarray([True, False])
     sw, sc = warm.state, cold.state
-    for _ in range(6):  # per-step logits, bitwise
+    for step in range(6):
         t = jnp.asarray([tok, 0], jnp.int32)
         lw, sw = paged_decode_step(params, sw, t, mc, active)
         lc, sc = paged_decode_step(params, sc, t, mc, active)
-        np.testing.assert_array_equal(np.asarray(lw[0]), np.asarray(lc[0]))
+        assert_logits_match(lw[0], lc[0], err_msg=f"step {step}")
         tok = int(jnp.argmax(lw[0]))
 
 
