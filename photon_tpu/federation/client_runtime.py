@@ -132,13 +132,16 @@ class ClientRuntime:
         """Cache the round's global params (reference: NM params shm write,
         ``client_app.py:104-115``). The broadcast doubles as the wire
         codec's delta base: this round's fit results upload as
-        ``w_new − w_global`` against exactly these arrays."""
-        self._current_params = self.transport.get(ptr, copy=True)
+        ``w_new − w_global`` against exactly these arrays. On the shm plane
+        they are read-only views of the server's segment: holding them holds
+        its mapping, and rebinding here is what lets the previous round's
+        pages go."""
+        self._current_params = self.transport.get(ptr)
         self.transport.set_reference(self._current_params[1])
 
     def _resolve_params(self, ptr) -> tuple[ParamsMetadata, list[np.ndarray]]:
         if ptr is not None:
-            self._current_params = self.transport.get(ptr, copy=True)
+            self._current_params = self.transport.get(ptr)
             self.transport.set_reference(self._current_params[1])
         if self._current_params is None:
             raise RuntimeError("no parameters: neither FitIns pointer nor prior broadcast")
@@ -234,10 +237,11 @@ class ClientRuntime:
         self.trainer.set_parameters(base_meta, params_in)
         # ``initial`` exists only to difference the pseudo-grad norm below.
         # When no personalize/randomize knob touched the params, params_in
-        # still aliases the cached broadcast arrays — which nothing mutates
-        # (set_parameters device_puts; fit returns FRESH host arrays) — so
-        # the ~full-model copy (~500 MB/client/round at 125M) is skipped
-        # and the norm is computed against the held broadcast reference.
+        # still aliases the cached broadcast arrays — which nothing CAN
+        # mutate (read-only views on the shm plane; set_parameters
+        # device_puts; fit returns FRESH host arrays) — so the ~full-model
+        # copy (~500 MB/client/round at 125M) is skipped and the norm is
+        # computed against the held broadcast reference.
         initial = [a.copy() for a in params_in] if params_touched else params_in
 
         # reset knobs (reference: ``load_ignore_keys`` globs, ``clients/utils.py:219-249``)
@@ -335,7 +339,7 @@ class ClientRuntime:
             # client's outgoing delta — the trainer's own arrays are never
             # mutated, only the copy that ships. Downstream, the aggregate
             # norm goes NaN and the health sentinel must flip /statusz.
-            poisoned = np.array(arrays[0], copy=True)
+            poisoned = np.array(arrays[0])
             poisoned.reshape(-1)[:1] = np.nan
             arrays = [poisoned, *arrays[1:]]
         # uplink payloads go through the wire codec when one is configured

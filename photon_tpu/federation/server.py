@@ -312,9 +312,12 @@ class ServerApp:
             ]
             if bad:
                 raise RuntimeError(f"broadcast failed on nodes {bad}: {[acks[n].detail for n in bad]}")
-            # free the PREVIOUS round's segment only now: nodes have copied the
-            # new payload (ack'd), nothing references the old one (reference:
-            # Ray GC thread / per-round shm unlink, ``utils.py:73-144``)
+            # free the PREVIOUS round's segment only now: nodes have MAPPED the
+            # new payload (ack'd) and dropped their views of the old one when
+            # ``set_broadcast_params`` rebound them. The free removes the old
+            # segment's name; its pages go with the last view of it, so one
+            # still held anywhere stays valid (reference: Ray GC thread /
+            # per-round shm unlink, ``utils.py:73-144``)
             if self._last_broadcast is not None:
                 self.transport.free(self._last_broadcast.params)
             self._last_broadcast = msg

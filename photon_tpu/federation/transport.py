@@ -161,11 +161,16 @@ class ParamTransport:
     def get(
         self,
         ptr: ParamPointer,
-        copy: bool = True,
         timeout: float = 120.0,
         decode: bool = True,
     ) -> tuple[ParamsMetadata, list[np.ndarray] | CompressedPayload]:
         """Resolve a pointer to ``(metadata, arrays)``.
+
+        The arrays are the caller's to READ and to keep: on the shm plane
+        they are read-only views of the mapped segment, which outlive
+        :meth:`free` and a later :meth:`put` under the same tag (the mapping
+        goes when the last of them does); on the inline plane they alias the
+        sender's. Whoever needs to write copies first.
 
         For codec-compressed pointers, ``decode=False`` returns
         ``(metadata, CompressedPayload)`` instead — the streaming
@@ -177,10 +182,9 @@ class ParamTransport:
         codec_info = meta_d.get("codec")
         if codec_info is None:
             self.stats.record_recv(metadata.total_bytes, metadata.total_bytes)
-            return self._get_raw(ptr, metadata, copy=copy, timeout=timeout)
+            return self._get_raw(ptr, metadata, timeout)
         _, (blob,) = self._get_raw(
-            ptr, _blob_metadata(int(codec_info["wire_nbytes"])),
-            copy=False, timeout=timeout,
+            ptr, _blob_metadata(int(codec_info["wire_nbytes"])), timeout
         )
         payload = CompressedPayload.from_bytes(bytes(blob))
         self.stats.record_recv(metadata.total_bytes, payload.wire_nbytes)
@@ -197,20 +201,25 @@ class ParamTransport:
         return metadata, arrays
 
     def _get_raw(
-        self, ptr: ParamPointer, metadata: ParamsMetadata, copy: bool, timeout: float
+        self, ptr: ParamPointer, metadata: ParamsMetadata, timeout: float
     ) -> tuple[ParamsMetadata, list[np.ndarray]]:
         """The plane read alone (waiting for the object included; decoding a
-        compressed payload is the caller's span)."""
+        compressed payload is the caller's span). ``copied_nbytes`` is what
+        the read materialises on the host: the payload out of the object
+        store's npz, nothing where the arrays are views (shm) or aliases
+        (inline)."""
+        copied = metadata.total_bytes if ptr.kind == "objstore" else 0
         with telemetry.span(TRANSPORT_GET_SPAN, push=False, mode=ptr.kind,
-                            copy=copy, wire_nbytes=metadata.total_bytes):
-            return self._read(ptr, metadata, copy, timeout)
+                            copied_nbytes=copied,
+                            wire_nbytes=metadata.total_bytes):
+            return self._read(ptr, metadata, timeout)
 
     def _read(
-        self, ptr: ParamPointer, metadata: ParamsMetadata, copy: bool, timeout: float
+        self, ptr: ParamPointer, metadata: ParamsMetadata, timeout: float
     ) -> tuple[ParamsMetadata, list[np.ndarray]]:
         if ptr.kind == "shm":
             shm.wait_for(ptr.locator, timeout=timeout)
-            got_meta, arrays = shm.read_params(ptr.locator, copy=copy)
+            got_meta, arrays = shm.read_params(ptr.locator)
             metadata.validate_arrays(arrays)
             return got_meta, arrays
         if ptr.kind == "objstore":

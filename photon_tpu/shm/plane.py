@@ -20,8 +20,12 @@ Design differences (deliberate, TPU-host-native):
   memcpy), the analog of the reference's threaded ``set_parameters_shm``
   (``shm/utils.py:626-651``).
 
-Layout: ``[16B header][metadata JSON][payload bytes]``.
+Layout: ``[16B header][metadata JSON, space-padded][payload bytes]``.
 Header: magic ``u32``, version ``u32``, meta_len ``u32``, committed ``u32``.
+``meta_len`` counts the padding, which puts the payload on a 64-byte
+boundary of the file (and so of the page-aligned mapping); inside the
+payload every array starts on a multiple of its dtype's alignment. Readers
+map read-only: what they hand out are views, never copies.
 """
 
 from __future__ import annotations
@@ -43,6 +47,8 @@ SHM_DIR = pathlib.Path(os.environ.get("PHOTON_SHM_DIR", "/dev/shm"))
 _MAGIC = 0x50484F54  # "PHOT"
 _VERSION = 1
 _HEADER = struct.Struct("<IIII")
+_PAYLOAD_ALIGN = 64  # a cache line; what a zero-copy ``device_put`` asks for
+_MAP_POPULATE = getattr(mmap, "MAP_POPULATE", 0)  # Linux; elsewhere pages fault in
 _COPY_CHUNK = 64 << 20  # 64 MiB per copy task
 _POOL = ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1))
 
@@ -68,6 +74,7 @@ class ShmSegment:
         size: int | None = None,
         create: bool = False,
         path: pathlib.Path | None = None,
+        populate: bool = False,
     ):
         self.name = name
         p = path if path is not None else _path(name)
@@ -82,10 +89,17 @@ class ShmSegment:
                 os.close(fd)
             self.mm[: _HEADER.size] = _HEADER.pack(_MAGIC, _VERSION, 0, 0)
         else:
-            fd = os.open(p, os.O_RDWR)
+            # read-only: a write through a reader's view raises instead of
+            # changing the segment under its other readers. ``populate``
+            # (for a reader that will touch every page) fills the page table
+            # in the one call: where a fault is dear, 0.03 s for 0.5 GB
+            # against 0.87 s of faults under the first read pass, a
+            # ``device_put`` included (PERF.md section 6, PR 32)
+            flags = mmap.MAP_SHARED | (_MAP_POPULATE if populate else 0)
+            fd = os.open(p, os.O_RDONLY)
             try:
                 total = os.fstat(fd).st_size
-                self.mm = mmap.mmap(fd, total)
+                self.mm = mmap.mmap(fd, total, flags=flags, prot=mmap.PROT_READ)
             finally:
                 os.close(fd)
             magic, version, _, _ = _HEADER.unpack_from(self.mm, 0)
@@ -143,30 +157,46 @@ def _parallel_copy(dst: memoryview, src: memoryview) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _array_offsets(metadata: ParamsMetadata) -> tuple[list[int], int]:
+    """Where each array starts inside the payload, and the payload's length:
+    arrays are packed, except that one starts on the next multiple of its
+    dtype's alignment (a payload of one dtype has no gaps)."""
+    offsets, off = [], 0
+    for dtype, nbytes in zip(metadata.dtypes, metadata.nbytes_each):
+        align = np.dtype(dtype).alignment
+        off = -(-off // align) * align
+        offsets.append(off)
+        off += nbytes
+    return offsets, off
+
+
 def write_params(name: str, metadata: ParamsMetadata, arrays: list[np.ndarray]) -> None:
     """Serialize the flat array list into the named segment and commit."""
     metadata.validate_arrays(arrays)
     meta_bytes = metadata.to_json().encode()
+    # trailing spaces (JSON ignores them, ``meta_len`` delimits them) put the
+    # payload on a _PAYLOAD_ALIGN boundary, so readers' views are aligned
+    meta_bytes += b" " * (-(_HEADER.size + len(meta_bytes)) % _PAYLOAD_ALIGN)
+    offsets, payload_len = _array_offsets(metadata)
     # write into a private temp file, then atomically rename over the final
     # name: readers (wait_for / read_params) only ever map a fully-committed
     # segment — no window where a stale committed=1 header fronts new bytes
     final = _path(name)
     tmp = final.parent / (final.name + f".tmp-{os.getpid()}")
-    seg = ShmSegment(name, size=len(meta_bytes) + metadata.total_bytes, create=True, path=tmp)
+    seg = ShmSegment(name, size=len(meta_bytes) + payload_len, create=True, path=tmp)
     try:
         body = seg.body()
         try:
             body[: len(meta_bytes)] = meta_bytes
-            off = len(meta_bytes)
-            for a in arrays:
+            for a, off in zip(arrays, offsets):
                 a = np.ascontiguousarray(a)
                 raw = a.reshape(-1).view(np.uint8)
-                chunk = body[off : off + a.nbytes]
+                start = len(meta_bytes) + off
+                chunk = body[start : start + a.nbytes]
                 try:
                     _parallel_copy(chunk, memoryview(raw))
                 finally:
                     chunk.release()
-                off += a.nbytes
         finally:
             body.release()
         seg.commit(len(meta_bytes))
@@ -178,32 +208,27 @@ def write_params(name: str, metadata: ParamsMetadata, arrays: list[np.ndarray]) 
     os.rename(tmp, final)
 
 
-def read_params(name: str, copy: bool = False) -> tuple[ParamsMetadata, list[np.ndarray]]:
+def read_params(name: str) -> tuple[ParamsMetadata, list[np.ndarray]]:
     """Map the segment and return (metadata, arrays).
 
-    ``copy=False`` returns zero-copy views valid until the segment is
-    unlinked; the reference deep-copies before unlink for the same
-    use-after-free reason (``node_manager_app.py:560-567``)."""
-    seg = ShmSegment(name)
+    The arrays are read-only views of the mapping, and the mapping lives as
+    long as any of them does: unlinking the segment, or renaming a new one
+    over its name, removes the NAME, and the pages go when the last view
+    dies (tmpfs keeps an unlinked file while it is mapped). So a reader
+    never needs a private copy to outlive the writer's clean-up; whoever
+    wants to write copies first."""
+    seg = ShmSegment(name, populate=True)
     if not seg.committed:
         seg.close()
         raise BlockingIOError(f"segment {name!r} not committed yet")
     meta = ParamsMetadata.from_json(bytes(seg.body()[: seg.meta_len]).decode())
     payload = seg.payload()
-    arrays: list[np.ndarray] = []
-    off = 0
-    for shape, dtype, nbytes in zip(meta.shapes, meta.dtypes, meta.nbytes_each):
-        view = np.frombuffer(
+    arrays = [
+        np.frombuffer(
             payload, dtype=np.dtype(dtype), count=int(np.prod(shape, dtype=np.int64)), offset=off
         ).reshape(shape)
-        arrays.append(view.copy() if copy else view)
-        del view
-        off += nbytes
-    if copy:
-        # all refs to the buffer dropped → the map can close now; zero-copy
-        # readers instead keep the mapping alive through the views
-        payload.release()
-        seg.close()
+        for shape, dtype, off in zip(meta.shapes, meta.dtypes, _array_offsets(meta)[0])
+    ]
     return meta, arrays
 
 

@@ -216,14 +216,16 @@ def aggregate_inplace(
     if n_total <= 0:
         raise ValueError(f"non-positive n_samples {n_total}")
 
-    # order="C": _fold_into relies on acc.reshape(-1) being a VIEW — an
-    # already-fp64 non-contiguous first payload would otherwise pass through
-    # asarray unchanged and every later fold would land in a discarded copy
+    # np.array, not asarray: the accumulator is OURS to write, and the first
+    # payload is not (a read-only view of the sender's segment on the shm
+    # plane, the sender's own arrays on the inline one) — an already-fp64
+    # payload would pass through asarray as itself. order="C": _fold_into
+    # relies on acc.reshape(-1) being a VIEW of the accumulator
     with fold_span() as sp:
         if pool is not None:
-            acc = pool.map(lambda a: np.asarray(a, dtype=np.float64, order="C"), arrays)
+            acc = pool.map(lambda a: np.array(a, dtype=np.float64, order="C"), arrays)
         else:
-            acc = [np.asarray(a, dtype=np.float64, order="C") for a in arrays]
+            acc = [np.array(a, dtype=np.float64, order="C") for a in arrays]
     t_fold[0] += sp.seconds
 
     pipelined = pool is not None and pool.pipelined

@@ -1,5 +1,6 @@
-"""Shared helpers: subprocess environments, the tiny llama config, and the
-tolerance at which two serving programs' logits are held to each other.
+"""Shared helpers: subprocess environments, the tiny llama config, the
+tolerance at which two serving programs' logits are held to each other, and
+what the shm plane's views look like from outside.
 
 A plain module (NOT conftest) so test files can import it without
 re-executing conftest's module-level jax.config setup under a second module
@@ -38,6 +39,19 @@ def assert_logits_match(got, want, err_msg: str = "") -> None:
     np.testing.assert_allclose(got, want, rtol=0, atol=SERVE_LOGITS_ATOL,
                                err_msg=err_msg)
     assert int(got.argmax(-1)) == int(want.argmax(-1)), err_msg
+
+
+def shm_mappings(name: str = "") -> list[str]:
+    """This process's mappings of shm-plane segments whose name starts with
+    ``name`` (all of them by default), as ``/proc/self/maps`` lines: an
+    unlinked segment keeps its path there, with `` (deleted)`` after it."""
+    with open("/proc/self/maps") as f:
+        return [line.rstrip() for line in f if f"/photon-{name}" in line]
+
+
+def is_readonly_view(a) -> bool:
+    """What the shm plane hands a reader: the mapping's memory, unwritable."""
+    return not a.flags.writeable and not a.flags.owndata
 
 
 def free_port() -> int:

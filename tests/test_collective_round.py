@@ -317,3 +317,39 @@ def test_collective_runner_resume_via_load_server_state(tmp_path):
     for a, b in zip(cont.strategy.current_parameters,
                     resumed.strategy.current_parameters):
         np.testing.assert_array_equal(a, b)
+
+
+def test_collective_round_reads_fetched_views_after_the_free(tmp_path):
+    """``run_round`` gets each client's upload, frees it at once and folds
+    later. On the shm plane the arrays are then read-only views of a segment
+    whose name is gone: they must still hold the client's values, so the
+    round comes out as on the inline plane, bit for bit."""
+    from photon_tpu.federation.collective_round import CollectiveFedRunner
+    from photon_tpu.federation.transport import ParamTransport
+    from tests._helpers import is_readonly_view, shm_mappings
+
+    cfg = _plane_cfg(tmp_path / "inline", "off", n_rounds=2)
+    inline = CollectiveFedRunner(cfg, [0, 1])
+    inline.run(2)
+
+    cfg = _plane_cfg(tmp_path / "shm", "off", n_rounds=2)
+    runner = CollectiveFedRunner(cfg, [0, 1])
+    runner.transport = runner.runtime.transport = ParamTransport("shm")
+    aggregate, folded = runner._aggregate_elastic, []
+
+    def spy(server_round, landed):
+        folded.extend(a for arrays, _ in landed.values() for a in arrays)
+        return aggregate(server_round, landed)
+
+    runner._aggregate_elastic = spy
+    try:
+        runner.run(2)
+    finally:
+        runner.transport.cleanup()
+    assert folded and all(map(is_readonly_view, folded))
+    # only what the test itself still holds: the uploads, unlinked long ago
+    held = shm_mappings()
+    assert held and all(line.endswith("(deleted)") for line in held)
+    for a, b in zip(inline.strategy.current_parameters,
+                    runner.strategy.current_parameters):
+        assert a.tobytes() == b.tobytes()

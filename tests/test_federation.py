@@ -6,6 +6,8 @@ Reference oracles (SURVEY.md §4): norm telemetry presence, deterministic
 client sampling incl. resume fast-forward, TooManyFailuresError budget.
 """
 
+import gc
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,7 @@ from photon_tpu.federation import (
     ServerApp,
     TooManyFailuresError,
 )
+from tests._helpers import is_readonly_view, shm_mappings
 
 
 def make_cfg(tmp_path, **fl_kw) -> Config:
@@ -53,11 +56,11 @@ def make_cfg(tmp_path, **fl_kw) -> Config:
     return cfg.validate()
 
 
-def make_app(cfg, tmp_path, n_nodes=2, with_ckpt=False):
-    transport = ParamTransport("inline")
+def make_app(cfg, tmp_path, n_nodes=2, with_ckpt=False, mode="inline"):
+    transport = ParamTransport(mode)
 
     def make_agent(node_id):
-        return NodeAgent(cfg, node_id, lambda: ParamTransport("inline"))
+        return NodeAgent(cfg, node_id, lambda: ParamTransport(mode))
 
     driver = InProcessDriver(cfg, make_agent, n_nodes=n_nodes)
     ckpt = None
@@ -222,3 +225,75 @@ def test_refresh_period_broadcast(tmp_path):
     history = app.run()
     assert len(history.series("server/round_time")) == 3
     app.driver.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the shm plane hands its readers views of the segment (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+
+def _photon_mappings() -> int:
+    return len(shm_mappings())
+
+
+def _shm_run(tmp_path, strategy: str, n_rounds: int) -> list[np.ndarray]:
+    cfg = make_cfg(tmp_path, n_total_clients=2, n_clients_per_round=2,
+                   n_rounds=n_rounds, strategy_name=strategy,
+                   server_learning_rate=1.0 if strategy == "fedavg" else 0.01)
+    app = make_app(cfg, tmp_path, n_nodes=1, mode="shm")
+    try:
+        app.run()
+        return [np.array(a) for a in app.strategy.current_parameters]
+    finally:
+        app.free_transport()
+        app.driver.shutdown()
+
+
+@pytest.mark.parametrize("strategy", ["fedavg", "fedadam"])
+def test_shm_views_give_the_bytes_deep_copies_gave(tmp_path, monkeypatch, strategy):
+    """Two rounds with every reader on views of the mapped segments against
+    the same run with ``get`` deep-copying every array, as it did before:
+    the same float32 values reach the device, the fold and the norms in the
+    same order, so the global parameters are the same bytes."""
+    viewed = _shm_run(tmp_path / "views", strategy, n_rounds=2)
+
+    plain_get = ParamTransport.get
+    seen = []
+
+    def copying_get(self, ptr, **kw):
+        meta, arrays = plain_get(self, ptr, **kw)
+        seen.append(all(map(is_readonly_view, arrays)))
+        return meta, [a.copy() for a in arrays]
+
+    monkeypatch.setattr(ParamTransport, "get", copying_get)
+    copied = _shm_run(tmp_path / "copies", strategy, n_rounds=2)
+    # a round: the node's read of the broadcast, the server's of each upload
+    assert len(seen) == 2 * 3 and all(seen), "every get was a read-only view"
+    assert len(viewed) == len(copied)
+    for a, b in zip(viewed, copied):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+def test_ten_shm_rounds_leave_no_mapping_behind(tmp_path):
+    """The leak guard: a view holds its segment's mapping, so views that
+    pile up are memory that piles up. Inside the run at most the live
+    broadcast stays mapped between rounds (on the CPU backend ``device_put``
+    may alias an aligned view, so this also trains on, and donates, buffers
+    whose segment was freed); after it, nothing."""
+    gc.collect()
+    before = _photon_mappings()  # what earlier tests of this process still hold
+    cfg = make_cfg(tmp_path, n_total_clients=2, n_clients_per_round=2, n_rounds=10)
+    app = make_app(cfg, tmp_path, n_nodes=1, mode="shm")
+    try:
+        for rnd in range(1, 11):
+            app.run_round(rnd)
+            gc.collect()
+            # the node's hold on this round's broadcast, and nothing older
+            assert 1 <= _photon_mappings() - before <= 2, f"round {rnd}"
+    finally:
+        app.free_transport()
+        app.driver.shutdown()
+    assert app.server_steps_cumulative == 10 * cfg.fl.local_steps
+    del app
+    gc.collect()
+    assert _photon_mappings() <= before

@@ -2,8 +2,10 @@
 (reference behavioral oracle: single-writer/single-reader + spin-wait,
 ``photon/shm/utils.py``)."""
 
+import gc
 import multiprocessing as mp
 import os
+import tracemalloc
 import uuid
 
 import numpy as np
@@ -20,7 +22,8 @@ from photon_tpu.shm import (
     write_params,
     write_scalar,
 )
-from photon_tpu.shm.plane import cleanup_stale, sweep_stale_tmp
+from photon_tpu.shm.plane import _HEADER, _MAGIC, _VERSION, _path, cleanup_stale, sweep_stale_tmp
+from tests._helpers import is_readonly_view, shm_mappings
 
 
 @pytest.fixture
@@ -55,11 +58,11 @@ def test_zero_copy_views_stable_across_rewrite(name):
     arrays = _arrays()
     meta = ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays)
     write_params(name, meta, arrays)
-    _, views = read_params(name, copy=False)
+    _, views = read_params(name)
     mutated = [a * 2 for a in arrays]
     write_params(name, meta, mutated)
     np.testing.assert_array_equal(views[0], arrays[0])  # old mapping intact
-    _, fresh = read_params(name, copy=True)
+    _, fresh = read_params(name)
     np.testing.assert_array_equal(fresh[0], mutated[0])
 
 
@@ -86,7 +89,7 @@ def test_blob_and_scalar(name):
 
 def _child(name: str, q) -> None:
     wait_for(name, timeout=20)
-    meta, arrays = read_params(name, copy=True)
+    meta, arrays = read_params(name)
     q.put((meta.names, [float(a.sum()) for a in arrays]))
 
 
@@ -164,6 +167,142 @@ def test_large_params_threaded_copy(name):
     big = [np.arange(20_000_000, dtype=np.float32)]  # 80 MB
     meta = ParamsMetadata.from_ndarrays(["big"], big)
     write_params(name, meta, big)
-    _, out = read_params(name, copy=False)
+    _, out = read_params(name)
     np.testing.assert_array_equal(out[0][:5], big[0][:5])
     np.testing.assert_array_equal(out[0][-5:], big[0][-5:])
+
+
+# ---------------------------------------------------------------------------
+# readers get views of the mapping: read-only, aligned, with a life of their
+# own (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+
+def _mapped(name: str) -> int:
+    return len(shm_mappings(name))
+
+
+def test_views_are_read_only(name):
+    arrays = _arrays()
+    write_params(name, ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays), arrays)
+    _, views = read_params(name)
+    for v in views:
+        assert is_readonly_view(v)
+        with pytest.raises(ValueError):
+            v[...] = 0
+        with pytest.raises(ValueError):
+            v.setflags(write=True)  # the mapping itself is read-only
+    _, again = read_params(name)  # another reader of the same segment
+    for a, b in zip(arrays, again):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("json_len", range(100, 171))
+def test_payload_and_views_are_aligned_whatever_the_metadata_length(name, json_len):
+    """The payload used to start at ``16 + len(JSON)``: aligned one length
+    in four. Lengths 100-170 cover every residue of 64 (and of 4)."""
+    # 20 bytes, then 3: the float64 that follows needs a byte of padding
+    arrays = [np.arange(5, dtype=np.float32), np.arange(3, dtype=np.int8),
+              np.arange(4, dtype=np.float64)]
+    bare = len(ParamsMetadata.from_ndarrays(["", "b", "c"], arrays).to_json())
+    names = ["n" * (json_len - bare), "b", "c"]
+    meta = ParamsMetadata.from_ndarrays(names, arrays)
+    assert len(meta.to_json()) == json_len
+    write_params(name, meta, arrays)
+    raw = _path(name).read_bytes()
+    meta_len = _HEADER.unpack_from(raw)[2]
+    assert (_HEADER.size + meta_len) % 64 == 0
+    assert raw[_HEADER.size : _HEADER.size + meta_len].rstrip(b" ") == meta.to_json().encode()
+    meta2, views = read_params(name)
+    assert meta2 == meta
+    assert views[0].ctypes.data % 64 == 0  # the mapping is page-aligned
+    for a, v in zip(arrays, views):
+        assert v.flags.aligned, (v.dtype, v.ctypes.data)
+        np.testing.assert_array_equal(a, v)
+
+
+def test_old_layout_segment_still_reads(name):
+    """A segment whose JSON is not padded (what the writer produced before
+    the payload was aligned) reads to the same values; its views are as
+    aligned as its offset lets them be."""
+    arrays = [np.arange(6, dtype=np.float32).reshape(2, 3), np.arange(4, dtype=np.float32)]
+    meta = ParamsMetadata.from_ndarrays(["aa", "b"], arrays)
+    meta_bytes = meta.to_json().encode()
+    assert (_HEADER.size + len(meta_bytes)) % 4 != 0  # the unaligned three in four
+    _path(name).write_bytes(
+        _HEADER.pack(_MAGIC, _VERSION, len(meta_bytes), 1) + meta_bytes
+        + b"".join(a.tobytes() for a in arrays)
+    )
+    meta2, views = read_params(name)
+    assert meta2 == meta
+    assert not views[0].flags.aligned and not views[0].flags.writeable
+    for a, v in zip(arrays, views):
+        np.testing.assert_array_equal(a, v)
+
+
+def test_mapping_lives_exactly_as_long_as_its_views(name):
+    arrays = _arrays()
+    meta = ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays)
+    write_params(name, meta, arrays)
+    assert _mapped(name) == 0  # the writer's mapping is closed
+    _, views = read_params(name)
+    assert _mapped(name) == 1
+    unlink(name)  # the name goes, the pages stay while mapped
+    assert not _path(name).exists()
+    np.testing.assert_array_equal(views[2], arrays[2])
+    last = views[1]
+    del views
+    gc.collect()
+    assert _mapped(name) == 1  # one view is enough to hold it
+    np.testing.assert_array_equal(last, arrays[1])
+    del last
+    gc.collect()
+    assert _mapped(name) == 0
+
+
+def test_transport_views_survive_free_and_a_reput_of_the_tag(name):
+    from photon_tpu.federation.transport import ParamTransport
+
+    arrays = _arrays()
+    meta = ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays)
+    tr = ParamTransport("shm")
+    try:
+        ptr = tr.put(name, meta, arrays)
+        _, views = tr.get(ptr)
+        assert all(map(is_readonly_view, views))
+        tr.free(ptr)
+        for a, v in zip(arrays, views):
+            np.testing.assert_array_equal(a, v)
+        ptr2 = tr.put(name, meta, [a * 3 for a in arrays])  # same tag, new file
+        _, fresh = tr.get(ptr2)
+        for a, v, f in zip(arrays, views, fresh):
+            np.testing.assert_array_equal(a, v)  # the old contents
+            np.testing.assert_array_equal(a * 3, f)
+        assert _mapped(name) == 2
+        del views, fresh, v, f
+        gc.collect()
+        assert _mapped(name) == 0
+    finally:
+        tr.cleanup()
+
+
+def test_transport_get_allocates_nothing_model_sized(name):
+    """64 MB through ``get`` on the shm plane: two mmaps and a JSON parse.
+    (The parent's ``copy=True`` default allocated the payload again.)"""
+    from photon_tpu.federation.transport import ParamTransport
+
+    big = [np.ones(8 << 20, np.float32), np.ones(8 << 20, np.float32)]  # 2 x 32 MB
+    meta = ParamsMetadata.from_ndarrays(["w0", "w1"], big)
+    tr = ParamTransport("shm")
+    try:
+        ptr = tr.put(name, meta, big)
+        tracemalloc.start()
+        try:
+            _, views = tr.get(ptr)
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        assert peak < 1 << 20, f"get allocated {peak} bytes"
+        assert float(views[1][-1]) == 1.0
+    finally:
+        tr.cleanup()

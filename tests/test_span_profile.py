@@ -84,7 +84,7 @@ SPAN_TABLE = [
     (P.TRANSPORT_PUT_SPAN, {P.BROADCAST_PRE_TIME, P.CLIENT_ENCODE_SPAN},
      {"mode", "nbytes", "wire_nbytes"}),
     (P.TRANSPORT_GET_SPAN, {P.NODE_SET_BROADCAST_SPAN, P.FIT_ROUND_TIME, None},
-     {"mode", "wire_nbytes"}),
+     {"mode", "copied_nbytes", "wire_nbytes"}),
     (P.TRANSPORT_FREE_SPAN, {P.BROADCAST_PRE_TIME, P.FIT_ROUND_TIME, None}, {"mode"}),
     (P.NODE_SET_BROADCAST_SPAN, {P.BROADCAST_PRE_TIME}, {"round", "node"}),
     (P.TRAINER_SET_PARAMETERS_SPAN, {P.CLIENT_FIT_SPAN}, {"nbytes"}),
@@ -207,6 +207,39 @@ def test_flash_kernels_keep_the_instruction_name_and_gain_their_own():
         debug_info=True)
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert f"{kernel}/multihead_attention" in text, kernel
+
+
+@pytest.mark.parametrize("mode", ["shm", "inline", "objstore"])
+def test_transport_get_says_what_it_copied(tmp_path, mode):
+    """``copied_nbytes`` is what the read materialised on the host: nothing
+    where the arrays are views of the segment (shm) or the sender's own
+    (inline), the payload out of the object store's npz. The caller's old
+    ``copy`` flag is gone with the parameter."""
+    import numpy as np
+
+    from photon_tpu.checkpoint import FileStore
+    from photon_tpu.codec import ParamsMetadata
+    from photon_tpu.config.schema import TelemetryConfig
+    from photon_tpu.federation import ParamTransport
+
+    arrays = [np.ones((64, 8), np.float32), np.arange(5, dtype=np.float32)]
+    meta = ParamsMetadata.from_ndarrays(["w", "b"], arrays)
+    tr = ParamTransport(mode, store=FileStore(tmp_path / "store"))
+    telemetry.install(TelemetryConfig(enabled=True), scope="server")
+    try:
+        ptr = tr.put(f"copied-{mode}-{tmp_path.name}", meta, arrays)
+        _, got = tr.get(ptr)
+        tr.free(ptr)
+    finally:
+        tr.cleanup()
+    (span,) = [d for d in telemetry.active().snapshot()
+               if d["name"] == P.TRANSPORT_GET_SPAN]
+    assert "copy" not in span["attrs"]
+    assert span["attrs"]["mode"] == mode
+    assert span["attrs"]["wire_nbytes"] == meta.total_bytes
+    assert span["attrs"]["copied_nbytes"] == (meta.total_bytes if mode == "objstore" else 0)
+    for a, b in zip(arrays, got):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_span_site_with_telemetry_off_stays_under_3_us():

@@ -44,6 +44,25 @@ def test_aggregate_inplace_matches_direct_mean_many():
     np.testing.assert_allclose(avg[0], direct, rtol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("threads", [1, 4])
+def test_aggregate_inplace_never_writes_a_payload(dtype, threads):
+    """The payloads are the sender's (the inline plane) or read-only views
+    of its segment (shm): the accumulator is a copy of the first even when
+    that is already float64 and would pass through ``asarray`` as itself."""
+    payloads = [([np.full((3, 5), v, dtype)], n) for v, n in ((1.0, 1), (4.0, 3), (2.0, 4))]
+    for arrays, _ in payloads:
+        arrays[0].setflags(write=False)
+    pool = HostPool(threads)
+    try:
+        avg, n = aggregate_inplace(iter(payloads), pool=pool)
+    finally:
+        pool.close()
+    assert n == 8
+    np.testing.assert_allclose(avg[0], np.full((3, 5), (1 + 12 + 8) / 8), rtol=1e-6)
+    assert [float(a[0][0, 0]) for a, _ in payloads] == [1.0, 4.0, 2.0]
+
+
 def test_aggregate_rejects_empty_and_bad_counts():
     with pytest.raises(ValueError):
         aggregate_inplace(iter([]))
