@@ -117,6 +117,31 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # A stack whose layers differ in kind (HF ``layer_types``, its list joined
+    # by commas so that YAML, JSON and ``--set`` all spell it alike): one entry
+    # a layer, ``mamba`` (a Mamba-2 mixer, ``ops/ssd.py``) or ``attention``;
+    # every layer keeps the block's norms, residuals and MLP. "" = attention
+    # everywhere, one scanned stack ``blocks``; otherwise each run of equal
+    # kind is a scanned stack of its own, ``blocks_0``, ``blocks_1``, ...
+    # (training path only). A Mamba-2 mixer has ``mamba_n_heads`` heads of
+    # ``mamba_d_head`` (the mixer's inner channels, ``mamba_d_inner``), one
+    # group of B and C of ``mamba_d_state``, a causal depthwise convolution
+    # of ``mamba_d_conv`` taps with bias over x | B | C, and scans in chunks
+    # of ``mamba_chunk_size`` positions (``max_seq_len`` a multiple of it).
+    layer_types: str = ""
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # Granite's four multipliers. At their defaults nothing is multiplied:
+    # the embedding's output, each residual branch and the logits (divided by
+    # ``logits_scaling``) are left as they are, and ``attention_multiplier``
+    # 0 keeps the softmax scale ``1/sqrt(d_head)``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
     attn_impl: str = AttnImpl.PALLAS.value
     # Numerics: params kept fp32, compute in bf16 (reference: amp_bf16 + FSDP
     # PURE mixed precision, ``mpt-125m.yaml:85-92``).
@@ -153,10 +178,47 @@ class ModelConfig:
         return self.moe_experts_held or self.moe_num_experts
 
     @property
+    def hybrid(self) -> bool:
+        """The layers differ in kind (``layer_types`` is set)."""
+        return bool(self.layer_types)
+
+    @property
+    def layer_kinds(self) -> tuple[str, ...]:
+        return tuple(k.strip() for k in self.layer_types.split(",")) if self.hybrid else ()
+
+    @property
+    def mamba_layers(self) -> int:
+        return self.layer_kinds.count("mamba")
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def layer_runs(self) -> list[tuple[str, int]]:
+        """``layer_types`` as runs of equal kind, in order: ``[(kind,
+        length), ...]``, one scanned stack each."""
+        runs: list[tuple[str, int]] = []
+        for kind in self.layer_kinds:
+            if runs and runs[-1][0] == kind:
+                runs[-1] = (kind, runs[-1][1] + 1)
+            else:
+                runs.append((kind, 1))
+        return runs
+
+    @property
+    def scaled(self) -> bool:
+        """Any of the four multipliers is off its default."""
+        return (self.embedding_multiplier != 1.0 or self.residual_multiplier != 1.0
+                or self.logits_scaling != 1.0 or self.attention_multiplier != 0.0)
+
+    @property
     def training_path_only(self) -> bool:
-        """Latent attention, the dropless expert layer or leading dense
-        blocks: what serving, cached decode, LoRA and the HF maps lack."""
-        return self.latent_attention or self.dropless_moe or self.first_k_dense > 0
+        """Latent attention, the dropless expert layer, leading dense blocks,
+        layers of different kinds or the multipliers: what serving, cached
+        decode, LoRA and the HF maps lack."""
+        return (self.latent_attention or self.dropless_moe or self.first_k_dense > 0
+                or self.hybrid or self.scaled)
 
     @property
     def d_head(self) -> int:
@@ -989,19 +1051,70 @@ class Config:
                     f"v_head_dim={m.v_head_dim} differs from qk_nope_head_dim + "
                     f"qk_rope_head_dim={m.qk_nope_head_dim + m.qk_rope_head_dim}: "
                     "the attention kernels take one head width for q, k and v")
+        self._validate_hybrid_family()
         if m.training_path_only:
             if m.lora_rank or self.photon.adapters.enabled:
                 raise ValueError(
                     "LoRA adapters (model.lora_rank / photon.adapters) are not "
-                    "supported with latent attention, the dropless expert layer "
-                    "or leading dense blocks: their projections are not adaptable "
-                    "modules yet")
+                    "supported with latent attention, the dropless expert layer, "
+                    "leading dense blocks, layer_types or the multipliers: "
+                    "their projections are not adaptable modules yet")
             if self.photon.serve.prefix_cache or self.photon.serve.enabled:
                 raise ValueError(
                     "photon.serve (and its prefix cache) is not supported with "
-                    "latent attention, the dropless expert layer or leading "
-                    "dense blocks: there is no latent paged cache or decode "
-                    "step for them yet")
+                    "latent attention, the dropless expert layer, leading "
+                    "dense blocks, layer_types or the multipliers: there is "
+                    "no cache or decode step for them yet")
+
+    def _validate_hybrid_family(self) -> None:
+        """``layer_types`` with its Mamba-2 sizes, and the four multipliers
+        (preset ``granite-4.0-h-micro-stage1``)."""
+        m = self.model
+        if min(m.embedding_multiplier, m.residual_multiplier, m.logits_scaling) <= 0 \
+                or m.attention_multiplier < 0:
+            raise ValueError(
+                "embedding_multiplier, residual_multiplier and logits_scaling "
+                "must be > 0, attention_multiplier >= 0 (0 = 1/sqrt(d_head))")
+        if m.attention_multiplier and (
+                m.attn_impl == AttnImpl.RING.value or self.mesh.sequence > 1):
+            raise ValueError(
+                "attention_multiplier is not supported with ring attention "
+                "(attn_impl='ring' / mesh.sequence > 1): its merge fixes the "
+                "scale at 1/sqrt(d_head)")
+        if (m.hybrid or m.scaled) and self.mesh.pipe > 1:
+            raise ValueError(
+                "mesh.pipe > 1 with layer_types or the multipliers is not "
+                "supported: the pipeline schedule embeds the tokens itself and "
+                "scans one uniform stack of blocks")
+        if not m.hybrid:
+            return
+        kinds = set(m.layer_kinds)
+        if len(m.layer_kinds) != m.n_layers or not kinds <= {"mamba", "attention"}:
+            raise ValueError(
+                f"layer_types needs n_layers={m.n_layers} comma-separated entries, "
+                f"each 'mamba' or 'attention'; got {len(m.layer_kinds)}: {sorted(kinds)}")
+        if m.first_k_dense or m.mlp == "moe" or m.latent_attention:
+            raise ValueError(
+                "layer_types does not combine with first_k_dense, mlp='moe' or "
+                "latent attention: a layer's kind picks its mixer only")
+        if m.mamba_layers:
+            sizes = (m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state,
+                     m.mamba_d_conv, m.mamba_chunk_size)
+            if min(sizes) <= 0:
+                raise ValueError(
+                    "a 'mamba' layer needs mamba_n_heads, mamba_d_head, "
+                    "mamba_d_state, mamba_d_conv and mamba_chunk_size all > 0")
+            if m.max_seq_len % m.mamba_chunk_size:
+                raise ValueError(
+                    f"max_seq_len={m.max_seq_len} is not a multiple of "
+                    f"mamba_chunk_size={m.mamba_chunk_size}: the scan walks "
+                    "whole chunks")
+            if self.mesh.sequence > 1 or self.mesh.tensor > 1:
+                raise ValueError(
+                    "mesh.sequence > 1 or mesh.tensor > 1 with 'mamba' layers "
+                    "is not supported: the scan carries its state along the "
+                    "whole row, and one group's B, C and gated norm span all "
+                    "heads")
 
     def validate(self) -> "Config":
         if self.fl.n_clients_per_round > self.fl.n_total_clients:
@@ -1639,6 +1752,8 @@ def refuse_training_only_family(model: ModelConfig, what: str) -> None:
         ("latent attention (kv_lora_rank > 0)", model.latent_attention),
         ("the dropless sigmoid router (moe_router='sigmoid')", model.dropless_moe),
         ("leading dense blocks (first_k_dense > 0)", model.first_k_dense > 0),
+        ("layers of different kinds (layer_types)", model.hybrid),
+        ("the embedding / residual / logits / attention multipliers", model.scaled),
     ) if on]
     if has:
         raise NotImplementedError(

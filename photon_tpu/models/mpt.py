@@ -20,6 +20,14 @@ and ``moe_router: sigmoid`` makes the stack's MLP the dropless expert layer of
 ``ops/moe.py`` with a shared expert. Norms, residuals and the attention
 dispatch are the one ``MPTBlock``'s.
 
+A hybrid family composes the same way again (preset
+``granite-4.0-h-micro-stage1``, training path only): ``layer_types`` gives
+every layer its mixer, attention or a Mamba-2 mixer (``MPTBlock._mamba_mixer``
+over ``ops/ssd.py``), and every run of equal kind is a scanned stack of its
+own (``blocks_0``, ``blocks_1``, ...); the four multipliers scale the
+embedding, each residual branch, the attention scores and the logits, and at
+their defaults add no operation to any other model's graph.
+
 TPU-first design choices (not in the reference):
 - Layers are stacked with ``nn.scan`` → one traced block, params carry a
   leading ``[n_layers, ...]`` axis. This keeps compile time flat in depth and
@@ -42,6 +50,12 @@ import jax.numpy as jnp
 
 from photon_tpu.config.schema import ModelConfig
 from photon_tpu.ops.attention import multihead_attention
+from photon_tpu.utils.profiling import (
+    MAMBA_CONV_SCOPE,
+    MAMBA_GATE_NORM_SCOPE,
+    MAMBA_PROJ_SCOPE,
+    MAMBA_SCAN_SCOPE,
+)
 
 
 def _dtype(name: str):
@@ -145,12 +159,86 @@ def apply_rope(q: jax.Array, k: jax.Array, theta: float) -> tuple[jax.Array, jax
 MLA_PROJ_SCOPE = "mla/proj"
 
 
+def _mamba_a_log_init(key, shape, dtype):
+    """``A = -exp(A_log)`` uniform in [-16, -1], as the public Mamba-2 code."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+
+
+def _mamba_dt_bias_init(key, shape, dtype):
+    """The inverse softplus of a step log-uniform in [1e-3, 1e-1], so that a
+    zero projection gives such a ``dt`` (the public Mamba-2 code's init)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _conv_init(taps: int):
+    """PyTorch's ``Conv1d`` default for a depthwise kernel of ``taps`` taps
+    and for its bias, which the public Mamba-2 code keeps: uniform in
+    ``+-1/sqrt(taps)``."""
+    bound = taps ** -0.5
+
+    def init(key, shape, dtype):
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound).astype(dtype)
+
+    return init
+
+
+def _residual(cfg: ModelConfig, x: jax.Array, branch: jax.Array) -> jax.Array:
+    """``x + residual_multiplier * branch``; at 1 the bare sum. (A function and
+    not a method of the block: flax names a method's operations after it, and
+    the other models' ``op_name``s stay as they were.)"""
+    m = cfg.residual_multiplier
+    return x + branch if m == 1.0 else x + branch * jnp.asarray(m, branch.dtype)
+
+
 class MPTBlock(nn.Module):
     cfg: ModelConfig
     #: a leading dense block of an expert model (``cfg.first_k_dense``): the
     #: same norms, residuals and attention, a SwiGLU of
     #: ``cfg.dense_mlp_hidden_size`` where the stack's blocks have experts
     dense_mlp: bool = False
+    #: what mixes the positions (``cfg.layer_types``): ``attention``, or
+    #: ``mamba`` for a Mamba-2 mixer in attention's place
+    mixer: str = "attention"
+
+    def _mamba_mixer(self, h: jax.Array, dense, resid_std: float) -> jax.Array:
+        """The Mamba-2 mixer on ``h [B, S, D]``: one projection to ``z | x B C
+        | dt``; a causal depthwise convolution and SiLU over ``x B C``; the
+        state-space scan (``ops/ssd.ssd_scan``: one group of ``B``, ``C`` for
+        all heads, float32 ``dt``, decays and state); the gate ``y * silu(z)``
+        before an RMSNorm over all inner channels; the projection back."""
+        from photon_tpu.ops import ssd
+
+        cfg = self.cfg
+        compute = _dtype(cfg.compute_dtype)
+        pd = _dtype(cfg.param_dtype)
+        b, s, _ = h.shape
+        inner, n, heads = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_n_heads
+        with jax.named_scope(MAMBA_PROJ_SCOPE):
+            zxbcdt = dense(2 * inner + 2 * n + heads, "in_proj", cfg.emb_init_std)(h)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
+        conv_init = _conv_init(cfg.mamba_d_conv)
+        conv_kernel = self.param(
+            "conv_kernel", conv_init, (cfg.mamba_d_conv, inner + 2 * n), pd)
+        conv_bias = self.param("conv_bias", conv_init, (inner + 2 * n,), pd)
+        a_log = self.param("A_log", _mamba_a_log_init, (heads,), jnp.float32)
+        dt_bias = self.param("dt_bias", _mamba_dt_bias_init, (heads,), jnp.float32)
+        skip = self.param("D", nn.initializers.ones, (heads,), jnp.float32)
+        with jax.named_scope(MAMBA_CONV_SCOPE):
+            xbc = nn.silu(ssd.causal_conv1d(xbc, conv_kernel, conv_bias)).astype(compute)
+        with jax.named_scope(MAMBA_SCAN_SCOPE):
+            y = ssd.ssd_scan(
+                xbc[..., :inner].reshape(b, s, heads, cfg.mamba_d_head),
+                nn.softplus(dt.astype(jnp.float32) + dt_bias), a_log,
+                xbc[..., inner:inner + n], xbc[..., inner + n:], skip,
+                # a row shorter than a chunk (``init_params``' 8 tokens) is one chunk
+                chunk=min(cfg.mamba_chunk_size, s), compute_dtype=compute)
+        with jax.named_scope(MAMBA_GATE_NORM_SCOPE):
+            y = y.reshape(b, s, inner) * nn.silu(z.astype(jnp.float32))
+            y = FP32RMSNorm(eps=cfg.norm_eps, name="mamba_norm")(y).astype(compute)
+        with jax.named_scope(MAMBA_PROJ_SCOPE):
+            return dense(cfg.d_model, "out_proj", resid_std)(y)
 
     def _latent_qkv(self, h: jax.Array, dense):
         """MLA in its training form: ``h [B, S, D]`` -> q, k, v
@@ -263,54 +351,59 @@ class MPTBlock(nn.Module):
 
         resid_std = cfg.emb_init_std / (2.0 * cfg.n_layers) ** 0.5
 
-        # --- attention ---
+        # --- the mixer: attention, or a Mamba-2 mixer in its place ---
         h = _norm(cfg, "ln_1")(x)
-        n_kv = cfg.n_kv_heads or cfg.n_heads
-        b, s, _ = h.shape
-        if cfg.latent_attention:
-            with jax.named_scope(MLA_PROJ_SCOPE):
-                q, k, v = self._latent_qkv(h, dense)
-        elif n_kv == cfg.n_heads:
-            qkv = adapted(3 * cfg.d_model, "wqkv", cfg.emb_init_std, h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+        if self.mixer == "mamba":
+            x = _residual(cfg, x, self._mamba_mixer(h, dense, resid_std))
         else:
-            # GQA: separate projections — a fused q||k||v matrix would put
-            # shard boundaries at positions that don't align with the
-            # tensor axis and force per-layer resharding; three
-            # column-parallel matmuls stay shard-local
-            q = adapted(cfg.n_heads * cfg.d_head, "q_proj", cfg.emb_init_std, h)
-            k = adapted(n_kv * cfg.d_head, "k_proj", cfg.emb_init_std, h)
-            v = adapted(n_kv * cfg.d_head, "v_proj", cfg.emb_init_std, h)
-        q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
-        k = k.reshape(b, s, n_kv, cfg.d_head)
-        v = v.reshape(b, s, n_kv, cfg.d_head)
-        if cfg.rope and not cfg.latent_attention:
-            # before the kv repeat: the rotation is per-head-identical, so
-            # rotating n_kv heads then replicating equals the reverse order
-            q, k = apply_rope(q, k, cfg.rope_theta)
-        # k/v go to the dispatch at their native n_kv width: the pallas
-        # flash kernel consumes GQA groups directly (index-mapped kv rows,
-        # no repeated tensor in HBM); the xla/ring paths replicate inside
-        # ops/attention.py
-        attn_out = multihead_attention(
-            q, k, v,
-            impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
-            interpret=cfg.attn_interpret,
-        )
-        if cfg.latent_attention:
-            with jax.named_scope(MLA_PROJ_SCOPE):
-                x = x + dense(cfg.d_model, "out_proj", resid_std)(
-                    attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim))
-        else:
-            attn_out = attn_out.reshape(b, s, cfg.d_model)
-            x = x + adapted(cfg.d_model, "out_proj", resid_std, attn_out)
+            n_kv = cfg.n_kv_heads or cfg.n_heads
+            b, s, _ = h.shape
+            if cfg.latent_attention:
+                with jax.named_scope(MLA_PROJ_SCOPE):
+                    q, k, v = self._latent_qkv(h, dense)
+            elif n_kv == cfg.n_heads:
+                qkv = adapted(3 * cfg.d_model, "wqkv", cfg.emb_init_std, h)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+            else:
+                # GQA: separate projections — a fused q||k||v matrix would put
+                # shard boundaries at positions that don't align with the
+                # tensor axis and force per-layer resharding; three
+                # column-parallel matmuls stay shard-local
+                q = adapted(cfg.n_heads * cfg.d_head, "q_proj", cfg.emb_init_std, h)
+                k = adapted(n_kv * cfg.d_head, "k_proj", cfg.emb_init_std, h)
+                v = adapted(n_kv * cfg.d_head, "v_proj", cfg.emb_init_std, h)
+            q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
+            k = k.reshape(b, s, n_kv, cfg.d_head)
+            v = v.reshape(b, s, n_kv, cfg.d_head)
+            if cfg.rope and not cfg.latent_attention:
+                # before the kv repeat: the rotation is per-head-identical, so
+                # rotating n_kv heads then replicating equals the reverse order
+                q, k = apply_rope(q, k, cfg.rope_theta)
+            # k/v go to the dispatch at their native n_kv width: the pallas
+            # flash kernel consumes GQA groups directly (index-mapped kv rows,
+            # no repeated tensor in HBM); the xla/ring paths replicate inside
+            # ops/attention.py
+            attn_out = multihead_attention(
+                q, k, v,
+                impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
+                interpret=cfg.attn_interpret,
+                # 0 -> None: the dispatch's own 1/sqrt(d_head)
+                scale=cfg.attention_multiplier or None,
+            )
+            if cfg.latent_attention:
+                with jax.named_scope(MLA_PROJ_SCOPE):
+                    x = _residual(cfg, x, dense(cfg.d_model, "out_proj", resid_std)(
+                        attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim)))
+            else:
+                attn_out = attn_out.reshape(b, s, cfg.d_model)
+                x = _residual(cfg, x, adapted(cfg.d_model, "out_proj", resid_std, attn_out))
 
         # --- MLP ---
         hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * cfg.d_model
         if self.dense_mlp:
             hidden = cfg.dense_mlp_hidden_size
         elif cfg.dropless_moe:
-            return x + self._dropless_moe(x, dense, hidden, resid_std)
+            return _residual(cfg, x, self._dropless_moe(x, dense, hidden, resid_std))
         h = _norm(cfg, "ln_2")(x)
         if cfg.mlp == "moe" and not self.dense_mlp:
             # expert-parallel MLP (ops/moe.py): router + E expert FFNs,
@@ -352,7 +445,7 @@ class MPTBlock(nn.Module):
             moe_out = _constrain_activation(
                 moe_out, P(("data", "fsdp", "expert"), "sequence", None)
             )
-            return x + moe_out
+            return _residual(cfg, x, moe_out)
         if cfg.mlp == "swiglu" or self.dense_mlp:
             # separate gate/up projections (standard llama layout): each is
             # column-parallel under the same sharding rule, so silu(gate)*up
@@ -365,8 +458,7 @@ class MPTBlock(nn.Module):
         else:
             h = adapted(hidden, "up_proj", cfg.emb_init_std, h)
             h = nn.gelu(h, approximate=True)
-        x = x + adapted(cfg.d_model, "down_proj", resid_std, h)
-        return x
+        return _residual(cfg, x, adapted(cfg.d_model, "down_proj", resid_std, h))
 
 
 class _ScanBlock(nn.Module):
@@ -375,10 +467,11 @@ class _ScanBlock(nn.Module):
 
     cfg: ModelConfig
     dense_mlp: bool = False
+    mixer: str = "attention"
 
     @nn.compact
     def __call__(self, carry: jax.Array, _: None):
-        return MPTBlock(self.cfg, self.dense_mlp, name="block")(carry), None
+        return MPTBlock(self.cfg, self.dense_mlp, self.mixer, name="block")(carry), None
 
 
 class MPTModel(nn.Module):
@@ -406,6 +499,8 @@ class MPTModel(nn.Module):
             name="wte",
         )
         x = wte(tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         # with ALiBi/RoPE the position signal lives in attention; no wpe
         if cfg.learned_pos_emb and not cfg.alibi and not cfg.rope:
             wpe = self.param(
@@ -424,7 +519,8 @@ class MPTModel(nn.Module):
                 prevent_cse=False,
             )
         # stack layers: params get a leading [n_layers] axis; single trace
-        def stack(length: int, name: str, dense_mlp: bool = False):
+        def stack(length: int, name: str, dense_mlp: bool = False,
+                  mixer: str = "attention"):
             return nn.scan(
                 block_cls,
                 # intermediates: per-layer MoE aux losses and counters stack
@@ -434,12 +530,17 @@ class MPTModel(nn.Module):
                 split_rngs={"params": True},
                 length=length,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, dense_mlp, name=name)
+            )(cfg, dense_mlp, mixer, name=name)
 
-        if cfg.first_k_dense:
-            # leading dense blocks under a scan of their own, beside the stack
-            x, _ = stack(cfg.first_k_dense, "dense_blocks", dense_mlp=True)(x, None)
-        x, _ = stack(cfg.n_layers - cfg.first_k_dense, "blocks")(x, None)
+        if cfg.hybrid:
+            # every run of equal kind under a scan of its own, in order
+            for i, (kind, length) in enumerate(cfg.layer_runs):
+                x, _ = stack(length, f"blocks_{i}", mixer=kind)(x, None)
+        else:
+            if cfg.first_k_dense:
+                # leading dense blocks under a scan of their own, beside the stack
+                x, _ = stack(cfg.first_k_dense, "dense_blocks", dense_mlp=True)(x, None)
+            x, _ = stack(cfg.n_layers - cfg.first_k_dense, "blocks")(x, None)
 
         x = _norm(cfg, "ln_f")(x)
         if return_hidden:
@@ -454,6 +555,8 @@ class MPTModel(nn.Module):
                 name="lm_head",
             )(x)
         logits = _constrain_logits(logits)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         return logits.astype(_dtype(cfg.logits_dtype))
 
 

@@ -45,15 +45,17 @@ def xla_attention(
     *,
     causal: bool = True,
     alibi: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
     """Plain softmax attention; XLA fuses mask+softmax into the matmuls.
 
     Numerically the oracle for the Pallas kernel's parity tests. ``alibi``
-    adds the per-head linear distance bias ``-slope_h * (q_pos - k_pos)``.
+    adds the per-head linear distance bias ``-slope_h * (q_pos - k_pos)``;
+    ``scale`` multiplies the scores (``None``: ``1/sqrt(d_head)``).
     """
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else float(scale)
     # [b, h, s_q, s_k] in fp32 for a stable softmax
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     scores = scores * scale
@@ -83,8 +85,11 @@ def multihead_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Dispatch on ``impl`` ∈ {pallas, xla, ring}. ``pallas`` runs the kernel
+    """Dispatch on ``impl`` ∈ {pallas, xla, ring}. ``scale`` multiplies the
+    scores before the softmax (``None``: ``1/sqrt(d_head)``; the ring merge
+    knows no other). ``pallas`` runs the kernel
     on a TPU (or anywhere under ``interpret``); on the CPU backend the tests
     use — and on no other — it steps down to ``xla_attention`` without a word
     (``flash_attention.pallas_supported``), so a CPU run never shows that the
@@ -116,6 +121,9 @@ def multihead_attention(
         mesh = current_mesh()
         inner = "pallas" if (pallas_supported(q) and not alibi) else "xla"
         if mesh is not None and mesh.shape.get("sequence", 1) > 1:
+            if scale is not None:
+                raise NotImplementedError(
+                    "ring attention fixes the softmax scale at 1/sqrt(d_head)")
             # GQA kv rides the ring at native width (group× less ppermute
             # traffic); ring_attention handles the groups in its chunk
             # kernel. Exception: kv heads that don't split over the tensor
@@ -151,7 +159,7 @@ def multihead_attention(
             if not sharded_axes:
                 return flash_attention(q, k, v, causal=causal, alibi=alibi,
                                        block_q=block_q, block_k=block_k,
-                                       interpret=interpret)
+                                       interpret=interpret, scale=scale)
             if h_kv % mesh.shape.get("tensor", 1):
                 # kv heads don't split over the tensor axis — replicate up
                 # to the q head count (which always splits; param_specs
@@ -173,7 +181,7 @@ def multihead_attention(
                 return flash_attention(q_s, k_s, v_s, causal=causal,
                                        alibi=alibi, alibi_slopes=sl,
                                        block_q=block_q, block_k=block_k,
-                                       interpret=interpret)
+                                       interpret=interpret, scale=scale)
 
             spec = P(("data", "fsdp", "expert"), None, "tensor", None)
             fn = shard_map(
@@ -187,4 +195,4 @@ def multihead_attention(
         impl = "xla"
     if impl != "xla":
         raise ValueError(f"unknown attention impl {impl!r}")
-    return xla_attention(q, rep(k), rep(v), causal=causal, alibi=alibi)
+    return xla_attention(q, rep(k), rep(v), causal=causal, alibi=alibi, scale=scale)

@@ -706,9 +706,9 @@ def flash_attention(
     causal: bool = True,
     alibi: bool = False,
     alibi_slopes: jax.Array | None = None,
-    block_q: int | None = None,
-    block_k: int | None = None,
+    block_q: int | None = None, block_k: int | None = None,
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
     """Flash attention over ``[batch, seq, heads, d_head]`` inputs.
 
@@ -726,8 +726,8 @@ def flash_attention(
     (tensor-parallel) caller passes ``alibi_slopes`` — its LOCAL [h] slice
     of the global slope table — so each shard biases with its true global
     head index (the in-kernel default would restart the slope sequence per
-    shard). ``interpret`` runs the kernel in the Pallas interpreter
-    (CPU-testable)."""
+    shard). ``interpret`` runs the kernel in the Pallas interpreter (CPU);
+    ``scale`` multiplies the scores before the softmax (``None``: 1/sqrt(d))."""
     b, s_q, h, d = q.shape
     h_kv = k.shape[2]
     if h % h_kv:
@@ -737,7 +737,7 @@ def flash_attention(
         # mismatch would silently read the wrong heads
         raise ValueError(f"k has {h_kv} heads but v has {v.shape[2]}")
     s_k = k.shape[1]
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else float(scale)
 
     d_pad = lane_padded(d)
     tiles = pick_tiles(s_q, s_k, d_pad, q.dtype.itemsize, h // h_kv, causal=causal,

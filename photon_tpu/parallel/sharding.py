@@ -47,6 +47,13 @@ _RULES: list[tuple[str, P]] = [
     (r"(q_a_proj|kv_a_proj)/kernel$", P("pipe", "fsdp", None)),
     (r"(q_b_proj|kv_b_proj)/kernel$", P("pipe", None, "tensor")),
     (r"(q_a_norm|kv_a_norm)/scale$", P("pipe")),
+    # a Mamba-2 mixer (models/mpt.py, ops/ssd.py): the in-projection's columns
+    # are z | x B C | dt, whose boundaries no tensor split respects, so they
+    # stay whole (its `out_proj` is row-parallel like attention's, below); the
+    # depthwise convolution and the per-head scalars are small and replicated
+    (r"in_proj/kernel$", P("pipe", "fsdp", None)),
+    (r"(conv_kernel|conv_bias|A_log|dt_bias|D)$", P("pipe")),
+    (r"mamba_norm/scale$", P("pipe")),
     # `up_proj` etc. also match the dropless layer's shared expert
     # (`shared_up_proj`, ...): the same column / row convention
     (r"(wqkv|up_proj|gate_proj|q_proj|k_proj|v_proj)/kernel$", P("pipe", "fsdp", "tensor")),

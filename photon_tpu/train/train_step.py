@@ -71,6 +71,7 @@ def _chunked_ce_sum(
         tf = jnp.pad(tf, (0, pad))
     mask = (jnp.arange(n_chunks * chunk) < n).astype(jnp.float32)
     emb_t = _output_embedding(model, params).astype(hidden.dtype).T  # [d, vocab]
+    logits_scaling = model.cfg.logits_scaling
 
     xs = xf.reshape(n_chunks, chunk, d)
     ts = tf.reshape(n_chunks, chunk)
@@ -79,6 +80,8 @@ def _chunked_ce_sum(
     def piece(carry, xtm):
         xc, tc, mc = xtm
         logits = jnp.dot(xc, emb_t, preferred_element_type=jnp.float32)
+        if logits_scaling != 1.0:
+            logits = logits / logits_scaling
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
         return carry + jnp.sum((lse - gold) * mc), None

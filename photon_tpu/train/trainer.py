@@ -75,6 +75,16 @@ def _flash_tile_attrs(model_cfg) -> dict[str, str]:
     ).attrs()
 
 
+def _mamba_attrs(model_cfg) -> dict[str, int]:
+    """The step's Mamba-2 layers and the chunks each one's scan walks a row
+    in, as span attributes: static counts, told once where the shapes are
+    known. Empty for a model without such layers."""
+    if not model_cfg.mamba_layers:
+        return {}
+    return {"mamba_layers": model_cfg.mamba_layers,
+            "ssd_chunks": model_cfg.max_seq_len // model_cfg.mamba_chunk_size}
+
+
 def _set_opt_count(opt_state: Any, step: int) -> Any:
     """Return ``opt_state`` with every ``count`` field (optax's step counter
     in AdoptState / ScaleByAdamState / ...) set to ``step``."""
@@ -136,7 +146,8 @@ class Trainer:
             mesh = make_mesh(mesh_cfg, devices=jax.local_devices())
 
         self.model = MPTModel(effective_model_config(cfg.model, mesh_cfg))
-        self._kernel_attrs = _flash_tile_attrs(self.model.cfg)
+        self._kernel_attrs = {**_flash_tile_attrs(self.model.cfg),
+                              **_mamba_attrs(self.model.cfg)}
         self.tx, self.lr_schedule = build_optimizer(cfg.optimizer, cfg.scheduler)
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)
 

@@ -191,6 +191,16 @@ TRAINER_MOE_LOAD_SPAN = "trainer/moe_load"
 MOE_ROWS_HELD = "moe/rows_held"
 #: the busiest held expert's rows over the held experts' mean, worst layer
 MOE_MAX_EXPERT_LOAD = "moe/max_expert_load"
+# -- Mamba-2 layers (models/mpt.py, ops/ssd.py): ``jax.named_scope``s in
+# every operation's ``op_name``, forward, transpose and recomputation alike --
+#: the mixer's in- and out-projection
+MAMBA_PROJ_SCOPE = "mamba/proj"
+#: the causal depthwise convolution and its SiLU
+MAMBA_CONV_SCOPE = "mamba/conv"
+#: dt's softplus, the chunked scan (``ops/ssd.ssd_scan``) and the skip term
+MAMBA_SCAN_SCOPE = "mamba/scan"
+#: the gate ``y * silu(z)`` and the RMSNorm over all inner channels
+MAMBA_GATE_NORM_SCOPE = "mamba/gate_norm"
 
 # -- transport-leg span names (federation/tcp.py; spans only, never KPIs) --
 TCP_SEND_SPAN = "tcp/send"
@@ -689,7 +699,8 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     blocks their own width; the dropless expert layer its router, shared
     experts and the routed experts at this chip's expected share (``top_k *
     held / routed`` experts a token): what the step computes here, not what
-    the whole model would."""
+    the whole model would. A Mamba-2 layer (``layer_types``) counts its two
+    projections and the chunked scan's products in attention's place."""
     d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
     hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * d
     if cfg.dropless_moe:
@@ -710,9 +721,18 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
         n_kv = cfg.n_kv_heads or cfg.n_heads
         attn_w = d * (cfg.n_heads + 2 * n_kv) * cfg.d_head + d * d
         attn = 12 * L * d * s  # score + value matmuls, fwd+bwd
+    n_mamba = cfg.mamba_layers  # their mixer stands in attention's place
+    mamba_w = 0
+    if n_mamba:
+        inner, n, q = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_chunk_size
+        mamba_w = d * (2 * inner + 2 * n + cfg.mamba_n_heads) + inner * d
+        # forward products a token: C B^T and the masked square at their causal
+        # half, the chunk's state and its read-out; three times with the backward
+        scan = 3 * (q * n + q * inner + 4 * inner * n)
+        attn = attn * (L - n_mamba) / L + n_mamba * scan
     n_dense = cfg.first_k_dense  # leading SwiGLU blocks of their own width
-    n_block = (L * attn_w + n_dense * 3 * d * cfg.dense_mlp_hidden_size
-               + (L - n_dense) * mlp_w)
+    n_block = ((L - n_mamba) * attn_w + n_mamba * mamba_w
+               + n_dense * 3 * d * cfg.dense_mlp_hidden_size + (L - n_dense) * mlp_w)
     head = 6 * d * v
     return 6.0 * n_block + attn + head
 

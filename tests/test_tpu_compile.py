@@ -302,6 +302,49 @@ def test_grouped_expert_products_compile(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# granite-4.0-h-micro-stage1: grouped heads with the published scale at 8,192
+# positions, and the chunked state-space scan
+# ---------------------------------------------------------------------------
+
+
+def test_flash_attention_compiles_at_the_hybrid_stages_shapes(one_chip):
+    """32 query / 8 key-value heads of 64 at 8,192 positions, one row (the
+    ``granite4hmicro-train`` cell), softmax scale 1/64, forward and backward
+    at the tiles ``pick_tiles`` derives."""
+    from photon_tpu.ops.flash_attention import flash_attention
+
+    q = _abstract((1, 8192, 32, 64), jnp.bfloat16, one_chip)
+    kv = _abstract((1, 8192, 8, 64), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=0.015625).astype(
+            jnp.float32).sum()
+
+    assert _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).count(KERNEL) >= 3
+
+
+def test_chunked_state_space_scan_compiles_and_keeps_one_chunk_of_decays(one_chip):
+    """``ops/ssd.ssd_scan`` at the cell's widths (one row of 8,192, 64 heads
+    of 64, state 128, chunks of 256), forward and backward: it compiles for the
+    chip, and what it holds beside its arguments and results is far under the
+    0.5 GB a whole row's ``[heads, chunks, 256, 256]`` float32 decays would
+    take (the chunks' start states are 67 MB, one chunk's decays 17 MB)."""
+    from photon_tpu.ops import ssd
+
+    x = _abstract((1, 8192, 64, 64), jnp.bfloat16, one_chip)
+    dt = _abstract((1, 8192, 64), jnp.float32, one_chip)
+    bc = _abstract((1, 8192, 128), jnp.bfloat16, one_chip)
+    head = _abstract((64,), jnp.float32, one_chip)
+
+    def loss(x, dt, a_log, b, c, d):
+        return ssd.ssd_scan(x, dt, a_log, b, c, d, chunk=256).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        x, dt, head, bc, bc, head).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4 * 2**30
+
+
+# ---------------------------------------------------------------------------
 # whole train steps
 # ---------------------------------------------------------------------------
 
