@@ -10,6 +10,7 @@ with next-token shift; loss in fp32.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import flax.struct
@@ -24,7 +25,8 @@ from photon_tpu.utils.profiling import MOE_MAX_EXPERT_LOAD, MOE_ROWS_HELD
 # ``op_name`` metadata (forward, transpose and recomputation alike), which is
 # where a profiler trace's reader finds them after any refactor.
 FORWARD_BACKWARD_SCOPE = "train_step/forward_backward"
-#: the chunked cross-entropy head, forward and its recomputation
+#: the chunked cross-entropy head: its forward loop, which also forms the
+#: head's gradients, and the backward's scaling of them
 LOSS_HEAD_SCOPE = "train_step/loss_head"
 #: ``tx.update`` + ``apply_updates``
 OPTIMIZER_SCOPE = "train_step/optimizer"
@@ -47,49 +49,100 @@ def _output_embedding(model: MPTModel, params) -> jax.Array:
     return params["lm_head"]["kernel"].T
 
 
-def _chunked_ce_sum(
-    model: MPTModel, params, hidden: jax.Array, targets: jax.Array, chunk: int
-) -> jax.Array:
-    """Sum of next-token CE without materializing ``[N, vocab]`` logits.
-
-    TPU-first memory trick: the fp32 logits tensor for a 2048-seq microbatch
-    is ~0.4 GB/row and its HBM round-trips dominate the step (the reference
-    leans on CUDA fused CE inside llm-foundry for the same reason). Here the
-    flattened tokens are scanned in ``chunk``-sized pieces: each piece does a
-    bf16 MXU matmul with fp32 accumulation, reduces to per-token CE, and the
-    piece's logits die in registers/VMEM. ``jax.checkpoint`` makes the
-    backward recompute them per piece instead of stashing them.
-    """
-    b, s, d = hidden.shape
-    n = b * s
-    xf = hidden.reshape(n, d)
-    tf = targets.reshape(n)
+def _ce_chunk_loop(x, emb, targets, chunk: int, logits_scaling: float, with_grads: bool):
+    """The head's one loop over ``chunk``-token pieces of ``x [N, d]`` against
+    ``emb [vocab, d]`` (both in the compute dtype): the sum of CE and, with
+    ``with_grads``, its gradients by ``x`` and ``emb`` at unit cotangent
+    (``None`` otherwise). A piece's logits are formed once; the statistics
+    and the gradient's two products all read that one tensor."""
+    n, d = x.shape
     n_chunks = -(-n // chunk)
     pad = n_chunks * chunk - n
     if pad:
-        xf = jnp.pad(xf, ((0, pad), (0, 0)))
-        tf = jnp.pad(tf, (0, pad))
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
     mask = (jnp.arange(n_chunks * chunk) < n).astype(jnp.float32)
-    emb_t = _output_embedding(model, params).astype(hidden.dtype).T  # [d, vocab]
-    logits_scaling = model.cfg.logits_scaling
-
-    xs = xf.reshape(n_chunks, chunk, d)
-    ts = tf.reshape(n_chunks, chunk)
-    ms = mask.reshape(n_chunks, chunk)
+    pieces = (x.reshape(n_chunks, chunk, d), targets.reshape(n_chunks, chunk),
+              mask.reshape(n_chunks, chunk))
 
     def piece(carry, xtm):
+        total, demb = carry
         xc, tc, mc = xtm
-        logits = jnp.dot(xc, emb_t, preferred_element_type=jnp.float32)
+        logits = jax.lax.dot_general(
+            xc, emb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if logits_scaling != 1.0:
             logits = logits / logits_scaling
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return carry + jnp.sum((lse - gold) * mc), None
+        hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) == tc[:, None]
+        gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        total = total + jnp.sum((lse - gold) * mc)
+        if not with_grads:
+            return (total, demb), None
+        dlogits = (jnp.exp(logits - lse[:, None]) - hit) * (mc / logits_scaling)[:, None]
+        dlogits = dlogits.astype(x.dtype)
+        dxc = jnp.dot(dlogits, emb, preferred_element_type=jnp.float32)
+        demb = demb + jax.lax.dot_general(
+            dlogits, xc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return (total, demb), dxc.astype(x.dtype)
 
-    total, _ = jax.lax.scan(
-        jax.checkpoint(piece), jnp.zeros([], jnp.float32), (xs, ts, ms)
-    )
-    return total
+    demb0 = jnp.zeros(emb.shape, jnp.float32) if with_grads else None
+    (total, demb), dx = jax.lax.scan(piece, (jnp.zeros([], jnp.float32), demb0), pieces)
+    if not with_grads:
+        return total, None, None
+    # ``emb``'s gradient waits for the optimizer through the whole backward of
+    # the blocks, so it waits in ``emb``'s dtype, as XLA's transpose of the
+    # product had it wait: rounded once, after the float32 sum over pieces. The
+    # barrier ties that rounding to ``dx``, which the backward needs first;
+    # without it the compiler rounds last and the float32 sum does the waiting
+    # (glm-4.7-flash-ep8: 158 MB more at the step's peak, 43 MB under the chip).
+    dx, demb = jax.lax.optimization_barrier(
+        (dx.reshape(n_chunks * chunk, d)[:n], demb.astype(emb.dtype)))
+    return total, dx, demb
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _ce_sum(x, emb, targets, chunk, logits_scaling):
+    return _ce_chunk_loop(x, emb, targets, chunk, logits_scaling, with_grads=False)[0]
+
+
+def _ce_sum_fwd(x, emb, targets, chunk, logits_scaling):
+    total, dx, demb = _ce_chunk_loop(x, emb, targets, chunk, logits_scaling, with_grads=True)
+    return total, (dx, demb)
+
+
+def _ce_sum_bwd(chunk, logits_scaling, grads, g):
+    dx, demb = grads
+    return (g * dx).astype(dx.dtype), (g * demb).astype(demb.dtype), None
+
+
+_ce_sum.defvjp(_ce_sum_fwd, _ce_sum_bwd)
+
+
+def _chunked_ce_sum(
+    model: MPTModel, params, hidden: jax.Array, targets: jax.Array, chunk: int
+) -> jax.Array:
+    """Sum of next-token CE over ``chunk``-token pieces, never holding more
+    than one piece's logits.
+
+    A piece's fp32 logits are ``chunk x vocab x 4`` bytes of HBM (412.6 MB
+    at 2,048 x 50,368). The bf16 product writes them, with fp32 accumulation
+    and the row maximum from the same pass; the sum of exponentials and the
+    gold logit read them once. Under differentiation the same loop goes on to
+    the head's two gradients at unit cotangent (``jax.custom_vjp``): ``d =
+    (softmax - onehot) * mask / logits_scaling`` in fp32, cast to the hidden
+    state's dtype (the MXU's default precision takes an fp32 operand in one
+    bf16 pass anyway), then ``dx = d . E`` and ``dE += d^T . x`` with fp32
+    accumulation, ``dE`` summed over pieces in fp32. XLA writes no ``d``: each
+    of the two products re-derives it from the logits inside its own fusion,
+    so a piece's logits cross HBM four times (one write, three reads). The
+    backward only scales the two gradients by the cotangent: nothing is
+    recomputed, and a caller that takes no gradient (the eval step) runs the
+    loop without the gradient half.
+    """
+    b, s, d = hidden.shape
+    emb = _output_embedding(model, params).astype(hidden.dtype)  # [vocab, d]
+    return _ce_sum(hidden.reshape(b * s, d), emb, targets.reshape(b * s), chunk,
+                   model.cfg.logits_scaling)
 
 
 def collect_moe_aux(variables: Any) -> jax.Array:

@@ -1,11 +1,20 @@
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
+import pytest
 
 from photon_tpu.config.schema import ModelConfig, OptimizerConfig, SchedulerConfig
 from photon_tpu.models.mpt import MPTModel, init_params
 from photon_tpu.optim import build_optimizer, build_schedule
 from photon_tpu.train import init_train_state, make_eval_step, make_train_step
+from photon_tpu.train.train_step import (
+    LOSS_HEAD_SCOPE,
+    _chunked_ce_sum,
+    _output_embedding,
+)
 
 TINY = ModelConfig(
     d_model=64, n_layers=2, n_heads=4, max_seq_len=32, vocab_size=64,
@@ -86,3 +95,194 @@ def test_determinism():
     assert float(m1["loss"]) == float(m2["loss"])
     for a, b in zip(jax.tree.leaves(s1.params), jax.tree.leaves(s2.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the chunked cross-entropy head (train_step._chunked_ce_sum) -------------
+
+HEAD_VOCAB = 96  # no other dimension of the head's toy shapes is 96
+
+
+def _head_model(tied: bool, logits_scaling: float, compute: str) -> MPTModel:
+    return MPTModel(ModelConfig(
+        d_model=32, n_layers=1, n_heads=2, max_seq_len=16, vocab_size=HEAD_VOCAB,
+        attn_impl="xla", compute_dtype=compute, tie_embeddings=tied,
+        logits_scaling=logits_scaling))
+
+
+def _head_inputs(model: MPTModel, rows: int, seq: int):
+    cfg = model.cfg
+    k_e, k_h, k_t = jax.random.split(jax.random.PRNGKey(7), 3)
+    emb = 0.5 * jax.random.normal(k_e, (cfg.vocab_size, cfg.d_model), jnp.float32)
+    params = ({"wte": {"embedding": emb}} if cfg.tie_embeddings
+              else {"lm_head": {"kernel": emb.T}})
+    hidden = jax.random.normal(k_h, (rows, seq, cfg.d_model), jnp.float32)
+    targets = jax.random.randint(k_t, (rows, seq), 0, cfg.vocab_size)
+    return params, hidden.astype(cfg.compute_dtype), targets
+
+
+def _plain_ce_sum(model, params, hidden, targets):
+    """Full ``[N, vocab]`` float32 logits, no chunks: what the head must equal."""
+    emb = _output_embedding(model, params).astype(jnp.float32)
+    logits = hidden.astype(jnp.float32) @ emb.T / model.cfg.logits_scaling
+    return jnp.sum(optax.softmax_cross_entropy_with_integer_labels(logits, targets))
+
+
+def _parent_chunked_ce_sum(model, params, hidden, targets, chunk):
+    """The head as it was before its gradient moved into the forward loop (a
+    checkpointed scan that XLA differentiates): the yardstick for how far
+    bf16 compute may sit from float32."""
+    b, s, d = hidden.shape
+    n = b * s
+    n_chunks = -(-n // chunk)
+    pad = n_chunks * chunk - n
+    xf = jnp.pad(hidden.reshape(n, d), ((0, pad), (0, 0)))
+    tf = jnp.pad(targets.reshape(n), (0, pad))
+    mask = (jnp.arange(n_chunks * chunk) < n).astype(jnp.float32)
+    emb_t = _output_embedding(model, params).astype(hidden.dtype).T
+
+    def piece(carry, xtm):
+        xc, tc, mc = xtm
+        logits = jnp.dot(xc, emb_t, preferred_element_type=jnp.float32)
+        logits = logits / model.cfg.logits_scaling
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+        return carry + jnp.sum((lse - gold) * mc), None
+
+    total, _ = jax.lax.scan(
+        jax.checkpoint(piece), jnp.zeros([], jnp.float32),
+        (xf.reshape(n_chunks, chunk, d), tf.reshape(n_chunks, chunk),
+         mask.reshape(n_chunks, chunk)))
+    return total
+
+
+def _rel(a, b) -> float:
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _value_and_grads(fn, params, hidden, cotangent):
+    """``(value, d params leaf, d hidden)`` of ``cotangent * fn``."""
+    value, (g_params, g_hidden) = jax.value_and_grad(
+        lambda p, h: cotangent * fn(p, h), argnums=(0, 1))(params, hidden)
+    (g_emb,) = jax.tree.leaves(g_params)
+    return value, g_emb, g_hidden
+
+
+HEAD_CASES = {
+    "tied": dict(tied=True, logits_scaling=1.0, rows=2, seq=16, chunk=8, cotangent=1.0),
+    "untied_logits_scaling_8": dict(tied=False, logits_scaling=8.0, rows=2, seq=16,
+                                    chunk=8, cotangent=1.0),
+    "padded_last_chunk": dict(tied=True, logits_scaling=1.0, rows=3, seq=15, chunk=8,
+                              cotangent=1.0),
+    "cotangent_not_1": dict(tied=True, logits_scaling=1.0, rows=2, seq=16, chunk=8,
+                            cotangent=-0.37),
+}
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(HEAD_CASES))
+def test_chunked_head_matches_plain_cross_entropy(case, compute):
+    """Loss, d hidden and d embedding of the chunked head against the plain
+    full-logits float32 cross-entropy: to 1e-5 at float32 compute; at bf16
+    compute no further from it than the parent's head."""
+    c = HEAD_CASES[case]
+    model = _head_model(c["tied"], c["logits_scaling"], compute)
+    params, hidden, targets = _head_inputs(model, c["rows"], c["seq"])
+    want = _value_and_grads(lambda p, h: _plain_ce_sum(model, p, h, targets),
+                            params, hidden, c["cotangent"])
+    got = _value_and_grads(lambda p, h: _chunked_ce_sum(model, p, h, targets, c["chunk"]),
+                           params, hidden, c["cotangent"])
+    assert got[1].dtype == jnp.float32 and got[2].dtype == hidden.dtype
+    gaps = [_rel(g, w) for g, w in zip(got, want)]
+    if compute == "float32":
+        assert max(gaps) < 1e-5, gaps
+        return
+    parent = _value_and_grads(
+        lambda p, h: _parent_chunked_ce_sum(model, p, h, targets, c["chunk"]),
+        params, hidden, c["cotangent"])
+    parent_gaps = [_rel(g, w) for g, w in zip(parent, want)]
+    # the loss is the parent's bit for bit; d embedding is nearer (summed over
+    # chunks in float32, not bf16); d hidden has one rounding more HERE: the
+    # CPU's products take the parent's float32 ``d`` whole, where the MXU's
+    # default precision rounds it to bf16 as the new head's cast does
+    assert gaps[0] == parent_gaps[0]
+    assert gaps[1] <= parent_gaps[1]
+    assert gaps[2] <= 1.25 * parent_gaps[2]
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_chunked_head_inside_the_microbatch_scan(compute):
+    """The whole step with two accumulated microbatches, each a padded pair
+    of chunks: loss and every leaf of the gradient (plain SGD at rate 1, so
+    the weights' change is the gradient) against the full-logits head."""
+    cfg = ModelConfig(d_model=32, n_layers=1, n_heads=2, max_seq_len=12, vocab_size=HEAD_VOCAB,
+                      attn_impl="xla", compute_dtype=compute)
+    model = MPTModel(cfg)
+    tx = optax.sgd(1.0)
+    params = init_params(cfg, seed=3)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (4, 12), 0, HEAD_VOCAB)
+
+    def stepped(loss_chunk_tokens):
+        step = jax.jit(make_train_step(model, tx, n_microbatches=2,
+                                       loss_chunk_tokens=loss_chunk_tokens))
+        state, metrics = step(init_train_state(model, tx, params), tokens)
+        return metrics["loss"], jax.tree.map(lambda a, b: a - b, params, state.params)
+
+    loss, grads = stepped(16)  # 22 targets a microbatch: a whole chunk and 6 of the next
+    want_loss, want_grads = stepped(0)
+    tol = 1e-5 if compute == "float32" else 2e-2
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=tol)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert _rel(got, want) < tol
+
+
+def test_eval_step_sum_is_the_plain_sum_and_the_parents():
+    """The eval step (no gradient taken: the loop without its gradient half)
+    sums what the full-logits path sums, and what the parent's head summed."""
+    model, state, _, _ = _setup()
+    tokens = _batch(jax.random.PRNGKey(2), b=3, s=27)  # 78 targets: 4 chunks of 24, padded
+    ce_sum, n = jax.jit(make_eval_step(model, loss_chunk_tokens=24))(state.params, tokens)
+    plain_sum, plain_n = jax.jit(make_eval_step(model, loss_chunk_tokens=0))(state.params, tokens)
+    assert int(n) == int(plain_n) == 78
+    np.testing.assert_allclose(float(ce_sum), float(plain_sum), rtol=1e-6)
+    hidden = model.apply({"params": state.params}, tokens, return_hidden=True)
+    parent_sum = _parent_chunked_ce_sum(model, state.params, hidden[:, :-1], tokens[:, 1:], 24)
+    np.testing.assert_allclose(float(ce_sum), float(parent_sum), rtol=1e-6)
+
+
+def _lowered_head_gradient(chunk=8):
+    """The head's gradient alone, under its scope, over three chunks:
+    ``(its jaxpr as text, the lowering)``."""
+    model = _head_model(tied=True, logits_scaling=1.0, compute="bfloat16")
+    params, hidden, targets = _head_inputs(model, rows=2, seq=12)
+
+    def head(p, h):
+        with jax.named_scope(LOSS_HEAD_SCOPE):
+            return _chunked_ce_sum(model, p, h, targets, chunk) / targets.size
+
+    grad = jax.grad(head, argnums=(0, 1))
+    return str(jax.make_jaxpr(grad)(params, hidden)), jax.jit(grad).lower(params, hidden)
+
+
+def test_head_gradient_takes_three_vocabulary_products_a_chunk_and_recomputes_nothing():
+    jaxpr, lowered = _lowered_head_gradient()
+    text = lowered.as_text()
+    # the chunk loop's body is in the program once, however many chunks run
+    products = [line for line in text.splitlines()
+                if "stablehlo.dot_general" in line and f"x{HEAD_VOCAB}x" in line.replace("<", "x")]
+    assert len(products) == 3, products  # logits, d hidden, d embedding (the parent: 4)
+    for word in ("checkpoint", "remat"):
+        assert word not in jaxpr and word not in text
+    assert text.count("stablehlo.while") == 1  # one loop: forward and gradient together
+
+
+def test_head_operations_all_carry_the_scope():
+    """Forward loop and backward scaling alike: every operation's ``op_name``
+    holds ``train_step/loss_head``, so ``loss_head_ms_train`` counts the whole
+    head. (Names without a ``/`` are the arguments' and the reducers' own.)"""
+    compiled = _lowered_head_gradient()[1].compile().as_text()
+    names = [n for n in re.findall(r'op_name="([^"]*)"', compiled) if "/" in n]
+    assert len(names) > 40
+    outside = sorted({n for n in names if LOSS_HEAD_SCOPE not in n})
+    assert not outside, outside
+    assert any(f"transpose(jvp({LOSS_HEAD_SCOPE}))" in n for n in names)  # the backward's
