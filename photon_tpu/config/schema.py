@@ -96,7 +96,11 @@ class ModelConfig:
     # After every optimizer step the selection bias moves against each
     # expert's load by ``moe_bias_update_speed`` at most
     # (``ops/moe.balanced_router_bias``); 0 holds it constant.
-    moe_router: str = "softmax"  # softmax (capacity path) | sigmoid (dropless)
+    # ``moe_router: softmax_topk`` is the dropless layer's second router: a
+    # float32 softmax over all ``moe_num_experts`` outputs, the ``moe_top_k``
+    # largest picked, their probabilities renormalised to sum to 1
+    # (``norm_topk_prob``); no selection bias, no scale, no shared expert.
+    moe_router: str = "softmax"  # softmax (capacity path) | sigmoid | softmax_topk (dropless)
     moe_experts_held: int = 0
     moe_first_expert: int = 0
     moe_shared_experts: int = 0
@@ -142,6 +146,25 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     attention_multiplier: float = 0.0
+    # Heads as wide as the projections make them (``q_proj`` to ``n_heads *
+    # head_dim``, ``out_proj`` back from it); 0 -> ``d_model / n_heads``.
+    head_dim: int = 0
+    # A per-head RMSNorm (learned scale over the head's width, ``norm_eps``)
+    # on q and on k before the rotation.
+    qk_norm: bool = False
+    # Learned sparse attention (``ops/dsa.py``, training path only):
+    # ``dsa_topk > 0`` gives every attention layer an indexer of
+    # ``dsa_index_heads`` heads of ``dsa_index_head_dim`` with one key head;
+    # a query attends to the ``dsa_topk`` earlier keys its indexer scores
+    # highest (all of them while there are no more), through a mask by
+    # (query, key) that all heads share; the indexer learns from its own
+    # alignment loss, which the step adds to the cross-entropy. The scores,
+    # the selection and the loss walk the queries in chunks of ``dsa_chunk``
+    # (HF ``sa_config.q_chunk_size``).
+    dsa_topk: int = 0
+    dsa_index_heads: int = 0
+    dsa_index_head_dim: int = 0
+    dsa_chunk: int = 512
     attn_impl: str = AttnImpl.PALLAS.value
     # Numerics: params kept fp32, compute in bf16 (reference: amp_bf16 + FSDP
     # PURE mixed precision, ``mpt-125m.yaml:85-92``).
@@ -171,7 +194,12 @@ class ModelConfig:
 
     @property
     def dropless_moe(self) -> bool:
-        return self.mlp == "moe" and self.moe_router == "sigmoid"
+        return self.mlp == "moe" and self.moe_router in ("sigmoid", "softmax_topk")
+
+    @property
+    def sparse_attention(self) -> bool:
+        """The indexer picks each query's keys (``dsa_topk`` is set)."""
+        return self.dsa_topk > 0
 
     @property
     def experts_held(self) -> int:
@@ -218,13 +246,16 @@ class ModelConfig:
         layers of different kinds or the multipliers: what serving, cached
         decode, LoRA and the HF maps lack."""
         return (self.latent_attention or self.dropless_moe or self.first_k_dense > 0
-                or self.hybrid or self.scaled)
+                or self.hybrid or self.scaled or self.sparse_attention
+                or self.qk_norm or self.head_dim > 0)
 
     @property
     def d_head(self) -> int:
         if self.latent_attention:
             # heads are as wide as the projections make them, not d_model / n_heads
             return self.qk_nope_head_dim + self.qk_rope_head_dim
+        if self.head_dim:
+            return self.head_dim
         if self.d_model % self.n_heads:
             raise ValueError(f"d_model {self.d_model} not divisible by n_heads {self.n_heads}")
         return self.d_model // self.n_heads
@@ -992,12 +1023,20 @@ class Config:
         """Latent attention, the dropless sigmoid router, the experts held
         here and the leading dense blocks (preset ``glm-4.7-flash-ep8``)."""
         m = self.model
-        if m.moe_router not in ("softmax", "sigmoid"):
+        if m.moe_router not in ("softmax", "sigmoid", "softmax_topk"):
             raise ValueError(f"bad model.moe_router {m.moe_router!r}")
-        if m.moe_router == "sigmoid":
+        if m.moe_router == "softmax_topk" and (
+                m.moe_shared_experts or m.moe_routed_scale != 1.0
+                or m.moe_bias_update_speed):
+            raise ValueError(
+                "moe_router='softmax_topk' has no selection bias, no scale and "
+                "no shared expert: moe_bias_update_speed, moe_routed_scale and "
+                "moe_shared_experts belong to moe_router='sigmoid'")
+        if m.moe_router in ("sigmoid", "softmax_topk"):
             if m.mlp != "moe" or m.moe_mlp_act != "swiglu":
                 raise ValueError(
-                    "moe_router='sigmoid' needs mlp='moe' with moe_mlp_act='swiglu'")
+                    f"moe_router={m.moe_router!r} needs mlp='moe' with "
+                    "moe_mlp_act='swiglu'")
             held = m.experts_held
             if held > m.moe_num_experts:
                 raise ValueError(
@@ -1015,16 +1054,17 @@ class Config:
                     f"one of the {m.moe_num_experts // held} shares of {held}")
             if self.mesh.expert > 1:
                 raise ValueError(
-                    "mesh.expert > 1 with moe_router='sigmoid' is not supported "
-                    "yet: the dropless layer has no expert exchange across "
-                    "chips (it computes the share it is told it holds)")
+                    f"mesh.expert > 1 with moe_router={m.moe_router!r} is not "
+                    "supported yet: the dropless layer has no expert exchange "
+                    "across chips (it computes the share it is told it holds)")
             if m.moe_shared_experts < 0 or m.moe_bias_update_speed < 0:
                 raise ValueError(
                     "moe_shared_experts and moe_bias_update_speed must be >= 0")
         elif (m.moe_experts_held or m.moe_first_expert or m.moe_shared_experts
               or m.moe_routed_scale != 1.0 or m.moe_bias_update_speed):
             raise ValueError(
-                "moe_experts_held / moe_first_expert / moe_shared_experts / "
+                "moe_experts_held / moe_first_expert belong to the dropless "
+                "routers ('sigmoid', 'softmax_topk'); moe_shared_experts / "
                 "moe_routed_scale / moe_bias_update_speed belong to "
                 "moe_router='sigmoid'")
         if m.first_k_dense:
@@ -1054,6 +1094,7 @@ class Config:
                     f"qk_rope_head_dim={m.qk_nope_head_dim + m.qk_rope_head_dim}: "
                     "the attention kernels take one head width for q, k and v")
         self._validate_hybrid_family()
+        self._validate_sparse_attention_family()
         if m.training_path_only:
             if m.lora_rank or self.photon.adapters.enabled:
                 raise ValueError(
@@ -1067,6 +1108,51 @@ class Config:
                     "latent attention, the dropless expert layer, leading "
                     "dense blocks, layer_types or the multipliers: there is "
                     "no cache or decode step for them yet")
+
+    def _validate_sparse_attention_family(self) -> None:
+        """``head_dim``, ``qk_norm`` and the indexer's sparse attention
+        (preset ``keye-vl-2.0-30b-a3b-ep8``)."""
+        m = self.model
+        if m.head_dim < 0:
+            raise ValueError("head_dim must be >= 0")
+        if (m.qk_norm or m.head_dim) and (m.latent_attention or not (
+                m.n_kv_heads and m.n_kv_heads != m.n_heads)):
+            raise ValueError(
+                "head_dim and qk_norm live in the grouped-query branch: they "
+                "need 0 < n_kv_heads < n_heads and no latent attention")
+        if (m.head_dim or m.qk_norm) and self.mesh.pipe > 1:
+            raise ValueError(
+                "mesh.pipe > 1 with head_dim or qk_norm is not supported")
+        if not m.sparse_attention:
+            if m.dsa_index_heads or m.dsa_index_head_dim:
+                raise ValueError(
+                    "dsa_index_heads / dsa_index_head_dim belong to dsa_topk > 0")
+            return
+        if min(m.dsa_index_heads, m.dsa_index_head_dim, m.dsa_chunk) <= 0 \
+                or m.dsa_index_head_dim % 2:
+            raise ValueError(
+                "dsa_topk > 0 needs dsa_index_heads, dsa_chunk > 0 and an even "
+                "dsa_index_head_dim > 0")
+        if m.alibi or m.latent_attention or m.hybrid or not m.rope or not (
+                m.n_kv_heads and m.n_kv_heads != m.n_heads):
+            raise ValueError(
+                "dsa_topk > 0 (the indexer's sparse attention) lives in the "
+                "grouped-query branch with rotary positions: it needs rope, "
+                "0 < n_kv_heads < n_heads, and no alibi, latent attention or "
+                "layer_types")
+        if m.attention_multiplier:
+            raise ValueError("dsa_topk > 0 fixes the softmax scale at 1/sqrt(d_head)")
+        if m.attn_impl == AttnImpl.RING.value or self.mesh.sequence > 1 \
+                or self.mesh.tensor > 1 or self.mesh.pipe > 1:
+            raise ValueError(
+                "dsa_topk > 0 is not supported with ring attention "
+                "(attn_impl='ring'), mesh.sequence > 1, mesh.tensor > 1 or "
+                "mesh.pipe > 1: the indexer scores a query against the whole "
+                "row and its mask is one for all heads")
+        if m.max_seq_len % min(m.dsa_chunk, m.max_seq_len):
+            raise ValueError(
+                f"max_seq_len={m.max_seq_len} is not a multiple of "
+                f"dsa_chunk={m.dsa_chunk}: the indexer walks whole chunks")
 
     def _validate_hybrid_family(self) -> None:
         """``layer_types`` with its Mamba-2 sizes, and the four multipliers
@@ -1752,10 +1838,14 @@ def refuse_training_only_family(model: ModelConfig, what: str) -> None:
     or the import maps. Refusing beats running it wrong."""
     has = [name for name, on in (
         ("latent attention (kv_lora_rank > 0)", model.latent_attention),
-        ("the dropless sigmoid router (moe_router='sigmoid')", model.dropless_moe),
+        ("the dropless routers (moe_router='sigmoid' / 'softmax_topk')",
+         model.dropless_moe),
         ("leading dense blocks (first_k_dense > 0)", model.first_k_dense > 0),
         ("layers of different kinds (layer_types)", model.hybrid),
         ("the embedding / residual / logits / attention multipliers", model.scaled),
+        ("the indexer's sparse attention (dsa_topk > 0)", model.sparse_attention),
+        ("per-head q/k norms (qk_norm)", model.qk_norm),
+        ("heads of their own width (head_dim)", model.head_dim > 0),
     ) if on]
     if has:
         raise NotImplementedError(

@@ -20,6 +20,17 @@ and ``moe_router: sigmoid`` makes the stack's MLP the dropless expert layer of
 ``ops/moe.py`` with a shared expert. Norms, residuals and the attention
 dispatch are the one ``MPTBlock``'s.
 
+A learned-sparse-attention / expert family composes the same way (preset
+``keye-vl-2.0-30b-a3b-ep8``, training path only): in the grouped-query branch
+``head_dim`` makes the heads as wide as the projections, ``qk_norm`` puts a
+per-head RMSNorm on q and k before the rotation, and ``dsa_topk > 0`` gives
+the block an indexer (``MPTBlock._sparse_attention`` over ``ops/dsa.py``)
+whose selection the masked flash kernel takes in place of the causal rule
+and whose alignment loss is sown beside the expert counters;
+``moe_router: softmax_topk`` is the dropless layer's second router (a float32
+softmax over all experts, the picked probabilities renormalised; no
+selection bias, no scale, no shared expert).
+
 A hybrid family composes the same way again (preset
 ``granite-4.0-h-micro-stage1``, training path only): ``layer_types`` gives
 every layer its mixer, attention or a Mamba-2 mixer (``MPTBlock._mamba_mixer``
@@ -51,6 +62,10 @@ import jax.numpy as jnp
 from photon_tpu.config.schema import ModelConfig
 from photon_tpu.ops.attention import multihead_attention
 from photon_tpu.utils.profiling import (
+    ATTN_QK_NORM_SCOPE,
+    DSA_INDEX_LOSS_SCOPE,
+    DSA_INDEXER_SCOPE,
+    DSA_SELECT_SCOPE,
     MAMBA_CONV_SCOPE,
     MAMBA_GATE_NORM_SCOPE,
     MAMBA_PROJ_SCOPE,
@@ -268,6 +283,52 @@ class MPTBlock(nn.Module):
             axis=-1)
         return q, k, kv[..., nope:]
 
+    def _sparse_attention(self, h: jax.Array, q: jax.Array, k: jax.Array,
+                          v: jax.Array, dense) -> jax.Array:
+        """Attention over the keys an indexer picks (``ops/dsa.py``): ``h [B,
+        S, D]`` the block's normed input, ``q`` / ``k`` / ``v`` the rotated
+        heads. The indexer reads ``h`` detached (its own alignment loss is
+        all that moves it): ``dsa_index_heads`` query heads and one key head
+        of ``dsa_index_head_dim`` (the key through a LayerNorm), both rotated
+        like q, and a weight a head scaled by ``heads^-1/2 * dim^-1/2``. Its
+        selection is a mask for all heads; the masked kernel's log-sum-exp
+        feeds the index loss, which is sown with the selection's counts."""
+        from photon_tpu.ops import dsa
+        from photon_tpu.ops.masked_flash_attention import (
+            base_tile, live_tables, masked_multihead_attention, plan_tiles, tile_counts)
+
+        cfg = self.cfg
+        b, s, _ = h.shape
+        heads, dim = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+        with jax.named_scope(DSA_INDEXER_SCOPE):
+            hd = jax.lax.stop_gradient(h)
+            q_idx = dense(heads * dim, "idx_q_proj", cfg.emb_init_std)(hd)
+            k_idx = FP32LayerNorm(use_bias=True, eps=cfg.norm_eps, name="idx_k_norm")(
+                dense(dim, "idx_k_proj", cfg.emb_init_std)(hd))
+            q_idx, k_idx = apply_rope(
+                q_idx.reshape(b, s, heads, dim), k_idx[:, :, None, :], cfg.rope_theta)
+            k_idx = k_idx[:, :, 0, :]
+            w_idx = dense(heads, "idx_w_proj", cfg.emb_init_std)(hd).astype(
+                jnp.float32) * (heads ** -0.5 * dim ** -0.5)
+        with jax.named_scope(DSA_SELECT_SCOPE):
+            mask = dsa.select_keys(q_idx, k_idx, w_idx, topk=cfg.dsa_topk,
+                                   chunk=cfg.dsa_chunk)
+            # the mask's one pass outside the kernel: the picked pairs by
+            # tile give the count and all three launches' live tiles
+            tiles = plan_tiles(s, s)
+            counts = tile_counts(mask, *base_tile(tiles))
+            live = live_tables(counts, tiles)
+            self.sow("intermediates", "dsa_picked_pairs",
+                     jnp.sum(counts).astype(jnp.float32))
+            self.sow("intermediates", "dsa_tiles_visited",
+                     jnp.sum(live[0], dtype=jnp.float32))
+        out, lse = masked_multihead_attention(
+            q, k, v, mask, impl=cfg.attn_impl, interpret=cfg.attn_interpret, live=live)
+        with jax.named_scope(DSA_INDEX_LOSS_SCOPE):
+            self.sow("intermediates", "dsa_index_loss", dsa.index_loss(
+                q_idx, k_idx, w_idx, q, k, lse, mask, chunk=cfg.dsa_chunk))
+        return out
+
     def _dropless_moe(self, x: jax.Array, dense, hidden: int, resid_std: float):
         """The dropless expert layer's residual branch (``ops/moe.py``):
         shared experts on every token plus this chip's part of the routed
@@ -286,10 +347,12 @@ class MPTBlock(nn.Module):
         # the train step moves it by the balancing rule
         # (``moe.balanced_router_bias``, ``cfg.moe_bias_update_speed``) from
         # the rows sown below; seeded small and non-zero so that selection
-        # and weights differ
-        router_bias = self.param(
-            "router_bias", nn.initializers.normal(stddev=0.01),
-            (cfg.moe_num_experts,), jnp.float32)
+        # and weights differ. The softmax top-k router has no bias: no parameter
+        router_bias = None
+        if cfg.moe_router == "sigmoid":
+            router_bias = self.param(
+                "router_bias", nn.initializers.normal(stddev=0.01),
+                (cfg.moe_num_experts,), jnp.float32)
         w_gate = self.param("moe_gate", init, (held, cfg.d_model, hidden), pd)
         w_up = self.param("moe_up", init, (held, cfg.d_model, hidden), pd)
         w_down = self.param(
@@ -298,8 +361,8 @@ class MPTBlock(nn.Module):
         out, counters = moe.dropless_moe_mlp(
             h32, router_w, router_bias, w_gate, w_up, w_down,
             top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
-            routed_scale=cfg.moe_routed_scale, compute_dtype=compute,
-            interpret=cfg.attn_interpret)
+            routed_scale=cfg.moe_routed_scale, router=cfg.moe_router,
+            compute_dtype=compute, interpret=cfg.attn_interpret)
         self.sow("intermediates", "moe_rows_held", counters["rows_held"])
         self.sow("intermediates", "moe_max_expert_load", counters["max_expert_load"])
         self.sow("intermediates", "moe_expert_rows", counters["expert_rows"])
@@ -375,6 +438,10 @@ class MPTBlock(nn.Module):
             q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
             k = k.reshape(b, s, n_kv, cfg.d_head)
             v = v.reshape(b, s, n_kv, cfg.d_head)
+            if cfg.qk_norm:
+                with jax.named_scope(ATTN_QK_NORM_SCOPE):
+                    q = FP32RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
+                    k = FP32RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
             if cfg.rope and not cfg.latent_attention:
                 # before the kv repeat: the rotation is per-head-identical, so
                 # rotating n_kv heads then replicating equals the reverse order
@@ -383,19 +450,22 @@ class MPTBlock(nn.Module):
             # flash kernel consumes GQA groups directly (index-mapped kv rows,
             # no repeated tensor in HBM); the xla/ring paths replicate inside
             # ops/attention.py
-            attn_out = multihead_attention(
-                q, k, v,
-                impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
-                interpret=cfg.attn_interpret,
-                # 0 -> None: the dispatch's own 1/sqrt(d_head)
-                scale=cfg.attention_multiplier or None,
-            )
+            if cfg.sparse_attention:
+                attn_out = self._sparse_attention(h, q, k, v, dense)
+            else:
+                attn_out = multihead_attention(
+                    q, k, v,
+                    impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
+                    interpret=cfg.attn_interpret,
+                    # 0 -> None: the dispatch's own 1/sqrt(d_head)
+                    scale=cfg.attention_multiplier or None,
+                )
             if cfg.latent_attention:
                 with jax.named_scope(MLA_PROJ_SCOPE):
                     x = _residual(cfg, x, dense(cfg.d_model, "out_proj", resid_std)(
                         attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim)))
             else:
-                attn_out = attn_out.reshape(b, s, cfg.d_model)
+                attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.d_head)
                 x = _residual(cfg, x, adapted(cfg.d_model, "out_proj", resid_std, attn_out))
 
         # --- MLP ---

@@ -170,6 +170,20 @@ def sigmoid_route(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array,
     return idx.astype(jnp.int32), gates
 
 
+def softmax_route(h32: jax.Array, router_w: jax.Array, top_k: int):
+    """Softmax top-k routing with the picked probabilities renormalised (HF
+    ``norm_topk_prob``): a float32 softmax over every routed expert's logit,
+    the ``top_k`` largest picked, their probabilities divided by their sum.
+    No bias, no scale. ``h32 [N, D]`` float32 -> ``(idx [N, k] int32,
+    gates [N, k] float32)``."""
+    probs = jax.nn.softmax(jnp.matmul(
+        h32.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
+    picked = jnp.take_along_axis(probs, idx, axis=-1)
+    return idx.astype(jnp.int32), picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _rows_by_expert(x: jax.Array, order: jax.Array, inv: jax.Array, k: int):
     """``x [N, D]`` -> ``[N k, D]``: row ``i`` is the token of the ``i``-th
@@ -283,11 +297,19 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
     return jnp.where(grouped[:, None], out, 0).astype(lhs.dtype)
 
 
-def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array,
+def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array | None,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
-                     top_k: int, first_expert: int, routed_scale: float,
+                     top_k: int, first_expert: int, routed_scale: float = 1.0,
+                     router: str = "sigmoid",
                      compute_dtype=jnp.bfloat16, interpret: bool = False):
     """The routed part of a dropless expert layer, for the experts held here.
+
+    Two routers: ``router='sigmoid'`` is ``noaux_tc`` (:func:`sigmoid_route`:
+    sigmoid scores, a selection bias ``router_bias [E]`` without gradient, the
+    picked scores renormalised to ``routed_scale``); ``router='softmax_topk'``
+    is :func:`softmax_route` (a float32 softmax over all ``E``, the picked
+    probabilities renormalised to 1; no bias, no scale: ``router_bias`` is
+    ``None``). Everything after the gates is one path.
 
     ``h32 [..., D]`` float32 normed activations; ``router_w [D, E]`` and
     ``router_bias [E]`` over ALL ``E`` routed experts; ``w_gate`` / ``w_up``
@@ -310,7 +332,12 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
     e_held = w_up.shape[0]
     hf32 = h32.reshape(n, d)
     with jax.named_scope(ROUTER_SCOPE):
-        idx, gates = sigmoid_route(hf32, router_w, router_bias, top_k, routed_scale)
+        if router == "softmax_topk":
+            idx, gates = softmax_route(hf32, router_w, top_k)
+        elif router == "sigmoid":
+            idx, gates = sigmoid_route(hf32, router_w, router_bias, top_k, routed_scale)
+        else:
+            raise ValueError(f"the dropless layer has no router {router!r}")
         expert_rows = jnp.sum(
             idx.reshape(n * top_k, 1) == jnp.arange(router_w.shape[-1], dtype=jnp.int32),
             axis=0, dtype=jnp.float32)

@@ -47,6 +47,13 @@ _RULES: list[tuple[str, P]] = [
     (r"(q_a_proj|kv_a_proj)/kernel$", P("pipe", "fsdp", None)),
     (r"(q_b_proj|kv_b_proj)/kernel$", P("pipe", None, "tensor")),
     (r"(q_a_norm|kv_a_norm)/scale$", P("pipe")),
+    # learned sparse attention (models/mpt.py, ops/dsa.py): the indexer's
+    # projections are small and their outputs (16 heads of 64, one key head,
+    # a weight a head) are scored against the whole row, so they stay whole;
+    # the per-head q / k norms and the indexer's key norm are replicated
+    (r"idx_(q|k|w)_proj/kernel$", P("pipe", "fsdp", None)),
+    (r"idx_(q|k|w)_proj/bias$", P("pipe")),
+    (r"(idx_k_norm|q_norm|k_norm)/(scale|bias)$", P("pipe")),
     # a Mamba-2 mixer (models/mpt.py, ops/ssd.py): the in-projection's columns
     # are z | x B C | dt, whose boundaries no tensor split respects, so they
     # stay whole (its `out_proj` is row-parallel like attention's, below); the

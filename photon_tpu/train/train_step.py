@@ -19,7 +19,13 @@ import jax.numpy as jnp
 import optax
 
 from photon_tpu.models.mpt import MPTModel
-from photon_tpu.utils.profiling import MOE_MAX_EXPERT_LOAD, MOE_ROWS_HELD
+from photon_tpu.utils.profiling import (
+    DSA_INDEX_LOSS,
+    DSA_PICKED_PAIRS,
+    DSA_TILES_VISITED,
+    MOE_MAX_EXPERT_LOAD,
+    MOE_ROWS_HELD,
+)
 
 # The step's stages as ``jax.named_scope``s: they reach every operation's
 # ``op_name`` metadata (forward, transpose and recomputation alike), which is
@@ -183,6 +189,23 @@ def collect_moe_counters(variables: Any) -> dict[str, jax.Array]:
             _EXPERT_ROWS: jnp.concatenate(by_expert)}
 
 
+def collect_dsa_counters(variables: Any) -> dict[str, jax.Array]:
+    """The sparse-attention layers' sows as the step's counters, each summed
+    over the layers: the pairs the indexers picked, the forward tiles that
+    hold one, and the index losses (which the objective adds to the
+    cross-entropy: the indexer's parameters get their gradient from these
+    alone, every other parameter from the cross-entropy alone). Empty for
+    every other model."""
+    names = {"dsa_picked_pairs": DSA_PICKED_PAIRS, "dsa_tiles_visited": DSA_TILES_VISITED,
+             "dsa_index_loss": DSA_INDEX_LOSS}
+    out: dict[str, jax.Array] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
+        for name in (names.get(getattr(k, "key", None)) for k in path):
+            if name:
+                out[name] = out.get(name, 0.0) + jnp.sum(jnp.asarray(leaf, jnp.float32))
+    return out
+
+
 def _merge_counters(a: dict, b: dict) -> dict:
     """Two microbatches' counters as one step's: rows add, the load is the worst."""
     return {k: jnp.maximum(a[k], b[k]) if k == MOE_MAX_EXPERT_LOAD else a[k] + b[k]
@@ -195,22 +218,28 @@ def _apply_collecting_aux(model: MPTModel, params, tokens, **kwargs):
     dropless layers' counters. The MoE blocks sow per-layer terms into
     ``intermediates`` (``models/mpt.py``); plain inference applies leave the
     collection immutable, so sow is a no-op there."""
-    if model.cfg.mlp != "moe":
+    if model.cfg.mlp != "moe" and not model.cfg.sparse_attention:
         return (model.apply({"params": params}, tokens, **kwargs),
                 jnp.zeros([], jnp.float32), {})
     out, variables = model.apply(
         {"params": params}, tokens, mutable=["intermediates"], **kwargs
     )
     sown = variables.get("intermediates", {})
-    return (out, model.cfg.moe_aux_weight * collect_moe_aux(sown),
-            collect_moe_counters(sown))
+    aux = model.cfg.moe_aux_weight * collect_moe_aux(sown)
+    counters = collect_moe_counters(sown)
+    if model.cfg.sparse_attention:
+        dsa = collect_dsa_counters(sown)
+        aux = aux + dsa[DSA_INDEX_LOSS]
+        counters = {**counters, **dsa}
+    return out, aux, counters
 
 
 def _make_loss_and_counters_fn(model: MPTModel, loss_chunk_tokens: int) -> Callable:
     def loss_fn(params, tokens: jax.Array):
         """``(loss, counters)``: mean next-token cross entropy over
         ``[B, S] int32`` tokens (+ the weighted MoE load-balance aux loss of
-        the capacity router), and the dropless layers' counters."""
+        the capacity router, + the sparse-attention layers' index losses),
+        and the dropless and sparse-attention layers' counters."""
         if loss_chunk_tokens:
             hidden, aux, counters = _apply_collecting_aux(
                 model, params, tokens, return_hidden=True
@@ -290,10 +319,16 @@ def make_train_step(
                         (model.cfg.n_layers - model.cfg.first_k_dense,
                          model.cfg.moe_num_experts), jnp.float32),
                 }
+            if model.cfg.sparse_attention:
+                zero_counters.update({k: jnp.zeros([], jnp.float32) for k in (
+                    DSA_PICKED_PAIRS, DSA_TILES_VISITED, DSA_INDEX_LOSS)})
             (loss_sum, grad_sum, counters), _ = jax.lax.scan(
                 body, (jnp.zeros([], jnp.float32), zero_grads, zero_counters), micro)
             loss = loss_sum / n_microbatches
             grads = jax.tree.map(lambda g: g / n_microbatches, grad_sum)
+            if DSA_INDEX_LOSS in counters:  # a mean like the loss it is part of
+                counters = {**counters,
+                            DSA_INDEX_LOSS: counters[DSA_INDEX_LOSS] / n_microbatches}
         else:
             (loss, counters), grads = grad_fn(state.params, tokens)
         return loss, grads, counters

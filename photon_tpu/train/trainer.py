@@ -46,8 +46,14 @@ from photon_tpu.utils.profiling import (
     CLIENT_STEPS,
     CLIENT_TOKENS_PER_SEC,
     EVENT_SPEED_MONITOR_PEAK,
+    DSA_CAUSAL_PAIRS,
+    DSA_INDEX_LOSS,
+    DSA_PICKED_PAIRS,
+    DSA_TILES_CAUSAL,
+    DSA_TILES_VISITED,
     MOE_MAX_EXPERT_LOAD,
     MOE_ROWS_HELD,
+    TRAINER_DSA_SPAN,
     TRAINER_FENCE_SPAN,
     TRAINER_MOE_LOAD_SPAN,
     TRAINER_GET_PARAMETERS_SPAN,
@@ -69,6 +75,13 @@ def _flash_tile_attrs(model_cfg) -> dict[str, str]:
             model_cfg.attn_interpret or pallas_supported(None)):
         return {}
     s = model_cfg.max_seq_len
+    if model_cfg.sparse_attention:
+        # the masked kernel's plan; which of its tiles are live is data
+        # (``trainer/dsa`` has the count)
+        from photon_tpu.ops.masked_flash_attention import LAUNCHES, plan_tiles
+
+        return {"flash_tiles": " ".join(
+            f"{n}={bq}x{bk}" for n, (bq, bk) in zip(LAUNCHES, plan_tiles(s, s)))}
     return pick_tiles(
         s, s, lane_padded(model_cfg.d_head), jnp.dtype(model_cfg.compute_dtype).itemsize,
         model_cfg.n_heads // (model_cfg.n_kv_heads or model_cfg.n_heads),
@@ -83,6 +96,28 @@ def _mamba_attrs(model_cfg) -> dict[str, int]:
         return {}
     return {"mamba_layers": model_cfg.mamba_layers,
             "ssd_chunks": model_cfg.max_seq_len // model_cfg.mamba_chunk_size}
+
+
+def _dsa_attrs(model_cfg) -> dict[str, int]:
+    """The step's sparse-attention layers and the keys each of their queries
+    picks, as span attributes: static counts. Empty for every other model."""
+    if not model_cfg.sparse_attention:
+        return {}
+    return {"dsa_layers": model_cfg.n_layers, "dsa_topk": model_cfg.dsa_topk}
+
+
+def _dsa_static_counts(model_cfg, batch_rows: int) -> dict[str, float]:
+    """What the selection is measured against, a step: the causal (query,
+    key) pairs of every layer and row, and the forward tiles of the masked
+    kernel that hold one."""
+    from photon_tpu.ops.flash_attention import live_tiles
+    from photon_tpu.ops.masked_flash_attention import plan_tiles
+
+    s = model_cfg.max_seq_len
+    rows = model_cfg.n_layers * batch_rows
+    tiles, _ = live_tiles(s, s, *plan_tiles(s, s)[0])
+    return {DSA_CAUSAL_PAIRS: float(rows * s * (s + 1) // 2),
+            DSA_TILES_CAUSAL: float(rows * tiles)}
 
 
 def _set_opt_count(opt_state: Any, step: int) -> Any:
@@ -147,7 +182,8 @@ class Trainer:
 
         self.model = MPTModel(effective_model_config(cfg.model, mesh_cfg))
         self._kernel_attrs = {**_flash_tile_attrs(self.model.cfg),
-                              **_mamba_attrs(self.model.cfg)}
+                              **_mamba_attrs(self.model.cfg),
+                              **_dsa_attrs(self.model.cfg)}
         self.tx, self.lr_schedule = build_optimizer(cfg.optimizer, cfg.scheduler)
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)
 
@@ -462,6 +498,18 @@ class Trainer:
                             TRAINER_MOE_LOAD_SPAN,
                             rows_held=last_metrics[MOE_ROWS_HELD],
                             max_expert_load=last_metrics[MOE_MAX_EXPERT_LOAD]):
+                        pass
+                if DSA_PICKED_PAIRS in last_metrics:
+                    # the last step's selection counters, the same way
+                    last_metrics.update(_dsa_static_counts(
+                        self.model.cfg, batch.shape[0]))
+                    with telemetry.span(
+                            TRAINER_DSA_SPAN,
+                            picked_pairs=last_metrics[DSA_PICKED_PAIRS],
+                            causal_pairs=last_metrics[DSA_CAUSAL_PAIRS],
+                            tiles_visited=last_metrics[DSA_TILES_VISITED],
+                            tiles_causal=last_metrics[DSA_TILES_CAUSAL],
+                            index_loss=last_metrics[DSA_INDEX_LOSS]):
                         pass
         dt = time.monotonic() - t0
         return {

@@ -191,6 +191,34 @@ TRAINER_MOE_LOAD_SPAN = "trainer/moe_load"
 MOE_ROWS_HELD = "moe/rows_held"
 #: the busiest held expert's rows over the held experts' mean, worst layer
 MOE_MAX_EXPERT_LOAD = "moe/max_expert_load"
+#: opened and closed inside the fence like ``trainer/moe_load``, only when the
+#: step holds the indexer's sparse attention: attrs ``picked_pairs``,
+#: ``causal_pairs``, ``tiles_visited``, ``tiles_causal``, ``index_loss`` (the
+#: five counters below, of the fit's last step)
+TRAINER_DSA_SPAN = "trainer/dsa"
+# -- learned sparse attention (models/mpt.py, ops/dsa.py): counters in the
+# train step's metrics, summed over the layers, fetched with the loss -------
+#: (query, key) pairs the indexers' selections picked
+DSA_PICKED_PAIRS = "dsa/picked_pairs"
+#: (query, key) pairs with the key no later than the query
+DSA_CAUSAL_PAIRS = "dsa/causal_pairs"
+#: tiles of the masked kernel's forward launch that hold a picked pair (a
+#: tile is one for all heads), and those that hold a causal pair
+DSA_TILES_VISITED = "dsa/tiles_visited"
+DSA_TILES_CAUSAL = "dsa/tiles_causal"
+#: the layers' index losses, summed (what the step adds to the cross-entropy)
+DSA_INDEX_LOSS = "dsa/loss"
+# -- its ``jax.named_scope``s, in every operation's ``op_name`` ------------
+#: the indexer's three projections, its key norm and the rotation
+DSA_INDEXER_SCOPE = "dsa/indexer"
+#: the index scores by query chunk, each query's threshold, the mask and its
+#: tile counts
+DSA_SELECT_SCOPE = "dsa/select"
+#: the second pass over q.k for the heads' mean probabilities, the index
+#: scores again, the loss and its gradient into the indexer
+DSA_INDEX_LOSS_SCOPE = "dsa/index_loss"
+#: the per-head RMSNorm of q and k before the rotation
+ATTN_QK_NORM_SCOPE = "attn/qk_norm"
 # -- Mamba-2 layers (models/mpt.py, ops/ssd.py): ``jax.named_scope``s in
 # every operation's ``op_name``, forward, transpose and recomputation alike --
 #: the mixer's in- and out-projection
@@ -690,6 +718,30 @@ class Timer:
             self.metrics[name] = self.metrics.get(name, 0.0) + time.monotonic() - t0
 
 
+def _sparse_attention_flops_per_token(cfg: ModelConfig) -> float:
+    """The terms of ``benchmark/costs/keye_sparse_moe_train.py`` at the
+    expected counts (``tests/test_keye_sparse.py`` holds the two equal): the
+    attention over the pairs a selection without ties picks (``min(t + 1,
+    topk)`` a query), not over the causal half; the indexer's projections at
+    4 operations a weight (their input is detached: no input gradient), its
+    scores once over every causal pair and their two backward products over
+    the picked pairs; router, the routed experts at this chip's expected
+    share, and the head."""
+    d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
+    h, g, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    j, di, k = cfg.dsa_index_heads, cfg.dsa_index_head_dim, min(cfg.dsa_topk, s)
+    picked = L * (k * (k + 1) / 2.0 + (s - k) * k) / s
+    causal = L * (s + 1) / 2.0
+    rows = L * cfg.moe_top_k * cfg.experts_held / cfg.moe_num_experts
+    return (6.0 * L * (d * (h + 2 * g) * dh + h * dh * d)
+            + 4.0 * L * d * (j * di + di + j)
+            + causal * j * 2 * di + 2.0 * picked * j * 2 * di
+            + 3.0 * picked * h * 4 * dh
+            + 6.0 * L * d * cfg.moe_num_experts
+            + 3.0 * rows * 3 * 2 * d * cfg.mlp_hidden_size
+            + 6.0 * d * v)
+
+
 def model_flops_per_token(cfg: ModelConfig) -> float:
     """Training FLOPs/token ≈ 6·N_nonemb + 12·L·d·s (attention) + 6·d·V
     (lm_head, tied or not). Matches the estimate used for BASELINE
@@ -700,8 +752,12 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     experts and the routed experts at this chip's expected share (``top_k *
     held / routed`` experts a token): what the step computes here, not what
     the whole model would. A Mamba-2 layer (``layer_types``) counts its two
-    projections and the chunked scan's products in attention's place."""
+    projections and the chunked scan's products in attention's place.
+    Learned sparse attention (``dsa_topk``) has a count of its own,
+    :func:`_sparse_attention_flops_per_token`."""
     d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
+    if cfg.sparse_attention:
+        return _sparse_attention_flops_per_token(cfg)
     hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * d
     if cfg.dropless_moe:
         experts = cfg.moe_top_k * cfg.experts_held / cfg.moe_num_experts

@@ -1,0 +1,627 @@
+"""Keye-VL-2.0-30B-A3B's language model on the training path, at a tiny size
+with the published structure: grouped-query heads wider than ``d_model /
+n_heads`` with per-head q / k norms, an indexer whose selection the attention
+takes in place of the causal rule, the indexer's own alignment loss in the
+objective, softmax top-k routing over experts of which a share is held.
+
+The plain reference is ``benchmark/reference/keye_sparse_moe.py`` (float32,
+``Precision.HIGHEST``, the selection by a full sort, whole probability rows);
+on the CPU the program runs ``attn_impl: xla`` and the grouped products
+through ``jax.lax.ragged_dot``, and the masked flash kernel in the Pallas
+interpreter where a test says so.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import pathlib
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:  # `benchmark` is a sibling of `tests`, not installed
+    sys.path.insert(0, str(ROOT))
+
+from benchmark.reference import keye_sparse_moe as ref  # noqa: E402
+from photon_tpu.config import load_preset  # noqa: E402
+from photon_tpu.models import MPTModel  # noqa: E402
+from photon_tpu.ops import dsa, moe  # noqa: E402
+from photon_tpu.ops import masked_flash_attention as mfa  # noqa: E402
+from photon_tpu.train.train_step import _make_loss_and_counters_fn, make_loss_fn  # noqa: E402
+from photon_tpu.utils.profiling import (  # noqa: E402
+    DSA_INDEX_LOSS,
+    DSA_PICKED_PAIRS,
+    DSA_TILES_VISITED,
+)
+
+PRESET = "keye-vl-2.0-30b-a3b-ep8"
+TINY = dict(
+    d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=32, max_seq_len=64,
+    vocab_size=96, dsa_topk=16, dsa_index_heads=4, dsa_index_head_dim=16, dsa_chunk=16,
+    mlp_hidden_size=32, moe_num_experts=8, moe_top_k=2, moe_experts_held=2,
+    attn_impl="xla", compute_dtype="float32",
+)
+INDEXER = ("idx_q_proj", "idx_k_proj", "idx_k_norm", "idx_w_proj")
+
+
+def tiny_cfg(**model):
+    """The preset with every size shrunk and nothing of its structure changed:
+    heads of 32 where ``d_model / n_heads`` is 16, 16 keys a query of 64, 8
+    experts top-2 of which 2 are held."""
+    cfg = load_preset(PRESET)
+    for key, value in {**TINY, **model}.items():
+        setattr(cfg.model, key, value)
+    cfg.train.global_batch_size = 2
+    cfg.train.device_microbatch_size = 2
+    return cfg.validate()
+
+
+def dims_of(cfg) -> dict:
+    return ref.dims_of(dataclasses.asdict(cfg.model))
+
+
+def leaf_names(tree) -> list[str]:
+    return ["/".join(str(getattr(k, "key", k)) for k in path)
+            for path, _ in jax.tree_util.tree_leaves_with_path(tree)]
+
+
+def by_name(tree) -> dict:
+    return dict(zip(leaf_names(tree), jax.tree.leaves(tree)))
+
+
+TOKENS = np.random.default_rng(3).integers(0, 96, size=(2, 64)).astype(np.int32)
+
+
+def reference_objective(params, dims, tokens=TOKENS):
+    total, _ = ref.objective_sum(params, jnp.asarray(tokens), dims)
+    return total / (tokens.shape[0] * (tokens.shape[1] - 1))
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """Seeded weights in the program's layout, and objective + gradients of
+    one batch from the program (float32 compute) and from the reference."""
+    cfg = tiny_cfg()
+    dims = dims_of(cfg)
+    params = ref.make_params(dims, 7)
+    got = jax.value_and_grad(make_loss_fn(MPTModel(cfg.model), 16))(params, TOKENS)
+    want = jax.value_and_grad(lambda p: reference_objective(p, dims))(params)
+    return cfg, dims, params, got, want
+
+
+def test_init_gives_the_reference_tree():
+    from photon_tpu.models import init_params
+
+    cfg = tiny_cfg()
+    mine = init_params(cfg.model, seed=0)
+    theirs = ref.make_params(dims_of(cfg), 0)
+    assert leaf_names(mine) == leaf_names(theirs)
+    assert jax.tree.map(jnp.shape, mine) == jax.tree.map(jnp.shape, theirs)
+
+
+def test_forward_logits_match_reference(seeded):
+    cfg, dims, params, _, _ = seeded
+    logits = MPTModel(cfg.model).apply({"params": params}, TOKENS)
+    np.testing.assert_allclose(logits, ref.forward(params, TOKENS, dims), atol=2e-5)
+
+
+def test_objective_matches_reference_and_holds_the_index_losses(seeded):
+    cfg, dims, params, (loss, _), (want, _) = seeded
+    assert abs(float(loss) - float(want)) < 1e-5
+    # cross-entropy plus the layers' index losses, which are not nothing
+    total, counters = _make_loss_and_counters_fn(MPTModel(cfg.model), 16)(params, TOKENS)
+    index = float(counters[DSA_INDEX_LOSS])
+    assert 0.01 < index < 2.0
+    _, (picked, _) = ref.objective_sum(params, jnp.asarray(TOKENS), dims)
+    assert float(counters[DSA_PICKED_PAIRS]) == float(picked)
+    # and the cross-entropy it is added to is the reference's
+    want_ce = sum(float(ref.row_objective(params, jnp.asarray(row), dims)[0])
+                  for row in TOKENS) / (2 * 63)
+    assert abs(float(total) - index - want_ce) < 1e-5
+
+
+LEAVES = leaf_names(ref.make_params(ref.dims_of({
+    **dataclasses.asdict(load_preset(PRESET).model), **TINY}), 0))
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_gradient_leaf_matches_reference(seeded, leaf):
+    *_, (_, got), (_, want) = seeded
+    got, want = by_name(got)[leaf], by_name(want)[leaf]
+    assert np.any(want), "a leaf without a gradient tests nothing"
+    # float32 on both sides; the largest entries of a leaf are 1e-4 .. 6e-2
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-4)
+
+
+@pytest.fixture(scope="module")
+def gradients_by_loss(seeded):
+    """Every leaf's gradient from the cross-entropy alone and from the
+    layers' index losses alone."""
+    cfg, _, params, _, _ = seeded
+    model = MPTModel(cfg.model)
+    objective = _make_loss_and_counters_fn(model, 16)
+
+    def ce_only(p):
+        total, counters = objective(p, TOKENS)
+        return total - counters[DSA_INDEX_LOSS]
+
+    index_only = lambda p: objective(p, TOKENS)[1][DSA_INDEX_LOSS]  # noqa: E731
+    return by_name(jax.grad(ce_only)(params)), by_name(jax.grad(index_only)(params))
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_the_two_losses_have_disjoint_gradients(gradients_by_loss, leaf):
+    """The indexer's leaves are moved by the index loss alone, every other
+    leaf by the cross-entropy alone."""
+    from_ce, from_index = (g[leaf] for g in gradients_by_loss)
+    if any(f"/{name}/" in leaf for name in INDEXER):
+        assert not np.any(from_ce) and np.any(from_index)
+    else:
+        assert np.any(from_ce) and not np.any(from_index)
+
+
+def test_bfloat16_compute_stays_near_the_reference(seeded):
+    _, dims, params, _, (want, _) = seeded
+    cfg = tiny_cfg(compute_dtype="bfloat16")
+    loss = make_loss_fn(MPTModel(cfg.model), 16)(params, TOKENS)
+    assert abs(float(loss) - float(want)) < 3e-2
+
+
+@pytest.mark.parametrize("microbatches", [1, 2])
+def test_three_steps_follow_the_reference(microbatches):
+    """The train step (objective, clip, ADOPT) against the reference's steps
+    on the same rows: losses, and the weights after three steps."""
+    from photon_tpu.optim import build_optimizer
+    from photon_tpu.train import init_train_state, make_train_step
+    from benchmark.program import optimizer_settings
+
+    cfg = tiny_cfg()
+    cfg.scheduler.t_warmup = 0
+    dims = dims_of(cfg)
+    params = ref.make_params(dims, 11)
+    model = MPTModel(cfg.model)
+    tx, _ = build_optimizer(cfg.optimizer, cfg.scheduler)
+    state = init_train_state(model, tx, params)
+    step = jax.jit(make_train_step(model, tx, n_microbatches=microbatches,
+                                   loss_chunk_tokens=16))
+    opt = optimizer_settings(cfg)
+    grad = ref.Grad(dims, rows=1)
+    p, s = params, ref.adopt_init(params)
+    rng = np.random.default_rng(5)
+    for _ in range(3):
+        batch = rng.integers(0, 96, size=(2, 64)).astype(np.int32)
+        state, metrics = step(state, batch)
+        loss, g = grad(p, batch)
+        p, s = ref.adopt_step(p, s, g, opt)
+        assert abs(float(metrics["loss"]) - loss) < 2e-5
+    moved = ref.leaf_norms(jax.tree.map(jnp.subtract, state.params, params))
+    want = ref.leaf_norms(jax.tree.map(jnp.subtract, p, params))
+    assert ref.worst_leaf_gap(moved, want) < 2e-3
+
+
+# ---------------------------------------------------------------------------
+# the selection
+# ---------------------------------------------------------------------------
+
+
+def _indexer_inputs(seed: int, b=2, s=64, heads=4, dim=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, s, heads, dim)),
+            jax.random.normal(ks[1], (b, s, dim)),
+            jax.random.normal(ks[2], (b, s, heads)))
+
+
+def _reference_mask(q_idx, k_idx, w, topk):
+    mm = ref.MATMULS["float32"]
+    t = jnp.arange(q_idx.shape[1], dtype=jnp.int32)
+    return jnp.stack([ref.select(ref.index_scores(q, k, ww, mm), t, topk)
+                      for q, k, ww in zip(q_idx, k_idx, w)])
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("topk", [1, 16, 33])
+def test_the_selection_is_the_references_set_on_every_row(topk, chunk):
+    """Exactly: rows with fewer earlier keys than ``topk`` keep them all,
+    every later row keeps its ``topk`` highest (more only where scores tie
+    at the threshold: a query whose every product is cut by the ``relu``
+    scores exactly 0 against several keys)."""
+    with jax.default_matmul_precision("highest"):
+        q_idx, k_idx, w = _indexer_inputs(0)
+        mask = dsa.select_keys(q_idx, k_idx, w, topk=topk, chunk=chunk)
+    want = _reference_mask(q_idx, k_idx, w, topk)
+    assert mask.dtype == jnp.int8 and bool(jnp.all((mask != 0) == want))
+    per_row = np.asarray(jnp.sum(mask, axis=-1))
+    least = np.minimum(np.arange(64) + 1, topk)[None, :]
+    assert (per_row >= least).all()
+    if topk > 1:  # the 16th place is rarely an exact zero; the first often is
+        assert (per_row == least).mean() > 0.95
+
+
+def test_ties_at_the_threshold_are_all_kept():
+    """Keys with one and the same score straddle the threshold: the rule
+    keeps every one of them, in the program as in the reference."""
+    with jax.default_matmul_precision("highest"):
+        q_idx, k_idx, w = _indexer_inputs(1)
+        # row 0: all keys alike, so every score of a query ties; row 1: keys
+        # 8..39 alike, a tie that the 16th place falls into for most queries
+        k_idx = k_idx.at[0].set(k_idx[0, 0]).at[1, 8:40].set(k_idx[1, 8])
+        mask = dsa.select_keys(q_idx, k_idx, w, topk=16, chunk=16)
+    want = _reference_mask(q_idx, k_idx, w, 16)
+    assert bool(jnp.all((mask != 0) == want))
+    assert bool(jnp.all((mask[0] != 0) == jnp.tril(jnp.ones((64, 64), bool))))
+    assert int(jnp.max(jnp.sum(mask[1], axis=-1))) > 16
+
+
+@pytest.mark.parametrize("k", [1, 5, 64])
+def test_kth_largest_is_exact(k):
+    rng = np.random.default_rng(k)
+    x = rng.normal(size=(8, 64)).astype(np.float32)
+    x[0, :10] = -np.inf  # masked entries count as smallest
+    x[1] = np.round(x[1])  # many ties
+    x[2, 3] = 0.0
+    x[2, 4] = -0.0
+    x[3] *= 1e-30  # tiny magnitudes, both signs
+    got = dsa.kth_largest(jnp.asarray(x), k)
+    np.testing.assert_array_equal(np.asarray(got), -np.sort(-x, axis=-1)[:, k - 1])
+
+
+def test_with_every_key_picked_the_sparse_branch_is_the_dense_one(seeded):
+    """``dsa_topk >= S``: the mask is the causal rule, and the block's output
+    is the dense grouped-query branch's on the same weights."""
+    _, _, params, _, _ = seeded
+    sparse = tiny_cfg(dsa_topk=64)
+    logits = MPTModel(sparse.model).apply({"params": params}, TOKENS)
+    dense = tiny_cfg()
+    dense.model.dsa_topk = dense.model.dsa_index_heads = dense.model.dsa_index_head_dim = 0
+    dense.validate()
+    block = {k: v for k, v in params["blocks"]["block"].items() if not k.startswith("idx_")}
+    want = MPTModel(dense.model).apply(
+        {"params": {**params, "blocks": {"block": block}}}, TOKENS)
+    np.testing.assert_allclose(logits, want, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the masked kernel
+# ---------------------------------------------------------------------------
+
+
+def _attention_inputs(seed: int, s=256, h=4, g=2, d=32, b=2):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, g, d))
+    v = jax.random.normal(ks[2], (b, s, g, d))
+    picked = jax.random.uniform(ks[3], (b, s, s)) < 0.2
+    mask = (picked | jnp.eye(s, dtype=bool)) & jnp.tril(jnp.ones((s, s), bool))
+    return q, k, v, mask.astype(jnp.int8)
+
+
+TILES = ((128, 128),) * 3
+
+
+def test_the_masked_kernel_matches_the_masked_xla_path():
+    """Forward, log-sum-exp and both backward launches, in the interpreter,
+    with several tiles a launch and grouped heads."""
+    q, k, v, mask = _attention_inputs(0)
+    want, want_lse = mfa.masked_xla_attention(q, k, v, mask)
+    got, lse = mfa.masked_flash_attention(q, k, v, mask, interpret=True, tiles=TILES)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-5)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)[0]))  # noqa: E731
+    g_want = jax.grad(loss(lambda q, k, v: mfa.masked_xla_attention(q, k, v, mask)),
+                      (0, 1, 2))(q, k, v)
+    g_got = jax.grad(loss(lambda q, k, v: mfa.masked_flash_attention(
+        q, k, v, mask, interpret=True, tiles=TILES)), (0, 1, 2))(q, k, v)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_tiles_without_a_picked_pair_are_not_fetched():
+    """A mask that empties whole tiles: the tables name a live block for
+    every dead step (a repeated index: no copy), the counts say how many
+    tiles were visited, and the kernel's results do not change."""
+    q, k, v, mask = _attention_inputs(1)
+    # queries 128.. see nothing of keys 0..127 (a dead tile under the
+    # diagonal), and key block 1 is seen by its own queries alone
+    mask = mask.at[:, 128:, :128].set(0)
+    counts = mfa.tile_counts(mask, 128, 128)
+    assert int(jnp.sum(counts)) == int(jnp.sum(mask))
+    live = counts > 0
+    assert live.shape == (2, 2, 2) and int(jnp.sum(live)) == 4  # of 6 causal
+    # launches of different tiles take their tables from one pass over the
+    # mask at the tiles' common divisor
+    mixed = ((256, 128), (128, 256), (128, 128))
+    assert mfa.base_tile(mixed) == (128, 128)
+    wide, tall, same = mfa.live_tables(counts, mixed)
+    assert wide.tolist() == [[[True, True]]] * 2 and tall.tolist() == [[[True], [True]]] * 2
+    assert jnp.array_equal(same, live)
+    flat_live, fetch = mfa.tile_tables(live)
+    assert flat_live.tolist() == [1, 0, 0, 1] * 2
+    # row 0: its dead step repeats block 0; row 1: its dead first step names
+    # the live block that follows
+    assert fetch.tolist() == [0, 0, 1, 1] * 2
+    # the dk/dv launch sweeps the queries of a key block
+    assert mfa.tile_tables(live.swapaxes(1, 2))[1].tolist() == [0, 0, 1, 1] * 2
+    want, _ = mfa.masked_xla_attention(q, k, v, mask)
+    got, _ = mfa.masked_flash_attention(q, k, v, mask, interpret=True, tiles=TILES)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    g_want = jax.grad(lambda k: jnp.sum(mfa.masked_xla_attention(q, k, v, mask)[0] ** 2))(k)
+    # the caller's tables, as the block hands them over, and mixed tiles
+    for tiles in (TILES, mixed):
+        tables = mfa.live_tables(counts, tiles)
+        g_got = jax.grad(lambda k: jnp.sum(mfa.masked_flash_attention(
+            q, k, v, mask, interpret=True, tiles=tiles, live=tables)[0] ** 2))(k)
+        np.testing.assert_allclose(g_got, g_want, atol=5e-5)
+
+
+def test_a_query_without_a_key_gives_zeros_and_no_nan():
+    q, k, v, mask = _attention_inputs(2, s=128)
+    mask = mask.at[:, 5].set(0)
+    got, lse = mfa.masked_flash_attention(q, k, v, mask, interpret=True)
+    assert not np.any(np.asarray(got[:, 5])) and np.all(np.isfinite(np.asarray(got)))
+    grads = jax.grad(lambda q, k, v: jnp.sum(mfa.masked_flash_attention(
+        q, k, v, mask, interpret=True)[0]), (0, 1, 2))(q, k, v)
+    assert all(np.all(np.isfinite(np.asarray(g))) for g in grads)
+
+
+def test_the_model_through_the_kernel_in_the_interpreter(seeded):
+    """The whole objective with the masked kernel where the CPU run has the
+    XLA path: the same loss and the same gradients."""
+    _, _, params, (want, g_want), _ = seeded
+    cfg = tiny_cfg(attn_impl="pallas", attn_interpret=True)
+    loss, grads = jax.value_and_grad(make_loss_fn(MPTModel(cfg.model), 16))(params, TOKENS)
+    assert abs(float(loss) - float(want)) < 1e-5
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
+
+
+def test_the_index_loss_takes_its_gradient_in_the_forward_loop():
+    """``ops/dsa.index_loss`` against plain autodiff of the same formula."""
+    b, s, h, g, d = 2, 64, 4, 2, 16
+    with jax.default_matmul_precision("highest"):
+        q_idx, k_idx, w = _indexer_inputs(4)
+        q, k, _, _ = _attention_inputs(4, s=s, h=h, g=g, d=d)
+        mask = dsa.select_keys(q_idx, k_idx, w, topk=16, chunk=16)
+        _, lse = mfa.masked_xla_attention(q, k, k, mask)
+
+        def plain(q_idx, k_idx, w):
+            mm = ref.MATMULS["float32"]
+            picked = mask != 0
+            scores = jnp.stack([ref.index_scores(a, c, e, mm)
+                                for a, c, e in zip(q_idx, k_idx, w)])
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, h // g, axis=2)) / d ** 0.5
+            pbar = jnp.mean(jax.nn.softmax(
+                jnp.where(picked[:, None], logits, -jnp.inf), axis=-1), axis=1)
+            log_soft = jax.nn.log_softmax(jnp.where(picked, scores, -jnp.inf), axis=-1)
+            kl = jnp.where(picked, pbar * (jnp.log(jnp.where(picked, pbar, 1.0))
+                                           - jnp.where(picked, log_soft, 0.0)), 0.0)
+            return jnp.sum(kl) / (b * s)
+
+        mine = lambda *a: dsa.index_loss(*a, q, k, lse, mask, chunk=16)  # noqa: E731
+        want, g_want = jax.value_and_grad(plain, (0, 1, 2))(q_idx, k_idx, w)
+        got, g_got = jax.value_and_grad(mine, (0, 1, 2))(q_idx, k_idx, w)
+    assert abs(float(got) - float(want)) < 1e-5
+    for a, c in zip(g_got, g_want):
+        np.testing.assert_allclose(a, c, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the softmax top-k router and the share
+# ---------------------------------------------------------------------------
+
+
+def _layer_weights(seed: int, e=16, d=32, f=24):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    n = lambda key, shape: jax.random.normal(key, shape, jnp.float32) * 0.2  # noqa: E731
+    return {"router": n(ks[0], (d, e)), "moe_gate": n(ks[1], (e, d, f)),
+            "moe_up": n(ks[2], (e, d, f)), "moe_down": n(ks[3], (e, f, d))}
+
+
+@pytest.mark.parametrize("held", [16, 2])
+def test_the_shares_routed_parts_sum_to_the_uncut_layer(held):
+    """Softmax top-8 of 16 experts: the eight shares of two experts each (or
+    the one share of all) add up to the layer with every expert held, which
+    the reference computes as a masked loop."""
+    p = _layer_weights(1)
+    h = jnp.asarray(np.random.default_rng(2).normal(size=(48, 32)), jnp.float32)
+    dims = dict(top_k=8, first_expert=0, experts_held=16)
+    mm = ref.MATMULS["float32"]
+    want, rows = ref.routed_experts(h, p, dims, mm)
+    assert float(rows) == 48 * 8
+    parts, seen = [], 0.0
+    for first in range(0, 16, held):
+        share = slice(first, first + held)
+        out, counters = moe.dropless_moe_mlp(
+            h, p["router"], None, p["moe_gate"][share], p["moe_up"][share],
+            p["moe_down"][share], top_k=8, first_expert=first, router="softmax_topk",
+            compute_dtype=jnp.float32)
+        parts.append(out)
+        seen += float(counters["rows_held"])
+        held_here = {**p, **{n: p[n][share] for n in ("moe_gate", "moe_up", "moe_down")}}
+        one, _ = ref.routed_experts(h, held_here, dims, mm, first_expert=first, experts=held)
+        np.testing.assert_allclose(out, one, atol=1e-5)
+    assert seen == 48 * 8  # every assignment is some share's, once
+    np.testing.assert_allclose(sum(parts), want, atol=1e-5)
+    if held < 16:  # and one share alone is not the layer
+        assert float(jnp.max(jnp.abs(parts[0] - want))) > 1e-3
+
+
+def test_the_softmax_router_renormalises_the_picked_probabilities():
+    p = _layer_weights(3)
+    h = jnp.asarray(np.random.default_rng(4).normal(size=(10, 32)), jnp.float32)
+    idx, gates = moe.softmax_route(h, p["router"], 8)
+    probs = jax.nn.softmax(h @ p["router"], axis=-1)
+    np.testing.assert_allclose(jnp.sum(gates, axis=-1), 1.0, atol=1e-6)
+    want_idx = jnp.argsort(-probs, axis=-1)[:, :8]
+    assert (np.sort(np.asarray(idx)) == np.sort(np.asarray(want_idx))).all()
+    picked = jnp.take_along_axis(probs, idx, axis=-1)
+    np.testing.assert_allclose(gates, picked / jnp.sum(picked, -1, keepdims=True), atol=1e-6)
+
+
+def test_the_block_has_no_selection_bias():
+    assert not any("router_bias" in name for name in LEAVES)
+
+
+# ---------------------------------------------------------------------------
+# the trainer, the sharding rules, the refusals, the preset
+# ---------------------------------------------------------------------------
+
+
+def test_fit_returns_the_selection_counters_on_their_span():
+    from photon_tpu.train.trainer import Trainer
+    from photon_tpu.utils.profiling import (
+        DSA_CAUSAL_PAIRS, DSA_TILES_CAUSAL, MOE_ROWS_HELD)
+
+    cfg = tiny_cfg()
+    trainer = Trainer(cfg, init_seed=0)
+    assert trainer._kernel_attrs["dsa_layers"] == 2
+    assert trainer._kernel_attrs["dsa_topk"] == 16
+    out = trainer.fit([TOKENS] * 2, duration_steps=2)
+    rows = 2 * 2  # layers x batch rows
+    assert out[DSA_CAUSAL_PAIRS] == rows * 64 * 65 // 2
+    # 16 full early rows then 16 a query, a few ties allowed for
+    least = rows * (16 * 17 // 2 + 48 * 16)
+    assert least <= out[DSA_PICKED_PAIRS] <= least + 200
+    assert out[DSA_TILES_VISITED] == out[DSA_TILES_CAUSAL] == rows  # one tile a row
+    assert 0.0 < out[DSA_INDEX_LOSS] < 2.0
+    assert 64 <= out[MOE_ROWS_HELD] <= 256  # ~ 2 x 64 x 2 x 2 x 2/8
+
+
+def test_every_new_parameter_has_a_sharding_rule():
+    import re
+
+    from jax.sharding import PartitionSpec as P
+
+    from photon_tpu.config.schema import MeshConfig
+    from photon_tpu.parallel.mesh import make_mesh
+    from photon_tpu.parallel.sharding import _RULES, param_specs
+
+    unruled = [n for n in LEAVES if not any(re.search(rx, n) for rx, _ in _RULES)]
+    assert not unruled
+    mesh = make_mesh(MeshConfig(fsdp=2, tensor=2), devices=jax.devices()[:4])
+    block = param_specs(ref.make_params(dims_of(tiny_cfg()), 0), mesh)["blocks"]["block"]
+    # the indexer's small outputs stay whole, the attention's heads split
+    assert block["idx_q_proj"]["kernel"] == P("pipe", "fsdp", None)
+    assert block["idx_w_proj"]["kernel"] == P("pipe", "fsdp", None)
+    assert block["q_proj"]["kernel"] == P("pipe", "fsdp", "tensor")
+    assert block["q_norm"]["scale"] == block["idx_k_norm"]["bias"] == P("pipe", None)
+
+
+def _refuse_serving():
+    from photon_tpu.serve.engine import PagedEngine
+
+    PagedEngine(tiny_cfg(), params={})
+
+
+def _refuse_decode():
+    from photon_tpu.models.decode import prefill
+
+    prefill({}, jnp.zeros((1, 4), jnp.int32), jnp.array([4]), tiny_cfg().model)
+
+
+def _refuse_hf_export():
+    from photon_tpu.checkpoint.hf_export import mixtral_state_dict
+
+    mixtral_state_dict({}, tiny_cfg().model)
+
+
+def _refuse_hf_import():
+    from photon_tpu.checkpoint.hf_import import llama_params_from_hf
+
+    llama_params_from_hf({}, tiny_cfg().model)
+
+
+@pytest.mark.parametrize("call", [_refuse_serving, _refuse_decode,
+                                  _refuse_hf_export, _refuse_hf_import],
+                         ids=lambda f: f.__name__.removeprefix("_refuse_"))
+def test_serving_decode_and_hf_interop_refuse_the_family(call):
+    with pytest.raises(NotImplementedError, match="training path only"):
+        call()
+
+
+def _with(cfg, **paths):
+    for dotted, value in paths.items():
+        obj = cfg
+        *parents, leaf = dotted.split("__")
+        for name in parents:
+            obj = getattr(obj, name)
+        setattr(obj, leaf, value)
+    return cfg
+
+
+@pytest.mark.parametrize("change, message", [
+    (dict(model__moe_bias_update_speed=0.1), "no selection bias"),
+    (dict(model__moe_shared_experts=1), "no shared expert"),
+    (dict(model__moe_routed_scale=1.8), "no scale"),
+    (dict(model__moe_experts_held=3), "does not divide"),
+    (dict(mesh__expert=2, mesh__surplus_devices="ignore"), "no expert exchange"),
+    (dict(model__alibi=True), "alibi"),
+    (dict(model__kv_lora_rank=16, model__q_lora_rank=24, model__qk_nope_head_dim=12,
+          model__qk_rope_head_dim=4, model__v_head_dim=16, model__n_kv_heads=0),
+     "grouped-query branch"),
+    (dict(model__attn_impl="ring"), "ring attention"),
+    (dict(mesh__sequence=2, mesh__surplus_devices="ignore"), "mesh.sequence"),
+    (dict(model__n_kv_heads=4), "grouped-query branch"),
+    (dict(model__dsa_index_head_dim=15), "even"),
+    (dict(model__dsa_chunk=48), "whole chunks"),
+    (dict(model__dsa_topk=0), "belong to dsa_topk"),
+    (dict(model__attention_multiplier=0.5), "softmax scale"),
+    (dict(model__lora_rank=4, model__lora_targets=("out_proj",)), "LoRA"),
+    (dict(photon__serve__prefix_cache=True), "prefix cache"),
+], ids=lambda v: "-".join(v) if isinstance(v, dict) else None)
+def test_schema_refuses_what_the_family_cannot_do_yet(change, message):
+    cfg = tiny_cfg()
+    with pytest.raises(ValueError, match=message):
+        _with(cfg, **change).validate()
+
+
+def test_the_preset_is_what_the_benchmark_configuration_states():
+    """``benchmark/program.build_config`` holds the preset to every size of
+    the configuration file; the cut's arithmetic (ISSUE 35, PERF.md section
+    4) is the tree's own count."""
+    import json
+
+    from benchmark.program import build_config
+    from photon_tpu.models import init_params
+
+    config = json.loads((ROOT / f"benchmark/configs/{PRESET}.json").read_text())
+    traffic = json.loads((ROOT / "benchmark/traffic/ep8-share-1x16384.json").read_text())
+    cfg = build_config(config, traffic, ROOT / ".bench_work" / "unused", seed=2 ** 31 + 5)
+    assert cfg.train.global_batch_size == cfg.train.device_microbatch_size == 1
+    assert cfg.optimizer.lr == 7.3e-6 and cfg.scheduler.t_warmup == 0
+    assert cfg.model.d_head == 128 and cfg.model.experts_held == 16
+    shapes = jax.eval_shape(lambda: init_params(cfg.model, seed=0))
+    by_leaf = {n: int(np.prod(leaf.shape)) for n, leaf in by_name(shapes).items()}
+    assert sum(by_leaf.values()) == 465_391_104  # x 16 bytes = 7.45 GB
+    layer = sum(v for n, v in by_leaf.items() if n.startswith("blocks/")) // 4
+    assert layer == 96_899_456
+    assert sum(v for n, v in by_leaf.items() if "/idx_" in n) // 4 == 2_261_120
+    config["model"]["dsa_topk"] = 1024
+    with pytest.raises(ValueError, match="dsa_topk"):
+        build_config(config, traffic, ROOT / ".bench_work" / "unused", seed=1)
+
+
+def test_model_flops_per_token_are_the_benchmarks_cost_module():
+    """``utils/profiling.model_flops_per_token`` answers for the family with
+    the terms of ``benchmark/costs/keye_sparse_moe_train.py`` at the expected
+    share of rows and the pairs the selection picks without ties."""
+    import json
+
+    from benchmark.costs import keye_sparse_moe_train as cost
+    from photon_tpu.utils.profiling import model_flops_per_token
+
+    config = json.loads((ROOT / f"benchmark/configs/{PRESET}.json").read_text())
+    cfg = load_preset(PRESET)
+    model = config["model"]
+    want = cost.flops_per_token(
+        model, cost.expected_routed_rows_per_token(model),
+        cost.expected_picked_pairs_per_token(model))
+    assert model_flops_per_token(cfg.model) == pytest.approx(want, rel=1e-9)
+    tiny = tiny_cfg()
+    model = dataclasses.asdict(tiny.model)
+    assert model_flops_per_token(tiny.model) == pytest.approx(cost.flops_per_token(
+        model, cost.expected_routed_rows_per_token(model),
+        cost.expected_picked_pairs_per_token(model)), rel=1e-9)

@@ -345,6 +345,58 @@ def test_chunked_state_space_scan_compiles_and_keeps_one_chunk_of_decays(one_chi
 
 
 # ---------------------------------------------------------------------------
+# keye-vl-2.0-30b-a3b-ep8: the flash kernel under a (query, key) mask, and the
+# exact selection
+# ---------------------------------------------------------------------------
+
+
+def test_masked_flash_attention_compiles_at_the_sparse_cells_widths(one_chip):
+    """32 query / 4 key-value heads of 128 under an int8 mask for all heads,
+    forward and both backward launches at the tiles of the cell
+    (``keyevl2-train-16k``; 4,096 positions here, the same 1,024- and
+    512-square tiles): Mosaic takes the int8 tile, the two prefetched tile
+    tables and the data-dependent block indices, and the launches keep the
+    names the benchmark's ``flash_*_ms_train`` readers find."""
+    from photon_tpu.ops.masked_flash_attention import masked_flash_attention, plan_tiles
+
+    s = 4096
+    assert plan_tiles(s, s) == plan_tiles(16384, 16384) == (
+        (1024, 1024), (1024, 1024), (512, 512))
+    q = _abstract((1, s, 32, 128), jnp.bfloat16, one_chip)
+    kv = _abstract((1, s, 4, 128), jnp.bfloat16, one_chip)
+    mask = _abstract((1, s, s), jnp.int8, one_chip)
+
+    def loss(q, k, v, mask):
+        out, lse = masked_flash_attention(q, k, v, mask)
+        return out.astype(jnp.float32).sum() + jax.lax.stop_gradient(lse).sum()
+
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, mask)
+    launches = [ln.strip() for ln in hlo.splitlines() if KERNEL in ln]
+    assert len(launches) >= 3
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert any(re.search(rf"\b{kernel}/multihead_attention\b", ln)
+                   for ln in launches), kernel
+
+
+def test_the_exact_selection_compiles_without_a_sort_or_a_whole_square(one_chip):
+    """``ops/dsa.select_keys`` at the cell's indexer widths (16 heads of 64,
+    one key head, 2,048 keys a query, chunks of 512; 8,192 positions here):
+    no sort in the program (the threshold is a search over the float's bits),
+    and beside the int8 mask it returns it holds a few chunks' worth, far
+    under the 4.3 GB the 16 heads' ``[S, S]`` products would take."""
+    from photon_tpu.ops import dsa
+
+    s = 8192
+    q_idx = _abstract((1, s, 16, 64), jnp.bfloat16, one_chip)
+    k_idx = _abstract((1, s, 64), jnp.bfloat16, one_chip)
+    w = _abstract((1, s, 16), jnp.float32, one_chip)
+    compiled = jax.jit(lambda q, k, w: dsa.select_keys(
+        q, k, w, topk=2048, chunk=512)).lower(q_idx, k_idx, w).compile()
+    assert not re.search(r"\bsort\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+
+
+# ---------------------------------------------------------------------------
 # whole train steps
 # ---------------------------------------------------------------------------
 
