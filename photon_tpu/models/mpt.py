@@ -287,12 +287,11 @@ class MPTBlock(nn.Module):
                           v: jax.Array, dense) -> jax.Array:
         """Attention over the keys an indexer picks (``ops/dsa.py``): ``h [B,
         S, D]`` the block's normed input, ``q`` / ``k`` / ``v`` the rotated
-        heads. The indexer reads ``h`` detached (its own alignment loss is
-        all that moves it): ``dsa_index_heads`` query heads and one key head
-        of ``dsa_index_head_dim`` (the key through a LayerNorm), both rotated
-        like q, and a weight a head scaled by ``heads^-1/2 * dim^-1/2``. Its
-        selection is a mask for all heads; the masked kernel's log-sum-exp
-        feeds the index loss, which is sown with the selection's counts."""
+        heads. The indexer reads ``h`` detached (only its alignment loss moves
+        it): ``dsa_index_heads`` query heads and one key head (through a
+        LayerNorm) of ``dsa_index_head_dim``, rotated like q, and a weight a
+        head scaled by ``heads^-1/2 * dim^-1/2``. Its selection masks all
+        heads; the kernel's log-sum-exp feeds the index loss, sown beside."""
         from photon_tpu.ops import dsa
         from photon_tpu.ops.masked_flash_attention import (
             base_tile, live_tables, masked_multihead_attention, plan_tiles, tile_counts)
@@ -326,7 +325,8 @@ class MPTBlock(nn.Module):
             q, k, v, mask, impl=cfg.attn_impl, interpret=cfg.attn_interpret, live=live)
         with jax.named_scope(DSA_INDEX_LOSS_SCOPE):
             self.sow("intermediates", "dsa_index_loss", dsa.index_loss(
-                q_idx, k_idx, w_idx, q, k, lse, mask, chunk=cfg.dsa_chunk))
+                q_idx, k_idx, w_idx, q, k, lse, mask, chunk=cfg.dsa_chunk,
+                impl=cfg.attn_impl, interpret=cfg.attn_interpret))
         return out
 
     def _dropless_moe(self, x: jax.Array, dense, hidden: int, resid_std: float):

@@ -25,8 +25,14 @@ Everything walks the queries in chunks of ``chunk`` (``sa_config``'s
 ``q_chunk_size``): a chunk's scores against all keys are ``[chunk, S]``
 float32, the indexer heads' products ``[chunk, heads, S]``; nothing of
 ``[heads, S, S]`` is ever whole. The chunks go in ``_BANDS`` bands, each
-against the keys up to its own last query only. The chunk loops are ``jax.numpy`` under
-``lax.scan``; PERF.md section 7 has what kernels would save.
+against the keys up to its own last query only. The chunk loops are ``lax.scan``s
+of ``jax.numpy`` but for one line: where the attention runs its Pallas kernel
+(``attn_impl: pallas``), the index loss takes ``pbar`` from a launch a chunk
+(``ops/index_pbar.py``) that keeps every head's ``[chunk, block_k]`` score
+tile in VMEM and writes ``[chunk, S]`` once; the ``xla`` path forms the
+heads' ``[H, chunk, S]`` float32 scores in HBM, and is what the CPU runs and
+what the tests hold the kernel to. PERF.md section 7 has what kernels for
+the selection and the index scores would save.
 """
 
 from __future__ import annotations
@@ -35,6 +41,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from photon_tpu.ops.index_pbar import head_mean_probabilities, key_block, live_key_tiles
 
 #: bits of the threshold search decided a pass: 15 counts over the chunk in
 #: one fused pass, 8 passes for a float32
@@ -136,32 +144,60 @@ def select_keys(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array, *, topk: int,
 # ---------------------------------------------------------------------------
 
 
-def _row_index_loss(q_idx, k_idx, w, q, k, lse, mask, scale: float, chunk: int,
-                    with_grads: bool):
-    """One row: ``(sum over queries of the KL, gradients by q_idx, k_idx, w
-    at unit cotangent or None)``. ``q [S, H, D]``, ``k [S, G, D]``, ``lse [H,
-    S]``, ``mask [S, S]``."""
+def uses_kernel(impl: str, interpret: bool = False, x: jax.Array | None = None) -> bool:
+    """Whether the index loss takes ``pbar`` from the Pallas launch, by
+    ``masked_multihead_attention``'s rule: ``pallas`` is the kernel on a TPU
+    (or anywhere under ``interpret``) and steps down on the CPU backend."""
+    # looked up at the call: the offline compile check swaps the function
+    from photon_tpu.ops import flash_attention
+
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"the index loss has no impl {impl!r}")
+    return impl == "pallas" and (interpret or flash_attention.pallas_supported(x))
+
+
+def _grouped(q, k, lse, chunk: int):
+    """A key-value group's heads side by side, one plain product a group:
+    ``q [S, H, D]`` -> ``[chunks, G, H/G * chunk, D]``, ``lse [H, S]`` ->
+    ``[chunks, G, H/G * chunk]`` in the same row order, ``k [S, G, D]`` ->
+    ``[G, S, D]``."""
     s, heads, d = q.shape
     groups = k.shape[1]
     per = heads // groups
-    # a kv group's heads side by side, one plain product a group:
-    # [chunks, G, H/G * chunk, D] against [G, S, D]
     qg = q.reshape(s // chunk, chunk, groups, per, d).transpose(0, 2, 3, 1, 4)
     qg = qg.reshape(s // chunk, groups, per * chunk, d)
     lse_g = lse.reshape(groups, per, s // chunk, chunk).transpose(2, 0, 1, 3)
-    lse_g = lse_g.reshape(s // chunk, groups, per * chunk)
-    kg = k.transpose(1, 0, 2)  # [G, S, D]
+    return qg, k.transpose(1, 0, 2), lse_g.reshape(s // chunk, groups, per * chunk)
+
+
+def _pbar_xla(qc, kg, lc, picked, scale: float):
+    """The ``xla`` path's ``pbar [chunk, n_keys]`` of one chunk: all its
+    heads' scores ``[H, chunk, n_keys]`` float32 at once."""
+    chunk, n_keys = picked.shape
+    dots = jnp.einsum("gmd,gsd->gms", qc, kg[:, :n_keys], preferred_element_type=jnp.float32)
+    probs = jnp.exp(dots * scale - lc[..., None]).reshape(-1, chunk, n_keys)
+    return jnp.where(picked, jnp.sum(probs, axis=0) / probs.shape[0], 0.0)
+
+
+def _row_index_loss(q_idx, k_idx, w, q, k, lse, mask, scale: float, chunk: int,
+                    with_grads: bool, kernel: bool, interpret: bool):
+    """One row: ``(sum over queries of the KL, gradients by q_idx, k_idx, w
+    at unit cotangent or None)``. ``q [S, H, D]``, ``k [S, G, D]``, ``lse [H,
+    S]``, ``mask [S, S]``."""
+    s = q.shape[0]
+    qg, kg, lse_g = _grouped(q, k, lse, chunk)
 
     def one_chunk(carry, xs, n_keys):
         total, dk_idx = carry
-        qic, wc, qc, lc, mc = xs
+        qic, wc, qc, lc, mc, c = xs
         picked = mc[:, :n_keys] != 0
         # pbar: every head's probabilities over the picked keys, from the
         # attention's own log-sum-exp, averaged over the heads
-        dots = jnp.einsum("gmd,gsd->gms", qc, kg[:, :n_keys],
-                          preferred_element_type=jnp.float32)
-        probs = jnp.exp(dots * scale - lc[..., None]).reshape(heads, chunk, n_keys)
-        pbar = jnp.where(picked, jnp.sum(probs, axis=0) / heads, 0.0)
+        if kernel:
+            pbar = head_mean_probabilities(qc, kg, lc, mc, c, scale=scale, n_keys=n_keys,
+                                           interpret=interpret)
+        else:
+            pbar = _pbar_xla(qc, kg, lc, picked, scale)
         if with_grads:
             scores, pull = jax.vjp(index_scores, qic, k_idx[:n_keys], wc)
         else:
@@ -183,7 +219,8 @@ def _row_index_loss(q_idx, k_idx, w, q, k, lse, mask, scale: float, chunk: int,
         dqic, dkc, dwc = pull(d_scores)
         return (total, dk_idx + dkc.astype(jnp.float32)), (dqic, dwc)
 
-    xs = (_chunked(q_idx, chunk), _chunked(w, chunk), qg, lse_g, _chunked(mask, chunk))
+    xs = (_chunked(q_idx, chunk), _chunked(w, chunk), qg, lse_g, _chunked(mask, chunk),
+          jnp.arange(s // chunk, dtype=jnp.int32))
     total = jnp.zeros([], jnp.float32)
     dk_idx = jnp.zeros(k_idx.shape, jnp.float32) if with_grads else None
     grads = []
@@ -204,10 +241,12 @@ def _row_index_loss(q_idx, k_idx, w, q, k, lse, mask, scale: float, chunk: int,
                    dw.reshape(w.shape))
 
 
-def _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, with_grads):
+def _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, kernel, interpret,
+                     with_grads):
     chunk = min(chunk, q.shape[1])
     totals, grads = jax.lax.map(
-        lambda a: _row_index_loss(*a, scale=scale, chunk=chunk, with_grads=with_grads),
+        lambda a: _row_index_loss(*a, scale=scale, chunk=chunk, with_grads=with_grads,
+                                  kernel=kernel, interpret=interpret),
         (q_idx, k_idx, w, q, k, lse, mask))
     n = q.shape[0] * q.shape[1]
     loss = jnp.sum(totals) / n
@@ -216,17 +255,18 @@ def _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, with_grads)
     return loss, jax.tree.map(lambda g: (g.astype(jnp.float32) / n).astype(g.dtype), grads)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _index_loss(q_idx, k_idx, w, q, k, lse, mask, scale, chunk):
-    return _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _index_loss(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, kernel, interpret):
+    return _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, kernel,
+                            interpret, False)[0]
 
 
-def _index_loss_fwd(q_idx, k_idx, w, q, k, lse, mask, scale, chunk):
-    loss, grads = _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, True)
-    return loss, grads
+def _index_loss_fwd(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, kernel, interpret):
+    return _index_loss_loop(q_idx, k_idx, w, q, k, lse, mask, scale, chunk, kernel,
+                            interpret, True)
 
 
-def _index_loss_bwd(scale, chunk, grads, g):
+def _index_loss_bwd(scale, chunk, kernel, interpret, grads, g):
     scaled = [(g * x.astype(jnp.float32)).astype(x.dtype) for x in grads]
     # the attention's q, k and log-sum-exp and the mask are detached
     return (*scaled, None, None, None, None)
@@ -237,7 +277,8 @@ _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 def index_loss(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array, q: jax.Array,
                k: jax.Array, lse: jax.Array, mask: jax.Array, *, chunk: int,
-               scale: float | None = None) -> jax.Array:
+               scale: float | None = None, impl: str = "xla",
+               interpret: bool = False) -> jax.Array:
     """The indexer's alignment loss, mean over the batch's queries.
 
     ``q_idx [B, S, J, Di]``, ``k_idx [B, S, Di]``, ``w [B, S, J]`` the
@@ -245,7 +286,31 @@ def index_loss(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array, q: jax.Array,
     take the gradient); ``q [B, S, H, D]``, ``k [B, S, G, D]`` the attention's
     own rotated queries and keys, ``lse [B, H, S]`` its log-sum-exp over the
     picked keys (``masked_flash_attention``'s second result) and ``mask [B, S,
-    S]`` the selection: all four detached here."""
+    S]`` the selection: all four detached here.
+
+    ``impl`` / ``interpret`` are the attention's own (``cfg.attn_impl``,
+    ``cfg.attn_interpret``) and choose how a chunk's ``pbar`` is made, the
+    one line the two paths do not share: ``pallas`` is one launch a chunk
+    (``ops/index_pbar.py``: the heads' score tiles stay in VMEM, ``[chunk,
+    S]`` float32 is written once) on a TPU or in the interpreter, and steps
+    down to ``xla`` on the CPU backend; ``xla`` is an ``einsum``, an
+    exponential and a sum over ``[H, chunk, S]`` float32 in HBM, the oracle
+    of the kernel's tests. The index scores, the KL and the gradient are
+    ``jax.numpy`` on both."""
     q, k, lse = jax.lax.stop_gradient((q, k, lse))
     scale = 1.0 / (q.shape[-1] ** 0.5) if scale is None else float(scale)
-    return _index_loss(q_idx, k_idx, w, q, k, lse, mask, scale, int(chunk))
+    return _index_loss(q_idx, k_idx, w, q, k, lse, mask, scale, int(chunk),
+                       uses_kernel(impl, interpret, q), bool(interpret))
+
+
+def index_loss_tiles(s: int, chunk: int) -> tuple[int, int]:
+    """``(computed, skipped)`` key tiles of the ``pbar`` launches of one row
+    of ``s`` positions, one pass: a chunk's launch walks its band's tiles and
+    computes those that hold a key one of its queries may see."""
+    chunk = min(chunk, s)
+    computed = grid = 0
+    for lo, hi in _bands(s // chunk):
+        block_k = key_block(hi * chunk)
+        grid += (hi - lo) * (hi * chunk // block_k)
+        computed += sum(live_key_tiles(c, chunk, block_k) for c in range(lo, hi))
+    return computed, grid - computed

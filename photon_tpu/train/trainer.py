@@ -120,6 +120,21 @@ def _dsa_static_counts(model_cfg, batch_rows: int) -> dict[str, float]:
             DSA_TILES_CAUSAL: float(rows * tiles)}
 
 
+def _index_loss_attrs(model_cfg, batch_rows: int) -> dict[str, Any]:
+    """Which path makes the index loss's ``pbar`` in this step (``ops/dsa.
+    uses_kernel``: the Pallas launch or ``jax.numpy``), and the key tiles the
+    launches of a step compute and skip: static, the skipped ones are dead by
+    the causal rule; every layer and row, twice under ``remat``."""
+    from photon_tpu.ops import dsa
+
+    kernel = dsa.uses_kernel(model_cfg.attn_impl, model_cfg.attn_interpret)
+    computed, skipped = dsa.index_loss_tiles(model_cfg.max_seq_len, model_cfg.dsa_chunk)
+    passes = (2 if model_cfg.remat else 1) if kernel else 0
+    launches = passes * model_cfg.n_layers * batch_rows
+    return {"index_loss_kernel": kernel, "index_loss_tiles": launches * computed,
+            "index_loss_tiles_skipped": launches * skipped}
+
+
 def _set_opt_count(opt_state: Any, step: int) -> Any:
     """Return ``opt_state`` with every ``count`` field (optax's step counter
     in AdoptState / ScaleByAdamState / ...) set to ``step``."""
@@ -509,7 +524,8 @@ class Trainer:
                             causal_pairs=last_metrics[DSA_CAUSAL_PAIRS],
                             tiles_visited=last_metrics[DSA_TILES_VISITED],
                             tiles_causal=last_metrics[DSA_TILES_CAUSAL],
-                            index_loss=last_metrics[DSA_INDEX_LOSS]):
+                            index_loss=last_metrics[DSA_INDEX_LOSS],
+                            **_index_loss_attrs(self.model.cfg, batch.shape[0])):
                         pass
         dt = time.monotonic() - t0
         return {

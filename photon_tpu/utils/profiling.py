@@ -194,7 +194,9 @@ MOE_MAX_EXPERT_LOAD = "moe/max_expert_load"
 #: opened and closed inside the fence like ``trainer/moe_load``, only when the
 #: step holds the indexer's sparse attention: attrs ``picked_pairs``,
 #: ``causal_pairs``, ``tiles_visited``, ``tiles_causal``, ``index_loss`` (the
-#: five counters below, of the fit's last step)
+#: five counters below, of the fit's last step) and the static
+#: ``index_loss_kernel``, ``index_loss_tiles``, ``index_loss_tiles_skipped``
+#: (which path makes the index loss's ``pbar``, and its launches' key tiles)
 TRAINER_DSA_SPAN = "trainer/dsa"
 # -- learned sparse attention (models/mpt.py, ops/dsa.py): counters in the
 # train step's metrics, summed over the layers, fetched with the loss -------
@@ -214,8 +216,9 @@ DSA_INDEXER_SCOPE = "dsa/indexer"
 #: the index scores by query chunk, each query's threshold, the mask and its
 #: tile counts
 DSA_SELECT_SCOPE = "dsa/select"
-#: the second pass over q.k for the heads' mean probabilities, the index
-#: scores again, the loss and its gradient into the indexer
+#: the second pass over q.k for the heads' mean probabilities (a launch a
+#: chunk under its own ``index_pbar`` where the attention runs its kernel),
+#: the index scores again, the loss and its gradient into the indexer
 DSA_INDEX_LOSS_SCOPE = "dsa/index_loss"
 #: the per-head RMSNorm of q and k before the rotation
 ATTN_QK_NORM_SCOPE = "attn/qk_norm"

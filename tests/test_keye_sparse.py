@@ -29,11 +29,12 @@ if str(ROOT) not in sys.path:  # `benchmark` is a sibling of `tests`, not instal
 from benchmark.reference import keye_sparse_moe as ref  # noqa: E402
 from photon_tpu.config import load_preset  # noqa: E402
 from photon_tpu.models import MPTModel  # noqa: E402
-from photon_tpu.ops import dsa, moe  # noqa: E402
+from photon_tpu.ops import dsa, index_pbar, moe  # noqa: E402
 from photon_tpu.ops import masked_flash_attention as mfa  # noqa: E402
 from photon_tpu.train.train_step import _make_loss_and_counters_fn, make_loss_fn  # noqa: E402
 from photon_tpu.utils.profiling import (  # noqa: E402
     DSA_INDEX_LOSS,
+    DSA_INDEX_LOSS_SCOPE,
     DSA_PICKED_PAIRS,
     DSA_TILES_VISITED,
 )
@@ -367,19 +368,108 @@ def test_a_query_without_a_key_gives_zeros_and_no_nan():
     assert all(np.all(np.isfinite(np.asarray(g))) for g in grads)
 
 
-def test_the_model_through_the_kernel_in_the_interpreter(seeded):
-    """The whole objective with the masked kernel where the CPU run has the
-    XLA path: the same loss and the same gradients."""
+def test_the_model_through_the_kernel_in_the_interpreter(seeded, monkeypatch):
+    """The whole objective with the masked kernel and the index loss's
+    ``pbar`` launch where the CPU run has the XLA paths: the same loss and the
+    same gradients."""
     _, _, params, (want, g_want), _ = seeded
+    launches = []
+
+    def counted(*args, **kwargs):
+        launches.append(kwargs["n_keys"])
+        return index_pbar.head_mean_probabilities(*args, **kwargs)
+
+    monkeypatch.setattr(dsa, "head_mean_probabilities", counted)
     cfg = tiny_cfg(attn_impl="pallas", attn_interpret=True)
     loss, grads = jax.value_and_grad(make_loss_fn(MPTModel(cfg.model), 16))(params, TOKENS)
+    # every trace of the block walks the four bands' chunk loops
+    assert launches and sorted(set(launches)) == [16, 32, 48, 64]
     assert abs(float(loss) - float(want)) < 1e-5
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(g_want)):
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
 
 
-def test_the_index_loss_takes_its_gradient_in_the_forward_loop():
-    """``ops/dsa.index_loss`` against plain autodiff of the same formula."""
+# ---------------------------------------------------------------------------
+# the index loss, and the launch that makes its pbar
+# ---------------------------------------------------------------------------
+
+
+def _pbar_case(case: str, h: int, g: int):
+    """One chunk's operands as ``dsa._row_index_loss`` lays them out, from a
+    row's attention inputs and the masked attention's own log-sum-exp:
+    ``(q, k, lse, mask of the chunk, chunk index, n_keys, block_k)``."""
+    s, chunk, d = 256, 32, 128
+    q, k, _, mask = _attention_inputs(5, s=s, h=h, g=g, d=d, b=1)
+    c, n_keys, block_k = 7, s, 128  # the row's last chunk: two live tiles
+    if case == "one_tile":
+        n_keys, block_k = 128, None
+        c = 3
+    elif case == "dead_tiles":
+        # keys 128.. are past chunk 3's last query, and none of its queries
+        # picked a key of 0..63 either
+        c = 3
+        mask = mask.at[:, c * chunk:(c + 1) * chunk, :64].set(0)
+        block_k = 64
+    elif case == "query_without_a_key":
+        mask = mask.at[:, c * chunk + 5].set(0)
+    elif case == "first_band":  # t < topk: every causal key is picked
+        c, n_keys = 1, 128
+        mask = jnp.tril(jnp.ones((s, s), jnp.int8))[None]
+    else:
+        assert case == "several_tiles"
+    _, lse = mfa.masked_xla_attention(q, k, k, mask)
+    qg, kg, lse_g = dsa._grouped(q[0], k[0], lse[0], chunk)
+    return qg[c], kg, lse_g[c], mask[0, c * chunk:(c + 1) * chunk], c, n_keys, block_k
+
+
+@pytest.mark.parametrize("h, g", [(4, 2), (8, 1)], ids=["h4g2", "h8g1"])
+@pytest.mark.parametrize("case", ["one_tile", "several_tiles", "dead_tiles",
+                                  "query_without_a_key", "first_band"])
+def test_the_pbar_launch_is_the_jax_numpy_line(case, h, g):
+    """``ops/index_pbar`` in the interpreter against ``dsa._pbar_xla``, the
+    line it replaces, and against the heads' softmax over the picked keys."""
+    with jax.default_matmul_precision("highest"):
+        qc, kg, lc, mc, c, n_keys, block_k = _pbar_case(case, h, g)
+        scale = qc.shape[-1] ** -0.5
+        got = index_pbar.head_mean_probabilities(
+            qc, kg, lc, mc, jnp.int32(c), scale=scale, n_keys=n_keys, interpret=True,
+            block_k=block_k)
+        picked = mc[:, :n_keys] != 0
+        want = dsa._pbar_xla(qc, kg, lc, picked, scale)
+        scores = jnp.einsum("gmd,gsd->gms", qc, kg) * scale
+        soft = jax.nn.softmax(jnp.where(jnp.tile(mc != 0, (h // g, 1))[None], scores, -1e30), -1)
+    assert got.shape == (32, n_keys) and got.dtype == jnp.float32
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    rows = np.asarray(jnp.any(mc != 0, axis=-1))
+    plain = jnp.mean(soft.reshape(h, 32, -1), axis=0)[:, :n_keys]
+    np.testing.assert_allclose(np.asarray(got)[rows], np.asarray(plain)[rows], atol=1e-6)
+    assert not np.any(np.asarray(got)[~np.asarray(picked)])
+    if case == "query_without_a_key":
+        assert not rows[5] and not np.any(np.asarray(got[5]))
+    elif case == "first_band":
+        assert bool(jnp.all(picked == (jnp.arange(n_keys)[None] <= c * 32 + jnp.arange(32)[:, None])))
+        np.testing.assert_allclose(jnp.sum(got, axis=-1), 1.0, atol=1e-5)
+    elif case == "dead_tiles":
+        assert not np.any(np.asarray(got[:, :64])) and np.any(np.asarray(got[:, 64:128]))
+
+
+def test_the_pbar_launch_counts_its_tiles():
+    """The tiles a row's launches compute and skip, as the ``trainer/dsa``
+    span tells them: at the cell's shapes 1,024-key tiles, of which a chunk
+    computes those up to its own last query."""
+    assert index_pbar.key_block(16384) == index_pbar.key_block(12288) == 1024
+    computed, skipped = dsa.index_loss_tiles(16384, 512)
+    assert computed == 2 * sum(range(1, 17)) == 272
+    assert computed + skipped == 8 * (4 + 8 + 12 + 16)
+    assert dsa.index_loss_tiles(64, 16) == (4, 0)  # a tiny row: one tile a chunk
+    assert [int(index_pbar.live_key_tiles(c, 512, 1024)) for c in (0, 1, 2, 31)] == [1, 1, 2, 16]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_index_loss_takes_its_gradient_in_the_forward_loop(impl):
+    """``ops/dsa.index_loss`` against plain autodiff of the same formula, on
+    the ``jax.numpy`` path and through the launch in the interpreter."""
     b, s, h, g, d = 2, 64, 4, 2, 16
     with jax.default_matmul_precision("highest"):
         q_idx, k_idx, w = _indexer_inputs(4)
@@ -400,12 +490,82 @@ def test_the_index_loss_takes_its_gradient_in_the_forward_loop():
                                            - jnp.where(picked, log_soft, 0.0)), 0.0)
             return jnp.sum(kl) / (b * s)
 
-        mine = lambda *a: dsa.index_loss(*a, q, k, lse, mask, chunk=16)  # noqa: E731
+        mine = lambda *a: dsa.index_loss(  # noqa: E731
+            *a, q, k, lse, mask, chunk=16, impl=impl, interpret=impl == "pallas")
         want, g_want = jax.value_and_grad(plain, (0, 1, 2))(q_idx, k_idx, w)
         got, g_got = jax.value_and_grad(mine, (0, 1, 2))(q_idx, k_idx, w)
     assert abs(float(got) - float(want)) < 1e-5
     for a, c in zip(g_got, g_want):
         np.testing.assert_allclose(a, c, atol=1e-6)
+
+
+def test_the_index_loss_has_no_impl_but_the_attentions():
+    q_idx, k_idx, w = _indexer_inputs(4)
+    q, k, _, mask = _attention_inputs(4, s=64, d=16)
+    with pytest.raises(ValueError, match="no impl"):
+        dsa.index_loss(q_idx, k_idx, w, q, k, jnp.zeros((2, 4, 64)), mask, chunk=16,
+                       impl="ring")
+
+
+def _equations(jaxpr, inside_launch=False):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold, each
+    with whether it is inside a ``pallas_call``."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_launch
+        inner = inside_launch or eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, inner)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_heads_scores_never_leave_the_launch(impl):
+    """What the launch is for: in the traced program of the kernel path (the
+    loss and its gradient, every chunk's step) no float32 value holds a
+    chunk's scores for all heads outside the ``pallas_call``; the ``xla``
+    path makes two of them (the product, the exponential) a band and pass."""
+    s, h, g, d, chunk = 320, 8, 2, 32, 80  # no two of the sizes below alike
+    q_idx, k_idx, w = _indexer_inputs(6, b=1, s=s)
+    q, k, _, mask = _attention_inputs(6, s=s, h=h, g=g, d=d, b=1)
+    lse = jnp.zeros((1, h, s), jnp.float32)
+    loss = lambda *a: dsa.index_loss(  # noqa: E731
+        *a, q, k, lse, mask, chunk=chunk, impl=impl, interpret=impl == "pallas")
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(q_idx, k_idx, w).jaxpr
+    # [H, chunk, n_keys], or the groups' [G, H/G * chunk, n_keys], for a band's n_keys
+    scores = {(a, b, n) for a, b in ((h, chunk), (g, h // g * chunk))
+              for n in range(chunk, s + 1, chunk)}
+    whole, launches = [], 0
+    for eqn, inside in _equations(jaxpr):
+        launches += eqn.primitive.name == "pallas_call"
+        whole += [(eqn.primitive.name, v.aval.shape) for v in eqn.outvars
+                  if not inside and tuple(v.aval.shape[-3:]) in scores]
+    if impl == "pallas":
+        assert launches == 4 and not whole, whole  # a launch a band
+    else:
+        assert launches == 0 and len(whole) >= 8, whole
+
+
+def test_the_pbar_launch_is_counted_under_the_index_loss_alone():
+    """Every operation of the launch (here the interpreter's, on the chip one
+    custom call: ``tests/test_tpu_compile.py``) carries ``dsa/index_loss`` and
+    its own scope, and none the names by which ``flash_fwd_ms_train``,
+    ``flash_bwd_ms_train`` and ``sparse_attention_roofline`` find the
+    attention's launches."""
+    import re
+
+    from benchmark.layer_metrics.sparse_attention_roofline import KERNELS
+
+    cfg = tiny_cfg(attn_impl="pallas", attn_interpret=True, n_layers=1)
+    params = ref.make_params(dims_of(cfg), 7)
+    compiled = jax.jit(jax.grad(make_loss_fn(MPTModel(cfg.model), 16))).lower(
+        params, TOKENS).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', compiled))
+    launch = {n for n in names if index_pbar.INDEX_PBAR_SCOPE in n}
+    assert len(launch) > 10
+    # ``dsa_index_loss_ms_train``'s pattern
+    assert all(re.search(rf"\b{DSA_INDEX_LOSS_SCOPE}\b", n) for n in launch)
+    flash = re.compile(KERNELS.replace(".*pallas_call", ""))
+    assert not any(flash.search(n) for n in launch)
+    assert any(flash.search(n) for n in names)  # the attention's own are there
 
 
 # ---------------------------------------------------------------------------
@@ -488,6 +648,23 @@ def test_fit_returns_the_selection_counters_on_their_span():
     assert out[DSA_TILES_VISITED] == out[DSA_TILES_CAUSAL] == rows  # one tile a row
     assert 0.0 < out[DSA_INDEX_LOSS] < 2.0
     assert 64 <= out[MOE_ROWS_HELD] <= 256  # ~ 2 x 64 x 2 x 2 x 2/8
+
+
+@pytest.mark.parametrize("impl, interpret, remat", [
+    ("xla", False, False), ("pallas", False, False), ("pallas", True, False),
+    ("pallas", True, True)])
+def test_the_dsa_span_says_which_path_made_pbar(impl, interpret, remat):
+    """``trainer/dsa`` carries the path the index loss took and the key tiles
+    its launches computed and skipped a step; on the CPU backend ``pallas``
+    without the interpreter steps down, as the attention does."""
+    from photon_tpu.train.trainer import _index_loss_attrs
+
+    model = tiny_cfg(attn_impl=impl, attn_interpret=interpret, remat=remat).model
+    attrs = _index_loss_attrs(model, batch_rows=2)
+    assert attrs["index_loss_kernel"] is (impl == "pallas" and interpret)
+    launches = 2 * 2 * (2 if remat else 1) if interpret else 0  # layers x rows x passes
+    assert attrs["index_loss_tiles"] == launches * 4
+    assert attrs["index_loss_tiles_skipped"] == 0
 
 
 def test_every_new_parameter_has_a_sharding_rule():

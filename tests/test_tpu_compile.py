@@ -396,6 +396,57 @@ def test_the_exact_selection_compiles_without_a_sort_or_a_whole_square(one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
 
 
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_the_index_loss_keeps_the_heads_scores_in_vmem(one_chip, monkeypatch, impl):
+    """``ops/dsa.index_loss`` with its gradient at the cell's widths (32 query
+    / 4 key-value heads of 128, an indexer of 16 heads of 64, chunks of 512;
+    4,096 positions here, the same 1,024-key tiles). On the kernel path
+    Mosaic takes a group's eight heads as row slices of one block and an
+    output block that stays put over the inner grid dimension; the launch
+    carries ``dsa/index_loss`` and a scope of its own, not the names by which
+    ``flash_*_ms_train`` and ``sparse_attention_roofline`` find the
+    attention's launches; and all the program's temporaries together (149 MB:
+    the indexer heads' ``[512, 16, S]`` float32 products are most of it) are
+    smaller than a chunk's ``[32, 512, S]`` float32 scores (268 MB here),
+    which the ``xla`` path holds (427 MB)."""
+    import photon_tpu.ops.flash_attention as fa
+    from photon_tpu.ops import dsa
+    from photon_tpu.ops.index_pbar import INDEX_PBAR_SCOPE, key_block
+    from photon_tpu.utils.profiling import DSA_INDEX_LOSS_SCOPE
+
+    # tracing here sees the CPU as the default backend, where the dispatch
+    # steps down to ``jax.numpy``: steer it, in the test
+    monkeypatch.setattr(fa, "pallas_supported", lambda x: True)
+    s = 4096
+    assert key_block(s) == key_block(16384) == 1024
+    q_idx = _abstract((1, s, 16, 64), jnp.bfloat16, one_chip)
+    k_idx = _abstract((1, s, 64), jnp.bfloat16, one_chip)
+    w = _abstract((1, s, 16), jnp.float32, one_chip)
+    q = _abstract((1, s, 32, 128), jnp.bfloat16, one_chip)
+    k = _abstract((1, s, 4, 128), jnp.bfloat16, one_chip)
+    lse = _abstract((1, 32, s), jnp.float32, one_chip)
+    mask = _abstract((1, s, s), jnp.int8, one_chip)
+
+    def loss(q_idx, k_idx, w, q, k, lse, mask):
+        with jax.named_scope(DSA_INDEX_LOSS_SCOPE):
+            return dsa.index_loss(q_idx, k_idx, w, q, k, lse, mask, chunk=512, impl=impl)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q_idx, k_idx, w, q, k, lse, mask).compile()
+    launches = [ln.strip() for ln in compiled.as_text().splitlines() if KERNEL in ln]
+    scores = 32 * 512 * s * 4
+    if impl == "xla":
+        assert not launches
+        assert compiled.memory_analysis().temp_size_in_bytes > scores
+        return
+    assert len(launches) == 4  # a launch a band, inside its chunk loop
+    for ln in launches:
+        name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert re.search(rf"\b{DSA_INDEX_LOSS_SCOPE}\b.*\b{INDEX_PBAR_SCOPE}/pallas_call", name)
+        assert not re.search(r"\bflash_(fwd|dq|dkv)/multihead_attention\b", name)
+    assert compiled.memory_analysis().temp_size_in_bytes < scores
+
+
 # ---------------------------------------------------------------------------
 # whole train steps
 # ---------------------------------------------------------------------------
