@@ -57,6 +57,7 @@ from photon_tpu.utils.profiling import (
     CLIENT_RESOLVE_PARAMS_SPAN,
     CLIENT_SKIPPED_ROUND,
     CLIENT_TRAIN_SPAN,
+    TRANSPORT_UNMAP_SPAN,
 )
 
 
@@ -134,15 +135,26 @@ class ClientRuntime:
         codec's delta base: this round's fit results upload as
         ``w_new − w_global`` against exactly these arrays. On the shm plane
         they are read-only views of the server's segment: holding them holds
-        its mapping, and rebinding here is what lets the previous round's
-        pages go."""
-        self._current_params = self.transport.get(ptr)
-        self.transport.set_reference(self._current_params[1])
+        its mapping, and letting the previous round's go here is what lets
+        its pages go."""
+        self._rebind_params(ptr)
+
+    def _rebind_params(self, ptr) -> None:
+        """The params behind ``ptr`` in the previous ones' place, here and as
+        the codec's delta base. Once the read has returned, the previous arrays
+        are dropped under a span of their own: on the shm plane these are the
+        last references to the old segment's mapping, and the un-map is 0.4 s
+        of a 9 s mpt-125m round (PERF.md section 5)."""
+        current = self.transport.get(ptr)
+        with telemetry.span(TRANSPORT_UNMAP_SPAN, push=False, mode=ptr.kind):
+            self.transport.set_reference(None)
+            self._current_params = None
+        self._current_params = current
+        self.transport.set_reference(current[1])
 
     def _resolve_params(self, ptr) -> tuple[ParamsMetadata, list[np.ndarray]]:
         if ptr is not None:
-            self._current_params = self.transport.get(ptr)
-            self.transport.set_reference(self._current_params[1])
+            self._rebind_params(ptr)
         if self._current_params is None:
             raise RuntimeError("no parameters: neither FitIns pointer nor prior broadcast")
         return self._current_params

@@ -62,7 +62,10 @@ import jax.numpy as jnp
 from photon_tpu.config.schema import ModelConfig
 from photon_tpu.ops.attention import multihead_attention
 from photon_tpu.utils.profiling import (
+    ATTN_PROJ_SCOPE,
     ATTN_QK_NORM_SCOPE,
+    BLOCK_MLP_SCOPE,
+    BLOCK_NORM_SCOPE,
     DSA_INDEX_LOSS_SCOPE,
     DSA_INDEXER_SCOPE,
     DSA_SELECT_SCOPE,
@@ -339,7 +342,8 @@ class MPTBlock(nn.Module):
         compute = _dtype(cfg.compute_dtype)
         pd = _dtype(cfg.param_dtype)
         init = nn.initializers.normal(stddev=cfg.emb_init_std)
-        h32 = _norm(cfg, "ln_2")(x.astype(jnp.float32))
+        with jax.named_scope(BLOCK_NORM_SCOPE):
+            h32 = _norm(cfg, "ln_2")(x.astype(jnp.float32))
         h = h32.astype(compute)
         held = cfg.experts_held
         router_w = self.param("router", init, (cfg.d_model, cfg.moe_num_experts), pd)
@@ -415,7 +419,8 @@ class MPTBlock(nn.Module):
         resid_std = cfg.emb_init_std / (2.0 * cfg.n_layers) ** 0.5
 
         # --- the mixer: attention, or a Mamba-2 mixer in its place ---
-        h = _norm(cfg, "ln_1")(x)
+        with jax.named_scope(BLOCK_NORM_SCOPE):
+            h = _norm(cfg, "ln_1")(x)
         if self.mixer == "mamba":
             x = _residual(cfg, x, self._mamba_mixer(h, dense, resid_std))
         else:
@@ -423,21 +428,23 @@ class MPTBlock(nn.Module):
             b, s, _ = h.shape
             if cfg.latent_attention:
                 with jax.named_scope(MLA_PROJ_SCOPE):
-                    q, k, v = self._latent_qkv(h, dense)
-            elif n_kv == cfg.n_heads:
-                qkv = adapted(3 * cfg.d_model, "wqkv", cfg.emb_init_std, h)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
+                    q, k, v = self._latent_qkv(h, dense)  # [B, S, H, d_head] each
             else:
-                # GQA: separate projections — a fused q||k||v matrix would put
-                # shard boundaries at positions that don't align with the
-                # tensor axis and force per-layer resharding; three
-                # column-parallel matmuls stay shard-local
-                q = adapted(cfg.n_heads * cfg.d_head, "q_proj", cfg.emb_init_std, h)
-                k = adapted(n_kv * cfg.d_head, "k_proj", cfg.emb_init_std, h)
-                v = adapted(n_kv * cfg.d_head, "v_proj", cfg.emb_init_std, h)
-            q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
-            k = k.reshape(b, s, n_kv, cfg.d_head)
-            v = v.reshape(b, s, n_kv, cfg.d_head)
+                with jax.named_scope(ATTN_PROJ_SCOPE):
+                    if n_kv == cfg.n_heads:
+                        qkv = adapted(3 * cfg.d_model, "wqkv", cfg.emb_init_std, h)
+                        q, k, v = jnp.split(qkv, 3, axis=-1)
+                    else:
+                        # GQA: separate projections — a fused q||k||v matrix would
+                        # put shard boundaries at positions that don't align with
+                        # the tensor axis and force per-layer resharding; three
+                        # column-parallel matmuls stay shard-local
+                        q = adapted(cfg.n_heads * cfg.d_head, "q_proj", cfg.emb_init_std, h)
+                        k = adapted(n_kv * cfg.d_head, "k_proj", cfg.emb_init_std, h)
+                        v = adapted(n_kv * cfg.d_head, "v_proj", cfg.emb_init_std, h)
+                    q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
+                    k = k.reshape(b, s, n_kv, cfg.d_head)
+                    v = v.reshape(b, s, n_kv, cfg.d_head)
             if cfg.qk_norm:
                 with jax.named_scope(ATTN_QK_NORM_SCOPE):
                     q = FP32RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
@@ -445,7 +452,8 @@ class MPTBlock(nn.Module):
             if cfg.rope and not cfg.latent_attention:
                 # before the kv repeat: the rotation is per-head-identical, so
                 # rotating n_kv heads then replicating equals the reverse order
-                q, k = apply_rope(q, k, cfg.rope_theta)
+                with jax.named_scope(ATTN_PROJ_SCOPE):
+                    q, k = apply_rope(q, k, cfg.rope_theta)
             # k/v go to the dispatch at their native n_kv width: the pallas
             # flash kernel consumes GQA groups directly (index-mapped kv rows,
             # no repeated tensor in HBM); the xla/ring paths replicate inside
@@ -465,8 +473,9 @@ class MPTBlock(nn.Module):
                     x = _residual(cfg, x, dense(cfg.d_model, "out_proj", resid_std)(
                         attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim)))
             else:
-                attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.d_head)
-                x = _residual(cfg, x, adapted(cfg.d_model, "out_proj", resid_std, attn_out))
+                with jax.named_scope(ATTN_PROJ_SCOPE):
+                    attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.d_head)
+                    x = _residual(cfg, x, adapted(cfg.d_model, "out_proj", resid_std, attn_out))
 
         # --- MLP ---
         hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * cfg.d_model
@@ -474,7 +483,8 @@ class MPTBlock(nn.Module):
             hidden = cfg.dense_mlp_hidden_size
         elif cfg.dropless_moe:
             return _residual(cfg, x, self._dropless_moe(x, dense, hidden, resid_std))
-        h = _norm(cfg, "ln_2")(x)
+        with jax.named_scope(BLOCK_NORM_SCOPE):
+            h = _norm(cfg, "ln_2")(x)
         if cfg.mlp == "moe" and not self.dense_mlp:
             # expert-parallel MLP (ops/moe.py): router + E expert FFNs,
             # GShard dense dispatch. Expert weights carry a leading [E]
@@ -516,19 +526,22 @@ class MPTBlock(nn.Module):
                 moe_out, P(("data", "fsdp", "expert"), "sequence", None)
             )
             return _residual(cfg, x, moe_out)
-        if cfg.mlp == "swiglu" or self.dense_mlp:
-            # separate gate/up projections (standard llama layout): each is
-            # column-parallel under the same sharding rule, so silu(gate)*up
-            # is shard-local — a fused gate||up matrix would put ALL of gate
-            # on the first half of the tensor group and force a per-layer
-            # resharding collective
-            gate = adapted(hidden, "gate_proj", cfg.emb_init_std, h)
-            up = adapted(hidden, "up_proj", cfg.emb_init_std, h)
-            h = nn.silu(gate) * up
-        else:
-            h = adapted(hidden, "up_proj", cfg.emb_init_std, h)
-            h = nn.gelu(h, approximate=True)
-        return _residual(cfg, x, adapted(cfg.d_model, "down_proj", resid_std, h))
+        # the non-expert MLP under one scope: the products, the activation
+        # between them (no module's name is on it) and the residual add
+        with jax.named_scope(BLOCK_MLP_SCOPE):
+            if cfg.mlp == "swiglu" or self.dense_mlp:
+                # separate gate/up projections (standard llama layout): each is
+                # column-parallel under the same sharding rule, so silu(gate)*up
+                # is shard-local — a fused gate||up matrix would put ALL of gate
+                # on the first half of the tensor group and force a per-layer
+                # resharding collective
+                gate = adapted(hidden, "gate_proj", cfg.emb_init_std, h)
+                up = adapted(hidden, "up_proj", cfg.emb_init_std, h)
+                h = nn.silu(gate) * up
+            else:
+                h = adapted(hidden, "up_proj", cfg.emb_init_std, h)
+                h = nn.gelu(h, approximate=True)
+            return _residual(cfg, x, adapted(cfg.d_model, "down_proj", resid_std, h))
 
 
 class _ScanBlock(nn.Module):
@@ -612,7 +625,8 @@ class MPTModel(nn.Module):
                 x, _ = stack(cfg.first_k_dense, "dense_blocks", dense_mlp=True)(x, None)
             x, _ = stack(cfg.n_layers - cfg.first_k_dense, "blocks")(x, None)
 
-        x = _norm(cfg, "ln_f")(x)
+        with jax.named_scope(BLOCK_NORM_SCOPE):
+            x = _norm(cfg, "ln_f")(x)
         if return_hidden:
             return x
         if cfg.tie_embeddings:

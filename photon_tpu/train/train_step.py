@@ -23,6 +23,7 @@ from photon_tpu.utils.profiling import (
     DSA_INDEX_LOSS,
     DSA_PICKED_PAIRS,
     DSA_TILES_VISITED,
+    GRAD_NORM_SCOPE,
     MOE_MAX_EXPERT_LOAD,
     MOE_ROWS_HELD,
 )
@@ -336,7 +337,11 @@ def make_train_step(
     def train_step(state: TrainState, tokens: jax.Array):
         with jax.named_scope(FORWARD_BACKWARD_SCOPE):
             loss, grads, counters = forward_backward(state, tokens)
-        grad_norm = optax.global_norm(grads)
+        # the two logged norms under a scope of their own (the clip inside
+        # ``tx`` takes the same gradient norm under the optimizer's scope; XLA
+        # computes it once and keeps this one's name: PERF.md section 5)
+        with jax.named_scope(GRAD_NORM_SCOPE):
+            grad_norm = optax.global_norm(grads)
         with jax.named_scope(OPTIMIZER_SCOPE):
             updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -345,11 +350,14 @@ def make_train_step(
             if expert_rows is not None and model.cfg.moe_bias_update_speed:
                 new_params = _balance_router_bias(
                     new_params, expert_rows, model.cfg.moe_bias_update_speed)
-        new_state = TrainState(step=state.step + 1, params=new_params, opt_state=new_opt_state)
+            new_state = TrainState(
+                step=state.step + 1, params=new_params, opt_state=new_opt_state)
+        with jax.named_scope(GRAD_NORM_SCOPE):
+            param_norm = optax.global_norm(new_params)
         metrics = {
             "loss": loss,
             "grad_norm": grad_norm,
-            "param_norm": optax.global_norm(new_params),
+            "param_norm": param_norm,
             **counters,
         }
         return new_state, metrics

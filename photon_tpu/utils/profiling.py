@@ -4,20 +4,16 @@ Reference (SURVEY.md §5): Composer's Profiler with cyclic schedule + JSON
 trace handler, llm-foundry ``speed_monitor``/``runtime_estimator`` callbacks,
 and photon's manual ``time.time_ns()`` spans. TPU equivalents:
 
-- :func:`trace` — ``jax.profiler`` trace context writing TensorBoard-format
-  traces (xplane) to a directory;
-- :class:`Timer` — named wall-clock spans exported with the reference's
-  metric names;
+- the name registry: every KPI, span, event and ``jax.named_scope`` name the
+  program writes, as module constants (spans are ``telemetry.span``, traces
+  ``telemetry.ProfileController``);
 - :func:`model_flops_per_token` / :class:`SpeedMonitor` — tokens/sec and MFU
   against a configurable peak (defaults to TPU v5e bf16 peak).
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import time
-from typing import Iterator
 
 from photon_tpu.config.schema import ModelConfig
 
@@ -232,6 +228,21 @@ MAMBA_CONV_SCOPE = "mamba/conv"
 MAMBA_SCAN_SCOPE = "mamba/scan"
 #: the gate ``y * silu(z)`` and the RMSNorm over all inner channels
 MAMBA_GATE_NORM_SCOPE = "mamba/gate_norm"
+# -- every block (models/mpt.py) and the step around them
+# (train/train_step.py): ``jax.named_scope``s like the families' above, so
+# that no device time of a step is left to a bare instruction name --------
+#: the non-expert MLP: its products, the activation between them, the
+#: residual add (the leading dense block of an expert model too; the expert
+#: layer keeps ``moe/*``)
+BLOCK_MLP_SCOPE = "block/mlp"
+#: attention's projections on the non-latent branch (``wqkv`` or ``q_proj`` /
+#: ``k_proj`` / ``v_proj``, the reshapes, the rotation, ``out_proj`` and its
+#: residual add); the latent branch keeps ``mla/proj``
+ATTN_PROJ_SCOPE = "attn/proj"
+#: the block norms ``ln_1`` / ``ln_2`` and the model's final ``ln_f``
+BLOCK_NORM_SCOPE = "block/norm"
+#: the gradient's global norm and the logged norm of the new weights
+GRAD_NORM_SCOPE = "train_step/grad_norm"
 
 # -- transport-leg span names (federation/tcp.py; spans only, never KPIs) --
 TCP_SEND_SPAN = "tcp/send"
@@ -241,6 +252,9 @@ TCP_RECV_SPAN = "tcp/recv"
 TRANSPORT_PUT_SPAN = "transport/put"
 TRANSPORT_GET_SPAN = "transport/get"
 TRANSPORT_FREE_SPAN = "transport/free"
+#: a node letting the previous broadcast go once the new one is read (on the
+#: shm plane the last reference un-maps the old segment); a leaf, attr mode
+TRANSPORT_UNMAP_SPAN = "transport/unmap"
 
 # -- serving plane (photon_tpu/serve, ISSUE 5) ----------------------------
 # KPIs the continuous batcher records into its own History (exported via
@@ -687,38 +701,6 @@ def dump_memory_profile(save_dir: str, tag: str = "oom") -> str | None:
         return str(path)
     except Exception:  # noqa: BLE001 — diagnostics must never mask the OOM
         return None
-
-
-@contextlib.contextmanager
-def trace(log_dir: str, enabled: bool = True) -> Iterator[None]:
-    """jax.profiler trace context (reference: Composer Profiler,
-    ``trainer_utils.py:1456-1482``)."""
-    if not enabled:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-class Timer:
-    """Named wall-clock spans → metrics dict (reference: manual ns spans,
-    e.g. ``client/fit_time`` ``llm_client_functions.py:205-209``)."""
-
-    def __init__(self) -> None:
-        self.metrics: dict[str, float] = {}
-
-    @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[None]:
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.metrics[name] = self.metrics.get(name, 0.0) + time.monotonic() - t0
 
 
 def _sparse_attention_flops_per_token(cfg: ModelConfig) -> float:

@@ -86,6 +86,7 @@ SPAN_TABLE = [
     (P.TRANSPORT_GET_SPAN, {P.NODE_SET_BROADCAST_SPAN, P.FIT_ROUND_TIME, None},
      {"mode", "copied_nbytes", "wire_nbytes"}),
     (P.TRANSPORT_FREE_SPAN, {P.BROADCAST_PRE_TIME, P.FIT_ROUND_TIME, None}, {"mode"}),
+    (P.TRANSPORT_UNMAP_SPAN, {P.NODE_SET_BROADCAST_SPAN, P.CLIENT_RESOLVE_PARAMS_SPAN}, {"mode"}),
     (P.NODE_SET_BROADCAST_SPAN, {P.BROADCAST_PRE_TIME}, {"round", "node"}),
     (P.TRAINER_SET_PARAMETERS_SPAN, {P.CLIENT_FIT_SPAN}, {"nbytes"}),
     (P.TRAINER_GET_PARAMETERS_SPAN, {P.CLIENT_FIT_SPAN}, set()),

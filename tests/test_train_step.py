@@ -7,13 +7,19 @@ import optax
 import pytest
 
 from photon_tpu.config.schema import ModelConfig, OptimizerConfig, SchedulerConfig
-from photon_tpu.models.mpt import MPTModel, init_params
+from photon_tpu.models.mpt import MLA_PROJ_SCOPE, MPTModel, init_params
 from photon_tpu.optim import build_optimizer, build_schedule
 from photon_tpu.train import init_train_state, make_eval_step, make_train_step
 from photon_tpu.train.train_step import (
     LOSS_HEAD_SCOPE,
     _chunked_ce_sum,
     _output_embedding,
+)
+from photon_tpu.utils.profiling import (
+    ATTN_PROJ_SCOPE,
+    BLOCK_MLP_SCOPE,
+    BLOCK_NORM_SCOPE,
+    GRAD_NORM_SCOPE,
 )
 
 TINY = ModelConfig(
@@ -286,3 +292,129 @@ def test_head_operations_all_carry_the_scope():
     outside = sorted({n for n in names if LOSS_HEAD_SCOPE not in n})
     assert not outside, outside
     assert any(f"transpose(jvp({LOSS_HEAD_SCOPE}))" in n for n in names)  # the backward's
+
+
+# -- every operation of a step under a name (ISSUE 37) ----------------------
+# The four scopes of ``models/mpt.py`` and ``train_step.py`` beside the ones
+# that were there, read as the trace's readers read them: from the compiled
+# step's ``op_name``s.
+_GQA = dict(d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, max_seq_len=32,
+            vocab_size=64, attn_impl="xla", compute_dtype="float32", rope=True,
+            learned_pos_emb=False, norm="rmsnorm", mlp="swiglu", remat=True)
+#: what the parent's ``init_params`` gave each toy: a scope renames no module
+_BLOCK_PATHS = {
+    "dense": {"blocks": ("down_proj/kernel", "ln_1/scale", "ln_2/scale",
+                         "out_proj/kernel", "up_proj/kernel", "wqkv/kernel"),
+              "": ("ln_f/scale", "wpe", "wte/embedding")},
+    "gqa_swiglu": {"blocks": ("down_proj/kernel", "gate_proj/kernel", "k_proj/kernel",
+                              "ln_1/scale", "ln_2/scale", "out_proj/kernel",
+                              "q_proj/kernel", "up_proj/kernel", "v_proj/kernel"),
+                   "": ("ln_f/scale", "wte/embedding")},
+    "dense_then_experts": {
+        "blocks": ("kv_a_norm/scale", "kv_a_proj/kernel", "kv_b_proj/kernel",
+                   "ln_1/scale", "ln_2/scale", "moe_down", "moe_gate", "moe_up",
+                   "out_proj/kernel", "q_a_norm/scale", "q_a_proj/kernel",
+                   "q_b_proj/kernel", "router", "router_bias",
+                   "shared_down_proj/kernel", "shared_gate_proj/kernel",
+                   "shared_up_proj/kernel"),
+        "dense_blocks": ("down_proj/kernel", "gate_proj/kernel", "kv_a_norm/scale",
+                         "kv_a_proj/kernel", "kv_b_proj/kernel", "ln_1/scale",
+                         "ln_2/scale", "out_proj/kernel", "q_a_norm/scale",
+                         "q_a_proj/kernel", "q_b_proj/kernel", "up_proj/kernel"),
+        "": ("lm_head/kernel", "ln_f/scale", "wte/embedding")},
+}
+#: the new scopes a toy's step must hold (a latent model has no ``attn/proj``)
+_SCOPES_OF = {
+    "dense": (BLOCK_MLP_SCOPE, ATTN_PROJ_SCOPE, BLOCK_NORM_SCOPE, GRAD_NORM_SCOPE),
+    "gqa_swiglu": (BLOCK_MLP_SCOPE, ATTN_PROJ_SCOPE, BLOCK_NORM_SCOPE, GRAD_NORM_SCOPE),
+    "dense_then_experts": (BLOCK_MLP_SCOPE, BLOCK_NORM_SCOPE, GRAD_NORM_SCOPE),
+}
+
+
+def _scoped_model_cfg(kind: str) -> ModelConfig:
+    if kind == "dense":
+        return TINY
+    if kind == "gqa_swiglu":
+        return ModelConfig(**_GQA)
+    from tests.test_glm_moe_lite import tiny_cfg  # 1 dense + 2 expert layers, latent attention
+
+    return tiny_cfg().model
+
+
+@pytest.fixture(scope="module", params=sorted(_SCOPES_OF))
+def scoped_step(request):
+    """``(kind, the parameter paths, the op_names of the compiled step)`` of a
+    toy model's whole train step."""
+    cfg = _scoped_model_cfg(request.param)
+    tx, _ = build_optimizer(OptimizerConfig(name="adopt", lr=1e-3),
+                            SchedulerConfig(t_warmup=2, t_max=50))
+    model = MPTModel(cfg)
+    params = init_params(cfg, seed=0)
+    state = init_train_state(model, tx, params)
+    tokens = jnp.zeros((4, cfg.max_seq_len), jnp.int32)
+    compiled = jax.jit(make_train_step(model, tx, loss_chunk_tokens=16)).lower(
+        state, tokens).compile().as_text()
+    paths = sorted("/".join(str(getattr(k, "key", k)) for k in path)
+                   for path, _ in jax.tree_util.tree_leaves_with_path(params))
+    return request.param, paths, re.findall(r'op_name="([^"]*)"', compiled)
+
+
+def test_every_operation_of_the_step_carries_a_train_step_scope(scoped_step):
+    """Nothing of the step is outside its stages, so ``step_unscoped_ms_train``
+    reads what a later change drops there. Two kinds of name are not the
+    step's own and are left out: a reducer's body (no ``jit(`` in front, no
+    ``/`` or the bare tail of its caller's name), and a loop-invariant table
+    jax hoists out of the layer scan (the rotation's angles, the router's
+    ``iota``s), which keeps only the block's own names: those must sit under a
+    scope the trace's partition (``benchmark/trace/step_parts.py``) has a part
+    for. The XLA attention's causal mask is such a table too; the kernel the
+    chip runs has none."""
+    _, _, names = scoped_step
+    own = [n for n in names if n.startswith("jit(train_step)/")]
+    assert len(own) > 200
+    hoisted = [n for n in own if re.match(r"jit\(train_step\)/(dense_)?blocks/block/", n)]
+    outside = sorted({n for n in own if "train_step/" not in n} - set(hoisted))
+    assert not outside, outside
+    from benchmark.trace import step_parts
+
+    lost = sorted({n for n in hoisted if "multihead_attention" not in n
+                   and step_parts.part_of([n]) in step_parts.REMAINDERS})
+    assert not lost, lost
+
+
+@pytest.mark.parametrize("scope", [BLOCK_MLP_SCOPE, ATTN_PROJ_SCOPE, BLOCK_NORM_SCOPE,
+                                   GRAD_NORM_SCOPE])
+def test_new_scope_is_on_the_forward_and_on_the_backward(scoped_step, scope):
+    """Forward, pull-back and recomputation alike carry a block's scope; the
+    norms of ``train_step/grad_norm`` are outside the differentiated function
+    and have no pull-back."""
+    kind, _, names = scoped_step
+    hits = [n for n in names if re.search(rf"\b{scope}\b", n)]
+    if scope not in _SCOPES_OF[kind]:
+        assert not hits  # the latent branch keeps ``mla/proj``
+        return
+    assert any("transpose(" not in n for n in hits), scope
+    if scope != GRAD_NORM_SCOPE:
+        assert any("transpose(jvp(" in n for n in hits), scope
+    if scope == BLOCK_NORM_SCOPE:  # the blocks' two and the model's last
+        for norm in ("ln_1", "ln_2", "ln_f"):
+            assert any(f"{scope}/{norm}/" in n for n in hits), norm
+    if scope == BLOCK_MLP_SCOPE:  # the activation has no module's name, only the scope's
+        assert any(re.search(rf"{scope}/(jit\(silu\)|tanh|mul)", n) for n in hits)
+
+
+def test_scopes_of_one_partition_never_share_an_operation(scoped_step):
+    _, _, names = scoped_step
+    both = [n for n in names
+            if (ATTN_PROJ_SCOPE in n and MLA_PROJ_SCOPE in n)
+            or (BLOCK_MLP_SCOPE in n and "moe/" in n)
+            or sum(s in n for s in (BLOCK_MLP_SCOPE, ATTN_PROJ_SCOPE, BLOCK_NORM_SCOPE)) > 1]
+    assert not both, both
+
+
+def test_a_scope_renames_no_parameter(scoped_step):
+    """Checkpoints and the HF maps find every leaf where the parent put it."""
+    kind, paths, _ = scoped_step
+    want = sorted(f"{stack}/block/{leaf}" if stack else leaf
+                  for stack, leaves in _BLOCK_PATHS[kind].items() for leaf in leaves)
+    assert paths == want
