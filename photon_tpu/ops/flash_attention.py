@@ -17,10 +17,29 @@ Design notes (TPU-first):
   ``block_k`` only to pin a tile (tests, the ladder).
 - The grid is dense over the causal square, so a tile strictly above the
   diagonal still costs its grid step, but nothing else: its body is
-  predicated off (``pl.when(live)``) and its k/v (forward, dq) or
+  predicated off (``_run_tile``) and its k/v (forward, dq) or
   q/dO/lse/delta (dk/dv) block index is clamped to the nearest live one
   (``_kv_block``, ``_q_block``), so the pipeline sees a repeated index and
   issues no copy.
+- A live tile multiplies no block of scores that lies wholly above the
+  diagonal. The tiles are large because a grid step and an online-softmax
+  update cost more than a small tile's products, so on the diagonal half of
+  a tile is dead; but the whole tile is in VMEM and which part is dead is
+  static. Where the geometry is (``strip_rows``: causal, a square tile, an
+  offset of whole tiles: every training shape), a tile under the diagonal
+  runs whole with no mask, and the tile on it runs as statically unrolled
+  strips of ``STRIP_ROWS`` queries (forward, dq) or keys (dk/dv), each
+  against the live extent of the other axis and masked in its last square
+  block alone. A forward strip is one plain softmax over its extent, merged
+  into the scratch once; where that tile holds the whole sequence
+  (``_lone_tile``: seq 2,048) nothing is carried from step to step, so the
+  forward takes no scratch and each strip writes its output rows and
+  log-sum-exp (the running max / denominator's column reads and broadcast
+  stores were a quarter of that launch's schedule). Anything else (non-causal, a pinned tile that is
+  not square, an offset that is no whole number of tiles) runs every live
+  tile whole under the mask. ``STRIP_ROWS`` is read off the same ladder as
+  the tiles; ``TilePlan.attrs()`` tells ``flash_executed_share``, the pairs
+  multiplied over the pairs visible.
 - Scores accumulate in fp32 on the MXU (``preferred_element_type``); inputs
   are bf16. The log-sum-exp is saved for the backward pass.
 - Blockwise structure means a ring/context-parallel extension only has to
@@ -74,12 +93,19 @@ VMEM_SLACK = 2**20  # the compiler's own scratch, and rounding to its tiles
 SCORE_TEMPS = 1.5  # [block_q, block_k] fp32 temporaries alive at once
 # The largest (block_q, block_k) at which each launch was still no slower than
 # at the next smaller tile, on the ladder ``scripts/flash_tile_ladder.py`` runs
-# on the chip (v5e, seq 2,048, d_head 64 and 128, PERF.md PR 28). The forward
-# is fastest with the whole 2,048 square in one step (its cost is per k step:
-# the running max / denominator stores and the accumulator rescale), dq peaks
-# at 1,024, and dk/dv, whose fp32-operand products grow with the masked part
-# of a diagonal tile, at 512.
-TILE_LADDER_TOP = {"fwd": (2048, 2048), "dq": (1024, 1024), "dkv": (512, 512)}
+# on the chip (v5e; PERF.md PR 28, read again with the strip bodies in PR 39 at
+# seq 2,048 / d_head 64 and seq 4,096 / d_head 256). With the dead part of a
+# diagonal tile gone every launch is fastest with the whole 2,048 square in
+# one step at the width it was read at (``TILE_LADDER_WIDTH``: the forward up
+# to 256 lanes, dq and dk/dv at 128); a wider head gets proportionally fewer
+# rows (at 256 lanes dq and dk/dv peak at 1,024 and 2,048 does not fit).
+TILE_LADDER_TOP = {"fwd": (2048, 2048), "dq": (2048, 2048), "dkv": (2048, 2048)}
+TILE_LADDER_WIDTH = {"fwd": 256, "dq": 128, "dkv": 128}
+# Rows of a strip: inside a tile that straddles the diagonal a launch runs
+# strips of this many queries (forward, dq) or keys (dk/dv), each against the
+# live extent of the other axis only (``strip_rows``, ``_run_tile``). 0 keeps
+# the whole-tile masked body. Read off the same ladder (``--sub``).
+STRIP_ROWS = {"fwd": 256, "dq": 256, "dkv": 256}
 
 
 def pallas_supported(x: jax.Array | None) -> bool:
@@ -104,29 +130,36 @@ def pallas_supported(x: jax.Array | None) -> bool:
     return platform == "tpu"
 
 
-def _tile_ids(q_blk: int, k_blk: int, block_q: int, block_k: int, offset: int):
-    """Global (query, key) position iotas for the (q_blk, k_blk) tile.
+def _pos_diff(q0, k0, rows: int, cols: int) -> jax.Array:
+    """``q_pos - k_pos`` as int32 ``[rows, cols]`` for a block of scores whose
+    first query sits at global position ``q0`` and whose first key at ``k0``.
 
-    ``offset = s_k - s_q`` aligns query positions to the end of the key
-    sequence (matches ``xla_attention``; matters when s_q != s_k).
+    ``q0`` carries ``offset = s_k - s_q``, which aligns query positions to the
+    end of the key sequence (matches ``xla_attention``; matters when
+    s_q != s_k). A pair is visible iff the difference is >= 0, and ALiBi's
+    bias is ``-slope`` times it.
     """
-    q_ids = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_blk * block_q + offset
-    k_ids = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + k_blk * block_k
-    return q_ids, k_ids
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)) + (q0 - k0)
 
 
-def _causal_mask(q_blk: int, k_blk: int, block_q: int, block_k: int, offset: int) -> jax.Array:
-    """Boolean [block_q, block_k] mask for the (q_blk, k_blk) tile."""
-    q_ids, k_ids = _tile_ids(q_blk, k_blk, block_q, block_k, offset)
-    return q_ids >= k_ids
-
-
-def _alibi_bias(slope, q_blk, k_blk, block_q, block_k, offset) -> jax.Array:
-    """Per-head ALiBi bias ``-slope * (q_pos - k_pos)`` for one tile
+def _scores(q, k, q0, k0, *, scale, slope, masked: bool) -> jax.Array:
+    """fp32 scores ``[rows, cols]`` of one block: ``q @ k^T * scale``, plus the
+    per-head ALiBi bias ``-slope * (q_pos - k_pos)`` when ``slope`` is given
     (reference: llm-foundry MPT ``attn_config.alibi``; oracle:
-    ``ops/attention.py:xla_attention``)."""
-    q_ids, k_ids = _tile_ids(q_blk, k_blk, block_q, block_k, offset)
-    return -slope * (q_ids - k_ids).astype(jnp.float32)
+    ``ops/attention.py:xla_attention``), with pairs above the diagonal at
+    ``NEG_INF`` when ``masked``. A block that lies wholly under the diagonal
+    is not ``masked`` and pays for no select."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+    if slope is None and not masked:
+        return s
+    diff = _pos_diff(q0, k0, q.shape[0], k.shape[0])
+    if slope is not None:
+        s = s + -slope * diff.astype(jnp.float32)
+    if masked:
+        s = jnp.where(diff >= 0, s, NEG_INF)
+    return s
 
 
 def _bh_slopes(h_slopes: jax.Array, bh: int) -> jax.Array:
@@ -168,6 +201,80 @@ def _q_block(i, j, *, causal, block_q, block_k, offset, n_q):
     return jnp.maximum(i, jnp.minimum(first_live, n_q - 1))
 
 
+def strip_rows(launch: str, block_q: int, block_k: int, *, causal: bool, offset) -> int:
+    """Rows of a strip for ``launch`` at this tile, or 0 where a live tile
+    keeps the whole-tile masked body. The strips need a diagonal tile's
+    geometry to be static: a causal call, a square tile, and ``offset`` a
+    known whole number of tiles, so that every live tile lies wholly under the
+    diagonal or has it as its own diagonal. A tile the strip does not divide
+    is one strip."""
+    sub = STRIP_ROWS[launch]
+    if not (causal and sub and block_q == block_k
+            and isinstance(offset, int) and offset % block_q == 0):
+        return 0
+    return block_q if block_q % sub else sub
+
+
+def _lone_tile(sub: int, s_q: int, s_k: int, block: int, offset) -> bool:
+    """Whether one tile on the diagonal is the forward's whole sweep (the
+    strips engage and the tile holds both sequences): nothing is carried from
+    step to step then, so the launch takes no scratch and each strip writes the
+    outputs. (dq and dk/dv add into a scratch that the compiler already
+    forwards when there is one step: the same program either way.)"""
+    return bool(sub) and s_q == s_k == block and offset == 0
+
+
+def _strips(block: int, sub: int, by: str) -> list[tuple[slice, list[tuple[slice, bool]]]]:
+    """A diagonal tile as strips: ``(strip, [(extent, masked), ...])``, all in
+    the tile's own coordinates. ``by == "q"`` (forward, dq): query strip ``r``
+    against keys ``[0, r * sub)``, wholly visible, and its own ``sub x sub``
+    block on the diagonal, the only one masked. ``by == "k"`` (dk/dv): key
+    strip ``c`` against its diagonal block of queries and queries
+    ``[(c + 1) * sub, block)``."""
+    strips = []
+    for lo in range(0, block, sub):
+        own = (slice(lo, lo + sub), True)
+        if by == "q":
+            rest = [(slice(0, lo), False)] if lo else []
+            strips.append((own[0], rest + [own]))
+        else:
+            rest = [(slice(lo + sub, block), False)] if lo + sub < block else []
+            strips.append((own[0], [own] + rest))
+    return strips
+
+
+def _run_tile(compute, by: str, q_blk, k_blk, block_q: int, block_k: int, *,
+              causal: bool, offset, sub: int) -> None:
+    """Runs ``compute(strips, guard)`` in the form grid step ``(q_blk, k_blk)``
+    needs. The grid is dense, so a dead tile is predicated off, not skipped
+    (it costs its grid step and no copy: ``_kv_block`` / ``_q_block`` repeat a
+    live index). ``sub`` (``strip_rows``) says the geometry is static: a tile
+    under the diagonal then runs whole and unmasked, the tile on it as strips
+    against their live extents. Otherwise every live tile runs whole under the
+    mask, and ``guard`` tells the body that a row may have no visible key."""
+    rows, cols = (block_q, block_k) if by == "q" else (block_k, block_q)
+
+    def whole(masked):
+        return [(slice(0, rows), [(slice(0, cols), masked)])]
+
+    if not causal:
+        compute(whole(False), guard=False)
+    elif not sub:
+        @pl.when(k_blk * block_k <= q_blk * block_q + (block_q - 1) + offset)
+        def _():
+            compute(whole(True), guard=True)
+    else:
+        diagonal = q_blk + offset // block_q  # the k block that straddles
+
+        @pl.when(k_blk < diagonal)
+        def _():
+            compute(whole(False), guard=False)
+
+        @pl.when(k_blk == diagonal)
+        def _():
+            compute(_strips(block_q, sub, by), guard=False)
+
+
 def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize: int) -> int:
     """VMEM one launch (``fwd``, ``dq`` or ``dkv``) needs at a tile, from the
     kernel's own buffers: every BlockSpec'd operand and result twice (the
@@ -179,7 +286,14 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
     off the smallest ``vmem_limit_bytes`` it accepts per tile (PERF.md,
     PR 28: over 90 readings of launch, tile, width and dtype this estimate is
     1.05 to 2.1 times that, never under it;
-    ``tests/test_tpu_compile.py::test_flash_vmem_estimate_is_enough``)."""
+    ``tests/test_tpu_compile.py::test_flash_vmem_estimate_is_enough``).
+
+    The estimate is the whole-tile body's, which every launch still holds
+    (a tile under the diagonal runs it). A strip's temporaries are smaller
+    and come one strip after another: its scores are ``[sub, extent]``, at
+    most a ``sub / block`` part of the tile's, its ``pv`` / ``dq`` parts
+    ``[sub, d]``; the upcasts are made once a tile and sliced, as large as
+    the whole-tile body's."""
     q_rows = block_q * d * itemsize  # one q-shaped block: q, o, do, dq
     k_rows = block_k * d * itemsize  # one k-shaped block: k, v, dk, dv
     row_stats = SUBLANE * block_q * 4  # one lse / delta block
@@ -218,6 +332,7 @@ class LaunchTiles(NamedTuple):
     vmem_bytes: int  # launch_vmem_bytes at this tile
     live_tiles: int  # tiles whose body runs, per (batch, q head)
     grid_tiles: int  # grid steps paid, per (batch, q head)
+    executed_share: float  # score pairs the bodies multiply over visible pairs
 
 
 class TilePlan(NamedTuple):
@@ -236,6 +351,8 @@ class TilePlan(NamedTuple):
                 f"{n}={t.block_q}x{t.block_k}" for n, t in zip(self._fields, self)),
             "flash_live_tiles": " ".join(
                 f"{n}={t.live_tiles}/{t.grid_tiles}" for n, t in zip(self._fields, self)),
+            "flash_executed_share": " ".join(
+                f"{n}={t.executed_share:.3f}" for n, t in zip(self._fields, self)),
         }
 
 
@@ -253,8 +370,9 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
     """``(block_q, block_k)`` of the forward, dq and dk/dv launches, from the
     shapes alone: for each launch the largest tile (by area) that divides
     both sequences, stays inside ``vmem_budget`` by :func:`launch_vmem_bytes`,
-    and does not pass ``TILE_LADDER_TOP``, the size past which the ladder on the chip
-    stopped paying (PERF.md, PR 28). A sequence no candidate fits gets its
+    and does not pass ``TILE_LADDER_TOP`` (scaled down for a head wider than
+    ``TILE_LADDER_WIDTH``), the size past which the ladder on the chip stopped
+    paying (PERF.md, PRs 28 and 39). A sequence no candidate fits gets its
     smallest candidate: there is always an answer, and it always divides.
 
     An explicit ``block_q`` / ``block_k`` pins that side for all three
@@ -268,7 +386,8 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
             f"seq lengths ({s_q},{s_k}) must divide blocks ({block_q},{block_k})")
     plan = []
     for launch in TilePlan._fields:
-        top_q, top_k = TILE_LADDER_TOP[launch]
+        narrow = min(TILE_LADDER_WIDTH[launch], d_pad)  # a wider head: fewer rows
+        top_q, top_k = (top * narrow // d_pad for top in TILE_LADDER_TOP[launch])
         sized = [(launch_vmem_bytes(launch, bq, bk, d_pad, itemsize), bq, bk)
                  for bq in qs for bk in ks]
         # largest area first; of two equal areas the longer k block (fewer
@@ -283,7 +402,9 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
             need, bq, bk = min(sized)
         live, grid = live_tiles(s_q, s_k, bq, bk, causal=causal, offset=offset)
         group = n_kv_group if launch == "dkv" else 1
-        plan.append(LaunchTiles(bq, bk, need, live * group, grid * group))
+        share = (executed_pairs(launch, s_q, s_k, bq, bk, causal=causal, offset=offset)
+                 / max(visible_pairs(s_q, s_k, causal=causal, offset=offset), 1))
+        plan.append(LaunchTiles(bq, bk, need, live * group, grid * group, share))
     return TilePlan(*plan)
 
 
@@ -301,20 +422,99 @@ def live_tiles(s_q: int, s_k: int, block_q: int, block_k: int, *,
     return live, n_q * n_k
 
 
+def visible_pairs(s_q: int, s_k: int, *, causal: bool = True,
+                  offset: int | None = None) -> int:
+    """(query, key) pairs of one (batch, head) that attention has to score:
+    query ``r`` sees keys ``[0, r + offset]``."""
+    if not causal:
+        return s_q * s_k
+    offset = s_k - s_q if offset is None else offset
+    first = min(max(-offset, 0), s_q)  # rows before it see nothing
+    full = min(max(s_k - offset - 1, first), s_q)  # rows from it on see every key
+    n = full - first  # rows first .. full - 1 see first + offset + 1, ... keys
+    return n * (first + offset + 1) + n * (n - 1) // 2 + (s_q - full) * s_k
+
+
+def executed_pairs(launch: str, s_q: int, s_k: int, block_q: int, block_k: int, *,
+                   causal: bool = True, offset: int | None = None) -> int:
+    """Score pairs one (batch, head) of ``launch`` multiplies at this tile.
+    Closed form of ``_run_tile``: a whole tile for each live one, or, where
+    the strips engage, whole tiles under the diagonal and on it each strip's
+    live extent."""
+    offset = s_k - s_q if offset is None else offset
+    sub = strip_rows(launch, block_q, block_k, causal=causal, offset=offset)
+    if not sub:
+        live, _ = live_tiles(s_q, s_k, block_q, block_k, causal=causal, offset=offset)
+        return live * block_q * block_k
+    n_q, n_k, n = s_q // block_q, s_k // block_k, block_q // sub
+    diagonals = [i + offset // block_q for i in range(n_q)]
+    under = sum(min(max(j, 0), n_k) for j in diagonals)
+    on = sum(0 <= j < n_k for j in diagonals)
+    return under * block_q * block_k + on * sub * sub * n * (n + 1) // 2
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi):
-    if use_alibi:
-        slopes_ref, o_ref, lse_ref, m_s, l_s, acc_s = rest
-    else:
-        slopes_ref = None
-        o_ref, lse_ref, m_s, l_s, acc_s = rest
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, lone):
+    slopes_ref = rest[0] if use_alibi else None
+    o_ref, lse_ref, *scratch = rest[1:] if use_alibi else rest
     q_blk = pl.program_id(1)
     k_blk = pl.program_id(2)
     n_k = pl.num_programs(2)
+
+    def _attend(rows, parts, guard, carried):
+        """``(m, l, acc)`` of the strip ``rows`` after all its key ``parts`` at
+        once: a plain softmax over the strip's extent, merged, where state is
+        ``carried``, with what earlier tiles of the row left in the scratch."""
+        slope = slopes_ref[0, 0, 0] if use_alibi else None
+        q = q_ref[0, rows, :]
+        q0 = q_blk * block_q + offset + rows.start
+        scores = [
+            _scores(q, k_ref[0, ks, :], q0, k_blk * block_k + ks.start,
+                    scale=scale, slope=slope, masked=masked)
+            for ks, masked in parts]
+        m = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in scores])  # [rows, 1]
+        l = pv = None
+        if carried:
+            m_s, l_s, acc_s = scratch
+            m_prev = m_s[rows, 0][:, None]
+            m = jnp.maximum(m_prev, m)
+            alpha = jnp.exp(m_prev - m)  # rescale of old state
+            l = alpha * l_s[rows, 0][:, None]
+        for s, (ks, _) in zip(scores, parts):
+            p = jnp.exp(s - m)
+            if guard:
+                # fully-masked rows keep m == NEG_INF; exp(s - m) would be
+                # exp(0)=1 there, so force p to 0 (their output and l stay 0)
+                p = jnp.where(m > NEG_INF / 2, p, 0.0)
+            l_part = jnp.sum(p, axis=-1, keepdims=True)
+            pv_part = jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, ks, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [rows, d]
+            l = l_part if l is None else l + l_part
+            pv = pv_part if pv is None else pv + pv_part
+        acc = acc_s[rows, :] * alpha + pv if carried else pv
+        return m, l, acc
+
+    def _emit(rows, m, l, acc):
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
+        lse = m[:, 0] + jnp.log(l_safe[:, 0])  # [rows]
+        lse_ref[0, :, rows] = jnp.broadcast_to(lse[None, :], (SUBLANE, lse.shape[0]))
+
+    if lone:
+        # the tile is the whole sequence: nothing is carried from step to
+        # step, so each strip goes straight to the outputs
+        for rows, parts in _strips(block_q, sub, "q"):
+            _emit(rows, *_attend(rows, parts, guard=False, carried=False))
+        return
+
+    m_s, l_s, acc_s = scratch
 
     @pl.when(k_blk == 0)
     def _init():
@@ -322,53 +522,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, off
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    # for causal attention, tiles strictly above the diagonal are dead
-    live = (not causal) or (k_blk * block_k <= q_blk * block_q + (block_q - 1) + offset)
+    def _compute(strips, guard):
+        for rows, parts in strips:
+            m, l, acc = _attend(rows, parts, guard, carried=True)
+            acc_s[rows, :] = acc
+            m_s[rows, :] = jnp.broadcast_to(m, (m.shape[0], LANE))
+            l_s[rows, :] = jnp.broadcast_to(l, (l.shape[0], LANE))
 
-    def _compute():
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        s = s * scale
-        if use_alibi:
-            s = s + _alibi_bias(slopes_ref[0, 0, 0], q_blk, k_blk, block_q, block_k, offset)
-        if causal:
-            s = jnp.where(_causal_mask(q_blk, k_blk, block_q, block_k, offset), s, NEG_INF)
-
-        m_prev = m_s[:, 0][:, None]  # [block_q, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # fully-masked rows keep m == NEG_INF; exp(s - m) would be exp(0)=1
-        # there, so force p to 0 (their output stays 0, l stays 0)
-        p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)  # [block_q, block_k]
-        alpha = jnp.exp(m_prev - m_new)  # rescale of old state
-        l_new = alpha * l_s[:, 0][:, None] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, d]
-        acc_s[:] = acc_s[:] * alpha + pv
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
-
-    if causal:
-        # the grid is dense, so a dead tile is predicated off, not skipped; it
-        # costs its grid step and no copy (_kv_block repeats a live index)
-        @pl.when(live)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _run_tile(_compute, "q", q_blk, k_blk, block_q, block_k, causal=causal,
+              offset=offset, sub=sub)
 
     @pl.when(k_blk == n_k - 1)
     def _finalize():
-        l = l_s[:, 0][:, None]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
-        lse = m_s[:, 0] + jnp.log(l_safe[:, 0])  # [block_q]
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], (SUBLANE, lse.shape[0]))
+        _emit(slice(0, block_q), m_s[:, 0][:, None], l_s[:, 0][:, None], acc_s[:])
 
 
 def _kv_row(h_q: int, h_kv: int):
@@ -403,9 +569,11 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
     offset = s_k - s_q if offset is None else offset
     kj = functools.partial(_kv_block, causal=causal, block_q=block_q,
                            block_k=block_k, offset=offset, n_k=n_k)
+    sub = strip_rows("fwd", block_q, block_k, causal=causal, offset=offset)
+    lone = _lone_tile(sub, s_q, s_k, block_q, offset)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, causal=causal,
-        offset=offset, use_alibi=slopes is not None,
+        offset=offset, use_alibi=slopes is not None, sub=sub, lone=lone,
     )
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -430,7 +598,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if lone else [
             pltpu.VMEM((block_q, LANE), jnp.float32),  # running max
             pltpu.VMEM((block_q, LANE), jnp.float32),  # running denom
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
@@ -449,7 +617,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub):
     if use_alibi:
         slopes_ref, dq_ref, dq_s = rest
     else:
@@ -463,42 +631,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    live = (not causal) or (k_blk * block_k <= q_blk * block_q + (block_q - 1) + offset)
+    def _compute(strips, guard):
+        slope = slopes_ref[0, 0, 0] if use_alibi else None
+        v32 = v_ref[0].astype(jnp.float32)
+        for rows, parts in strips:
+            q = q_ref[0, rows, :]
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            lse = lse_ref[0, 0, rows][:, None]
+            delta = delta_ref[0, 0, rows][:, None]
+            q0 = q_blk * block_q + offset + rows.start
+            dq = None
+            for ks, masked in parts:
+                k = k_ref[0, ks, :]
+                s = _scores(q, k, q0, k_blk * block_k + ks.start,
+                            scale=scale, slope=slope, masked=masked)
+                p = jnp.exp(s - lse)  # [rows, cols]
+                if guard:
+                    # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
+                    p = jnp.where(lse > NEG_INF / 2, p, 0.0)
+                dp = jax.lax.dot_general(
+                    do, v32[ks], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                ds = p * (dp - delta) * scale
+                part = jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dq = part if dq is None else dq + part
+            dq_s[rows, :] += dq
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        if use_alibi:
-            s = s + _alibi_bias(slopes_ref[0, 0, 0], q_blk, k_blk, block_q, block_k, offset)
-        if causal:
-            s = jnp.where(_causal_mask(q_blk, k_blk, block_q, block_k, offset), s, NEG_INF)
-        lse = lse_ref[0, 0][:, None]
-        # guard fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
-        p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)  # [block_q, block_k]
-        do = do_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-        dq_s[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    if causal:
-        @pl.when(live)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _run_tile(_compute, "q", q_blk, k_blk, block_q, block_k, causal=causal,
+              offset=offset, sub=sub)
 
     @pl.when(k_blk == n_k - 1)
     def _finalize():
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, n_q):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, n_q, sub):
     """Inner grid dim sweeps ``group * n_q`` steps: for grouped-query
     attention every kv row accumulates dk/dv over ALL q heads of its group
     (t // n_q picks the group member, t % n_q the q block); MHA is the
@@ -518,40 +688,43 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    live = (not causal) or (k_blk * block_k <= q_blk * block_q + (block_q - 1) + offset)
+    def _compute(strips, guard):
+        slope = slopes_ref[0, 0, 0] if use_alibi else None
+        # made once a tile and sliced: a key strip's queries overlap the next's
+        q32 = q_ref[0].astype(jnp.float32)
+        do32 = do_ref[0].astype(jnp.float32)
+        lse_col = lse_ref[0, 0][:, None]
+        delta_col = delta_ref[0, 0][:, None]
+        for cols, parts in strips:
+            k = k_ref[0, cols, :]
+            v = v_ref[0, cols, :].astype(jnp.float32)
+            k0 = k_blk * block_k + cols.start
+            dk = dv = None
+            for qs, masked in parts:
+                s = _scores(q_ref[0, qs, :], k, q_blk * block_q + offset + qs.start, k0,
+                            scale=scale, slope=slope, masked=masked)
+                lse = lse_col[qs]
+                p = jnp.exp(s - lse)  # [rows, cols]
+                if guard:
+                    # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
+                    p = jnp.where(lse > NEG_INF / 2, p, 0.0)
+                do = do32[qs]
+                # dv += p^T @ do
+                part_v = jax.lax.dot_general(
+                    p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(
+                    do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                ds = p * (dp - delta_col[qs]) * scale  # [rows, cols]
+                # dk += ds^T @ q
+                part_k = jax.lax.dot_general(
+                    ds, q32[qs], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                dv = part_v if dv is None else dv + part_v
+                dk = part_k if dk is None else dk + part_k
+            dv_s[cols, :] += dv
+            dk_s[cols, :] += dk
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        if use_alibi:
-            s = s + _alibi_bias(slopes_ref[0, 0, 0], q_blk, k_blk, block_q, block_k, offset)
-        if causal:
-            s = jnp.where(_causal_mask(q_blk, k_blk, block_q, block_k, offset), s, NEG_INF)
-        lse = lse_ref[0, 0][:, None]
-        # guard fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
-        p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)  # [block_q, block_k]
-        do = do_ref[0].astype(jnp.float32)
-        # dv += p^T @ do
-        dv_s[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale  # [block_q, block_k]
-        # dk += ds^T @ q
-        dk_s[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    if causal:
-        @pl.when(live)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _run_tile(_compute, "k", q_blk, k_blk, block_q, block_k, causal=causal,
+              offset=offset, sub=sub)
 
     @pl.when(t == n_t - 1)
     def _finalize():
@@ -591,7 +764,9 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
                            block_k=block_k, offset=offset, n_k=n_k)
     launch_dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
-                          causal=causal, offset=offset, use_alibi=use_alibi),
+                          causal=causal, offset=offset, use_alibi=use_alibi,
+                          sub=strip_rows("dq", block_q, block_k, causal=causal,
+                                         offset=offset)),
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q
@@ -629,7 +804,9 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
 
     launch_dkv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
-                          causal=causal, offset=offset, use_alibi=use_alibi, n_q=n_q),
+                          causal=causal, offset=offset, use_alibi=use_alibi, n_q=n_q,
+                          sub=strip_rows("dkv", block_q, block_k, causal=causal,
+                                         offset=offset)),
         grid=(bh_k, n_k, group * n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, t: (qrow(b, t), qi(j, t), 0)),  # q

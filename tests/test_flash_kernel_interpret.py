@@ -325,3 +325,143 @@ def test_non_causal_call_keeps_the_identity_maps():
                   argnums=(0, 1, 2))(q, k, v)
     for a, ref in zip(gk, gx):
         assert _rel(a, ref) < 2e-4
+
+
+# ---------------------------------------------------------------------------
+# strips (PR 39): a tile on the diagonal multiplies each strip's live extent
+# ---------------------------------------------------------------------------
+
+# s_q, s_k, tile, STRIP_ROWS for all three launches, kv heads, ALiBi
+STRIP_CASES = {
+    "one-tile": (512, 512, 512, 128, H, False),          # the whole sequence, 4 strips
+    "beside-interior": (512, 512, 256, 128, H, False),   # 2 x 2 tiles, one under the diagonal
+    "one-strip": (512, 512, 256, 256, H, False),         # sub = tile
+    "alibi": (512, 512, 512, 128, H, True),
+    "gqa2": (512, 512, 256, 128, H // 2, False),
+    "whole-tile-offset": (256, 512, 256, 128, H, False),  # s_q != s_k, offset one tile
+}
+
+
+def _strip_case(case, monkeypatch):
+    from photon_tpu.ops import flash_attention as fa
+
+    s_q, s_k, tile, sub, h_kv, alibi = STRIP_CASES[case]
+    monkeypatch.setattr(fa, "STRIP_ROWS", dict.fromkeys(("fwd", "dq", "dkv"), sub))
+    for launch in ("fwd", "dq", "dkv"):  # the strips engage, and skip work
+        assert fa.strip_rows(launch, tile, tile, causal=True, offset=s_k - s_q) == sub
+        live, _ = fa.live_tiles(s_q, s_k, tile, tile)
+        executed = fa.executed_pairs(launch, s_q, s_k, tile, tile)
+        assert (executed < live * tile * tile) == (sub < tile)
+    ks = jax.random.split(jax.random.PRNGKey(39), 4)
+    q = jax.random.normal(ks[0], (B, s_q, H, D))
+    k = jax.random.normal(ks[1], (B, s_k, h_kv, D))
+    v = jax.random.normal(ks[2], (B, s_k, h_kv, D))
+    w = jax.random.normal(ks[3], (B, s_q, H, D))
+    rep = (lambda x: jnp.repeat(x, H // h_kv, axis=2)) if h_kv != H else (lambda x: x)
+    return q, k, v, w, rep, tile, alibi
+
+
+@pytest.mark.parametrize("case", list(STRIP_CASES))
+def test_strips_forward_and_lse(case, monkeypatch):
+    q, k, v, _, rep, tile, alibi = _strip_case(case, monkeypatch)
+    o_k = flash_attention(q, k, v, causal=True, alibi=alibi,
+                          block_q=tile, block_k=tile, interpret=True)
+    o_x = xla_attention(q, rep(k), rep(v), causal=True, alibi=alibi)
+    assert _rel(o_k, o_x) < 2e-5, case
+    if not alibi:  # the lse variant takes no bias
+        s_q, s_k = q.shape[1], k.shape[1]
+        o_l, lse_k = flash_attention_with_lse(
+            q, k, v, causal=True, q_start=s_k - s_q, k_start=0,
+            block_q=tile, block_k=tile, interpret=True)
+        _, lse_x = xla_chunk_attention(q, rep(k), rep(v), q_start=s_k - s_q,
+                                       k_start=0, causal=True)
+        assert _rel(o_l, o_x) < 2e-5, case
+        assert _rel(lse_k, lse_x) < 2e-5, case
+
+
+@pytest.mark.parametrize("case", list(STRIP_CASES))
+def test_strips_gradients(case, monkeypatch):
+    q, k, v, w, rep, tile, alibi = _strip_case(case, monkeypatch)
+    gk = jax.grad(
+        lambda q, k, v: (flash_attention(
+            q, k, v, causal=True, alibi=alibi, block_q=tile, block_k=tile,
+            interpret=True) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    gx = jax.grad(
+        lambda q, k, v: (xla_attention(
+            q, rep(k), rep(v), causal=True, alibi=alibi) * w).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    for name, a, ref in zip(("dq", "dk", "dv"), gk, gx):
+        assert a.shape == ref.shape
+        assert _rel(a, ref) < 2e-4, (case, name)
+
+
+def test_odd_offset_keeps_the_whole_tile_body():
+    """A chunk whose offset is no whole number of tiles (ring attention's
+    kernel at an uneven start) has no static diagonal inside a tile: the
+    strips stand down and the masked whole-tile body gives the oracle's
+    ``(o, lse)``."""
+    from photon_tpu.ops import flash_attention as fa
+
+    assert fa.strip_rows("fwd", BLOCK, BLOCK, causal=True, offset=192) == 0
+    assert fa.strip_rows("fwd", BLOCK, BLOCK, causal=True, offset=256) == BLOCK
+    assert fa.strip_rows("fwd", 256, 128, causal=True, offset=0) == 0  # not square
+    assert fa.strip_rows("fwd", BLOCK, BLOCK, causal=False, offset=0) == 0
+    q, k, v = _qkv(s=256, seed=5)
+    o_k, lse_k = flash_attention_with_lse(
+        q, k, v, causal=True, q_start=192, k_start=0,
+        block_q=BLOCK, block_k=BLOCK, interpret=True)
+    o_x, lse_x = xla_chunk_attention(q, k, v, q_start=192, k_start=0, causal=True)
+    assert _rel(o_k, o_x) < 2e-5
+    assert _rel(lse_k, lse_x) < 2e-5
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+def test_strip_body_is_the_whole_tile_body(alibi, monkeypatch):
+    """At one pinned tile, strips of 128 against ``STRIP_ROWS`` 0 (every live
+    tile whole under the mask, as before PR 39): the same pairs summed in
+    another order, so equal to rounding, output and all three gradients."""
+    from photon_tpu.ops import flash_attention as fa
+
+    q, k, v, w, _, _ = _clamp_case("plain")
+
+    def run(sub):
+        monkeypatch.setattr(fa, "STRIP_ROWS", dict.fromkeys(("fwd", "dq", "dkv"), sub))
+
+        def f(q, k, v):
+            return flash_attention(q, k, v, causal=True, alibi=alibi, block_q=256,
+                                   block_k=256, interpret=True)
+
+        return (f(q, k, v), *jax.grad(lambda q, k, v: (f(q, k, v) * w).sum(),
+                                      argnums=(0, 1, 2))(q, k, v))
+
+    for name, a, ref in zip(("o", "dq", "dk", "dv"), run(128), run(0)):
+        assert _rel(a, ref) < 2e-6, (alibi, name)
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+def test_lone_tile_forward_is_the_carried_one(alibi, monkeypatch):
+    """A forward whose one tile holds the whole sequence takes no scratch and
+    writes each strip's output and log-sum-exp directly; with that turned off
+    the same strips go through the running state and the finalize step. The
+    two agree to the last bit but for the order of one addition."""
+    from photon_tpu.ops import flash_attention as fa
+
+    assert fa._lone_tile(128, 512, 512, 512, 0)
+    assert not fa._lone_tile(0, 512, 512, 512, 0)       # no strips: the masked body
+    assert not fa._lone_tile(128, 1024, 1024, 512, 0)   # more tiles than one
+    assert not fa._lone_tile(128, 512, 512, 512, 512)   # a chunk wholly in the past
+    monkeypatch.setattr(fa, "STRIP_ROWS", dict.fromkeys(("fwd", "dq", "dkv"), 128))
+    q, k, v, _, _, _ = _clamp_case("plain")
+
+    def run():
+        o = flash_attention(q, k, v, causal=True, alibi=alibi, block_q=512, block_k=512,
+                            interpret=True)
+        _, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=512, block_k=512,
+                                          interpret=True)
+        return o, lse
+
+    lone = run()
+    monkeypatch.setattr(fa, "_lone_tile", lambda *a: False)
+    for name, a, ref in zip(("o", "lse"), lone, run()):
+        assert _rel(a, ref) < 1e-6, (alibi, name)
+    assert _rel(lone[0], xla_attention(q, k, v, causal=True, alibi=alibi)) < 2e-5

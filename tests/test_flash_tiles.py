@@ -16,9 +16,12 @@ from photon_tpu.ops.flash_attention import (
     VMEM_BUDGET,
     _kv_block,
     _q_block,
+    executed_pairs,
     launch_vmem_bytes,
     live_tiles,
     pick_tiles,
+    strip_rows,
+    visible_pairs,
 )
 
 LAUNCHES = ("fwd", "dq", "dkv")
@@ -65,8 +68,85 @@ def test_pick_tiles_divides_fits_and_counts(s_q, s_k, d_pad, itemsize, group):
         assert 0 < t.live_tiles <= t.grid_tiles
     assert plan.blocks == tuple((t.block_q, t.block_k) for t in plan)
     attrs = plan.attrs()
-    assert set(attrs) == {"flash_tiles", "flash_live_tiles"}
+    assert set(attrs) == {"flash_tiles", "flash_live_tiles", "flash_executed_share"}
     assert f"fwd={plan.fwd.block_q}x{plan.fwd.block_k}" in attrs["flash_tiles"]
+    assert f"fwd={plan.fwd.executed_share:.3f}" in attrs["flash_executed_share"]
+
+
+def _brute_visible(s_q, s_k, offset):
+    return sum(min(max(r + offset + 1, 0), s_k) for r in range(s_q))
+
+
+def _brute_executed(s_q, s_k, bq, bk, offset, sub):
+    """What the bodies multiply, counted block by block: with strips, every
+    ``sub x sub`` block of a live tile but those wholly above the diagonal;
+    without, every live tile whole."""
+    if not sub:
+        return _brute_live(s_q, s_k, bq, bk, offset) * bq * bk
+    return sub * sub * sum(
+        a * sub + (sub - 1) + offset >= b * sub  # the block's last query sees its first key
+        for a in range(s_q // sub) for b in range(s_k // sub))
+
+
+@pytest.mark.parametrize("s_q,s_k,d_pad,itemsize,group", SHAPES)
+def test_executed_share_is_the_bodies_count(s_q, s_k, d_pad, itemsize, group):
+    """``flash_executed_share``'s closed form against a count of the blocks the
+    bodies multiply, at the tiles the rule picks and at pinned ones, with the
+    shipped strip heights and with others."""
+    offset = s_k - s_q
+    assert visible_pairs(s_q, s_k) == _brute_visible(s_q, s_k, offset)
+    assert visible_pairs(s_q, s_k, causal=False) == s_q * s_k
+    plan = pick_tiles(s_q, s_k, d_pad, itemsize, group)
+    tiles = {(t.block_q, t.block_k) for t in plan}
+    tiles |= {(b, b) for b in (128, 256, 512) if s_q % b == 0 and s_k % b == 0}
+    for launch, t in zip(LAUNCHES, plan):
+        for bq, bk in sorted(tiles):
+            sub = strip_rows(launch, bq, bk, causal=True, offset=offset)
+            assert sub == 0 or (bq == bk and bq % sub == 0)
+            assert executed_pairs(launch, s_q, s_k, bq, bk) == _brute_executed(
+                s_q, s_k, bq, bk, offset, sub), (launch, bq, bk, sub)
+        assert executed_pairs(launch, s_q, s_k, t.block_q, t.block_k, causal=False) == s_q * s_k
+        assert t.executed_share == pytest.approx(
+            executed_pairs(launch, s_q, s_k, t.block_q, t.block_k) / visible_pairs(s_q, s_k))
+        assert t.executed_share >= 1.0
+
+
+@pytest.mark.parametrize("offset", [-256, 0, 128, 192, 256, 1024])
+def test_executed_share_at_an_offset(offset, monkeypatch):
+    """Ring attention's chunks: an offset of whole tiles keeps the strips (a
+    chunk wholly in the past has no tile on the diagonal), another one does
+    not, and the closed forms follow."""
+    monkeypatch.setattr(fa, "STRIP_ROWS", dict.fromkeys(LAUNCHES, 128))
+    for tile in (128, 256):
+        sub = strip_rows("fwd", tile, tile, causal=True, offset=offset)
+        assert sub == (128 if offset % tile == 0 else 0)
+        assert visible_pairs(512, 512, offset=offset) == _brute_visible(512, 512, offset)
+        assert executed_pairs("fwd", 512, 512, tile, tile, offset=offset) == _brute_executed(
+            512, 512, tile, tile, offset, sub)
+
+
+@pytest.mark.parametrize("cell,shape", [
+    ("mpt125m-train", (2048, 2048, 128, 2, 1)),        # and -gbs256, mpt125m-fedround
+    ("glm47flash-train", (4096, 4096, 256, 2, 1)),
+    ("granite4hmicro-train", (8192, 8192, 128, 2, 4)),
+    ("mpt-1b", (2048, 2048, 128, 2, 1)),
+])
+def test_the_cells_forward_executes_little_dead_work(cell, shape):
+    """Every dense-kernel cell's shapes engage the strips in all three
+    launches: no launch multiplies more than 1.25 pairs a visible pair."""
+    plan = pick_tiles(*shape)
+    for launch, t in zip(LAUNCHES, plan):
+        assert strip_rows(launch, t.block_q, t.block_k, causal=True, offset=0), (cell, launch)
+        assert 1.0 <= t.executed_share <= 1.25, (cell, launch, t)
+
+
+def test_executed_share_before_the_strips(monkeypatch):
+    """What ISSUE 39 read off the parent: whole tiles at mpt-125m's plan."""
+    monkeypatch.setattr(fa, "STRIP_ROWS", dict.fromkeys(LAUNCHES, 0))
+    half = 2048 * 2049 // 2
+    shares = [executed_pairs(launch, 2048, 2048, b, b) / half
+              for launch, b in zip(LAUNCHES, (2048, 1024, 512))]
+    assert [round(s, 2) for s in shares] == [2.0, 1.5, 1.25]
 
 
 @pytest.mark.parametrize("s_q,s_k,d_pad,itemsize,group", SHAPES)

@@ -124,7 +124,9 @@ def test_flash_attention_compiles(one_chip, h, h_kv, d, block, alibi, grad):
 @pytest.mark.parametrize(
     "d,block_q,block_k",
     [(64, 512, 512), (128, 1024, 1024), (128, 2048, 1024), (128, 1024, 2048),
-     (256, 2048, 512), (256, 1024, 1024)],
+     (256, 2048, 512), (256, 1024, 1024),
+     # a whole-sequence tile: all three launches run it as eight strips
+     (64, 2048, 2048), (128, 2048, 2048)],
     ids=lambda v: str(v),
 )
 def test_flash_vmem_estimate_is_enough(one_chip, monkeypatch, d, block_q, block_k, dtype):
@@ -132,10 +134,15 @@ def test_flash_vmem_estimate_is_enough(one_chip, monkeypatch, d, block_q, block_
     launch asks the compiler for, so it may never be under what the compiler
     needs: with the unasked-for 16 MiB taken away, every launch compiles
     inside its own estimate (tall, wide and square tiles; the widths and
-    dtypes whose buffers differ)."""
+    dtypes whose buffers differ). A square tile's launches hold the strip
+    bodies beside the whole-tile one, a tall or wide tile's the masked body
+    alone."""
     from photon_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "VMEM_SCOPED_DEFAULT", 0)
+    for launch in ("fwd", "dq", "dkv"):
+        strips = fa.strip_rows(launch, block_q, block_k, causal=True, offset=0)
+        assert bool(strips) == (block_q == block_k)
     q = _abstract((1, 2048, 4, d), dtype, one_chip)
 
     def loss(q, k, v):
