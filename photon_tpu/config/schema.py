@@ -106,6 +106,10 @@ class ModelConfig:
     moe_shared_experts: int = 0
     moe_routed_scale: float = 1.0
     moe_bias_update_speed: float = 0.0
+    # what the sigmoid router adds to the picked scores' sum before it divides
+    # by it (1e-20 guards the division and changes no gate; a model that
+    # publishes another, as ``+ 1e-6``, states it)
+    moe_gate_eps: float = 1.0e-20
     # Leading dense blocks before the expert stack (HF ``first_k_dense_replace``):
     # the first ``first_k_dense`` layers are SwiGLU blocks of width
     # ``dense_mlp_hidden_size``, under a scan of their own (``dense_blocks``).
@@ -123,10 +127,12 @@ class ModelConfig:
     v_head_dim: int = 0
     # A stack whose layers differ in kind (HF ``layer_types``, its list joined
     # by commas so that YAML, JSON and ``--set`` all spell it alike): one entry
-    # a layer, ``mamba`` (a Mamba-2 mixer, ``ops/ssd.py``) or ``attention``;
-    # every layer keeps the block's norms, residuals and MLP. "" = attention
-    # everywhere, one scanned stack ``blocks``; otherwise each run of equal
-    # kind is a scanned stack of its own, ``blocks_0``, ``blocks_1``, ...
+    # a layer, ``mamba`` (a Mamba-2 mixer, ``ops/ssd.py``), ``conv`` (a gated
+    # short convolution, below) or ``attention``; every layer keeps the
+    # block's norms and residuals. "" = attention everywhere, one scanned
+    # stack ``blocks``; otherwise each run of layers equal in mixer AND in MLP
+    # kind (the first ``first_k_dense`` dense, the others the model's
+    # ``mlp``) is a scanned stack of its own, ``blocks_0``, ``blocks_1``, ...
     # (training path only). A Mamba-2 mixer has ``mamba_n_heads`` heads of
     # ``mamba_d_head`` (the mixer's inner channels, ``mamba_d_inner``), one
     # group of B and C of ``mamba_d_state``, a causal depthwise convolution
@@ -138,6 +144,12 @@ class ModelConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # A ``conv`` layer's mixer (HF ``lfm2``'s short convolution): one
+    # projection to ``B | C | u``, each ``d_model`` wide; a causal depthwise
+    # convolution of ``conv_kernel_size`` taps (HF ``conv_L_cache``), no bias
+    # and no activation, over ``B * u``; the gate ``C *`` on its output; the
+    # projection back.
+    conv_kernel_size: int = 3
     # Granite's four multipliers. At their defaults nothing is multiplied:
     # the embedding's output, each residual branch and the logits (divided by
     # ``logits_scaling``) are left as they are, and ``attention_multiplier``
@@ -219,20 +231,33 @@ class ModelConfig:
         return self.layer_kinds.count("mamba")
 
     @property
+    def conv_layers(self) -> int:
+        return self.layer_kinds.count("conv")
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_n_heads * self.mamba_d_head
 
     @property
-    def layer_runs(self) -> list[tuple[str, int]]:
-        """``layer_types`` as runs of equal kind, in order: ``[(kind,
-        length), ...]``, one scanned stack each."""
-        runs: list[tuple[str, int]] = []
-        for kind in self.layer_kinds:
-            if runs and runs[-1][0] == kind:
-                runs[-1] = (kind, runs[-1][1] + 1)
+    def stacks(self) -> list[tuple[str, str, bool, int]]:
+        """The model's scanned stacks in order: ``(name, mixer, dense MLP,
+        length)``. One stack of attention blocks (``blocks``) behind the
+        leading dense ones (``dense_blocks``); with ``layer_types`` every run
+        of layers equal in mixer and in MLP kind, ``blocks_0``, ``blocks_1``,
+        ... (a leading dense layer ends a run its mixer would go on)."""
+        if not self.hybrid:
+            blocks = [("blocks", "attention", False, self.n_layers - self.first_k_dense)]
+            if self.first_k_dense:
+                blocks.insert(0, ("dense_blocks", "attention", True, self.first_k_dense))
+            return blocks
+        runs: list[tuple[str, bool, int]] = []
+        for i, kind in enumerate(self.layer_kinds):
+            dense = i < self.first_k_dense
+            if runs and runs[-1][:2] == (kind, dense):
+                runs[-1] = (kind, dense, runs[-1][2] + 1)
             else:
-                runs.append((kind, 1))
-        return runs
+                runs.append((kind, dense, 1))
+        return [(f"blocks_{i}", *run) for i, run in enumerate(runs)]
 
     @property
     def scaled(self) -> bool:
@@ -1067,6 +1092,9 @@ class Config:
                 "routers ('sigmoid', 'softmax_topk'); moe_shared_experts / "
                 "moe_routed_scale / moe_bias_update_speed belong to "
                 "moe_router='sigmoid'")
+        if m.moe_gate_eps != 1.0e-20 and (m.moe_router != "sigmoid" or m.moe_gate_eps <= 0):
+            raise ValueError(
+                "moe_gate_eps belongs to moe_router='sigmoid' and must be > 0")
         if m.first_k_dense:
             if not 0 < m.first_k_dense < m.n_layers or m.dense_mlp_hidden_size <= 0:
                 raise ValueError(
@@ -1156,7 +1184,9 @@ class Config:
 
     def _validate_hybrid_family(self) -> None:
         """``layer_types`` with its Mamba-2 sizes, and the four multipliers
-        (preset ``granite-4.0-h-micro-stage1``)."""
+        (preset ``granite-4.0-h-micro-stage1``); ``conv`` layers, with leading
+        dense layers and expert layers among them (preset
+        ``lfm2-8b-a1b-ep4``)."""
         m = self.model
         if min(m.embedding_multiplier, m.residual_multiplier, m.logits_scaling) <= 0 \
                 or m.attention_multiplier < 0:
@@ -1177,14 +1207,33 @@ class Config:
         if not m.hybrid:
             return
         kinds = set(m.layer_kinds)
-        if len(m.layer_kinds) != m.n_layers or not kinds <= {"mamba", "attention"}:
+        if len(m.layer_kinds) != m.n_layers or not kinds <= {"mamba", "conv", "attention"}:
             raise ValueError(
                 f"layer_types needs n_layers={m.n_layers} comma-separated entries, "
-                f"each 'mamba' or 'attention'; got {len(m.layer_kinds)}: {sorted(kinds)}")
-        if m.first_k_dense or m.mlp == "moe" or m.latent_attention:
+                f"each 'mamba', 'conv' or 'attention'; got {len(m.layer_kinds)}: "
+                f"{sorted(kinds)}")
+        if m.latent_attention:
             raise ValueError(
-                "layer_types does not combine with first_k_dense, mlp='moe' or "
-                "latent attention: a layer's kind picks its mixer only")
+                "layer_types does not combine with latent attention: its "
+                "attention layers are the grouped-query branch's")
+        if m.mamba_layers and (m.first_k_dense or m.mlp == "moe"):
+            raise ValueError(
+                "'mamba' layers do not combine with first_k_dense or mlp='moe': "
+                "no model with a Mamba-2 mixer before an expert layer runs here")
+        if m.mlp == "moe" and not m.dropless_moe:
+            raise ValueError(
+                "layer_types with mlp='moe' needs a dropless router "
+                "(moe_router='sigmoid' / 'softmax_topk'): the capacity path's "
+                "stack is one of attention blocks")
+        if m.conv_layers:
+            if m.conv_kernel_size <= 0:
+                raise ValueError("a 'conv' layer needs conv_kernel_size > 0")
+            if self.mesh.sequence > 1 or self.mesh.tensor > 1:
+                raise ValueError(
+                    "mesh.sequence > 1 or mesh.tensor > 1 with 'conv' layers "
+                    "is not supported: the taps reach back along the whole "
+                    "row, and no tensor split respects the in-projection's "
+                    "B | C | u columns")
         if m.mamba_layers:
             sizes = (m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state,
                      m.mamba_d_conv, m.mamba_chunk_size)

@@ -39,6 +39,15 @@ own (``blocks_0``, ``blocks_1``, ...); the four multipliers scale the
 embedding, each residual branch, the attention scores and the logits, and at
 their defaults add no operation to any other model's graph.
 
+A convolution / expert hybrid composes from all of these (preset
+``lfm2-8b-a1b-ep4``, training path only): a third mixer, ``conv``
+(``MPTBlock._short_conv_mixer``: a gated short convolution over
+``ops/ssd.causal_conv1d``), in three layers of four, the grouped-query branch
+with ``qk_norm`` in the fourth; the leading ``first_k_dense`` layers dense, the
+others the sigmoid-routed dropless expert layer; every run of layers equal in
+mixer and in MLP kind a scanned stack of its own (``ModelConfig.stacks``), so
+the model has two expert stacks, each with its own selection bias.
+
 TPU-first design choices (not in the reference):
 - Layers are stacked with ``nn.scan`` → one traced block, params carry a
   leading ``[n_layers, ...]`` axis. This keeps compile time flat in depth and
@@ -73,6 +82,8 @@ from photon_tpu.utils.profiling import (
     MAMBA_GATE_NORM_SCOPE,
     MAMBA_PROJ_SCOPE,
     MAMBA_SCAN_SCOPE,
+    SHORTCONV_MIX_SCOPE,
+    SHORTCONV_PROJ_SCOPE,
 )
 
 
@@ -216,8 +227,9 @@ class MPTBlock(nn.Module):
     #: same norms, residuals and attention, a SwiGLU of
     #: ``cfg.dense_mlp_hidden_size`` where the stack's blocks have experts
     dense_mlp: bool = False
-    #: what mixes the positions (``cfg.layer_types``): ``attention``, or
-    #: ``mamba`` for a Mamba-2 mixer in attention's place
+    #: what mixes the positions (``cfg.layer_types``): ``attention``, or in
+    #: its place ``mamba`` for a Mamba-2 mixer, ``conv`` for a gated short
+    #: convolution
     mixer: str = "attention"
 
     def _mamba_mixer(self, h: jax.Array, dense, resid_std: float) -> jax.Array:
@@ -256,6 +268,26 @@ class MPTBlock(nn.Module):
             y = y.reshape(b, s, inner) * nn.silu(z.astype(jnp.float32))
             y = FP32RMSNorm(eps=cfg.norm_eps, name="mamba_norm")(y).astype(compute)
         with jax.named_scope(MAMBA_PROJ_SCOPE):
+            return dense(cfg.d_model, "out_proj", resid_std)(y)
+
+    def _short_conv_mixer(self, h: jax.Array, dense, resid_std: float) -> jax.Array:
+        """The gated short convolution on ``h [B, S, D]`` (HF ``lfm2``): one
+        projection to ``B | C | u``; a causal depthwise convolution of
+        ``conv_kernel_size`` taps, without bias or activation, over ``B * u``;
+        the gate ``C *`` on its output; the projection back. The taps and the
+        second gate are float32, as ``causal_conv1d`` makes its sum."""
+        from photon_tpu.ops import ssd
+
+        cfg = self.cfg
+        with jax.named_scope(SHORTCONV_PROJ_SCOPE):
+            bcu = dense(3 * cfg.d_model, "in_proj", cfg.emb_init_std)(h)
+        kernel = self.param(
+            "conv_kernel", nn.initializers.normal(stddev=cfg.emb_init_std),
+            (cfg.conv_kernel_size, cfg.d_model), _dtype(cfg.param_dtype))
+        with jax.named_scope(SHORTCONV_MIX_SCOPE):
+            b, c, u = jnp.split(bcu, 3, axis=-1)
+            y = (c * ssd.causal_conv1d(b * u, kernel)).astype(_dtype(cfg.compute_dtype))
+        with jax.named_scope(SHORTCONV_PROJ_SCOPE):
             return dense(cfg.d_model, "out_proj", resid_std)(y)
 
     def _latent_qkv(self, h: jax.Array, dense):
@@ -366,6 +398,7 @@ class MPTBlock(nn.Module):
             h32, router_w, router_bias, w_gate, w_up, w_down,
             top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
             routed_scale=cfg.moe_routed_scale, router=cfg.moe_router,
+            gate_eps=cfg.moe_gate_eps,
             compute_dtype=compute, interpret=cfg.attn_interpret)
         self.sow("intermediates", "moe_rows_held", counters["rows_held"])
         self.sow("intermediates", "moe_max_expert_load", counters["max_expert_load"])
@@ -418,11 +451,13 @@ class MPTBlock(nn.Module):
 
         resid_std = cfg.emb_init_std / (2.0 * cfg.n_layers) ** 0.5
 
-        # --- the mixer: attention, or a Mamba-2 mixer in its place ---
+        # --- the mixer: attention, or another kind in its place ---
         with jax.named_scope(BLOCK_NORM_SCOPE):
             h = _norm(cfg, "ln_1")(x)
         if self.mixer == "mamba":
             x = _residual(cfg, x, self._mamba_mixer(h, dense, resid_std))
+        elif self.mixer == "conv":
+            x = _residual(cfg, x, self._short_conv_mixer(h, dense, resid_std))
         else:
             n_kv = cfg.n_kv_heads or cfg.n_heads
             b, s, _ = h.shape
@@ -615,15 +650,10 @@ class MPTModel(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, dense_mlp, mixer, name=name)
 
-        if cfg.hybrid:
-            # every run of equal kind under a scan of its own, in order
-            for i, (kind, length) in enumerate(cfg.layer_runs):
-                x, _ = stack(length, f"blocks_{i}", mixer=kind)(x, None)
-        else:
-            if cfg.first_k_dense:
-                # leading dense blocks under a scan of their own, beside the stack
-                x, _ = stack(cfg.first_k_dense, "dense_blocks", dense_mlp=True)(x, None)
-            x, _ = stack(cfg.n_layers - cfg.first_k_dense, "blocks")(x, None)
+        # leading dense blocks, and with ``layer_types`` every run of equal
+        # mixer and MLP kind, under a scan of their own, in order
+        for name, mixer, dense_mlp, length in cfg.stacks:
+            x, _ = stack(length, name, dense_mlp, mixer)(x, None)
 
         with jax.named_scope(BLOCK_NORM_SCOPE):
             x = _norm(cfg, "ln_f")(x)

@@ -154,19 +154,19 @@ GMM_TILING = (512, 1024, 1024)
 
 
 def sigmoid_route(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array,
-                  top_k: int, routed_scale: float):
+                  top_k: int, routed_scale: float, eps: float = 1e-20):
     """``noaux_tc`` routing with one group: float32 sigmoid scores of every
     routed expert, the ``top_k`` picked by score + ``router_bias`` (which only
     selects and takes no gradient), the picked scores renormalised to sum to
-    ``routed_scale``. ``h32 [N, D]`` float32 -> ``(idx [N, k] int32,
-    gates [N, k] float32)``."""
+    ``routed_scale`` (their sum ``+ eps`` divides: a model publishes its own).
+    ``h32 [N, D]`` float32 -> ``(idx [N, k] int32, gates [N, k] float32)``."""
     scores = jax.nn.sigmoid(jnp.matmul(
         h32.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     biased = scores + jax.lax.stop_gradient(router_bias.astype(jnp.float32))
     _, idx = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
-    gates = routed_scale * picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    gates = routed_scale * picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), gates
 
 
@@ -300,13 +300,14 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
 def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array | None,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
                      top_k: int, first_expert: int, routed_scale: float = 1.0,
-                     router: str = "sigmoid",
+                     router: str = "sigmoid", gate_eps: float = 1e-20,
                      compute_dtype=jnp.bfloat16, interpret: bool = False):
     """The routed part of a dropless expert layer, for the experts held here.
 
     Two routers: ``router='sigmoid'`` is ``noaux_tc`` (:func:`sigmoid_route`:
     sigmoid scores, a selection bias ``router_bias [E]`` without gradient, the
-    picked scores renormalised to ``routed_scale``); ``router='softmax_topk'``
+    picked scores renormalised to ``routed_scale`` over their sum ``+
+    gate_eps``); ``router='softmax_topk'``
     is :func:`softmax_route` (a float32 softmax over all ``E``, the picked
     probabilities renormalised to 1; no bias, no scale: ``router_bias`` is
     ``None``). Everything after the gates is one path.
@@ -335,7 +336,8 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
         if router == "softmax_topk":
             idx, gates = softmax_route(hf32, router_w, top_k)
         elif router == "sigmoid":
-            idx, gates = sigmoid_route(hf32, router_w, router_bias, top_k, routed_scale)
+            idx, gates = sigmoid_route(hf32, router_w, router_bias, top_k, routed_scale,
+                                       gate_eps)
         else:
             raise ValueError(f"the dropless layer has no router {router!r}")
         expert_rows = jnp.sum(

@@ -1,6 +1,8 @@
 """Mamba-2's state-space recurrence in its chunked form (SSD), and the causal
-depthwise convolution that feeds it. Pure ``jax.numpy``; training only. The
-caller names the scopes (``models/mpt.py``: ``mamba/conv``, ``mamba/scan``).
+depthwise convolution that feeds it (and that a ``conv`` layer's gated short
+convolution takes its taps from). Pure ``jax.numpy``; training only. The
+caller names the scopes (``models/mpt.py``: ``mamba/conv``, ``mamba/scan``;
+``shortconv/mix``).
 
 Per head ``h`` (width ``P``) and position ``t``, with one group of ``B_t``,
 ``C_t`` (width ``N``) shared by all heads, ``A_h = -exp(A_log_h)`` and
@@ -37,16 +39,19 @@ import jax
 import jax.numpy as jnp
 
 
-def causal_conv1d(x: jax.Array, kernel: jax.Array, bias: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, kernel: jax.Array,
+                  bias: jax.Array | None = None) -> jax.Array:
     """Depthwise causal convolution over ``x [B, S, C]``: ``y_t = bias +
     sum_k kernel[k] * x_(t - W + 1 + k)`` with zeros before the row's start
-    (``kernel [W, C]``), as ``W`` shifted products in float32."""
+    (``kernel [W, C]``; no ``bias``: the sum alone), as ``W`` shifted products
+    in float32."""
     width, s = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     kernel = kernel.astype(jnp.float32)
-    y = bias.astype(jnp.float32)
+    y = None if bias is None else bias.astype(jnp.float32)
     for k in range(width):
-        y = y + kernel[k] * padded[:, k:k + s]
+        tap = kernel[k] * padded[:, k:k + s]
+        y = tap if y is None else y + tap
     return y
 
 

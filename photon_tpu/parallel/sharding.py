@@ -57,7 +57,11 @@ _RULES: list[tuple[str, P]] = [
     # a Mamba-2 mixer (models/mpt.py, ops/ssd.py): the in-projection's columns
     # are z | x B C | dt, whose boundaries no tensor split respects, so they
     # stay whole (its `out_proj` is row-parallel like attention's, below); the
-    # depthwise convolution and the per-head scalars are small and replicated
+    # depthwise convolution and the per-head scalars are small and replicated.
+    # A gated short convolution (`conv` layers) has the same three names: its
+    # in-projection's columns are B | C | u, whole for the same reason, its
+    # 3-tap `conv_kernel` replicated. (An expert stack under `blocks_<i>`
+    # matches the MoE rules above by its leaves' names, as under `blocks`.)
     (r"in_proj/kernel$", P("pipe", "fsdp", None)),
     (r"(conv_kernel|conv_bias|A_log|dt_bias|D)$", P("pipe")),
     (r"mamba_norm/scale$", P("pipe")),

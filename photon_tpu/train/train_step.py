@@ -164,30 +164,32 @@ def collect_moe_aux(variables: Any) -> jax.Array:
     return aux
 
 
-#: the step's rows by expert layer and routed expert, ``[layers, E]``: beside
-#: the counters until the step has moved the selection bias by them; no metric
+#: the step's rows by expert stack, layer and routed expert (``{stack:
+#: [layers, E]}``): beside the counters until the step has moved each stack's
+#: selection bias by its own; no metric
 _EXPERT_ROWS = "moe/expert_rows"
 
 
 def collect_moe_counters(variables: Any) -> dict[str, jax.Array]:
     """The dropless expert layers' per-layer sows as the step's counters:
     rows routed to the experts held here summed over layers, and the busiest
-    held expert's rows over the mean, worst layer; and the rows of every
-    routed expert by layer, for the balancing rule. Empty for every other
-    model."""
-    rows, worst, by_expert = [], [], []
+    held expert's rows over the mean, worst layer, both over every expert
+    stack; and the rows of every routed expert by layer, under the name of
+    the stack that sowed them (a sow's path starts with it), for the
+    balancing rule. Empty for every other model."""
+    rows, worst, by_expert = [], [], {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
-        keys = {getattr(k, "key", None) for k in path}
+        keys = [getattr(k, "key", None) for k in path]
         if "moe_rows_held" in keys:
             rows.append(jnp.sum(jnp.asarray(leaf, jnp.float32)))
         elif "moe_max_expert_load" in keys:
             worst.append(jnp.max(jnp.asarray(leaf, jnp.float32)))
         elif "moe_expert_rows" in keys:
-            by_expert.append(jnp.asarray(leaf, jnp.float32).reshape(-1, leaf.shape[-1]))
+            by_expert[keys[0]] = jnp.asarray(leaf, jnp.float32).reshape(-1, leaf.shape[-1])
     if not rows:
         return {}
     return {MOE_ROWS_HELD: sum(rows), MOE_MAX_EXPERT_LOAD: jnp.max(jnp.stack(worst)),
-            _EXPERT_ROWS: jnp.concatenate(by_expert)}
+            _EXPERT_ROWS: by_expert}
 
 
 def collect_dsa_counters(variables: Any) -> dict[str, jax.Array]:
@@ -209,8 +211,8 @@ def collect_dsa_counters(variables: Any) -> dict[str, jax.Array]:
 
 def _merge_counters(a: dict, b: dict) -> dict:
     """Two microbatches' counters as one step's: rows add, the load is the worst."""
-    return {k: jnp.maximum(a[k], b[k]) if k == MOE_MAX_EXPERT_LOAD else a[k] + b[k]
-            for k in a}
+    return {k: jnp.maximum(a[k], b[k]) if k == MOE_MAX_EXPERT_LOAD
+            else jax.tree.map(jnp.add, a[k], b[k]) for k in a}
 
 
 def _apply_collecting_aux(model: MPTModel, params, tokens, **kwargs):
@@ -267,16 +269,17 @@ def make_loss_fn(model: MPTModel, loss_chunk_tokens: int = 2048) -> Callable:
     return lambda params, tokens: fn(params, tokens)[0]
 
 
-def _balance_router_bias(params, expert_rows: jax.Array, speed: float):
-    """The expert stack's selection bias (``router_bias [layers, E]``, which
+def _balance_router_bias(params, expert_rows: dict[str, jax.Array], speed: float):
+    """Every expert stack's selection bias (``router_bias [layers, E]``, which
     the optimizer leaves alone: it has no gradient) moved one step against
-    the loads this step routed."""
+    the loads this step routed to that stack (``expert_rows[stack]``)."""
     from photon_tpu.ops.moe import balanced_router_bias
 
     def move(path, leaf):
         if getattr(path[-1], "key", None) != "router_bias":
             return leaf
-        return balanced_router_bias(leaf, expert_rows, speed).astype(leaf.dtype)
+        rows = expert_rows[path[0].key]
+        return balanced_router_bias(leaf, rows, speed).astype(leaf.dtype)
 
     return jax.tree_util.tree_map_with_path(move, params)
 
@@ -316,9 +319,9 @@ def make_train_step(
                 zero_counters = {
                     MOE_ROWS_HELD: jnp.zeros([], jnp.float32),
                     MOE_MAX_EXPERT_LOAD: jnp.zeros([], jnp.float32),
-                    _EXPERT_ROWS: jnp.zeros(
-                        (model.cfg.n_layers - model.cfg.first_k_dense,
-                         model.cfg.moe_num_experts), jnp.float32),
+                    _EXPERT_ROWS: {
+                        name: jnp.zeros((length, model.cfg.moe_num_experts), jnp.float32)
+                        for name, _, dense_mlp, length in model.cfg.stacks if not dense_mlp},
                 }
             if model.cfg.sparse_attention:
                 zero_counters.update({k: jnp.zeros([], jnp.float32) for k in (

@@ -98,6 +98,12 @@ def _mamba_attrs(model_cfg) -> dict[str, int]:
             "ssd_chunks": model_cfg.max_seq_len // model_cfg.mamba_chunk_size}
 
 
+def _conv_attrs(model_cfg) -> dict[str, int]:
+    """The step's gated short-convolution layers, as a span attribute: a
+    static count. Empty for a model without such layers."""
+    return {"conv_layers": model_cfg.conv_layers} if model_cfg.conv_layers else {}
+
+
 def _dsa_attrs(model_cfg) -> dict[str, int]:
     """The step's sparse-attention layers and the keys each of their queries
     picks, as span attributes: static counts. Empty for every other model."""
@@ -198,6 +204,7 @@ class Trainer:
         self.model = MPTModel(effective_model_config(cfg.model, mesh_cfg))
         self._kernel_attrs = {**_flash_tile_attrs(self.model.cfg),
                               **_mamba_attrs(self.model.cfg),
+                              **_conv_attrs(self.model.cfg),
                               **_dsa_attrs(self.model.cfg)}
         self.tx, self.lr_schedule = build_optimizer(cfg.optimizer, cfg.scheduler)
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)

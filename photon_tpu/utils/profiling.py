@@ -228,6 +228,13 @@ MAMBA_CONV_SCOPE = "mamba/conv"
 MAMBA_SCAN_SCOPE = "mamba/scan"
 #: the gate ``y * silu(z)`` and the RMSNorm over all inner channels
 MAMBA_GATE_NORM_SCOPE = "mamba/gate_norm"
+# -- gated short-convolution layers (models/mpt.py over ops/ssd.causal_conv1d):
+# ``jax.named_scope``s like the Mamba-2 layers'; no pattern over one family's
+# names matches the other's --------------------------------------------------
+#: the mixer's in- and out-projection
+SHORTCONV_PROJ_SCOPE = "shortconv/proj"
+#: the split into ``B | C | u``, the gate ``B * u``, the taps, the gate ``C *``
+SHORTCONV_MIX_SCOPE = "shortconv/mix"
 # -- every block (models/mpt.py) and the step around them
 # (train/train_step.py): ``jax.named_scope``s like the families' above, so
 # that no device time of a step is left to a bare instruction name --------
@@ -737,7 +744,8 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     experts and the routed experts at this chip's expected share (``top_k *
     held / routed`` experts a token): what the step computes here, not what
     the whole model would. A Mamba-2 layer (``layer_types``) counts its two
-    projections and the chunked scan's products in attention's place.
+    projections and the chunked scan's products in attention's place, a
+    ``conv`` layer its two projections, its taps and its gates.
     Learned sparse attention (``dsa_topk``) has a count of its own,
     :func:`_sparse_attention_flops_per_token`."""
     d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
@@ -762,7 +770,8 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
         n_kv = cfg.n_kv_heads or cfg.n_heads
         attn_w = d * (cfg.n_heads + 2 * n_kv) * cfg.d_head + d * d
         attn = 12 * L * d * s  # score + value matmuls, fwd+bwd
-    n_mamba = cfg.mamba_layers  # their mixer stands in attention's place
+    # another mixer stands in attention's place in these layers
+    n_mamba, n_conv = cfg.mamba_layers, cfg.conv_layers
     mamba_w = 0
     if n_mamba:
         inner, n, q = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_chunk_size
@@ -771,8 +780,13 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
         # half, the chunk's state and its read-out; three times with the backward
         scan = 3 * (q * n + q * inner + 4 * inner * n)
         attn = attn * (L - n_mamba) / L + n_mamba * scan
+    if n_conv:
+        # the taps and both gates are elementwise: 2 operations a tap and
+        # channel, two gates, three times with the backward
+        attn = attn * (L - n_conv) / L + n_conv * 3 * (2 * cfg.conv_kernel_size + 2) * d
+    conv_w = 3 * d * d + d * d  # B | C | u, and the projection back
     n_dense = cfg.first_k_dense  # leading SwiGLU blocks of their own width
-    n_block = ((L - n_mamba) * attn_w + n_mamba * mamba_w
+    n_block = ((L - n_mamba - n_conv) * attn_w + n_mamba * mamba_w + n_conv * conv_w
                + n_dense * 3 * d * cfg.dense_mlp_hidden_size + (L - n_dense) * mlp_w)
     head = 6 * d * v
     return 6.0 * n_block + attn + head

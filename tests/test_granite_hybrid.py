@@ -413,7 +413,8 @@ def test_the_preset_is_what_the_benchmark_configuration_states():
     traffic = json.loads((ROOT / "benchmark/traffic/stage1-1x8192.json").read_text())
     cfg = build_config(config, traffic, ROOT / ".bench_work" / "unused", seed=2**31 + 5)
     assert (cfg.train.global_batch_size, cfg.train.device_microbatch_size) == (1, 1)
-    assert cfg.model.layer_runs == [("mamba", 5), ("attention", 1), ("mamba", 4)]
+    assert [s[1:] for s in cfg.model.stacks] == [
+        ("mamba", False, 5), ("attention", False, 1), ("mamba", False, 4)]
     assert cfg.model.mamba_layers == 9 and cfg.model.mamba_d_inner == 4096
     assert cfg.model.d_head == 64 and cfg.model.training_path_only
     # a preset edited under the benchmark is refused
@@ -546,10 +547,17 @@ def _with(cfg, **paths):
 
 @pytest.mark.parametrize("change, message", [
     (dict(model__layer_types="mamba,attention"), "needs n_layers=4"),
-    (dict(model__layer_types="mamba,mamba,conv,mamba"), "each 'mamba' or 'attention'"),
+    (dict(model__layer_types="mamba,mamba,full_attention,mamba"),
+     "each 'mamba', 'conv' or 'attention'"),
     (dict(model__max_seq_len=36), "not a multiple of mamba_chunk_size"),
     (dict(model__mamba_d_state=0), "all > 0"),
-    (dict(model__first_k_dense=1, model__dense_mlp_hidden_size=8), "does not combine"),
+    (dict(model__first_k_dense=1, model__dense_mlp_hidden_size=8),
+     "'mamba' layers do not combine with first_k_dense or mlp='moe'"),
+    (dict(model__mlp="moe", model__moe_router="sigmoid", model__moe_mlp_act="swiglu",
+          model__moe_num_experts=4), "'mamba' layers do not combine"),
+    (dict(model__kv_lora_rank=8, model__q_lora_rank=8, model__qk_nope_head_dim=4,
+          model__qk_rope_head_dim=4, model__v_head_dim=8, model__n_kv_heads=0, model__rope=True),
+     "layer_types does not combine with latent attention"),
     (dict(model__residual_multiplier=0.0), "must be > 0"),
     (dict(model__attention_multiplier=-1.0), "attention_multiplier >= 0"),
     (dict(model__attn_impl="ring"), "not supported with ring attention"),
@@ -573,6 +581,7 @@ def test_layer_types_survives_yaml_and_json_as_written(tmp_path):
     back = Config.from_yaml(tmp_path / "resolved.yaml").validate()
     assert back.model.layer_types == TINY["layer_types"]
     assert back.model.layer_kinds == ("mamba", "mamba", "attention", "mamba")
-    assert Config.from_json(cfg.to_json()).model.layer_runs == [
-        ("mamba", 2), ("attention", 1), ("mamba", 1)]
+    assert Config.from_json(cfg.to_json()).model.stacks == [
+        ("blocks_0", "mamba", False, 2), ("blocks_1", "attention", False, 1),
+        ("blocks_2", "mamba", False, 1)]
     assert Config().model.layer_kinds == () and not Config().model.training_path_only

@@ -352,6 +352,67 @@ def test_chunked_state_space_scan_compiles_and_keeps_one_chunk_of_decays(one_chi
 
 
 # ---------------------------------------------------------------------------
+# lfm2-8b-a1b-ep4: the grouped products at a width that is 7 x 256, and the
+# gated short convolution's mix
+# ---------------------------------------------------------------------------
+
+
+def test_grouped_expert_products_compile_at_a_width_of_seven_tiles(one_chip, monkeypatch):
+    """The dropless layer's three grouped products at the ``lfm2moe-train-8k``
+    cell's widths (65,536 static rows of 2,048, 8 experts of 1,792 = 7 x 256),
+    forward and backward: under ``ops/moe.GMM_TILING`` the 1,792 dimension
+    takes an 896 tile (it divides; a 1,024 tile would pad and mask an eighth),
+    and megablox's three kernels accept it."""
+    import photon_tpu.ops.flash_attention as fa
+    from photon_tpu.ops import moe
+
+    assert moe._tiles(moe.GMM_TILING, 65536, 2048, 1792) == (512, 1024, 896)
+    assert moe._tiles(moe.GMM_TILING, 65536, 1792, 2048) == (512, 896, 1024)
+    monkeypatch.setattr(fa, "pallas_supported", lambda x: True)
+    rows = _abstract((65536, 2048), jnp.bfloat16, one_chip)
+    w_in = _abstract((8, 2048, 1792), jnp.bfloat16, one_chip)
+    w_out = _abstract((8, 1792, 2048), jnp.bfloat16, one_chip)
+    sizes = _abstract((9,), jnp.int32, one_chip)
+
+    def loss(x, w_gate, w_up, w_down, sizes):
+        mm = lambda a, b: moe.grouped_matmul(a, b, sizes)  # noqa: E731
+        out = mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2, 3)), rows, w_in, w_in, w_out, sizes)
+    assert hlo.count(KERNEL) >= 8
+
+
+def test_the_short_convolutions_mix_compiles_to_two_forward_passes(one_chip):
+    """``B | C | u`` -> ``C * taps(B * u)`` at the cell's widths (2 rows of
+    8,192, 2,048 channels, 3 taps): the forward is TWO passes over the rows
+    (``B * u`` written once in float32, 134 MB, which is all it holds beside
+    its argument and result; then the three shifted products and the second
+    gate in one fusion), and the backward's temporaries stay under four
+    float32 copies of the output. (A pass fewer is a kernel's work: PERF.md
+    section 7.)"""
+    from photon_tpu.ops import ssd
+
+    bcu = _abstract((2, 8192, 6144), jnp.bfloat16, one_chip)
+    dy = _abstract((2, 8192, 2048), jnp.bfloat16, one_chip)
+    kernel = _abstract((3, 2048), jnp.float32, one_chip)
+
+    def mix(bcu, kernel):
+        b, c, u = jnp.split(bcu, 3, axis=-1)
+        return (c * ssd.causal_conv1d(b * u, kernel)).astype(jnp.bfloat16)
+
+    forward = jax.jit(mix).lower(bcu, kernel).compile()
+    passes = re.findall(r"^\s*(?:ROOT )?%\S*fusion\S* = \w+\[2,8192,2048\]", forward.as_text(), re.M)
+    assert len(passes) == 2, passes
+    assert forward.memory_analysis().temp_size_in_bytes <= 2 * 8192 * 2048 * 4 * 1.01
+    backward = jax.jit(jax.grad(
+        lambda bcu, kernel, dy: jnp.vdot(mix(bcu, kernel).astype(jnp.float32),
+                                         dy.astype(jnp.float32)), argnums=(0, 1))).lower(
+        bcu, kernel, dy).compile()
+    assert backward.memory_analysis().temp_size_in_bytes < 4 * 2 * 8192 * 2048 * 4
+
+
+# ---------------------------------------------------------------------------
 # keye-vl-2.0-30b-a3b-ep8: the flash kernel under a (query, key) mask, and the
 # exact selection
 # ---------------------------------------------------------------------------
