@@ -400,9 +400,8 @@ class MPTBlock(nn.Module):
             routed_scale=cfg.moe_routed_scale, router=cfg.moe_router,
             gate_eps=cfg.moe_gate_eps,
             compute_dtype=compute, interpret=cfg.attn_interpret)
-        self.sow("intermediates", "moe_rows_held", counters["rows_held"])
-        self.sow("intermediates", "moe_max_expert_load", counters["max_expert_load"])
-        self.sow("intermediates", "moe_expert_rows", counters["expert_rows"])
+        for name, value in counters.items():
+            self.sow("intermediates", f"moe_{name}", value)
         if cfg.moe_shared_experts:
             with jax.named_scope(moe.SHARED_EXPERT_SCOPE):
                 width = cfg.moe_shared_experts * hidden

@@ -37,7 +37,7 @@ import numpy as np
 # operation's ``op_name``, forward, transpose and recomputation alike; the
 # benchmark's ``moe_*`` readers sum device time by them).
 ROUTER_SCOPE = "moe/router"
-#: sort by expert, permute, un-permute, combine
+#: sort by expert, permute, un-permute (from the live rows: PR 43), combine
 DISPATCH_SCOPE = "moe/dispatch"
 #: the grouped products of the experts held here and the activation between
 EXPERTS_SCOPE = "moe/experts"
@@ -184,44 +184,112 @@ def softmax_route(h32: jax.Array, router_w: jax.Array, top_k: int):
     return idx.astype(jnp.int32), picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_by_expert(x: jax.Array, order: jax.Array, inv: jax.Array, k: int):
+#: bytes of the expert-ordered rows' prefix the dispatch's un-permutes gather
+#: from: what XLA keeps in a v5e's VMEM as a gather's operand, from where rows
+#: move five times as fast as from HBM (``scripts/moe_dispatch_ladder.py``;
+#: PERF.md, PR 43)
+DISPATCH_CHUNK_BYTES = 80 * 2**20
+
+
+def chunk_rows(src: jax.Array, chunk: int | None = None) -> int:
+    """Rows of ``src [M, D]`` in that prefix (``chunk``, or ``DISPATCH_CHUNK_BYTES``' worth)."""
+    rows = chunk or max(8, DISPATCH_CHUNK_BYTES // (src.shape[1] * src.dtype.itemsize) // 8 * 8)
+    return min(rows, src.shape[0])
+
+
+def rows_of_live_prefix(src: jax.Array, idx: jax.Array, n_live: jax.Array | None,
+                        chunk: int | None = None) -> jax.Array:
+    """``out[s] = src[idx[s]]``: ``src [M, D]`` whose rows past the first
+    ``n_live`` are zeros (:func:`grouped_matmul`'s contract for the rows of no
+    group here), ``idx [S] int32``; so zeros where ``idx[s] >= n_live``.
+    Where the live rows fit the first ``chunk`` rows of ``src`` with one to
+    spare (the common case: what a chip holds of an expert-parallel group is a
+    fraction of the experts) that prefix is the gather's operand, small enough
+    for VMEM, and every dead slot reads its last row, a zero one; where they
+    do not, all ``M`` rows are the operand, as before (and as where every row
+    is live by the shapes: ``n_live`` None). Which of the two runs is data
+    (``n_live``), so this can be a primal or a pull-back of a ``custom_vjp``,
+    not something differentiated through. A conditional and not a loop over
+    chunks: a second trip costs more than the whole gather from HBM, and XLA
+    does not merge a one-layer stack's recomputation with its forward across a
+    loop (PERF.md, PR 43)."""
+    chunk = chunk_rows(src, chunk)
+    if n_live is None or chunk >= src.shape[0]:
+        return src[idx]
+    return jax.lax.cond(
+        n_live < chunk,
+        lambda: src[:chunk][jnp.where(idx < n_live, idx, chunk - 1)],
+        lambda: src[idx])
+
+
+def rows_moved(n_live: jax.Array | None, m: int, chunk: int) -> jax.Array:
+    """Rows of its ``m``-row operand one un-permute gathers from (float32):
+    :func:`rows_of_live_prefix`'s choice."""
+    if n_live is None or chunk >= m:
+        return jnp.asarray(m, jnp.float32)
+    return jnp.where(n_live < chunk, chunk, m).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_by_expert(x: jax.Array, order: jax.Array, inv: jax.Array,
+                    n_live: jax.Array | None, k: int):
     """``x [N, D]`` -> ``[N k, D]``: row ``i`` is the token of the ``i``-th
-    assignment in expert order. Its transpose is written as a gather too (a
-    token's ``k`` assignments sit at ``inv[n k : n k + k]``): XLA's own
-    transpose of a gather is a scatter-add, slow on a TPU."""
+    assignment in expert order (the operand is the ``N`` tokens: small enough
+    for VMEM already). Its transpose is written as a gather too (a token's
+    ``k`` assignments sit at ``inv[n k : n k + k]``): XLA's own transpose of a
+    gather is a scatter-add, slow on a TPU. Only the first ``n_live`` rows of
+    the cotangent are live (the sort puts the assignments to experts held here
+    first; ``n_live`` None: every row), so the transpose gathers from that
+    prefix (:func:`rows_of_live_prefix`)."""
     return x[order // k]
 
 
-def _rows_by_expert_fwd(x, order, inv, k):
-    return _rows_by_expert(x, order, inv, k), (order, inv, x.shape[0])
+def _rows_by_expert_fwd(x, order, inv, n_live, k):
+    return _rows_by_expert(x, order, inv, n_live, k), (inv, n_live)
 
 
 def _rows_by_expert_bwd(k, res, g):
-    order, inv, n = res
-    dx = jnp.sum(g[inv].reshape(n, k, g.shape[-1]).astype(jnp.float32), axis=1)
-    return dx.astype(g.dtype), None, None
+    by_token = rows_of_live_prefix(g, *res)
+    dx = jnp.sum(by_token.reshape(-1, k, g.shape[-1]).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None, None
 
 
 _rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
 
 
 @jax.custom_vjp
-def _rows_by_token(rows: jax.Array, order: jax.Array, inv: jax.Array):
-    """Expert-ordered rows back in assignment order (``[N k, D]``, token
-    ``n``'s at ``n k : n k + k``); the transpose is the gather by ``order``."""
-    return rows[inv]
+def _combine(rows: jax.Array, gates: jax.Array, order: jax.Array, inv: jax.Array,
+             n_live: jax.Array | None):
+    """The layer's output from its expert-ordered rows: ``out[n] = sum_j
+    gates[n, j] rows[inv[n k + j]]`` accumulated in float32, in ``rows``' dtype.
+    ``rows [N k, D]`` (live in its first ``n_live``, zeros after; None: all
+    live), ``gates [N, k]`` float32, zero where the expert is not held here.
+    The rows come back in assignment order through
+    :func:`rows_of_live_prefix`. The pull-back works in expert order, on the
+    output's cotangent ``[N, D]`` gathered by token (an operand that fits
+    VMEM): weighed by the row's gate it is the rows' cotangent, and its
+    product with the row summed over ``D`` the gate's, which goes back to its
+    slot as one float. So the backward pass needs no row in assignment order,
+    and ``remat`` does not un-permute again."""
+    per_slot = rows_of_live_prefix(rows, inv, n_live).reshape(*gates.shape, rows.shape[-1])
+    out = jnp.einsum("nk,nkd->nd", gates, per_slot, preferred_element_type=jnp.float32)
+    return out.astype(rows.dtype)
 
 
-def _rows_by_token_fwd(rows, order, inv):
-    return _rows_by_token(rows, order, inv), order
+def _combine_fwd(rows, gates, order, inv, n_live):
+    return _combine(rows, gates, order, inv, n_live), (rows, gates, order, inv)
 
 
-def _rows_by_token_bwd(order, g):
-    return g[order], None, None
+def _combine_bwd(res, g):
+    rows, gates, order, inv = res
+    g32 = g[order // gates.shape[1]].astype(jnp.float32)
+    # a dead row's gate is zero and the row itself is: both cotangents are
+    d_rows = (gates.reshape(-1)[order][:, None] * g32).astype(rows.dtype)
+    d_gates = jnp.sum(g32 * rows.astype(jnp.float32), axis=-1)[inv].reshape(gates.shape)
+    return d_rows, d_gates, None, None, None
 
 
-_rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _megablox():
@@ -320,12 +388,23 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
     (sorted by expert, three grouped products), the others add nothing: what
     the absent experts would have given is another chip's part of the sum.
 
-    Shapes are static for the worst case (``N k`` rows, all routed here); the
-    grouped products' device time follows the rows that were. Returns
-    ``(out [..., D] compute_dtype, counters)`` with ``rows_held`` (assignments
-    to experts held here) and ``max_expert_load`` (the busiest held expert's
-    rows over their mean), both float32 scalars, and ``expert_rows [E]``
-    float32, the assignments to each of ALL the routed experts (what
+    Shapes are static for the worst case (``N k`` rows, all routed here). The
+    grouped products' device time follows the rows that were, and so do the
+    two un-permutes of a layer and step (expert order back to assignment
+    order: forward and in the permute's pull-back; the combine's pull-back
+    works in expert order, so ``remat`` does not un-permute again): they gather
+    from the live rows where those fit a chunk that fits VMEM
+    (:func:`rows_of_live_prefix`), and zeros stand in the slots of experts not
+    held here. The permute and the combine's pull-back gather from the ``N``
+    tokens, which fit VMEM as they are. Where the layer holds every routed
+    expert every row is live, which the shapes say, and the un-permutes are
+    whole gathers. Returns ``(out [..., D] compute_dtype, counters)`` with
+    ``rows_held`` (assignments to experts held here), ``max_expert_load`` (the
+    busiest held expert's rows over their mean), ``dispatch_rows_moved`` (the
+    rows of their operand the two un-permutes gathered from) and
+    ``dispatch_rows_static`` (2 ``N k``, what whole gathers read from), all
+    float32 scalars, and ``expert_rows [E]`` float32, the
+    assignments to each of ALL the routed experts (what
     :func:`balanced_router_bias` steers by).
     """
     lead, d = h32.shape[:-1], h32.shape[-1]
@@ -349,11 +428,15 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
         # assignments to absent experts sort last, under a group of their own
         key = jnp.where(held, local, e_held).reshape(n * top_k)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.argsort(order).astype(jnp.int32)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(e_held + 1, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32)
-        rows = _rows_by_expert(hf32.astype(compute_dtype), order, inv, top_k)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        # where every routed expert is held here every row is live, which the
+        # shapes say: the un-permutes stay whole gathers
+        every = first_expert == 0 and e_held == router_w.shape[-1]
+        n_live = None if every else jnp.sum(group_sizes[:e_held])
+        rows = _rows_by_expert(hf32.astype(compute_dtype), order, inv, n_live, top_k)
     with jax.named_scope(EXPERTS_SCOPE):
         mm = functools.partial(grouped_matmul, group_sizes=group_sizes,
                                interpret=interpret)
@@ -361,17 +444,19 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
         up = mm(rows, w_up.astype(compute_dtype))
         rows = mm(jax.nn.silu(gate) * up, w_down.astype(compute_dtype))
     with jax.named_scope(DISPATCH_SCOPE):
-        per_slot = _rows_by_token(rows, order, inv).reshape(n, top_k, d)
-        out = jnp.einsum("nk,nkd->nd", jnp.where(held, gates, 0.0), per_slot,
-                         preferred_element_type=jnp.float32)
+        out = _combine(rows, jnp.where(held, gates, 0.0), order, inv, n_live)
         held_sizes = group_sizes[:e_held].astype(jnp.float32)
         rows_held = jnp.sum(held_sizes)
         counters = {
+            # the two un-permutes of a layer and step: forward, and in the
+            # permute's pull-back
+            "dispatch_rows_moved": 2.0 * rows_moved(n_live, n * top_k, chunk_rows(rows)),
+            "dispatch_rows_static": jnp.asarray(2.0 * n * top_k, jnp.float32),
             "rows_held": rows_held,
             "max_expert_load": jnp.max(held_sizes) / jnp.maximum(rows_held / e_held, 1.0),
             "expert_rows": expert_rows,
         }
-    return out.astype(compute_dtype).reshape(*lead, d), counters
+    return out.reshape(*lead, d), counters
 
 
 def balanced_router_bias(router_bias: jax.Array, expert_rows: jax.Array,

@@ -24,6 +24,8 @@ from photon_tpu.utils.profiling import (
     DSA_PICKED_PAIRS,
     DSA_TILES_VISITED,
     GRAD_NORM_SCOPE,
+    MOE_DISPATCH_ROWS_MOVED,
+    MOE_DISPATCH_ROWS_STATIC,
     MOE_MAX_EXPERT_LOAD,
     MOE_ROWS_HELD,
 )
@@ -172,24 +174,27 @@ _EXPERT_ROWS = "moe/expert_rows"
 
 def collect_moe_counters(variables: Any) -> dict[str, jax.Array]:
     """The dropless expert layers' per-layer sows as the step's counters:
-    rows routed to the experts held here summed over layers, and the busiest
-    held expert's rows over the mean, worst layer, both over every expert
+    rows routed to the experts held here and the rows the dispatch's movements
+    copied, beside their static worst case, summed over layers, and the busiest
+    held expert's rows over the mean, worst layer, all over every expert
     stack; and the rows of every routed expert by layer, under the name of
     the stack that sowed them (a sow's path starts with it), for the
     balancing rule. Empty for every other model."""
-    rows, worst, by_expert = [], [], {}
+    summed = {"moe_rows_held": MOE_ROWS_HELD,
+              "moe_dispatch_rows_moved": MOE_DISPATCH_ROWS_MOVED,
+              "moe_dispatch_rows_static": MOE_DISPATCH_ROWS_STATIC}
+    sums, worst, by_expert = {}, [], {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
         keys = [getattr(k, "key", None) for k in path]
-        if "moe_rows_held" in keys:
-            rows.append(jnp.sum(jnp.asarray(leaf, jnp.float32)))
-        elif "moe_max_expert_load" in keys:
+        for name in (summed[k] for k in keys if k in summed):
+            sums[name] = sums.get(name, 0.0) + jnp.sum(jnp.asarray(leaf, jnp.float32))
+        if "moe_max_expert_load" in keys:
             worst.append(jnp.max(jnp.asarray(leaf, jnp.float32)))
         elif "moe_expert_rows" in keys:
             by_expert[keys[0]] = jnp.asarray(leaf, jnp.float32).reshape(-1, leaf.shape[-1])
-    if not rows:
+    if not sums:
         return {}
-    return {MOE_ROWS_HELD: sum(rows), MOE_MAX_EXPERT_LOAD: jnp.max(jnp.stack(worst)),
-            _EXPERT_ROWS: by_expert}
+    return {**sums, MOE_MAX_EXPERT_LOAD: jnp.max(jnp.stack(worst)), _EXPERT_ROWS: by_expert}
 
 
 def collect_dsa_counters(variables: Any) -> dict[str, jax.Array]:
@@ -317,8 +322,9 @@ def make_train_step(
             zero_counters = {}
             if model.cfg.dropless_moe:
                 zero_counters = {
-                    MOE_ROWS_HELD: jnp.zeros([], jnp.float32),
-                    MOE_MAX_EXPERT_LOAD: jnp.zeros([], jnp.float32),
+                    **{k: jnp.zeros([], jnp.float32) for k in (
+                        MOE_ROWS_HELD, MOE_DISPATCH_ROWS_MOVED, MOE_DISPATCH_ROWS_STATIC,
+                        MOE_MAX_EXPERT_LOAD)},
                     _EXPERT_ROWS: {
                         name: jnp.zeros((length, model.cfg.moe_num_experts), jnp.float32)
                         for name, _, dense_mlp, length in model.cfg.stacks if not dense_mlp},

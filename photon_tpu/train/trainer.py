@@ -51,6 +51,8 @@ from photon_tpu.utils.profiling import (
     DSA_PICKED_PAIRS,
     DSA_TILES_CAUSAL,
     DSA_TILES_VISITED,
+    MOE_DISPATCH_ROWS_MOVED,
+    MOE_DISPATCH_ROWS_STATIC,
     MOE_MAX_EXPERT_LOAD,
     MOE_ROWS_HELD,
     TRAINER_DSA_SPAN,
@@ -519,7 +521,9 @@ class Trainer:
                     with telemetry.span(
                             TRAINER_MOE_LOAD_SPAN,
                             rows_held=last_metrics[MOE_ROWS_HELD],
-                            max_expert_load=last_metrics[MOE_MAX_EXPERT_LOAD]):
+                            max_expert_load=last_metrics[MOE_MAX_EXPERT_LOAD],
+                            dispatch_rows_moved=last_metrics[MOE_DISPATCH_ROWS_MOVED],
+                            dispatch_rows_static=last_metrics[MOE_DISPATCH_ROWS_STATIC]):
                         pass
                 if DSA_PICKED_PAIRS in last_metrics:
                     # the last step's selection counters, the same way

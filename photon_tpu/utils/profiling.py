@@ -178,8 +178,9 @@ TRAINER_STEPS_SPAN = "trainer/steps"
 TRAINER_NEXT_BATCH_SPAN = "trainer/next_batch"
 TRAINER_FENCE_SPAN = "trainer/fence"
 #: opened and closed inside the fence once the last step's metrics are on the
-#: host, only when the step has dropless expert layers: attrs ``rows_held``
-#: and ``max_expert_load`` (the two counters below), for a trace's reader
+#: host, only when the step has dropless expert layers: attrs ``rows_held``,
+#: ``max_expert_load``, ``dispatch_rows_moved`` and ``dispatch_rows_static``
+#: (the four counters below), for a trace's reader
 TRAINER_MOE_LOAD_SPAN = "trainer/moe_load"
 # -- dropless expert layers (ops/moe.py): counters in the train step's
 # metrics, fetched with the loss at the trainer's fence -------------------
@@ -187,6 +188,11 @@ TRAINER_MOE_LOAD_SPAN = "trainer/moe_load"
 MOE_ROWS_HELD = "moe/rows_held"
 #: the busiest held expert's rows over the held experts' mean, worst layer
 MOE_MAX_EXPERT_LOAD = "moe/max_expert_load"
+#: rows the dispatch's six row movements a layer copied (the blocks they
+#: visited), and their static worst case (6 ``N k`` a layer), summed over the
+#: layers: their ratio is how far the dispatch followed the rows held
+MOE_DISPATCH_ROWS_MOVED = "moe/dispatch_rows_moved"
+MOE_DISPATCH_ROWS_STATIC = "moe/dispatch_rows_static"
 #: opened and closed inside the fence like ``trainer/moe_load``, only when the
 #: step holds the indexer's sparse attention: attrs ``picked_pairs``,
 #: ``causal_pairs``, ``tiles_visited``, ``tiles_causal``, ``index_loss`` (the

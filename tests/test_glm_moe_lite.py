@@ -336,22 +336,214 @@ def test_no_tokens_by_experts_by_capacity_tensor_in_the_dropless_path():
 
 
 # ---------------------------------------------------------------------------
+# the dispatch's un-permutes gather from the live prefix (PR 43)
+# ---------------------------------------------------------------------------
+
+CHUNK = 512  # rows a trip reads in these tests (the chip's: DISPATCH_CHUNK_BYTES' worth)
+LIVE = {"none": 0, "one": 1, "chunk-1": CHUNK - 1, "chunk": CHUNK, "chunk+1": CHUNK + 1,
+        "all": None}
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """``CHUNK`` rows a trip for bfloat16 rows of width 32."""
+    monkeypatch.setattr(moe, "DISPATCH_CHUNK_BYTES", CHUNK * 32 * 2)
+
+
+def _routed_order(router: str, k: int, n: int, d: int = 16, experts: int = 16):
+    """``n`` tokens routed by ``router`` to ``k`` of ``experts``, sorted as the
+    layer sorts them with the first quarter of the experts held."""
+    rng = np.random.default_rng(k)
+    h = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(d, experts)), jnp.float32)
+    if router == "sigmoid":
+        idx, _ = moe.sigmoid_route(h, w, jnp.zeros(experts), k, 1.0)
+    else:
+        idx, _ = moe.softmax_route(h, w, k)
+    held = experts // 4
+    key = jnp.where(idx < held, idx, held).reshape(n * k)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    return order, jnp.argsort(order).astype(jnp.int32)
+
+
+# the parent's formulation (before PR 43): every movement a whole gather from
+# all ``N k`` rows, unmasked; the combine an einsum over the un-permuted rows
+
+
+@jax.custom_vjp
+def _whole_rows_by_token(rows, order, inv):
+    return rows[inv]
+
+
+_whole_rows_by_token.defvjp(lambda rows, order, inv: (rows[inv], order),
+                            lambda order, g: (g[order], None, None))
+
+
+def _whole_combine(rows, gates, order, inv, n_live):
+    per_slot = _whole_rows_by_token(rows, order, inv).reshape(*gates.shape, rows.shape[-1])
+    return jnp.einsum("nk,nkd->nd", gates, per_slot,
+                      preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+@pytest.mark.parametrize("router", ["sigmoid", "softmax_topk"])
+@pytest.mark.parametrize("k", [4, 8])
+@pytest.mark.parametrize("live", sorted(LIVE))
+def test_the_unpermutes_are_the_masked_gathers_exactly(live, k, router, small_chunks):
+    """The un-permute and the two functions it sits in, forward and through
+    ``jax.vjp``, against ``where(live, rows[inv], 0)`` and the parent's whole
+    gathers, to the bit, with the live rows ending before, at and after a
+    chunk's end: nothing is rounded differently, dead slots are zeros."""
+    n, d = CHUNK, 32  # N k = 4 or 8 chunks
+    m = n * k
+    order, inv = _routed_order(router, k, n)
+    n_live = jnp.asarray(m if LIVE[live] is None else LIVE[live], jnp.int32)
+    rng = np.random.default_rng(11)
+    bf16 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)  # noqa: E731
+    x, g = bf16(n, d), bf16(n, d)
+    # expert-ordered rows are zero past the live ones (megablox's own zeros)
+    prefix = (jnp.arange(m) < n_live)[:, None]
+    rows, g_rows = jnp.where(prefix, bf16(m, d), 0), jnp.where(prefix, bf16(m, d), 0)
+    slots = (inv < n_live)[:, None]
+    gates = jnp.where(slots.reshape(n, k), jnp.asarray(rng.uniform(size=(n, k)), jnp.float32), 0)
+
+    np.testing.assert_array_equal(
+        moe.rows_of_live_prefix(rows, inv, n_live), jnp.where(slots, rows[inv], 0))
+
+    by_expert, pull = jax.vjp(lambda x: moe._rows_by_expert(x, order, inv, n_live, k), x)
+    np.testing.assert_array_equal(by_expert, x[order // k])
+    want = jnp.sum(g_rows[inv].reshape(n, k, d).astype(jnp.float32), axis=1)
+    np.testing.assert_array_equal(pull(g_rows)[0], want.astype(jnp.bfloat16))
+
+    got, pull = jax.vjp(lambda r, w: moe._combine(r, w, order, inv, n_live), rows, gates)
+    want, pull_whole = jax.vjp(lambda r, w: _whole_combine(r, w, order, inv, n_live), rows, gates)
+    np.testing.assert_array_equal(got, want)
+    (d_rows, d_gates), (want_rows, want_gates) = pull(g), pull_whole(g)
+    np.testing.assert_array_equal(d_rows, want_rows)
+    # the gates' cotangent sums the same products over D in expert order: on
+    # the CPU the einsum's transpose is a matrix product with an order of its own
+    np.testing.assert_allclose(d_gates, want_gates, rtol=2e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("router, k, experts, held, interpret", [
+    ("sigmoid", 4, 8, 3, False), ("sigmoid", 8, 16, 5, False),
+    ("softmax_topk", 4, 8, 3, False), ("softmax_topk", 8, 16, 5, False),
+    ("sigmoid", 4, 16, 1, False), ("sigmoid", 2, 8, 3, True)])
+def test_two_rematted_layers_in_a_scan_give_the_whole_gathers_results_exactly(
+        router, k, experts, held, interpret, monkeypatch, small_chunks):
+    """``dropless_moe_mlp`` under ``jax.checkpoint`` inside a ``lax.scan`` of
+    two layers (the model's own nesting), bfloat16 compute: the output and the
+    experts' gradients are the parent formulation's to the bit, the router's
+    and the input's to the order of a sum over ``D``, at sizes where the
+    live rows pass the first chunk, where they fit it (1 of 16 held), and,
+    with megablox in the interpreter, where the chunk is all the rows (the
+    kernel reads no dead row and zeroes what it does not write)."""
+    n, d, hidden = (256, 128, 128) if interpret else (2 * CHUNK, 32, 48)
+    rng = np.random.default_rng(k)
+    f32 = lambda *shape, scale=0.2: jnp.asarray(  # noqa: E731
+        rng.normal(size=shape) * scale, jnp.float32)
+    h0 = f32(n, d, scale=1.0)
+    layers = {"router": f32(2, d, experts, scale=0.5), "gate": f32(2, held, d, hidden),
+              "up": f32(2, held, d, hidden), "down": f32(2, held, hidden, d)}
+    bias = None if router == "softmax_topk" else jnp.zeros(experts)
+
+    def layer(h, p):
+        out, counters = moe.dropless_moe_mlp(
+            h, p["router"], bias, p["gate"], p["up"], p["down"], top_k=k, first_expert=1,
+            router=router, interpret=interpret)
+        return h + out.astype(jnp.float32), counters
+
+    def loss(h, layers):
+        h, counters = jax.lax.scan(jax.checkpoint(layer), h, layers)
+        return jnp.sum(h ** 2), (h, counters)
+
+    run = lambda: jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(h0, layers)  # noqa: E731
+    (_, (got, counters)), got_grads = run()
+    with monkeypatch.context() as patch:
+        patch.setattr(moe, "rows_of_live_prefix", lambda src, idx, n_live: src[idx])
+        patch.setattr(moe, "_combine", _whole_combine)
+        (_, (want, _)), want_grads = run()
+    np.testing.assert_array_equal(got, want)
+    (d_h, d_layers), (want_h, want_layers) = got_grads, want_grads
+    for name in ("gate", "up", "down"):
+        np.testing.assert_array_equal(d_layers[name], want_layers[name])
+    assert float(jnp.max(jnp.abs(d_layers["down"]))) > 0
+    # what passes through the gates' cotangent (see the test above)
+    scale = float(jnp.max(jnp.abs(want_h)))
+    np.testing.assert_allclose(d_h, want_h, rtol=1e-4, atol=1e-4 * scale)
+    np.testing.assert_allclose(d_layers["router"], want_layers["router"], rtol=1e-4,
+                               atol=1e-4 * float(jnp.max(jnp.abs(want_layers["router"]))))
+    # and the counter says from how many of the rows the un-permutes gathered:
+    # the first chunk where the live rows fit it, else all of them
+    m = n * k
+    chunk = min(m, CHUNK * 32 // d)
+    held_rows = np.asarray(counters["rows_held"])
+    np.testing.assert_array_equal(
+        counters["dispatch_rows_moved"], 2 * np.where(held_rows <= chunk, chunk, m))
+    np.testing.assert_array_equal(counters["dispatch_rows_static"], [2.0 * m] * 2)
+    if not interpret:
+        fits = held == 1
+        assert np.all((held_rows <= chunk) == fits) and 0 < held_rows.min() < 0.8 * m
+
+
+def test_a_layer_that_holds_every_expert_keeps_its_whole_gathers(monkeypatch):
+    """``e_held == E``: every row is live, which the shapes say, so the layer
+    has no conditional in its program, forward or backward (a share of the
+    experts has one an un-permute), and counts every row."""
+    monkeypatch.setattr(moe, "DISPATCH_CHUNK_BYTES", 16 * 32 * 4)  # 16 of the 48 float32 rows
+    p = _layer_weights(1)
+    h = jnp.asarray(np.random.default_rng(2).normal(size=(24, 32)), jnp.float32)
+    program = lambda held: str(jax.make_jaxpr(jax.grad(  # noqa: E731
+        lambda h: jnp.sum(_program_share(h, p, 0, held)[0] ** 2)))(h))
+    assert program(4).count(" cond[") == 2 and " cond[" not in program(8)
+    _, counters = _program_share(h, p, 0, 8)
+    assert float(counters["dispatch_rows_moved"]) == float(
+        counters["dispatch_rows_static"]) == 2 * 24 * 2
+
+
+# ---------------------------------------------------------------------------
 # the step, the trainer, a federated round
 # ---------------------------------------------------------------------------
 
 
-def test_fit_returns_the_routing_counters_and_no_aux_loss():
+def recorded_spans(monkeypatch) -> list:
+    """``(name, attrs)`` of every ``telemetry.span`` the trainer opens from here on."""
+    import contextlib
+
+    from photon_tpu.train import trainer
+
+    spans = []
+
+    @contextlib.contextmanager
+    def span(name, **attrs):
+        spans.append((name, attrs))
+        yield
+
+    monkeypatch.setattr(trainer.telemetry, "span", span)
+    return spans
+
+
+def test_fit_returns_the_routing_counters_and_no_aux_loss(monkeypatch):
     from photon_tpu.train.trainer import Trainer
-    from photon_tpu.utils.profiling import MOE_MAX_EXPERT_LOAD, MOE_ROWS_HELD
+    from photon_tpu.utils.profiling import (
+        MOE_DISPATCH_ROWS_MOVED, MOE_DISPATCH_ROWS_STATIC, MOE_MAX_EXPERT_LOAD,
+        MOE_ROWS_HELD, TRAINER_MOE_LOAD_SPAN)
 
     cfg = tiny_cfg()
     cfg.train.global_batch_size, cfg.train.device_microbatch_size = 4, 2  # two microbatches
     trainer = Trainer(cfg, init_seed=0)
+    spans = recorded_spans(monkeypatch)
     out = trainer.fit([TOKENS] * 3, duration_steps=3)
     # 4 rows x 32 tokens x top-2 x 2 expert layers = 512 assignments, about
     # half of them to the 4 of 8 experts held here
     assert 128 <= out[MOE_ROWS_HELD] <= 384
     assert 1.0 <= out[MOE_MAX_EXPERT_LOAD] <= 4.0
+    # a tiny layer's 128 static rows are one chunk: two un-permutes a layer
+    # gather from it, in both layers and both microbatches
+    assert out[MOE_DISPATCH_ROWS_MOVED] == out[MOE_DISPATCH_ROWS_STATIC] == 2 * 512
+    (attrs,) = [a for name, a in spans if name == TRAINER_MOE_LOAD_SPAN]
+    assert attrs == {"rows_held": out[MOE_ROWS_HELD],
+                     "max_expert_load": out[MOE_MAX_EXPERT_LOAD],
+                     "dispatch_rows_moved": 2 * 512, "dispatch_rows_static": 2 * 512}
     # the sigmoid router has no aux loss: the step's loss is the cross entropy
     model = MPTModel(cfg.model)
     ce = make_loss_fn(model, 16)(trainer.state.params, TOKENS)

@@ -633,7 +633,8 @@ def test_the_block_has_no_selection_bias():
 def test_fit_returns_the_selection_counters_on_their_span():
     from photon_tpu.train.trainer import Trainer
     from photon_tpu.utils.profiling import (
-        DSA_CAUSAL_PAIRS, DSA_TILES_CAUSAL, MOE_ROWS_HELD)
+        DSA_CAUSAL_PAIRS, DSA_TILES_CAUSAL, MOE_DISPATCH_ROWS_MOVED,
+        MOE_DISPATCH_ROWS_STATIC, MOE_ROWS_HELD)
 
     cfg = tiny_cfg()
     trainer = Trainer(cfg, init_seed=0)
@@ -648,6 +649,8 @@ def test_fit_returns_the_selection_counters_on_their_span():
     assert out[DSA_TILES_VISITED] == out[DSA_TILES_CAUSAL] == rows  # one tile a row
     assert 0.0 < out[DSA_INDEX_LOSS] < 2.0
     assert 64 <= out[MOE_ROWS_HELD] <= 256  # ~ 2 x 64 x 2 x 2 x 2/8
+    # two un-permutes a layer, a tiny layer's rows one chunk: 2 x 64 x 2 x 2
+    assert out[MOE_DISPATCH_ROWS_MOVED] == out[MOE_DISPATCH_ROWS_STATIC] == 2 * 512
 
 
 @pytest.mark.parametrize("impl, interpret, remat", [

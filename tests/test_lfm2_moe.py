@@ -352,7 +352,8 @@ def test_three_adopt_steps_follow_the_reference(microbatches):
 
 def test_fit_returns_the_routing_counters_of_both_stacks():
     from photon_tpu.train.trainer import Trainer
-    from photon_tpu.utils.profiling import MOE_MAX_EXPERT_LOAD, MOE_ROWS_HELD
+    from photon_tpu.utils.profiling import (
+        MOE_DISPATCH_ROWS_MOVED, MOE_DISPATCH_ROWS_STATIC, MOE_MAX_EXPERT_LOAD, MOE_ROWS_HELD)
 
     cfg = tiny_cfg()
     cfg.train.global_batch_size, cfg.train.device_microbatch_size = 4, 2  # two microbatches
@@ -362,6 +363,8 @@ def test_fit_returns_the_routing_counters_of_both_stacks():
     # half of them to the 4 of 8 experts held here
     assert 256 <= out[MOE_ROWS_HELD] <= 768
     assert 1.0 <= out[MOE_MAX_EXPERT_LOAD] <= 4.0
+    # two un-permutes a layer over both stacks' layers; a tiny layer is one chunk
+    assert out[MOE_DISPATCH_ROWS_MOVED] == out[MOE_DISPATCH_ROWS_STATIC] == 2 * 1024
 
 
 # ---------------------------------------------------------------------------

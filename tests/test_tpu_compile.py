@@ -308,6 +308,55 @@ def test_grouped_expert_products_compile(one_chip, monkeypatch):
     assert hlo.count(KERNEL) >= 8
 
 
+@pytest.mark.parametrize("k, experts, held, hidden, router", [
+    (4, 32, 8, 1792, "sigmoid"), (8, 128, 16, 768, "softmax_topk")],
+    ids=["lfm2moe-train-8k", "keyevl2-train-16k"])
+def test_the_dispatchs_gathers_read_from_vmem(one_chip, monkeypatch, k, experts, held,
+                                              hidden, router):
+    """One dropless layer under ``jax.checkpoint``, forward and backward, at
+    the cells' shapes (16,384 tokens: 65,536 and 131,072 static rows of 2,048):
+    each of the two un-permutes is a conditional whose common branch gathers
+    from a chunk of ``ops/moe.DISPATCH_CHUNK_BYTES`` of the expert-ordered
+    rows, which the compiler keeps in VMEM (memory space 1), and whose other
+    branch gathers from all ``N k``; every other row gather's operand is the
+    ``N`` tokens; nothing un-permutes under ``remat``; inside a minute and a
+    half."""
+    import functools
+    import time
+
+    import photon_tpu.ops.flash_attention as fa
+    from photon_tpu.ops import moe
+
+    monkeypatch.setattr(fa, "pallas_supported", lambda x: True)
+    tokens, d = 16384, 2048
+    w_in = _abstract((held, d, hidden), jnp.float32, one_chip)
+
+    def loss(h32, router_w, w_gate, w_up, w_down):
+        bias = jnp.zeros((experts,)) if router == "sigmoid" else None
+        out, _ = jax.checkpoint(functools.partial(
+            moe.dropless_moe_mlp, top_k=k, first_expert=0, router=router))(
+                h32, router_w, bias, w_gate, w_up, w_down)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    t0 = time.monotonic()
+    text = _hlo(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                _abstract((tokens, d), jnp.float32, one_chip),
+                _abstract((d, experts), jnp.float32, one_chip), w_in, w_in,
+                _abstract((held, hidden, d), jnp.float32, one_chip))
+    assert time.monotonic() - t0 < 90
+    chunk, m = moe.DISPATCH_CHUNK_BYTES // (d * 2), tokens * k
+    gathers = re.findall(r"fusion\((%[\w.\-]+), [^\n]*(moe/dispatch\)?/[^\"]*gather)\"", text)
+    shapes = [(re.findall(rf"^\s*(?:ROOT )?{re.escape(operand)} = (\S+) ", text, flags=re.M)[0], op)
+              for operand, op in gathers]
+    rows = [(shape, op) for shape, op in shapes if shape.startswith("bf16[")]
+    assert not any("rematted_computation" in op and "cond" in op for _, op in rows)
+    chunks = [shape for shape, _ in rows if shape.startswith(f"bf16[{chunk},{d}]")]
+    assert len(chunks) == 2 and all(shape.endswith("S(1)}") for shape in chunks), chunks
+    assert len([1 for shape, op in rows if shape.startswith(f"bf16[{m},{d}]")]) == 2
+    assert {shape.split("{")[0] for shape, op in rows if "cond" not in op} == {
+        f"bf16[{tokens},{d}]"}
+
+
 # ---------------------------------------------------------------------------
 # granite-4.0-h-micro-stage1: grouped heads with the published scale at 8,192
 # positions, and the chunked state-space scan
