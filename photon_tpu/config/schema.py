@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import json
+import math
 import pathlib
 import typing
 import warnings
@@ -125,6 +126,36 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # YaRN's frequency scaling (HF ``rope_scaling`` with ``type: yarn``, key
+    # for key; "" = plain ``rope_theta`` frequencies). The rotary dims whose
+    # wavelength fits ``rope_scaling_original_max_position`` more than
+    # ``rope_scaling_beta_fast`` times keep their frequency, those that fit it
+    # fewer than ``rope_scaling_beta_slow`` times turn ``rope_scaling_factor``
+    # times slower, a linear ramp between; the softmax scale is multiplied by
+    # ``mscale(factor, mscale_all_dim)**2``, with ``mscale(f, m) = 0.1 m ln f
+    # + 1`` (``ModelConfig.rope_inv_freq``, ``softmax_scale``). cos and sin
+    # would be scaled by ``mscale(factor, mscale) / mscale(factor,
+    # mscale_all_dim)``: validation takes the two equal, so by 1.
+    rope_scaling_type: str = ""
+    rope_scaling_factor: float = 1.0
+    rope_scaling_original_max_position: int = 0
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_scaling_mscale: float = 1.0
+    rope_scaling_mscale_all_dim: float = 0.0
+    # Manifold-constrained hyper-connections (``models/mpt.py``, training path
+    # only): ``hc_mult > 1`` residual streams in place of one. Around every
+    # sublayer three maps, made per token from all streams at once (an RMSNorm
+    # over the flattened streams with ``hc_eps``, one projection, learned
+    # scales and biases): the read-in weights (a sigmoid a stream), the
+    # write-back weights (twice a sigmoid) and the streams' mixing matrix,
+    # ``exp`` of its logits cut to ``+-hc_res_clamp`` and then
+    # ``hc_sinkhorn_iters`` rounds of column and row normalisation (each sum
+    # ``+ hc_eps``), doubly stochastic to the iteration's precision.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1.0e-6
+    hc_res_clamp: float = 30.0
     # A stack whose layers differ in kind (HF ``layer_types``, its list joined
     # by commas so that YAML, JSON and ``--set`` all spell it alike): one entry
     # a layer, ``mamba`` (a Mamba-2 mixer, ``ops/ssd.py``), ``conv`` (a gated
@@ -266,13 +297,59 @@ class ModelConfig:
                 or self.logits_scaling != 1.0 or self.attention_multiplier != 0.0)
 
     @property
+    def hyper_connected(self) -> bool:
+        """The blocks mix ``hc_mult`` residual streams (``hc_mult > 1``)."""
+        return self.hc_mult > 1
+
+    @property
+    def yarn(self) -> bool:
+        return self.rope_scaling_type == "yarn"
+
+    @property
     def training_path_only(self) -> bool:
         """Latent attention, the dropless expert layer, leading dense blocks,
-        layers of different kinds or the multipliers: what serving, cached
-        decode, LoRA and the HF maps lack."""
+        layers of different kinds, the multipliers, hyper-connected streams or
+        scaled rotary frequencies: what serving, cached decode, LoRA and the
+        HF maps lack."""
         return (self.latent_attention or self.dropless_moe or self.first_k_dense > 0
                 or self.hybrid or self.scaled or self.sparse_attention
-                or self.qk_norm or self.head_dim > 0)
+                or self.qk_norm or self.head_dim > 0 or self.hyper_connected
+                or self.yarn)
+
+    def rope_inv_freq(self, dim: int) -> tuple[float, ...] | None:
+        """The rotary inverse frequencies of ``dim`` rotary dims under YaRN
+        (``dim / 2`` numbers, static), or ``None`` for the plain
+        ``rope_theta ** (-2i / dim)`` that ``apply_rope`` makes itself."""
+        if not self.yarn:
+            return None
+        half, theta = dim // 2, self.rope_theta
+
+        def correction_dim(rotations: float) -> float:
+            return dim * math.log(self.rope_scaling_original_max_position
+                                  / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(self.rope_scaling_beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.rope_scaling_beta_slow)), dim - 1)
+        span = (high - low) or 0.001
+        out = []
+        for i in range(half):
+            plain = theta ** (-2.0 * i / dim)
+            keep = 1.0 - min(max((i - low) / span, 0.0), 1.0)
+            out.append(plain / self.rope_scaling_factor * (1.0 - keep) + plain * keep)
+        return tuple(out)
+
+    @property
+    def softmax_scale(self) -> float | None:
+        """What multiplies the scores before the softmax: ``None`` for the
+        dispatch's own ``1/sqrt(d_head)``, ``attention_multiplier`` where it
+        is set, and under YaRN ``1/sqrt(d_head)`` times the square of
+        ``mscale(factor, mscale_all_dim)``."""
+        if self.attention_multiplier:
+            return self.attention_multiplier
+        if self.yarn:
+            m = 0.1 * self.rope_scaling_mscale_all_dim * math.log(self.rope_scaling_factor) + 1.0
+            return self.d_head ** -0.5 * m * m
+        return None
 
     @property
     def d_head(self) -> int:
@@ -1116,26 +1193,91 @@ class Config:
                     "latent attention needs rope=true and an even qk_rope_head_dim")
             if m.n_kv_heads:
                 raise ValueError("latent attention has no grouped kv heads (n_kv_heads)")
-            if m.v_head_dim != m.qk_nope_head_dim + m.qk_rope_head_dim:
+            if m.v_head_dim != m.d_head and (
+                    m.attn_impl == AttnImpl.RING.value or self.mesh.sequence > 1):
                 raise ValueError(
                     f"v_head_dim={m.v_head_dim} differs from qk_nope_head_dim + "
-                    f"qk_rope_head_dim={m.qk_nope_head_dim + m.qk_rope_head_dim}: "
-                    "the attention kernels take one head width for q, k and v")
+                    f"qk_rope_head_dim={m.d_head}: ring attention (attn_impl='ring' "
+                    "/ mesh.sequence > 1) takes one head width for q, k and v")
         self._validate_hybrid_family()
         self._validate_sparse_attention_family()
+        self._validate_hyper_connected_family()
         if m.training_path_only:
             if m.lora_rank or self.photon.adapters.enabled:
                 raise ValueError(
                     "LoRA adapters (model.lora_rank / photon.adapters) are not "
                     "supported with latent attention, the dropless expert layer, "
-                    "leading dense blocks, layer_types or the multipliers: "
+                    "leading dense blocks, layer_types, the multipliers, "
+                    "hc_mult > 1 or rope_scaling_type: "
                     "their projections are not adaptable modules yet")
             if self.photon.serve.prefix_cache or self.photon.serve.enabled:
                 raise ValueError(
                     "photon.serve (and its prefix cache) is not supported with "
                     "latent attention, the dropless expert layer, leading "
-                    "dense blocks, layer_types or the multipliers: there is "
-                    "no cache or decode step for them yet")
+                    "dense blocks, layer_types, the multipliers, hc_mult > 1 or "
+                    "rope_scaling_type: there is no cache or decode step for "
+                    "them yet")
+
+    def _validate_hyper_connected_family(self) -> None:
+        """Hyper-connected residual streams and YaRN's frequencies (preset
+        ``xing4.0-29b-a4b-ep8``)."""
+        m = self.model
+        if m.rope_scaling_type not in ("", "yarn"):
+            raise ValueError(
+                f"rope_scaling_type={m.rope_scaling_type!r}: only 'yarn' (or '' "
+                "for plain rope_theta frequencies) is computed here")
+        if m.yarn:
+            if not m.rope or m.rope_scaling_factor < 1 \
+                    or m.rope_scaling_original_max_position <= 0 \
+                    or not m.rope_scaling_beta_fast > m.rope_scaling_beta_slow > 0 \
+                    or m.rope_scaling_mscale <= 0:
+                raise ValueError(
+                    "rope_scaling_type='yarn' needs rope=true, rope_scaling_factor "
+                    ">= 1, rope_scaling_original_max_position > 0, "
+                    "rope_scaling_beta_fast > rope_scaling_beta_slow > 0 and "
+                    "rope_scaling_mscale > 0")
+            if m.rope_scaling_mscale != m.rope_scaling_mscale_all_dim:
+                raise ValueError(
+                    f"rope_scaling_mscale={m.rope_scaling_mscale} differs from "
+                    f"rope_scaling_mscale_all_dim={m.rope_scaling_mscale_all_dim}: "
+                    "cos and sin would be scaled by the ratio of their mscales, "
+                    "which the rotation does not do yet")
+            if m.attention_multiplier:
+                raise ValueError(
+                    "attention_multiplier and rope_scaling_mscale_all_dim both "
+                    "set the softmax scale: state one")
+            if m.attn_impl == AttnImpl.RING.value or self.mesh.sequence > 1:
+                raise ValueError(
+                    "rope_scaling_type='yarn' is not supported with ring "
+                    "attention (attn_impl='ring' / mesh.sequence > 1): its merge "
+                    "fixes the softmax scale at 1/sqrt(d_head)")
+        elif (m.rope_scaling_factor != 1.0 or m.rope_scaling_original_max_position
+              or m.rope_scaling_mscale_all_dim):
+            raise ValueError(
+                "rope_scaling_factor / rope_scaling_original_max_position / "
+                "rope_scaling_mscale_all_dim belong to rope_scaling_type='yarn'")
+        if m.hc_mult < 1:
+            raise ValueError("hc_mult must be >= 1 (1 = one residual stream)")
+        if not m.hyper_connected:
+            return
+        if m.hc_sinkhorn_iters < 1 or m.hc_eps <= 0 or m.hc_res_clamp <= 0:
+            raise ValueError(
+                "hc_mult > 1 needs hc_sinkhorn_iters >= 1, hc_eps > 0 and "
+                "hc_res_clamp > 0")
+        if m.hybrid:
+            raise ValueError(
+                "hc_mult > 1 does not combine with layer_types: the Mamba-2 "
+                "and short-convolution mixers have no hyper-connected form here")
+        if m.residual_multiplier != 1.0:
+            raise ValueError(
+                "hc_mult > 1 does not combine with residual_multiplier: the "
+                "write-back weights scale the branch")
+        if max(self.mesh.pipe, self.mesh.tensor, self.mesh.sequence, self.mesh.expert) > 1:
+            raise ValueError(
+                "hc_mult > 1 with mesh.pipe, mesh.tensor, mesh.sequence or "
+                "mesh.expert > 1 is not supported: the pipeline schedule "
+                "carries one stream, and the maps read every stream's whole "
+                "width at each token")
 
     def _validate_sparse_attention_family(self) -> None:
         """``head_dim``, ``qk_norm`` and the indexer's sparse attention
@@ -1168,8 +1310,10 @@ class Config:
                 "grouped-query branch with rotary positions: it needs rope, "
                 "0 < n_kv_heads < n_heads, and no alibi, latent attention or "
                 "layer_types")
-        if m.attention_multiplier:
-            raise ValueError("dsa_topk > 0 fixes the softmax scale at 1/sqrt(d_head)")
+        if m.attention_multiplier or m.yarn:
+            raise ValueError(
+                "dsa_topk > 0 fixes the softmax scale at 1/sqrt(d_head) and "
+                "its indexer turns by plain rope_theta frequencies")
         if m.attn_impl == AttnImpl.RING.value or self.mesh.sequence > 1 \
                 or self.mesh.tensor > 1 or self.mesh.pipe > 1:
             raise ValueError(
@@ -1895,6 +2039,8 @@ def refuse_training_only_family(model: ModelConfig, what: str) -> None:
         ("the indexer's sparse attention (dsa_topk > 0)", model.sparse_attention),
         ("per-head q/k norms (qk_norm)", model.qk_norm),
         ("heads of their own width (head_dim)", model.head_dim > 0),
+        ("hyper-connected residual streams (hc_mult > 1)", model.hyper_connected),
+        ("YaRN's rotary frequencies (rope_scaling_type)", model.yarn),
     ) if on]
     if has:
         raise NotImplementedError(

@@ -48,6 +48,18 @@ others the sigmoid-routed dropless expert layer; every run of layers equal in
 mixer and in MLP kind a scanned stack of its own (``ModelConfig.stacks``), so
 the model has two expert stacks, each with its own selection bias.
 
+Hyper-connected residual streams compose with the latent-attention / expert
+family (preset ``xing4.0-29b-a4b-ep8``, training path only): ``hc_mult > 1``
+makes the blocks' carry ``hc_mult`` streams ``[B, S, D]`` (a tuple: each is an
+activation like any other, and no pass copies them into one array), and each
+of a block's two residual adds becomes a read-in, a write-back and a mixing of
+the streams by three per-token maps (``_hc_read_in`` / ``_write_back``; the
+mixing matrix is Sinkhorn-projected onto the doubly stochastic ones). The
+embedding enters every stream and their sum leaves for ``ln_f``. Latent
+attention's v may be narrower than its q and k (``v_head_dim``), and
+``rope_scaling_type: yarn`` gives the rotation YaRN's blended frequencies and
+the softmax its scale. At ``hc_mult == 1`` none of it adds an operation.
+
 TPU-first design choices (not in the reference):
 - Layers are stacked with ``nn.scan`` → one traced block, params carry a
   leading ``[n_layers, ...]`` axis. This keeps compile time flat in depth and
@@ -62,7 +74,7 @@ TPU-first design choices (not in the reference):
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -82,6 +94,9 @@ from photon_tpu.utils.profiling import (
     MAMBA_GATE_NORM_SCOPE,
     MAMBA_PROJ_SCOPE,
     MAMBA_SCAN_SCOPE,
+    MHC_MAPS_SCOPE,
+    MHC_READ_IN_SCOPE,
+    MHC_WRITE_BACK_SCOPE,
     SHORTCONV_MIX_SCOPE,
     SHORTCONV_PROJ_SCOPE,
 )
@@ -161,14 +176,20 @@ def _norm(cfg: ModelConfig, name: str) -> nn.Module:
     return FP32LayerNorm(use_bias=not cfg.no_bias, eps=cfg.norm_eps, name=name)
 
 
-def apply_rope(q: jax.Array, k: jax.Array, theta: float) -> tuple[jax.Array, jax.Array]:
+def apply_rope(q: jax.Array, k: jax.Array, theta: float,
+               inv_freq: tuple[float, ...] | None = None) -> tuple[jax.Array, jax.Array]:
     """Rotary positions on ``[B, S, H, D]`` q/k (llama/GPT-NeoX rotate-half
     convention, angles in fp32). Positions are LOGICAL sequence indices, so
     the rotation is correct under a GSPMD-sharded ``sequence`` mesh axis —
-    ring attention receives already-rotated q/k and needs no offset."""
+    ring attention receives already-rotated q/k and needs no offset.
+    ``inv_freq`` (``D / 2`` static numbers) stands in for ``theta``'s own
+    frequencies: YaRN's (``ModelConfig.rope_inv_freq``)."""
     d = q.shape[-1]
     half = d // 2
-    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(q.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.cos(ang)[None, :, None, :]  # [1, S, 1, half]
     sin = jnp.sin(ang)[None, :, None, :]
@@ -219,6 +240,129 @@ def _residual(cfg: ModelConfig, x: jax.Array, branch: jax.Array) -> jax.Array:
     the other models' ``op_name``s stay as they were.)"""
     m = cfg.residual_multiplier
     return x + branch if m == 1.0 else x + branch * jnp.asarray(m, branch.dtype)
+
+
+#: the mixing logits' diagonal at the start: after the projection stream j
+#: keeps e^4 / (e^4 + n - 1) of itself (0.948 of four streams)
+HC_RES_INIT = 4.0
+#: the three learned scales (on the read-in, write-back and mixing logits'
+#: data-dependent part) at the start
+HC_ALPHA_INIT = 0.01
+
+
+class _HyperConnection(NamedTuple):
+    """What a sublayer's write-back takes from its read-in: the streams it
+    read and the two maps that are not yet used, tokens on the last axis."""
+
+    streams: tuple[jax.Array, ...]  # n x [B, S, D]
+    post: jax.Array  # [n, N] float32: the branch's weight into stream j
+    res: jax.Array  # [n, n, N] float32: stream i's weight into stream j, [j, i]
+
+
+def _hc_bias_init(n: int):
+    """``b`` of a sublayer's maps, ``[pre | post | res]``: the read-in weights
+    start at ``1/n`` each (``sigmoid(-ln(n - 1))``), the write-back weights at
+    1 (``2 sigmoid(0)``), the mixing logits at ``HC_RES_INIT`` on the diagonal
+    and 0 off it, so that the projected matrix starts near the identity."""
+    def init(key, shape, dtype):
+        del key
+        b = jnp.concatenate([jnp.full((n,), -jnp.log(n - 1.0)), jnp.zeros((n,)),
+                             HC_RES_INIT * jnp.eye(n).reshape(-1)])
+        return b.reshape(shape).astype(dtype)
+
+    return init
+
+
+def _hc_maps(cfg: ModelConfig, streams, phi, b, alpha):
+    """The three maps of one sublayer from its streams (``n`` x ``[B, S, D]``),
+    all float32 with the tokens on the last (lane) axis: ``pre [n, N]`` in
+    (0, 1), ``post [n, N]`` in (0, 2), ``res [n, n, N]`` doubly stochastic to
+    the iteration's precision, and the largest distance of one of ``res``'s
+    row or column sums from 1. ``r = vec(X) / rms(vec(X))`` over all ``n D``
+    values of a token (no gain: it would fold into ``phi``); ``[p | q | R] = r
+    phi``; ``alpha * . + b``; a sigmoid, twice a sigmoid, and for ``R`` the
+    ``exp`` of its clamped logits, then ``hc_sinkhorn_iters`` times columns
+    and rows divided by their sums (``+ hc_eps``). The loops are unrolled and
+    the sums over four slices are written out, so that all of it is
+    elementwise on ``[.., N]`` arrays and differentiates as such."""
+    n, eps = len(streams), cfg.hc_eps
+    bsz, s, d = streams[0].shape
+    tokens = bsz * s
+    f32 = jnp.float32
+    squares = sum(jnp.sum(jnp.square(x.astype(f32)), axis=-1) for x in streams)
+    inv_rms = jax.lax.rsqrt(squares / (n * d) + eps).reshape(1, tokens)
+    phi = phi.astype(f32).reshape(phi.shape[0], n, d)
+    raw = sum(jnp.einsum("kd,bsd->kbs", phi[:, i], x.astype(f32)) for i, x in enumerate(streams))
+    raw = raw.reshape(-1, tokens) * inv_rms
+    alpha, b = alpha.astype(f32), b.astype(f32)[:, None]
+    pre = jax.nn.sigmoid(alpha[0] * raw[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * raw[n:2 * n] + b[n:2 * n])
+    logits = (alpha[2] * raw[2 * n:] + b[2 * n:]).reshape(n, n, tokens)
+    m = jnp.exp(jnp.clip(logits, -cfg.hc_res_clamp, cfg.hc_res_clamp))
+
+    def columns(m):  # [n, N]: column i's sum over the rows j
+        return sum(m[j] for j in range(n))
+
+    def rows(m):  # [n, N]: row j's sum over the columns i
+        return sum(m[:, i] for i in range(n))
+
+    for _ in range(cfg.hc_sinkhorn_iters):
+        m = m / (columns(m) + eps)[None]
+        m = m / (rows(m) + eps)[:, None]
+    gap = jnp.maximum(jnp.max(jnp.abs(columns(m) - 1.0)), jnp.max(jnp.abs(rows(m) - 1.0)))
+    return pre, post, m, gap
+
+
+def _per_token(w: jax.Array, like: jax.Array) -> jax.Array:
+    """A map's ``[N]`` float32 weights against ``like [B, S, D]``."""
+    return w.reshape(*like.shape[:2], 1)
+
+
+def _hc_read_in(block: nn.Module, x, name: str):
+    """A sublayer's input and what its write-back needs. One residual stream
+    (``hc_mult == 1``): ``x`` itself and ``None``, no operation. Hyper-
+    connected, ``x`` is the ``n`` streams: the sublayer's maps from the
+    parameters ``{name}_phi [2n + n^2, n D]``, ``{name}_b``, ``{name}_alpha``
+    (float32; ``phi`` keeps the tokens' ``n D`` values on its lane axis), the
+    read-in ``u = sum_i pre_i X_i`` in the streams' dtype, and the
+    :class:`_HyperConnection`. The mixing matrix's distance from doubly
+    stochastic is sown (``mhc_sinkhorn_gap``)."""
+    cfg = block.cfg
+    if not cfg.hyper_connected:
+        return x, None
+    n, d = cfg.hc_mult, cfg.d_model
+    k = 2 * n + n * n
+    phi = block.param(f"{name}_phi", nn.initializers.normal(stddev=cfg.emb_init_std),
+                      (k, n * d), _dtype(cfg.param_dtype))
+    b = block.param(f"{name}_b", _hc_bias_init(n), (k,), jnp.float32)
+    alpha = block.param(f"{name}_alpha", nn.initializers.constant(HC_ALPHA_INIT),
+                        (3,), jnp.float32)
+    with jax.named_scope(MHC_MAPS_SCOPE):
+        pre, post, res, gap = _hc_maps(cfg, x, phi, b, alpha)
+        block.sow("intermediates", "mhc_sinkhorn_gap", gap)
+    with jax.named_scope(MHC_READ_IN_SCOPE):
+        u = sum(_per_token(pre[i], xi) * xi.astype(jnp.float32) for i, xi in enumerate(x))
+        u = u.astype(x[0].dtype)
+    return u, _HyperConnection(tuple(x), post, res)
+
+
+def _write_back(cfg: ModelConfig, x: jax.Array, branch: jax.Array,
+                hc: _HyperConnection | None):
+    """The sublayer's output into the residual path. One stream (``hc`` is
+    ``None``): :func:`_residual`. Hyper-connected: the new streams ``X'_j =
+    sum_i res[j, i] X_i + post_j branch``, float32 sums rounded to the
+    streams' dtype, under a scope of their own (call it outside the scope of
+    the branch's last projection: no operation carries two readers' scopes)."""
+    if hc is None:
+        return _residual(cfg, x, branch)
+    with jax.named_scope(MHC_WRITE_BACK_SCOPE):
+        f32 = jnp.float32
+        y = branch.astype(f32)
+        wide = [xi.astype(f32) for xi in hc.streams]
+        return tuple(
+            (sum(_per_token(hc.res[j, i], xi) * xi for i, xi in enumerate(wide))
+             + _per_token(hc.post[j], y) * y).astype(hc.streams[j].dtype)
+            for j in range(len(wide)))
 
 
 class MPTBlock(nn.Module):
@@ -291,9 +435,9 @@ class MPTBlock(nn.Module):
             return dense(cfg.d_model, "out_proj", resid_std)(y)
 
     def _latent_qkv(self, h: jax.Array, dense):
-        """MLA in its training form: ``h [B, S, D]`` -> q, k, v
-        ``[B, S, H, d_head]``. q through a low-rank pair with an RMSNorm
-        between; one projection gives the kv latent and a rotary key that all
+        """MLA in its training form: ``h [B, S, D]`` -> q, k ``[B, S, H,
+        d_head]`` and v ``[B, S, H, v_head_dim]``. q through a low-rank pair
+        with an RMSNorm between; one projection gives the kv latent and a rotary key that all
         heads share; the normed latent expands to per-head ``k_nope | v``.
         RoPE turns the rope part of q and the shared key; a head's key is
         ``[k_nope_h | k_rope]``."""
@@ -311,7 +455,8 @@ class MPTBlock(nn.Module):
         kv = dense(cfg.n_heads * (nope + dv), "kv_b_proj", std)(c_kv)
         kv = kv.reshape(b, s, cfg.n_heads, nope + dv)
         q_rope, k_rope = apply_rope(
-            q[..., nope:], kv_a[..., None, cfg.kv_lora_rank:], cfg.rope_theta)
+            q[..., nope:], kv_a[..., None, cfg.kv_lora_rank:], cfg.rope_theta,
+            cfg.rope_inv_freq(rope))
         q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, cfg.n_heads, rope))],
@@ -412,7 +557,7 @@ class MPTBlock(nn.Module):
         return out
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x):
         cfg = self.cfg
         compute = _dtype(cfg.compute_dtype)
         dense = lambda feats, name, init_std: nn.Dense(  # noqa: E731
@@ -451,6 +596,9 @@ class MPTBlock(nn.Module):
         resid_std = cfg.emb_init_std / (2.0 * cfg.n_layers) ** 0.5
 
         # --- the mixer: attention, or another kind in its place ---
+        # (hyper-connected, ``x`` comes in as the streams, each sublayer reads
+        # one input out of them and writes its branch back into all of them)
+        x, hc = _hc_read_in(self, x, "hc_1")
         with jax.named_scope(BLOCK_NORM_SCOPE):
             h = _norm(cfg, "ln_1")(x)
         if self.mixer == "mamba":
@@ -487,7 +635,7 @@ class MPTBlock(nn.Module):
                 # before the kv repeat: the rotation is per-head-identical, so
                 # rotating n_kv heads then replicating equals the reverse order
                 with jax.named_scope(ATTN_PROJ_SCOPE):
-                    q, k = apply_rope(q, k, cfg.rope_theta)
+                    q, k = apply_rope(q, k, cfg.rope_theta, cfg.rope_inv_freq(cfg.d_head))
             # k/v go to the dispatch at their native n_kv width: the pallas
             # flash kernel consumes GQA groups directly (index-mapped kv rows,
             # no repeated tensor in HBM); the xla/ring paths replicate inside
@@ -499,24 +647,31 @@ class MPTBlock(nn.Module):
                     q, k, v,
                     impl=cfg.attn_impl, causal=True, alibi=cfg.alibi,
                     interpret=cfg.attn_interpret,
-                    # 0 -> None: the dispatch's own 1/sqrt(d_head)
-                    scale=cfg.attention_multiplier or None,
+                    # None: the dispatch's own 1/sqrt(d_head)
+                    scale=cfg.softmax_scale,
                 )
             if cfg.latent_attention:
                 with jax.named_scope(MLA_PROJ_SCOPE):
-                    x = _residual(cfg, x, dense(cfg.d_model, "out_proj", resid_std)(
-                        attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim)))
+                    branch = dense(cfg.d_model, "out_proj", resid_std)(
+                        attn_out.reshape(b, s, cfg.n_heads * cfg.v_head_dim))
+                    if hc is None:
+                        x = _residual(cfg, x, branch)
             else:
                 with jax.named_scope(ATTN_PROJ_SCOPE):
                     attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.d_head)
-                    x = _residual(cfg, x, adapted(cfg.d_model, "out_proj", resid_std, attn_out))
+                    branch = adapted(cfg.d_model, "out_proj", resid_std, attn_out)
+                    if hc is None:
+                        x = _residual(cfg, x, branch)
+            if hc is not None:
+                x = _write_back(cfg, x, branch, hc)
 
         # --- MLP ---
+        x, hc = _hc_read_in(self, x, "hc_2")
         hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * cfg.d_model
         if self.dense_mlp:
             hidden = cfg.dense_mlp_hidden_size
         elif cfg.dropless_moe:
-            return _residual(cfg, x, self._dropless_moe(x, dense, hidden, resid_std))
+            return _write_back(cfg, x, self._dropless_moe(x, dense, hidden, resid_std), hc)
         with jax.named_scope(BLOCK_NORM_SCOPE):
             h = _norm(cfg, "ln_2")(x)
         if cfg.mlp == "moe" and not self.dense_mlp:
@@ -559,7 +714,7 @@ class MPTBlock(nn.Module):
             moe_out = _constrain_activation(
                 moe_out, P(("data", "fsdp", "expert"), "sequence", None)
             )
-            return _residual(cfg, x, moe_out)
+            return _write_back(cfg, x, moe_out, hc)
         # the non-expert MLP under one scope: the products, the activation
         # between them (no module's name is on it) and the residual add
         with jax.named_scope(BLOCK_MLP_SCOPE):
@@ -575,7 +730,10 @@ class MPTBlock(nn.Module):
             else:
                 h = adapted(hidden, "up_proj", cfg.emb_init_std, h)
                 h = nn.gelu(h, approximate=True)
-            return _residual(cfg, x, adapted(cfg.d_model, "down_proj", resid_std, h))
+            branch = adapted(cfg.d_model, "down_proj", resid_std, h)
+            if hc is None:
+                return _residual(cfg, x, branch)
+        return _write_back(cfg, x, branch, hc)
 
 
 class _ScanBlock(nn.Module):
@@ -587,7 +745,7 @@ class _ScanBlock(nn.Module):
     mixer: str = "attention"
 
     @nn.compact
-    def __call__(self, carry: jax.Array, _: None):
+    def __call__(self, carry, _: None):
         return MPTBlock(self.cfg, self.dense_mlp, self.mixer, name="block")(carry), None
 
 
@@ -651,8 +809,13 @@ class MPTModel(nn.Module):
 
         # leading dense blocks, and with ``layer_types`` every run of equal
         # mixer and MLP kind, under a scan of their own, in order
+        if cfg.hyper_connected:  # the embedding enters every stream
+            x = (x,) * cfg.hc_mult
         for name, mixer, dense_mlp, length in cfg.stacks:
             x, _ = stack(length, name, dense_mlp, mixer)(x, None)
+        if cfg.hyper_connected:  # and their sum leaves
+            with jax.named_scope(MHC_READ_IN_SCOPE):
+                x = sum(xi.astype(jnp.float32) for xi in x).astype(compute)
 
         with jax.named_scope(BLOCK_NORM_SCOPE):
             x = _norm(cfg, "ln_f")(x)

@@ -6,8 +6,9 @@ The reference selects between CUDA flash-attention and a plain torch path via
 kernel (``attn_impl=pallas``) or a pure-XLA softmax attention
 (``attn_impl=xla``) that XLA fuses itself.
 
-All shapes are ``[batch, seq, heads, d_head]``; softmax runs in fp32
-regardless of input dtype.
+All shapes are ``[batch, seq, heads, d_head]`` (v, and with it the output, may
+have a head width of its own: latent attention's 128 beside q / k's 192);
+softmax runs in fp32 regardless of input dtype.
 """
 
 from __future__ import annotations

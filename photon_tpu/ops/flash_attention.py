@@ -47,6 +47,10 @@ Design notes (TPU-first):
   (SURVEY.md §5 long-context note).
 - ``d_head`` is zero-padded to the 128-lane width when smaller (padding
   columns contribute nothing to scores or outputs).
+- v may be narrower (or wider) than q and k (latent attention: q/k 192, v
+  128): v, o, dO, dv and the output accumulator then take v's own padded
+  width, q, k, dq and dk theirs, and ``launch_vmem_bytes`` / ``pick_tiles``
+  reckon both; nothing is padded from one width to the other in HBM.
 
 Backward follows FlashAttention-2: a precomputed ``delta = rowsum(dO·O)``,
 one kernel accumulating dq over k blocks, one accumulating dk/dv over q
@@ -275,9 +279,11 @@ def _run_tile(compute, by: str, q_blk, k_blk, block_q: int, block_k: int, *,
             compute(_strips(block_q, sub, by), guard=False)
 
 
-def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize: int) -> int:
+def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize: int,
+                      d_v: int | None = None) -> int:
     """VMEM one launch (``fwd``, ``dq`` or ``dkv``) needs at a tile, from the
-    kernel's own buffers: every BlockSpec'd operand and result twice (the
+    kernel's own buffers (``d`` the padded width of q and k, ``d_v`` of v,
+    ``None`` = the same): every BlockSpec'd operand and result twice (the
     pipeline double-buffers them), the scratch accumulators, the fp32
     temporaries as large as an operand (the forward's ``pv`` and rescaled
     accumulator, the backward bodies' upcasts), and the ``[block_q, block_k]``
@@ -294,22 +300,25 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
     most a ``sub / block`` part of the tile's, its ``pv`` / ``dq`` parts
     ``[sub, d]``; the upcasts are made once a tile and sliced, as large as
     the whole-tile body's."""
-    q_rows = block_q * d * itemsize  # one q-shaped block: q, o, do, dq
-    k_rows = block_k * d * itemsize  # one k-shaped block: k, v, dk, dv
+    d_v = d if d_v is None else d_v
+    q_rows = block_q * d * itemsize  # one q-shaped block: q, dq
+    o_rows = block_q * d_v * itemsize  # one o-shaped block: o, do
+    k_rows = block_k * d * itemsize  # one k-shaped block: k, dk
+    v_rows = block_k * d_v * itemsize  # one v-shaped block: v, dv
     row_stats = SUBLANE * block_q * 4  # one lse / delta block
     slopes = SUBLANE * LANE * 4
     if launch == "fwd":
-        piped = 2 * q_rows + 2 * k_rows + row_stats  # q, o; k, v; lse
-        scratch = 2 * block_q * LANE * 4 + block_q * d * 4  # m, l; acc
-        upcast = 2 * block_q * d * 4  # pv, acc * alpha
+        piped = q_rows + o_rows + k_rows + v_rows + row_stats  # q, o; k, v; lse
+        scratch = 2 * block_q * LANE * 4 + block_q * d_v * 4  # m, l; acc
+        upcast = 2 * block_q * d_v * 4  # pv, acc * alpha
     elif launch == "dq":
-        piped = 3 * q_rows + 2 * k_rows + 2 * row_stats  # q, do, dq; k, v
+        piped = 2 * q_rows + o_rows + k_rows + v_rows + 2 * row_stats  # q, dq, do; k, v
         scratch = block_q * d * 4
-        upcast = (block_q + block_k) * d * 4  # do, v
+        upcast = (block_q + block_k) * d_v * 4  # do, v
     elif launch == "dkv":
-        piped = 2 * q_rows + 4 * k_rows + 2 * row_stats  # q, do; k, v, dk, dv
-        scratch = 2 * block_k * d * 4
-        upcast = (2 * block_q + block_k) * d * 4  # do, q, v
+        piped = q_rows + o_rows + 2 * k_rows + 2 * v_rows + 2 * row_stats  # q, do; k, dk, v, dv
+        scratch = block_k * (d + d_v) * 4
+        upcast = block_q * (d + d_v) * 4 + block_k * d_v * 4  # q, do, v
     else:
         raise ValueError(f"unknown launch {launch!r}")
     scores = int(SCORE_TEMPS * block_q * block_k * 4)
@@ -366,7 +375,7 @@ def _tile_sizes(s: int) -> list[int]:
 def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 1, *,
                causal: bool = True, offset: int | None = None,
                block_q: int | None = None, block_k: int | None = None,
-               vmem_budget: int = VMEM_BUDGET) -> TilePlan:
+               vmem_budget: int = VMEM_BUDGET, d_v_pad: int | None = None) -> TilePlan:
     """``(block_q, block_k)`` of the forward, dq and dk/dv launches, from the
     shapes alone: for each launch the largest tile (by area) that divides
     both sequences, stays inside ``vmem_budget`` by :func:`launch_vmem_bytes`,
@@ -378,7 +387,10 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
     An explicit ``block_q`` / ``block_k`` pins that side for all three
     launches, as given (``min(block, s)``; it must divide, or ``ValueError``).
     ``n_kv_group`` is the grouped-query group: dk/dv sweeps it too, so its
-    tile counts are per kv head times the group."""
+    tile counts are per kv head times the group. ``d_v_pad`` is v's padded
+    width where it is not q's and k's (``d_pad``): it enters the VMEM
+    estimate; the ladder's top follows ``d_pad``, the width of the score
+    products, where it was read."""
     qs = _tile_sizes(s_q) if block_q is None else [min(block_q, s_q)]
     ks = _tile_sizes(s_k) if block_k is None else [min(block_k, s_k)]
     if s_q % qs[0] or s_k % ks[0]:  # only a pinned block can fail to divide
@@ -388,7 +400,7 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
     for launch in TilePlan._fields:
         narrow = min(TILE_LADDER_WIDTH[launch], d_pad)  # a wider head: fewer rows
         top_q, top_k = (top * narrow // d_pad for top in TILE_LADDER_TOP[launch])
-        sized = [(launch_vmem_bytes(launch, bq, bk, d_pad, itemsize), bq, bk)
+        sized = [(launch_vmem_bytes(launch, bq, bk, d_pad, itemsize, d_v_pad), bq, bk)
                  for bq in qs for bk in ks]
         # largest area first; of two equal areas the longer k block (fewer
         # steps of the forward's and dq's inner sweep)
@@ -556,7 +568,7 @@ def _kv_row(h_q: int, h_kv: int):
 def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
          h_q=0, interpret=False):
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
+    s_k, d_v = k.shape[1], v.shape[2]  # v, o and the accumulator at v's width
     n_q = pl.cdiv(s_q, block_q)
     n_k = pl.cdiv(s_k, block_k)
     grid = (bh, n_q, n_k)
@@ -578,7 +590,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), kj(i, j), 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), kj(i, j), 0)),
+        pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (kv(b), kj(i, j), 0)),
     ]
     inputs = [q, k, v]
     if slopes is not None:
@@ -587,7 +599,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
     # lse carries SUBLANE redundant rows so its (1, 8, block_q) blocks are
     # exactly one fp32 tile; callers use row 0
     out_shape = [
-        jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+        jax.ShapeDtypeStruct((bh, s_q, d_v), q.dtype),
         jax.ShapeDtypeStruct((bh, SUBLANE, s_q), jnp.float32),
     ]
     launch = pl.pallas_call(
@@ -595,17 +607,17 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),
         ],
         scratch_shapes=[] if lone else [
             pltpu.VMEM((block_q, LANE), jnp.float32),  # running max
             pltpu.VMEM((block_q, LANE), jnp.float32),  # running denom
-            pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         out_shape=out_shape,
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("fwd", block_q, block_k, d, q.dtype.itemsize)),
+        **_vmem_params(launch_vmem_bytes("fwd", block_q, block_k, d, q.dtype.itemsize, d_v)),
     )
     with _kernel_scope("flash_fwd"):
         o, lse = launch(*inputs)
@@ -737,7 +749,7 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
     """``dq_tile`` / ``dkv_tile``: each launch's own ``(block_q, block_k)``."""
     q, k, v, o, lse = res
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
+    s_k, d_v = k.shape[1], v.shape[2]  # v, do and dv at v's width
     offset = s_k - s_q
     itemsize = q.dtype.itemsize
     bh_k = k.shape[0]
@@ -771,8 +783,8 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), kj(i, j), 0)),  # k
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), kj(i, j), 0)),  # v
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # do
+            pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (kv(b), kj(i, j), 0)),  # v
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),  # do
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),  # lse
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),  # delta
         ] + slope_spec,
@@ -780,7 +792,7 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("dq", block_q, block_k, d, itemsize)),
+        **_vmem_params(launch_vmem_bytes("dq", block_q, block_k, d, itemsize, d_v)),
     )
     with _kernel_scope("flash_dq"):
         dq = launch_dq(q, k, v, do, lse_b, delta_b, *extra_inputs)
@@ -811,8 +823,8 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, t: (qrow(b, t), qi(j, t), 0)),  # q
             pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),  # k
-            pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),  # v
-            pl.BlockSpec((1, block_q, d), lambda b, j, t: (qrow(b, t), qi(j, t), 0)),  # do
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, t: (b, j, 0)),  # v
+            pl.BlockSpec((1, block_q, d_v), lambda b, j, t: (qrow(b, t), qi(j, t), 0)),  # do
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, j, t: (qrow(b, t), 0, qi(j, t))),  # lse
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, j, t: (qrow(b, t), 0, qi(j, t))),  # delta
         ] + (
@@ -821,18 +833,18 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         ),
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, t: (b, j, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh_k, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh_k, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh_k, s_k, d_v), v.dtype),
         ],
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("dkv", block_q, block_k, d, itemsize)),
+        **_vmem_params(launch_vmem_bytes("dkv", block_q, block_k, d, itemsize, d_v)),
     )
     with _kernel_scope("flash_dkv"):
         dk, dv = launch_dkv(q, k, v, do, lse_b, delta_b, *extra_inputs)
@@ -898,6 +910,9 @@ def flash_attention(
     onto its kv group row, so the repeated-kv tensor is never materialized
     in HBM (fwd reads and bwd dk/dv are kv-row-major).
 
+    ``v`` may have a head width of its own (``[batch, seq, heads, d_v]``):
+    the output has it too, and each width is padded to whole lanes by itself.
+
     ``alibi`` adds the per-head linear distance bias in-kernel. Slopes
     default to ``ops/attention.py:alibi_slopes(h)``; a head-sharded
     (tensor-parallel) caller passes ``alibi_slopes`` — its LOCAL [h] slice
@@ -913,17 +928,18 @@ def flash_attention(
         # the kv row map is derived from k's width and applied to v — a
         # mismatch would silently read the wrong heads
         raise ValueError(f"k has {h_kv} heads but v has {v.shape[2]}")
-    s_k = k.shape[1]
+    s_k, d_v = k.shape[1], v.shape[3]
     scale = 1.0 / (d**0.5) if scale is None else float(scale)
 
     d_pad = lane_padded(d)
     tiles = pick_tiles(s_q, s_k, d_pad, q.dtype.itemsize, h // h_kv, causal=causal,
-                       block_q=block_q, block_k=block_k).blocks
+                       block_q=block_q, block_k=block_k, d_v_pad=lane_padded(d_v)).blocks
 
     def to_bh(x, s, heads):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * heads, s, d)
-        if d_pad != d:
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, d_pad - d)))
+        width = x.shape[3]
+        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * heads, s, width)
+        if lane_padded(width) != width:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, lane_padded(width) - width)))
         return x
 
     qb, kb, vb = to_bh(q, s_q, h), to_bh(k, s_k, h_kv), to_bh(v, s_k, h_kv)
@@ -935,7 +951,7 @@ def flash_attention(
         slopes = _bh_slopes(h_slopes.astype(jnp.float32), b * h)
     ob = _flash(qb, kb, vb, slopes, scale, causal, tiles, interpret,
                 h if h_kv != h else 0)
-    o = ob[..., :d].reshape(b, h, s_q, d)
+    o = ob[..., :d_v].reshape(b, h, s_q, d_v)
     return jnp.transpose(o, (0, 2, 1, 3))
 
 

@@ -65,6 +65,10 @@ _RULES: list[tuple[str, P]] = [
     (r"in_proj/kernel$", P("pipe", "fsdp", None)),
     (r"(conv_kernel|conv_bias|A_log|dt_bias|D)$", P("pipe")),
     (r"mamba_norm/scale$", P("pipe")),
+    # hyper-connected residual streams (models/mpt.py): a sublayer's maps read
+    # every stream's whole width at each token through `phi [2n + n^2, n D]`,
+    # which with its bias and three scales stays whole (1.4 MB a sublayer)
+    (r"hc_[12]_(phi|b|alpha)$", P("pipe")),
     # `up_proj` etc. also match the dropless layer's shared expert
     # (`shared_up_proj`, ...): the same column / row convention
     (r"(wqkv|up_proj|gate_proj|q_proj|k_proj|v_proj)/kernel$", P("pipe", "fsdp", "tensor")),

@@ -24,6 +24,7 @@ from photon_tpu.utils.profiling import (
     DSA_PICKED_PAIRS,
     DSA_TILES_VISITED,
     GRAD_NORM_SCOPE,
+    MHC_SINKHORN_GAP,
     MOE_DISPATCH_ROWS_MOVED,
     MOE_DISPATCH_ROWS_STATIC,
     MOE_MAX_EXPERT_LOAD,
@@ -214,9 +215,24 @@ def collect_dsa_counters(variables: Any) -> dict[str, jax.Array]:
     return out
 
 
+def collect_mhc_counters(variables: Any) -> dict[str, jax.Array]:
+    """The hyper-connected blocks' sows as the step's one counter: the largest
+    distance from 1 of a row or column sum of any sublayer's mixing matrix.
+    Empty for every other model."""
+    gaps = [jnp.max(jnp.asarray(leaf, jnp.float32))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {})
+            if any(getattr(k, "key", None) == "mhc_sinkhorn_gap" for k in path)]
+    return {MHC_SINKHORN_GAP: jnp.max(jnp.stack(gaps))} if gaps else {}
+
+
+#: the counters that are the worst of their layers and microbatches, not a sum
+_WORST = (MOE_MAX_EXPERT_LOAD, MHC_SINKHORN_GAP)
+
+
 def _merge_counters(a: dict, b: dict) -> dict:
-    """Two microbatches' counters as one step's: rows add, the load is the worst."""
-    return {k: jnp.maximum(a[k], b[k]) if k == MOE_MAX_EXPERT_LOAD
+    """Two microbatches' counters as one step's: rows add, the load and the
+    mixing matrices' gap are the worst."""
+    return {k: jnp.maximum(a[k], b[k]) if k in _WORST
             else jax.tree.map(jnp.add, a[k], b[k]) for k in a}
 
 
@@ -226,7 +242,8 @@ def _apply_collecting_aux(model: MPTModel, params, tokens, **kwargs):
     dropless layers' counters. The MoE blocks sow per-layer terms into
     ``intermediates`` (``models/mpt.py``); plain inference applies leave the
     collection immutable, so sow is a no-op there."""
-    if model.cfg.mlp != "moe" and not model.cfg.sparse_attention:
+    cfg = model.cfg
+    if cfg.mlp != "moe" and not cfg.sparse_attention and not cfg.hyper_connected:
         return (model.apply({"params": params}, tokens, **kwargs),
                 jnp.zeros([], jnp.float32), {})
     out, variables = model.apply(
@@ -239,6 +256,8 @@ def _apply_collecting_aux(model: MPTModel, params, tokens, **kwargs):
         dsa = collect_dsa_counters(sown)
         aux = aux + dsa[DSA_INDEX_LOSS]
         counters = {**counters, **dsa}
+    if cfg.hyper_connected:
+        counters = {**counters, **collect_mhc_counters(sown)}
     return out, aux, counters
 
 
@@ -332,6 +351,8 @@ def make_train_step(
             if model.cfg.sparse_attention:
                 zero_counters.update({k: jnp.zeros([], jnp.float32) for k in (
                     DSA_PICKED_PAIRS, DSA_TILES_VISITED, DSA_INDEX_LOSS)})
+            if model.cfg.hyper_connected:
+                zero_counters[MHC_SINKHORN_GAP] = jnp.zeros([], jnp.float32)
             (loss_sum, grad_sum, counters), _ = jax.lax.scan(
                 body, (jnp.zeros([], jnp.float32), zero_grads, zero_counters), micro)
             loss = loss_sum / n_microbatches

@@ -51,12 +51,14 @@ from photon_tpu.utils.profiling import (
     DSA_PICKED_PAIRS,
     DSA_TILES_CAUSAL,
     DSA_TILES_VISITED,
+    MHC_SINKHORN_GAP,
     MOE_DISPATCH_ROWS_MOVED,
     MOE_DISPATCH_ROWS_STATIC,
     MOE_MAX_EXPERT_LOAD,
     MOE_ROWS_HELD,
     TRAINER_DSA_SPAN,
     TRAINER_FENCE_SPAN,
+    TRAINER_MHC_SPAN,
     TRAINER_MOE_LOAD_SPAN,
     TRAINER_GET_PARAMETERS_SPAN,
     TRAINER_NEXT_BATCH_SPAN,
@@ -84,9 +86,11 @@ def _flash_tile_attrs(model_cfg) -> dict[str, str]:
 
         return {"flash_tiles": " ".join(
             f"{n}={bq}x{bk}" for n, (bq, bk) in zip(LAUNCHES, plan_tiles(s, s)))}
+    d_v = model_cfg.v_head_dim if model_cfg.latent_attention else model_cfg.d_head
     return pick_tiles(
         s, s, lane_padded(model_cfg.d_head), jnp.dtype(model_cfg.compute_dtype).itemsize,
         model_cfg.n_heads // (model_cfg.n_kv_heads or model_cfg.n_heads),
+        d_v_pad=lane_padded(d_v),
     ).attrs()
 
 
@@ -104,6 +108,15 @@ def _conv_attrs(model_cfg) -> dict[str, int]:
     """The step's gated short-convolution layers, as a span attribute: a
     static count. Empty for a model without such layers."""
     return {"conv_layers": model_cfg.conv_layers} if model_cfg.conv_layers else {}
+
+
+def _mhc_attrs(model_cfg) -> dict[str, int]:
+    """The residual streams of the step's hyper-connected blocks and the
+    sublayers whose maps, read-in and write-back it runs (two a layer), as
+    span attributes: static counts. Empty for a model with one stream."""
+    if not model_cfg.hyper_connected:
+        return {}
+    return {"mhc_streams": model_cfg.hc_mult, "mhc_sublayers": 2 * model_cfg.n_layers}
 
 
 def _dsa_attrs(model_cfg) -> dict[str, int]:
@@ -207,6 +220,7 @@ class Trainer:
         self._kernel_attrs = {**_flash_tile_attrs(self.model.cfg),
                               **_mamba_attrs(self.model.cfg),
                               **_conv_attrs(self.model.cfg),
+                              **_mhc_attrs(self.model.cfg),
                               **_dsa_attrs(self.model.cfg)}
         self.tx, self.lr_schedule = build_optimizer(cfg.optimizer, cfg.scheduler)
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)
@@ -524,6 +538,10 @@ class Trainer:
                             max_expert_load=last_metrics[MOE_MAX_EXPERT_LOAD],
                             dispatch_rows_moved=last_metrics[MOE_DISPATCH_ROWS_MOVED],
                             dispatch_rows_static=last_metrics[MOE_DISPATCH_ROWS_STATIC]):
+                        pass
+                if MHC_SINKHORN_GAP in last_metrics:
+                    with telemetry.span(TRAINER_MHC_SPAN,
+                                        sinkhorn_gap=last_metrics[MHC_SINKHORN_GAP]):
                         pass
                 if DSA_PICKED_PAIRS in last_metrics:
                     # the last step's selection counters, the same way

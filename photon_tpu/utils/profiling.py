@@ -241,6 +241,21 @@ MAMBA_GATE_NORM_SCOPE = "mamba/gate_norm"
 SHORTCONV_PROJ_SCOPE = "shortconv/proj"
 #: the split into ``B | C | u``, the gate ``B * u``, the taps, the gate ``C *``
 SHORTCONV_MIX_SCOPE = "shortconv/mix"
+# -- hyper-connected residual streams (models/mpt.py): ``jax.named_scope``s in
+# every operation's ``op_name``, and one counter in the train step's metrics --
+#: a sublayer's three maps: the flattened norm, its projection, the squashes
+#: and the mixing matrix's Sinkhorn iterations
+MHC_MAPS_SCOPE = "mhc/maps"
+#: the sublayer's input read out of the streams (and the model's exit sum)
+MHC_READ_IN_SCOPE = "mhc/read_in"
+#: the streams mixed and the branch written back into each
+MHC_WRITE_BACK_SCOPE = "mhc/write_back"
+#: the largest distance from 1 of a row or column sum of any sublayer's
+#: mixing matrix in the step (0 = doubly stochastic), fetched with the loss
+MHC_SINKHORN_GAP = "mhc/sinkhorn_gap"
+#: opened and closed inside the fence like ``trainer/moe_load``, only when the
+#: step's blocks are hyper-connected: attr ``sinkhorn_gap``
+TRAINER_MHC_SPAN = "trainer/mhc"
 # -- every block (models/mpt.py) and the step around them
 # (train/train_step.py): ``jax.named_scope``s like the families' above, so
 # that no device time of a step is left to a bare instruction name --------
@@ -751,7 +766,9 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     held / routed`` experts a token): what the step computes here, not what
     the whole model would. A Mamba-2 layer (``layer_types``) counts its two
     projections and the chunked scan's products in attention's place, a
-    ``conv`` layer its two projections, its taps and its gates.
+    ``conv`` layer its two projections, its taps and its gates. Hyper-
+    connected streams (``hc_mult``) count their maps' projection, two a
+    layer; their mixing is elementwise and bound by bytes, not counted.
     Learned sparse attention (``dsa_topk``) has a count of its own,
     :func:`_sparse_attention_flops_per_token`."""
     d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
@@ -794,6 +811,9 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     n_dense = cfg.first_k_dense  # leading SwiGLU blocks of their own width
     n_block = ((L - n_mamba - n_conv) * attn_w + n_mamba * mamba_w + n_conv * conv_w
                + n_dense * 3 * d * cfg.dense_mlp_hidden_size + (L - n_dense) * mlp_w)
+    if cfg.hyper_connected:
+        n = cfg.hc_mult
+        n_block += 2 * L * n * d * (2 * n + n * n)
     head = 6 * d * v
     return 6.0 * n_block + attn + head
 

@@ -674,7 +674,8 @@ def _with(cfg, **paths):
     (dict(model__lora_rank=4, model__lora_targets=("out_proj",)), "LoRA"),
     (dict(photon__adapters__enabled=True), "LoRA|adapters"),
     (dict(photon__serve__prefix_cache=True), "prefix cache"),
-    (dict(model__v_head_dim=32), "one head width"),
+    # since PR 44 the kernels take a v width of its own; ring attention does not
+    (dict(model__v_head_dim=32, model__attn_impl="ring"), "one head width"),
     (dict(model__rope=False, model__learned_pos_emb=True), "rope=true"),
     (dict(model__moe_mlp_act="gelu"), "swiglu"),
     (dict(model__moe_bias_update_speed=-0.1), "moe_bias_update_speed"),

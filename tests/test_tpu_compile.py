@@ -643,6 +643,38 @@ def test_chip_smoke_train_step_compiles(topo_devices, monkeypatch, mesh_kw):
     assert _live_gib(compiled) < 14.0
 
 
+def test_flash_attention_compiles_at_a_v_width_of_its_own(one_chip):
+    """32 heads at 4,096 tokens with q / k 192 wide and v 128 (the
+    ``xing4-train-4k`` cell): v, o, dO and dv blocks of 128 lanes beside q / k
+    blocks of 256, forward and backward at the tiles ``pick_tiles`` derives."""
+    from photon_tpu.ops.flash_attention import flash_attention
+
+    qk = _abstract((1, 4096, 32, 192), jnp.bfloat16, one_chip)
+    v = _abstract((1, 4096, 32, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=0.1447).astype(jnp.float32).sum()
+
+    text = _hlo(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert text.count(KERNEL) >= 3
+    assert "bf16[32,4096,128]" in text and "bf16[32,4096,256]" in text  # v is not padded to 256
+
+
+def test_the_hyper_connected_cells_step_compiles_and_fits_the_chip(topo_devices, monkeypatch):
+    """``xing4.0-29b-a4b-ep8`` at its cell's size (1 row x 4,096 tokens, one
+    microbatch, ``remat``, 759 M parameters): the whole train step for a
+    described v5e, the flash kernel's four launches a layer in it, and the
+    compiler's memory report (the donated state + its temporaries) under the
+    chip's 16 GiB (~95 s)."""
+    from photon_tpu.config import load_preset
+
+    cfg = load_preset("xing4.0-29b-a4b-ep8")
+    compiled, state = _compile_train_step(cfg, topo_devices()[:1], monkeypatch)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params)) == 759_346_446
+    assert compiled.as_text().count(KERNEL) >= 4
+    assert 12.0 < _live_gib(compiled) < 16.0
+
+
 @pytest.mark.slow  # real-TPU-compiler compile of a 32-device program, ~2 min
 def test_mpt_7b_train_step_compiles_on_32_chips(topo_devices):
     """7B needs 32 chips; fsdp8 x tensor4 fits where fsdp16 x tensor2
