@@ -82,6 +82,7 @@ import jax.numpy as jnp
 
 from photon_tpu.config.schema import ModelConfig
 from photon_tpu.ops.attention import multihead_attention
+from photon_tpu.ops.flash_attention import IN_PLACE, flash_layout
 from photon_tpu.utils.profiling import (
     ATTN_PROJ_SCOPE,
     ATTN_QK_NORM_SCOPE,
@@ -201,6 +202,22 @@ def apply_rope(q: jax.Array, k: jax.Array, theta: float,
         return out.astype(x.dtype)
 
     return rot(q), rot(k)
+
+
+class DenseKernel(nn.Module):
+    """A bias-free ``nn.Dense``'s kernel without its product: the parameter
+    ``<name>/kernel`` that ``nn.Dense(features, name=name)`` makes (the same
+    shape, init and place in the tree), for a caller that multiplies by parts
+    of it."""
+
+    features: int
+    param_dtype: Any
+    kernel_init: Any
+
+    @nn.compact
+    def __call__(self, fan_in: int) -> jax.Array:
+        return self.param("kernel", self.kernel_init, (fan_in, self.features),
+                          self.param_dtype)
 
 
 #: latent attention's projections (both low-rank paths, their norms, RoPE,
@@ -452,16 +469,54 @@ class MPTBlock(nn.Module):
         kv_a = dense(cfg.kv_lora_rank + rope, "kv_a_proj", std)(h)
         c_kv = FP32RMSNorm(eps=cfg.norm_eps, name="kv_a_norm")(
             kv_a[..., :cfg.kv_lora_rank])
-        kv = dense(cfg.n_heads * (nope + dv), "kv_b_proj", std)(c_kv)
-        kv = kv.reshape(b, s, cfg.n_heads, nope + dv)
+        in_place = cfg.no_bias and flash_layout(
+            cfg.n_heads, cfg.n_heads, nope + rope, dv) == IN_PLACE
+        if not in_place:
+            kv = dense(cfg.n_heads * (nope + dv), "kv_b_proj", std)(c_kv)
+            kv = kv.reshape(b, s, cfg.n_heads, nope + dv)
         q_rope, k_rope = apply_rope(
             q[..., nope:], kv_a[..., None, cfg.kv_lora_rank:], cfg.rope_theta,
             cfg.rope_inv_freq(rope))
         q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        if in_place:
+            return (q, *self._latent_kv_in_place(c_kv, k_rope[:, :, 0, :], std))
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, cfg.n_heads, rope))],
             axis=-1)
         return q, k, kv[..., nope:]
+
+    def _latent_kv_in_place(self, c_kv: jax.Array, k_rope: jax.Array, std: float):
+        """``_latent_qkv``'s k and v where the flash launches read their
+        operands in place (``ops/flash_attention.flash_layout``: whole-lane
+        head widths), each STRAIGHT OUT OF A PRODUCT as ``[B, S, H·D]``:
+        ``v = c_kv @ W[:, h, nope:]`` and ``k = [c_kv | k_rope] @ [[W[:, h,
+        :nope], 0], [0, I]]``, the shared rotary key carried into every head's
+        last columns by an identity block (``rope`` more rows of a
+        ``kv_lora_rank``-deep contraction, exact in any dtype). ``kv_b_proj``
+        is the parameter it always was. Why: sliced and concatenated as
+        ``[B, S, H, nope + dv]``, XLA lays a 20-head array out sequence-minor
+        (heads would pad to whole sublane tiles) and copies k, v, dk and dv
+        between that and the launches' row-major arrays, forward, under
+        ``remat`` and backward; a product writes the layout its consumer
+        asks for (PERF.md section 6, PR 45). q keeps its rotation on the
+        ``[B, S, H, D]`` view, and its copy in and out."""
+        cfg = self.cfg
+        b, s, rank = c_kv.shape
+        heads, nope, rope, dv = (cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                                 cfg.v_head_dim)
+        compute = _dtype(cfg.compute_dtype)
+        w = DenseKernel(heads * (nope + dv), _dtype(cfg.param_dtype),
+                        nn.initializers.normal(stddev=std), name="kv_b_proj")(rank)
+        w = w.astype(compute).reshape(rank, heads, nope + dv)
+        eye = jnp.broadcast_to(jnp.eye(rope, dtype=compute)[:, None, :], (rope, heads, rope))
+        w_k = jnp.concatenate([
+            jnp.concatenate([w[..., :nope], jnp.zeros((rank, heads, rope), compute)], axis=-1),
+            jnp.concatenate([jnp.zeros((rope, heads, nope), compute), eye], axis=-1),
+        ], axis=0).reshape(rank + rope, heads * (nope + rope))
+        c_kv = c_kv.astype(compute)
+        k = jnp.concatenate([c_kv, k_rope.astype(compute)], axis=-1) @ w_k
+        v = c_kv @ w[..., nope:].reshape(rank, heads * dv)
+        return k.reshape(b, s, heads, nope + rope), v.reshape(b, s, heads, dv)
 
     def _sparse_attention(self, h: jax.Array, q: jax.Array, k: jax.Array,
                           v: jax.Array, dense) -> jax.Array:

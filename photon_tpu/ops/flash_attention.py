@@ -8,6 +8,29 @@ Design notes (TPU-first):
 - Grid is ``(batch*heads, q_blocks, k_blocks)``; the innermost k dimension is
   executed sequentially per core, so the online-softmax running state
   ``(m, l, acc)`` lives in VMEM scratch and persists across k iterations.
+- The launches read the projections' own arrays where the head widths let
+  them (``flash_layout``, a rule by widths alone; no knob). ``[B, S, H, D] ->
+  [B, S, H·D]`` moves no data, and a head is a COLUMN BLOCK of that array:
+  ``(1, block, D)`` at ``(b, i, h)`` for q, o, dO, dq and at ``(b, j, h //
+  group)`` for k, v, dk, dv, legal for Mosaic where D is whole lanes
+  (``IN_PLACE``). The grid's first axis is still ``batch*heads``; the index
+  maps decode it (``_block_at``). At D 64 a 128-lane column block is two
+  neighbouring heads, and with as many k / v heads as q heads the grid's
+  first axis is ``batch * heads / 2`` and each step runs both heads of its
+  PAIR (``HEAD_PAIRS``): a head's strip of q (dO; in dk/dv, of k and v) with
+  the other head's 64 lanes at zero (``_own_lanes``) stands where the zero
+  pad stood, so a contraction over the 128 lanes is the lone padded head's
+  bit for bit, and a product whose outputs are the 128 lanes has the head's
+  own columns in its own lanes, which the body keeps (``_by_head``). The MXU
+  does the work it did on a padded head; HBM reads of q, k, v, dO halve.
+  What the backward saves is then q, k, v and o as the projections and
+  ``out_proj`` hold them: no transposed, padded copy. Everything else (a
+  width that is not whole lanes, as 192; grouped heads at 64, where a q pair
+  and its kv head sit in different halves of different blocks;
+  ``flash_attention_with_lse``) keeps ``HEAD_MAJOR``: ``to_bh``'s
+  ``[B·H, S, lane_padded(D)]`` copies, blocks ``(1, block, D_pad)`` at
+  ``(bh, i, 0)``. lse and delta are small ``[rows, 8, S]`` float32 arrays in
+  every layout (a pair's two rows in the halves of the eight sublanes).
 - The tile is a function of the shapes, not a default: ``pick_tiles`` gives
   each of the three launches the largest ``(block_q, block_k)`` that divides
   the sequences, fits the VMEM budget by ``launch_vmem_bytes`` (an estimate
@@ -45,8 +68,8 @@ Design notes (TPU-first):
 - Blockwise structure means a ring/context-parallel extension only has to
   rotate k/v blocks between chips — the inner kernel is unchanged
   (SURVEY.md §5 long-context note).
-- ``d_head`` is zero-padded to the 128-lane width when smaller (padding
-  columns contribute nothing to scores or outputs).
+- Under ``HEAD_MAJOR`` ``d_head`` is zero-padded to the 128-lane width when
+  smaller (padding columns contribute nothing to scores or outputs).
 - v may be narrower (or wider) than q and k (latent attention: q/k 192, v
   128): v, o, dO, dv and the output accumulator then take v's own padded
   width, q, k, dq and dk theirs, and ``launch_vmem_bytes`` / ``pick_tiles``
@@ -74,6 +97,31 @@ SUBLANE = 8  # fp32 sublane height; lse/delta carry 8 redundant rows for tiling
 def lane_padded(d: int) -> int:
     """``d_head`` as the kernels see it: zero-padded up to whole lane widths."""
     return -(-d // LANE) * LANE
+
+
+# Where the launches find a head (``flash_layout``; the span attribute
+# ``flash_layout`` on ``trainer/steps`` says which one a step's launches took).
+IN_PLACE = "in_place"  # [B, S, H·D] as the projections wrote it, a head a column block
+HEAD_PAIRS = "head_pairs"  # the same arrays at D 64: a 128-lane column block is two heads
+HEAD_MAJOR = "head_major"  # [B·H, S, lane_padded(D)] copies made by ``to_bh``
+PAIR_WIDTH = LANE // 2  # the head width two of which fill a column block
+
+
+def flash_layout(h_q: int, h_kv: int, d: int, d_v: int) -> str:
+    """The layout ``flash_attention``'s launches read for these heads, from
+    the widths alone. ``[B, S, H, D] -> [B, S, H·D]`` moves no data, and a
+    ``(block, D)`` column block of that array is a legal Mosaic block where D
+    is whole lanes: such heads are read and written ``IN_PLACE``. At D 64 a
+    128-lane column block holds two neighbouring heads; where q, k and v all
+    have the same even number of such heads (so that a q pair's k and v are
+    one block too) the launches take ``HEAD_PAIRS``. Every other shape (a
+    width that is not whole lanes, grouped heads at 64) keeps ``HEAD_MAJOR``:
+    transposed and padded copies."""
+    if d % LANE == 0 and d_v % LANE == 0:
+        return IN_PLACE
+    if d == d_v == PAIR_WIDTH and h_q == h_kv and h_q % 2 == 0:
+        return HEAD_PAIRS
+    return HEAD_MAJOR
 
 
 def _kernel_scope(kernel: str):
@@ -166,16 +214,100 @@ def _scores(q, k, q0, k0, *, scale, slope, masked: bool) -> jax.Array:
     return s
 
 
-def _bh_slopes(h_slopes: jax.Array, bh: int) -> jax.Array:
+def _bh_slopes(h_slopes: jax.Array, bh: int, pair: bool = False) -> jax.Array:
     """[bh, SUBLANE, LANE] per-(batch*head) slope array (replicated across
     the tile so each grid row DMAs one full fp32 tile). ``h_slopes`` is the
     per-head slope vector [h] — by default ``attention.alibi_slopes(h)``,
     but a caller under a head-sharded (tensor-parallel) mesh passes its
     LOCAL slice of the global slope table so every shard biases with its
-    true global head index."""
+    true global head index. ``pair``: a grid row is two heads, and the tile
+    holds the first one's slope in its upper half (``_stat_row``), the
+    second's in its lower: ``[bh / 2, SUBLANE, LANE]``."""
     h = h_slopes.shape[0]
     slopes = jnp.tile(h_slopes, bh // h)  # head-major order
+    if pair:
+        return _stats_to_blocks(jnp.broadcast_to(slopes[:, None], (bh, LANE)), pair)
     return jnp.broadcast_to(slopes[:, None, None], (bh, SUBLANE, LANE))
+
+
+# ---------------------------------------------------------------------------
+# Where a launch finds a head: the block's index, and a pair's two halves
+# ---------------------------------------------------------------------------
+
+
+def _block_at(cols: int):
+    """``(row, block) -> block index`` of a ``(1, rows, width)`` block.
+    ``cols == 0``: the array is head-major ``[B·H, S, D]`` and ``row`` is its
+    own. Otherwise it is ``[B, S, cols · width]`` and ``row = b · cols + c``
+    is decoded: the head (or pair of heads) is column block ``c`` of batch
+    row ``b``."""
+    if not cols:
+        return lambda row, blk: (row, blk, 0)
+    return lambda row, blk: (row // cols, blk, row % cols)
+
+
+def _own_lanes(x: jax.Array, e: int, pair: bool) -> jax.Array:
+    """``x [rows, 128]`` with the other head's 64 lanes at zero, for head ``e``
+    of a pair: what the zero pad is to a lone 64-wide head. A contraction over
+    the 128 lanes then sums head ``e``'s products and exact zeros, and a
+    product whose 128 lanes are outputs has head ``e``'s columns and zeros.
+    Not a pair: ``x`` itself."""
+    if not pair:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    keep = lane < PAIR_WIDTH if e == 0 else lane >= PAIR_WIDTH
+    return jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _pair_lanes(first: jax.Array, second: jax.Array) -> jax.Array:
+    """``[rows, 128]``: the first head's lanes of ``first``, the second's of
+    ``second``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, first.shape, first.ndim - 1)
+    return jnp.where(lane < PAIR_WIDTH, first, second)
+
+
+def _by_head(xs: list[jax.Array]) -> jax.Array:
+    """One block's ``[rows, width]`` result from its heads': the lone head's
+    own, or a pair's two halves side by side."""
+    return xs[0] if len(xs) == 1 else _pair_lanes(*xs)
+
+
+def _stat_row(e: int, pair: bool) -> int:
+    """The sublane row of a lse / delta / slope block that head ``e`` of the
+    block reads: a lone head's statistic fills all ``SUBLANE`` rows, a pair's
+    two fill half each."""
+    return e * (SUBLANE // 2) if pair else 0
+
+
+def _stat_block(rows: list[jax.Array]) -> jax.Array:
+    """``[SUBLANE, n]`` block of a per-row statistic from its ``[n]`` vector:
+    a lone head's over all the sublanes, a pair's two over half of them each
+    (``_stat_row``)."""
+    shape = (SUBLANE, rows[0].shape[0])
+    if len(rows) == 1:
+        return jnp.broadcast_to(rows[0][None, :], shape)
+    upper = jax.lax.broadcasted_iota(jnp.int32, shape, 0) < _stat_row(1, True)
+    return jnp.where(upper, rows[0][None, :], rows[1][None, :])
+
+
+def _stats_to_blocks(x: jax.Array, pair: bool) -> jax.Array:
+    """``[B·H, S]`` per-head rows (lse, delta) as the launches' ``[rows,
+    SUBLANE, S]`` blocks: a lone head's row replicated over the sublanes (one
+    fp32 tile a block), a pair's two rows over half of them each."""
+    bh, s = x.shape
+    if not pair:
+        return jnp.broadcast_to(x[:, None, :], (bh, SUBLANE, s))
+    half = SUBLANE // 2
+    return jnp.broadcast_to(
+        x.reshape(bh // 2, 2, 1, s), (bh // 2, 2, half, s)).reshape(bh // 2, SUBLANE, s)
+
+
+def _stats_from_blocks(x: jax.Array, pair: bool) -> jax.Array:
+    """The inverse: ``[rows, SUBLANE, S]`` blocks to ``[B·H, S]``."""
+    if not pair:
+        return x[:, 0, :]
+    rows, _, s = x.shape
+    return x[:, ::SUBLANE // 2, :].reshape(rows * 2, s)
 
 
 # ---------------------------------------------------------------------------
@@ -280,7 +412,7 @@ def _run_tile(compute, by: str, q_blk, k_blk, block_q: int, block_k: int, *,
 
 
 def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize: int,
-                      d_v: int | None = None) -> int:
+                      d_v: int | None = None, layout: str = HEAD_MAJOR) -> int:
     """VMEM one launch (``fwd``, ``dq`` or ``dkv``) needs at a tile, from the
     kernel's own buffers (``d`` the padded width of q and k, ``d_v`` of v,
     ``None`` = the same): every BlockSpec'd operand and result twice (the
@@ -299,8 +431,26 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
     and come one strip after another: its scores are ``[sub, extent]``, at
     most a ``sub / block`` part of the tile's, its ``pv`` / ``dq`` parts
     ``[sub, d]``; the upcasts are made once a tile and sliced, as large as
-    the whole-tile body's."""
+    the whole-tile body's.
+
+    ``layout`` is what the operands are read in (``flash_layout``), and the
+    numbers above are ``HEAD_MAJOR``'s. Read in place (``IN_PLACE``,
+    ``HEAD_PAIRS``), a column block of a wider array, dq and dk/dv want four
+    more q-shaped blocks than over head-major rows, whatever the tile (2.0,
+    4.3 and 8.4 MB at three tiles, widths and dtypes: 4 x ``block_q`` x ``d``
+    x ``itemsize`` to 3 %; the forward wants none), and ``IN_PLACE``'s dq
+    holds the forward's o block, from which it makes delta. ``HEAD_PAIRS`` (``d`` is
+    then the pair's 128 lanes) adds the second head's running max,
+    denominator and accumulator to the forward's scratch (dq and dk/dv add
+    both heads into one accumulator, each in its own lanes), and one more
+    set of score temporaries: the two heads' bodies are unrolled side by
+    side, and the compiler starts the second's scores while the first's are
+    alive (at a 1,024 square it wanted 1.0 to 1.7 score tiles more than for
+    a lone padded head: 15.4 / 14.0 / 17.3 MB for the three launches, where
+    this gives 20.0 / 19.0 / 20.6). Both read off the smallest limit the
+    compiler accepts, as the rest was (PERF.md, PR 45)."""
     d_v = d if d_v is None else d_v
+    pair = layout == HEAD_PAIRS
     q_rows = block_q * d * itemsize  # one q-shaped block: q, dq
     o_rows = block_q * d_v * itemsize  # one o-shaped block: o, do
     k_rows = block_k * d * itemsize  # one k-shaped block: k, dk
@@ -309,10 +459,12 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
     slopes = SUBLANE * LANE * 4
     if launch == "fwd":
         piped = q_rows + o_rows + k_rows + v_rows + row_stats  # q, o; k, v; lse
-        scratch = 2 * block_q * LANE * 4 + block_q * d_v * 4  # m, l; acc
+        scratch = (2 if pair else 1) * (2 * block_q * LANE * 4 + block_q * d_v * 4)  # m, l; acc
         upcast = 2 * block_q * d_v * 4  # pv, acc * alpha
     elif launch == "dq":
         piped = 2 * q_rows + o_rows + k_rows + v_rows + 2 * row_stats  # q, dq, do; k, v
+        if layout == IN_PLACE:
+            piped += o_rows  # o comes in, delta goes out where it came in
         scratch = block_q * d * 4
         upcast = (block_q + block_k) * d_v * 4  # do, v
     elif launch == "dkv":
@@ -321,8 +473,10 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
         upcast = block_q * (d + d_v) * 4 + block_k * d_v * 4  # q, do, v
     else:
         raise ValueError(f"unknown launch {launch!r}")
-    scores = int(SCORE_TEMPS * block_q * block_k * 4)
-    return 2 * (piped + slopes) + scratch + upcast + scores + VMEM_SLACK
+    scores = (2 if pair else 1) * int(SCORE_TEMPS * block_q * block_k * 4)
+    in_place = 4 * block_q * max(d, d_v) * itemsize if (
+        layout != HEAD_MAJOR and launch != "fwd") else 0
+    return 2 * (piped + slopes) + scratch + upcast + scores + in_place + VMEM_SLACK
 
 
 def _vmem_params(need: int) -> dict:
@@ -353,9 +507,11 @@ class TilePlan(NamedTuple):
     def blocks(self) -> tuple[tuple[int, int], ...]:
         return tuple((t.block_q, t.block_k) for t in self)
 
-    def attrs(self) -> dict[str, str]:
-        """The plan as span attributes (``trainer/steps`` carries them)."""
+    def attrs(self, layout: str = HEAD_MAJOR) -> dict[str, str]:
+        """The plan as span attributes (``trainer/steps`` carries them), with
+        the ``layout`` its launches read (``flash_layout``)."""
         return {
+            "flash_layout": layout,
             "flash_tiles": " ".join(
                 f"{n}={t.block_q}x{t.block_k}" for n, t in zip(self._fields, self)),
             "flash_live_tiles": " ".join(
@@ -375,7 +531,8 @@ def _tile_sizes(s: int) -> list[int]:
 def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 1, *,
                causal: bool = True, offset: int | None = None,
                block_q: int | None = None, block_k: int | None = None,
-               vmem_budget: int = VMEM_BUDGET, d_v_pad: int | None = None) -> TilePlan:
+               vmem_budget: int = VMEM_BUDGET, d_v_pad: int | None = None,
+               layout: str = HEAD_MAJOR) -> TilePlan:
     """``(block_q, block_k)`` of the forward, dq and dk/dv launches, from the
     shapes alone: for each launch the largest tile (by area) that divides
     both sequences, stays inside ``vmem_budget`` by :func:`launch_vmem_bytes`,
@@ -390,7 +547,15 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
     tile counts are per kv head times the group. ``d_v_pad`` is v's padded
     width where it is not q's and k's (``d_pad``): it enters the VMEM
     estimate; the ladder's top follows ``d_pad``, the width of the score
-    products, where it was read."""
+    products, where it was read. ``d_pad`` is the width of a block as the
+    launches hold it: a head's own under ``IN_PLACE``, 128 for a pair of
+    64-wide heads (``HEAD_PAIRS``), the padded one under ``HEAD_MAJOR``: the
+    same number for the same heads whatever the ``layout``. Every layout's
+    tile is fitted by ``HEAD_MAJOR``'s estimate, so the layout does not move
+    a tile (the ladder's tops were read per head, and a pair's step is two
+    heads' work at the same tile); ``vmem_bytes`` is the layout's own, larger
+    estimate, which the launch asks for: it may pass ``vmem_budget``, a limit
+    on what a tile is picked by, not on what a core has (128 MiB)."""
     qs = _tile_sizes(s_q) if block_q is None else [min(block_q, s_q)]
     ks = _tile_sizes(s_k) if block_k is None else [min(block_k, s_k)]
     if s_q % qs[0] or s_k % ks[0]:  # only a pinned block can fail to divide
@@ -412,6 +577,8 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
             _, bk, bq, need = max(fits)
         else:
             need, bq, bk = min(sized)
+        if layout != HEAD_MAJOR:
+            need = launch_vmem_bytes(launch, bq, bk, d_pad, itemsize, d_v_pad, layout)
         live, grid = live_tiles(s_q, s_k, bq, bk, causal=causal, offset=offset)
         group = n_kv_group if launch == "dkv" else 1
         share = (executed_pairs(launch, s_q, s_k, bq, bk, causal=causal, offset=offset)
@@ -470,19 +637,26 @@ def executed_pairs(launch: str, s_q: int, s_k: int, block_q: int, block_k: int, 
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, lone):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, lone, pair=False):
+    """``pair``: the block is two 64-wide heads side by side (``HEAD_PAIRS``).
+    Each runs as a lone head does, its q with the other's lanes at zero
+    (``_own_lanes``) against the whole k block, so its scores are a lone
+    padded head's bit for bit; ``p @ v`` over the whole v block has its own
+    output columns in its own lanes, which ``_emit`` keeps."""
     slopes_ref = rest[0] if use_alibi else None
     o_ref, lse_ref, *scratch = rest[1:] if use_alibi else rest
     q_blk = pl.program_id(1)
     k_blk = pl.program_id(2)
     n_k = pl.num_programs(2)
+    heads = range(2 if pair else 1)
 
-    def _attend(rows, parts, guard, carried):
-        """``(m, l, acc)`` of the strip ``rows`` after all its key ``parts`` at
-        once: a plain softmax over the strip's extent, merged, where state is
-        ``carried``, with what earlier tiles of the row left in the scratch."""
-        slope = slopes_ref[0, 0, 0] if use_alibi else None
-        q = q_ref[0, rows, :]
+    def _attend(rows, parts, guard, carried, e):
+        """``(m, l, acc)`` of head ``e``'s strip ``rows`` after all its key
+        ``parts`` at once: a plain softmax over the strip's extent, merged,
+        where state is ``carried``, with what earlier tiles of the row left in
+        the head's scratch."""
+        slope = slopes_ref[0, _stat_row(e, pair), 0] if use_alibi else None
+        q = _own_lanes(q_ref[0, rows, :], e, pair)
         q0 = q_blk * block_q + offset + rows.start
         scores = [
             _scores(q, k_ref[0, ks, :], q0, k_blk * block_k + ks.start,
@@ -492,7 +666,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, off
             jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in scores])  # [rows, 1]
         l = pv = None
         if carried:
-            m_s, l_s, acc_s = scratch
+            m_s, l_s, acc_s = scratch[3 * e:3 * e + 3]
             m_prev = m_s[rows, 0][:, None]
             m = jnp.maximum(m_prev, m)
             alpha = jnp.exp(m_prev - m)  # rescale of old state
@@ -513,40 +687,49 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, off
         acc = acc_s[rows, :] * alpha + pv if carried else pv
         return m, l, acc
 
-    def _emit(rows, m, l, acc):
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
-        lse = m[:, 0] + jnp.log(l_safe[:, 0])  # [rows]
-        lse_ref[0, :, rows] = jnp.broadcast_to(lse[None, :], (SUBLANE, lse.shape[0]))
+    def _emit(rows, state):
+        """Writes the rows' output and log-sum-exp from each head's ``(m, l,
+        acc)``: a pair's outputs side by side, its two log-sum-exps in the
+        halves of the block's sublanes (``_stat_row``)."""
+        safe = [jnp.where(l == 0.0, 1.0, l) for _, l, _ in state]
+        o_ref[0, rows, :] = _by_head(
+            [acc / l_safe for (_, _, acc), l_safe in zip(state, safe)]).astype(o_ref.dtype)
+        lse_ref[0, :, rows] = _stat_block(
+            [m[:, 0] + jnp.log(l_safe[:, 0]) for (m, _, _), l_safe in zip(state, safe)])
 
     if lone:
         # the tile is the whole sequence: nothing is carried from step to
         # step, so each strip goes straight to the outputs
         for rows, parts in _strips(block_q, sub, "q"):
-            _emit(rows, *_attend(rows, parts, guard=False, carried=False))
+            _emit(rows, [_attend(rows, parts, guard=False, carried=False, e=e)
+                         for e in heads])
         return
-
-    m_s, l_s, acc_s = scratch
 
     @pl.when(k_blk == 0)
     def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+        for e in heads:
+            m_s, l_s, acc_s = scratch[3 * e:3 * e + 3]
+            m_s[:] = jnp.full_like(m_s, NEG_INF)
+            l_s[:] = jnp.zeros_like(l_s)
+            acc_s[:] = jnp.zeros_like(acc_s)
 
     def _compute(strips, guard):
         for rows, parts in strips:
-            m, l, acc = _attend(rows, parts, guard, carried=True)
-            acc_s[rows, :] = acc
-            m_s[rows, :] = jnp.broadcast_to(m, (m.shape[0], LANE))
-            l_s[rows, :] = jnp.broadcast_to(l, (l.shape[0], LANE))
+            for e in heads:
+                m_s, l_s, acc_s = scratch[3 * e:3 * e + 3]
+                m, l, acc = _attend(rows, parts, guard, carried=True, e=e)
+                acc_s[rows, :] = acc
+                m_s[rows, :] = jnp.broadcast_to(m, (m.shape[0], LANE))
+                l_s[rows, :] = jnp.broadcast_to(l, (l.shape[0], LANE))
 
     _run_tile(_compute, "q", q_blk, k_blk, block_q, block_k, causal=causal,
               offset=offset, sub=sub)
 
     @pl.when(k_blk == n_k - 1)
     def _finalize():
-        _emit(slice(0, block_q), m_s[:, 0][:, None], l_s[:, 0][:, None], acc_s[:])
+        _emit(slice(0, block_q),
+              [(m_s[:, 0][:, None], l_s[:, 0][:, None], acc_s[:])
+               for m_s, l_s, acc_s in (scratch[3 * e:3 * e + 3] for e in heads)])
 
 
 def _kv_row(h_q: int, h_kv: int):
@@ -565,15 +748,68 @@ def _kv_row(h_q: int, h_kv: int):
     return row
 
 
+class _Heads(NamedTuple):
+    """The launches' view of arrays read in place, ``[B, S, cols · width]``:
+    the column blocks a batch row of q (o, dO, dq) and of k (v, dk, dv) has,
+    a head each or, with ``pair``, two 64-wide heads each. ``None`` in its
+    place: head-major ``[B·H, S, D]`` arrays, a block a whole row."""
+
+    q_cols: int
+    kv_cols: int
+    pair: bool = False
+
+    @classmethod
+    def of(cls, layout: str, h_q: int, h_kv: int) -> "_Heads | None":
+        """What ``layout`` makes of ``h_q`` query and ``h_kv`` key heads."""
+        if layout == HEAD_MAJOR:
+            return None
+        return cls(h_q // 2, h_kv // 2, True) if layout == HEAD_PAIRS else cls(h_q, h_kv)
+
+
+class _Launch(NamedTuple):
+    """A launch's geometry, whichever arrays it reads: the grid's rows over q
+    and over k / v, the heads a batch row has of each, a block's width for q
+    and k and for v, the block index of ``(row, block)`` in a q-shaped and a
+    k-shaped array, and whether a block is a pair of heads."""
+
+    bh: int
+    bh_k: int
+    h_q: int
+    h_kv: int
+    d: int
+    d_v: int
+    at_q: object
+    at_kv: object
+    pair: bool
+    layout: str
+
+
+def _launch_of(q, k, v, h_q: int, heads: _Heads | None) -> _Launch:
+    """Over head-major arrays (``heads`` is ``None``; ``h_q`` 0 → MHA: kv row
+    == q row, exact head split irrelevant) or over arrays read in place."""
+    if heads is None:
+        bh, bh_k = q.shape[0], k.shape[0]
+        h_q = h_q or 1
+        return _Launch(bh, bh_k, h_q, h_q * bh_k // bh, q.shape[2], v.shape[2],
+                       _block_at(0), _block_at(0), False, HEAD_MAJOR)
+    b, (q_cols, kv_cols, pair) = q.shape[0], heads
+    return _Launch(b * q_cols, b * kv_cols, q_cols, kv_cols, q.shape[2] // q_cols,
+                   v.shape[2] // kv_cols, _block_at(q_cols), _block_at(kv_cols), pair,
+                   HEAD_PAIRS if pair else IN_PLACE)
+
+
 def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
-         h_q=0, interpret=False):
-    bh, s_q, d = q.shape
-    s_k, d_v = k.shape[1], v.shape[2]  # v, o and the accumulator at v's width
+         h_q=0, interpret=False, heads: _Heads | None = None):
+    """The forward launch: ``(o, lse [B·H, s_q])``. ``q``, ``k``, ``v`` (and
+    ``o``) are head-major ``[B·H, S, D]``, or, with ``heads``, the
+    projections' own ``[B, S, H·D]``."""
+    s_q, s_k = q.shape[1], k.shape[1]
+    # v, o and the accumulator at v's width
+    bh, _, h_q, h_kv, d, d_v, at_q, at_kv, pair, layout = _launch_of(q, k, v, h_q, heads)
     n_q = pl.cdiv(s_q, block_q)
     n_k = pl.cdiv(s_k, block_k)
     grid = (bh, n_q, n_k)
-    h_q = h_q or 1  # 0 → MHA (kv row == q row; exact head split irrelevant)
-    kv = _kv_row(h_q, h_q * k.shape[0] // bh)
+    kv = _kv_row(h_q, h_kv)
 
     # offset generalizes the causal mask to chunked/global positions:
     # visible iff q_id + offset >= k_id (ring attention passes
@@ -585,12 +821,12 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
     lone = _lone_tile(sub, s_q, s_k, block_q, offset)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, causal=causal,
-        offset=offset, use_alibi=slopes is not None, sub=sub, lone=lone,
+        offset=offset, use_alibi=slopes is not None, sub=sub, lone=lone, pair=pair,
     )
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), kj(i, j), 0)),
-        pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (kv(b), kj(i, j), 0)),
+        pl.BlockSpec((1, block_q, d), lambda b, i, j: at_q(b, i)),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: at_kv(kv(b), kj(i, j))),
+        pl.BlockSpec((1, block_k, d_v), lambda b, i, j: at_kv(kv(b), kj(i, j))),
     ]
     inputs = [q, k, v]
     if slopes is not None:
@@ -598,8 +834,9 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
         inputs.append(slopes)
     # lse carries SUBLANE redundant rows so its (1, 8, block_q) blocks are
     # exactly one fp32 tile; callers use row 0
+    o_shape = (bh, s_q, d_v) if heads is None else (q.shape[0], s_q, h_q * d_v)
     out_shape = [
-        jax.ShapeDtypeStruct((bh, s_q, d_v), q.dtype),
+        jax.ShapeDtypeStruct(o_shape, q.dtype),
         jax.ShapeDtypeStruct((bh, SUBLANE, s_q), jnp.float32),
     ]
     launch = pl.pallas_call(
@@ -607,21 +844,22 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: at_q(b, i)),
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),
         ],
         scratch_shapes=[] if lone else [
             pltpu.VMEM((block_q, LANE), jnp.float32),  # running max
             pltpu.VMEM((block_q, LANE), jnp.float32),  # running denom
             pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
-        ],
+        ] * (2 if pair else 1),  # a pair: each head its own three
         out_shape=out_shape,
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("fwd", block_q, block_k, d, q.dtype.itemsize, d_v)),
+        **_vmem_params(launch_vmem_bytes("fwd", block_q, block_k, d, q.dtype.itemsize, d_v,
+                                         layout)),
     )
     with _kernel_scope("flash_fwd"):
         o, lse = launch(*inputs)
-    return o, lse[:, 0, :]
+    return o, _stats_from_blocks(lse, pair)
 
 
 # ---------------------------------------------------------------------------
@@ -629,11 +867,27 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, pair=False, makes_delta=False):
+    """``pair`` (``HEAD_PAIRS``): each head of the block with its q and dO
+    strips masked to its own lanes (``_own_lanes``) against the whole k and v
+    blocks; ``ds @ k`` has the head's dq in its own lanes, and the block's dq
+    is the two side by side.
+
+    ``makes_delta`` (whole-lane heads read in place): the sixth operand is
+    the forward's o block, not delta, and ``delta = rowsum(dO * o)`` is a
+    second RESULT, made at a q block's first step from the two blocks the
+    launch holds anyway, read back by the steps of that block, and handed to
+    the dk/dv launch. (Summed by XLA over a head's columns of ``[B, S, H·D]``
+    it cost a relayout of the float32 product to a ``[B, S, H, D]`` view's
+    layout: 7 ms a step of ``glm47flash-train``, PERF.md PR 45.)"""
+    heads = range(2 if pair else 1)
+    slopes_ref = None
     if use_alibi:
-        slopes_ref, dq_ref, dq_s = rest
+        slopes_ref, *rest = rest
+    if makes_delta:
+        o_ref = delta_ref
+        dq_ref, delta_ref, dq_s = rest
     else:
-        slopes_ref = None
         dq_ref, dq_s = rest
     q_blk = pl.program_id(1)
     k_blk = pl.program_id(2)
@@ -642,35 +896,43 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale
     @pl.when(k_blk == 0)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
+        if makes_delta:  # never a pair
+            prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+            delta_ref[0] = _stat_block([jnp.sum(prod, axis=-1)])
 
     def _compute(strips, guard):
-        slope = slopes_ref[0, 0, 0] if use_alibi else None
+        slopes = [slopes_ref[0, _stat_row(e, pair), 0] if use_alibi else None for e in heads]
         v32 = v_ref[0].astype(jnp.float32)
         for rows, parts in strips:
-            q = q_ref[0, rows, :]
-            do = do_ref[0, rows, :].astype(jnp.float32)
-            lse = lse_ref[0, 0, rows][:, None]
-            delta = delta_ref[0, 0, rows][:, None]
-            q0 = q_blk * block_q + offset + rows.start
-            dq = None
-            for ks, masked in parts:
-                k = k_ref[0, ks, :]
-                s = _scores(q, k, q0, k_blk * block_k + ks.start,
-                            scale=scale, slope=slope, masked=masked)
-                p = jnp.exp(s - lse)  # [rows, cols]
-                if guard:
-                    # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
-                    p = jnp.where(lse > NEG_INF / 2, p, 0.0)
-                dp = jax.lax.dot_general(
-                    do, v32[ks], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                ds = p * (dp - delta) * scale
-                part = jax.lax.dot_general(
-                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                dq = part if dq is None else dq + part
-            dq_s[rows, :] += dq
+            q_rows = q_ref[0, rows, :]
+            do_rows = do_ref[0, rows, :].astype(jnp.float32)
+            dqs = []
+            for e, slope in zip(heads, slopes):
+                q = _own_lanes(q_rows, e, pair)
+                do = _own_lanes(do_rows, e, pair)
+                lse = lse_ref[0, _stat_row(e, pair), rows][:, None]
+                delta = delta_ref[0, _stat_row(e, pair), rows][:, None]
+                q0 = q_blk * block_q + offset + rows.start
+                dq = None
+                for ks, masked in parts:
+                    k = k_ref[0, ks, :]
+                    s = _scores(q, k, q0, k_blk * block_k + ks.start,
+                                scale=scale, slope=slope, masked=masked)
+                    p = jnp.exp(s - lse)  # [rows, cols]
+                    if guard:
+                        # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
+                        p = jnp.where(lse > NEG_INF / 2, p, 0.0)
+                    dp = jax.lax.dot_general(
+                        do, v32[ks], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    ds = p * (dp - delta) * scale
+                    part = jax.lax.dot_general(
+                        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    dq = part if dq is None else dq + part
+                dqs.append(dq)
+            dq_s[rows, :] += _by_head(dqs)
 
     _run_tile(_compute, "q", q_blk, k_blk, block_q, block_k, causal=causal,
               offset=offset, sub=sub)
@@ -680,11 +942,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, n_q, sub):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, n_q, sub, pair=False):
     """Inner grid dim sweeps ``group * n_q`` steps: for grouped-query
     attention every kv row accumulates dk/dv over ALL q heads of its group
     (t // n_q picks the group member, t % n_q the q block); MHA is the
-    group == 1 degenerate case."""
+    group == 1 degenerate case.
+
+    ``pair`` (``HEAD_PAIRS``): each head of the block with its k and v strips
+    masked to its own lanes (``_own_lanes``) against the whole q and dO
+    blocks; ``ds^T @ q`` and ``p^T @ dO`` have the head's dk and dv in its
+    own lanes, and the block's are the two side by side."""
+    heads = range(2 if pair else 1)
     if use_alibi:
         slopes_ref, dk_ref, dv_ref, dk_s, dv_s = rest
     else:
@@ -701,39 +969,45 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
         dv_s[:] = jnp.zeros_like(dv_s)
 
     def _compute(strips, guard):
-        slope = slopes_ref[0, 0, 0] if use_alibi else None
+        slopes = [slopes_ref[0, _stat_row(e, pair), 0] if use_alibi else None for e in heads]
         # made once a tile and sliced: a key strip's queries overlap the next's
         q32 = q_ref[0].astype(jnp.float32)
         do32 = do_ref[0].astype(jnp.float32)
-        lse_col = lse_ref[0, 0][:, None]
-        delta_col = delta_ref[0, 0][:, None]
+        stats = [(lse_ref[0, _stat_row(e, pair)][:, None],
+                  delta_ref[0, _stat_row(e, pair)][:, None]) for e in heads]
         for cols, parts in strips:
-            k = k_ref[0, cols, :]
-            v = v_ref[0, cols, :].astype(jnp.float32)
+            k_cols = k_ref[0, cols, :]
+            v_cols = v_ref[0, cols, :].astype(jnp.float32)
             k0 = k_blk * block_k + cols.start
-            dk = dv = None
-            for qs, masked in parts:
-                s = _scores(q_ref[0, qs, :], k, q_blk * block_q + offset + qs.start, k0,
-                            scale=scale, slope=slope, masked=masked)
-                lse = lse_col[qs]
-                p = jnp.exp(s - lse)  # [rows, cols]
-                if guard:
-                    # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
-                    p = jnp.where(lse > NEG_INF / 2, p, 0.0)
-                do = do32[qs]
-                # dv += p^T @ do
-                part_v = jax.lax.dot_general(
-                    p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-                dp = jax.lax.dot_general(
-                    do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-                ds = p * (dp - delta_col[qs]) * scale  # [rows, cols]
-                # dk += ds^T @ q
-                part_k = jax.lax.dot_general(
-                    ds, q32[qs], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-                dv = part_v if dv is None else dv + part_v
-                dk = part_k if dk is None else dk + part_k
-            dv_s[cols, :] += dv
-            dk_s[cols, :] += dk
+            dks, dvs = [], []
+            for e, slope, (lse_col, delta_col) in zip(heads, slopes, stats):
+                k = _own_lanes(k_cols, e, pair)
+                v = _own_lanes(v_cols, e, pair)
+                dk = dv = None
+                for qs, masked in parts:
+                    s = _scores(q_ref[0, qs, :], k, q_blk * block_q + offset + qs.start, k0,
+                                scale=scale, slope=slope, masked=masked)
+                    lse = lse_col[qs]
+                    p = jnp.exp(s - lse)  # [rows, cols]
+                    if guard:
+                        # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
+                        p = jnp.where(lse > NEG_INF / 2, p, 0.0)
+                    do = do32[qs]
+                    # dv += p^T @ do
+                    part_v = jax.lax.dot_general(
+                        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                    dp = jax.lax.dot_general(
+                        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                    ds = p * (dp - delta_col[qs]) * scale  # [rows, cols]
+                    # dk += ds^T @ q
+                    part_k = jax.lax.dot_general(
+                        ds, q32[qs], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                    dv = part_v if dv is None else dv + part_v
+                    dk = part_k if dk is None else dk + part_k
+                dks.append(dk)
+                dvs.append(dv)
+            dv_s[cols, :] += _by_head(dvs)
+            dk_s[cols, :] += _by_head(dks)
 
     _run_tile(_compute, "k", q_blk, k_blk, block_q, block_k, causal=causal,
               offset=offset, sub=sub)
@@ -745,23 +1019,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
 
 
 def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
-         interpret=False):
-    """``dq_tile`` / ``dkv_tile``: each launch's own ``(block_q, block_k)``."""
+         interpret=False, heads: _Heads | None = None):
+    """``dq_tile`` / ``dkv_tile``: each launch's own ``(block_q, block_k)``.
+    ``heads``: as :func:`_fwd`; dO comes in and dq, dk, dv go out in the
+    layout of q, k, v."""
     q, k, v, o, lse = res
-    bh, s_q, d = q.shape
-    s_k, d_v = k.shape[1], v.shape[2]  # v, do and dv at v's width
+    s_q, s_k = q.shape[1], k.shape[1]
+    # v, do and dv at v's width
+    bh, bh_k, h_q, h_kv, d, d_v, at_q, at_kv, pair, layout = _launch_of(q, k, v, h_q, heads)
     offset = s_k - s_q
     itemsize = q.dtype.itemsize
-    bh_k = k.shape[0]
-    h_q = h_q or 1
-    h_kv = h_q * bh_k // bh
     group = h_q // h_kv
     kv = _kv_row(h_q, h_kv)
 
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [bh, s_q]
+    # delta = rowsum(dO * o). Over head-major rows XLA's fused sum is cheap,
+    # and over a pair's 64-wide heads too (0.5 ms a step of mpt125m-train).
+    # Whole-lane heads read in place make it in the dq launch, from the o
+    # block, and hand it on to dk/dv: XLA lays a [B, S, 20, 256] view out
+    # sequence-minor and paid a relayout of the float32 product for the sum
+    # (7 ms a step of glm47flash-train; PERF.md, PR 45)
+    makes_delta = layout == IN_PLACE
+    if makes_delta:
+        delta = None
+    elif pair:
+        h = lse.shape[0] // q.shape[0]
+        delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+            q.shape[0], s_q, h, -1), axis=-1)  # [B, s_q, H], then head-major like lse
+        delta = jnp.transpose(delta, (0, 2, 1)).reshape(lse.shape)
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [bh, s_q]
     # SUBLANE-replicated rows for TPU tiling (see _fwd)
-    lse_b = jnp.broadcast_to(lse[:, None, :], (bh, SUBLANE, s_q))
-    delta_b = jnp.broadcast_to(delta[:, None, :], (bh, SUBLANE, s_q))
+    lse_b = _stats_to_blocks(lse, pair)
+    delta_b = o if makes_delta else _stats_to_blocks(delta, pair)
 
     use_alibi = slopes is not None
     extra_inputs = [slopes] if use_alibi else []
@@ -774,28 +1063,36 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
     n_k = pl.cdiv(s_k, block_k)
     kj = functools.partial(_kv_block, causal=causal, block_q=block_q,
                            block_k=block_k, offset=offset, n_k=n_k)
+    dq_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: at_q(b, i))]
+    dq_shapes = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     launch_dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
                           causal=causal, offset=offset, use_alibi=use_alibi,
                           sub=strip_rows("dq", block_q, block_k, causal=causal,
-                                         offset=offset)),
+                                         offset=offset), pair=pair, makes_delta=makes_delta),
         grid=(bh, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), kj(i, j), 0)),  # k
-            pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (kv(b), kj(i, j), 0)),  # v
-            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),  # do
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: at_q(b, i)),  # q
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: at_kv(kv(b), kj(i, j))),  # k
+            pl.BlockSpec((1, block_k, d_v), lambda b, i, j: at_kv(kv(b), kj(i, j))),  # v
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: at_q(b, i)),  # do
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),  # lse
-            pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i)),  # delta
+            (pl.BlockSpec((1, block_q, d_v), lambda b, i, j: at_q(b, i)) if makes_delta  # o
+             else pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i))),  # delta
         ] + slope_spec,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=dq_specs + [
+            pl.BlockSpec((1, SUBLANE, block_q), lambda b, i, j: (b, 0, i))] if makes_delta
+        else dq_specs[0],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+        out_shape=dq_shapes + [jax.ShapeDtypeStruct((bh, SUBLANE, s_q), jnp.float32)]
+        if makes_delta else dq_shapes[0],
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("dq", block_q, block_k, d, itemsize, d_v)),
+        **_vmem_params(launch_vmem_bytes("dq", block_q, block_k, d, itemsize, d_v, layout)),
     )
     with _kernel_scope("flash_dq"):
         dq = launch_dq(q, k, v, do, lse_b, delta_b, *extra_inputs)
+    if makes_delta:
+        dq, delta_b = dq
 
     # dkv grid rows are the kv STORAGE rows; the inner dim sweeps the
     # group's q heads × q blocks so each kv row accumulates its whole
@@ -818,13 +1115,13 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
                           causal=causal, offset=offset, use_alibi=use_alibi, n_q=n_q,
                           sub=strip_rows("dkv", block_q, block_k, causal=causal,
-                                         offset=offset)),
+                                         offset=offset), pair=pair),
         grid=(bh_k, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, t: (qrow(b, t), qi(j, t), 0)),  # q
-            pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),  # k
-            pl.BlockSpec((1, block_k, d_v), lambda b, j, t: (b, j, 0)),  # v
-            pl.BlockSpec((1, block_q, d_v), lambda b, j, t: (qrow(b, t), qi(j, t), 0)),  # do
+            pl.BlockSpec((1, block_q, d), lambda b, j, t: at_q(qrow(b, t), qi(j, t))),  # q
+            pl.BlockSpec((1, block_k, d), lambda b, j, t: at_kv(b, j)),  # k
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, t: at_kv(b, j)),  # v
+            pl.BlockSpec((1, block_q, d_v), lambda b, j, t: at_q(qrow(b, t), qi(j, t))),  # do
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, j, t: (qrow(b, t), 0, qi(j, t))),  # lse
             pl.BlockSpec((1, SUBLANE, block_q), lambda b, j, t: (qrow(b, t), 0, qi(j, t))),  # delta
         ] + (
@@ -832,19 +1129,19 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
             if use_alibi else []
         ),
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, t: at_kv(b, j)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, t: at_kv(b, j)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh_k, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh_k, s_k, d_v), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("dkv", block_q, block_k, d, itemsize, d_v)),
+        **_vmem_params(launch_vmem_bytes("dkv", block_q, block_k, d, itemsize, d_v, layout)),
     )
     with _kernel_scope("flash_dkv"):
         dk, dv = launch_dkv(q, k, v, do, lse_b, delta_b, *extra_inputs)
@@ -863,24 +1160,29 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
 # non-learned constants). ``h_q`` (static) carries the q-head count for
 # grouped-query attention, where k/v hold fewer rows than q; 0 = MHA.
 # ``tiles`` (static) is ``((block_q, block_k),) * 3`` for the forward, dq and
-# dk/dv launches: ``TilePlan.blocks``.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0):
+# dk/dv launches: ``TilePlan.blocks``. ``heads`` (static) is ``None`` for
+# head-major operands, a ``_Heads`` for operands read in place; what is saved
+# for the backward is then the projections' own q, k, v and the o that
+# ``out_proj`` reads, no copy of any.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0, heads=None):
     o, _ = _fwd(q, k, v, scale=scale, causal=causal, block_q=tiles[0][0],
-                block_k=tiles[0][1], slopes=slopes, h_q=h_q, interpret=interpret)
+                block_k=tiles[0][1], slopes=slopes, h_q=h_q, interpret=interpret,
+                heads=heads)
     return o
 
 
-def _flash_fwd(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0):
+def _flash_fwd(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0, heads=None):
     o, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=tiles[0][0],
-                  block_k=tiles[0][1], slopes=slopes, h_q=h_q, interpret=interpret)
+                  block_k=tiles[0][1], slopes=slopes, h_q=h_q, interpret=interpret,
+                  heads=heads)
     return o, (q, k, v, o, lse, slopes)
 
 
-def _flash_bwd(scale, causal, tiles, interpret, h_q, res, do):
+def _flash_bwd(scale, causal, tiles, interpret, h_q, heads, res, do):
     q, k, v, o, lse, slopes = res
     dq, dk, dv = _bwd(scale, causal, tiles[1], tiles[2], (q, k, v, o, lse), do,
-                      slopes=slopes, h_q=h_q, interpret=interpret)
+                      slopes=slopes, h_q=h_q, interpret=interpret, heads=heads)
     return dq, dk, dv, jax.tree.map(jnp.zeros_like, slopes)
 
 
@@ -900,6 +1202,17 @@ def flash_attention(
     scale: float | None = None,
 ) -> jax.Array:
     """Flash attention over ``[batch, seq, heads, d_head]`` inputs.
+
+    Which arrays the three launches read follows the head widths
+    (:func:`flash_layout`): at whole-lane widths (``d_head % 128 == 0``, v's
+    too) the inputs' own ``[batch, seq, heads * d_head]`` arrays, a head a
+    column block (``in_place``); at 64 / 64 with as many k / v heads as q
+    heads (an even number) the same arrays, a column block a pair of heads
+    whose other half is masked where a lone head's pad would be zero
+    (``head_pairs``); otherwise transposed, padded ``[batch * heads, seq,
+    d_pad]`` copies, and the output transposed back (``head_major``). The
+    results are the same numbers; a span attribute (``TilePlan.attrs``) says
+    which layout a training step took.
 
     ``block_q`` / ``block_k`` ``None`` (what the models pass) derives each
     launch's tile from the shapes (:func:`pick_tiles`); an explicit value is
@@ -931,9 +1244,29 @@ def flash_attention(
     s_k, d_v = k.shape[1], v.shape[3]
     scale = 1.0 / (d**0.5) if scale is None else float(scale)
 
+    layout = flash_layout(h, h_kv, d, d_v)
+    # a pair's block is 128 lanes wide, as the lone head's padded one is
     d_pad = lane_padded(d)
     tiles = pick_tiles(s_q, s_k, d_pad, q.dtype.itemsize, h // h_kv, causal=causal,
-                       block_q=block_q, block_k=block_k, d_v_pad=lane_padded(d_v)).blocks
+                       block_q=block_q, block_k=block_k, d_v_pad=lane_padded(d_v),
+                       layout=layout).blocks
+
+    def bh_slopes(pair=False):
+        if not alibi:
+            return None
+        from photon_tpu.ops.attention import alibi_slopes as default_slopes
+
+        h_slopes = alibi_slopes if alibi_slopes is not None else default_slopes(h)
+        return _bh_slopes(h_slopes.astype(jnp.float32), b * h, pair)
+
+    heads = _Heads.of(layout, h, h_kv)
+    if heads is not None:
+        # the projections' own arrays: [B, S, H, D] -> [B, S, H·D] moves nothing
+        slopes = bh_slopes(heads.pair)
+        o = _flash(q.reshape(b, s_q, h * d), k.reshape(b, s_k, h_kv * d),
+                   v.reshape(b, s_k, h_kv * d_v), slopes, scale, causal, tiles, interpret,
+                   0, heads)
+        return o.reshape(b, s_q, h, d_v)
 
     def to_bh(x, s, heads):
         width = x.shape[3]
@@ -943,13 +1276,7 @@ def flash_attention(
         return x
 
     qb, kb, vb = to_bh(q, s_q, h), to_bh(k, s_k, h_kv), to_bh(v, s_k, h_kv)
-    slopes = None
-    if alibi:
-        from photon_tpu.ops.attention import alibi_slopes as default_slopes
-
-        h_slopes = alibi_slopes if alibi_slopes is not None else default_slopes(h)
-        slopes = _bh_slopes(h_slopes.astype(jnp.float32), b * h)
-    ob = _flash(qb, kb, vb, slopes, scale, causal, tiles, interpret,
+    ob = _flash(qb, kb, vb, bh_slopes(), scale, causal, tiles, interpret,
                 h if h_kv != h else 0)
     o = ob[..., :d_v].reshape(b, h, s_q, d_v)
     return jnp.transpose(o, (0, 2, 1, 3))

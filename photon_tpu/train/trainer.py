@@ -73,7 +73,8 @@ def _flash_tile_attrs(model_cfg) -> dict[str, str]:
     how many of its grid steps are live, as span attributes: static per
     shape, so they are told here, where the shapes are known, once for the
     step and not per launch. Empty unless the step holds the kernel."""
-    from photon_tpu.ops.flash_attention import lane_padded, pallas_supported, pick_tiles
+    from photon_tpu.ops.flash_attention import (
+        flash_layout, lane_padded, pallas_supported, pick_tiles)
 
     if model_cfg.attn_impl != "pallas" or not (
             model_cfg.attn_interpret or pallas_supported(None)):
@@ -87,11 +88,14 @@ def _flash_tile_attrs(model_cfg) -> dict[str, str]:
         return {"flash_tiles": " ".join(
             f"{n}={bq}x{bk}" for n, (bq, bk) in zip(LAUNCHES, plan_tiles(s, s)))}
     d_v = model_cfg.v_head_dim if model_cfg.latent_attention else model_cfg.d_head
+    n_kv = model_cfg.n_kv_heads or model_cfg.n_heads
+    # the layout the launches read, by the model's own heads (a shard of a
+    # tensor-parallel mesh applies the same rule to its local ones)
+    layout = flash_layout(model_cfg.n_heads, n_kv, model_cfg.d_head, d_v)
     return pick_tiles(
         s, s, lane_padded(model_cfg.d_head), jnp.dtype(model_cfg.compute_dtype).itemsize,
-        model_cfg.n_heads // (model_cfg.n_kv_heads or model_cfg.n_heads),
-        d_v_pad=lane_padded(d_v),
-    ).attrs()
+        model_cfg.n_heads // n_kv, d_v_pad=lane_padded(d_v), layout=layout,
+    ).attrs(layout)
 
 
 def _mamba_attrs(model_cfg) -> dict[str, int]:

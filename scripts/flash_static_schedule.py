@@ -10,6 +10,11 @@ grid step's code, every body it holds counted once) and the slot uses by unit
 
     python scripts/flash_static_schedule.py --launch fwd,dq,dkv --tile 2048 --sub 0,256
 
+``--layout auto`` (the default) compiles each launch over operands in the
+layout ``flash_attention`` reads for the shape (``flash_layout``: in place, or
+the pair body at d_head 64: what the cells run); ``--layout head_major`` over
+``to_bh``'s padded copies. ``--shape`` is ``batch*heads,seq,d_head`` either way.
+
 A count, not a time: on the chip a launch took 1.1 to 1.4 times its bundles
 at 1.5 GHz (PERF.md, PR 39), stalls and DMA waits being what the dump cannot
 see. It orders variants of one body well, which is what it is for: try a
@@ -37,7 +42,8 @@ sys.path.insert(0, str(ROOT))
 UNITS = ("MXU", "XLU", "VALU", "EUP", "VLOAD", "VLOAD_FILL", "VSTORE", "VSTORE_SPILL", "SALU")
 
 
-def compile_one(launch: str, shape: tuple[int, int, int], tile: int, sub: int, alibi: bool):
+def compile_one(launch: str, shape: tuple[int, int, int], tile: int, sub: int, alibi: bool,
+                layout: str = "auto"):
     """In the child: compile ``launch`` alone at one pinned square tile."""
     import jax
     import jax.numpy as jnp
@@ -48,10 +54,16 @@ def compile_one(launch: str, shape: tuple[int, int, int], tile: int, sub: int, a
 
     one = SingleDeviceSharding(abstract_tpu_devices("v5e:2x2x1")[0])
     bh, s, d = shape
-    d = fa.lane_padded(d)
-    x = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16, sharding=one)
+    took = fa.flash_layout(bh, bh, d, d) if layout == "auto" else layout
+    heads = fa._Heads.of(took, bh, bh)
+    if heads is None:
+        rows = bh
+        x = jax.ShapeDtypeStruct((bh, s, fa.lane_padded(d)), jnp.bfloat16, sharding=one)
+    else:  # one batch row of bh heads, read in place
+        rows = heads.q_cols
+        x = jax.ShapeDtypeStruct((1, s, bh * d), jnp.bfloat16, sharding=one)
     row = jax.ShapeDtypeStruct((bh, s), jnp.float32, sharding=one)
-    slopes = jax.ShapeDtypeStruct((bh, fa.SUBLANE, fa.LANE), jnp.float32, sharding=one)
+    slopes = jax.ShapeDtypeStruct((rows, fa.SUBLANE, fa.LANE), jnp.float32, sharding=one)
     fa.STRIP_ROWS = dict.fromkeys(fa.STRIP_ROWS, sub)
     small = (min(tile, 256),) * 2  # the launch that is not asked for is dropped as dead code
 
@@ -59,10 +71,10 @@ def compile_one(launch: str, shape: tuple[int, int, int], tile: int, sub: int, a
         sl = sl if alibi else None
         if launch == "fwd":
             return fa._fwd(q, k, v, scale=0.125, causal=True, block_q=tile, block_k=tile,
-                           slopes=sl)[0]
+                           slopes=sl, heads=heads)[0]
         grads = fa._bwd(0.125, True, (tile, tile) if launch == "dq" else small,
                         (tile, tile) if launch == "dkv" else small,
-                        (q, k, v, o, lse), do, slopes=sl)
+                        (q, k, v, o, lse), do, slopes=sl, heads=heads)
         return grads[0] if launch == "dq" else grads[1:]
 
     jax.jit(fn).lower(x, x, x, x, row, x, slopes).compile()
@@ -96,12 +108,15 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--sub", default=None, help="strip heights (0: the whole-tile body); "
                                                 "default: the module's STRIP_ROWS")
     ap.add_argument("--no-alibi", action="store_true")
+    ap.add_argument("--layout", default="auto", choices=["auto", "head_major"],
+                    help="auto: the layout flash_attention reads for the shape; "
+                         "head_major: to_bh's padded copies")
     ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     shape = tuple(int(x) for x in args.shape.split(","))
     if args.child:
         launch, tile, sub = args.child.split(",")
-        compile_one(launch, shape, int(tile), int(sub), not args.no_alibi)
+        compile_one(launch, shape, int(tile), int(sub), not args.no_alibi, args.layout)
         return 0
 
     from photon_tpu.ops.flash_attention import STRIP_ROWS
@@ -114,7 +129,7 @@ def main(argv: list[str] | None = None) -> int:
                     env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
                                LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true")
                     cmd = [sys.executable, __file__, "--shape", args.shape,
-                           "--child", f"{launch},{tile},{sub}"]
+                           "--layout", args.layout, "--child", f"{launch},{tile},{sub}"]
                     if args.no_alibi:
                         cmd.append("--no-alibi")
                     child = subprocess.run(cmd, env=env, capture_output=True, text=True)
@@ -123,8 +138,8 @@ def main(argv: list[str] | None = None) -> int:
                     except (RuntimeError, ValueError):
                         print(child.stderr[-2000:], file=sys.stderr)
                         raise
-                print(json.dumps({"launch": launch, "shape": list(shape), "tile": tile,
-                                  "sub": sub, **row}), flush=True)
+                print(json.dumps({"launch": launch, "shape": list(shape), "layout": args.layout,
+                                  "tile": tile, "sub": sub, **row}), flush=True)
     return 0
 
 

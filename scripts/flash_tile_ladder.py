@@ -2,7 +2,10 @@
 ``ops/flash_attention.pick_tiles`` is held to (PERF.md has the readings).
 
 Each of the three launches (forward, dq, dk/dv) is timed by itself at each
-tile, in the kernels' own ``[batch*heads, seq, d_pad]`` layout, bf16, causal:
+tile, bf16, causal, over operands in the layout ``flash_attention`` reads for
+the shape (``--layout auto``: ``[batch, seq, heads*d]`` in place or as head
+pairs where ``flash_layout`` says so, which is what the cells run) or in
+``to_bh``'s ``[batch*heads, seq, d_pad]`` copies (``--layout head_major``):
 ``--calls`` launches are queued back to back and waited for once, ``--rounds``
 times, and the median round is kept. ``--sub 0,128,256,512`` times every
 square tile once per strip height (``STRIP_ROWS`` for all three launches; 0 is
@@ -61,7 +64,7 @@ def _time(fn, args, calls: int, rounds: int) -> float:
     return statistics.median(per_call)
 
 
-def ladder(fa, shapes, tiles, subs, calls, rounds, alibi):
+def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto"):
     import jax
     import jax.numpy as jnp
 
@@ -71,30 +74,45 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi):
         fa.STRIP_ROWS = dict(shipped)
         d_pad = fa.lane_padded(d)
         bh = b * h
+        took = fa.flash_layout(h, h, d, d) if layout == "auto" else layout
+        pair = took == fa.HEAD_PAIRS
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
-        q, k, v, do = (jnp.pad(jax.random.normal(kk, (bh, s, d), jnp.bfloat16),
-                               ((0, 0), (0, 0), (0, d_pad - d))) for kk in keys)
+        heads = fa._Heads.of(took, h, h)
+        if heads is None:
+            q, k, v, do = (jnp.pad(jax.random.normal(kk, (bh, s, d), jnp.bfloat16),
+                                   ((0, 0), (0, 0), (0, d_pad - d))) for kk in keys)
+        else:  # the projections' own arrays, a head (or a pair) a column block
+            q, k, v, do = (jax.random.normal(kk, (b, s, h * d), jnp.bfloat16) for kk in keys)
         slopes = None
         if alibi:
             from photon_tpu.ops.attention import alibi_slopes
 
-            slopes = fa._bh_slopes(alibi_slopes(h), bh)
+            slopes = fa._bh_slopes(alibi_slopes(h), bh, pair)
         scale = 1.0 / d**0.5
         o, lse = jax.jit(lambda q, k, v: fa._fwd(
             q, k, v, scale=scale, causal=True, block_q=256, block_k=256,
-            slopes=slopes))(q, k, v)
-        plan = fa.pick_tiles(s, s, d_pad, 2)
+            slopes=slopes, heads=heads))(q, k, v)
+        plan = fa.pick_tiles(s, s, d_pad, 2, layout=took)
         picked_sub = {name: fa.strip_rows(name, t.block_q, t.block_k, causal=True, offset=0)
                       for name, t in zip(LAUNCHES, plan)}
+
         # what every dq / dkv reading holds besides its kernel: _bwd's delta
         # and the sublane-replicated lse / delta, the same at every tile
-        prologue = _time(jax.jit(lambda o, lse, do: (
-            jnp.broadcast_to(jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                                     axis=-1)[:, None, :], (bh, fa.SUBLANE, s)),
-            jnp.broadcast_to(lse[:, None, :], (bh, fa.SUBLANE, s)))),
-            (o, lse, do), calls, rounds)
-        print(json.dumps({"shape": [b, h, s, d], "bwd_prologue_ms": prologue}), flush=True)
-        rows.append({"shape": [b, h, s, d], "bwd_prologue_ms": prologue})
+        def prologue_fn(o, lse, do):
+            if took == fa.IN_PLACE:  # the dq launch makes delta itself
+                return fa._stats_to_blocks(lse, pair)
+            delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+            if heads is None:
+                delta = jnp.sum(delta, axis=-1)
+            else:
+                delta = jnp.transpose(jnp.sum(delta.reshape(b, s, h, d), axis=-1),
+                                      (0, 2, 1)).reshape(bh, s)
+            return fa._stats_to_blocks(delta, pair), fa._stats_to_blocks(lse, pair)
+
+        prologue = _time(jax.jit(prologue_fn), (o, lse, do), calls, rounds)
+        head = {"shape": [b, h, s, d], "layout": took, "bwd_prologue_ms": prologue}
+        print(json.dumps(head), flush=True)
+        rows.append(head)
         for (bq, bk), sub in ((t, sub) for t in tiles for sub in subs):
             if s % bq or s % bk:
                 continue
@@ -102,7 +120,7 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi):
             if sub and (bq != bk or bq % sub):
                 continue
             fa.STRIP_ROWS = dict.fromkeys(LAUNCHES, sub)
-            row = {"shape": [b, h, s, d], "tile": [bq, bk], "sub": sub,
+            row = {"shape": [b, h, s, d], "layout": took, "tile": [bq, bk], "sub": sub,
                    "executed_share": {
                        name: round(fa.executed_pairs(name, s, s, bq, bk)
                                    / fa.visible_pairs(s, s), 4) for name in LAUNCHES},
@@ -112,13 +130,13 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi):
             launches = {
                 "fwd": (lambda q, k, v, o, lse, do: fa._fwd(
                     q, k, v, scale=scale, causal=True, block_q=bq, block_k=bk,
-                    slopes=slopes)[0]),
+                    slopes=slopes, heads=heads)[0]),
                 "dq": (lambda q, k, v, o, lse, do: fa._bwd(
                     scale, True, (bq, bk), (bq, bk), (q, k, v, o, lse), do,
-                    slopes=slopes)[0]),
+                    slopes=slopes, heads=heads)[0]),
                 "dkv": (lambda q, k, v, o, lse, do: fa._bwd(
                     scale, True, (bq, bk), (bq, bk), (q, k, v, o, lse), do,
-                    slopes=slopes)[1:]),
+                    slopes=slopes, heads=heads)[1:]),
             }
             for name, fn in launches.items():
                 try:
@@ -192,6 +210,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--calls", type=int, default=40)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--alibi", action="store_true")
+    ap.add_argument("--layout", default="auto", choices=["auto", "head_major"],
+                    help="auto: the layout flash_attention reads for each shape "
+                         "(what the cells run); head_major: to_bh's copies")
     ap.add_argument("--no-clamp", action="store_true")
     ap.add_argument("--parent", default=None)
     ap.add_argument("--interpret", action="store_true")
@@ -209,7 +230,7 @@ def main(argv: list[str] | None = None) -> int:
         fa._kv_block = lambda i, j, **kw: j
         fa._q_block = lambda i, j, **kw: i
     result = {"device": jax.devices()[0].device_kind, "jax": jax.__version__,
-              "clamp": not args.no_clamp, "alibi": args.alibi}
+              "clamp": not args.no_clamp, "alibi": args.alibi, "layout": args.layout}
     if args.parent:
         result["against_parent"] = against_parent(fa, args.parent, args.interpret)
     if not args.interpret:
@@ -217,7 +238,8 @@ def main(argv: list[str] | None = None) -> int:
         tiles = [tuple(int(x) for x in t.split("x")) for t in args.tiles.split(",")]
         subs = ([int(x) for x in args.sub.split(",")] if args.sub
                 else sorted({0, *fa.STRIP_ROWS.values()}))
-        result["ladder"] = ladder(fa, shapes, tiles, subs, args.calls, args.rounds, args.alibi)
+        result["ladder"] = ladder(fa, shapes, tiles, subs, args.calls, args.rounds, args.alibi,
+                                  args.layout)
     if args.out:
         out = pathlib.Path(args.out)
         out.mkdir(parents=True, exist_ok=True)

@@ -465,3 +465,135 @@ def test_lone_tile_forward_is_the_carried_one(alibi, monkeypatch):
     for name, a, ref in zip(("o", "lse"), lone, run()):
         assert _rel(a, ref) < 1e-6, (alibi, name)
     assert _rel(lone[0], xla_attention(q, k, v, causal=True, alibi=alibi)) < 2e-5
+
+
+# ---------------------------------------------------------------------------
+# the launches read the projections' own arrays (PR 45): in place, head pairs
+# ---------------------------------------------------------------------------
+
+# case -> (heads, kv heads, d, d_v, s_q, s_k, tile, strip rows, alibi, layout)
+LAYOUT_CASES = {
+    "256/256 MHA": (2, 2, 256, 256, 256, 256, 128, 0, False, "in_place"),
+    "128/128 GQA 4": (4, 1, 128, 128, 256, 256, 128, 0, False, "in_place"),
+    "128/256 v wider": (2, 2, 128, 256, 256, 256, 128, 0, False, "in_place"),
+    "256/128 alibi": (2, 1, 256, 128, 256, 256, 128, 0, True, "in_place"),
+    "64/64 MHA pairs": (4, 4, 64, 64, 256, 256, 128, 0, False, "head_pairs"),
+    "64/64 MHA pairs + alibi": (4, 4, 64, 64, 256, 256, 128, 0, True, "head_pairs"),
+    "s_q != s_k": (2, 2, 128, 128, 128, 384, 128, 0, False, "in_place"),
+    "pairs, s_q != s_k": (2, 2, 64, 64, 128, 384, 128, 0, True, "head_pairs"),
+    # one tile holds the sequence: the forward's strips write straight out
+    "strip tile": (2, 2, 128, 128, 512, 512, 512, 128, False, "in_place"),
+    "pairs, strip tile": (2, 2, 64, 64, 512, 512, 512, 128, True, "head_pairs"),
+    # tiles under the diagonal: the running state is carried in the scratch
+    "carried tile": (2, 2, 128, 128, 512, 512, 256, 128, False, "in_place"),
+    "pairs, carried tile": (6, 6, 64, 64, 512, 512, 256, 128, False, "head_pairs"),
+    "pairs, carried masked tile": (2, 2, 64, 64, 512, 512, 256, 0, True, "head_pairs"),
+}
+
+
+def _layout_run(case, monkeypatch, forced=None):
+    """``(o, lse, dq, dk, dv)`` of one case, and the layout its launches took;
+    ``forced`` makes the call take that layout whatever its widths."""
+    from photon_tpu.ops import flash_attention as fa
+
+    h, h_kv, d, d_v, s_q, s_k, tile, sub, alibi, _ = LAYOUT_CASES[case]
+    monkeypatch.setattr(fa, "STRIP_ROWS", dict.fromkeys(("fwd", "dq", "dkv"), sub))
+    if forced:
+        monkeypatch.setattr(fa, "flash_layout", lambda *a: forced)
+    seen = {}
+    real_fwd = fa._fwd
+
+    def spy(q, k, v, **kw):
+        o, lse = real_fwd(q, k, v, **kw)
+        heads = kw.get("heads")
+        seen["layout"] = ("head_major" if heads is None
+                          else "head_pairs" if heads.pair else "in_place")
+        seen.setdefault("lse", lse)
+        return o, lse
+
+    monkeypatch.setattr(fa, "_fwd", spy)
+    ks = jax.random.split(jax.random.PRNGKey(45), 4)
+    q = jax.random.normal(ks[0], (B, s_q, h, d))
+    k = jax.random.normal(ks[1], (B, s_k, h_kv, d))
+    v = jax.random.normal(ks[2], (B, s_k, h_kv, d_v))
+    w = jax.random.normal(ks[3], (B, s_q, h, d_v))
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, alibi=alibi, block_q=tile,
+                               block_k=tile, interpret=True)
+
+    o, pull = jax.vjp(attend, q, k, v)
+    return (o, seen["lse"], *pull(w)), seen["layout"]
+
+
+@pytest.mark.parametrize("case", list(LAYOUT_CASES))
+def test_layouts_equal_the_head_major_path(case, monkeypatch):
+    """The launches over the projections' own ``[B, S, H·D]`` arrays give what
+    they give over ``to_bh``'s transposed and padded copies: the output, the
+    log-sum-exp and all three gradients. A pair's body does at its masked
+    lanes what the padded body does at its pad, so at equal tiles the two are
+    equal bit for bit; in place the bodies are the same and only ``delta``'s
+    sum runs over another view."""
+    want = LAYOUT_CASES[case][-1]
+    new, took = _layout_run(case, monkeypatch)
+    assert took == want
+    old, took = _layout_run(case, monkeypatch, forced="head_major")
+    assert took == "head_major"
+    for name, a, ref in zip(("o", "lse", "dq", "dk", "dv"), new, old):
+        assert a.shape == ref.shape and a.dtype == ref.dtype, (case, name)
+        if want == "head_pairs":
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(ref), err_msg=f"{case} {name}")
+        else:
+            assert _rel(a, ref) < 2e-5, (case, name)
+
+
+# what each benchmark configuration hands the kernel, and shapes beside them:
+# (q heads, kv heads, d, d_v) -> the layout
+@pytest.mark.parametrize("heads,layout", [
+    pytest.param((12, 12, 64, 64), "head_pairs", id="mpt-125m"),
+    pytest.param((20, 20, 256, 256), "in_place", id="glm-4.7-flash-ep8"),
+    pytest.param((32, 32, 192, 128), "head_major", id="xing4.0-29b-a4b-ep8"),
+    pytest.param((32, 8, 64, 64), "head_major", id="lfm2-8b-a1b-ep4"),
+    pytest.param((40, 8, 64, 64), "head_major", id="granite-4.0-h-micro-stage1"),
+    pytest.param((16, 16, 128, 128), "in_place", id="mpt-1b"),
+    pytest.param((16, 4, 128, 128), "in_place", id="gqa-128"),
+    pytest.param((3, 3, 64, 64), "head_major", id="odd-heads-64"),
+    pytest.param((6, 6, 64, 128), "head_major", id="64-with-v-128"),
+    pytest.param((4, 4, 32, 32), "head_major", id="d32"),
+])
+def test_layout_follows_the_head_widths(heads, layout):
+    from photon_tpu.ops import flash_attention as fa
+
+    assert fa.flash_layout(*heads) == layout
+
+
+def test_benchmark_presets_take_their_layouts():
+    """The rule applied to the presets the benchmark's cells run."""
+    from photon_tpu.config import load_preset
+    from photon_tpu.ops import flash_attention as fa
+
+    took = {}
+    for preset in ("mpt-125m", "glm-4.7-flash-ep8", "xing4.0-29b-a4b-ep8",
+                   "lfm2-8b-a1b-ep4", "granite-4.0-h-micro-stage1"):
+        m = load_preset(preset).model
+        d_v = m.v_head_dim if m.latent_attention else m.d_head
+        took[preset] = fa.flash_layout(m.n_heads, m.n_kv_heads or m.n_heads, m.d_head, d_v)
+    assert took == {"mpt-125m": "head_pairs", "glm-4.7-flash-ep8": "in_place",
+                    "xing4.0-29b-a4b-ep8": "head_major", "lfm2-8b-a1b-ep4": "head_major",
+                    "granite-4.0-h-micro-stage1": "head_major"}
+
+
+def test_lse_variant_stays_head_major(monkeypatch):
+    """The ring's inner kernel keeps ``to_bh``: its launch takes no
+    ``heads``, at a width that ``flash_attention`` reads in place."""
+    from photon_tpu.ops import flash_attention as fa
+
+    seen = []
+    real_fwd = fa._fwd
+    monkeypatch.setattr(fa, "_fwd", lambda *a, **kw: (seen.append(kw.get("heads")),
+                                                      real_fwd(*a, **kw))[1])
+    q, k, v = _qkv(d=128)
+    flash_attention_with_lse(q, k, v, causal=True, block_q=BLOCK, block_k=BLOCK,
+                             interpret=True)
+    flash_attention(q, k, v, causal=True, block_q=BLOCK, block_k=BLOCK, interpret=True)
+    assert seen[0] is None and seen[1] == fa._Heads(H, H)

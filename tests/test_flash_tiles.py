@@ -68,7 +68,10 @@ def test_pick_tiles_divides_fits_and_counts(s_q, s_k, d_pad, itemsize, group):
         assert 0 < t.live_tiles <= t.grid_tiles
     assert plan.blocks == tuple((t.block_q, t.block_k) for t in plan)
     attrs = plan.attrs()
-    assert set(attrs) == {"flash_tiles", "flash_live_tiles", "flash_executed_share"}
+    assert set(attrs) == {"flash_layout", "flash_tiles", "flash_live_tiles",
+                          "flash_executed_share"}
+    assert attrs["flash_layout"] == fa.HEAD_MAJOR
+    assert plan.attrs(fa.IN_PLACE) == {**attrs, "flash_layout": "in_place"}
     assert f"fwd={plan.fwd.block_q}x{plan.fwd.block_k}" in attrs["flash_tiles"]
     assert f"fwd={plan.fwd.executed_share:.3f}" in attrs["flash_executed_share"]
 
@@ -260,12 +263,17 @@ def test_maps_are_the_identity_without_a_mask():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("impl,interpret,told", [
-    ("pallas", True, True),    # the kernel is in the step (the interpreter runs it)
-    ("pallas", False, False),  # the CPU backend steps down to XLA: nothing to tell
-    ("xla", False, False),
+@pytest.mark.parametrize("impl,interpret,told,d_model,layout", [
+    # the kernel is in the step (the interpreter runs it): d_head 32, padded copies
+    ("pallas", True, True, 64, "head_major"),
+    ("pallas", True, True, 128, "head_pairs"),  # two heads of 64: one column block
+    ("pallas", True, True, 256, "in_place"),  # d_head 128: a head is a column block
+    # the CPU backend steps down to XLA: nothing to tell
+    ("pallas", False, False, 64, None),
+    ("xla", False, False, 64, None),
 ])
-def test_trainer_steps_span_carries_the_tile_plan(impl, interpret, told, monkeypatch):
+def test_trainer_steps_span_carries_the_tile_plan(impl, interpret, told, d_model, layout,
+                                                  monkeypatch):
     import numpy as np
 
     from photon_tpu import telemetry
@@ -276,7 +284,7 @@ def test_trainer_steps_span_carries_the_tile_plan(impl, interpret, told, monkeyp
     from photon_tpu.utils.profiling import TRAINER_STEPS_SPAN
 
     cfg = Config(
-        model=ModelConfig(d_model=64, n_layers=1, n_heads=2, max_seq_len=128,
+        model=ModelConfig(d_model=d_model, n_layers=1, n_heads=2, max_seq_len=128,
                           vocab_size=64, attn_impl=impl, attn_interpret=interpret,
                           compute_dtype="float32"),
         mesh=MeshConfig(),
@@ -299,7 +307,8 @@ def test_trainer_steps_span_carries_the_tile_plan(impl, interpret, told, monkeyp
     if told:
         plan = pick_tiles(128, 128, fa.LANE, 4)
         assert plan.blocks == ((128, 128),) * 3
-        assert {k: attrs[k] for k in plan.attrs()} == plan.attrs()
+        assert {k: attrs[k] for k in plan.attrs()} == plan.attrs(layout)
+        assert attrs["flash_layout"] == layout
         assert attrs["flash_live_tiles"] == "fwd=1/1 dq=1/1 dkv=1/1"
     else:
         assert set(attrs) == {"steps"}

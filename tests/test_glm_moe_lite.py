@@ -718,3 +718,46 @@ def test_the_preset_is_what_the_benchmark_configuration_states():
     config["model"]["moe_top_k"] = 6
     with pytest.raises(ValueError, match="moe_top_k"):
         build_config(config, traffic, ROOT / ".bench_work" / "unused", seed=1)
+
+
+# ---------------------------------------------------------------------------
+# whole-lane head widths: k and v straight out of their products (PR 45)
+# ---------------------------------------------------------------------------
+
+WHOLE_LANES = dict(qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=128)
+
+
+@pytest.mark.parametrize("widths,in_place", [({}, False), (WHOLE_LANES, True)],
+                         ids=["tiny-widths", "whole-lanes"])
+def test_latent_kv_in_place_is_the_reference(widths, in_place, monkeypatch):
+    """Where the flash launches read their operands in place (head widths of
+    whole lanes, as the preset's 256 / 256), ``_latent_qkv`` makes k and v by
+    products with parts of ``kv_b_proj`` and an identity block for the shared
+    rotary key. The tree, the seeded init, the logits and every gradient are
+    what the sliced and concatenated form gives, and the plain reference's."""
+    from photon_tpu.models import init_params, mpt
+
+    cfg = tiny_cfg(**widths)
+    took = []
+    real = mpt.MPTBlock._latent_kv_in_place
+    monkeypatch.setattr(mpt.MPTBlock, "_latent_kv_in_place",
+                        lambda self, *a: (took.append(1), real(self, *a))[1])
+    dims = dims_of(cfg)
+    params = ref.make_params(dims, 11)
+    model = MPTModel(cfg.model)
+    loss = make_loss_fn(model, 16)
+    got = jax.value_and_grad(loss)(params, TOKENS)
+    assert bool(took) == in_place
+    n = TOKENS.shape[0] * (TOKENS.shape[1] - 1)
+    want = jax.value_and_grad(lambda p: ref.ce_sum(p, TOKENS, dims) / n)(params)
+    assert abs(float(got[0]) - float(want[0])) < 1e-5
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got[1]),
+                            jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4, err_msg=str(path))
+    # the same tree and the same seeded init as the path that calls nn.Dense
+    mine = init_params(cfg.model, seed=0)
+    monkeypatch.setattr(mpt, "flash_layout", lambda *a: "head_major")
+    theirs = init_params(cfg.model, seed=0)
+    assert leaf_names(mine) == leaf_names(theirs)
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
+        np.testing.assert_array_equal(a, b)

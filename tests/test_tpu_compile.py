@@ -136,21 +136,71 @@ def test_flash_vmem_estimate_is_enough(one_chip, monkeypatch, d, block_q, block_
     inside its own estimate (tall, wide and square tiles; the widths and
     dtypes whose buffers differ). A square tile's launches hold the strip
     bodies beside the whole-tile one, a tall or wide tile's the masked body
-    alone."""
+    alone. The launches read the layout the widths give (``flash_layout``):
+    the pair body at 64, in place at 128 and 256."""
+    _vmem_estimate_holds(one_chip, monkeypatch, (1, 2048, 4, d), dtype, block_q, block_k,
+                         {64: "head_pairs", 128: "in_place", 256: "in_place"}[d])
+
+
+def _vmem_estimate_holds(one_chip, monkeypatch, shape, dtype, block_q, block_k, layout,
+                         forced=None, kv_heads=None, d_v=None):
+    """Compiles forward and backward of ``shape``'s attention with every
+    launch held to its own estimate, and says the ``layout`` its launches took."""
     from photon_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "VMEM_SCOPED_DEFAULT", 0)
+    if forced:
+        monkeypatch.setattr(fa, "flash_layout", lambda *a: forced)
     for launch in ("fwd", "dq", "dkv"):
-        strips = fa.strip_rows(launch, block_q, block_k, causal=True, offset=0)
-        assert bool(strips) == (block_q == block_k)
-    q = _abstract((1, 2048, 4, d), dtype, one_chip)
+        if block_q is not None:
+            strips = fa.strip_rows(launch, block_q, block_k, causal=True, offset=0)
+            assert bool(strips) == (block_q == block_k)
+    b, s, h, d = shape
+    q = _abstract(shape, dtype, one_chip)
+    k = _abstract((b, s, kv_heads or h, d), dtype, one_chip)
+    v = _abstract((b, s, kv_heads or h, d_v or d), dtype, one_chip)
+    assert fa.flash_layout(h, kv_heads or h, d, d_v or d) == layout
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, alibi=True, block_q=block_q,
                                   block_k=block_k).astype(jnp.float32).sum()
 
-    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
     assert hlo.count(KERNEL) >= 3
+    # to_bh's copies are head-major arrays; read in place there is none
+    head_major = re.search(rf"\[{b * h},{s},{fa.lane_padded(d)}\]", hlo) is not None
+    assert head_major == (layout == "head_major")
+
+
+@pytest.mark.parametrize(
+    "d,block_q,block_k",
+    [(64, 512, 512), (128, 1024, 1024), (256, 2048, 512), (64, 2048, 2048)],
+    ids=lambda v: str(v),
+)
+def test_flash_vmem_estimate_is_enough_for_head_major_copies(one_chip, monkeypatch, d,
+                                                             block_q, block_k):
+    """The same launches over ``to_bh``'s transposed and padded copies, the
+    layout every shape had before PR 45 and grouped heads at 64, a 192-wide
+    head and the ring's kernel still have."""
+    _vmem_estimate_holds(one_chip, monkeypatch, (1, 2048, 4, d), jnp.bfloat16, block_q,
+                         block_k, "head_major", forced="head_major")
+
+
+@pytest.mark.parametrize("shape,kv_heads,d_v,layout", [
+    pytest.param((4, 2048, 12, 64), None, None, "head_pairs", id="mpt125m-train"),
+    pytest.param((2, 4096, 20, 256), None, None, "in_place", id="glm47flash-train"),
+    pytest.param((1, 4096, 32, 192), None, 128, "head_major", id="xing4-train-4k"),
+    pytest.param((1, 8192, 32, 64), 8, None, "head_major", id="lfm2moe-train-8k"),
+    pytest.param((1, 8192, 40, 64), 8, None, "head_major", id="granite4hmicro-train"),
+])
+def test_flash_vmem_estimate_is_enough_at_the_cells_tiles(one_chip, monkeypatch, shape,
+                                                          kv_heads, d_v, layout):
+    """Each cell's heads at the tiles ``pick_tiles`` derives for them, in the
+    layout their widths give: two heads of 64 side by side (the pair body's
+    second set of score temporaries is in its estimate), 20 heads of 256 in
+    place, and the three that keep ``to_bh``."""
+    _vmem_estimate_holds(one_chip, monkeypatch, shape, jnp.bfloat16, None, None, layout,
+                         kv_heads=kv_heads, d_v=d_v)
 
 
 def test_flash_attention_with_lse_compiles(one_chip):
@@ -572,6 +622,12 @@ def test_the_index_loss_keeps_the_heads_scores_in_vmem(one_chip, monkeypatch, im
 def _compile_train_step(cfg, devices, monkeypatch=None):
     """The jitted train step the Trainer would build for ``cfg``, compiled
     against described devices from shapes alone."""
+    lowered, state = _lower_train_step(cfg, devices, monkeypatch)
+    return lowered.compile(), state
+
+
+def _lower_train_step(cfg, devices, monkeypatch=None):
+    """That step lowered for the described devices, not yet compiled."""
     from photon_tpu.config.schema import effective_model_config
     from photon_tpu.models.mpt import MPTModel, init_params
     from photon_tpu.optim import build_optimizer
@@ -610,7 +666,7 @@ def _compile_train_step(cfg, devices, monkeypatch=None):
     jitted = jax.jit(step, in_shardings=(shardings, batch_sh),
                      out_shardings=(shardings, None), donate_argnums=0)
     with use_mesh(mesh):
-        return jitted.lower(state, tokens).compile(), state
+        return jitted.lower(state, tokens), state
 
 
 def _live_gib(compiled) -> float:
@@ -717,3 +773,59 @@ def test_autotune_hbm_estimate_brackets_tpu_memory_analysis(topo_devices):
     )
     # and both respect the chip the tuner said it fits
     assert live < HardwareModel().hbm_bytes
+
+
+# ---------------------------------------------------------------------------
+# which layout a preset's train step hands the flash launches (PR 45)
+# ---------------------------------------------------------------------------
+
+# each preset cut to a toy in depth, sequence, vocabulary and experts, its
+# HEAD widths (and its grouping of them) the published ones
+LAYOUT_PRESETS = {
+    "mpt-125m": (dict(n_layers=2, n_heads=4, d_model=256, max_seq_len=256, vocab_size=256),
+                 "head_pairs"),
+    "glm-4.7-flash-ep8": (dict(
+        n_layers=2, n_heads=2, d_model=128, max_seq_len=256, vocab_size=256, q_lora_rank=32,
+        kv_lora_rank=128, dense_mlp_hidden_size=128, mlp_hidden_size=128, moe_num_experts=8,
+        moe_top_k=2, moe_experts_held=4), "in_place"),
+    "xing4.0-29b-a4b-ep8": (dict(
+        n_layers=2, n_heads=2, d_model=128, max_seq_len=256, vocab_size=256, q_lora_rank=32,
+        kv_lora_rank=128, rope_scaling_original_max_position=64, dense_mlp_hidden_size=128,
+        mlp_hidden_size=128, moe_num_experts=8, moe_top_k=2, moe_experts_held=4),
+        "head_major"),
+    "lfm2-8b-a1b-ep4": (dict(
+        d_model=256, n_heads=4, n_kv_heads=2, max_seq_len=256, vocab_size=256,
+        dense_mlp_hidden_size=128, mlp_hidden_size=128, moe_num_experts=8, moe_top_k=2,
+        moe_experts_held=4), "head_major"),
+    "granite-4.0-h-micro-stage1": (dict(
+        d_model=256, n_layers=4, layer_types="mamba,mamba,attention,mamba", n_heads=4,
+        n_kv_heads=2, max_seq_len=256, vocab_size=256, mamba_n_heads=4, mamba_d_head=16,
+        mamba_d_state=8, mamba_chunk_size=64, mlp_hidden_size=128), "head_major"),
+}
+TO_BH = re.compile(r"stablehlo\.transpose.*dims = \[0, 2, 1, 3\]")
+
+
+@pytest.mark.parametrize("preset", list(LAYOUT_PRESETS))
+def test_a_presets_train_step_takes_its_layout(topo_devices, monkeypatch, preset):
+    """The rule by head widths, seen in a whole lowered train step: ``xing4``
+    (q / k 192, v 128), ``lfm2`` and ``granite`` (grouped heads of 64) tell
+    ``head_major`` on ``trainer/steps`` and still lower ``to_bh``'s
+    transposes to head-major around their launches; ``mpt-125m`` (pairs of
+    64-wide heads) and ``glm`` (256 / 256) hand the launches ``[B, S, H·D]``
+    and lower no such transpose."""
+    from photon_tpu.config import load_preset
+    from photon_tpu.train.trainer import _flash_tile_attrs
+
+    overrides, layout = LAYOUT_PRESETS[preset]
+    cfg = load_preset(preset)
+    for key, value in overrides.items():
+        setattr(cfg.model, key, value)
+    cfg.train.global_batch_size = cfg.train.device_microbatch_size = 2
+    lowered, _ = _lower_train_step(cfg, topo_devices()[:1], monkeypatch)
+    text = lowered.as_text()
+    assert text.count(KERNEL) >= 3
+    assert _flash_tile_attrs(cfg.model)["flash_layout"] == layout
+    assert bool(TO_BH.search(text)) == (layout == "head_major")
+    heads, d = cfg.model.n_heads, cfg.model.d_head
+    copies = f"tensor<{2 * heads}x256x{-(-d // 128) * 128}x"  # to_bh's [B·H, S, d_pad]
+    assert (copies in text) == (layout == "head_major")
