@@ -13,12 +13,19 @@ Design differences (deliberate, TPU-host-native):
 - The segment is self-describing: a fixed header (magic, payload length,
   metadata length, commit flag) precedes the metadata JSON and the raw
   array bytes, so readers need only the name. The commit flag is written
-  last (after an ``mmap.flush``-visible full payload), making the
-  write-then-spin-wait protocol race-free without locks (single-writer /
-  multi-reader, reference protocol: ``worker.py:241-252`` spin-wait).
-- Large copies fan out over a thread pool (numpy releases the GIL on
-  memcpy), the analog of the reference's threaded ``set_parameters_shm``
-  (``shm/utils.py:626-651``).
+  last, after the whole payload, into a private staging file that is then
+  renamed over the name, making the write-then-spin-wait protocol
+  race-free without locks (single-writer / multi-reader, reference
+  protocol: ``worker.py:241-252`` spin-wait).
+- The writer maps nothing: it ``os.pwrite``s the metadata and each array
+  at its offset, one after another (the reference's ``set_parameters_shm``
+  copies through a mapping on threads, ``shm/utils.py:626-651``). A store
+  through a new mapping takes a page fault for each 4 KiB of a new tmpfs
+  file, ~121,000 of them for mpt-125m's 0.49 GB, and where a fault is dear
+  that is 1.03 s against 0.22 s for the same bytes through ``write()``;
+  chunks of 8-64 MiB on 2-8 threads write no faster, 0.22-0.24 s (PERF.md
+  section 6, PR 47). A full ``/dev/shm`` is an ``OSError(ENOSPC)`` in the
+  writer, not a ``SIGBUS``.
 
 Layout: ``[16B header][metadata JSON, space-padded][payload bytes]``.
 Header: magic ``u32``, version ``u32``, meta_len ``u32``, committed ``u32``.
@@ -36,7 +43,7 @@ import pathlib
 import pickle
 import struct
 import time
-from concurrent.futures import ThreadPoolExecutor
+from collections.abc import Iterable
 from typing import Any
 
 import numpy as np
@@ -49,8 +56,6 @@ _VERSION = 1
 _HEADER = struct.Struct("<IIII")
 _PAYLOAD_ALIGN = 64  # a cache line; what a zero-copy ``device_put`` asks for
 _MAP_POPULATE = getattr(mmap, "MAP_POPULATE", 0)  # Linux; elsewhere pages fault in
-_COPY_CHUNK = 64 << 20  # 64 MiB per copy task
-_POOL = ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1))
 
 # name suffixes (reference: ``shm/constants.py:5-12`` `{uuid}+suffix` scheme)
 PARAMS_SUFFIX = "-params"
@@ -66,53 +71,31 @@ def _path(name: str) -> pathlib.Path:
 
 
 class ShmSegment:
-    """A mapped segment; use the module-level helpers for one-shot IO."""
+    """A segment mapped read-only; use the module-level helpers for one-shot IO."""
 
-    def __init__(
-        self,
-        name: str,
-        size: int | None = None,
-        create: bool = False,
-        path: pathlib.Path | None = None,
-        populate: bool = False,
-    ):
+    def __init__(self, name: str, populate: bool = False):
         self.name = name
-        p = path if path is not None else _path(name)
-        if create:
-            if size is None:
-                raise ValueError("size required to create")
-            fd = os.open(p, os.O_CREAT | os.O_RDWR, 0o600)
-            try:
-                os.ftruncate(fd, _HEADER.size + size)
-                self.mm = mmap.mmap(fd, _HEADER.size + size)
-            finally:
-                os.close(fd)
-            self.mm[: _HEADER.size] = _HEADER.pack(_MAGIC, _VERSION, 0, 0)
-        else:
-            # read-only: a write through a reader's view raises instead of
-            # changing the segment under its other readers. ``populate``
-            # (for a reader that will touch every page) fills the page table
-            # in the one call: where a fault is dear, 0.03 s for 0.5 GB
-            # against 0.87 s of faults under the first read pass, a
-            # ``device_put`` included (PERF.md section 6, PR 32)
-            flags = mmap.MAP_SHARED | (_MAP_POPULATE if populate else 0)
-            fd = os.open(p, os.O_RDONLY)
-            try:
-                total = os.fstat(fd).st_size
-                self.mm = mmap.mmap(fd, total, flags=flags, prot=mmap.PROT_READ)
-            finally:
-                os.close(fd)
-            magic, version, _, _ = _HEADER.unpack_from(self.mm, 0)
-            if magic != _MAGIC or version != _VERSION:
-                raise ValueError(f"segment {name!r} has bad header")
+        # read-only: a write through a reader's view raises instead of
+        # changing the segment under its other readers. ``populate`` (for a
+        # reader that will touch every page) fills the page table in the one
+        # call: where a fault is dear, 0.03 s for 0.5 GB against 0.87 s of
+        # faults under the first read pass, a ``device_put`` included
+        # (PERF.md section 6, PR 32)
+        flags = mmap.MAP_SHARED | (_MAP_POPULATE if populate else 0)
+        fd = os.open(_path(name), os.O_RDONLY)
+        try:
+            total = os.fstat(fd).st_size
+            self.mm = mmap.mmap(fd, total, flags=flags, prot=mmap.PROT_READ)
+        finally:
+            os.close(fd)
+        magic, version, _, _ = _HEADER.unpack_from(self.mm, 0)
+        if magic != _MAGIC or version != _VERSION:
+            raise ValueError(f"segment {name!r} has bad header")
 
     # -- header ---------------------------------------------------------
     @property
     def committed(self) -> bool:
         return _HEADER.unpack_from(self.mm, 0)[3] == 1
-
-    def commit(self, meta_len: int) -> None:
-        self.mm[: _HEADER.size] = _HEADER.pack(_MAGIC, _VERSION, meta_len, 1)
 
     @property
     def meta_len(self) -> int:
@@ -128,28 +111,47 @@ class ShmSegment:
         self.mm.close()
 
 
-def _parallel_copy(dst: memoryview, src: memoryview) -> None:
-    n = len(src)
-    if n <= _COPY_CHUNK:
-        dst[:n] = src
-        return
-    try:
-        # native multi-threaded memcpy when built (make native)
-        from photon_tpu.native import available, parallel_memcpy
+def _pwrite_all(fd: int, buf, offset: int) -> None:
+    """``os.pwrite`` may write short (Linux caps one call near 2 GiB): go on
+    until the whole buffer is in the file."""
+    view = memoryview(buf)
+    while view.nbytes:
+        n = os.pwrite(fd, view, offset)
+        view = view[n:]
+        offset += n
 
-        if available():
-            parallel_memcpy(dst[:n], src)
-            return
-    except ImportError:
-        pass
-    d = np.frombuffer(dst, np.uint8, count=n)
-    s = np.frombuffer(src, np.uint8, count=n)
-    futures = [
-        _POOL.submit(np.copyto, d[off : off + _COPY_CHUNK], s[off : off + _COPY_CHUNK])
-        for off in range(0, n, _COPY_CHUNK)
-    ]
-    for f in futures:
-        f.result()
+
+def _write_segment(
+    name: str, meta_bytes: bytes, parts: Iterable[tuple[int, Any]], payload_len: int
+) -> None:
+    """Create the committed segment ``name``: ``parts`` are (offset inside
+    the payload, contiguous byte buffer).
+
+    The bytes go into a private staging file, the header that says
+    ``committed`` last, and the file is then renamed over the final name:
+    readers (wait_for / read_params) only ever map a fully-committed segment,
+    with no window where a stale committed=1 header fronts new bytes. A
+    failure (``ENOSPC`` on a full tmpfs included) unlinks the staging file
+    and leaves whatever was committed under the name as it was."""
+    final = _path(name)
+    tmp = final.parent / (final.name + f".tmp-{os.getpid()}")
+    body = _HEADER.size + len(meta_bytes)
+    try:
+        fd = os.open(tmp, os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o600)
+        try:
+            # the whole extent whatever the parts cover: a zero-size last
+            # array or an alignment gap is a hole, which reads as zeros
+            os.ftruncate(fd, body + payload_len)
+            _pwrite_all(fd, _HEADER.pack(_MAGIC, _VERSION, 0, 0) + meta_bytes, 0)
+            for off, raw in parts:
+                _pwrite_all(fd, raw, body + off)
+            _pwrite_all(fd, _HEADER.pack(_MAGIC, _VERSION, len(meta_bytes), 1), 0)
+        finally:
+            os.close(fd)
+        os.rename(tmp, final)
+    except BaseException:
+        tmp.unlink(missing_ok=True)
+        raise
 
 
 # ---------------------------------------------------------------------------
@@ -178,34 +180,11 @@ def write_params(name: str, metadata: ParamsMetadata, arrays: list[np.ndarray]) 
     # payload on a _PAYLOAD_ALIGN boundary, so readers' views are aligned
     meta_bytes += b" " * (-(_HEADER.size + len(meta_bytes)) % _PAYLOAD_ALIGN)
     offsets, payload_len = _array_offsets(metadata)
-    # write into a private temp file, then atomically rename over the final
-    # name: readers (wait_for / read_params) only ever map a fully-committed
-    # segment — no window where a stale committed=1 header fronts new bytes
-    final = _path(name)
-    tmp = final.parent / (final.name + f".tmp-{os.getpid()}")
-    seg = ShmSegment(name, size=len(meta_bytes) + payload_len, create=True, path=tmp)
-    try:
-        body = seg.body()
-        try:
-            body[: len(meta_bytes)] = meta_bytes
-            for a, off in zip(arrays, offsets):
-                a = np.ascontiguousarray(a)
-                raw = a.reshape(-1).view(np.uint8)
-                start = len(meta_bytes) + off
-                chunk = body[start : start + a.nbytes]
-                try:
-                    _parallel_copy(chunk, memoryview(raw))
-                finally:
-                    chunk.release()
-        finally:
-            body.release()
-        seg.commit(len(meta_bytes))
-    except BaseException:
-        seg.close()
-        tmp.unlink(missing_ok=True)
-        raise
-    seg.close()
-    os.rename(tmp, final)
+    parts = (
+        (off, np.ascontiguousarray(a).reshape(-1).view(np.uint8))
+        for a, off in zip(arrays, offsets)
+    )
+    _write_segment(name, meta_bytes, parts, payload_len)
 
 
 def read_params(name: str) -> tuple[ParamsMetadata, list[np.ndarray]]:
@@ -241,18 +220,7 @@ def write_blob(name: str, obj: Any) -> None:
     """Pickled object cell (reference: ``set_dict_configsrecord_shm``,
     ``shm/utils.py:432-522``)."""
     data = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
-    final = _path(name)
-    tmp = final.parent / (final.name + f".tmp-{os.getpid()}")
-    seg = ShmSegment(name, size=len(data), create=True, path=tmp)
-    try:
-        seg.body()[: len(data)] = data
-        seg.commit(0)
-    except BaseException:
-        seg.close()
-        tmp.unlink(missing_ok=True)
-        raise
-    seg.close()
-    os.rename(tmp, final)
+    _write_segment(name, b"", [(0, data)], len(data))
 
 
 def read_blob(name: str) -> Any:

@@ -2,6 +2,7 @@
 (reference behavioral oracle: single-writer/single-reader + spin-wait,
 ``photon/shm/utils.py``)."""
 
+import errno
 import gc
 import multiprocessing as mp
 import os
@@ -22,7 +23,15 @@ from photon_tpu.shm import (
     write_params,
     write_scalar,
 )
-from photon_tpu.shm.plane import _HEADER, _MAGIC, _VERSION, _path, cleanup_stale, sweep_stale_tmp
+from photon_tpu.shm.plane import (
+    _HEADER,
+    _MAGIC,
+    _VERSION,
+    _array_offsets,
+    _path,
+    cleanup_stale,
+    sweep_stale_tmp,
+)
 from tests._helpers import is_readonly_view, shm_mappings
 
 
@@ -67,10 +76,8 @@ def test_zero_copy_views_stable_across_rewrite(name):
 
 
 def test_read_before_commit_raises(name):
-    from photon_tpu.shm.plane import ShmSegment
-
-    seg = ShmSegment(name, size=64, create=True)
-    seg.close()
+    # what the writer's staging file holds before its last write
+    _path(name).write_bytes(_HEADER.pack(_MAGIC, _VERSION, 0, 0) + bytes(64))
     with pytest.raises(BlockingIOError):
         read_params(name)
 
@@ -162,14 +169,149 @@ def test_transport_startup_sweeps_orphans():
         orphan.unlink(missing_ok=True)
 
 
-def test_large_params_threaded_copy(name):
-    """>64MiB payload exercises the thread-pool copy path."""
-    big = [np.arange(20_000_000, dtype=np.float32)]  # 80 MB
-    meta = ParamsMetadata.from_ndarrays(["big"], big)
-    write_params(name, meta, big)
-    _, out = read_params(name)
-    np.testing.assert_array_equal(out[0][:5], big[0][:5])
-    np.testing.assert_array_equal(out[0][-5:], big[0][-5:])
+# ---------------------------------------------------------------------------
+# the writer: ``os.pwrite`` into a staging file, the committed header last,
+# then a rename; it maps nothing (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+
+def _payload(kind: str) -> list[np.ndarray]:
+    import ml_dtypes
+
+    rng = np.random.default_rng(1)
+    if kind == "float32":
+        return _arrays()[::2] + [rng.normal(size=(7,)).astype(np.float32)]
+    if kind == "mixed_with_gaps":  # 3 bytes, a gap of 1, 10 bytes, a gap of 2, then float64
+        return [rng.integers(-9, 9, (3,)).astype(np.int8),
+                rng.normal(size=(5,)).astype(ml_dtypes.bfloat16), rng.normal(size=(4,))]
+    if kind == "zero_size_last":  # the float64's offset is past the last byte written
+        return [np.arange(3, dtype=np.int8), np.zeros((0, 4), np.float64)]
+    if kind == "zero_d":
+        return [np.float32(2.5).reshape(()), np.asarray(7, dtype=np.int64)]
+    if kind == "non_contiguous":
+        a = rng.normal(size=(6, 10)).astype(np.float32)
+        return [a.T, a[::2, 1::3], np.arange(12, dtype=np.int64)[::-1]]
+    if kind == "above_64MiB":
+        return [np.arange(20_000_000, dtype=np.float32), np.ones(3, np.float64)]  # 80 MB
+    raise KeyError(kind)
+
+
+@pytest.mark.parametrize(
+    "kind",
+    ["float32", "mixed_with_gaps", "zero_size_last", "zero_d", "non_contiguous", "above_64MiB"],
+)
+def test_writer_lays_out_every_kind_of_payload(name, kind):
+    arrays = _payload(kind)
+    meta = ParamsMetadata.from_ndarrays([f"p{i}" for i in range(len(arrays))], arrays)
+    write_params(name, meta, arrays)
+    with open(_path(name), "rb") as f:
+        meta_len = _HEADER.unpack(f.read(_HEADER.size))[2]
+    assert (_HEADER.size + meta_len) % 64 == 0
+    # the whole extent, even where the last array is empty or a gap ends it
+    assert _path(name).stat().st_size == _HEADER.size + meta_len + _array_offsets(meta)[1]
+    meta2, views = read_params(name)
+    assert meta2 == meta
+    assert views[0].ctypes.data % 64 == 0
+    for a, v in zip(arrays, views):
+        assert v.flags.aligned, (v.dtype, v.ctypes.data)
+        assert v.shape == a.shape and v.dtype == a.dtype
+        assert v.tobytes() == a.tobytes()  # bit for bit, bfloat16 included
+
+
+@pytest.mark.parametrize("most", [7, 1 << 20])
+def test_short_writes_still_round_trip(name, most, monkeypatch):
+    """Linux caps one ``write`` near 2 GiB; here every call writes short."""
+    real, calls = os.pwrite, []
+
+    def short_pwrite(fd, data, offset):
+        calls.append(len(data))
+        return real(fd, memoryview(data)[:most], offset)
+
+    n = 3 * most // 4 + 5  # float32s: 3 x ``most`` and a tail
+    arrays = [np.arange(n, dtype=np.float32), np.arange(5, dtype=np.int8),
+              np.arange(n, dtype=np.float64)[::-1]]
+    meta = ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays)
+    with monkeypatch.context() as m:
+        m.setattr(os, "pwrite", short_pwrite)
+        write_params(name, meta, arrays)
+    assert max(calls) > most  # some call did ask for more than it got
+    meta2, views = read_params(name)
+    assert meta2 == meta
+    for a, v in zip(arrays, views):
+        np.testing.assert_array_equal(a, v)
+
+
+def _staging_files(name: str) -> list:
+    return list(_path(name).parent.glob(f"photon-{name}.tmp-*"))
+
+
+def test_full_tmpfs_raises_and_leaves_the_committed_segment(name, monkeypatch):
+    """A full ``/dev/shm`` is ``ENOSPC`` from ``write()`` (through a mapping
+    it was a ``SIGBUS``): no staging file stays, the old segment still reads."""
+    old = _arrays()
+    meta = ParamsMetadata.from_ndarrays(["a", "b", "c"], old)
+    write_params(name, meta, old)
+    real, calls = os.pwrite, []
+
+    def full_pwrite(fd, data, offset):
+        calls.append(offset)
+        if len(calls) == 3:  # header + metadata, the first array, then no room
+            assert _staging_files(name)
+            raise OSError(errno.ENOSPC, os.strerror(errno.ENOSPC))
+        return real(fd, data, offset)
+
+    with monkeypatch.context() as m, pytest.raises(OSError) as e:
+        m.setattr(os, "pwrite", full_pwrite)
+        write_params(name, meta, [a * 2 for a in old])
+    assert e.value.errno == errno.ENOSPC
+    assert not _staging_files(name)
+    _, views = read_params(name)
+    for a, v in zip(old, views):
+        np.testing.assert_array_equal(a, v)
+
+
+def test_staging_file_reads_uncommitted_until_the_last_write(name, monkeypatch):
+    real, seen = os.pwrite, []
+
+    def watching_pwrite(fd, data, offset):
+        (staging,) = _staging_files(name)
+        with open(staging, "rb") as f:
+            seen.append(_HEADER.unpack(f.read(_HEADER.size)))
+        return real(fd, data, offset)
+
+    arrays = _arrays()
+    with monkeypatch.context() as m:
+        m.setattr(os, "pwrite", watching_pwrite)
+        write_params(name, ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays), arrays)
+    # before the header + metadata nothing is there; before each of the three
+    # arrays and before the committed header a reader sees ``committed == 0``
+    assert seen == [(0, 0, 0, 0)] + 4 * [(_MAGIC, _VERSION, 0, 0)]
+    assert not _staging_files(name)
+    read_params(name)  # and the renamed file is committed
+
+
+def test_writer_maps_nothing(name, monkeypatch):
+    """What a CPU cannot time: no page of a new segment is faulted through a
+    mapping, because the write side has none."""
+    import mmap
+
+    def no_mapping(*a, **k):
+        raise AssertionError("the writer mapped the segment")
+
+    arrays = _arrays()
+    meta = ParamsMetadata.from_ndarrays(["a", "b", "c"], arrays)
+    with monkeypatch.context() as m:
+        m.setattr(mmap, "mmap", no_mapping)
+        write_params(name, meta, arrays)
+        write_blob(name + "-blob", {"k": 1})
+    try:
+        assert read_blob(name + "-blob") == {"k": 1}
+    finally:
+        unlink(name + "-blob")
+    _, views = read_params(name)
+    for a, v in zip(arrays, views):
+        assert is_readonly_view(v)
+        np.testing.assert_array_equal(a, v)
 
 
 # ---------------------------------------------------------------------------
