@@ -1,21 +1,16 @@
 # Every target runs on the CPU (JAX_PLATFORMS=cpu): tests never take a chip.
 # The chip is reached through the chip tool, one process per chip:
 # `python chip_smoke.py`, `python3 benchmark/run.py` (`make benchmark`).
-.PHONY: test test-all verify benchmark chaos chaos-collective telemetry-smoke serve-smoke spec-smoke fleet-smoke adapters-smoke async-smoke autopilot-smoke lint lint-tests native clean
-# native build is best-effort: the package degrades to numpy fallbacks when
-# the .so is absent, so tests must run even without a C++ toolchain
+.PHONY: test test-all verify benchmark chaos chaos-collective telemetry-smoke serve-smoke spec-smoke fleet-smoke adapters-smoke async-smoke autopilot-smoke lint lint-tests
 test:
-	-$(MAKE) native
 	python -m pytest tests/ -x -q
 
 # the FULL pyramid including `slow` (multiprocess e2e, TCP, jax.distributed)
 test-all:
-	-$(MAKE) native
 	python -m pytest tests/ -x -q -m "slow or not slow"
 
 # tier-1: the not-slow suite, once (ROADMAP.md "Tier-1 verify")
 verify:
-	-$(MAKE) native
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m "not slow" \
 		--continue-on-collection-errors -p no:cacheprovider
 
@@ -144,11 +139,3 @@ chaos: lint
 chaos-collective: lint
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_collective_elastic.py -q -m "slow or not slow"
-
-native: native/libphoton_native.so
-
-native/libphoton_native.so: native/photon_native.cpp
-	g++ -O3 -march=native -shared -fPIC -pthread -std=c++17 -o $@ $<
-
-clean:
-	rm -f native/libphoton_native.so

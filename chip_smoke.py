@@ -23,7 +23,6 @@ import json
 import math
 import pathlib
 import shutil
-import subprocess
 import time
 import urllib.request
 from concurrent.futures import ThreadPoolExecutor
@@ -86,7 +85,6 @@ class Run:
     devices: list
     seed: int
     clock: BackendCompileClock
-    native_helper: str = "numpy"  # or "native": which twin this run loaded
 
     def record(self, phase: str, t0: float, **extra) -> None:
         """The phase's JSON line: it passed, and what it cost."""
@@ -100,7 +98,6 @@ class Run:
             "backend_compile_s": self.clock.lap(),
             "peak_bytes_in_use": peaks[0] if len(peaks) == 1 else peaks,
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
-            "native_helper": self.native_helper,
             **extra,
         })
 
@@ -125,20 +122,6 @@ def require_tpu(n_chips: int) -> list:
             f"chip_smoke: --chips {n_chips} but JAX found {len(devices)} device(s)"
         )
     return devices
-
-
-def rebuild_native() -> str:
-    """Rebuild the C++ data-plane helper from source (``make -B native``) and
-    say which twin the run uses. A library that was on disk before this
-    process started is never loaded: it was built on some other machine."""
-    try:
-        subprocess.run(["make", "-B", "native"], cwd=HERE, check=True,
-                       timeout=120, capture_output=True)
-    except (OSError, subprocess.SubprocessError):
-        (HERE / "native" / "libphoton_native.so").unlink(missing_ok=True)
-    from photon_tpu import native
-
-    return "native" if native.available() else "numpy"
 
 
 def load_config(preset: str, overrides, save_path: pathlib.Path):
@@ -471,7 +454,7 @@ def main(argv: list[str] | None = None) -> int:
     use_compile_cache()
     out = pathlib.Path(args.out).resolve()
     out.mkdir(parents=True, exist_ok=True)
-    run = Run(out, devices, args.seed, BackendCompileClock(), rebuild_native())
+    run = Run(out, devices, args.seed, BackendCompileClock())
     run.record("device", t0, kind=devices[0].device_kind, count=len(devices))
 
     overrides = (*TRAIN_SETS, f"seed={args.seed}")

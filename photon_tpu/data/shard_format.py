@@ -5,8 +5,7 @@ consumed via ``photon/clients/llm_config_functions.py`` stream configs): a
 dataset is a directory of fixed-length token-sample shards plus a JSON index.
 TPU-first design: samples are fixed ``[seq_len]`` token rows stored as a dense
 2-D array per shard — a reader can ``mmap`` a shard and slice batches with
-zero parsing, and the C++ fast path (``photon_tpu/native``) maps the same
-bytes.
+zero parsing.
 
 Layout of ``shard_{i:05d}.pts``::
 
@@ -191,17 +190,13 @@ class ShardedDataset:
         return self._load(shard_idx)[row].astype(np.int32)
 
     def batch(self, idxs: np.ndarray) -> np.ndarray:
-        """Gather ``[len(idxs), seq_len] int32`` (hot path for the loader);
-        uses the native fused gather+widen when built (``make native``)."""
-        from photon_tpu.native import gather_rows
-
+        """Gather ``[len(idxs), seq_len] int32`` (hot path for the loader):
+        each row widened from its mapped shard's dtype as it is written."""
         out = np.empty((len(idxs), self.seq_len), np.int32)
-        rows = []
-        for i in idxs:
+        for n, i in enumerate(idxs):
             i = int(i)
             if not 0 <= i < len(self):
                 raise IndexError(i)
             shard_idx = int(np.searchsorted(self.shard_offsets, i, side="right") - 1)
-            rows.append(self._load(shard_idx)[i - int(self.shard_offsets[shard_idx])])
-        gather_rows(rows, out)
+            out[n] = self._load(shard_idx)[i - int(self.shard_offsets[shard_idx])]
         return out

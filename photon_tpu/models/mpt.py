@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu.config.schema import ModelConfig
+from photon_tpu.models.step import sow
 from photon_tpu.ops.attention import multihead_attention
 from photon_tpu.ops.flash_attention import IN_PLACE, flash_layout
 from photon_tpu.utils.profiling import (
@@ -356,7 +357,7 @@ def _hc_read_in(block: nn.Module, x, name: str):
                         (3,), jnp.float32)
     with jax.named_scope(MHC_MAPS_SCOPE):
         pre, post, res, gap = _hc_maps(cfg, x, phi, b, alpha)
-        block.sow("intermediates", "mhc_sinkhorn_gap", gap)
+        sow(block, "mhc_sinkhorn_gap", gap)
     with jax.named_scope(MHC_READ_IN_SCOPE):
         u = sum(_per_token(pre[i], xi) * xi.astype(jnp.float32) for i, xi in enumerate(x))
         u = u.astype(x[0].dtype)
@@ -552,14 +553,12 @@ class MPTBlock(nn.Module):
             tiles = plan_tiles(s, s)
             counts = tile_counts(mask, *base_tile(tiles))
             live = live_tables(counts, tiles)
-            self.sow("intermediates", "dsa_picked_pairs",
-                     jnp.sum(counts).astype(jnp.float32))
-            self.sow("intermediates", "dsa_tiles_visited",
-                     jnp.sum(live[0], dtype=jnp.float32))
+            sow(self, "dsa_picked_pairs", jnp.sum(counts).astype(jnp.float32))
+            sow(self, "dsa_tiles_visited", jnp.sum(live[0], dtype=jnp.float32))
         out, lse = masked_multihead_attention(
             q, k, v, mask, impl=cfg.attn_impl, interpret=cfg.attn_interpret, live=live)
         with jax.named_scope(DSA_INDEX_LOSS_SCOPE):
-            self.sow("intermediates", "dsa_index_loss", dsa.index_loss(
+            sow(self, "dsa_index_loss", dsa.index_loss(
                 q_idx, k_idx, w_idx, q, k, lse, mask, chunk=cfg.dsa_chunk,
                 impl=cfg.attn_impl, interpret=cfg.attn_interpret))
         return out
@@ -601,7 +600,7 @@ class MPTBlock(nn.Module):
             gate_eps=cfg.moe_gate_eps,
             compute_dtype=compute, interpret=cfg.attn_interpret)
         for name, value in counters.items():
-            self.sow("intermediates", f"moe_{name}", value)
+            sow(self, f"moe_{name}", value)
         if cfg.moe_shared_experts:
             with jax.named_scope(moe.SHARED_EXPERT_SCOPE):
                 width = cfg.moe_shared_experts * hidden
@@ -757,7 +756,7 @@ class MPTBlock(nn.Module):
                 h.astype(compute), router_w, w_up, w_down, w_gate=w_gate,
                 top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
             )
-            self.sow("intermediates", "moe_aux", aux)
+            sow(self, "moe_aux", aux)
             # pin the combine output back to the residual-stream layout
             # (batch over data+fsdp+expert; d_model REPLICATED over tensor —
             # the residual add and the next ln_1 consume the full feature

@@ -19,17 +19,8 @@ import jax.numpy as jnp
 import optax
 
 from photon_tpu.models.mpt import MPTModel
-from photon_tpu.utils.profiling import (
-    DSA_INDEX_LOSS,
-    DSA_PICKED_PAIRS,
-    DSA_TILES_VISITED,
-    GRAD_NORM_SCOPE,
-    MHC_SINKHORN_GAP,
-    MOE_DISPATCH_ROWS_MOVED,
-    MOE_DISPATCH_ROWS_STATIC,
-    MOE_MAX_EXPERT_LOAD,
-    MOE_ROWS_HELD,
-)
+from photon_tpu.models.step import COUNTERS, EXPERT_ROWS
+from photon_tpu.utils.profiling import GRAD_NORM_SCOPE
 
 # The step's stages as ``jax.named_scope``s: they reach every operation's
 # ``op_name`` metadata (forward, transpose and recomputation alike), which is
@@ -155,118 +146,72 @@ def _chunked_ce_sum(
                    model.cfg.logits_scaling)
 
 
+def collect_counters(sown: Any) -> dict[str, Any]:
+    """An ``intermediates`` collection as the step's counters, by sown key:
+    every per-layer value over the layers of all stacks as its row of
+    ``models/step.COUNTERS`` says (a sum or a maximum, both float32 scalars, or
+    ``{stack: [layers, ...]}`` under the name of the stack that sowed it, which
+    a sow's path starts with). A key without a row raises. Empty where nothing
+    was sown."""
+    out: dict[str, Any] = {}
+    worst: dict[str, list[jax.Array]] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown or {}):
+        keys = [getattr(k, "key", None) for k in path]
+        key = next((k for k in keys if k in COUNTERS), None)
+        if key is None:
+            raise KeyError(f"sown at {keys} and has no row in models/step.COUNTERS")
+        leaf = jnp.asarray(leaf, jnp.float32)
+        rule = COUNTERS[key].layers
+        if rule == "sum":
+            out[key] = out.get(key, 0.0) + jnp.sum(leaf)
+        elif rule == "max":
+            worst.setdefault(key, []).append(jnp.max(leaf))
+        else:
+            out.setdefault(key, {})[keys[0]] = leaf.reshape(-1, leaf.shape[-1])
+    return {**out, **{key: jnp.max(jnp.stack(v)) for key, v in worst.items()}}
+
+
 def collect_moe_aux(variables: Any) -> jax.Array:
     """Sum the per-layer ``moe_aux`` sows out of an ``intermediates``
     collection (and ONLY those — other sown diagnostics must not leak
-    into the objective). Shared by the standard loss fn and the pipeline
-    stage scan."""
-    aux = jnp.zeros([], jnp.float32)
-    for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
-        if any(getattr(k, "key", None) == "moe_aux" for k in path):
-            aux = aux + jnp.sum(jnp.asarray(leaf, jnp.float32))
-    return aux
+    into the objective). The pipeline's stage scan, which applies blocks
+    outside ``_apply_collecting_aux``, takes its aux loss from here."""
+    return collect_counters(variables).get("moe_aux", jnp.zeros([], jnp.float32))
 
 
-#: the step's rows by expert stack, layer and routed expert (``{stack:
-#: [layers, E]}``): beside the counters until the step has moved each stack's
-#: selection bias by its own; no metric
-_EXPERT_ROWS = "moe/expert_rows"
-
-
-def collect_moe_counters(variables: Any) -> dict[str, jax.Array]:
-    """The dropless expert layers' per-layer sows as the step's counters:
-    rows routed to the experts held here and the rows the dispatch's movements
-    copied, beside their static worst case, summed over layers, and the busiest
-    held expert's rows over the mean, worst layer, all over every expert
-    stack; and the rows of every routed expert by layer, under the name of
-    the stack that sowed them (a sow's path starts with it), for the
-    balancing rule. Empty for every other model."""
-    summed = {"moe_rows_held": MOE_ROWS_HELD,
-              "moe_dispatch_rows_moved": MOE_DISPATCH_ROWS_MOVED,
-              "moe_dispatch_rows_static": MOE_DISPATCH_ROWS_STATIC}
-    sums, worst, by_expert = {}, [], {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
-        keys = [getattr(k, "key", None) for k in path]
-        for name in (summed[k] for k in keys if k in summed):
-            sums[name] = sums.get(name, 0.0) + jnp.sum(jnp.asarray(leaf, jnp.float32))
-        if "moe_max_expert_load" in keys:
-            worst.append(jnp.max(jnp.asarray(leaf, jnp.float32)))
-        elif "moe_expert_rows" in keys:
-            by_expert[keys[0]] = jnp.asarray(leaf, jnp.float32).reshape(-1, leaf.shape[-1])
-    if not sums:
-        return {}
-    return {**sums, MOE_MAX_EXPERT_LOAD: jnp.max(jnp.stack(worst)), _EXPERT_ROWS: by_expert}
-
-
-def collect_dsa_counters(variables: Any) -> dict[str, jax.Array]:
-    """The sparse-attention layers' sows as the step's counters, each summed
-    over the layers: the pairs the indexers picked, the forward tiles that
-    hold one, and the index losses (which the objective adds to the
-    cross-entropy: the indexer's parameters get their gradient from these
-    alone, every other parameter from the cross-entropy alone). Empty for
-    every other model."""
-    names = {"dsa_picked_pairs": DSA_PICKED_PAIRS, "dsa_tiles_visited": DSA_TILES_VISITED,
-             "dsa_index_loss": DSA_INDEX_LOSS}
-    out: dict[str, jax.Array] = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {}):
-        for name in (names.get(getattr(k, "key", None)) for k in path):
-            if name:
-                out[name] = out.get(name, 0.0) + jnp.sum(jnp.asarray(leaf, jnp.float32))
-    return out
-
-
-def collect_mhc_counters(variables: Any) -> dict[str, jax.Array]:
-    """The hyper-connected blocks' sows as the step's one counter: the largest
-    distance from 1 of a row or column sum of any sublayer's mixing matrix.
-    Empty for every other model."""
-    gaps = [jnp.max(jnp.asarray(leaf, jnp.float32))
-            for path, leaf in jax.tree_util.tree_leaves_with_path(variables or {})
-            if any(getattr(k, "key", None) == "mhc_sinkhorn_gap" for k in path)]
-    return {MHC_SINKHORN_GAP: jnp.max(jnp.stack(gaps))} if gaps else {}
-
-
-#: the counters that are the worst of their layers and microbatches, not a sum
-_WORST = (MOE_MAX_EXPERT_LOAD, MHC_SINKHORN_GAP)
+_MERGE = {"add": jnp.add, "max": jnp.maximum, "mean": jnp.add}
 
 
 def _merge_counters(a: dict, b: dict) -> dict:
-    """Two microbatches' counters as one step's: rows add, the load and the
-    mixing matrices' gap are the worst."""
-    return {k: jnp.maximum(a[k], b[k]) if k in _WORST
-            else jax.tree.map(jnp.add, a[k], b[k]) for k in a}
+    """Two microbatches' counters as one step's, each by its row's rule (a
+    mean is a sum until the step divides it)."""
+    return {k: jax.tree.map(_MERGE[COUNTERS[k].microbatches], a[k], b[k]) for k in a}
 
 
 def _apply_collecting_aux(model: MPTModel, params, tokens, **kwargs):
-    """``model.apply`` that also returns the summed MoE aux loss (0.0 for
-    dense models and for the dropless router, which has none) and the
-    dropless layers' counters. The MoE blocks sow per-layer terms into
-    ``intermediates`` (``models/mpt.py``); plain inference applies leave the
-    collection immutable, so sow is a no-op there."""
-    cfg = model.cfg
-    if cfg.mlp != "moe" and not cfg.sparse_attention and not cfg.hyper_connected:
-        return (model.apply({"params": params}, tokens, **kwargs),
-                jnp.zeros([], jnp.float32), {})
+    """``model.apply`` that also returns what its blocks sowed
+    (``models/step.COUNTERS``; nothing for most models): the counters, and the
+    sum of those that join the objective, each under its weight. Plain
+    inference applies leave ``intermediates`` immutable, so a sow is a no-op
+    there."""
     out, variables = model.apply(
         {"params": params}, tokens, mutable=["intermediates"], **kwargs
     )
-    sown = variables.get("intermediates", {})
-    aux = model.cfg.moe_aux_weight * collect_moe_aux(sown)
-    counters = collect_moe_counters(sown)
-    if model.cfg.sparse_attention:
-        dsa = collect_dsa_counters(sown)
-        aux = aux + dsa[DSA_INDEX_LOSS]
-        counters = {**counters, **dsa}
-    if cfg.hyper_connected:
-        counters = {**counters, **collect_mhc_counters(sown)}
+    counters = collect_counters(variables.get("intermediates", {}))
+    aux = jnp.zeros([], jnp.float32)
+    for key, total in counters.items():
+        weight = COUNTERS[key].weight
+        if weight is not None:
+            aux = aux + (getattr(model.cfg, weight) if isinstance(weight, str)
+                         else weight) * total
     return out, aux, counters
 
 
 def _make_loss_and_counters_fn(model: MPTModel, loss_chunk_tokens: int) -> Callable:
     def loss_fn(params, tokens: jax.Array):
         """``(loss, counters)``: mean next-token cross entropy over
-        ``[B, S] int32`` tokens (+ the weighted MoE load-balance aux loss of
-        the capacity router, + the sparse-attention layers' index losses),
-        and the dropless and sparse-attention layers' counters."""
+        ``[B, S] int32`` tokens plus what the blocks sowed for the objective,
+        and everything they sowed, by sown key."""
         if loss_chunk_tokens:
             hidden, aux, counters = _apply_collecting_aux(
                 model, params, tokens, return_hidden=True
@@ -321,8 +266,8 @@ def make_train_step(
     analog of the reference's ``device_train_microbatch_size`` grad
     accumulation (``conf/llm_config/mpt-125m.yaml:80-81``).
     """
-    grad_fn = jax.value_and_grad(
-        _make_loss_and_counters_fn(model, loss_chunk_tokens), has_aux=True)
+    loss_fn = _make_loss_and_counters_fn(model, loss_chunk_tokens)
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def forward_backward(state: TrainState, tokens: jax.Array):
         if n_microbatches > 1:
@@ -338,28 +283,15 @@ def make_train_step(
                         _merge_counters(counters_acc, counters)), None
 
             zero_grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            zero_counters = {}
-            if model.cfg.dropless_moe:
-                zero_counters = {
-                    **{k: jnp.zeros([], jnp.float32) for k in (
-                        MOE_ROWS_HELD, MOE_DISPATCH_ROWS_MOVED, MOE_DISPATCH_ROWS_STATIC,
-                        MOE_MAX_EXPERT_LOAD)},
-                    _EXPERT_ROWS: {
-                        name: jnp.zeros((length, model.cfg.moe_num_experts), jnp.float32)
-                        for name, _, dense_mlp, length in model.cfg.stacks if not dense_mlp},
-                }
-            if model.cfg.sparse_attention:
-                zero_counters.update({k: jnp.zeros([], jnp.float32) for k in (
-                    DSA_PICKED_PAIRS, DSA_TILES_VISITED, DSA_INDEX_LOSS)})
-            if model.cfg.hyper_connected:
-                zero_counters[MHC_SINKHORN_GAP] = jnp.zeros([], jnp.float32)
+            zero_counters = jax.tree.map(
+                lambda c: jnp.zeros(c.shape, c.dtype),
+                jax.eval_shape(lambda p, mb: loss_fn(p, mb)[1], state.params, micro[0]))
             (loss_sum, grad_sum, counters), _ = jax.lax.scan(
                 body, (jnp.zeros([], jnp.float32), zero_grads, zero_counters), micro)
             loss = loss_sum / n_microbatches
             grads = jax.tree.map(lambda g: g / n_microbatches, grad_sum)
-            if DSA_INDEX_LOSS in counters:  # a mean like the loss it is part of
-                counters = {**counters,
-                            DSA_INDEX_LOSS: counters[DSA_INDEX_LOSS] / n_microbatches}
+            counters = {k: v / n_microbatches if COUNTERS[k].microbatches == "mean" else v
+                        for k, v in counters.items()}
         else:
             (loss, counters), grads = grad_fn(state.params, tokens)
         return loss, grads, counters
@@ -375,11 +307,9 @@ def make_train_step(
         with jax.named_scope(OPTIMIZER_SCOPE):
             updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
-            counters = dict(counters)
-            expert_rows = counters.pop(_EXPERT_ROWS, None)
-            if expert_rows is not None and model.cfg.moe_bias_update_speed:
+            if EXPERT_ROWS in counters and model.cfg.moe_bias_update_speed:
                 new_params = _balance_router_bias(
-                    new_params, expert_rows, model.cfg.moe_bias_update_speed)
+                    new_params, counters[EXPERT_ROWS], model.cfg.moe_bias_update_speed)
             new_state = TrainState(
                 step=state.step + 1, params=new_params, opt_state=new_opt_state)
         with jax.named_scope(GRAD_NORM_SCOPE):
@@ -388,7 +318,7 @@ def make_train_step(
             "loss": loss,
             "grad_norm": grad_norm,
             "param_norm": param_norm,
-            **counters,
+            **{COUNTERS[k].metric: v for k, v in counters.items() if COUNTERS[k].metric},
         }
         return new_state, metrics
 

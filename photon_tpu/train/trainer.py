@@ -29,6 +29,7 @@ from photon_tpu import telemetry
 from photon_tpu.codec import ParamsMetadata, params_from_ndarrays, params_to_ndarrays
 from photon_tpu.config.schema import Config
 from photon_tpu.models.mpt import MPTModel, init_params
+from photon_tpu.models.step import step_attrs
 from photon_tpu.optim import build_optimizer
 from photon_tpu.parallel.mesh import make_mesh
 from photon_tpu.parallel.sharding import batch_spec, state_shardings
@@ -46,118 +47,14 @@ from photon_tpu.utils.profiling import (
     CLIENT_STEPS,
     CLIENT_TOKENS_PER_SEC,
     EVENT_SPEED_MONITOR_PEAK,
-    DSA_CAUSAL_PAIRS,
-    DSA_INDEX_LOSS,
-    DSA_PICKED_PAIRS,
-    DSA_TILES_CAUSAL,
-    DSA_TILES_VISITED,
-    MHC_SINKHORN_GAP,
-    MOE_DISPATCH_ROWS_MOVED,
-    MOE_DISPATCH_ROWS_STATIC,
-    MOE_MAX_EXPERT_LOAD,
-    MOE_ROWS_HELD,
-    TRAINER_DSA_SPAN,
+    FENCE_SPANS,
     TRAINER_FENCE_SPAN,
-    TRAINER_MHC_SPAN,
-    TRAINER_MOE_LOAD_SPAN,
     TRAINER_GET_PARAMETERS_SPAN,
     TRAINER_NEXT_BATCH_SPAN,
     TRAINER_SET_PARAMETERS_SPAN,
     TRAINER_STEPS_SPAN,
     SpeedMonitor,
 )
-
-
-def _flash_tile_attrs(model_cfg) -> dict[str, str]:
-    """The tiles the flash kernel takes in this trainer's compiled step, and
-    how many of its grid steps are live, as span attributes: static per
-    shape, so they are told here, where the shapes are known, once for the
-    step and not per launch. Empty unless the step holds the kernel."""
-    from photon_tpu.ops.flash_attention import (
-        flash_layout, lane_padded, pallas_supported, pick_tiles)
-
-    if model_cfg.attn_impl != "pallas" or not (
-            model_cfg.attn_interpret or pallas_supported(None)):
-        return {}
-    s = model_cfg.max_seq_len
-    if model_cfg.sparse_attention:
-        # the masked kernel's plan; which of its tiles are live is data
-        # (``trainer/dsa`` has the count)
-        from photon_tpu.ops.masked_flash_attention import LAUNCHES, plan_tiles
-
-        return {"flash_tiles": " ".join(
-            f"{n}={bq}x{bk}" for n, (bq, bk) in zip(LAUNCHES, plan_tiles(s, s)))}
-    d_v = model_cfg.v_head_dim if model_cfg.latent_attention else model_cfg.d_head
-    n_kv = model_cfg.n_kv_heads or model_cfg.n_heads
-    # the layout the launches read, by the model's own heads (a shard of a
-    # tensor-parallel mesh applies the same rule to its local ones)
-    layout = flash_layout(model_cfg.n_heads, n_kv, model_cfg.d_head, d_v)
-    return pick_tiles(
-        s, s, lane_padded(model_cfg.d_head), jnp.dtype(model_cfg.compute_dtype).itemsize,
-        model_cfg.n_heads // n_kv, d_v_pad=lane_padded(d_v), layout=layout,
-    ).attrs(layout)
-
-
-def _mamba_attrs(model_cfg) -> dict[str, int]:
-    """The step's Mamba-2 layers and the chunks each one's scan walks a row
-    in, as span attributes: static counts, told once where the shapes are
-    known. Empty for a model without such layers."""
-    if not model_cfg.mamba_layers:
-        return {}
-    return {"mamba_layers": model_cfg.mamba_layers,
-            "ssd_chunks": model_cfg.max_seq_len // model_cfg.mamba_chunk_size}
-
-
-def _conv_attrs(model_cfg) -> dict[str, int]:
-    """The step's gated short-convolution layers, as a span attribute: a
-    static count. Empty for a model without such layers."""
-    return {"conv_layers": model_cfg.conv_layers} if model_cfg.conv_layers else {}
-
-
-def _mhc_attrs(model_cfg) -> dict[str, int]:
-    """The residual streams of the step's hyper-connected blocks and the
-    sublayers whose maps, read-in and write-back it runs (two a layer), as
-    span attributes: static counts. Empty for a model with one stream."""
-    if not model_cfg.hyper_connected:
-        return {}
-    return {"mhc_streams": model_cfg.hc_mult, "mhc_sublayers": 2 * model_cfg.n_layers}
-
-
-def _dsa_attrs(model_cfg) -> dict[str, int]:
-    """The step's sparse-attention layers and the keys each of their queries
-    picks, as span attributes: static counts. Empty for every other model."""
-    if not model_cfg.sparse_attention:
-        return {}
-    return {"dsa_layers": model_cfg.n_layers, "dsa_topk": model_cfg.dsa_topk}
-
-
-def _dsa_static_counts(model_cfg, batch_rows: int) -> dict[str, float]:
-    """What the selection is measured against, a step: the causal (query,
-    key) pairs of every layer and row, and the forward tiles of the masked
-    kernel that hold one."""
-    from photon_tpu.ops.flash_attention import live_tiles
-    from photon_tpu.ops.masked_flash_attention import plan_tiles
-
-    s = model_cfg.max_seq_len
-    rows = model_cfg.n_layers * batch_rows
-    tiles, _ = live_tiles(s, s, *plan_tiles(s, s)[0])
-    return {DSA_CAUSAL_PAIRS: float(rows * s * (s + 1) // 2),
-            DSA_TILES_CAUSAL: float(rows * tiles)}
-
-
-def _index_loss_attrs(model_cfg, batch_rows: int) -> dict[str, Any]:
-    """Which path makes the index loss's ``pbar`` in this step (``ops/dsa.
-    uses_kernel``: the Pallas launch or ``jax.numpy``), and the key tiles the
-    launches of a step compute and skip: static, the skipped ones are dead by
-    the causal rule; every layer and row, twice under ``remat``."""
-    from photon_tpu.ops import dsa
-
-    kernel = dsa.uses_kernel(model_cfg.attn_impl, model_cfg.attn_interpret)
-    computed, skipped = dsa.index_loss_tiles(model_cfg.max_seq_len, model_cfg.dsa_chunk)
-    passes = (2 if model_cfg.remat else 1) if kernel else 0
-    launches = passes * model_cfg.n_layers * batch_rows
-    return {"index_loss_kernel": kernel, "index_loss_tiles": launches * computed,
-            "index_loss_tiles_skipped": launches * skipped}
 
 
 def _set_opt_count(opt_state: Any, step: int) -> Any:
@@ -221,11 +118,6 @@ class Trainer:
             mesh = make_mesh(mesh_cfg, devices=jax.local_devices())
 
         self.model = MPTModel(effective_model_config(cfg.model, mesh_cfg))
-        self._kernel_attrs = {**_flash_tile_attrs(self.model.cfg),
-                              **_mamba_attrs(self.model.cfg),
-                              **_conv_attrs(self.model.cfg),
-                              **_mhc_attrs(self.model.cfg),
-                              **_dsa_attrs(self.model.cfg)}
         self.tx, self.lr_schedule = build_optimizer(cfg.optimizer, cfg.scheduler)
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh)
 
@@ -300,6 +192,8 @@ class Trainer:
         n_micro = cfg.train.global_batch_size // rows_per_scan
         assert n_micro * rows_per_scan == cfg.train.global_batch_size
         self._n_micro = n_micro
+        # the step's static numbers, told by the model where the shapes are known
+        self._step_attrs = step_attrs(self.model.cfg, cfg.train.global_batch_size)
 
         self.state: TrainState = jax.tree.map(
             lambda leaf, sh: jax.device_put(leaf, sh), host_state, self._shardings
@@ -510,7 +404,7 @@ class Trainer:
         metrics: dict = {}
         try:
             with telemetry.span(TRAINER_STEPS_SPAN, steps=duration_steps,
-                                **self._kernel_attrs):
+                                **self._step_attrs.steps):
                 for i in range(duration_steps):
                     try:
                         with telemetry.span(TRAINER_NEXT_BATCH_SPAN):
@@ -533,32 +427,17 @@ class Trainer:
             jax.block_until_ready(self.state)
             if duration_steps:
                 log(duration_steps - 1, metrics)
-                if MOE_ROWS_HELD in last_metrics:
-                    # the last step's routing counters, where a trace's
-                    # reader finds them (they came with the loss: no new sync)
-                    with telemetry.span(
-                            TRAINER_MOE_LOAD_SPAN,
-                            rows_held=last_metrics[MOE_ROWS_HELD],
-                            max_expert_load=last_metrics[MOE_MAX_EXPERT_LOAD],
-                            dispatch_rows_moved=last_metrics[MOE_DISPATCH_ROWS_MOVED],
-                            dispatch_rows_static=last_metrics[MOE_DISPATCH_ROWS_STATIC]):
-                        pass
-                if MHC_SINKHORN_GAP in last_metrics:
-                    with telemetry.span(TRAINER_MHC_SPAN,
-                                        sinkhorn_gap=last_metrics[MHC_SINKHORN_GAP]):
-                        pass
-                if DSA_PICKED_PAIRS in last_metrics:
-                    # the last step's selection counters, the same way
-                    last_metrics.update(_dsa_static_counts(
-                        self.model.cfg, batch.shape[0]))
-                    with telemetry.span(
-                            TRAINER_DSA_SPAN,
-                            picked_pairs=last_metrics[DSA_PICKED_PAIRS],
-                            causal_pairs=last_metrics[DSA_CAUSAL_PAIRS],
-                            tiles_visited=last_metrics[DSA_TILES_VISITED],
-                            tiles_causal=last_metrics[DSA_TILES_CAUSAL],
-                            index_loss=last_metrics[DSA_INDEX_LOSS],
-                            **_index_loss_attrs(self.model.cfg, batch.shape[0])):
+                # the last step's counters where a trace's reader finds them
+                # (they came with the loss: no new sync), each family's on a
+                # span of its own beside the model's static counts
+                for name, measured in FENCE_SPANS.items():
+                    if not any(m in last_metrics for m in measured.values()):
+                        continue
+                    static = self._step_attrs.fence.get(name, {})
+                    last_metrics.update(
+                        {measured[a]: v for a, v in static.items() if a in measured})
+                    attrs = {a: last_metrics[m] for a, m in measured.items()}
+                    with telemetry.span(name, **{**attrs, **static}):
                         pass
         dt = time.monotonic() - t0
         return {

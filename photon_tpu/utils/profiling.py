@@ -256,6 +256,22 @@ MHC_SINKHORN_GAP = "mhc/sinkhorn_gap"
 #: opened and closed inside the fence like ``trainer/moe_load``, only when the
 #: step's blocks are hyper-connected: attr ``sinkhorn_gap``
 TRAINER_MHC_SPAN = "trainer/mhc"
+#: the spans inside ``trainer/fence``, in the order ``Trainer.fit`` opens
+#: them, each with its ``{attr: step metric}``: one is opened when the last
+#: step returned a metric of its, and a static count of the model's
+#: (``models/step.step_attrs``) under an attr listed here joins what ``fit``
+#: returns under that metric's name
+FENCE_SPANS: dict[str, dict[str, str]] = {
+    TRAINER_MOE_LOAD_SPAN: {
+        "rows_held": MOE_ROWS_HELD, "max_expert_load": MOE_MAX_EXPERT_LOAD,
+        "dispatch_rows_moved": MOE_DISPATCH_ROWS_MOVED,
+        "dispatch_rows_static": MOE_DISPATCH_ROWS_STATIC},
+    TRAINER_MHC_SPAN: {"sinkhorn_gap": MHC_SINKHORN_GAP},
+    TRAINER_DSA_SPAN: {
+        "picked_pairs": DSA_PICKED_PAIRS, "causal_pairs": DSA_CAUSAL_PAIRS,
+        "tiles_visited": DSA_TILES_VISITED, "tiles_causal": DSA_TILES_CAUSAL,
+        "index_loss": DSA_INDEX_LOSS},
+}
 # -- every block (models/mpt.py) and the step around them
 # (train/train_step.py): ``jax.named_scope``s like the families' above, so
 # that no device time of a step is left to a bare instruction name --------

@@ -37,9 +37,5 @@ pip install flax optax orbax-checkpoint chex einops numpy pyyaml pytest
 #! Optional extras the reference also gates at runtime
 pip install transformers datasets 2>/dev/null || echo "install_env.sh: HF extras skipped (offline?)"
 
-#! Native data-plane helpers (ctypes .so with a pure-numpy fallback, so a
-#! failed build is non-fatal — matches native/__init__.py's contract)
-make -C "$PROJECT_PATH" native 2>/dev/null || echo "install_env.sh: native build skipped"
-
 python -c "import jax; print('install_env.sh: jax', jax.__version__, 'devices:', jax.devices())"
 echo "install_env.sh: done — activate with 'source .venv/bin/activate'"

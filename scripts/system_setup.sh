@@ -5,11 +5,11 @@
 # CuDNN + nvidia persistence mode). On TPU none of that exists: the
 # accelerator stack is libtpu, shipped as a Python wheel with jax[tpu]
 # (installed by install_env.sh), so system setup reduces to build
-# essentials for the native helpers and a few kernel knobs.
+# essentials and a few kernel knobs.
 set -euo pipefail
 
-#! Update and install the essentials (native/ builds need a C++ toolchain;
-#! the rest mirrors the reference's python-build prerequisites)
+#! Update and install the essentials (mirrors the reference's python-build
+#! prerequisites)
 sudo apt-get update
 sudo apt-get install -y build-essential cmake ninja-build g++ \
 	zlib1g-dev libssl-dev liblzma-dev libffi-dev libbz2-dev \

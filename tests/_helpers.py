@@ -13,10 +13,16 @@ import os
 import pathlib
 import socket
 
-# honor a user-set cache dir; default to the suite's persistent cache
+# honor a user-set cache dir; default to the suite's persistent cache (a
+# directory of its own under the ignored one: entries written before the
+# cache was locked carry no access time, and jax's eviction scan, which every
+# locked write runs, fails on the first of them)
 TEST_JAX_CACHE = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
-    pathlib.Path(__file__).parent / ".jax_cache"
+    pathlib.Path(__file__).parent / ".jax_cache" / "locked"
 )
+#: eviction "on" is what makes jax lock an entry's read and write
+#: (``tests/conftest.py``); 8 GiB is never reached (a whole run writes ~0.1), so nothing is evicted
+TEST_JAX_CACHE_MAX_SIZE = 8 << 30
 
 
 #: How far the fp32 logits of two serving programs that compute the same
@@ -72,6 +78,7 @@ def subprocess_env() -> dict:
     )
     env["JAX_PLATFORMS"] = "cpu"
     env["JAX_COMPILATION_CACHE_DIR"] = TEST_JAX_CACHE
+    env["JAX_COMPILATION_CACHE_MAX_SIZE"] = str(TEST_JAX_CACHE_MAX_SIZE)
     env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.5"
     return env
 
@@ -98,3 +105,63 @@ def tiny_llama_config(n_kv_heads: int = 0):
     cfg.model.mlp_hidden_size = 48
     cfg.model.tie_embeddings = False
     return cfg.validate()
+
+
+#: each benchmark preset at a tiny size (its own family's test's sizes), for
+#: tests that hold every preset to one thing
+TINY_PRESETS = {
+    "mpt-125m": dict(d_model=32, n_layers=2, n_heads=2, max_seq_len=32, vocab_size=96),
+    "glm-4.7-flash-ep8": dict(
+        d_model=64, n_layers=3, n_heads=4, max_seq_len=32, vocab_size=96, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+        dense_mlp_hidden_size=160, mlp_hidden_size=48, moe_num_experts=8, moe_top_k=2,
+        moe_experts_held=4),
+    "granite-4.0-h-micro-stage1": dict(
+        d_model=32, n_layers=4, layer_types="mamba,mamba,attention,mamba", n_heads=4,
+        n_kv_heads=2, max_seq_len=32, vocab_size=96, mamba_n_heads=4, mamba_d_head=16,
+        mamba_d_state=8, mamba_chunk_size=8, mlp_hidden_size=48,
+        attention_multiplier=0.125),
+    "keye-vl-2.0-30b-a3b-ep8": dict(
+        d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=32, max_seq_len=64,
+        vocab_size=96, dsa_topk=16, dsa_index_heads=4, dsa_index_head_dim=16, dsa_chunk=16,
+        mlp_hidden_size=32, moe_num_experts=8, moe_top_k=2, moe_experts_held=2),
+    "lfm2-8b-a1b-ep4": dict(
+        d_model=32, n_heads=4, n_kv_heads=2, max_seq_len=32, vocab_size=96,
+        dense_mlp_hidden_size=48, mlp_hidden_size=24, moe_num_experts=8, moe_top_k=2,
+        moe_experts_held=4),
+    "xing4.0-29b-a4b-ep8": dict(
+        d_model=32, n_layers=3, n_heads=2, max_seq_len=32, vocab_size=96,
+        q_lora_rank=12, kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=6,
+        rope_scaling_original_max_position=16, dense_mlp_hidden_size=48, mlp_hidden_size=24,
+        moe_num_experts=8, moe_top_k=2, moe_experts_held=4),
+}
+
+
+def tiny_preset(preset: str, batch: int = 2, microbatch: int = 2, **model):
+    """``preset`` at its :data:`TINY_PRESETS` size, float32 on the XLA
+    attention, ``batch`` rows a step in microbatches of ``microbatch``."""
+    from photon_tpu.config import load_preset
+
+    cfg = load_preset(preset)
+    for key, value in {**TINY_PRESETS[preset], "attn_impl": "xla",
+                       "compute_dtype": "float32", **model}.items():
+        setattr(cfg.model, key, value)
+    cfg.train.global_batch_size, cfg.train.device_microbatch_size = batch, microbatch
+    return cfg.validate()
+
+
+def recorded_spans(monkeypatch) -> list:
+    """``(name, attrs)`` of every ``telemetry.span`` the trainer opens from here on."""
+    import contextlib
+
+    from photon_tpu.train import trainer
+
+    spans: list = []
+
+    @contextlib.contextmanager
+    def span(name, **attrs):
+        spans.append((name, attrs))
+        yield
+
+    monkeypatch.setattr(trainer.telemetry, "span", span)
+    return spans

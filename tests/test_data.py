@@ -58,6 +58,25 @@ def test_uint32_for_large_vocab(tmp_path):
     assert (ds[0] == 100_000).all()
 
 
+@pytest.mark.parametrize("vocab, dtype", [(60_000, np.uint16), (1 << 17, np.uint32)])
+def test_batch_widens_rows_across_shards_to_int32(tmp_path, vocab, dtype):
+    """``batch`` writes each row out of its mapped shard into one int32
+    array, whichever of the two on-disk dtypes the vocabulary asked for."""
+    top = vocab - 1  # past the signed range of a type as wide as the shard's
+    with ShardWriter(tmp_path / "ds", 6, vocab, samples_per_shard=4) as w:
+        for i in range(10):
+            w.write(np.full(6, top - i, np.int64))
+    ds = ShardedDataset(tmp_path / "ds")
+    assert ds.dtype == dtype and len(ds.shard_sizes) == 3
+    idxs = np.array([9, 0, 4, 3, 8])
+    got = ds.batch(idxs)
+    assert got.dtype == np.int32 and got.shape == (5, 6)
+    assert (got == (top - idxs)[:, None]).all()
+    assert ds.batch(np.array([], np.int64)).shape == (0, 6)
+    with pytest.raises(IndexError):
+        ds.batch(np.array([10]))
+
+
 def test_loader_epoch_is_permutation(tmp_path):
     ds = _write_range_dataset(tmp_path / "ds", n=100)
     loader = StreamingLoader(ds, batch_size=10, seed=3, shuffle_block_size=16)

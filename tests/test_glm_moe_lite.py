@@ -29,6 +29,7 @@ from photon_tpu.config import load_preset  # noqa: E402
 from photon_tpu.models import MPTModel  # noqa: E402
 from photon_tpu.ops import moe  # noqa: E402
 from photon_tpu.train.train_step import make_loss_fn  # noqa: E402
+from tests._helpers import recorded_spans  # noqa: E402
 
 TINY = dict(
     d_model=64, n_layers=3, n_heads=4, max_seq_len=32, vocab_size=96,
@@ -503,23 +504,6 @@ def test_a_layer_that_holds_every_expert_keeps_its_whole_gathers(monkeypatch):
 # ---------------------------------------------------------------------------
 # the step, the trainer, a federated round
 # ---------------------------------------------------------------------------
-
-
-def recorded_spans(monkeypatch) -> list:
-    """``(name, attrs)`` of every ``telemetry.span`` the trainer opens from here on."""
-    import contextlib
-
-    from photon_tpu.train import trainer
-
-    spans = []
-
-    @contextlib.contextmanager
-    def span(name, **attrs):
-        spans.append((name, attrs))
-        yield
-
-    monkeypatch.setattr(trainer.telemetry, "span", span)
-    return spans
 
 
 def test_fit_returns_the_routing_counters_and_no_aux_loss(monkeypatch):

@@ -115,10 +115,10 @@ def test_objective_matches_reference_and_holds_the_index_losses(seeded):
     assert abs(float(loss) - float(want)) < 1e-5
     # cross-entropy plus the layers' index losses, which are not nothing
     total, counters = _make_loss_and_counters_fn(MPTModel(cfg.model), 16)(params, TOKENS)
-    index = float(counters[DSA_INDEX_LOSS])
+    index = float(counters["dsa_index_loss"])  # by sown key: models/step.COUNTERS
     assert 0.01 < index < 2.0
     _, (picked, _) = ref.objective_sum(params, jnp.asarray(TOKENS), dims)
-    assert float(counters[DSA_PICKED_PAIRS]) == float(picked)
+    assert float(counters["dsa_picked_pairs"]) == float(picked)
     # and the cross-entropy it is added to is the reference's
     want_ce = sum(float(ref.row_objective(params, jnp.asarray(row), dims)[0])
                   for row in TOKENS) / (2 * 63)
@@ -148,9 +148,9 @@ def gradients_by_loss(seeded):
 
     def ce_only(p):
         total, counters = objective(p, TOKENS)
-        return total - counters[DSA_INDEX_LOSS]
+        return total - counters["dsa_index_loss"]
 
-    index_only = lambda p: objective(p, TOKENS)[1][DSA_INDEX_LOSS]  # noqa: E731
+    index_only = lambda p: objective(p, TOKENS)[1]["dsa_index_loss"]  # noqa: E731
     return by_name(jax.grad(ce_only)(params)), by_name(jax.grad(index_only)(params))
 
 
@@ -638,8 +638,8 @@ def test_fit_returns_the_selection_counters_on_their_span():
 
     cfg = tiny_cfg()
     trainer = Trainer(cfg, init_seed=0)
-    assert trainer._kernel_attrs["dsa_layers"] == 2
-    assert trainer._kernel_attrs["dsa_topk"] == 16
+    assert trainer._step_attrs.steps["dsa_layers"] == 2
+    assert trainer._step_attrs.steps["dsa_topk"] == 16
     out = trainer.fit([TOKENS] * 2, duration_steps=2)
     rows = 2 * 2  # layers x batch rows
     assert out[DSA_CAUSAL_PAIRS] == rows * 64 * 65 // 2
@@ -660,10 +660,11 @@ def test_the_dsa_span_says_which_path_made_pbar(impl, interpret, remat):
     """``trainer/dsa`` carries the path the index loss took and the key tiles
     its launches computed and skipped a step; on the CPU backend ``pallas``
     without the interpreter steps down, as the attention does."""
-    from photon_tpu.train.trainer import _index_loss_attrs
+    from photon_tpu.models.step import step_attrs
+    from photon_tpu.utils.profiling import TRAINER_DSA_SPAN
 
     model = tiny_cfg(attn_impl=impl, attn_interpret=interpret, remat=remat).model
-    attrs = _index_loss_attrs(model, batch_rows=2)
+    attrs = step_attrs(model, batch_rows=2).fence[TRAINER_DSA_SPAN]
     assert attrs["index_loss_kernel"] is (impl == "pallas" and interpret)
     launches = 2 * 2 * (2 if remat else 1) if interpret else 0  # layers x rows x passes
     assert attrs["index_loss_tiles"] == launches * 4

@@ -95,9 +95,14 @@ def test_spec_drops_indivisible_axes():
         MeshConfig(fsdp=8),
         MeshConfig(data=2, fsdp=4),
         MeshConfig(data=2, fsdp=2, tensor=2),
-        MeshConfig(fsdp=2, tensor=2, sequence=2),
+        # the sequence axis beside each of the others on FOUR of the eight
+        # virtual devices: on all eight (fsdp=2, tensor=2, sequence=2) the
+        # ring's in-process collectives starve each other of XLA:CPU's one
+        # 8-thread pool and ``rendezvous.cc`` aborts the process after 40 s
+        MeshConfig(fsdp=2, sequence=2),
+        MeshConfig(tensor=2, sequence=2),
     ],
-    ids=["dp8", "fsdp8", "dp2xfsdp4", "dp2fsdp2tp2", "fsdp2tp2sp2"],
+    ids=["dp8", "fsdp8", "dp2xfsdp4", "dp2fsdp2tp2", "fsdp2sp2", "tp2sp2"],
 )
 def test_sharded_training_matches_single_device(mesh_cfg):
     """The same batch must produce the same loss trajectory on any mesh."""

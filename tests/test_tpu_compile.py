@@ -814,7 +814,7 @@ def test_a_presets_train_step_takes_its_layout(topo_devices, monkeypatch, preset
     64-wide heads) and ``glm`` (256 / 256) hand the launches ``[B, S, H·D]``
     and lower no such transpose."""
     from photon_tpu.config import load_preset
-    from photon_tpu.train.trainer import _flash_tile_attrs
+    from photon_tpu.models.step import step_attrs
 
     overrides, layout = LAYOUT_PRESETS[preset]
     cfg = load_preset(preset)
@@ -824,7 +824,7 @@ def test_a_presets_train_step_takes_its_layout(topo_devices, monkeypatch, preset
     lowered, _ = _lower_train_step(cfg, topo_devices()[:1], monkeypatch)
     text = lowered.as_text()
     assert text.count(KERNEL) >= 3
-    assert _flash_tile_attrs(cfg.model)["flash_layout"] == layout
+    assert step_attrs(cfg.model, batch_rows=2).steps["flash_layout"] == layout
     assert bool(TO_BH.search(text)) == (layout == "head_major")
     heads, d = cfg.model.n_heads, cfg.model.d_head
     copies = f"tensor<{2 * heads}x256x{-(-d // 128) * 128}x"  # to_bh's [B·H, S, d_pad]

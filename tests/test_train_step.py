@@ -418,3 +418,186 @@ def test_a_scope_renames_no_parameter(scoped_step):
     want = sorted(f"{stack}/block/{leaf}" if stack else leaf
                   for stack, leaves in _BLOCK_PATHS[kind].items() for leaf in leaves)
     assert paths == want
+
+
+# ---------------------------------------------------------------------------
+# the seam between the model and the step: ``models/step.COUNTERS``, the one
+# walk and the one merge here, ``models/step.step_attrs`` and
+# ``utils/profiling.FENCE_SPANS`` in ``Trainer.fit``
+# ---------------------------------------------------------------------------
+
+from photon_tpu.models import mpt  # noqa: E402
+from photon_tpu.models.step import COUNTERS, Counter, sow  # noqa: E402
+from photon_tpu.train.train_step import _make_loss_and_counters_fn  # noqa: E402
+from photon_tpu.utils import profiling as names  # noqa: E402
+from tests._helpers import TINY_PRESETS, recorded_spans, tiny_preset  # noqa: E402
+
+
+def _tiny_tokens(cfg, rows: int = 4) -> np.ndarray:
+    return np.random.default_rng(3).integers(
+        0, 96, size=(rows, cfg.model.max_seq_len)).astype(np.int32)
+
+
+def _sowing_blocks(monkeypatch, key: str, value) -> None:
+    """Every block sows ``value(x)`` under ``key`` before it runs."""
+    run = mpt.MPTBlock.__call__
+
+    def call(self, x):
+        sow(self, key, value(x))
+        return run(self, x)
+
+    monkeypatch.setattr(mpt.MPTBlock, "__call__", call)
+
+
+#: the keys each preset's blocks sow
+SOWN = {
+    "mpt-125m": set(),
+    "granite-4.0-h-micro-stage1": set(),
+    "glm-4.7-flash-ep8": {k for k in COUNTERS if k.startswith("moe_")} - {"moe_aux"},
+    "lfm2-8b-a1b-ep4": {k for k in COUNTERS if k.startswith("moe_")} - {"moe_aux"},
+    "keye-vl-2.0-30b-a3b-ep8":
+        {k for k in COUNTERS if k.startswith(("moe_", "dsa_"))} - {"moe_aux"},
+    "xing4.0-29b-a4b-ep8":
+        {k for k in COUNTERS if k.startswith(("moe_", "mhc_"))} - {"moe_aux"},
+}
+
+
+@pytest.mark.parametrize("preset", list(TINY_PRESETS))
+def test_every_sown_key_has_a_row(preset, monkeypatch):
+    """What a preset's blocks sow is in the table, key for key, and a block
+    that sows a key without a row raises where it is traced."""
+    cfg = tiny_preset(preset, batch=4, microbatch=4)
+    model = MPTModel(cfg.model)
+    params = jax.eval_shape(lambda: init_params(cfg.model, seed=0))
+    tokens = jax.ShapeDtypeStruct((4, cfg.model.max_seq_len), jnp.int32)
+
+    def sown():  # (a new function a call: a trace is cached by its function)
+        return jax.eval_shape(
+            lambda p, t: model.apply({"params": p}, t, mutable=["intermediates"])[1],
+            params, tokens)
+
+    tree = sown().get("intermediates", {})
+    found = {k.key for path, _ in jax.tree_util.tree_leaves_with_path(tree)
+             for k in path if getattr(k, "key", None) in COUNTERS}
+    assert found == SOWN[preset]
+    assert len(jax.tree.leaves(tree)) == sum(
+        1 for path, _ in jax.tree_util.tree_leaves_with_path(tree)
+        if any(getattr(k, "key", None) in COUNTERS for k in path))
+    _sowing_blocks(monkeypatch, "rows_nobody_lists", lambda x: jnp.zeros([]))
+    with pytest.raises(KeyError, match="rows_nobody_lists"):
+        sown()
+
+
+def test_the_walk_refuses_a_key_without_a_row():
+    """A value that reached ``intermediates`` around ``models/step.sow`` is
+    not dropped in silence either."""
+    from photon_tpu.train.train_step import collect_counters
+
+    with pytest.raises(KeyError, match="stray"):
+        collect_counters({"blocks": {"block": {"stray": (jnp.zeros([2]),)}}})
+    assert collect_counters({}) == {} and collect_counters(None) == {}
+
+
+#: what every ``Trainer.fit`` returns
+FIT_KEYS = {
+    "loss", "grad_norm", "param_norm", names.CLIENT_FINAL_LOSS,
+    names.CLIENT_FIT_SET_PARAMETERS_TIME, names.CLIENT_FIT_TIME, names.CLIENT_LR,
+    names.CLIENT_STEPS, names.CLIENT_TOKENS_PER_SEC, "throughput/tokens_per_sec",
+    "throughput/tokens_per_sec_ema"}
+_MOE = {"rows_held": names.MOE_ROWS_HELD, "max_expert_load": names.MOE_MAX_EXPERT_LOAD,
+        "dispatch_rows_moved": names.MOE_DISPATCH_ROWS_MOVED,
+        "dispatch_rows_static": names.MOE_DISPATCH_ROWS_STATIC}
+#: the parent of the seam at these sizes (4 rows in two microbatches): the
+#: static attrs of ``trainer/steps``, and the spans inside ``trainer/fence``
+#: in order, each attr with the step metric ``fit`` returns under it or with
+#: its static value
+PARENTS = {
+    "mpt-125m": ({}, []),
+    "granite-4.0-h-micro-stage1": ({"mamba_layers": 3, "ssd_chunks": 4}, []),
+    "glm-4.7-flash-ep8": ({}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
+    "lfm2-8b-a1b-ep4": ({"conv_layers": 4}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
+    "xing4.0-29b-a4b-ep8": (
+        {"mhc_streams": 4, "mhc_sublayers": 6},
+        [(names.TRAINER_MOE_LOAD_SPAN, _MOE),
+         (names.TRAINER_MHC_SPAN, {"sinkhorn_gap": names.MHC_SINKHORN_GAP})]),
+    "keye-vl-2.0-30b-a3b-ep8": (
+        {"dsa_layers": 2, "dsa_topk": 16},
+        [(names.TRAINER_MOE_LOAD_SPAN, _MOE),
+         (names.TRAINER_DSA_SPAN, {
+             "picked_pairs": names.DSA_PICKED_PAIRS, "causal_pairs": names.DSA_CAUSAL_PAIRS,
+             "tiles_visited": names.DSA_TILES_VISITED, "tiles_causal": names.DSA_TILES_CAUSAL,
+             "index_loss": names.DSA_INDEX_LOSS, "index_loss_kernel": False,
+             "index_loss_tiles": 0, "index_loss_tiles_skipped": 0})]),
+}
+
+
+@pytest.mark.parametrize("preset", list(TINY_PRESETS))
+def test_step_metrics_and_span_attrs_are_the_parents(preset, monkeypatch):
+    """The keys ``Trainer.fit`` returns, the attrs of ``trainer/steps`` and
+    the spans inside ``trainer/fence`` with theirs, name for name and in the
+    order the parent of the seam opened them."""
+    from photon_tpu.train.trainer import Trainer
+
+    steps, fence = PARENTS[preset]
+    cfg = tiny_preset(preset, batch=4, microbatch=2)
+    trainer = Trainer(cfg, init_seed=0)
+    spans = recorded_spans(monkeypatch)
+    out = trainer.fit([_tiny_tokens(cfg)] * 2, duration_steps=2)
+    metrics = {m for _, attrs in fence for m in attrs.values() if isinstance(m, str)}
+    assert set(out) == FIT_KEYS | metrics
+    (told,) = [attrs for name, attrs in spans if name == names.TRAINER_STEPS_SPAN]
+    assert told == {"steps": 2, **steps}
+    after = [name for name, _ in spans]
+    after = after[after.index(names.TRAINER_FENCE_SPAN) + 1:]
+    assert after == [name for name, _ in fence]
+    for name, attrs in fence:
+        (got,) = [a for n, a in spans if n == name]
+        assert list(got) == list(attrs)
+        assert got == {a: out[m] if isinstance(m, str) else m for a, m in attrs.items()}
+    if preset.startswith("keye"):  # its static counts: 2 layers x 4 rows of 64 tokens
+        assert out[names.DSA_CAUSAL_PAIRS] == 8 * 64 * 65 // 2
+        assert out[names.DSA_TILES_CAUSAL] == 8
+
+
+def test_microbatches_merge_by_each_rows_rule():
+    """Two microbatches' counters as one step's: a row that adds is the sum
+    of the two, one that takes the worst their maximum, one that is a mean
+    their mean; the scan starts from zeros of the loss function's own
+    counters' shapes (``jax.eval_shape``), the rows by expert stack included."""
+    cfg = tiny_preset("keye-vl-2.0-30b-a3b-ep8", batch=4, microbatch=2)
+    model = MPTModel(cfg.model)
+    params = init_params(cfg.model, seed=0)
+    tx, _ = build_optimizer(cfg.optimizer, cfg.scheduler)
+    tokens = jnp.asarray(_tiny_tokens(cfg))
+    counters = jax.jit(lambda p, t: _make_loss_and_counters_fn(model, 16)(p, t)[1])
+    first, second = counters(params, tokens[:2]), counters(params, tokens[2:])
+    assert first["moe_expert_rows"]["blocks"].shape == (2, 8)
+    step = jax.jit(make_train_step(model, tx, n_microbatches=2, loss_chunk_tokens=16))
+    _, metrics = step(init_train_state(model, tx, params), tokens)
+    rules = set()
+    for key, row in COUNTERS.items():
+        if key not in first or row.metric is None:
+            continue
+        a, b, got = float(first[key]), float(second[key]), float(metrics[row.metric])
+        want = {"add": a + b, "max": max(a, b), "mean": (a + b) / 2}[row.microbatches]
+        assert got == pytest.approx(want, rel=1e-6), key
+        assert a != b or row.microbatches == "add", key  # the rules tell apart
+        rules.add(row.microbatches)
+    assert rules == {"add", "max", "mean"}
+    assert "moe_expert_rows" not in metrics and "moe/expert_rows" not in metrics
+
+
+def test_an_eleventh_counter_takes_a_row_and_a_sow(monkeypatch):
+    """One row in the table and one sow in a block: the counter is reduced
+    over layers and microbatches by its row and is in ``Trainer.fit``'s
+    metrics, with no line of ``train/`` knowing it."""
+    from photon_tpu.train.trainer import Trainer
+
+    monkeypatch.setitem(COUNTERS, "tokens_mixed", Counter("test/tokens_mixed", "sum", "add"))
+    monkeypatch.setitem(COUNTERS, "widest_row", Counter("test/widest_row", "max", "max"))
+    _sowing_blocks(monkeypatch, "tokens_mixed",
+                   lambda x: jnp.asarray(x.shape[0] * x.shape[1], jnp.float32))
+    cfg = tiny_preset("mpt-125m", batch=4, microbatch=2)
+    out = Trainer(cfg, init_seed=0).fit([_tiny_tokens(cfg)], duration_steps=1)
+    assert out["test/tokens_mixed"] == 2 * 4 * 32  # layers x rows x tokens
+    assert "test/widest_row" not in out  # a row nobody sows adds no metric

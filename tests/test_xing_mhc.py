@@ -45,15 +45,10 @@ from photon_tpu.ops.flash_attention import (  # noqa: E402
 from photon_tpu.train.train_step import make_loss_fn  # noqa: E402
 from photon_tpu.utils.profiling import (  # noqa: E402
     MHC_MAPS_SCOPE, MHC_READ_IN_SCOPE, MHC_SINKHORN_GAP, MHC_WRITE_BACK_SCOPE)
+from tests._helpers import TINY_PRESETS, tiny_preset  # noqa: E402
 
 PRESET = "xing4.0-29b-a4b-ep8"
-TINY = dict(
-    d_model=32, n_layers=3, n_heads=2, max_seq_len=32, vocab_size=96,
-    q_lora_rank=12, kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=6,
-    rope_scaling_original_max_position=16, dense_mlp_hidden_size=48, mlp_hidden_size=24,
-    moe_num_experts=8, moe_top_k=2, moe_experts_held=4,
-    attn_impl="xla", compute_dtype="float32",
-)
+TINY = {**TINY_PRESETS[PRESET], "attn_impl": "xla", "compute_dtype": "float32"}
 
 
 def tiny_cfg(**model):
@@ -357,11 +352,12 @@ def test_a_narrower_v_is_not_padded_to_the_score_width():
         assert launch_vmem_bytes(launch, 1024, 1024, 256, 2, 256) == launch_vmem_bytes(
             launch, 1024, 1024, 256, 2)
     # what the trainer tells on its span is the same plan
-    from photon_tpu.train.trainer import _flash_tile_attrs
+    from photon_tpu.models.step import step_attrs
 
     model = load_preset(PRESET).model
     model.attn_interpret = True  # the plan is told where the kernel is in the step
-    assert _flash_tile_attrs(model) == both.attrs()
+    told = step_attrs(model, batch_rows=1).steps
+    assert {k: v for k, v in told.items() if k.startswith("flash_")} == both.attrs()
 
 
 def test_yarn_frequencies_and_scale_from_the_published_numbers():
@@ -507,42 +503,17 @@ def test_no_operation_is_under_two_of_the_readers_scopes(step_op_names):
 # one residual stream and one head width: every other preset as it was
 # ---------------------------------------------------------------------------
 
-#: each benchmark preset at a tiny size (its own family's test's sizes): the
+#: each benchmark preset at its tiny size (``tests/_helpers.TINY_PRESETS``): the
 #: leaves of its parameter tree and its loss on ``TOKENS`` with seed-0 weights,
 #: read on the commit before hyper-connections and the v width existed; the
 #: lowered train steps were equal text for text there too (PERF.md, PR 44)
 UNCHANGED = {
-    "mpt-125m": (dict(d_model=32, n_layers=2, n_heads=2, max_seq_len=32, vocab_size=96),
-                 9, 4.5944647789001465),
-    "glm-4.7-flash-ep8": (dict(
-        d_model=64, n_layers=3, n_heads=4, max_seq_len=32, vocab_size=96, q_lora_rank=24,
-        kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
-        dense_mlp_hidden_size=160, mlp_hidden_size=48, moe_num_experts=8, moe_top_k=2,
-        moe_experts_held=4), 32, 4.5490946769714355),
-    "granite-4.0-h-micro-stage1": (dict(
-        d_model=32, n_layers=4, layer_types="mamba,mamba,attention,mamba", n_heads=4,
-        n_kv_heads=2, max_seq_len=32, vocab_size=96, mamba_n_heads=4, mamba_d_head=16,
-        mamba_d_state=8, mamba_chunk_size=8, mlp_hidden_size=48,
-        attention_multiplier=0.125), 37, 4.566521644592285),
-    "keye-vl-2.0-30b-a3b-ep8": (dict(
-        d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=32, max_seq_len=64,
-        vocab_size=96, dsa_topk=16, dsa_index_heads=4, dsa_index_head_dim=16, dsa_chunk=16,
-        mlp_hidden_size=32, moe_num_experts=8, moe_top_k=2, moe_experts_held=2),
-        20, 4.790014743804932),
-    "lfm2-8b-a1b-ep4": (dict(
-        d_model=32, n_heads=4, n_kv_heads=2, max_seq_len=32, vocab_size=96,
-        dense_mlp_hidden_size=48, mlp_hidden_size=24, moe_num_experts=8, moe_top_k=2,
-        moe_experts_held=4), 33, 4.588274002075195),
+    "mpt-125m": (9, 4.5944647789001465),
+    "glm-4.7-flash-ep8": (32, 4.5490946769714355),
+    "granite-4.0-h-micro-stage1": (37, 4.566521644592285),
+    "keye-vl-2.0-30b-a3b-ep8": (20, 4.790014743804932),
+    "lfm2-8b-a1b-ep4": (33, 4.588274002075195),
 }
-
-
-def _tiny_preset(preset: str):
-    cfg = load_preset(preset)
-    for key, value in {**UNCHANGED[preset][0], "attn_impl": "xla",
-                       "compute_dtype": "float32"}.items():
-        setattr(cfg.model, key, value)
-    cfg.train.global_batch_size = cfg.train.device_microbatch_size = 2
-    return cfg.validate()
 
 
 @pytest.mark.parametrize("preset", list(UNCHANGED))
@@ -556,16 +527,16 @@ def test_every_other_preset_keeps_its_tree_its_loss_and_its_step(preset, monkeyp
     from photon_tpu.train import init_train_state
     from photon_tpu.train.train_step import make_train_step
 
-    cfg = _tiny_preset(preset)
+    cfg = tiny_preset(preset)
     assert not cfg.model.hyper_connected and not cfg.model.yarn
     params = init_params(cfg.model, seed=0)
     names = leaf_names(params)
-    assert len(names) == UNCHANGED[preset][1] and not [n for n in names if "hc_" in n]
+    assert len(names) == UNCHANGED[preset][0] and not [n for n in names if "hc_" in n]
     tokens = np.random.default_rng(3).integers(
         0, 96, size=(2, cfg.model.max_seq_len)).astype(np.int32)
     model = MPTModel(cfg.model)
     loss = float(make_loss_fn(model, 16)(params, jnp.asarray(tokens)))
-    assert loss == pytest.approx(UNCHANGED[preset][2], abs=1e-6)
+    assert loss == pytest.approx(UNCHANGED[preset][1], abs=1e-6)
 
     tx, _ = build_optimizer(cfg.optimizer, cfg.scheduler)
     state = init_train_state(model, tx, params)
@@ -670,11 +641,13 @@ def test_every_parameter_has_a_sharding_rule():
 
 
 def test_trainer_tells_the_streams_and_sublayers_on_its_span():
-    from photon_tpu.train.trainer import _mhc_attrs
+    from photon_tpu.models.step import step_attrs
 
-    assert _mhc_attrs(load_preset(PRESET).model) == {"mhc_streams": 4, "mhc_sublayers": 10}
-    assert _mhc_attrs(tiny_cfg().model) == {"mhc_streams": 4, "mhc_sublayers": 6}
-    assert _mhc_attrs(load_preset("glm-4.7-flash-ep8").model) == {}
+    # (no kernel in a step on the CPU backend: the flash plan adds no key)
+    told = lambda model: step_attrs(model, batch_rows=2).steps  # noqa: E731
+    assert told(load_preset(PRESET).model) == {"mhc_streams": 4, "mhc_sublayers": 10}
+    assert told(tiny_cfg().model) == {"mhc_streams": 4, "mhc_sublayers": 6}
+    assert told(load_preset("glm-4.7-flash-ep8").model) == {}
 
 
 def test_a_federated_client_fit_trains_the_family(tmp_path):
