@@ -18,7 +18,7 @@ import pathlib
 import typing
 import warnings
 from dataclasses import dataclass, field
-from typing import Any
+from typing import Any, NamedTuple
 
 import yaml
 
@@ -37,6 +37,23 @@ class AttnImpl(str, enum.Enum):
     PALLAS = "pallas"  # blockwise flash attention kernel (TPU)
     XLA = "xla"  # pure-XLA reference path (reference's ``attn_impl: torch``)
     RING = "ring"  # ring/context-parallel attention over the sequence mesh axis
+
+
+#: the ``layer_types`` entry of a windowed attention layer
+SLIDING_ATTENTION = "sliding_attention"
+#: every ``layer_types`` entry whose mixer is attention
+ATTENTION_KINDS = ("attention", "full_attention", SLIDING_ATTENTION)
+
+
+class AttentionKind(NamedTuple):
+    """One kind of attention layer (``ModelConfig.attention_kind``)."""
+
+    n_heads: int
+    window: int | None  # keys a query sees, its own among them; None = all
+    rope_theta: float
+    rotary_dim: int  # the head's leading dims that turn
+    inv_freq: tuple[float, ...] | None  # None: plain ``rope_theta`` frequencies
+    rope_factor: float  # on cos and sin
 
 
 @dataclass
@@ -159,8 +176,10 @@ class ModelConfig:
     # A stack whose layers differ in kind (HF ``layer_types``, its list joined
     # by commas so that YAML, JSON and ``--set`` all spell it alike): one entry
     # a layer, ``mamba`` (a Mamba-2 mixer, ``ops/ssd.py``), ``conv`` (a gated
-    # short convolution, below) or ``attention``; every layer keeps the
-    # block's norms and residuals. "" = attention everywhere, one scanned
+    # short convolution, below), ``attention``, or ``full_attention`` /
+    # ``sliding_attention`` (attention layers of two kinds, further below);
+    # every layer keeps the block's norms and residuals. "" = attention
+    # everywhere, one scanned
     # stack ``blocks``; otherwise each run of layers equal in mixer AND in MLP
     # kind (the first ``first_k_dense`` dense, the others the model's
     # ``mlp``) is a scanned stack of its own, ``blocks_0``, ``blocks_1``, ...
@@ -181,6 +200,31 @@ class ModelConfig:
     # and no activation, over ``B * u``; the gate ``C *`` on its output; the
     # projection back.
     conv_kernel_size: int = 3
+    # Attention layers of two kinds in one model (HF ``layer_types``'
+    # ``full_attention`` / ``sliding_attention``, training path only;
+    # ``attention`` stays the model-wide kind, a full layer). A sliding layer
+    # sees the last ``sliding_window`` keys, its own among them (``i -
+    # sliding_window < j <= i``: the flash kernel walks that band,
+    # ``ops/flash_attention.py``), has ``swa_n_heads`` query heads (0 ->
+    # ``n_heads``, which stays the full layers'; both kinds share
+    # ``n_kv_heads`` and ``head_dim``) and turns its whole head by plain
+    # ``swa_rope_theta`` frequencies (0 -> ``rope_theta``). A full layer turns
+    # the first ``partial_rotary_factor`` of its head's dims (rotate-half
+    # inside them; the rest pass) by ``rope_theta``'s frequencies, YaRN's
+    # where ``rope_scaling_type`` says so, computed over the turned dims.
+    # ``rope_scaling_attention_factor`` multiplies a full layer's cos and sin
+    # (HF's ``attention_factor``: the turned part of a score carries its
+    # square, the passed part 1; the softmax scale stays ``1/sqrt(d_head)``);
+    # 0 keeps YaRN's ``mscale`` form above, a scale on the softmax.
+    # ``attn_gate: headwise`` gates every attention layer's output before
+    # ``out_proj``: ``sigmoid(h W_g)``, one gate a head and token (``attn_gate``
+    # ``[d_model, heads]``).
+    sliding_window: int = 0
+    swa_n_heads: int = 0
+    swa_rope_theta: float = 0.0
+    partial_rotary_factor: float = 1.0
+    rope_scaling_attention_factor: float = 0.0
+    attn_gate: str = ""  # "" | headwise
     # Granite's four multipliers. At their defaults nothing is multiplied:
     # the embedding's output, each residual branch and the logits (divided by
     # ``logits_scaling``) are left as they are, and ``attention_multiplier``
@@ -270,6 +314,33 @@ class ModelConfig:
         return self.mamba_n_heads * self.mamba_d_head
 
     @property
+    def swa_layers(self) -> int:
+        return self.layer_kinds.count(SLIDING_ATTENTION)
+
+    @property
+    def full_attention_layers(self) -> int:
+        """The layers whose attention sees every earlier key."""
+        if not self.hybrid:
+            return self.n_layers
+        return sum(k in ATTENTION_KINDS for k in self.layer_kinds) - self.swa_layers
+
+    def attention_kind(self, kind: str = "attention") -> "AttentionKind":
+        """What an attention layer of ``kind`` (a ``layer_types`` entry) reads
+        where the kinds differ: its heads, window and rotation."""
+        if kind == SLIDING_ATTENTION:
+            return AttentionKind(self.swa_n_heads or self.n_heads, self.sliding_window,
+                                 self.swa_rope_theta or self.rope_theta, self.d_head, None, 1.0)
+        rotary = self.rotary_dim
+        return AttentionKind(self.n_heads, None, self.rope_theta, rotary,
+                             self.rope_inv_freq(rotary),
+                             self.rope_scaling_attention_factor or 1.0)
+
+    @property
+    def rotary_dim(self) -> int:
+        """The dims of a full layer's head that turn."""
+        return int(round(self.d_head * self.partial_rotary_factor))
+
+    @property
     def stacks(self) -> list[tuple[str, str, bool, int]]:
         """The model's scanned stacks in order: ``(name, mixer, dense MLP,
         length)``. One stack of attention blocks (``blocks``) behind the
@@ -308,13 +379,14 @@ class ModelConfig:
     @property
     def training_path_only(self) -> bool:
         """Latent attention, the dropless expert layer, leading dense blocks,
-        layers of different kinds, the multipliers, hyper-connected streams or
-        scaled rotary frequencies: what serving, cached decode, LoRA and the
-        HF maps lack."""
+        layers of different kinds (a window among them), the multipliers,
+        hyper-connected streams, scaled rotary frequencies, a partly turned
+        head or a gated attention output: what serving, cached decode, LoRA
+        and the HF maps lack."""
         return (self.latent_attention or self.dropless_moe or self.first_k_dense > 0
                 or self.hybrid or self.scaled or self.sparse_attention
                 or self.qk_norm or self.head_dim > 0 or self.hyper_connected
-                or self.yarn)
+                or self.yarn or self.partial_rotary_factor != 1.0 or bool(self.attn_gate))
 
     def rope_inv_freq(self, dim: int) -> tuple[float, ...] | None:
         """The rotary inverse frequencies of ``dim`` rotary dims under YaRN
@@ -343,10 +415,11 @@ class ModelConfig:
         """What multiplies the scores before the softmax: ``None`` for the
         dispatch's own ``1/sqrt(d_head)``, ``attention_multiplier`` where it
         is set, and under YaRN ``1/sqrt(d_head)`` times the square of
-        ``mscale(factor, mscale_all_dim)``."""
+        ``mscale(factor, mscale_all_dim)`` (unless the factor is on cos and
+        sin: ``rope_scaling_attention_factor``)."""
         if self.attention_multiplier:
             return self.attention_multiplier
-        if self.yarn:
+        if self.yarn and not self.rope_scaling_attention_factor:
             m = 0.1 * self.rope_scaling_mscale_all_dim * math.log(self.rope_scaling_factor) + 1.0
             return self.d_head ** -0.5 * m * m
         return None
@@ -1202,6 +1275,7 @@ class Config:
         self._validate_hybrid_family()
         self._validate_sparse_attention_family()
         self._validate_hyper_connected_family()
+        self._validate_windowed_family()
         if m.training_path_only:
             if m.lora_rank or self.photon.adapters.enabled:
                 raise ValueError(
@@ -1236,7 +1310,13 @@ class Config:
                     ">= 1, rope_scaling_original_max_position > 0, "
                     "rope_scaling_beta_fast > rope_scaling_beta_slow > 0 and "
                     "rope_scaling_mscale > 0")
-            if m.rope_scaling_mscale != m.rope_scaling_mscale_all_dim:
+            if m.rope_scaling_attention_factor:
+                if m.rope_scaling_mscale != 1.0 or m.rope_scaling_mscale_all_dim:
+                    raise ValueError(
+                        "rope_scaling_attention_factor (on cos and sin) and "
+                        "rope_scaling_mscale / rope_scaling_mscale_all_dim (on "
+                        "the softmax) both scale the scores: state one")
+            elif m.rope_scaling_mscale != m.rope_scaling_mscale_all_dim:
                 raise ValueError(
                     f"rope_scaling_mscale={m.rope_scaling_mscale} differs from "
                     f"rope_scaling_mscale_all_dim={m.rope_scaling_mscale_all_dim}: "
@@ -1278,6 +1358,73 @@ class Config:
                 "mesh.expert > 1 is not supported: the pipeline schedule "
                 "carries one stream, and the maps read every stream's whole "
                 "width at each token")
+
+    def _validate_windowed_family(self) -> None:
+        """Sliding-window layers beside full ones, a partly turned head, the
+        factor on cos and sin, and the gate on attention's output (preset
+        ``laguna-xs.2-ep8``)."""
+        m = self.model
+        if m.attn_gate not in ("", "headwise"):
+            raise ValueError(f"attn_gate={m.attn_gate!r}: only 'headwise' (or '') is computed here")
+        if m.attn_gate and (m.latent_attention or m.sparse_attention):
+            raise ValueError(
+                "attn_gate lives in the plain attention branch: it does not "
+                "combine with latent attention or dsa_topk > 0")
+        if not 0.0 < m.partial_rotary_factor <= 1.0 or m.rope_scaling_attention_factor < 0:
+            raise ValueError(
+                "partial_rotary_factor must lie in (0, 1] and "
+                "rope_scaling_attention_factor be >= 0 (0 = the mscale form)")
+        if m.partial_rotary_factor != 1.0:
+            if not m.rope or m.latent_attention or m.sparse_attention:
+                raise ValueError(
+                    "partial_rotary_factor needs rope=true and the plain attention "
+                    "branch: latent attention has qk_rope_head_dim, and the "
+                    "indexer turns whole heads")
+            turned = m.d_head * m.partial_rotary_factor
+            if turned != int(turned) or int(turned) % 2:
+                raise ValueError(
+                    f"partial_rotary_factor={m.partial_rotary_factor} of d_head="
+                    f"{m.d_head} is {turned} dims: the turned part must be an even "
+                    "whole number")
+        if m.rope_scaling_attention_factor and not m.yarn:
+            raise ValueError(
+                "rope_scaling_attention_factor belongs to rope_scaling_type='yarn'")
+        if not m.swa_layers:
+            if m.sliding_window or m.swa_n_heads or m.swa_rope_theta:
+                raise ValueError(
+                    "sliding_window / swa_n_heads / swa_rope_theta belong to "
+                    "'sliding_attention' layers in layer_types")
+            return
+        if m.sliding_window < 1 or m.swa_n_heads < 0 or m.swa_rope_theta < 0:
+            raise ValueError(
+                "a 'sliding_attention' layer needs sliding_window >= 1 (and "
+                "swa_n_heads, swa_rope_theta >= 0)")
+        if m.alibi or not m.rope or m.latent_attention or m.sparse_attention \
+                or m.hyper_connected:
+            raise ValueError(
+                "'sliding_attention' layers need rope=true and do not combine "
+                "with alibi, latent attention, dsa_topk > 0 or hc_mult > 1: the "
+                "band is the plain causal branch's second bound")
+        if m.yarn and not m.rope_scaling_attention_factor:
+            raise ValueError(
+                "'sliding_attention' layers beside rope_scaling_type='yarn' need "
+                "rope_scaling_attention_factor: the mscale form scales every "
+                "layer's softmax, the sliding layers' plain rotation too")
+        if m.swa_n_heads and m.swa_n_heads != m.n_heads:
+            n_kv = m.n_kv_heads or m.n_heads
+            if not m.head_dim or m.swa_n_heads % n_kv:
+                raise ValueError(
+                    f"swa_n_heads={m.swa_n_heads} beside n_heads={m.n_heads} needs "
+                    "head_dim (one head width for both kinds) and a multiple of "
+                    f"the {n_kv} key-value heads")
+        if m.attn_impl == AttnImpl.RING.value or max(
+                self.mesh.pipe, self.mesh.tensor, self.mesh.sequence, self.mesh.expert,
+                self.mesh.fsdp) > 1:
+            raise ValueError(
+                "'sliding_attention' layers are not supported with ring attention "
+                "(attn_impl='ring') or a mesh axis above 1 other than data: ring "
+                "chunks know no second bound, and the two kinds' head counts "
+                "split differently")
 
     def _validate_sparse_attention_family(self) -> None:
         """``head_dim``, ``qk_norm`` and the indexer's sparse attention
@@ -1351,11 +1498,11 @@ class Config:
         if not m.hybrid:
             return
         kinds = set(m.layer_kinds)
-        if len(m.layer_kinds) != m.n_layers or not kinds <= {"mamba", "conv", "attention"}:
+        if len(m.layer_kinds) != m.n_layers or not kinds <= {"mamba", "conv", *ATTENTION_KINDS}:
             raise ValueError(
                 f"layer_types needs n_layers={m.n_layers} comma-separated entries, "
-                f"each 'mamba', 'conv' or 'attention'; got {len(m.layer_kinds)}: "
-                f"{sorted(kinds)}")
+                f"each 'mamba', 'conv', 'attention', 'full_attention' or "
+                f"'sliding_attention'; got {len(m.layer_kinds)}: {sorted(kinds)}")
         if m.latent_attention:
             raise ValueError(
                 "layer_types does not combine with latent attention: its "
@@ -2041,6 +2188,9 @@ def refuse_training_only_family(model: ModelConfig, what: str) -> None:
         ("heads of their own width (head_dim)", model.head_dim > 0),
         ("hyper-connected residual streams (hc_mult > 1)", model.hyper_connected),
         ("YaRN's rotary frequencies (rope_scaling_type)", model.yarn),
+        ("sliding-window layers (layer_types' sliding_attention)", model.swa_layers > 0),
+        ("a partly turned head (partial_rotary_factor)", model.partial_rotary_factor != 1.0),
+        ("a gated attention output (attn_gate)", bool(model.attn_gate)),
     ) if on]
     if has:
         raise NotImplementedError(

@@ -60,6 +60,18 @@ attention's v may be narrower than its q and k (``v_head_dim``), and
 ``rope_scaling_type: yarn`` gives the rotation YaRN's blended frequencies and
 the softmax its scale. At ``hc_mult == 1`` none of it adds an operation.
 
+Attention layers of two kinds compose with the expert family (preset
+``laguna-xs.2-ep8``, training path only): ``layer_types``' ``full_attention``
+and ``sliding_attention`` give a block its kind, and a kind its query heads
+(``n_heads`` / ``swa_n_heads`` over the same key-value heads), its window (a
+sliding layer's keys ``i - sliding_window < j <= i``: the flash kernel walks
+that band), and its rotation (a full layer turns ``partial_rotary_factor`` of
+its head by YaRN's frequencies with ``rope_scaling_attention_factor`` on cos
+and sin, a sliding layer its whole head by plain ``swa_rope_theta``);
+``attn_gate: headwise`` multiplies every head's output by a sigmoid gate of
+the block's normed input before ``out_proj``. Runs of layers equal in kind and
+MLP are the scanned stacks (``ModelConfig.stacks``).
+
 TPU-first design choices (not in the reference):
 - Layers are stacked with ``nn.scan`` → one traced block, params carry a
   leading ``[n_layers, ...]`` axis. This keeps compile time flat in depth and
@@ -85,6 +97,7 @@ from photon_tpu.models.step import sow
 from photon_tpu.ops.attention import multihead_attention
 from photon_tpu.ops.flash_attention import IN_PLACE, flash_layout
 from photon_tpu.utils.profiling import (
+    ATTN_GATE_SCOPE,
     ATTN_PROJ_SCOPE,
     ATTN_QK_NORM_SCOPE,
     BLOCK_MLP_SCOPE,
@@ -179,14 +192,20 @@ def _norm(cfg: ModelConfig, name: str) -> nn.Module:
 
 
 def apply_rope(q: jax.Array, k: jax.Array, theta: float,
-               inv_freq: tuple[float, ...] | None = None) -> tuple[jax.Array, jax.Array]:
+               inv_freq: tuple[float, ...] | None = None,
+               rotary_dim: int | None = None,
+               factor: float = 1.0) -> tuple[jax.Array, jax.Array]:
     """Rotary positions on ``[B, S, H, D]`` q/k (llama/GPT-NeoX rotate-half
     convention, angles in fp32). Positions are LOGICAL sequence indices, so
     the rotation is correct under a GSPMD-sharded ``sequence`` mesh axis —
     ring attention receives already-rotated q/k and needs no offset.
-    ``inv_freq`` (``D / 2`` static numbers) stands in for ``theta``'s own
-    frequencies: YaRN's (``ModelConfig.rope_inv_freq``)."""
-    d = q.shape[-1]
+    ``inv_freq`` (half as many static numbers as dims turn) stands in for
+    ``theta``'s own frequencies: YaRN's (``ModelConfig.rope_inv_freq``).
+    ``rotary_dim`` (``None``: all ``D``) turns the head's first ``rotary_dim``
+    dims, dim ``i`` with ``i + rotary_dim / 2``, and passes the rest;
+    ``factor`` multiplies cos and sin (HF's ``attention_factor``), so that
+    the turned part of a score carries its square and the passed part 1."""
+    d = q.shape[-1] if rotary_dim is None else rotary_dim
     half = d // 2
     if inv_freq is None:
         inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
@@ -195,12 +214,16 @@ def apply_rope(q: jax.Array, k: jax.Array, theta: float,
     ang = jnp.arange(q.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.cos(ang)[None, :, None, :]  # [1, S, 1, half]
     sin = jnp.sin(ang)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
 
     def rot(x):
         x1 = x[..., :half].astype(jnp.float32)
-        x2 = x[..., half:].astype(jnp.float32)
-        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-        return out.astype(x.dtype)
+        x2 = x[..., half:d].astype(jnp.float32)
+        parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+        if d != x.shape[-1]:
+            parts.append(x[..., d:].astype(jnp.float32))
+        return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
     return rot(q), rot(k)
 
@@ -389,8 +412,10 @@ class MPTBlock(nn.Module):
     #: same norms, residuals and attention, a SwiGLU of
     #: ``cfg.dense_mlp_hidden_size`` where the stack's blocks have experts
     dense_mlp: bool = False
-    #: what mixes the positions (``cfg.layer_types``): ``attention``, or in
-    #: its place ``mamba`` for a Mamba-2 mixer, ``conv`` for a gated short
+    #: what mixes the positions (``cfg.layer_types``): ``attention`` (or
+    #: ``full_attention`` / ``sliding_attention`` where a model has both kinds:
+    #: ``cfg.attention_kind`` gives a kind its heads, window and rotation), or
+    #: in its place ``mamba`` for a Mamba-2 mixer, ``conv`` for a gated short
     #: convolution
     mixer: str = "attention"
 
@@ -661,13 +686,17 @@ class MPTBlock(nn.Module):
             x = _residual(cfg, x, self._short_conv_mixer(h, dense, resid_std))
         else:
             n_kv = cfg.n_kv_heads or cfg.n_heads
+            # this layer's kind: the model's one, or where ``layer_types`` has
+            # full and sliding layers each kind's heads, window and rotation
+            kind = cfg.attention_kind(self.mixer)
+            n_heads = kind.n_heads
             b, s, _ = h.shape
             if cfg.latent_attention:
                 with jax.named_scope(MLA_PROJ_SCOPE):
                     q, k, v = self._latent_qkv(h, dense)  # [B, S, H, d_head] each
             else:
                 with jax.named_scope(ATTN_PROJ_SCOPE):
-                    if n_kv == cfg.n_heads:
+                    if n_kv == n_heads:
                         qkv = adapted(3 * cfg.d_model, "wqkv", cfg.emb_init_std, h)
                         q, k, v = jnp.split(qkv, 3, axis=-1)
                     else:
@@ -675,10 +704,10 @@ class MPTBlock(nn.Module):
                         # put shard boundaries at positions that don't align with
                         # the tensor axis and force per-layer resharding; three
                         # column-parallel matmuls stay shard-local
-                        q = adapted(cfg.n_heads * cfg.d_head, "q_proj", cfg.emb_init_std, h)
+                        q = adapted(n_heads * cfg.d_head, "q_proj", cfg.emb_init_std, h)
                         k = adapted(n_kv * cfg.d_head, "k_proj", cfg.emb_init_std, h)
                         v = adapted(n_kv * cfg.d_head, "v_proj", cfg.emb_init_std, h)
-                    q = q.reshape(b, s, cfg.n_heads, cfg.d_head)
+                    q = q.reshape(b, s, n_heads, cfg.d_head)
                     k = k.reshape(b, s, n_kv, cfg.d_head)
                     v = v.reshape(b, s, n_kv, cfg.d_head)
             if cfg.qk_norm:
@@ -689,7 +718,8 @@ class MPTBlock(nn.Module):
                 # before the kv repeat: the rotation is per-head-identical, so
                 # rotating n_kv heads then replicating equals the reverse order
                 with jax.named_scope(ATTN_PROJ_SCOPE):
-                    q, k = apply_rope(q, k, cfg.rope_theta, cfg.rope_inv_freq(cfg.d_head))
+                    q, k = apply_rope(q, k, kind.rope_theta, kind.inv_freq,
+                                      rotary_dim=kind.rotary_dim, factor=kind.rope_factor)
             # k/v go to the dispatch at their native n_kv width: the pallas
             # flash kernel consumes GQA groups directly (index-mapped kv rows,
             # no repeated tensor in HBM); the xla/ring paths replicate inside
@@ -703,7 +733,15 @@ class MPTBlock(nn.Module):
                     interpret=cfg.attn_interpret,
                     # None: the dispatch's own 1/sqrt(d_head)
                     scale=cfg.softmax_scale,
+                    window=kind.window,
                 )
+            if cfg.attn_gate:
+                # one gate a head and token, from the block's normed input
+                with jax.named_scope(ATTN_GATE_SCOPE):
+                    gate = jax.nn.sigmoid(
+                        dense(n_heads, "attn_gate", cfg.emb_init_std)(h).astype(jnp.float32))
+                    attn_out = (attn_out.astype(jnp.float32) * gate[..., None]).astype(
+                        attn_out.dtype)
             if cfg.latent_attention:
                 with jax.named_scope(MLA_PROJ_SCOPE):
                     branch = dense(cfg.d_model, "out_proj", resid_std)(
@@ -712,7 +750,7 @@ class MPTBlock(nn.Module):
                         x = _residual(cfg, x, branch)
             else:
                 with jax.named_scope(ATTN_PROJ_SCOPE):
-                    attn_out = attn_out.reshape(b, s, cfg.n_heads * cfg.d_head)
+                    attn_out = attn_out.reshape(b, s, n_heads * cfg.d_head)
                     branch = adapted(cfg.d_model, "out_proj", resid_std, attn_out)
                     if hc is None:
                         x = _residual(cfg, x, branch)

@@ -14,7 +14,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from photon_tpu.config.schema import ModelConfig
+from photon_tpu.config.schema import SLIDING_ATTENTION, ModelConfig
 from photon_tpu.utils.profiling import (
     DSA_INDEX_LOSS,
     DSA_PICKED_PAIRS,
@@ -106,13 +106,21 @@ def _flash_attrs(cfg: ModelConfig) -> dict[str, str]:
             f"{n}={bq}x{bk}" for n, (bq, bk) in zip(LAUNCHES, plan_tiles(s, s)))}
     d_v = cfg.v_head_dim if cfg.latent_attention else cfg.d_head
     n_kv = cfg.n_kv_heads or cfg.n_heads
-    # the layout the launches read, by the model's own heads (a shard of a
-    # tensor-parallel mesh applies the same rule to its local ones)
-    layout = flash_layout(cfg.n_heads, n_kv, cfg.d_head, d_v)
-    return pick_tiles(
-        s, s, lane_padded(cfg.d_head), jnp.dtype(cfg.compute_dtype).itemsize,
-        cfg.n_heads // n_kv, d_v_pad=lane_padded(d_v), layout=layout,
-    ).attrs(layout)
+
+    def plan(n_heads: int, window: int | None) -> dict[str, str]:
+        # the layout the launches read, by the model's own heads (a shard of
+        # a tensor-parallel mesh applies the same rule to its local ones)
+        layout = flash_layout(n_heads, n_kv, cfg.d_head, d_v)
+        return pick_tiles(
+            s, s, lane_padded(cfg.d_head), jnp.dtype(cfg.compute_dtype).itemsize,
+            n_heads // n_kv, d_v_pad=lane_padded(d_v), layout=layout, window=window,
+        ).attrs(layout, banded=window is not None)
+
+    told = plan(cfg.n_heads, None) if cfg.full_attention_layers else {}
+    if cfg.swa_layers and cfg.sliding_window < s:  # else the causal launches
+        sliding = cfg.attention_kind(SLIDING_ATTENTION)
+        told.update(plan(sliding.n_heads, sliding.window))
+    return told
 
 
 def _selection_attrs(cfg: ModelConfig, batch_rows: int) -> dict[str, Any]:
@@ -148,6 +156,8 @@ def step_attrs(cfg: ModelConfig, batch_rows: int) -> StepAttrs:
                      ssd_chunks=cfg.max_seq_len // cfg.mamba_chunk_size)
     if cfg.conv_layers:
         steps.update(conv_layers=cfg.conv_layers)
+    if cfg.swa_layers:  # beside them: the banded launches' plan (``_flash_attrs``)
+        steps.update(swa_layers=cfg.swa_layers, sliding_window=cfg.sliding_window)
     if cfg.hyper_connected:  # maps, read-in and write-back: two sublayers a layer
         steps.update(mhc_streams=cfg.hc_mult, mhc_sublayers=2 * cfg.n_layers)
     fence = {}

@@ -47,12 +47,15 @@ def xla_attention(
     causal: bool = True,
     alibi: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Plain softmax attention; XLA fuses mask+softmax into the matmuls.
 
     Numerically the oracle for the Pallas kernel's parity tests. ``alibi``
     adds the per-head linear distance bias ``-slope_h * (q_pos - k_pos)``;
-    ``scale`` multiplies the scores (``None``: ``1/sqrt(d_head)``).
+    ``scale`` multiplies the scores (``None``: ``1/sqrt(d_head)``);
+    ``window`` (causal only) keeps the keys ``q_pos - window < k_pos <=
+    q_pos``, the query's own among them.
     """
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
@@ -68,6 +71,8 @@ def xla_attention(
     if causal:
         # offset supports s_q != s_k (e.g. decode); here typically equal
         mask = q_pos >= k_pos
+        if window is not None:
+            mask &= q_pos - k_pos < window
         scores = jnp.where(mask[None, None, :, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
@@ -87,10 +92,13 @@ def multihead_attention(
     block_k: int | None = None,
     interpret: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Dispatch on ``impl`` ∈ {pallas, xla, ring}. ``scale`` multiplies the
     scores before the softmax (``None``: ``1/sqrt(d_head)``; the ring merge
-    knows no other). ``pallas`` runs the kernel
+    knows no other). ``window`` (causal, no ALiBi, not ``ring``) keeps each
+    query's last ``window`` keys: the kernel walks that band, the XLA path
+    masks it. ``pallas`` runs the kernel
     on a TPU (or anywhere under ``interpret``); on the CPU backend the tests
     use — and on no other — it steps down to ``xla_attention`` without a word
     (``flash_attention.pallas_supported``), so a CPU run never shows that the
@@ -110,6 +118,10 @@ def multihead_attention(
     h_q, h_kv = q.shape[2], k.shape[2]
     if h_q % h_kv:
         raise ValueError(f"q heads ({h_q}) must be a multiple of kv heads ({h_kv})")
+    if window is not None and (impl == "ring" or alibi or not causal or window < 1):
+        raise NotImplementedError(
+            "a window needs causal attention without ALiBi on the pallas or xla "
+            "path (ring attention's chunks and the bias know no second bound)")
 
     def rep(x):
         return jnp.repeat(x, h_q // h_kv, axis=2) if h_kv != h_q else x
@@ -160,7 +172,7 @@ def multihead_attention(
             if not sharded_axes:
                 return flash_attention(q, k, v, causal=causal, alibi=alibi,
                                        block_q=block_q, block_k=block_k,
-                                       interpret=interpret, scale=scale)
+                                       interpret=interpret, scale=scale, window=window)
             if h_kv % mesh.shape.get("tensor", 1):
                 # kv heads don't split over the tensor axis — replicate up
                 # to the q head count (which always splits; param_specs
@@ -182,7 +194,7 @@ def multihead_attention(
                 return flash_attention(q_s, k_s, v_s, causal=causal,
                                        alibi=alibi, alibi_slopes=sl,
                                        block_q=block_q, block_k=block_k,
-                                       interpret=interpret, scale=scale)
+                                       interpret=interpret, scale=scale, window=window)
 
             spec = P(("data", "fsdp", "expert"), None, "tensor", None)
             fn = shard_map(
@@ -196,4 +208,5 @@ def multihead_attention(
         impl = "xla"
     if impl != "xla":
         raise ValueError(f"unknown attention impl {impl!r}")
-    return xla_attention(q, rep(k), rep(v), causal=causal, alibi=alibi, scale=scale)
+    return xla_attention(q, rep(k), rep(v), causal=causal, alibi=alibi, scale=scale,
+                         window=window)

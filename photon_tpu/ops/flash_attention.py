@@ -38,12 +38,29 @@ Design notes (TPU-first):
   than the next smaller; a launch whose estimate passes what Mosaic gives
   unasked asks for it (``vmem_limit_bytes``). Callers pass ``block_q`` /
   ``block_k`` only to pin a tile (tests, the ladder).
-- The grid is dense over the causal square, so a tile strictly above the
-  diagonal still costs its grid step, but nothing else: its body is
+- A causal launch's grid is dense over the causal square, so a tile strictly
+  above the diagonal still costs its grid step, but nothing else: its body is
   predicated off (``_run_tile``) and its k/v (forward, dq) or
   q/dO/lse/delta (dk/dv) block index is clamped to the nearest live one
   (``_kv_block``, ``_q_block``), so the pipeline sees a repeated index and
   issues no copy.
+- A windowed launch (``window``: key ``j`` is live for query ``i`` iff ``i -
+  window < j <= i``) walks the BAND and not the square (``_Band``): the inner
+  axis of the forward's and dq's grid has only as many steps as k tiles can
+  meet one q tile's band, and the index maps place step ``j`` at the band's
+  first tile for that q tile (clamped at 0; a step past the diagonal repeats
+  the last live index and is predicated off); the dk/dv launch walks the q
+  tiles of one k tile's band likewise. A tile wholly inside the band runs
+  unmasked, the tile on the diagonal as the causal launch's strips (where a
+  tile is no wider than the window), the tile on the band's lower edge under
+  the second bound alone (as the diagonal's strips mirrored, ``_edge_strips``,
+  where the window is a whole number of tiles), and only a tile that both
+  bounds cut under both.
+  Which of those bodies a launch holds is static (``_Band.cases``). The
+  windowed launches carry their own names (``flash_swa_fwd``,
+  ``flash_swa_dq``, ``flash_swa_dkv``); ``window=None`` is the causal
+  program, instruction for instruction, and so is a window that holds the
+  whole sequence.
 - A live tile multiplies no block of scores that lies wholly above the
   diagonal. The tiles are large because a grid step and an online-softmax
   update cost more than a small tile's products, so on the diagonal half of
@@ -89,6 +106,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from photon_tpu.utils.profiling import (
+    FLASH_SWA_DKV_KERNEL,
+    FLASH_SWA_DQ_KERNEL,
+    FLASH_SWA_FWD_KERNEL,
+)
 
 LANE = 128
 SUBLANE = 8  # fp32 sublane height; lse/delta carry 8 redundant rows for tiling
@@ -143,6 +166,7 @@ VMEM_SCOPED_DEFAULT = 16 * 2**20
 VMEM_BUDGET = 48 * 2**20
 VMEM_SLACK = 2**20  # the compiler's own scratch, and rounding to its tiles
 SCORE_TEMPS = 1.5  # [block_q, block_k] fp32 temporaries alive at once
+BAND_SCORE_TEMPS = 0.5  # more of them in a windowed launch: the second bound's select
 # The largest (block_q, block_k) at which each launch was still no slower than
 # at the next smaller tile, on the ladder ``scripts/flash_tile_ladder.py`` runs
 # on the chip (v5e; PERF.md PR 28, read again with the strip bodies in PR 39 at
@@ -158,6 +182,19 @@ TILE_LADDER_WIDTH = {"fwd": 256, "dq": 128, "dkv": 128}
 # live extent of the other axis only (``strip_rows``, ``_run_tile``). 0 keeps
 # the whole-tile masked body. Read off the same ladder (``--sub``).
 STRIP_ROWS = {"fwd": 256, "dq": 256, "dkv": 256}
+# The same for a windowed launch, whose tiles are smaller (a strip is a larger
+# part of one) and whose tile on the band's lower edge runs as strips too: read
+# off the ladder at window 512 (``--window 512 --sub``; PERF.md, PR 49).
+BAND_STRIP_ROWS = {"fwd": 128, "dq": 256, "dkv": 256}
+# A windowed launch's grid step (its fixed cost: the pipeline's turn, the
+# online-softmax merge or the accumulator's read and write) in score pairs, by
+# which ``pick_tiles`` weighs a band's tile: small tiles multiply few pairs no
+# query sees and pay many steps, large ones the reverse. From the ladder at 64 /
+# 8 heads of 128, window 512, seq 16,384 (PERF.md, PR 49): a launch's time at
+# 256- and 512-square tiles is ``a x executed pairs + b x steps`` with ``b / a``
+# these (1.06 / 0.56 / 1.49 us a step against 6.0 / 4.1 / 8.1 ps a pair); the
+# 512 square they pick is the ladder's fastest for every launch.
+BAND_STEP_PAIRS = {"fwd": 176_000, "dq": 135_000, "dkv": 184_000}
 
 
 def pallas_supported(x: jax.Array | None) -> bool:
@@ -195,13 +232,22 @@ def _pos_diff(q0, k0, rows: int, cols: int) -> jax.Array:
             - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)) + (q0 - k0)
 
 
-def _scores(q, k, q0, k0, *, scale, slope, masked: bool) -> jax.Array:
+# Which bounds a block of scores is masked by (``masked``: bit flags, so that
+# the causal launch's ``False`` / ``True`` read as before): the diagonal
+# (``q_pos - k_pos >= 0``), and a windowed launch's second bound (``q_pos -
+# k_pos < window``).
+CAUSAL_BOUND = 1
+WINDOW_BOUND = 2
+
+
+def _scores(q, k, q0, k0, *, scale, slope, masked: int, window: int | None = None) -> jax.Array:
     """fp32 scores ``[rows, cols]`` of one block: ``q @ k^T * scale``, plus the
     per-head ALiBi bias ``-slope * (q_pos - k_pos)`` when ``slope`` is given
     (reference: llm-foundry MPT ``attn_config.alibi``; oracle:
     ``ops/attention.py:xla_attention``), with pairs above the diagonal at
-    ``NEG_INF`` when ``masked``. A block that lies wholly under the diagonal
-    is not ``masked`` and pays for no select."""
+    ``NEG_INF`` when ``masked`` has ``CAUSAL_BOUND`` and pairs ``window`` or
+    more apart when it has ``WINDOW_BOUND``. A block that lies wholly inside
+    what is visible is not ``masked`` and pays for no select."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
     if slope is None and not masked:
@@ -209,8 +255,10 @@ def _scores(q, k, q0, k0, *, scale, slope, masked: bool) -> jax.Array:
     diff = _pos_diff(q0, k0, q.shape[0], k.shape[0])
     if slope is not None:
         s = s + -slope * diff.astype(jnp.float32)
-    if masked:
+    if masked & CAUSAL_BOUND:
         s = jnp.where(diff >= 0, s, NEG_INF)
+    if masked & WINDOW_BOUND:
+        s = jnp.where(diff < window, s, NEG_INF)
     return s
 
 
@@ -337,14 +385,101 @@ def _q_block(i, j, *, causal, block_q, block_k, offset, n_q):
     return jnp.maximum(i, jnp.minimum(first_live, n_q - 1))
 
 
-def strip_rows(launch: str, block_q: int, block_k: int, *, causal: bool, offset) -> int:
+def _clip(x, lo: int, hi: int):
+    """``x`` held to ``[lo, hi]``: a Python int stays one (the static counts),
+    a traced index stays traced (the index maps, the kernels)."""
+    if isinstance(x, int):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+class _Band(NamedTuple):
+    """A windowed causal launch's geometry in tiles: query ``r`` (at position
+    ``r + offset`` among the keys) sees keys ``(r + offset - window, r +
+    offset]``. Every method takes a Python int (the static counts: grid
+    sizes, ``live_tiles``, ``executed_pairs``) or a traced index (the index
+    maps and the kernels' own ``program_id``s) and is the same arithmetic."""
+
+    block_q: int
+    block_k: int
+    offset: int
+    window: int
+    n_q: int
+    n_k: int
+
+    def k_span(self, i):
+        """``(first, last)`` k tile that q tile ``i``'s band meets."""
+        q_lo = i * self.block_q + self.offset
+        first = _clip((q_lo - self.window + 1) // self.block_k, 0, self.n_k - 1)
+        last = _clip((q_lo + self.block_q - 1) // self.block_k, 0, self.n_k - 1)
+        return first, last
+
+    def q_span(self, j):
+        """``(first, last)`` q tile whose band meets k tile ``j``."""
+        k_lo = j * self.block_k - self.offset
+        first = _clip(k_lo // self.block_q, 0, self.n_q - 1)
+        last = _clip((k_lo + self.block_k - 1 + self.window - 1) // self.block_q,
+                     0, self.n_q - 1)
+        return first, last
+
+    def kv_block(self, i, step):
+        """k/v block that step ``step`` of q tile ``i``'s sweep holds (forward,
+        dq): the band's first tile plus ``step``; a step past the band's last
+        tile repeats it, so the pipeline issues no copy (as ``_kv_block``)."""
+        first, last = self.k_span(i)
+        return jnp.minimum(first + step, last)
+
+    def q_block(self, j, step):
+        """q/dO/lse/delta block of step ``step`` of k tile ``j``'s sweep (dk/dv)."""
+        first, last = self.q_span(j)
+        return jnp.minimum(first + step, last)
+
+    def cuts(self, i, j):
+        """``(live, causal, window)`` of tile ``(i, j)``: whether a pair of it is
+        visible, and whether the diagonal / the band's lower edge passes
+        through it (so that its scores need that bound's mask)."""
+        q_lo = i * self.block_q + self.offset
+        q_hi = q_lo + self.block_q - 1
+        k_lo = j * self.block_k
+        k_hi = k_lo + self.block_k - 1
+        live = (k_lo <= q_hi) & (k_hi > q_lo - self.window)
+        return live, k_hi > q_lo, q_hi - k_lo >= self.window
+
+    def tiles(self, by: str):
+        """Every live ``(i, j, causal, window)`` (static), swept as the launch
+        ``by`` (``"q"``: forward, dq; ``"k"``: dk/dv) sweeps them."""
+        span, n = (self.k_span, self.n_q) if by == "q" else (self.q_span, self.n_k)
+        for outer in range(n):
+            first, last = span(outer)
+            for inner in range(first, last + 1):
+                i, j = (outer, inner) if by == "q" else (inner, outer)
+                live, causal, window = self.cuts(i, j)
+                if live:
+                    yield i, j, causal, window
+
+    def steps(self, by: str) -> int:
+        """Steps of the inner sweep: the most tiles one q tile's (``"q"``) or
+        one k tile's (``"k"``) band meets."""
+        if by == "q":
+            return max(hi - lo + 1 for lo, hi in map(self.k_span, range(self.n_q)))
+        return max(hi - lo + 1 for lo, hi in map(self.q_span, range(self.n_k)))
+
+    def cases(self, by: str) -> frozenset[tuple[bool, bool]]:
+        """The ``(causal, window)`` kinds of live tile this launch meets: the
+        bodies its kernel holds."""
+        return frozenset((c, w) for _, _, c, w in self.tiles(by))
+
+
+def strip_rows(launch: str, block_q: int, block_k: int, *, causal: bool, offset,
+               window: int | None = None) -> int:
     """Rows of a strip for ``launch`` at this tile, or 0 where a live tile
     keeps the whole-tile masked body. The strips need a diagonal tile's
     geometry to be static: a causal call, a square tile, and ``offset`` a
     known whole number of tiles, so that every live tile lies wholly under the
     diagonal or has it as its own diagonal. A tile the strip does not divide
-    is one strip."""
-    sub = STRIP_ROWS[launch]
+    is one strip. A windowed launch has strip heights of its own
+    (``BAND_STRIP_ROWS``)."""
+    sub = (STRIP_ROWS if window is None else BAND_STRIP_ROWS)[launch]
     if not (causal and sub and block_q == block_k
             and isinstance(offset, int) and offset % block_q == 0):
         return 0
@@ -379,21 +514,65 @@ def _strips(block: int, sub: int, by: str) -> list[tuple[slice, list[tuple[slice
     return strips
 
 
+def _edge_strips(block: int, sub: int, by: str) -> list[tuple[slice, list[tuple[slice, int]]]]:
+    """The tile on a band's lower edge as strips, where the window is a whole
+    number of (square, aligned) tiles: in the tile's own coordinates key ``c``
+    is visible to query ``r`` iff ``c > r``, the mirror of the diagonal
+    tile's triangle. ``by == "q"``: query strip ``r`` against its own ``sub x
+    sub`` block, the only one masked (by the window's bound), and keys ``[(r +
+    1) * sub, block)``, wholly visible. ``by == "k"``: key strip ``c`` against
+    queries ``[0, c * sub)`` and its own block."""
+    # the diagonal tile's strips as the other axis has them, under the other bound
+    mirrored = _strips(block, sub, "k" if by == "q" else "q")
+    return [(rows, [(extent, WINDOW_BOUND if masked else False) for extent, masked in parts])
+            for rows, parts in mirrored]
+
+
+def _edge_as_strips(sub: int, block: int, window: int | None) -> bool:
+    """Whether a windowed launch's lower-edge tile has the static triangle
+    ``_edge_strips`` runs: the strips engage (``sub``: a square tile, aligned)
+    and the window is a whole number of tiles."""
+    return bool(sub) and window is not None and window % block == 0
+
+
 def _run_tile(compute, by: str, q_blk, k_blk, block_q: int, block_k: int, *,
-              causal: bool, offset, sub: int) -> None:
+              causal: bool, offset, sub: int, band: _Band | None = None) -> None:
     """Runs ``compute(strips, guard)`` in the form grid step ``(q_blk, k_blk)``
     needs. The grid is dense, so a dead tile is predicated off, not skipped
     (it costs its grid step and no copy: ``_kv_block`` / ``_q_block`` repeat a
     live index). ``sub`` (``strip_rows``) says the geometry is static: a tile
     under the diagonal then runs whole and unmasked, the tile on it as strips
     against their live extents. Otherwise every live tile runs whole under the
-    mask, and ``guard`` tells the body that a row may have no visible key."""
+    mask, and ``guard`` tells the body that a row may have no visible key.
+
+    ``band`` (a windowed launch): the tile is one of the band's sweep, or a
+    step past its end (not live). A live tile runs by what cuts it
+    (``_Band.cuts``): neither bound, whole and unmasked; the diagonal alone,
+    as strips where ``sub`` says so (a tile no wider than the window), else
+    whole under the causal mask; the band's lower edge alone, as the mirrored
+    strips where the window is a whole number of such tiles
+    (``_edge_strips``; a query of that tile may see none of its keys, so the
+    body guards), else whole under the second bound; both, whole under both.
+    Only the kinds the launch meets (``_Band.cases``) are in its kernel."""
     rows, cols = (block_q, block_k) if by == "q" else (block_k, block_q)
 
     def whole(masked):
         return [(slice(0, rows), [(slice(0, cols), masked)])]
 
-    if not causal:
+    if band is not None:
+        live, cut_c, cut_w = band.cuts(q_blk, k_blk)
+        live &= (q_blk < band.n_q) & (k_blk < band.n_k)
+        for on_c, on_w in sorted(band.cases(by)):
+            @pl.when(live & (cut_c == on_c) & (cut_w == on_w))
+            def _(on_c=on_c, on_w=on_w):
+                if on_c and not on_w and sub:
+                    compute(_strips(block_q, sub, by), guard=False)
+                elif on_w and not on_c and _edge_as_strips(sub, block_q, band.window):
+                    compute(_edge_strips(block_q, sub, by), guard=True)
+                else:
+                    compute(whole(CAUSAL_BOUND * on_c + WINDOW_BOUND * on_w),
+                            guard=on_c or on_w)
+    elif not causal:
         compute(whole(False), guard=False)
     elif not sub:
         @pl.when(k_blk * block_k <= q_blk * block_q + (block_q - 1) + offset)
@@ -412,7 +591,8 @@ def _run_tile(compute, by: str, q_blk, k_blk, block_q: int, block_k: int, *,
 
 
 def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize: int,
-                      d_v: int | None = None, layout: str = HEAD_MAJOR) -> int:
+                      d_v: int | None = None, layout: str = HEAD_MAJOR,
+                      window: int | None = None) -> int:
     """VMEM one launch (``fwd``, ``dq`` or ``dkv``) needs at a tile, from the
     kernel's own buffers (``d`` the padded width of q and k, ``d_v`` of v,
     ``None`` = the same): every BlockSpec'd operand and result twice (the
@@ -448,7 +628,10 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
     alive (at a 1,024 square it wanted 1.0 to 1.7 score tiles more than for
     a lone padded head: 15.4 / 14.0 / 17.3 MB for the three launches, where
     this gives 20.0 / 19.0 / 20.6). Both read off the smallest limit the
-    compiler accepts, as the rest was (PERF.md, PR 45)."""
+    compiler accepts, as the rest was (PERF.md, PR 45).
+
+    ``window``: a banded launch's bodies hold the second bound's select
+    beside the first's, ``BAND_SCORE_TEMPS`` more score temporaries."""
     d_v = d if d_v is None else d_v
     pair = layout == HEAD_PAIRS
     q_rows = block_q * d * itemsize  # one q-shaped block: q, dq
@@ -473,7 +656,8 @@ def launch_vmem_bytes(launch: str, block_q: int, block_k: int, d: int, itemsize:
         upcast = block_q * (d + d_v) * 4 + block_k * d_v * 4  # q, do, v
     else:
         raise ValueError(f"unknown launch {launch!r}")
-    scores = (2 if pair else 1) * int(SCORE_TEMPS * block_q * block_k * 4)
+    temps = SCORE_TEMPS + (BAND_SCORE_TEMPS if window is not None else 0.0)
+    scores = (2 if pair else 1) * int(temps * block_q * block_k * 4)
     in_place = 4 * block_q * max(d, d_v) * itemsize if (
         layout != HEAD_MAJOR and launch != "fwd") else 0
     return 2 * (piped + slopes) + scratch + upcast + scores + in_place + VMEM_SLACK
@@ -507,18 +691,22 @@ class TilePlan(NamedTuple):
     def blocks(self) -> tuple[tuple[int, int], ...]:
         return tuple((t.block_q, t.block_k) for t in self)
 
-    def attrs(self, layout: str = HEAD_MAJOR) -> dict[str, str]:
+    def attrs(self, layout: str = HEAD_MAJOR, banded: bool = False) -> dict[str, str]:
         """The plan as span attributes (``trainer/steps`` carries them), with
-        the ``layout`` its launches read (``flash_layout``)."""
-        return {
-            "flash_layout": layout,
-            "flash_tiles": " ".join(
+        the ``layout`` its launches read (``flash_layout``). ``banded``: the
+        plan of a model's windowed layers, told as ``swa_*`` beside the full
+        layers' ``flash_*`` (one layout for both: it follows the head widths)."""
+        told = {
+            "tiles": " ".join(
                 f"{n}={t.block_q}x{t.block_k}" for n, t in zip(self._fields, self)),
-            "flash_live_tiles": " ".join(
+            "live_tiles": " ".join(
                 f"{n}={t.live_tiles}/{t.grid_tiles}" for n, t in zip(self._fields, self)),
-            "flash_executed_share": " ".join(
+            "executed_share": " ".join(
                 f"{n}={t.executed_share:.3f}" for n, t in zip(self._fields, self)),
         }
+        if banded:
+            return {f"swa_{key}": value for key, value in told.items()}
+        return {"flash_layout": layout, **{f"flash_{key}": v for key, v in told.items()}}
 
 
 def _tile_sizes(s: int) -> list[int]:
@@ -532,7 +720,7 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
                causal: bool = True, offset: int | None = None,
                block_q: int | None = None, block_k: int | None = None,
                vmem_budget: int = VMEM_BUDGET, d_v_pad: int | None = None,
-               layout: str = HEAD_MAJOR) -> TilePlan:
+               layout: str = HEAD_MAJOR, window: int | None = None) -> TilePlan:
     """``(block_q, block_k)`` of the forward, dq and dk/dv launches, from the
     shapes alone: for each launch the largest tile (by area) that divides
     both sequences, stays inside ``vmem_budget`` by :func:`launch_vmem_bytes`,
@@ -555,7 +743,16 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
     a tile (the ladder's tops were read per head, and a pair's step is two
     heads' work at the same tile); ``vmem_bytes`` is the layout's own, larger
     estimate, which the launch asks for: it may pass ``vmem_budget``, a limit
-    on what a tile is picked by, not on what a core has (128 MiB)."""
+    on what a tile is picked by, not on what a core has (128 MiB).
+
+    ``window``: the launches walk a band, and the largest tile is no longer
+    the best: a 2,048 square is four 512-windows wide and most of the pairs
+    it multiplies no query of the band sees, while a tile far under the
+    window pays a grid step and an online-softmax update for little work. Of
+    the tiles that fit, each launch takes the one with the least
+    ``executed_pairs + grid steps x BAND_STEP_PAIRS``: what the bodies
+    multiply, and a step's fixed cost in pairs, read off the ladder on the
+    chip at window 512 (PERF.md, PR 49); of equals the larger."""
     qs = _tile_sizes(s_q) if block_q is None else [min(block_q, s_q)]
     ks = _tile_sizes(s_k) if block_k is None else [min(block_k, s_k)]
     if s_q % qs[0] or s_k % ks[0]:  # only a pinned block can fail to divide
@@ -573,24 +770,50 @@ def pick_tiles(s_q: int, s_k: int, d_pad: int, itemsize: int, n_kv_group: int = 
                 if need <= vmem_budget
                 and (bq <= top_q or block_q is not None)
                 and (bk <= top_k or block_k is not None)]
-        if fits:
+        if fits and window is not None:
+            def band_cost(fit):
+                _, bk, bq, _ = fit
+                _, steps = live_tiles(s_q, s_k, bq, bk, offset=offset, window=window,
+                                      launch=launch)
+                return steps * BAND_STEP_PAIRS[launch] + executed_pairs(
+                    launch, s_q, s_k, bq, bk, offset=offset, window=window)
+
+            _, bk, bq, need = min(fits, key=lambda fit: (band_cost(fit), -fit[0], -fit[1]))
+        elif fits:
             _, bk, bq, need = max(fits)
         else:
             need, bq, bk = min(sized)
-        if layout != HEAD_MAJOR:
-            need = launch_vmem_bytes(launch, bq, bk, d_pad, itemsize, d_v_pad, layout)
-        live, grid = live_tiles(s_q, s_k, bq, bk, causal=causal, offset=offset)
+        if layout != HEAD_MAJOR or window is not None:
+            need = launch_vmem_bytes(launch, bq, bk, d_pad, itemsize, d_v_pad, layout, window)
+        live, grid = live_tiles(s_q, s_k, bq, bk, causal=causal, offset=offset,
+                                window=window, launch=launch)
         group = n_kv_group if launch == "dkv" else 1
-        share = (executed_pairs(launch, s_q, s_k, bq, bk, causal=causal, offset=offset)
-                 / max(visible_pairs(s_q, s_k, causal=causal, offset=offset), 1))
+        share = (executed_pairs(launch, s_q, s_k, bq, bk, causal=causal, offset=offset,
+                                window=window)
+                 / max(visible_pairs(s_q, s_k, causal=causal, offset=offset,
+                                     window=window), 1))
         plan.append(LaunchTiles(bq, bk, need, live * group, grid * group, share))
     return TilePlan(*plan)
 
 
+def _static_band(s_q: int, s_k: int, block_q: int, block_k: int, offset: int | None,
+                 window: int) -> _Band:
+    return _Band(block_q, block_k, s_k - s_q if offset is None else offset, window,
+                 s_q // block_q, s_k // block_k)
+
+
 def live_tiles(s_q: int, s_k: int, block_q: int, block_k: int, *,
-               causal: bool = True, offset: int | None = None) -> tuple[int, int]:
+               causal: bool = True, offset: int | None = None,
+               window: int | None = None, launch: str = "fwd") -> tuple[int, int]:
     """``(live, grid)`` tiles of one (batch, head): the tiles whose body runs
-    and the grid steps paid. Closed form of the kernels' ``live`` predicate."""
+    and the grid steps paid. Closed form of the kernels' ``live`` predicate.
+    With a ``window`` the grid is the band's (``_Band.steps`` steps for each
+    q tile, or for each k tile in ``launch`` ``dkv``)."""
+    if window is not None:
+        band = _static_band(s_q, s_k, block_q, block_k, offset, window)
+        by = "k" if launch == "dkv" else "q"
+        return (sum(1 for _ in band.tiles(by)),
+                (band.n_k if by == "k" else band.n_q) * band.steps(by))
     offset = s_k - s_q if offset is None else offset
     n_q, n_k = s_q // block_q, s_k // block_k
     if not causal:
@@ -602,12 +825,16 @@ def live_tiles(s_q: int, s_k: int, block_q: int, block_k: int, *,
 
 
 def visible_pairs(s_q: int, s_k: int, *, causal: bool = True,
-                  offset: int | None = None) -> int:
+                  offset: int | None = None, window: int | None = None) -> int:
     """(query, key) pairs of one (batch, head) that attention has to score:
-    query ``r`` sees keys ``[0, r + offset]``."""
+    query ``r`` sees keys ``[0, r + offset]``, with a ``window`` the last
+    ``window`` of them."""
     if not causal:
         return s_q * s_k
     offset = s_k - s_q if offset is None else offset
+    if window is not None:
+        return sum(max(min(r + offset, s_k - 1) - max(r + offset - window + 1, 0) + 1, 0)
+                   for r in range(s_q))
     first = min(max(-offset, 0), s_q)  # rows before it see nothing
     full = min(max(s_k - offset - 1, first), s_q)  # rows from it on see every key
     n = full - first  # rows first .. full - 1 see first + offset + 1, ... keys
@@ -615,13 +842,24 @@ def visible_pairs(s_q: int, s_k: int, *, causal: bool = True,
 
 
 def executed_pairs(launch: str, s_q: int, s_k: int, block_q: int, block_k: int, *,
-                   causal: bool = True, offset: int | None = None) -> int:
+                   causal: bool = True, offset: int | None = None,
+                   window: int | None = None) -> int:
     """Score pairs one (batch, head) of ``launch`` multiplies at this tile.
     Closed form of ``_run_tile``: a whole tile for each live one, or, where
     the strips engage, whole tiles under the diagonal and on it each strip's
-    live extent."""
+    live extent. With a ``window`` the live tiles are the band's, and the
+    strips engage on a diagonal tile that the window's edge does not cut and
+    on a lower-edge tile that the diagonal does not (``_edge_as_strips``)."""
     offset = s_k - s_q if offset is None else offset
-    sub = strip_rows(launch, block_q, block_k, causal=causal, offset=offset)
+    sub = strip_rows(launch, block_q, block_k, causal=causal, offset=offset, window=window)
+    if window is not None:
+        band = _static_band(s_q, s_k, block_q, block_k, offset, window)
+        n = block_q // sub if sub else 0
+        stripped = sub * sub * n * (n + 1) // 2  # a triangle of the tile, in strips
+        edge = _edge_as_strips(sub, block_q, window)
+        return sum(
+            stripped if sub and on_c != on_w and (on_c or edge) else block_q * block_k
+            for _, _, on_c, on_w in band.tiles("k" if launch == "dkv" else "q"))
     if not sub:
         live, _ = live_tiles(s_q, s_k, block_q, block_k, causal=causal, offset=offset)
         return live * block_q * block_k
@@ -637,17 +875,23 @@ def executed_pairs(launch: str, s_q: int, s_k: int, block_q: int, block_k: int, 
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, lone, pair=False):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, lone, pair=False, band=None):
     """``pair``: the block is two 64-wide heads side by side (``HEAD_PAIRS``).
     Each runs as a lone head does, its q with the other's lanes at zero
     (``_own_lanes``) against the whole k block, so its scores are a lone
     padded head's bit for bit; ``p @ v`` over the whole v block has its own
-    output columns in its own lanes, which ``_emit`` keeps."""
+    output columns in its own lanes, which ``_emit`` keeps.
+
+    ``band`` (a windowed launch): the inner axis counts the steps of this q
+    tile's band, and the k tile is the band's first plus the step."""
     slopes_ref = rest[0] if use_alibi else None
     o_ref, lse_ref, *scratch = rest[1:] if use_alibi else rest
     q_blk = pl.program_id(1)
-    k_blk = pl.program_id(2)
+    step = k_blk = pl.program_id(2)
     n_k = pl.num_programs(2)
+    window = None
+    if band is not None:
+        window, k_blk = band.window, band.k_span(q_blk)[0] + step
     heads = range(2 if pair else 1)
 
     def _attend(rows, parts, guard, carried, e):
@@ -660,7 +904,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, off
         q0 = q_blk * block_q + offset + rows.start
         scores = [
             _scores(q, k_ref[0, ks, :], q0, k_blk * block_k + ks.start,
-                    scale=scale, slope=slope, masked=masked)
+                    scale=scale, slope=slope, masked=masked, window=window)
             for ks, masked in parts]
         m = functools.reduce(
             jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s in scores])  # [rows, 1]
@@ -705,7 +949,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, off
                          for e in heads])
         return
 
-    @pl.when(k_blk == 0)
+    @pl.when(step == 0)
     def _init():
         for e in heads:
             m_s, l_s, acc_s = scratch[3 * e:3 * e + 3]
@@ -723,9 +967,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, causal, off
                 l_s[rows, :] = jnp.broadcast_to(l, (l.shape[0], LANE))
 
     _run_tile(_compute, "q", q_blk, k_blk, block_q, block_k, causal=causal,
-              offset=offset, sub=sub)
+              offset=offset, sub=sub, band=band)
 
-    @pl.when(k_blk == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finalize():
         _emit(slice(0, block_q),
               [(m_s[:, 0][:, None], l_s[:, 0][:, None], acc_s[:])
@@ -798,30 +1042,42 @@ def _launch_of(q, k, v, h_q: int, heads: _Heads | None) -> _Launch:
                    HEAD_PAIRS if pair else IN_PLACE)
 
 
+def _band_of(window, causal, block_q, block_k, offset, n_q, n_k) -> _Band | None:
+    """The band a launch walks, or ``None`` for the causal square."""
+    if window is None:
+        return None
+    if not causal or not isinstance(offset, int):
+        raise ValueError("a window needs a causal launch and a static offset")
+    return _Band(block_q, block_k, offset, window, n_q, n_k)
+
+
 def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
-         h_q=0, interpret=False, heads: _Heads | None = None):
+         h_q=0, interpret=False, heads: _Heads | None = None, window: int | None = None):
     """The forward launch: ``(o, lse [B·H, s_q])``. ``q``, ``k``, ``v`` (and
     ``o``) are head-major ``[B·H, S, D]``, or, with ``heads``, the
-    projections' own ``[B, S, H·D]``."""
+    projections' own ``[B, S, H·D]``. ``window``: the launch walks the band
+    (``_Band``) under its own name."""
     s_q, s_k = q.shape[1], k.shape[1]
     # v, o and the accumulator at v's width
     bh, _, h_q, h_kv, d, d_v, at_q, at_kv, pair, layout = _launch_of(q, k, v, h_q, heads)
     n_q = pl.cdiv(s_q, block_q)
     n_k = pl.cdiv(s_k, block_k)
-    grid = (bh, n_q, n_k)
     kv = _kv_row(h_q, h_kv)
 
     # offset generalizes the causal mask to chunked/global positions:
     # visible iff q_id + offset >= k_id (ring attention passes
     # q_start - k_start; default aligns q to the end of k)
     offset = s_k - s_q if offset is None else offset
-    kj = functools.partial(_kv_block, causal=causal, block_q=block_q,
-                           block_k=block_k, offset=offset, n_k=n_k)
-    sub = strip_rows("fwd", block_q, block_k, causal=causal, offset=offset)
-    lone = _lone_tile(sub, s_q, s_k, block_q, offset)
+    band = _band_of(window, causal, block_q, block_k, offset, n_q, n_k)
+    grid = (bh, n_q, n_k if band is None else band.steps("q"))
+    kj = band.kv_block if band is not None else functools.partial(
+        _kv_block, causal=causal, block_q=block_q, block_k=block_k, offset=offset, n_k=n_k)
+    sub = strip_rows("fwd", block_q, block_k, causal=causal, offset=offset, window=window)
+    lone = band is None and _lone_tile(sub, s_q, s_k, block_q, offset)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, causal=causal,
         offset=offset, use_alibi=slopes is not None, sub=sub, lone=lone, pair=pair,
+        **({} if band is None else {"band": band}),
     )
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: at_q(b, i)),
@@ -855,9 +1111,9 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
         out_shape=out_shape,
         interpret=interpret,
         **_vmem_params(launch_vmem_bytes("fwd", block_q, block_k, d, q.dtype.itemsize, d_v,
-                                         layout)),
+                                         layout, window)),
     )
-    with _kernel_scope("flash_fwd"):
+    with _kernel_scope("flash_fwd" if band is None else FLASH_SWA_FWD_KERNEL):
         o, lse = launch(*inputs)
     return o, _stats_from_blocks(lse, pair)
 
@@ -867,7 +1123,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, offset=None, slopes=None,
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, pair=False, makes_delta=False):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, sub, pair=False, makes_delta=False, band=None):
     """``pair`` (``HEAD_PAIRS``): each head of the block with its q and dO
     strips masked to its own lanes (``_own_lanes``) against the whole k and v
     blocks; ``ds @ k`` has the head's dq in its own lanes, and the block's dq
@@ -890,10 +1146,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale
     else:
         dq_ref, dq_s = rest
     q_blk = pl.program_id(1)
-    k_blk = pl.program_id(2)
+    step = k_blk = pl.program_id(2)
     n_k = pl.num_programs(2)
+    window = None
+    if band is not None:  # as the forward's
+        window, k_blk = band.window, band.k_span(q_blk)[0] + step
 
-    @pl.when(k_blk == 0)
+    @pl.when(step == 0)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
         if makes_delta:  # never a pair
@@ -917,7 +1176,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale
                 for ks, masked in parts:
                     k = k_ref[0, ks, :]
                     s = _scores(q, k, q0, k_blk * block_k + ks.start,
-                                scale=scale, slope=slope, masked=masked)
+                                scale=scale, slope=slope, masked=masked, window=window)
                     p = jnp.exp(s - lse)  # [rows, cols]
                     if guard:
                         # fully-masked rows (lse == NEG_INF): exp(s - lse) would be 1
@@ -935,14 +1194,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale
             dq_s[rows, :] += _by_head(dqs)
 
     _run_tile(_compute, "q", q_blk, k_blk, block_q, block_k, causal=causal,
-              offset=offset, sub=sub)
+              offset=offset, sub=sub, band=band)
 
-    @pl.when(k_blk == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finalize():
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, n_q, sub, pair=False):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scale, block_q, block_k, causal, offset, use_alibi, n_q, sub, pair=False, band=None):
     """Inner grid dim sweeps ``group * n_q`` steps: for grouped-query
     attention every kv row accumulates dk/dv over ALL q heads of its group
     (t // n_q picks the group member, t % n_q the q block); MHA is the
@@ -951,7 +1210,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
     ``pair`` (``HEAD_PAIRS``): each head of the block with its k and v strips
     masked to its own lanes (``_own_lanes``) against the whole q and dO
     blocks; ``ds^T @ q`` and ``p^T @ dO`` have the head's dk and dv in its
-    own lanes, and the block's are the two side by side."""
+    own lanes, and the block's are the two side by side.
+
+    ``band`` (a windowed launch): ``n_q`` is the steps of one k tile's band,
+    and the q tile is the band's first for this k tile plus ``t % n_q``."""
     heads = range(2 if pair else 1)
     if use_alibi:
         slopes_ref, dk_ref, dv_ref, dk_s, dv_s = rest
@@ -962,6 +1224,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
     t = pl.program_id(2)
     n_t = pl.num_programs(2)
     q_blk = t % n_q
+    window = None
+    if band is not None:
+        window, q_blk = band.window, band.q_span(k_blk)[0] + q_blk
 
     @pl.when(t == 0)
     def _init():
@@ -986,7 +1251,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
                 dk = dv = None
                 for qs, masked in parts:
                     s = _scores(q_ref[0, qs, :], k, q_blk * block_q + offset + qs.start, k0,
-                                scale=scale, slope=slope, masked=masked)
+                                scale=scale, slope=slope, masked=masked, window=window)
                     lse = lse_col[qs]
                     p = jnp.exp(s - lse)  # [rows, cols]
                     if guard:
@@ -1010,7 +1275,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
             dk_s[cols, :] += _by_head(dks)
 
     _run_tile(_compute, "k", q_blk, k_blk, block_q, block_k, causal=causal,
-              offset=offset, sub=sub)
+              offset=offset, sub=sub, band=band)
 
     @pl.when(t == n_t - 1)
     def _finalize():
@@ -1019,10 +1284,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, scal
 
 
 def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
-         interpret=False, heads: _Heads | None = None):
+         interpret=False, heads: _Heads | None = None, window: int | None = None):
     """``dq_tile`` / ``dkv_tile``: each launch's own ``(block_q, block_k)``.
-    ``heads``: as :func:`_fwd`; dO comes in and dq, dk, dv go out in the
-    layout of q, k, v."""
+    ``heads``, ``window``: as :func:`_fwd`; dO comes in and dq, dk, dv go out
+    in the layout of q, k, v."""
     q, k, v, o, lse = res
     s_q, s_k = q.shape[1], k.shape[1]
     # v, do and dv at v's width
@@ -1061,16 +1326,20 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
     block_q, block_k = dq_tile
     n_q = pl.cdiv(s_q, block_q)
     n_k = pl.cdiv(s_k, block_k)
-    kj = functools.partial(_kv_block, causal=causal, block_q=block_q,
-                           block_k=block_k, offset=offset, n_k=n_k)
+    band = _band_of(window, causal, block_q, block_k, offset, n_q, n_k)
+    banded = {} if band is None else {"band": band}
+    kj = band.kv_block if band is not None else functools.partial(
+        _kv_block, causal=causal, block_q=block_q, block_k=block_k, offset=offset, n_k=n_k)
     dq_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: at_q(b, i))]
     dq_shapes = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     launch_dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
                           causal=causal, offset=offset, use_alibi=use_alibi,
                           sub=strip_rows("dq", block_q, block_k, causal=causal,
-                                         offset=offset), pair=pair, makes_delta=makes_delta),
-        grid=(bh, n_q, n_k),
+                                         offset=offset, window=window),
+                          pair=pair, makes_delta=makes_delta,
+                          **banded),
+        grid=(bh, n_q, n_k if band is None else band.steps("q")),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: at_q(b, i)),  # q
             pl.BlockSpec((1, block_k, d), lambda b, i, j: at_kv(kv(b), kj(i, j))),  # k
@@ -1087,9 +1356,10 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         out_shape=dq_shapes + [jax.ShapeDtypeStruct((bh, SUBLANE, s_q), jnp.float32)]
         if makes_delta else dq_shapes[0],
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("dq", block_q, block_k, d, itemsize, d_v, layout)),
+        **_vmem_params(launch_vmem_bytes("dq", block_q, block_k, d, itemsize, d_v, layout,
+                                         window)),
     )
-    with _kernel_scope("flash_dq"):
+    with _kernel_scope("flash_dq" if band is None else FLASH_SWA_DQ_KERNEL):
         dq = launch_dq(q, k, v, do, lse_b, delta_b, *extra_inputs)
     if makes_delta:
         dq, delta_b = dq
@@ -1101,6 +1371,10 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
     block_q, block_k = dkv_tile
     n_q = pl.cdiv(s_q, block_q)
     n_k = pl.cdiv(s_k, block_k)
+    band = _band_of(window, causal, block_q, block_k, offset, n_q, n_k)
+    banded = {} if band is None else {"band": band}
+    if band is not None:
+        n_q = band.steps("k")  # what one member of the group sweeps of a k tile
 
     def qrow(b, t):
         if group == 1:
@@ -1108,6 +1382,8 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         return (b // h_kv) * h_q + (b % h_kv) * group + t // n_q
 
     def qi(j, t):
+        if band is not None:
+            return band.q_block(j, t % n_q)
         return _q_block(t % n_q, j, causal=causal, block_q=block_q,
                         block_k=block_k, offset=offset, n_q=n_q)
 
@@ -1115,7 +1391,8 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
                           causal=causal, offset=offset, use_alibi=use_alibi, n_q=n_q,
                           sub=strip_rows("dkv", block_q, block_k, causal=causal,
-                                         offset=offset), pair=pair),
+                                         offset=offset, window=window),
+                          pair=pair, **banded),
         grid=(bh_k, n_k, group * n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, t: at_q(qrow(b, t), qi(j, t))),  # q
@@ -1141,9 +1418,10 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-        **_vmem_params(launch_vmem_bytes("dkv", block_q, block_k, d, itemsize, d_v, layout)),
+        **_vmem_params(launch_vmem_bytes("dkv", block_q, block_k, d, itemsize, d_v, layout,
+                                         window)),
     )
-    with _kernel_scope("flash_dkv"):
+    with _kernel_scope("flash_dkv" if band is None else FLASH_SWA_DKV_KERNEL):
         dk, dv = launch_dkv(q, k, v, do, lse_b, delta_b, *extra_inputs)
 
     return dq, dk, dv
@@ -1163,26 +1441,29 @@ def _bwd(scale, causal, dq_tile, dkv_tile, res, do, *, slopes=None, h_q=0,
 # dk/dv launches: ``TilePlan.blocks``. ``heads`` (static) is ``None`` for
 # head-major operands, a ``_Heads`` for operands read in place; what is saved
 # for the backward is then the projections' own q, k, v and the o that
-# ``out_proj`` reads, no copy of any.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0, heads=None):
+# ``out_proj`` reads, no copy of any. ``window`` (static) makes the three
+# launches the banded ones.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0, heads=None, window=None):
     o, _ = _fwd(q, k, v, scale=scale, causal=causal, block_q=tiles[0][0],
                 block_k=tiles[0][1], slopes=slopes, h_q=h_q, interpret=interpret,
-                heads=heads)
+                heads=heads, window=window)
     return o
 
 
-def _flash_fwd(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0, heads=None):
+def _flash_fwd(q, k, v, slopes, scale, causal, tiles, interpret, h_q=0, heads=None,
+               window=None):
     o, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=tiles[0][0],
                   block_k=tiles[0][1], slopes=slopes, h_q=h_q, interpret=interpret,
-                  heads=heads)
+                  heads=heads, window=window)
     return o, (q, k, v, o, lse, slopes)
 
 
-def _flash_bwd(scale, causal, tiles, interpret, h_q, heads, res, do):
+def _flash_bwd(scale, causal, tiles, interpret, h_q, heads, window, res, do):
     q, k, v, o, lse, slopes = res
     dq, dk, dv = _bwd(scale, causal, tiles[1], tiles[2], (q, k, v, o, lse), do,
-                      slopes=slopes, h_q=h_q, interpret=interpret, heads=heads)
+                      slopes=slopes, h_q=h_q, interpret=interpret, heads=heads,
+                      window=window)
     return dq, dk, dv, jax.tree.map(jnp.zeros_like, slopes)
 
 
@@ -1200,8 +1481,14 @@ def flash_attention(
     block_q: int | None = None, block_k: int | None = None,
     interpret: bool = False,
     scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention over ``[batch, seq, heads, d_head]`` inputs.
+
+    ``window`` (causal only, no ALiBi): query ``i`` sees keys ``i - window <
+    j <= i``, its own among them, and the three launches walk that band and
+    not the causal square. A window that holds every key of the sequence is
+    the causal call itself, the same program.
 
     Which arrays the three launches read follows the head widths
     (:func:`flash_layout`): at whole-lane widths (``d_head % 128 == 0``, v's
@@ -1243,13 +1530,18 @@ def flash_attention(
         raise ValueError(f"k has {h_kv} heads but v has {v.shape[2]}")
     s_k, d_v = k.shape[1], v.shape[3]
     scale = 1.0 / (d**0.5) if scale is None else float(scale)
+    if window is not None:
+        if window < 1 or not causal or alibi:
+            raise ValueError("window needs window >= 1, causal=True and no alibi")
+        if window >= s_k:  # no causal pair is further apart: the causal call
+            window = None
 
     layout = flash_layout(h, h_kv, d, d_v)
     # a pair's block is 128 lanes wide, as the lone head's padded one is
     d_pad = lane_padded(d)
     tiles = pick_tiles(s_q, s_k, d_pad, q.dtype.itemsize, h // h_kv, causal=causal,
                        block_q=block_q, block_k=block_k, d_v_pad=lane_padded(d_v),
-                       layout=layout).blocks
+                       layout=layout, window=window).blocks
 
     def bh_slopes(pair=False):
         if not alibi:
@@ -1265,7 +1557,7 @@ def flash_attention(
         slopes = bh_slopes(heads.pair)
         o = _flash(q.reshape(b, s_q, h * d), k.reshape(b, s_k, h_kv * d),
                    v.reshape(b, s_k, h_kv * d_v), slopes, scale, causal, tiles, interpret,
-                   0, heads)
+                   0, heads, window)
         return o.reshape(b, s_q, h, d_v)
 
     def to_bh(x, s, heads):
@@ -1277,7 +1569,7 @@ def flash_attention(
 
     qb, kb, vb = to_bh(q, s_q, h), to_bh(k, s_k, h_kv), to_bh(v, s_k, h_kv)
     ob = _flash(qb, kb, vb, bh_slopes(), scale, causal, tiles, interpret,
-                h if h_kv != h else 0)
+                h if h_kv != h else 0, None, window)
     o = ob[..., :d_v].reshape(b, h, s_q, d_v)
     return jnp.transpose(o, (0, 2, 1, 3))
 

@@ -75,6 +75,9 @@ _RULES: list[tuple[str, P]] = [
     (r"(out_proj|down_proj)/kernel$", P("pipe", "tensor", "fsdp")),
     (r"(wqkv|up_proj|gate_proj|q_proj|k_proj|v_proj)/bias$", P("pipe", "tensor")),
     (r"(out_proj|down_proj)/bias$", P("pipe", "fsdp")),
+    # the headwise gate on attention's output: a column a head, split like q's
+    (r"attn_gate/kernel$", P("pipe", "fsdp", "tensor")),
+    (r"attn_gate/bias$", P("pipe", "tensor")),
     (r"lm_head/kernel$", P("tensor", "fsdp")),
     (r"(ln_1|ln_2)/(scale|bias)$", P("pipe")),
     (r"ln_f/(scale|bias)$", P()),

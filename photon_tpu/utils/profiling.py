@@ -283,6 +283,16 @@ BLOCK_MLP_SCOPE = "block/mlp"
 #: ``k_proj`` / ``v_proj``, the reshapes, the rotation, ``out_proj`` and its
 #: residual add); the latent branch keeps ``mla/proj``
 ATTN_PROJ_SCOPE = "attn/proj"
+#: the flash kernel's three WINDOWED launches (``ops/flash_attention.py``: the
+#: sliding-window layers' forward, dq and dk/dv, which walk the band), as the
+#: kernel's name stands in a launch's ``op_name`` before ``/multihead_attention``;
+#: the causal launches keep ``flash_fwd`` / ``flash_dq`` / ``flash_dkv``
+FLASH_SWA_FWD_KERNEL = "flash_swa_fwd"
+FLASH_SWA_DQ_KERNEL = "flash_swa_dq"
+FLASH_SWA_DKV_KERNEL = "flash_swa_dkv"
+#: the headwise gate on attention's output (``attn_gate``): its ``[D, H]``
+#: product, the sigmoid and the multiply
+ATTN_GATE_SCOPE = "attn/gate"
 #: the block norms ``ln_1`` / ``ln_2`` and the model's final ``ln_f``
 BLOCK_NORM_SCOPE = "block/norm"
 #: the gradient's global norm and the logged norm of the new weights
@@ -771,6 +781,38 @@ def _sparse_attention_flops_per_token(cfg: ModelConfig) -> float:
             + 6.0 * d * v)
 
 
+def _kinds_flops_per_token(cfg: ModelConfig) -> float:
+    """The terms of ``benchmark/costs/laguna_swa_moe_train.py`` at the expected
+    counts (``tests/test_laguna_swa.py`` holds the two equal): every attention
+    layer by its kind (``ModelConfig.attention_kind``: projections at its own
+    head count, the headwise gate's ``[D, H]`` product, the score and value
+    products over the pairs it sees: a full layer's causal half, a sliding
+    layer's band), the leading dense layers, the router, the shared expert,
+    the routed experts at this chip's expected share, and the head. Layers
+    that are not attention are not this count's."""
+    d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
+    n_kv, dh = cfg.n_kv_heads or cfg.n_heads, cfg.d_head
+    kinds = cfg.layer_kinds or ("attention",) * L
+    total = 0.0
+    for name in kinds:
+        kind = cfg.attention_kind(name)
+        h, w = kind.n_heads, min(kind.window or s, s)
+        pairs = w * (w + 1) / 2.0 + (s - w) * w  # query t sees min(t + 1, w) keys
+        total += 6.0 * (d * (h + 2 * n_kv) * dh + h * dh * d)
+        total += 6.0 * d * h if cfg.attn_gate else 0.0
+        total += 3.0 * pairs / s * h * 4 * dh
+    n_dense = cfg.first_k_dense
+    total += 6.0 * n_dense * 3 * d * cfg.dense_mlp_hidden_size
+    hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * d
+    if cfg.dropless_moe:
+        rows = cfg.moe_top_k * cfg.experts_held / cfg.moe_num_experts
+        total += (L - n_dense) * 6.0 * (
+            d * cfg.moe_num_experts + (rows + cfg.moe_shared_experts) * 3 * d * hidden)
+    else:
+        total += (L - n_dense) * 6.0 * (3 if cfg.mlp == "swiglu" else 2) * d * hidden
+    return total + 6.0 * d * v
+
+
 def model_flops_per_token(cfg: ModelConfig) -> float:
     """Training FLOPs/token ≈ 6·N_nonemb + 12·L·d·s (attention) + 6·d·V
     (lm_head, tied or not). Matches the estimate used for BASELINE
@@ -804,6 +846,11 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
                   + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_head_dim + dv)
                   + cfg.n_heads * dv * d)
         attn = 6 * L * s * cfg.n_heads * (qk + dv) / 2  # causal half, fwd+bwd
+    elif cfg.swa_layers or cfg.attn_gate:
+        # full and sliding layers, each kind's heads: a full layer's causal
+        # half, a sliding layer's band (``min(t + 1, window)`` keys a query);
+        # the gate's product a head
+        return _kinds_flops_per_token(cfg)
     else:
         # GQA shrinks the kv projections: q + 2·kv groups + out_proj
         n_kv = cfg.n_kv_heads or cfg.n_heads

@@ -10,7 +10,9 @@ pairs where ``flash_layout`` says so, which is what the cells run) or in
 times, and the median round is kept. ``--sub 0,128,256,512`` times every
 square tile once per strip height (``STRIP_ROWS`` for all three launches; 0 is
 the whole-tile masked body, the only one a tile that is not square has), and
-each row carries ``executed_share``, the pairs its bodies multiply over the
+``--window N`` times the windowed launches, which walk the band (``--kv-heads``
+for grouped heads: ``--shapes 1,64,16384,128 --kv-heads 8 --window 512`` is a
+sliding layer of ``lagunaxs2-train-16k``); each row carries ``executed_share``, the pairs its bodies multiply over the
 visible ones. With ``--no-clamp`` the dead-tile index clamps are replaced by
 the identity maps (every grid step fetches its own block, as before PR 28),
 which is how the clamp's share is read. With ``--parent FILE`` (a copy of an
@@ -64,25 +66,32 @@ def _time(fn, args, calls: int, rounds: int) -> float:
     return statistics.median(per_call)
 
 
-def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto"):
+def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto", window=None,
+           kv_heads=0):
     import jax
     import jax.numpy as jnp
 
     rows = []
-    shipped = dict(fa.STRIP_ROWS)
+    # the strip heights the launches under test read: the band's own with a window
+    strips = "STRIP_ROWS" if window is None else "BAND_STRIP_ROWS"
+    shipped = dict(getattr(fa, strips))
     for b, h, s, d in shapes:
-        fa.STRIP_ROWS = dict(shipped)
+        setattr(fa, strips, dict(shipped))
         d_pad = fa.lane_padded(d)
         bh = b * h
-        took = fa.flash_layout(h, h, d, d) if layout == "auto" else layout
+        h_kv = kv_heads or h  # grouped heads: k and v hold ``--kv-heads`` of them
+        took = fa.flash_layout(h, h_kv, d, d) if layout == "auto" else layout
         pair = took == fa.HEAD_PAIRS
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
-        heads = fa._Heads.of(took, h, h)
+        heads = fa._Heads.of(took, h, h_kv)
         if heads is None:
-            q, k, v, do = (jnp.pad(jax.random.normal(kk, (bh, s, d), jnp.bfloat16),
-                                   ((0, 0), (0, 0), (0, d_pad - d))) for kk in keys)
+            q, k, v, do = (jnp.pad(jax.random.normal(kk, (b * n, s, d), jnp.bfloat16),
+                                   ((0, 0), (0, 0), (0, d_pad - d)))
+                           for kk, n in zip(keys, (h, h_kv, h_kv, h)))
         else:  # the projections' own arrays, a head (or a pair) a column block
-            q, k, v, do = (jax.random.normal(kk, (b, s, h * d), jnp.bfloat16) for kk in keys)
+            q, k, v, do = (jax.random.normal(kk, (b, s, n * d), jnp.bfloat16)
+                           for kk, n in zip(keys, (h, h_kv, h_kv, h)))
+        h_q = h if heads is None and h_kv != h else 0
         slopes = None
         if alibi:
             from photon_tpu.ops.attention import alibi_slopes
@@ -91,9 +100,10 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto"):
         scale = 1.0 / d**0.5
         o, lse = jax.jit(lambda q, k, v: fa._fwd(
             q, k, v, scale=scale, causal=True, block_q=256, block_k=256,
-            slopes=slopes, heads=heads))(q, k, v)
-        plan = fa.pick_tiles(s, s, d_pad, 2, layout=took)
-        picked_sub = {name: fa.strip_rows(name, t.block_q, t.block_k, causal=True, offset=0)
+            slopes=slopes, heads=heads, h_q=h_q, window=window))(q, k, v)
+        plan = fa.pick_tiles(s, s, d_pad, 2, h // h_kv, layout=took, window=window)
+        picked_sub = {name: fa.strip_rows(name, t.block_q, t.block_k, causal=True, offset=0,
+                                          window=window)
                       for name, t in zip(LAUNCHES, plan)}
 
         # what every dq / dkv reading holds besides its kernel: _bwd's delta
@@ -110,7 +120,8 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto"):
             return fa._stats_to_blocks(delta, pair), fa._stats_to_blocks(lse, pair)
 
         prologue = _time(jax.jit(prologue_fn), (o, lse, do), calls, rounds)
-        head = {"shape": [b, h, s, d], "layout": took, "bwd_prologue_ms": prologue}
+        head = {"shape": [b, h, s, d], "kv_heads": h_kv, "window": window, "layout": took,
+                "bwd_prologue_ms": prologue}
         print(json.dumps(head), flush=True)
         rows.append(head)
         for (bq, bk), sub in ((t, sub) for t in tiles for sub in subs):
@@ -119,24 +130,25 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto"):
             # a strip height is a reading only where it changes the body
             if sub and (bq != bk or bq % sub):
                 continue
-            fa.STRIP_ROWS = dict.fromkeys(LAUNCHES, sub)
+            setattr(fa, strips, dict.fromkeys(LAUNCHES, sub))
             row = {"shape": [b, h, s, d], "layout": took, "tile": [bq, bk], "sub": sub,
                    "executed_share": {
-                       name: round(fa.executed_pairs(name, s, s, bq, bk)
-                                   / fa.visible_pairs(s, s), 4) for name in LAUNCHES},
+                       name: round(fa.executed_pairs(name, s, s, bq, bk, window=window)
+                                   / fa.visible_pairs(s, s, window=window), 4)
+                       for name in LAUNCHES},
                    "picked": [
                        name for name, t in zip(LAUNCHES, plan)
                        if (t.block_q, t.block_k) == (bq, bk) and sub == picked_sub[name]]}
             launches = {
                 "fwd": (lambda q, k, v, o, lse, do: fa._fwd(
                     q, k, v, scale=scale, causal=True, block_q=bq, block_k=bk,
-                    slopes=slopes, heads=heads)[0]),
+                    slopes=slopes, heads=heads, h_q=h_q, window=window)[0]),
                 "dq": (lambda q, k, v, o, lse, do: fa._bwd(
                     scale, True, (bq, bk), (bq, bk), (q, k, v, o, lse), do,
-                    slopes=slopes, heads=heads)[0]),
+                    slopes=slopes, heads=heads, h_q=h_q, window=window)[0]),
                 "dkv": (lambda q, k, v, o, lse, do: fa._bwd(
                     scale, True, (bq, bk), (bq, bk), (q, k, v, o, lse), do,
-                    slopes=slopes, heads=heads)[1:]),
+                    slopes=slopes, heads=heads, h_q=h_q, window=window)[1:]),
             }
             for name, fn in launches.items():
                 try:
@@ -147,7 +159,7 @@ def ladder(fa, shapes, tiles, subs, calls, rounds, alibi, layout="auto"):
                     row[name + "_error"] = str(e).strip().splitlines()[-1][:200]
             print(json.dumps(row), flush=True)
             rows.append(row)
-    fa.STRIP_ROWS = shipped
+    setattr(fa, strips, shipped)
     return rows
 
 
@@ -213,6 +225,10 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--layout", default="auto", choices=["auto", "head_major"],
                     help="auto: the layout flash_attention reads for each shape "
                          "(what the cells run); head_major: to_bh's copies")
+    ap.add_argument("--window", type=int, default=None,
+                    help="time the windowed (banded) launches: a query's last N keys")
+    ap.add_argument("--kv-heads", type=int, default=0,
+                    help="grouped heads: k and v hold this many (0: as many as q)")
     ap.add_argument("--no-clamp", action="store_true")
     ap.add_argument("--parent", default=None)
     ap.add_argument("--interpret", action="store_true")
@@ -239,7 +255,7 @@ def main(argv: list[str] | None = None) -> int:
         subs = ([int(x) for x in args.sub.split(",")] if args.sub
                 else sorted({0, *fa.STRIP_ROWS.values()}))
         result["ladder"] = ladder(fa, shapes, tiles, subs, args.calls, args.rounds, args.alibi,
-                                  args.layout)
+                                  args.layout, args.window, args.kv_heads)
     if args.out:
         out = pathlib.Path(args.out)
         out.mkdir(parents=True, exist_ok=True)

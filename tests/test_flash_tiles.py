@@ -312,3 +312,113 @@ def test_trainer_steps_span_carries_the_tile_plan(impl, interpret, told, d_model
         assert attrs["flash_live_tiles"] == "fwd=1/1 dq=1/1 dkv=1/1"
     else:
         assert set(attrs) == {"steps"}
+
+
+# ---------------------------------------------------------------------------
+# a window: the band's tiles, pairs and pick
+# ---------------------------------------------------------------------------
+
+
+def _brute_band(s_q, s_k, bq, bk, offset, window, sub):
+    """``(live tiles, executed pairs, visible pairs)`` of a windowed launch,
+    tile by tile and pair by pair: a tile is live iff it holds a visible pair;
+    it runs whole, but for the tile on the diagonal that the window's edge
+    does not cut, which runs as strips where ``sub`` says so."""
+    import numpy as np
+
+    q = np.arange(s_q)[:, None] + offset
+    k = np.arange(s_k)[None, :]
+    seen = (k <= q) & (k > q - window)
+    live = executed = 0
+    for i in range(s_q // bq):
+        for j in range(s_k // bk):
+            tile = seen[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+            if not tile.any():
+                continue
+            live += 1
+            on_c = j * bk + bk - 1 > i * bq + offset
+            on_w = i * bq + offset + bq - 1 - j * bk >= window
+            n = bq // sub if sub else 0
+            edge = sub and window % bq == 0  # the lower-edge tile's mirrored strips
+            stripped = sub and ((on_c and not on_w) or (on_w and not on_c and edge))
+            executed += sub * sub * n * (n + 1) // 2 if stripped else bq * bk
+    return live, executed, int(seen.sum())
+
+
+@pytest.mark.parametrize("s, window, bq, bk", [
+    (2048, 512, 512, 512), (2048, 512, 256, 256), (2048, 512, 128, 128),
+    (2048, 512, 1024, 1024), (2048, 512, 256, 512), (2048, 512, 512, 256),
+    (2048, 200, 128, 128), (1536, 512, 512, 512), (2048, 64, 128, 128),
+    (2048, 2047, 512, 512), (640, 256, 128, 128),
+])
+def test_a_windows_counts_are_the_bands(s, window, bq, bk):
+    """``live_tiles``, ``executed_pairs`` and ``visible_pairs`` with a window
+    against a brute count, for each launch; the grid paid is the band's steps
+    and never the square's."""
+    for launch in LAUNCHES:
+        sub = strip_rows(launch, bq, bk, causal=True, offset=0, window=window)
+        live, executed, visible = _brute_band(s, s, bq, bk, 0, window, sub)
+        got_live, grid = live_tiles(s, s, bq, bk, window=window, launch=launch)
+        assert got_live == live and live <= grid
+        assert executed_pairs(launch, s, s, bq, bk, window=window) == executed
+        assert visible_pairs(s, s, window=window) == visible
+        n_q, n_k = s // bq, s // bk
+        if launch == "dkv":
+            assert grid <= n_k * min(n_q, -(-(window - 1 + bk) // bq) + 1)
+        else:
+            assert grid <= n_q * min(n_k, -(-(window - 1 + bq) // bk) + 1)
+    assert visible_pairs(s, s, window=window) == sum(min(t + 1, window) for t in range(s))
+
+
+def test_the_bands_index_maps_fetch_live_blocks_only():
+    """Step ``j`` of q tile ``i`` holds the band's first tile plus ``j``, held
+    at the diagonal's (a repeated index: no copy); dk/dv likewise over q."""
+    band = fa._Band(256, 256, 0, 512, 8, 8)
+    assert [band.k_span(i) for i in range(4)] == [(0, 0), (0, 1), (0, 2), (1, 3)]
+    assert [band.q_span(j) for j in (0, 5, 7)] == [(0, 2), (5, 7), (7, 7)]
+    assert (band.steps("q"), band.steps("k")) == (3, 3)
+    assert [int(band.kv_block(3, j)) for j in range(3)] == [1, 2, 3]
+    assert [int(band.kv_block(0, j)) for j in range(3)] == [0, 0, 0]
+    assert [int(band.q_block(7, j)) for j in range(3)] == [7, 7, 7]
+    # the kinds of tile a launch meets are its kernel's bodies
+    assert band.cases("q") == {(True, False), (False, False), (False, True)}
+    assert fa._Band(512, 512, 0, 512, 4, 4).cases("q") == {(True, False), (False, True)}
+    assert fa._Band(1024, 1024, 0, 512, 2, 2).cases("k") == {(True, True), (False, True)}
+
+
+def test_pick_tiles_weighs_a_bands_executed_pairs_against_its_steps(monkeypatch):
+    """At the cell's sliding layers (64 / 8 heads of 128, window 512, 16,384
+    positions) the causal square's 2,048 tile is four windows wide and
+    multiplies 4.5 pairs a visible one: the rule takes the tile with the least
+    executed pairs + steps x ``BAND_STEP_PAIRS``; with free steps the smallest
+    tile wins, with dear ones a larger one, and without a window nothing moves."""
+    args = (16384, 16384, 128, 2, 8)
+    causal = pick_tiles(*args, layout=fa.IN_PLACE)
+    assert causal.blocks == ((2048, 2048),) * 3
+    assert executed_pairs("fwd", 16384, 16384, 2048, 2048, window=512) / visible_pairs(
+        16384, 16384, window=512) > 4.0
+    plan = pick_tiles(*args, layout=fa.IN_PLACE, window=512)
+    for launch, t in zip(LAUNCHES, plan):
+        assert t.block_q == t.block_k and 128 <= t.block_q <= 1024, (launch, t)
+        assert 1.0 <= t.executed_share < 2.0
+        assert t.live_tiles <= t.grid_tiles
+        assert t.vmem_bytes == launch_vmem_bytes(
+            launch, t.block_q, t.block_k, 128, 2, None, fa.IN_PLACE, 512) <= VMEM_BUDGET
+    assert plan.fwd.grid_tiles < 16384 // plan.fwd.block_q * 4  # not the square's
+    monkeypatch.setattr(fa, "BAND_STEP_PAIRS", dict.fromkeys(LAUNCHES, 0))
+    free = pick_tiles(*args, layout=fa.IN_PLACE, window=512)
+    for launch, t in zip(LAUNCHES, free):  # the least executed pairs; of equals the larger
+        pairs = {b: executed_pairs(launch, 16384, 16384, b, b, window=512)
+                 for b in (128, 256, 512, 1024, 2048)}
+        assert pairs[t.block_q] == min(pairs.values()), (launch, t, pairs)
+        assert t.block_q == max(b for b, n in pairs.items() if n == min(pairs.values()))
+    monkeypatch.setattr(fa, "BAND_STEP_PAIRS", dict.fromkeys(LAUNCHES, 10**7))
+    assert pick_tiles(*args, layout=fa.IN_PLACE, window=512).fwd.block_q >= 1024
+    assert pick_tiles(*args, layout=fa.IN_PLACE).blocks == causal.blocks
+    # a pinned tile is taken as given, and its counts are the band's
+    pinned = pick_tiles(*args, layout=fa.IN_PLACE, window=512, block_q=256, block_k=256)
+    assert pinned.blocks == ((256, 256),) * 3 and pinned.fwd.executed_share == pytest.approx(
+        executed_pairs("fwd", 16384, 16384, 256, 256, window=512)
+        / visible_pairs(16384, 16384, window=512))
+    told = plan.attrs(fa.IN_PLACE, banded=True)
+    assert set(told) == {"swa_tiles", "swa_live_tiles", "swa_executed_share"}

@@ -549,8 +549,8 @@ def _with(cfg, **paths):
 
 @pytest.mark.parametrize("change, message", [
     (dict(model__layer_types="mamba,attention"), "needs n_layers=4"),
-    (dict(model__layer_types="mamba,mamba,full_attention,mamba"),
-     "each 'mamba', 'conv' or 'attention'"),
+    (dict(model__layer_types="mamba,mamba,linear_attention,mamba"),
+     "each 'mamba', 'conv', 'attention', 'full_attention' or"),
     (dict(model__max_seq_len=36), "not a multiple of mamba_chunk_size"),
     (dict(model__mamba_d_state=0), "all > 0"),
     (dict(model__first_k_dense=1, model__dense_mlp_hidden_size=8),

@@ -682,7 +682,8 @@ def _with(cfg, **paths):
 
 @pytest.mark.parametrize("change, message", [
     (dict(model__layer_types="conv,attention,conv"), "needs n_layers=5"),
-    (dict(model__layer_types="conv,full_attention,conv,conv,conv"), "'mamba', 'conv' or 'attention'"),
+    (dict(model__layer_types="conv,linear_attention,conv,conv,conv"),
+     "'mamba', 'conv', 'attention', 'full_attention' or"),
     (dict(model__conv_kernel_size=0), "conv_kernel_size > 0"),
     (dict(model__moe_router="softmax", model__moe_experts_held=0, model__moe_gate_eps=1e-20,
           model__moe_bias_update_speed=0.0), "needs a dropless router"),

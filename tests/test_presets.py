@@ -11,9 +11,11 @@ def test_all_presets_validate():
     for name in names:
         cfg = load_preset(name)
         # a latent-attention preset's heads are as wide as its projections
-        # make them (2,048 over 20 heads of 256): d_head answers for both
+        # make them (2,048 over 20 heads of 256), and so are those of a preset
+        # that states `head_dim` (2,048 over 48 heads of 128): d_head answers
         assert cfg.model.d_head > 0, name
-        assert cfg.model.latent_attention or cfg.model.d_model % cfg.model.n_heads == 0, name
+        assert (cfg.model.latent_attention or cfg.model.head_dim
+                or cfg.model.d_model % cfg.model.n_heads == 0), name
         assert cfg.scheduler.t_max > 100
 
 

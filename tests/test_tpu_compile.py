@@ -829,3 +829,46 @@ def test_a_presets_train_step_takes_its_layout(topo_devices, monkeypatch, preset
     heads, d = cfg.model.n_heads, cfg.model.d_head
     copies = f"tensor<{2 * heads}x256x{-(-d // 128) * 128}x"  # to_bh's [B·H, S, d_pad]
     assert (copies in text) == (layout == "head_major")
+
+
+def test_the_banded_flash_launches_compile_at_the_sliding_layers_shapes(one_chip):
+    """64 query / 8 key-value heads of 128 at 16,384 tokens under a window of
+    512 (a sliding layer of ``lagunaxs2-train-16k``): forward, dq and dk/dv at
+    the tiles ``pick_tiles`` derives for the band, read in place, under their
+    own names; the band's grid and not the square's."""
+    from photon_tpu.ops import flash_attention as fa
+
+    q = _abstract((1, 16384, 64, 128), jnp.bfloat16, one_chip)
+    kv = _abstract((1, 16384, 8, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=512).astype(jnp.float32).sum()
+
+    text = _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count(KERNEL) == 3
+    for name in ("flash_swa_fwd", "flash_swa_dq", "flash_swa_dkv"):
+        assert f"{name}/multihead_attention" in text, name
+    assert "flash_fwd/" not in text and "flash_dq/" not in text
+    assert "bf16[1,16384,8192]" in text and "bf16[1,16384,1024]" in text  # in place
+
+
+def test_the_windowed_cells_step_compiles_and_fits_the_chip(topo_devices, monkeypatch):
+    """``laguna-xs.2-ep8`` at its cell's size (1 row x 16,384 tokens, one
+    microbatch, ``remat``, 692 M parameters): the whole train step for a
+    described v5e, the full layers' causal launches and the sliding layers'
+    banded ones in it, and the donated state + its temporaries by
+    ``memory_analysis()``, which counts temporaries that are never live
+    together: 16.21 GiB where the compiler's own report
+    (``XLA_FLAGS=--xla_dump_to``, ``*memory-usage-report.txt``) totals 14.77
+    and the program fits the chip's 15.75 (~70 s)."""
+    from photon_tpu.config import load_preset
+
+    cfg = load_preset("laguna-xs.2-ep8")
+    compiled, state = _compile_train_step(cfg, topo_devices()[:1], monkeypatch)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params)) == 691_624_960
+    text = compiled.as_text()
+    assert text.count(KERNEL) >= 8  # four launches a layer kind, the experts' besides
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "flash_swa_fwd", "flash_swa_dq",
+                 "flash_swa_dkv"):
+        assert f"{name}/multihead_attention" in text, name
+    assert 11.0 < _live_gib(compiled) < 16.5

@@ -459,6 +459,7 @@ SOWN = {
         {k for k in COUNTERS if k.startswith(("moe_", "dsa_"))} - {"moe_aux"},
     "xing4.0-29b-a4b-ep8":
         {k for k in COUNTERS if k.startswith(("moe_", "mhc_"))} - {"moe_aux"},
+    "laguna-xs.2-ep8": {k for k in COUNTERS if k.startswith("moe_")} - {"moe_aux"},
 }
 
 
@@ -520,6 +521,8 @@ PARENTS = {
         {"mhc_streams": 4, "mhc_sublayers": 6},
         [(names.TRAINER_MOE_LOAD_SPAN, _MOE),
          (names.TRAINER_MHC_SPAN, {"sinkhorn_gap": names.MHC_SINKHORN_GAP})]),
+    "laguna-xs.2-ep8": (
+        {"swa_layers": 3, "sliding_window": 8}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "keye-vl-2.0-30b-a3b-ep8": (
         {"dsa_layers": 2, "dsa_topk": 16},
         [(names.TRAINER_MOE_LOAD_SPAN, _MOE),
