@@ -69,7 +69,12 @@ that band), and its rotation (a full layer turns ``partial_rotary_factor`` of
 its head by YaRN's frequencies with ``rope_scaling_attention_factor`` on cos
 and sin, a sliding layer its whole head by plain ``swa_rope_theta``);
 ``attn_gate: headwise`` multiplies every head's output by a sigmoid gate of
-the block's normed input before ``out_proj``. Runs of layers equal in kind and
+the block's normed input before ``out_proj``. The multiply and its pull-back
+are ``ops/head_gate.head_gate``, a ``custom_vjp`` that reads the flash
+kernel's output and ``out_proj``'s cotangent as they lie in memory
+(``[B, S, H·D]`` in the compute dtype, a head a column block) beside the
+``[B, S, H]`` logits the ``attn_gate`` ``Dense`` returns, one Pallas launch a
+pass where a head is whole lanes. Runs of layers equal in kind and
 MLP are the scanned stacks (``ModelConfig.stacks``).
 
 TPU-first design choices (not in the reference):
@@ -96,6 +101,7 @@ from photon_tpu.config.schema import ModelConfig
 from photon_tpu.models.step import sow
 from photon_tpu.ops.attention import multihead_attention
 from photon_tpu.ops.flash_attention import IN_PLACE, flash_layout
+from photon_tpu.ops.head_gate import head_gate
 from photon_tpu.utils.profiling import (
     ATTN_GATE_SCOPE,
     ATTN_PROJ_SCOPE,
@@ -738,10 +744,11 @@ class MPTBlock(nn.Module):
             if cfg.attn_gate:
                 # one gate a head and token, from the block's normed input
                 with jax.named_scope(ATTN_GATE_SCOPE):
-                    gate = jax.nn.sigmoid(
-                        dense(n_heads, "attn_gate", cfg.emb_init_std)(h).astype(jnp.float32))
-                    attn_out = (attn_out.astype(jnp.float32) * gate[..., None]).astype(
-                        attn_out.dtype)
+                    attn_out = head_gate(
+                        attn_out.reshape(b, s, -1),  # as the kernel wrote it
+                        dense(n_heads, "attn_gate", cfg.emb_init_std)(h),
+                        impl=cfg.attn_impl, interpret=cfg.attn_interpret,
+                    ).reshape(attn_out.shape)
             if cfg.latent_attention:
                 with jax.named_scope(MLA_PROJ_SCOPE):
                     branch = dense(cfg.d_model, "out_proj", resid_std)(

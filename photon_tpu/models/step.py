@@ -158,6 +158,12 @@ def step_attrs(cfg: ModelConfig, batch_rows: int) -> StepAttrs:
         steps.update(conv_layers=cfg.conv_layers)
     if cfg.swa_layers:  # beside them: the banded launches' plan (``_flash_attrs``)
         steps.update(swa_layers=cfg.swa_layers, sliding_window=cfg.sliding_window)
+    if cfg.attn_gate:  # the gated layers whose multiply is ``ops/head_gate``'s launches
+        from photon_tpu.ops.head_gate import uses_kernel
+
+        kernel = uses_kernel(cfg.attn_impl, cfg.attn_interpret, cfg.max_seq_len, cfg.d_head)
+        steps.update(
+            head_gate_layers=cfg.full_attention_layers + cfg.swa_layers if kernel else 0)
     if cfg.hyper_connected:  # maps, read-in and write-back: two sublayers a layer
         steps.update(mhc_streams=cfg.hc_mult, mhc_sublayers=2 * cfg.n_layers)
     fence = {}

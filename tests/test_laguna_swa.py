@@ -37,6 +37,7 @@ from photon_tpu.config import load_preset  # noqa: E402
 from photon_tpu.config.schema import Config  # noqa: E402
 from photon_tpu.models import MPTModel, init_params, mpt  # noqa: E402
 from photon_tpu.ops import flash_attention as fa  # noqa: E402
+from photon_tpu.ops import head_gate as hg  # noqa: E402
 from photon_tpu.ops import moe  # noqa: E402
 from photon_tpu.ops.attention import multihead_attention, xla_attention  # noqa: E402
 from photon_tpu.train.train_step import make_loss_fn  # noqa: E402
@@ -547,6 +548,138 @@ def test_the_shares_add_up_to_the_uncut_layer(held):
 
 
 # ---------------------------------------------------------------------------
+# the headwise gate: one function with a pull-back of its own
+# ---------------------------------------------------------------------------
+
+
+def _two_line_gate(o, logits, **_):
+    """The gate as the block wrote it before ``ops/head_gate``, its pull-back
+    left to autodiff: what ``head_gate`` has to equal."""
+    attn_out = o.reshape(*o.shape[:-1], logits.shape[-1], -1)
+    gate = jax.nn.sigmoid(logits.astype(jnp.float32))
+    return (attn_out.astype(jnp.float32) * gate[..., None]).astype(o.dtype).reshape(o.shape)
+
+
+def _gate_readings(gate_fn, s, heads, d, dtype):
+    """``gated``, and from one cotangent ``d_o`` and ``d_logits``, then the
+    gradients of the gate's own ``[D, H]`` kernel and of the block's normed
+    input ``h`` through the gate's ``Dense``."""
+    keys = jax.random.split(jax.random.PRNGKey(s + heads + d), 4)
+    h = jax.random.normal(keys[0], (2, s, 16), dtype)
+    kernel = jax.random.normal(keys[1], (16, heads), jnp.float32)
+    o = jax.random.normal(keys[2], (2, s, heads * d), dtype)
+    dy = jax.random.normal(keys[3], (2, s, heads * d), dtype)
+    dense = lambda h, kernel: h @ kernel.astype(dtype)  # noqa: E731 — nn.Dense(dtype=compute)
+    gated, pull = jax.vjp(gate_fn, o, dense(h, kernel))
+    d_o, d_logits = pull(dy)
+    d_h, d_kernel = jax.grad(
+        lambda h, kernel: jnp.vdot(gate_fn(o, dense(h, kernel)).astype(jnp.float32),
+                                   dy.astype(jnp.float32)), argnums=(0, 1))(h, kernel)
+    assert gated.dtype == d_o.dtype == d_logits.dtype == d_h.dtype == dtype
+    return {k: np.asarray(v, np.float32) for k, v in dict(
+        gated=gated, d_o=d_o, d_logits=d_logits, d_kernel=d_kernel, d_h=d_h).items()}
+
+
+def _assert_the_gate_is_the_two_lines(s, heads, d, dtype, interpret):
+    gate_fn = lambda o, logits: hg.head_gate(  # noqa: E731
+        o, logits, impl="pallas", interpret=interpret)
+    o = jnp.zeros((2, s, heads * d), dtype)
+    launches = str(jax.make_jaxpr(gate_fn)(o, jnp.zeros((2, s, heads), dtype))).count(
+        "pallas_call")
+    assert launches == int(hg.uses_kernel("pallas", interpret, s, d)) == int(interpret)
+    got = _gate_readings(gate_fn, s, heads, d, dtype)
+    want = _gate_readings(_two_line_gate, s, heads, d, dtype)
+    for name in ("gated", "d_o"):  # the same roundings at the same places
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+    if not interpret:  # and the same float32 sum over D
+        for name in ("d_logits", "d_kernel", "d_h"):
+            np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+        return
+    # a launch sums a head's D products in its own order: one ulp of the dtype
+    ulp = float(jnp.finfo(dtype).eps)
+    np.testing.assert_allclose(got["d_logits"], want["d_logits"], rtol=ulp, atol=1e-30)
+    for name in ("d_kernel", "d_h"):  # sums of those over tokens / heads
+        scale = np.abs(want[name]).max()
+        np.testing.assert_allclose(got[name], want[name], rtol=0, atol=4 * ulp * scale,
+                                   err_msg=name)
+
+
+GATE_CASES = [  # the ``jax.numpy`` expression: a head of whole lanes or not, a decode step
+    pytest.param(s, heads, d, dtype, False, id=f"s{s}-h{heads}-d{d}-{dtype}")
+    for s in (1, 32) for heads in (4, 6) for d in (128, 24) for dtype in ("bfloat16", "float32")
+] + [  # the Pallas launches under the interpreter, at a block-aligned size
+    pytest.param(hg.ROW_BLOCK, heads, 128, dtype, True, id=f"interpret-h{heads}-{dtype}")
+    for heads in (4, 6) for dtype in ("bfloat16", "float32")]
+
+
+@pytest.mark.parametrize("s,heads,d,dtype,interpret", GATE_CASES)
+def test_head_gate_is_the_two_lines_and_their_autodiff(s, heads, d, dtype, interpret):
+    _assert_the_gate_is_the_two_lines(s, heads, d, jnp.dtype(dtype), interpret)
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["jnp", "interpret"])
+def test_a_gate_without_the_sigmoids_slope_fails_the_comparison(monkeypatch, interpret):
+    """The planted fault: ``g * (1 - g)`` dropped from the pull-back."""
+    monkeypatch.setattr(hg, "_gate_slope", jnp.ones_like)
+    with pytest.raises(AssertionError, match="d_logits|Not equal to tolerance"):
+        _assert_the_gate_is_the_two_lines(hg.ROW_BLOCK, 4, 128, jnp.dtype("float32"), interpret)
+
+
+def test_on_a_mesh_every_shard_of_rows_and_heads_runs_its_own_launches():
+    """Rows over ``fsdp``, heads over ``tensor``: the launches under
+    ``shard_map`` (a Mosaic call is not GSPMD's to partition) against the two
+    lines on one device."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from photon_tpu.config.schema import MeshConfig
+    from photon_tpu.parallel.context import use_mesh
+    from photon_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(MeshConfig(fsdp=2, tensor=2), devices=jax.devices()[:4])
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    o, logits, dy = (jax.random.normal(k, (2, hg.ROW_BLOCK, w), jnp.bfloat16)
+                     for k, w in zip(keys, (4 * 128, 4, 4 * 128)))
+
+    def readings(gate_fn, *arrays):
+        gated, pull = jax.vjp(gate_fn, *arrays[:2])
+        return gated, *pull(arrays[2])
+
+    with use_mesh(mesh):
+        sharding = NamedSharding(mesh, P("fsdp", None, "tensor"))
+        sharded = jax.jit(lambda *a: readings(
+            lambda o, logits: hg.head_gate(o, logits, impl="pallas", interpret=True), *a))
+        text = sharded.lower(*(jax.device_put(a, sharding) for a in (o, logits, dy))).as_text()
+        assert "shard_map" in text or "manual" in text
+        got = sharded(*(jax.device_put(a, sharding) for a in (o, logits, dy)))
+    assert got[0].sharding.spec == P("fsdp", None, "tensor")
+    for name, a, b in zip(("gated", "d_o", "d_logits"), got, readings(_two_line_gate, o, logits, dy)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                      err_msg=name)
+
+
+def test_the_gated_models_gradient_is_the_two_lines(monkeypatch):
+    """Through the blocks: the tiny model's loss and every leaf of its
+    gradient (``attn_gate/kernel`` and, through ``h``, all that lies under
+    it) with ``head_gate`` and with the two lines in its place."""
+    cfg = tiny_cfg()
+    params = init_params(cfg.model, seed=0)
+    grad = lambda: jax.value_and_grad(  # noqa: E731
+        make_loss_fn(MPTModel(cfg.model), 16))(params, TOKENS)
+    loss, got = grad()
+    traced = []  # (the two lines did take its place: one trace a stack, at least)
+    monkeypatch.setattr(
+        mpt, "head_gate", lambda *a, **kw: traced.append(a) or _two_line_gate(*a, **kw))
+    want_loss, want = grad()
+    assert len(traced) >= 3
+    assert float(loss) == float(want_loss)
+    names = leaf_names(want)
+    assert sum("attn_gate/kernel" in n for n in names) == 3
+    for name, a, b in zip(names, jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
+# ---------------------------------------------------------------------------
 # scopes, span attributes, counts
 # ---------------------------------------------------------------------------
 
@@ -620,14 +753,21 @@ def test_trainer_tells_the_sliding_layers_and_the_bands_plan_on_its_span():
 
     told = lambda model: step_attrs(model, batch_rows=1).steps  # noqa: E731
     # (no kernel in a step on the CPU backend: the flash plans add no key)
-    assert told(load_preset(PRESET).model) == {"swa_layers": 3, "sliding_window": 512}
-    assert told(tiny_cfg().model) == {"swa_layers": 3, "sliding_window": 8}
-    assert "swa_layers" not in told(load_preset("lfm2-8b-a1b-ep4").model)
+    # (nor does the gate take its launches there: ``head_gate_layers`` 0)
+    assert told(load_preset(PRESET).model) == {
+        "swa_layers": 3, "sliding_window": 512, "head_gate_layers": 0}
+    assert told(tiny_cfg().model) == {
+        "swa_layers": 3, "sliding_window": 8, "head_gate_layers": 0}
+    lfm2 = told(load_preset("lfm2-8b-a1b-ep4").model)
+    assert "swa_layers" not in lfm2 and "head_gate_layers" not in lfm2
     # with the kernel in the step: the full layers' plan and the band's, each
     # by its kind's heads, the executed share from ``TilePlan.attrs()``
     model = dataclasses.replace(load_preset(PRESET).model, attn_interpret=True)
     attrs = told(model)
     assert attrs["flash_layout"] == "in_place"
+    assert attrs["head_gate_layers"] == 5  # heads of 128 lanes in rows of 16,384
+    assert told(tiny_cfg(attn_impl="pallas", attn_interpret=True).model)[
+        "head_gate_layers"] == 0  # heads of 8: the ``jax.numpy`` expression
     assert attrs["flash_tiles"] == "fwd=2048x2048 dq=2048x2048 dkv=2048x2048"
     plan = fa.pick_tiles(16384, 16384, 128, 2, 8, layout=fa.IN_PLACE, window=512)
     assert attrs["swa_tiles"] == " ".join(

@@ -860,7 +860,11 @@ def test_the_windowed_cells_step_compiles_and_fits_the_chip(topo_devices, monkey
     ``memory_analysis()``, which counts temporaries that are never live
     together: 16.21 GiB where the compiler's own report
     (``XLA_FLAGS=--xla_dump_to``, ``*memory-usage-report.txt``) totals 14.77
-    and the program fits the chip's 15.75 (~70 s)."""
+    and the program fits the chip's 15.75 (~70 s). Those are the step's
+    before the headwise gate had a pull-back of its own (``ops/head_gate.py``,
+    PR 50); with it no fusion writes a float32 array of tokens x heads x 128
+    (three did: the gate's multiply forward, recomputed and back), the gate's
+    two launches are in the text, and the step asks for no more."""
     from photon_tpu.config import load_preset
 
     cfg = load_preset("laguna-xs.2-ep8")
@@ -871,4 +875,10 @@ def test_the_windowed_cells_step_compiles_and_fits_the_chip(topo_devices, monkey
     for name in ("flash_fwd", "flash_dq", "flash_dkv", "flash_swa_fwd", "flash_swa_dq",
                  "flash_swa_dkv"):
         assert f"{name}/multihead_attention" in text, name
-    assert 11.0 < _live_gib(compiled) < 16.5
+    wide = re.compile(r"f32\[(1,)?16384,(64,128|48,128|8192|6144)\]")
+    written = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) fusion\(", text, re.M)
+    assert len(written) > 500 and not [shape for shape in written if wide.search(shape)]
+    for name in ("head_gate_fwd", "head_gate_bwd"):
+        assert f"attn/gate/{name}/pallas_call" in text, name
+    print(f"live GiB {_live_gib(compiled):.3f}")
+    assert 11.0 < _live_gib(compiled) <= 16.21  # the parent's reading

@@ -522,7 +522,8 @@ PARENTS = {
         [(names.TRAINER_MOE_LOAD_SPAN, _MOE),
          (names.TRAINER_MHC_SPAN, {"sinkhorn_gap": names.MHC_SINKHORN_GAP})]),
     "laguna-xs.2-ep8": (
-        {"swa_layers": 3, "sliding_window": 8}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
+        {"swa_layers": 3, "sliding_window": 8, "head_gate_layers": 0},
+        [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "keye-vl-2.0-30b-a3b-ep8": (
         {"dsa_layers": 2, "dsa_topk": 16},
         [(names.TRAINER_MOE_LOAD_SPAN, _MOE),
