@@ -456,7 +456,8 @@ class MPTBlock(nn.Module):
                 nn.softplus(dt.astype(jnp.float32) + dt_bias), a_log,
                 xbc[..., inner:inner + n], xbc[..., inner + n:], skip,
                 # a row shorter than a chunk (``init_params``' 8 tokens) is one chunk
-                chunk=min(cfg.mamba_chunk_size, s), compute_dtype=compute)
+                chunk=min(cfg.mamba_chunk_size, s), compute_dtype=compute,
+                impl=cfg.attn_impl, interpret=cfg.attn_interpret)
         with jax.named_scope(MAMBA_GATE_NORM_SCOPE):
             y = y.reshape(b, s, inner) * nn.silu(z.astype(jnp.float32))
             y = FP32RMSNorm(eps=cfg.norm_eps, name="mamba_norm")(y).astype(compute)

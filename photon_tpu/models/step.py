@@ -151,9 +151,16 @@ def step_attrs(cfg: ModelConfig, batch_rows: int) -> StepAttrs:
     once, where the shapes are known, and not per launch. A kind of layer the
     model lacks adds no key."""
     steps: dict[str, Any] = _flash_attrs(cfg)
-    if cfg.mamba_layers:  # and the chunks each one's scan walks a row in
+    if cfg.mamba_layers:  # the chunks each one's scan walks a row in, and the
+        # layers whose scan is ``ops/ssd``'s launches at these shapes
+        from photon_tpu.ops import ssd
+
+        kernel = ssd.uses_kernel(cfg.attn_impl, cfg.attn_interpret, cfg.max_seq_len,
+                                 cfg.mamba_chunk_size, cfg.mamba_n_heads, cfg.mamba_d_head,
+                                 cfg.mamba_d_state)
         steps.update(mamba_layers=cfg.mamba_layers,
-                     ssd_chunks=cfg.max_seq_len // cfg.mamba_chunk_size)
+                     ssd_chunks=cfg.max_seq_len // cfg.mamba_chunk_size,
+                     ssd_kernel_layers=cfg.mamba_layers if kernel else 0)
     if cfg.conv_layers:
         steps.update(conv_layers=cfg.conv_layers)
     if cfg.swa_layers:  # beside them: the banded launches' plan (``_flash_attrs``)

@@ -12,9 +12,12 @@ order of summation alone and every tolerance below is a float32 one.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import inspect
 import json
 import math
 import pathlib
+import re
 import sys
 
 import jax
@@ -71,11 +74,17 @@ TOKENS = np.random.default_rng(3).integers(0, 96, size=(2, 32)).astype(np.int32)
 SCAN_ARGS = ("x", "dt", "a_log", "b", "c", "d")
 #: (sequence, chunk): 1, 2 and 5 chunks of 8, and a chunk that is not 8
 SCAN_SHAPES = [(8, 8), (16, 8), (40, 8), (24, 4)]
+#: the Pallas launches under the interpreter, by compute dtype: three chunks of
+#: 128 and two blocks of two heads of 64 (``HEAD_BLOCK`` held to 2), state 128
+LAUNCH_SHAPE = dict(seq=384, chunk=128, b=1, h=4, p=64, n=128)
+SCAN_CASES = [(seq, chunk, None) for seq, chunk in SCAN_SHAPES] + [
+    (LAUNCH_SHAPE["seq"], LAUNCH_SHAPE["chunk"], dtype) for dtype in ("float32", "bfloat16")]
+#: bf16 operands under float32 sums, against float32: of the largest entry
+BF16_TOLERANCE = 0.05
 
 
-def _scan_inputs(seq: int):
+def _scan_inputs(seq: int, b: int = 2, h: int = 3, p: int = 4, n: int = 5, **_):
     rng = np.random.default_rng(seq)
-    b, h, p, n = 2, 3, 4, 5
     f32 = lambda t: jnp.asarray(t, jnp.float32)  # noqa: E731
     return dict(
         x=f32(rng.normal(size=(b, seq, h, p))),
@@ -85,9 +94,9 @@ def _scan_inputs(seq: int):
         d=f32(rng.normal(size=h)))
 
 
-def _chunked(args: dict, chunk: int):
+def _chunked(args: dict, chunk: int, **how):
     return ssd.ssd_scan(*(args[k] for k in SCAN_ARGS), chunk=chunk,
-                        compute_dtype=jnp.float32)
+                        **{"compute_dtype": jnp.float32, **how})
 
 
 def _sequential(args: dict):
@@ -95,8 +104,81 @@ def _sequential(args: dict):
                           args["c"], args["d"])
 
 
-@pytest.mark.parametrize("seq,chunk", SCAN_SHAPES)
-def test_chunked_scan_values_match_the_sequential_recurrence(seq, chunk):
+@functools.lru_cache(maxsize=None)
+def _launch_readings(dtype: str, fault: str | None = None) -> dict:
+    """``y`` and the six gradients of ``sum(weights * y)`` at ``LAUNCH_SHAPE``
+    from the launches (under the interpreter), the walk (both with ``dtype``
+    operands) and the sequential recurrence (float32)."""
+    args = _scan_inputs(**LAUNCH_SHAPE)
+    shape = args["x"].shape
+    weights = jnp.asarray(np.random.default_rng(1).normal(size=shape), jnp.float32)
+
+    def readings(fn):
+        y, pull = jax.vjp(lambda *a: fn(dict(zip(SCAN_ARGS, a))), *(args[k] for k in SCAN_ARGS))
+        return dict(zip(("y", *SCAN_ARGS), (y, *pull(weights))))
+
+    how = dict(chunk=LAUNCH_SHAPE["chunk"], compute_dtype=jnp.dtype(dtype))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ssd, "HEAD_BLOCK", 2)
+        if fault:
+            FAULTS[fault](patch)
+        launches = lambda a: _chunked(a, **how, impl="pallas", interpret=True)  # noqa: E731
+        assert str(jax.make_jaxpr(launches)(args)).count("pallas_call") == 1
+        got = jax.jit(readings, static_argnums=0)(launches)
+    return {"launches": got, "walk": readings(lambda a: _chunked(a, **how)),
+            "sequential": readings(_sequential)}
+
+
+def _plant_bf16_state(patch):
+    """The carried state rounded to bf16 between chunks."""
+    real = ssd._forward_kernel
+
+    def kernel(*refs, **static):
+        real(*refs, **static)
+        state = refs[-5]  # the first of the launch's five scratch buffers
+        state[...] = state[...].astype(jnp.bfloat16).astype(jnp.float32)
+
+    patch.setattr(ssd, "_forward_kernel", kernel)
+
+
+def _plant_dropped_dh(patch):
+    """``dH`` dropped across every chunk boundary."""
+    real = ssd._backward_kernel
+
+    def kernel(*refs, **static):
+        d_state = refs[list(inspect.signature(real).parameters).index("d_state")]
+        d_state[...] = jnp.zeros_like(d_state)
+        real(*refs, **static)
+
+    patch.setattr(ssd, "_backward_kernel", kernel)
+
+
+FAULTS = {"bf16_state": _plant_bf16_state, "dropped_dh": _plant_dropped_dh}
+
+
+def _check_launches(dtype: str, name: str, fault: str | None = None):
+    """One reading of the launches against both references: float32 at the
+    tests' float32 tolerance, bf16 operands at ``BF16_TOLERANCE``."""
+    found = _launch_readings(dtype, fault)
+    for other in ("sequential", "walk"):
+        got, want = found["launches"][name], found[other][name]
+        scale = float(jnp.max(jnp.abs(want)))
+        assert scale > 1e-2
+        if dtype == "float32":
+            # (``a_log``: one number a head, summed over every position and
+            # channel: at these widths the walk itself stands 0.5-1.6 of the
+            # tolerance from the recurrence, the launches 0.3-3.4)
+            slack = 8 if name == "a_log" else 1
+            np.testing.assert_allclose(got, want, atol=2e-5 * scale * slack, rtol=2e-4 * slack,
+                                       err_msg=f"{name} against the {other}")
+        else:
+            assert float(jnp.max(jnp.abs(got - want))) < BF16_TOLERANCE * scale, (name, other)
+
+
+@pytest.mark.parametrize("seq,chunk,launches", SCAN_CASES)
+def test_chunked_scan_values_match_the_sequential_recurrence(seq, chunk, launches):
+    if launches:
+        return _check_launches(launches, "y")
     args = _scan_inputs(seq)
     want = _sequential(args)
     assert float(jnp.max(jnp.abs(want))) > 1.0
@@ -106,8 +188,10 @@ def test_chunked_scan_values_match_the_sequential_recurrence(seq, chunk):
 
 
 @pytest.mark.parametrize("wrt", SCAN_ARGS)
-@pytest.mark.parametrize("seq,chunk", SCAN_SHAPES)
-def test_chunked_scan_gradient_matches_the_sequential_recurrence(seq, chunk, wrt):
+@pytest.mark.parametrize("seq,chunk,launches", SCAN_CASES)
+def test_chunked_scan_gradient_matches_the_sequential_recurrence(seq, chunk, launches, wrt):
+    if launches:
+        return _check_launches(launches, wrt)
     args = _scan_inputs(seq)
     weights = jnp.asarray(np.random.default_rng(1).normal(size=(2, seq, 3, 4)), jnp.float32)
 
@@ -122,6 +206,49 @@ def test_chunked_scan_gradient_matches_the_sequential_recurrence(seq, chunk, wrt
     np.testing.assert_allclose(got, want, atol=2e-5 * scale, rtol=2e-4)
 
 
+@pytest.mark.parametrize("fault,wrt", [("bf16_state", "y"), ("dropped_dh", "dt")])
+def test_a_planted_fault_fails_the_launches_comparison(fault, wrt):
+    with pytest.raises(AssertionError, match=f"{wrt} against the"):
+        _check_launches("float32", wrt, fault)
+
+
+def test_on_a_mesh_every_shard_of_rows_runs_its_own_launches(monkeypatch):
+    """Rows over ``fsdp``: the launches under ``shard_map`` (a Mosaic call is
+    not GSPMD's to partition), the per-head gradients summed over the shards,
+    against the same launches on one device."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from photon_tpu.config.schema import MeshConfig
+    from photon_tpu.parallel.context import use_mesh
+    from photon_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(ssd, "HEAD_BLOCK", 2)
+    shape = dict(LAUNCH_SHAPE, b=2, seq=256)
+    args = _scan_inputs(**shape)
+    operands = tuple(args[k] for k in SCAN_ARGS)
+    weights = jnp.asarray(np.random.default_rng(1).normal(size=args["x"].shape), jnp.float32)
+
+    def readings(*a):
+        y, pull = jax.vjp(lambda *a: _chunked(dict(zip(SCAN_ARGS, a)), 128, impl="pallas",
+                                              interpret=True), *a)
+        return (y, *pull(weights))
+
+    want = jax.jit(readings)(*operands)
+    mesh = make_mesh(MeshConfig(fsdp=2), devices=jax.devices()[:2])
+    with use_mesh(mesh):
+        rows, whole = NamedSharding(mesh, P("fsdp")), NamedSharding(mesh, P())
+        placed = tuple(jax.device_put(a, whole if a.ndim == 1 else rows) for a in operands)
+        sharded = jax.jit(readings)
+        text = sharded.lower(*placed).as_text()
+        assert "shard_map" in text or "manual" in text
+        got = sharded(*placed)
+    assert got[0].sharding.spec == P("fsdp")
+    for name, a, b in zip(("y", *SCAN_ARGS), got, want):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=1e-5 * scale, rtol=1e-5, err_msg=name)
+
+
 def test_scan_refuses_a_sequence_that_is_not_whole_chunks():
     with pytest.raises(ValueError, match="multiple of the scan's chunk"):
         _chunked(_scan_inputs(12), 8)
@@ -129,18 +256,46 @@ def test_scan_refuses_a_sequence_that_is_not_whole_chunks():
 
 def test_scan_keeps_state_and_decays_in_float32_under_bfloat16_compute():
     """bf16 operands, float32 accumulation: the result stays within bf16's
-    rounding of the float32 one, and the scan's carry is float32."""
+    rounding of the float32 one, and the scan's carry is float32, in the walk
+    and in the launches (whose chunks' start states are what a chunk hands
+    the next)."""
     args = _scan_inputs(40)
     exact = _chunked(args, 8)
-    low = ssd.ssd_scan(*(args[k] for k in SCAN_ARGS), chunk=8, compute_dtype=jnp.bfloat16)
+    low = _chunked(args, 8, compute_dtype=jnp.bfloat16)
     assert low.dtype == jnp.float32
-    assert float(jnp.max(jnp.abs(low - exact))) < 0.05 * float(jnp.max(jnp.abs(exact)))
+    assert float(jnp.max(jnp.abs(low - exact))) < BF16_TOLERANCE * float(jnp.max(jnp.abs(exact)))
     bf16 = lambda k: args[k][:, :8].astype(jnp.bfloat16)  # noqa: E731
     state, y = jax.eval_shape(
         lambda: ssd._chunk(-jnp.exp(args["a_log"]), jnp.bfloat16,
                            jnp.zeros((2, 3, 4, 5), jnp.float32),
                            (bf16("x"), args["dt"][:, :8], bf16("b"), bf16("c"))))
     assert state.dtype == y.dtype == jnp.float32  # what a chunk hands the next
+    found = _launch_readings("bfloat16")["launches"]
+    assert {v.dtype for v in found.values()} == {jnp.dtype("float32")}
+    wide = _scan_inputs(**LAUNCH_SHAPE)
+    seq, h, p = (LAUNCH_SHAPE[k] for k in ("seq", "h", "p"))
+    y, states = jax.eval_shape(
+        lambda: ssd._forward(wide["x"].astype(jnp.bfloat16).reshape(1, seq, h * p), wide["dt"],
+                             -jnp.exp(wide["a_log"]), wide["b"].astype(jnp.bfloat16),
+                             wide["c"].astype(jnp.bfloat16), wide["d"], 128, True, True))
+    assert y.dtype == states.dtype == jnp.float32
+    assert states.shape == (1, 3, LAUNCH_SHAPE["n"], h * p)  # a start state a chunk
+
+
+@pytest.mark.parametrize("impl,interpret,seq,chunk,heads,d_head,d_state,takes", [
+    ("pallas", True, 8192, 256, 64, 64, 128, True),  # the cell's shapes
+    ("pallas", False, 8192, 256, 64, 64, 128, False),  # no TPU here and no interpreter
+    ("xla", True, 8192, 256, 64, 64, 128, False),
+    ("pallas", True, 8, 8, 64, 64, 128, False),  # ``init_params``' row of 8 tokens
+    ("pallas", True, 32, 8, 4, 16, 8, False),  # the tiny preset
+    ("pallas", True, 8192, 256, 64, 128, 128, False),  # a head that is a whole lane block
+    ("pallas", True, 8192, 256, 63, 64, 128, False),  # heads that do not pair
+    ("pallas", True, 8192, 192, 64, 64, 128, False),  # a chunk that is not whole strips
+    ("pallas", True, 8192, 256, 64, 64, 64, False),  # a state under a lane block
+])
+def test_the_launches_are_taken_by_shape_and_where_a_kernel_can_run(
+        impl, interpret, seq, chunk, heads, d_head, d_state, takes):
+    assert ssd.uses_kernel(impl, interpret, seq, chunk, heads, d_head, d_state) is takes
 
 
 def test_convolution_matches_the_reference():
@@ -241,6 +396,77 @@ def test_gradient_leaf_matches_reference(seeded, leaf):
 @pytest.mark.parametrize("leaf", BLOCK_LEAVES)
 def test_mamba_block_gradient_leaf_matches_reference(seeded_block, leaf):
     _check_leaf(seeded_block, leaf)
+
+
+CELL_TOKENS = np.random.default_rng(3).integers(0, 96, size=(2, 384)).astype(np.int32)
+
+
+def _cell_block_cfg(**model):
+    """One Mamba-2 layer at widths the launches take: three chunks of 128, two
+    blocks of two heads of 64 (``HEAD_BLOCK`` held to 2), state 128."""
+    return tiny_cfg(n_layers=1, layer_types="mamba", max_seq_len=384, mamba_n_heads=4,
+                    mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=128, **model)
+
+
+@functools.lru_cache(maxsize=None)
+def _cell_block_readings(compute: str, fault: str | None = None):
+    """Loss and gradient of one Mamba-2 layer as ``granite4hmicro-train``
+    configures the step (``remat``, the kernels' path, here under the
+    interpreter) at widths the launches take (three chunks of 128, two blocks
+    of two heads of 64, state 128), and the reference's."""
+    cfg = _cell_block_cfg(remat=True, attn_impl="pallas", attn_interpret=True,
+                          compute_dtype=compute)
+    params = ref.make_params(dims_of(cfg), 7)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ssd, "HEAD_BLOCK", 2)
+        if fault:
+            FAULTS[fault](patch)
+        loss_fn = make_loss_fn(MPTModel(cfg.model), 16)
+        launches = re.findall(r"name=(ssd_scan_\w+)",
+                              str(jax.make_jaxpr(jax.grad(loss_fn))(params, CELL_TOKENS)))
+        # forward, forward again under ``remat`` (keeping the start states), backward
+        assert sorted(launches) == ["ssd_scan_bwd", "ssd_scan_fwd", "ssd_scan_fwd"]
+        got = jax.value_and_grad(loss_fn)(params, CELL_TOKENS)
+    return got, _cell_block_reference(), [
+        n for n in LEAVES if not re.match("blocks_[12]/", n)]
+
+
+@functools.lru_cache(maxsize=None)
+def _cell_block_reference():
+    dims = dims_of(_cell_block_cfg())
+    n = CELL_TOKENS.shape[0] * (CELL_TOKENS.shape[1] - 1)
+    return jax.value_and_grad(lambda p: ref.ce_sum(p, CELL_TOKENS, dims) / n)(
+        ref.make_params(dims, 7))
+
+
+def _check_cell_block(compute: str, fault: str | None = None):
+    (loss, got), (want_loss, want), names = _cell_block_readings(compute, fault)
+    assert names == leaf_names(got) == leaf_names(want)
+    # float32: the order of summation alone, ``_check_leaf``'s tolerance;
+    # bf16 operands: the benchmark's limit on a loss, and of a leaf's largest
+    # entry (the sound program reads 0.3-1.1 % here)
+    loss_gap, atol, rtol = (1e-5, 1e-4, 1e-3) if compute == "float32" else (2e-3, 0.03, 0.0)
+    assert abs(float(loss) - float(want_loss)) < loss_gap
+    for name, g, w in zip(names, jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = float(jnp.max(jnp.abs(w)))
+        assert scale > 0
+        np.testing.assert_allclose(g, w, atol=atol * scale, rtol=rtol, err_msg=name)
+
+
+@pytest.mark.parametrize("compute", ["bfloat16", "float32"])
+def test_mamba_block_gradient_as_the_cell_configures_the_step_matches_reference(compute):
+    _check_cell_block(compute)
+
+
+@pytest.mark.parametrize("compute,fault", [
+    ("bfloat16", "dropped_dh"), ("float32", "dropped_dh"), ("float32", "bf16_state")])
+def test_a_planted_fault_fails_the_cell_blocks_comparison(compute, fault):
+    """(A state rounded to bf16 between chunks cannot fail the bf16 case: the
+    products round the state they read to bf16 there, as the configuration
+    states, and at the seeded decays a chunk's end state is ``exp(-30)`` of
+    its start's; under float32 operands it moves ``dt_bias`` by 0.8 %.)"""
+    with pytest.raises(AssertionError, match="A_log|dt_bias"):
+        _check_cell_block(compute, fault)
 
 
 def test_three_adopt_steps_match_reference(seeded):
@@ -457,10 +683,19 @@ def test_sharded_specs_keep_the_in_projection_whole():
 def test_trainer_tells_the_mamba_layers_and_chunks_on_its_span():
     from photon_tpu.models.step import step_attrs
 
-    # (no kernel in a step on the CPU backend: the flash plan adds no key)
+    # (no kernel in a step on the CPU backend: the flash plan adds no key, and
+    # no scan takes ``ops/ssd``'s launches)
     told = lambda model: step_attrs(model, batch_rows=2).steps  # noqa: E731
-    assert told(load_preset(PRESET).model) == {"mamba_layers": 9, "ssd_chunks": 32}
-    assert told(tiny_cfg().model) == {"mamba_layers": 3, "ssd_chunks": 4}
+    preset = load_preset(PRESET).model
+    assert told(preset) == {"mamba_layers": 9, "ssd_chunks": 32, "ssd_kernel_layers": 0}
+    # where a kernel can run (on the chip; here under the interpreter) the
+    # preset's shapes take the launches, the tiny configuration's never do
+    on_the_kernels_path = told(dataclasses.replace(preset, attn_interpret=True))
+    assert on_the_kernels_path["ssd_kernel_layers"] == on_the_kernels_path["mamba_layers"] == 9
+    tiny = tiny_cfg(attn_impl="pallas", attn_interpret=True).model
+    assert {k: v for k, v in told(tiny).items() if "flash" not in k} == {
+        "mamba_layers": 3, "ssd_chunks": 4, "ssd_kernel_layers": 0}
+    assert told(tiny_cfg().model) == {"mamba_layers": 3, "ssd_chunks": 4, "ssd_kernel_layers": 0}
     assert told(load_preset("mpt-125m").model) == {}
 
 

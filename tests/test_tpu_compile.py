@@ -429,25 +429,64 @@ def test_flash_attention_compiles_at_the_hybrid_stages_shapes(one_chip):
     assert _hlo(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).count(KERNEL) >= 3
 
 
-def test_chunked_state_space_scan_compiles_and_keeps_one_chunk_of_decays(one_chip):
+def test_chunked_state_space_scan_compiles_and_keeps_one_chunk_of_decays(one_chip, monkeypatch):
     """``ops/ssd.ssd_scan`` at the cell's widths (one row of 8,192, 64 heads
-    of 64, state 128, chunks of 256), forward and backward: it compiles for the
-    chip, and what it holds beside its arguments and results is far under the
-    0.5 GB a whole row's ``[heads, chunks, 256, 256]`` float32 decays would
-    take (the chunks' start states are 67 MB, one chunk's decays 17 MB)."""
+    of 64, state 128, chunks of 256), forward and backward: the two launches
+    compile for the chip (a head's ``[256, 256]`` decays live in their VMEM),
+    XLA writes no float32 ``[..., 256, 256]`` array beside them (the walk
+    wrote three a chunk, 17 MB each), and what the program holds beside its
+    arguments and results stays under the 0.4 GiB line the walk was held to
+    (the chunks' start states are 67 MB, ``y`` 134)."""
+    import photon_tpu.ops.flash_attention as fa
     from photon_tpu.ops import ssd
 
+    monkeypatch.setattr(fa, "pallas_supported", lambda x: True)
     x = _abstract((1, 8192, 64, 64), jnp.bfloat16, one_chip)
     dt = _abstract((1, 8192, 64), jnp.float32, one_chip)
     bc = _abstract((1, 8192, 128), jnp.bfloat16, one_chip)
     head = _abstract((64,), jnp.float32, one_chip)
+    assert ssd.uses_kernel("pallas", False, 8192, 256, 64, 64, 128)
 
     def loss(x, dt, a_log, b, c, d):
-        return ssd.ssd_scan(x, dt, a_log, b, c, d, chunk=256).sum()
+        return ssd.ssd_scan(x, dt, a_log, b, c, d, chunk=256, impl="pallas").sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
         x, dt, head, bc, bc, head).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 2
+    launches = [ln for ln in text.splitlines() if KERNEL in ln]
+    for name in ("ssd_scan_fwd", "ssd_scan_bwd"):  # each carries its name in its op_name
+        assert sum(bool(re.search(rf"\b{name}\)*/pallas_call", ln)) for ln in launches) == 1, name
+    assert not re.search(r"f32\[[\d,]*256,256\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.4 * 2**30
+
+
+def test_the_hybrid_cells_step_compiles_with_the_scans_launches(topo_devices, monkeypatch):
+    """``granite-4.0-h-micro-stage1`` at its cell's size (1 row x 8,192
+    tokens, one microbatch, ``remat``, 772 M parameters): the whole train step
+    for a described v5e; each stack of Mamba-2 layers holds the scan's forward
+    launch twice (the second under ``remat``, keeping the chunks' start
+    states) and its backward launch once, all under ``mamba/scan``; no fusion
+    writes a float32 ``[..., 256, 256]`` array; and the donated state + its
+    temporaries by ``memory_analysis()`` stand where the walk's step stood
+    (16.446 GiB then, 16.500 now: the scheduler's, 16.250 at 64 heads a block
+    and 16.500 again at 32; the compiler's own report totals 14.27 GiB where
+    the walk's step took 14.26: ``XLA_FLAGS=--xla_dump_to``,
+    ``*memory-usage-report.txt``) (~25 s)."""
+    from photon_tpu.config import load_preset
+
+    cfg = load_preset("granite-4.0-h-micro-stage1")
+    compiled, state = _compile_train_step(cfg, topo_devices()[:1], monkeypatch)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params)) == 772_160_448
+    text = compiled.as_text()
+    launches = [ln for ln in text.splitlines() if KERNEL in ln and "ssd_scan_" in ln]
+    assert len(launches) == 6 and all("mamba/scan/" in ln for ln in launches)
+    assert sum("ssd_scan_bwd" in ln for ln in launches) == 2
+    written = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) fusion\(", text, re.M)
+    assert len(written) > 300 and not [
+        shape for shape in written if re.search(r"f32\[[\d,]*256,256\]", shape)]
+    print(f"live GiB {_live_gib(compiled):.3f}")
+    assert 11.0 < _live_gib(compiled) < 16.446 + 0.1  # the parent's reading, and a hundredth
 
 
 # ---------------------------------------------------------------------------

@@ -514,7 +514,8 @@ _MOE = {"rows_held": names.MOE_ROWS_HELD, "max_expert_load": names.MOE_MAX_EXPER
 #: its static value
 PARENTS = {
     "mpt-125m": ({}, []),
-    "granite-4.0-h-micro-stage1": ({"mamba_layers": 3, "ssd_chunks": 4}, []),
+    "granite-4.0-h-micro-stage1": (
+        {"mamba_layers": 3, "ssd_chunks": 4, "ssd_kernel_layers": 0}, []),
     "glm-4.7-flash-ep8": ({}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "lfm2-8b-a1b-ep4": ({"conv_layers": 4}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "xing4.0-29b-a4b-ep8": (
