@@ -43,6 +43,8 @@ class AttnImpl(str, enum.Enum):
 SLIDING_ATTENTION = "sliding_attention"
 #: every ``layer_types`` entry whose mixer is attention
 ATTENTION_KINDS = ("attention", "full_attention", SLIDING_ATTENTION)
+#: the ``layer_types`` entry of an expert layer that is the layer's one branch
+MOE_LAYER = "moe"
 
 
 class AttentionKind(NamedTuple):
@@ -101,7 +103,7 @@ class ModelConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01  # Switch load-balance loss weight
-    moe_mlp_act: str = "gelu"  # gelu | swiglu (Mixtral-style gated experts)
+    moe_mlp_act: str = "gelu"  # gelu | swiglu (Mixtral-style gated experts) | relu2 (dropless only)
     # The dropless expert layer (``ops/moe.dropless_moe_mlp``, training
     # only): ``moe_router: sigmoid`` scores every one of ``moe_num_experts``
     # with a sigmoid, selects ``moe_top_k`` by score + a selection bias that
@@ -110,7 +112,10 @@ class ModelConfig:
     # slice of an expert-parallel deployment HOLDS ``moe_experts_held``
     # experts (0 -> all) from ``moe_first_expert`` on: it routes over all of
     # them and computes its own experts' part of the result.
-    # ``moe_shared_experts`` SwiGLU experts of the same width see every token.
+    # ``moe_shared_experts`` SwiGLU experts of the same width see every token
+    # (``moe_shared_hidden_size`` > 0: one shared expert of that width).
+    # ``moe_mlp_act: relu2`` makes the routed and the shared experts ungated,
+    # ``W_down relu(W_up h)^2``: two matrices an expert and no ``moe_gate``.
     # After every optimizer step the selection bias moves against each
     # expert's load by ``moe_bias_update_speed`` at most
     # (``ops/moe.balanced_router_bias``); 0 holds it constant.
@@ -122,6 +127,7 @@ class ModelConfig:
     moe_experts_held: int = 0
     moe_first_expert: int = 0
     moe_shared_experts: int = 0
+    moe_shared_hidden_size: int = 0
     moe_routed_scale: float = 1.0
     moe_bias_update_speed: float = 0.0
     # what the sigmoid router adds to the picked scores' sum before it divides
@@ -188,8 +194,18 @@ class ModelConfig:
     # group of B and C of ``mamba_d_state``, a causal depthwise convolution
     # of ``mamba_d_conv`` taps with bias over x | B | C, and scans in chunks
     # of ``mamba_chunk_size`` positions (``max_seq_len`` a multiple of it).
+    # ``mamba_n_groups`` > 1 gives B and C that many groups (head ``h`` reads
+    # group ``h // (mamba_n_heads / mamba_n_groups)``; the convolution runs over
+    # x | every group's B | every group's C) and norms the gated output within
+    # each group's ``mamba_d_inner / mamba_n_groups`` channels.
+    # ``single_branch_layers`` (HF ``nemotron_h``'s ``hybrid_override_pattern``):
+    # a layer is ONE pre-norm (``ln_1``), ONE branch and one add, and its
+    # ``layer_types`` entry names the branch: ``mamba``, ``attention``, or
+    # ``moe`` for the model's dropless expert layer standing alone.
     layer_types: str = ""
+    single_branch_layers: bool = False
     mamba_n_heads: int = 0
+    mamba_n_groups: int = 1
     mamba_d_head: int = 0
     mamba_d_state: int = 0
     mamba_d_conv: int = 4
@@ -308,6 +324,27 @@ class ModelConfig:
     @property
     def conv_layers(self) -> int:
         return self.layer_kinds.count("conv")
+
+    @property
+    def moe_layers(self) -> int:
+        """The layers with the model's expert MLP: ``moe`` entries where a
+        layer is one branch, else every layer behind the leading dense ones."""
+        if self.mlp != "moe":
+            return 0
+        if self.single_branch_layers:
+            return self.layer_kinds.count(MOE_LAYER)
+        return self.n_layers - self.first_k_dense
+
+    @property
+    def shared_expert_width(self) -> int:
+        """The shared expert's hidden width (0: none)."""
+        hidden = self.mlp_hidden_size or self.expansion_ratio * self.d_model
+        return self.moe_shared_hidden_size or self.moe_shared_experts * hidden
+
+    @property
+    def moe_gated(self) -> bool:
+        """The experts are SwiGLU (three matrices); ``relu2`` has two."""
+        return self.moe_mlp_act != "relu2"
 
     @property
     def mamba_d_inner(self) -> int:
@@ -1208,10 +1245,10 @@ class Config:
                 "no shared expert: moe_bias_update_speed, moe_routed_scale and "
                 "moe_shared_experts belong to moe_router='sigmoid'")
         if m.moe_router in ("sigmoid", "softmax_topk"):
-            if m.mlp != "moe" or m.moe_mlp_act != "swiglu":
+            if m.mlp != "moe" or m.moe_mlp_act not in ("swiglu", "relu2"):
                 raise ValueError(
                     f"moe_router={m.moe_router!r} needs mlp='moe' with "
-                    "moe_mlp_act='swiglu'")
+                    "moe_mlp_act='swiglu' (or the ungated 'relu2')")
             held = m.experts_held
             if held > m.moe_num_experts:
                 raise ValueError(
@@ -1235,12 +1272,23 @@ class Config:
             if m.moe_shared_experts < 0 or m.moe_bias_update_speed < 0:
                 raise ValueError(
                     "moe_shared_experts and moe_bias_update_speed must be >= 0")
+            if m.moe_shared_hidden_size and (
+                    m.moe_shared_hidden_size < 0 or m.moe_shared_experts != 1):
+                raise ValueError(
+                    "moe_shared_hidden_size is the width of ONE shared expert: "
+                    "it needs moe_shared_experts=1 and must be > 0")
+        elif m.moe_mlp_act == "relu2":
+            raise ValueError(
+                "moe_mlp_act='relu2' (ungated experts) belongs to the dropless "
+                "routers ('sigmoid', 'softmax_topk'): the capacity path has gelu "
+                "and swiglu experts")
         elif (m.moe_experts_held or m.moe_first_expert or m.moe_shared_experts
+              or m.moe_shared_hidden_size
               or m.moe_routed_scale != 1.0 or m.moe_bias_update_speed):
             raise ValueError(
                 "moe_experts_held / moe_first_expert belong to the dropless "
                 "routers ('sigmoid', 'softmax_topk'); moe_shared_experts / "
-                "moe_routed_scale / moe_bias_update_speed belong to "
+                "moe_shared_hidden_size / moe_routed_scale / moe_bias_update_speed belong to "
                 "moe_router='sigmoid'")
         if m.moe_gate_eps != 1.0e-20 and (m.moe_router != "sigmoid" or m.moe_gate_eps <= 0):
             raise ValueError(
@@ -1496,21 +1544,32 @@ class Config:
                 "supported: the pipeline schedule embeds the tokens itself and "
                 "scans one uniform stack of blocks")
         if not m.hybrid:
+            if m.single_branch_layers:
+                raise ValueError(
+                    "single_branch_layers needs layer_types: each entry names "
+                    "its layer's one branch")
             return
         kinds = set(m.layer_kinds)
-        if len(m.layer_kinds) != m.n_layers or not kinds <= {"mamba", "conv", *ATTENTION_KINDS}:
+        known = ({"mamba", "attention", MOE_LAYER} if m.single_branch_layers
+                 else {"mamba", "conv", *ATTENTION_KINDS})
+        if len(m.layer_kinds) != m.n_layers or not kinds <= known:
             raise ValueError(
                 f"layer_types needs n_layers={m.n_layers} comma-separated entries, "
                 f"each 'mamba', 'conv', 'attention', 'full_attention' or "
-                f"'sliding_attention'; got {len(m.layer_kinds)}: {sorted(kinds)}")
+                f"'sliding_attention' (with single_branch_layers: 'mamba', "
+                f"'attention' or 'moe'); got {len(m.layer_kinds)}: {sorted(kinds)}")
         if m.latent_attention:
             raise ValueError(
                 "layer_types does not combine with latent attention: its "
                 "attention layers are the grouped-query branch's")
-        if m.mamba_layers and (m.first_k_dense or m.mlp == "moe"):
+        if m.mamba_layers and not m.single_branch_layers and (
+                m.first_k_dense or m.mlp == "moe"):
             raise ValueError(
-                "'mamba' layers do not combine with first_k_dense or mlp='moe': "
-                "no model with a Mamba-2 mixer before an expert layer runs here")
+                "'mamba' layers do not combine with first_k_dense or mlp='moe' "
+                "unless a layer is one branch (single_branch_layers): no model "
+                "with a Mamba-2 mixer AND an expert layer in one block runs here")
+        if m.single_branch_layers:
+            self._validate_single_branch_layers()
         if m.mlp == "moe" and not m.dropless_moe:
             raise ValueError(
                 "layer_types with mlp='moe' needs a dropless router "
@@ -1532,6 +1591,12 @@ class Config:
                 raise ValueError(
                     "a 'mamba' layer needs mamba_n_heads, mamba_d_head, "
                     "mamba_d_state, mamba_d_conv and mamba_chunk_size all > 0")
+            if m.mamba_n_groups < 1 or m.mamba_n_heads % m.mamba_n_groups:
+                raise ValueError(
+                    f"mamba_n_groups={m.mamba_n_groups} does not divide the "
+                    f"{m.mamba_n_heads} Mamba heads: a group of B and C serves "
+                    "whole heads, and a share of the heads that splits a group "
+                    "has no B and C of its own")
             if m.max_seq_len % m.mamba_chunk_size:
                 raise ValueError(
                     f"max_seq_len={m.max_seq_len} is not a multiple of "
@@ -1543,6 +1608,29 @@ class Config:
                     "is not supported: the scan carries its state along the "
                     "whole row, and one group's B, C and gated norm span all "
                     "heads")
+
+    def _validate_single_branch_layers(self) -> None:
+        """A layer that is one pre-norm, one branch and one add (preset
+        ``nemotron-3-nano-30b-a3b-ep16``)."""
+        m = self.model
+        if MOE_LAYER in m.layer_kinds and not m.dropless_moe:
+            raise ValueError(
+                "a 'moe' layer is the dropless expert layer standing alone: it "
+                "needs mlp='moe' with moe_router='sigmoid' or 'softmax_topk'")
+        if m.first_k_dense or m.hyper_connected or m.sparse_attention or m.attn_gate \
+                or m.alibi:
+            raise ValueError(
+                "single_branch_layers does not combine with first_k_dense, "
+                "hc_mult > 1, dsa_topk > 0, attn_gate or alibi: a layer has no "
+                "second sublayer, and its attention is the plain causal branch")
+        if m.attn_impl == AttnImpl.RING.value or max(
+                self.mesh.pipe, self.mesh.tensor, self.mesh.sequence, self.mesh.expert,
+                self.mesh.fsdp) > 1:
+            raise ValueError(
+                "single_branch_layers is not supported with ring attention "
+                "(attn_impl='ring') or a mesh axis above 1 other than data: the "
+                "stacks are runs of one kind, each its own scan, and the Mamba "
+                "heads are not split by group over mesh.tensor yet")
 
     def validate(self) -> "Config":
         if self.fl.n_clients_per_round > self.fl.n_total_clients:
@@ -1613,7 +1701,7 @@ class Config:
                     f"moe_capacity_factor must be > 0, got "
                     f"{self.model.moe_capacity_factor}"
                 )
-            if self.model.moe_mlp_act not in ("gelu", "swiglu"):
+            if self.model.moe_mlp_act not in ("gelu", "swiglu", "relu2"):
                 raise ValueError(f"bad moe_mlp_act {self.model.moe_mlp_act}")
             if not 1 <= self.model.moe_top_k <= self.model.moe_num_experts:
                 raise ValueError("moe_top_k must be in [1, moe_num_experts]")

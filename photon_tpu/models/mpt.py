@@ -77,6 +77,19 @@ kernel's output and ``out_proj``'s cotangent as they lie in memory
 pass where a head is whole lanes. Runs of layers equal in kind and
 MLP are the scanned stacks (``ModelConfig.stacks``).
 
+Layers of one branch compose with the Mamba-2 mixer and the expert layer
+(preset ``nemotron-3-nano-30b-a3b-ep16``, training path only):
+``single_branch_layers`` makes a block ONE pre-norm (``ln_1``), ONE branch and
+one add, the branch by the layer's ``layer_types`` entry: a Mamba-2 mixer whose
+B and C come in ``mamba_n_groups`` groups (head ``h`` reads group ``h //
+(heads / groups)``) and whose gated norm works within each group's channels,
+plain causal attention without positions, or the dropless expert layer alone
+(``moe``), its experts ungated (``moe_mlp_act: relu2``: ``W_down relu(W_up
+h)^2``, no ``moe_gate``) beside one shared expert of its own width
+(``moe_shared_hidden_size``). Every run of equal kind is a scanned stack
+(``ModelConfig.stacks``), so a pattern without equal neighbours is stacks of
+one layer.
+
 TPU-first design choices (not in the reference):
 - Layers are stacked with ``nn.scan`` → one traced block, params carry a
   leading ``[n_layers, ...]`` axis. This keeps compile time flat in depth and
@@ -177,18 +190,24 @@ class FP32LayerNorm(nn.Module):
 
 
 class FP32RMSNorm(nn.Module):
-    """RMSNorm in fp32 (llama-family norm; scale-only by construction)."""
+    """RMSNorm in fp32 (llama-family norm; scale-only by construction).
+    ``groups > 1`` norms within each of that many equal runs of the last axis'
+    channels (the Mamba-2 gated norm's ``group_size``); the scale stays one a
+    channel."""
 
     eps: float = 1.0e-5
+    groups: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         x32 = x.astype(jnp.float32)
+        if self.groups > 1:
+            x32 = x32.reshape(*x.shape[:-1], self.groups, -1)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps
         )
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        return (y * scale).astype(x.dtype)
+        return (y.reshape(x.shape) * scale).astype(x.dtype)
 
 
 def _norm(cfg: ModelConfig, name: str) -> nn.Module:
@@ -428,16 +447,18 @@ class MPTBlock(nn.Module):
     def _mamba_mixer(self, h: jax.Array, dense, resid_std: float) -> jax.Array:
         """The Mamba-2 mixer on ``h [B, S, D]``: one projection to ``z | x B C
         | dt``; a causal depthwise convolution and SiLU over ``x B C``; the
-        state-space scan (``ops/ssd.ssd_scan``: one group of ``B``, ``C`` for
-        all heads, float32 ``dt``, decays and state); the gate ``y * silu(z)``
-        before an RMSNorm over all inner channels; the projection back."""
+        state-space scan (``ops/ssd.ssd_scan``: ``mamba_n_groups`` groups of
+        ``B``, ``C``, each for its run of heads, float32 ``dt``, decays and
+        state); the gate ``y * silu(z)`` before an RMSNorm within each group's
+        inner channels (one group: over all of them); the projection back."""
         from photon_tpu.ops import ssd
 
         cfg = self.cfg
         compute = _dtype(cfg.compute_dtype)
         pd = _dtype(cfg.param_dtype)
         b, s, _ = h.shape
-        inner, n, heads = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_n_heads
+        inner, heads, groups = cfg.mamba_d_inner, cfg.mamba_n_heads, cfg.mamba_n_groups
+        n = groups * cfg.mamba_d_state  # B's columns, and C's: group-major
         with jax.named_scope(MAMBA_PROJ_SCOPE):
             zxbcdt = dense(2 * inner + 2 * n + heads, "in_proj", cfg.emb_init_std)(h)
         z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
@@ -457,10 +478,10 @@ class MPTBlock(nn.Module):
                 xbc[..., inner:inner + n], xbc[..., inner + n:], skip,
                 # a row shorter than a chunk (``init_params``' 8 tokens) is one chunk
                 chunk=min(cfg.mamba_chunk_size, s), compute_dtype=compute,
-                impl=cfg.attn_impl, interpret=cfg.attn_interpret)
+                impl=cfg.attn_impl, interpret=cfg.attn_interpret, groups=groups)
         with jax.named_scope(MAMBA_GATE_NORM_SCOPE):
             y = y.reshape(b, s, inner) * nn.silu(z.astype(jnp.float32))
-            y = FP32RMSNorm(eps=cfg.norm_eps, name="mamba_norm")(y).astype(compute)
+            y = FP32RMSNorm(eps=cfg.norm_eps, groups=groups, name="mamba_norm")(y).astype(compute)
         with jax.named_scope(MAMBA_PROJ_SCOPE):
             return dense(cfg.d_model, "out_proj", resid_std)(y)
 
@@ -595,10 +616,14 @@ class MPTBlock(nn.Module):
                 impl=cfg.attn_impl, interpret=cfg.attn_interpret))
         return out
 
-    def _dropless_moe(self, x: jax.Array, dense, hidden: int, resid_std: float):
+    def _dropless_moe(self, x: jax.Array, dense, hidden: int, resid_std: float,
+                      norm: str = "ln_2"):
         """The dropless expert layer's residual branch (``ops/moe.py``):
         shared experts on every token plus this chip's part of the routed
-        sum. The router reads the norm in float32."""
+        sum. The router reads the norm (``norm``: the block's second, or the
+        one pre-norm of a layer that is this branch alone) in float32. Ungated
+        experts (``moe_mlp_act: relu2``) have no ``moe_gate`` and no
+        ``shared_gate_proj``."""
         from photon_tpu.ops import moe
 
         cfg = self.cfg
@@ -606,7 +631,7 @@ class MPTBlock(nn.Module):
         pd = _dtype(cfg.param_dtype)
         init = nn.initializers.normal(stddev=cfg.emb_init_std)
         with jax.named_scope(BLOCK_NORM_SCOPE):
-            h32 = _norm(cfg, "ln_2")(x.astype(jnp.float32))
+            h32 = _norm(cfg, norm)(x.astype(jnp.float32))
         h = h32.astype(compute)
         held = cfg.experts_held
         router_w = self.param("router", init, (cfg.d_model, cfg.moe_num_experts), pd)
@@ -620,7 +645,9 @@ class MPTBlock(nn.Module):
             router_bias = self.param(
                 "router_bias", nn.initializers.normal(stddev=0.01),
                 (cfg.moe_num_experts,), jnp.float32)
-        w_gate = self.param("moe_gate", init, (held, cfg.d_model, hidden), pd)
+        w_gate = None
+        if cfg.moe_gated:
+            w_gate = self.param("moe_gate", init, (held, cfg.d_model, hidden), pd)
         w_up = self.param("moe_up", init, (held, cfg.d_model, hidden), pd)
         w_down = self.param(
             "moe_down", nn.initializers.normal(stddev=resid_std),
@@ -635,11 +662,14 @@ class MPTBlock(nn.Module):
             sow(self, f"moe_{name}", value)
         if cfg.moe_shared_experts:
             with jax.named_scope(moe.SHARED_EXPERT_SCOPE):
-                width = cfg.moe_shared_experts * hidden
-                gate = dense(width, "shared_gate_proj", cfg.emb_init_std)(h)
-                up = dense(width, "shared_up_proj", cfg.emb_init_std)(h)
-                out = out + dense(cfg.d_model, "shared_down_proj", resid_std)(
-                    nn.silu(gate) * up)
+                width = cfg.shared_expert_width
+                if cfg.moe_gated:
+                    gate = dense(width, "shared_gate_proj", cfg.emb_init_std)(h)
+                    up = dense(width, "shared_up_proj", cfg.emb_init_std)(h)
+                    act = nn.silu(gate) * up
+                else:
+                    act = jnp.square(nn.relu(dense(width, "shared_up_proj", cfg.emb_init_std)(h)))
+                out = out + dense(cfg.d_model, "shared_down_proj", resid_std)(act)
         return out
 
     @nn.compact
@@ -680,6 +710,13 @@ class MPTBlock(nn.Module):
             return y
 
         resid_std = cfg.emb_init_std / (2.0 * cfg.n_layers) ** 0.5
+        if cfg.single_branch_layers:
+            # one residual branch a layer, not two (HF ``nemotron_h``'s
+            # ``rescale_prenorm_residual``: ``/ sqrt(n_layers)``)
+            resid_std = cfg.emb_init_std / cfg.n_layers ** 0.5
+            if self.mixer == "moe":  # the expert layer is the whole layer
+                hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * cfg.d_model
+                return _residual(cfg, x, self._dropless_moe(x, dense, hidden, resid_std, "ln_1"))
 
         # --- the mixer: attention, or another kind in its place ---
         # (hyper-connected, ``x`` comes in as the streams, each sublayer reads
@@ -764,6 +801,9 @@ class MPTBlock(nn.Module):
                         x = _residual(cfg, x, branch)
             if hc is not None:
                 x = _write_back(cfg, x, branch, hc)
+
+        if cfg.single_branch_layers:  # the mixer was the layer's one branch
+            return x
 
         # --- MLP ---
         x, hc = _hc_read_in(self, x, "hc_2")

@@ -157,10 +157,12 @@ def step_attrs(cfg: ModelConfig, batch_rows: int) -> StepAttrs:
 
         kernel = ssd.uses_kernel(cfg.attn_impl, cfg.attn_interpret, cfg.max_seq_len,
                                  cfg.mamba_chunk_size, cfg.mamba_n_heads, cfg.mamba_d_head,
-                                 cfg.mamba_d_state)
-        steps.update(mamba_layers=cfg.mamba_layers,
+                                 cfg.mamba_d_state, groups=cfg.mamba_n_groups)
+        steps.update(mamba_layers=cfg.mamba_layers, mamba_groups=cfg.mamba_n_groups,
                      ssd_chunks=cfg.max_seq_len // cfg.mamba_chunk_size,
                      ssd_kernel_layers=cfg.mamba_layers if kernel else 0)
+    if cfg.single_branch_layers:  # a layer is one branch: the other two kinds' counts
+        steps.update(moe_layers=cfg.moe_layers, attention_layers=cfg.full_attention_layers)
     if cfg.conv_layers:
         steps.update(conv_layers=cfg.conv_layers)
     if cfg.swa_layers:  # beside them: the banded launches' plan (``_flash_attrs``)

@@ -300,16 +300,30 @@ def _megablox():
     return importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
+def _ragged_tile(dim: int, cap: int) -> int:
+    """The tile of a dimension no multiple of 128 divides (an expert width of
+    1,856 = 14.5 x 128): the multiple of 128 under the cap that pads the
+    dimension least, the largest of those (640 over 1,856: three tiles, 1,920,
+    the last one masked by the kernel); the whole dimension where it is under
+    128. As ONE tile 1,856 does not compile: the transposed product's
+    ``[1,856, 896]`` weight tile and ``[512, 1,856]`` output take 16.6 MB of a
+    16 MB scoped VMEM (PERF.md section 6, PR 52, with the ladder of the tiles
+    that do: ``scripts/moe_grouped_ladder.py --ragged-tiles``)."""
+    tiles = range(min(cap, dim) // 128 * 128, 0, -128)
+    return min(tiles, key=lambda t: (-(-dim // t) * t, -t), default=dim)
+
+
 def _tiles(caps: tuple[int, int, int], m: int, k: int, n: int) -> tuple[int, int, int]:
     """The kernel's (rows, contraction, output) tile for one product: under
     each cap, the largest multiple of 128 that divides the dimension (a tile
     that does not divide is padded and masked: 1,024 over 1,536 wastes a
-    third), or the whole dimension where none does."""
-    def fit(dim: int, cap: int) -> int:
+    third); where none does, the whole of the rows and :func:`_ragged_tile` of
+    the contraction and the output."""
+    def fit(dim: int, cap: int, whole: bool = False) -> int:
         return next((t for t in range(min(cap, dim) // 128 * 128, 0, -128)
-                     if dim % t == 0), dim)
+                     if dim % t == 0), None) or (dim if whole else _ragged_tile(dim, cap))
 
-    return fit(m, caps[0]), fit(k, caps[1]), fit(n, caps[2])
+    return fit(m, caps[0], whole=True), fit(k, caps[1]), fit(n, caps[2])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -366,7 +380,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
 
 
 def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array | None,
-                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+                     w_gate: jax.Array | None, w_up: jax.Array, w_down: jax.Array, *,
                      top_k: int, first_expert: int, routed_scale: float = 1.0,
                      router: str = "sigmoid", gate_eps: float = 1e-20,
                      compute_dtype=jnp.bfloat16, interpret: bool = False):
@@ -383,9 +397,11 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
     ``h32 [..., D]`` float32 normed activations; ``router_w [D, E]`` and
     ``router_bias [E]`` over ALL ``E`` routed experts; ``w_gate`` / ``w_up``
     ``[E_held, D, H]`` and ``w_down [E_held, H, D]`` the SwiGLU experts
-    ``first_expert .. first_expert + E_held`` this chip holds. Every token is
-    routed over all ``E``; its assignments to experts held here are computed
-    (sorted by expert, three grouped products), the others add nothing: what
+    ``first_expert .. first_expert + E_held`` this chip holds (``w_gate``
+    ``None``: ungated experts ``W_down relu(W_up h)^2``, two matrices each).
+    Every token is routed over all ``E``; its assignments to experts held here
+    are computed (sorted by expert, three grouped products, two where the
+    experts are ungated), the others add nothing: what
     the absent experts would have given is another chip's part of the sum.
 
     Shapes are static for the worst case (``N k`` rows, all routed here). The
@@ -440,9 +456,13 @@ def dropless_moe_mlp(h32: jax.Array, router_w: jax.Array, router_bias: jax.Array
     with jax.named_scope(EXPERTS_SCOPE):
         mm = functools.partial(grouped_matmul, group_sizes=group_sizes,
                                interpret=interpret)
-        gate = mm(rows, w_gate.astype(compute_dtype))
-        up = mm(rows, w_up.astype(compute_dtype))
-        rows = mm(jax.nn.silu(gate) * up, w_down.astype(compute_dtype))
+        if w_gate is None:
+            act = jnp.square(jax.nn.relu(mm(rows, w_up.astype(compute_dtype))))
+        else:
+            gate = mm(rows, w_gate.astype(compute_dtype))
+            up = mm(rows, w_up.astype(compute_dtype))
+            act = jax.nn.silu(gate) * up
+        rows = mm(act, w_down.astype(compute_dtype))
     with jax.named_scope(DISPATCH_SCOPE):
         out = _combine(rows, jnp.where(held, gates, 0.0), order, inv, n_live)
         held_sizes = group_sizes[:e_held].astype(jnp.float32)

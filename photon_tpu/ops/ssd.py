@@ -3,12 +3,16 @@ depthwise convolution that feeds it (and that a ``conv`` layer's gated short
 convolution takes its taps from). Training only. The caller names the scopes
 (``models/mpt.py``: ``mamba/conv``, ``mamba/scan``; ``shortconv/mix``).
 
-Per head ``h`` (width ``P``) and position ``t``, with one group of ``B_t``,
-``C_t`` (width ``N``) shared by all heads, ``A_h = -exp(A_log_h)`` and
-``dt`` already through its softplus:
+Per head ``h`` (width ``P``) and position ``t``, with ``G`` groups of ``B_t``,
+``C_t`` (width ``N`` each; head ``h`` reads group ``h // (H / G)``; one group
+is shared by all heads), ``A_h = -exp(A_log_h)`` and ``dt`` already through
+its softplus:
 
     H_t = exp(dt_t A) H_(t-1) + dt_t x_t B_t^T        (P x N, H_0 = 0)
     y_t = H_t C_t + D x_t
+
+``B`` and ``C`` arrive as they lie behind the convolution, ``[B, S, G·N]``
+with a group a block of ``N`` columns.
 
 The program never walks positions. A row is cut into chunks of ``Q``
 positions, walked in order with the state ``H`` at each chunk's start
@@ -30,7 +34,9 @@ before the exponential, never a quotient of two exponentials.
 
 **The launches** (a TPU, or anywhere under ``interpret``): a ``custom_vjp``
 whose forward and backward are one Pallas launch each over a grid of (row,
-block of ``HEAD_BLOCK`` heads, chunk), the chunk axis innermost and in
+block of ``HEAD_BLOCK`` heads, chunk; a block's heads all of one group, so
+no more than a group has, and its ``B`` / ``C`` that group's columns), the
+chunk axis innermost and in
 order, the block's state ``[N, heads·P]`` float32 in VMEM scratch from chunk
 to chunk. ``x`` and ``y`` are read and written where they lie, ``[B, S,
 H·P]`` with a head a block of ``P`` columns (two heads a lane block of 128 at
@@ -128,9 +134,17 @@ def _chunk(a_neg: jax.Array, compute_dtype, state: jax.Array, inputs):
     return state * jnp.exp(cs_h[..., -1])[..., None, None] + grown, y
 
 
-def _walk(x, dt, a_log, b, c, d, chunk: int, compute_dtype) -> jax.Array:
+def _walk(x, dt, a_log, b, c, d, chunk: int, compute_dtype, groups: int = 1) -> jax.Array:
     """:func:`ssd_scan` as ``lax.scan`` over the chunks, ``jax.numpy`` inside."""
     bsz, s, h, p = x.shape
+    if groups > 1:  # a group with its run of heads is a scan of its own
+        def group_first(t, axis):  # [.., groups * k, ..] at ``axis`` -> [groups, .., k, ..]
+            return jnp.moveaxis(t.reshape(*t.shape[:axis], groups, -1, *t.shape[axis + 1:]), axis, 0)
+
+        y = jax.vmap(lambda *of_group: _walk(*of_group, chunk, compute_dtype))(
+            group_first(x, 2), group_first(dt, 2), group_first(a_log, 0),
+            group_first(b, 2), group_first(c, 2), group_first(d, 0))
+        return jnp.moveaxis(y, 0, 2).reshape(x.shape)
     n = b.shape[-1]
     a_neg = -jnp.exp(a_log.astype(jnp.float32))
 
@@ -169,8 +183,11 @@ STRIP = LANE
 _ROWS = 8
 
 
-def _head_block(heads: int) -> int:
-    """Heads a block: ``HEAD_BLOCK``, or the most that divides ``heads``."""
+def _head_block(heads: int, groups: int = 1) -> int:
+    """Heads a block: ``HEAD_BLOCK``, or the most that divides the heads of
+    one group (all ``heads`` where there is one): a block reads one group's
+    ``B`` and ``C``."""
+    heads //= groups
     block = min(HEAD_BLOCK, heads)
     while heads % block:
         block -= 2
@@ -178,15 +195,15 @@ def _head_block(heads: int) -> int:
 
 
 def uses_kernel(impl: str, interpret: bool, seq: int, chunk: int, heads: int, d_head: int,
-                d_state: int, x: jax.Array | None = None) -> bool:
+                d_state: int, x: jax.Array | None = None, groups: int = 1) -> bool:
     """Whether a scan over rows of ``seq`` positions takes the Pallas launches:
-    by the shape (heads in pairs that fill a lane block, chunks and states of
-    whole lane blocks) and by ``ops/attention.py``'s rule for where a kernel
-    can run (``pallas`` on a TPU or anywhere under ``interpret``)."""
+    by the shape (a group's heads in pairs that fill a lane block, chunks and
+    states of whole lane blocks) and by ``ops/attention.py``'s rule for where a
+    kernel can run (``pallas`` on a TPU or anywhere under ``interpret``)."""
     # looked up at the call: the offline compile check swaps the function
     from photon_tpu.ops import flash_attention
 
-    return (impl == "pallas" and 2 * d_head == LANE and heads % 2 == 0
+    return (impl == "pallas" and 2 * d_head == LANE and heads % (2 * groups) == 0
             and chunk % STRIP == 0 and seq % chunk == 0 and d_state % LANE == 0
             and (interpret or flash_attention.pallas_supported(x)))
 
@@ -407,17 +424,21 @@ def _beside(dt: jax.Array, a_neg: jax.Array, d: jax.Array, chunk: int, block: in
             jnp.repeat(d, p)[None])
 
 
-def _specs(bsz: int, s: int, h: int, p: int, n: int, chunk: int, block: int, backward: bool):
+def _specs(bsz: int, s: int, h: int, p: int, n: int, chunk: int, block: int, backward: bool,
+           groups: int = 1):
     """Block specs by name over the grid (row, head block, chunk); the
-    backward's chunk index counts from the row's last."""
+    backward's chunk index counts from the row's last. A head block reads its
+    group's ``n`` columns of ``B`` and ``C`` ``[B, S, G·n]``."""
     chunks = s // chunk
     at = (lambda c: chunks - 1 - c) if backward else (lambda c: c)
     width = block * p
+    per_group = h // groups // block  # head blocks a group
+    group_of = (lambda j: 0) if groups == 1 else (lambda j: j // per_group)
     return {
         "wide": pl.BlockSpec((1, chunk, width), lambda i, j, c: (i, at(c), j)),
         "pairs": pl.BlockSpec((1, 1, 1, block // 2, _ROWS, chunk),
                               lambda i, j, c: (i, j, at(c), 0, 0, 0)),
-        "bc": pl.BlockSpec((1, chunk, n), lambda i, j, c: (i, at(c), 0)),
+        "bc": pl.BlockSpec((1, chunk, n), lambda i, j, c: (i, at(c), group_of(j))),
         "lanes": pl.BlockSpec((1, 1, 1, width), lambda i, j, c: (i, at(c), 0, j)),
         "skip": pl.BlockSpec((1, width), lambda i, j, c: (0, j)),
         "states": pl.BlockSpec((1, 1, n, width), lambda i, j, c: (i, at(c), 0, j)),
@@ -436,14 +457,15 @@ def _params(piped: int, scratch: int):
         vmem_limit_bytes=2 * piped + scratch + 4 * VMEM_SLACK)
 
 
-def _forward(x, dt, a_neg, b, c, d, chunk: int, keep_states: bool, interpret: bool):
+def _forward(x, dt, a_neg, b, c, d, chunk: int, keep_states: bool, interpret: bool,
+             groups: int = 1):
     """``y [B, S, H·P]`` float32 and, where kept, the chunks' start states
     ``[B, chunks, N, H·P]`` float32, from ``x [B, S, H·P]``."""
     bsz, s, h = dt.shape
-    p, n = x.shape[-1] // h, b.shape[-1]
-    block = _head_block(h)
+    p, n = x.shape[-1] // h, b.shape[-1] // groups
+    block = _head_block(h, groups)
     width, chunks = block * p, s // chunk
-    spec = _specs(bsz, s, h, p, n, chunk, block, backward=False)
+    spec = _specs(bsz, s, h, p, n, chunk, block, backward=False, groups=groups)
     pairs, last, skip = _beside(dt, a_neg, d, chunk, block, p)
     item = x.dtype.itemsize
     piped = (chunk * width * (item + 4) + block // 2 * _ROWS * chunk * 4
@@ -469,14 +491,15 @@ def _forward(x, dt, a_neg, b, c, d, chunk: int, keep_states: bool, interpret: bo
     return out if keep_states else out[0]
 
 
-def _backward(x, dt, a_neg, b, c, d, y, states, dy, chunk: int, interpret: bool):
+def _backward(x, dt, a_neg, b, c, d, y, states, dy, chunk: int, interpret: bool,
+              groups: int = 1):
     """The gradients of ``x`` (in its dtype), ``dt``, ``a_neg``, ``b`` and ``c``
     (float32) and ``d`` from ``dy [B, S, H·P]`` float32."""
     bsz, s, h = dt.shape
-    p, n = x.shape[-1] // h, b.shape[-1]
-    block = _head_block(h)
+    p, n = x.shape[-1] // h, b.shape[-1] // groups
+    block = _head_block(h, groups)
     width, chunks, blocks = block * p, s // chunk, h // block
-    spec = _specs(bsz, s, h, p, n, chunk, block, backward=True)
+    spec = _specs(bsz, s, h, p, n, chunk, block, backward=True, groups=groups)
     pairs, last, skip = _beside(dt, a_neg, d, chunk, block, p)
     item = x.dtype.itemsize
     f32 = jnp.float32
@@ -519,27 +542,34 @@ def _backward(x, dt, a_neg, b, c, d, y, states, dy, chunk: int, interpret: bool)
     d_a = (jnp.flip(jnp.cumsum(jnp.flip(d_cs, 2), axis=2), 2)
            + jnp.sum(ends.reshape(bsz, chunks, 1, h, p), axis=-1)).reshape(bsz, s, h)
     by_x = by_x.reshape(bsz, s, h)
-    return (dx, by_x + a_neg * d_a, jnp.sum(dt * d_a, axis=(0, 1)), jnp.sum(db, axis=1),
-            jnp.sum(dc, axis=1), jnp.sum(dd.reshape(bsz, h, p), axis=(0, 2)))
+
+    def of_groups(partial):  # a head block's partial [B, blocks, S, N] -> [B, S, G·N]
+        if groups == 1:
+            return jnp.sum(partial, axis=1)
+        by_group = jnp.sum(partial.reshape(bsz, groups, blocks // groups, s, n), axis=2)
+        return jnp.swapaxes(by_group, 1, 2).reshape(bsz, s, groups * n)
+
+    return (dx, by_x + a_neg * d_a, jnp.sum(dt * d_a, axis=(0, 1)), of_groups(db),
+            of_groups(dc), jnp.sum(dd.reshape(bsz, h, p), axis=(0, 2)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _launches(x, dt, a_log, b, c, d, chunk: int, interpret: bool):
-    return _forward(x, dt, -jnp.exp(a_log), b, c, d, chunk, False, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _launches(x, dt, a_log, b, c, d, chunk: int, interpret: bool, groups: int = 1):
+    return _forward(x, dt, -jnp.exp(a_log), b, c, d, chunk, False, interpret, groups)
 
 
-def _launches_fwd(x, dt, a_log, b, c, d, chunk: int, interpret: bool):
-    y, states = _forward(x, dt, -jnp.exp(a_log), b, c, d, chunk, True, interpret)
+def _launches_fwd(x, dt, a_log, b, c, d, chunk: int, interpret: bool, groups: int):
+    y, states = _forward(x, dt, -jnp.exp(a_log), b, c, d, chunk, True, interpret, groups)
     return y, (x, dt, a_log, b, c, d, y, states)
 
 
-def _launches_bwd(chunk: int, interpret: bool, residuals, dy):
+def _launches_bwd(chunk: int, interpret: bool, groups: int, residuals, dy):
     # (traced under the scopes of the call it pulls back: the caller's
     # ``mamba/scan`` holds these operations too)
     x, dt, a_log, b, c, d, y, states = residuals
     a_neg = -jnp.exp(a_log)
     dx, d_dt, d_a_neg, db, dc, dd = _backward(x, dt, a_neg, b, c, d, y, states, dy, chunk,
-                                              interpret)
+                                              interpret, groups)
     return dx, d_dt, d_a_neg * a_neg, db.astype(b.dtype), dc.astype(c.dtype), dd
 
 
@@ -549,10 +579,11 @@ _launches.defvjp(_launches_fwd, _launches_bwd)
 def ssd_scan(x: jax.Array, dt: jax.Array, a_log: jax.Array, b: jax.Array,
              c: jax.Array, d: jax.Array, *, chunk: int,
              compute_dtype=jnp.bfloat16, impl: str = "xla",
-             interpret: bool = False) -> jax.Array:
+             interpret: bool = False, groups: int = 1) -> jax.Array:
     """``y [B, S, H, P]`` float32 of the recurrence above over ``x [B, S, H,
     P]``, ``dt [B, S, H]`` (positive, float32), ``a_log [H]``, ``b``, ``c``
-    ``[B, S, N]`` and the skip weight ``d [H]``. ``S`` is a multiple of
+    ``[B, S, groups·N]`` (a group a block of ``N`` columns, for its ``H /
+    groups`` heads) and the skip weight ``d [H]``. ``S`` is a multiple of
     ``chunk``; every row starts from a zero state. ``impl`` / ``interpret``
     are ``ops/attention.py``'s: with ``pallas`` the shapes :func:`uses_kernel`
     admits take the launches."""
@@ -560,14 +591,16 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a_log: jax.Array, b: jax.Array,
     if s % chunk:
         raise ValueError(f"sequence {s} is not a multiple of the scan's chunk {chunk}")
     dt = dt.astype(jnp.float32)
-    if not uses_kernel(impl, interpret, s, chunk, h, p, b.shape[-1], x):
-        return _walk(x, dt, a_log, b, c, d, chunk, compute_dtype)
+    if h % groups or b.shape[-1] % groups:
+        raise ValueError(f"{groups} groups do not divide {h} heads and B's {b.shape[-1]} columns")
+    if not uses_kernel(impl, interpret, s, chunk, h, p, b.shape[-1] // groups, x, groups):
+        return _walk(x, dt, a_log, b, c, d, chunk, compute_dtype, groups)
 
     def scan(x, dt, a_log, b, c, d):
         f32 = jnp.float32
         y = _launches(x.astype(compute_dtype).reshape(*x.shape[:2], h * p), dt, a_log.astype(f32),
                       b.astype(compute_dtype), c.astype(compute_dtype), d.astype(f32),
-                      chunk, interpret)
+                      chunk, interpret, groups)
         return y.reshape(x.shape)
 
     # a Mosaic launch cannot be partitioned by GSPMD: on a mesh each shard of

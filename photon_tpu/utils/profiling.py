@@ -813,6 +813,32 @@ def _kinds_flops_per_token(cfg: ModelConfig) -> float:
     return total + 6.0 * d * v
 
 
+def _single_branch_flops_per_token(cfg: ModelConfig) -> float:
+    """The terms of ``benchmark/costs/nemotron_h_moe_train.py`` at the expected
+    counts (``tests/test_nemotron_h.py`` holds the two equal), a layer by its
+    one branch: a Mamba-2 mixer's two projections, its taps, and the scan's
+    products with ``C B^T`` once a group; an attention layer's projections and
+    the causal half of its score and value products; an expert layer's router,
+    its shared expert and the routed experts at this chip's expected share,
+    two matrices each where they are ungated; and the head."""
+    d, s, v = cfg.d_model, cfg.max_seq_len, cfg.vocab_size
+    hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * d
+    n_kv, h, dh = cfg.n_kv_heads or cfg.n_heads, cfg.n_heads, cfg.d_head
+    inner, n, g = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_n_groups
+    q, matrices = cfg.mamba_chunk_size, 3 if cfg.moe_gated else 2
+    pairs = (q + 1) / 2.0  # earlier positions of its chunk a position meets
+    mamba = (6.0 * (d * (2 * inner + 2 * g * n + cfg.mamba_n_heads) + inner * d)
+             + 3.0 * 2 * cfg.mamba_d_conv * (inner + 2 * g * n)
+             + 3.0 * (pairs * 2 * (g * n + inner) + 4 * inner * n))
+    attention = 6.0 * (d * (h + 2 * n_kv) * dh + h * dh * d) + 3.0 * (s + 1) / 2.0 * h * 4 * dh
+    rows = cfg.moe_top_k * cfg.experts_held / cfg.moe_num_experts
+    moe = 6.0 * (d * cfg.moe_num_experts + matrices * d * (
+        rows * hidden + cfg.shared_expert_width))
+    kinds = cfg.layer_kinds
+    return (kinds.count("mamba") * mamba + kinds.count("attention") * attention
+            + cfg.moe_layers * moe + 6.0 * d * v)
+
+
 def model_flops_per_token(cfg: ModelConfig) -> float:
     """Training FLOPs/token ≈ 6·N_nonemb + 12·L·d·s (attention) + 6·d·V
     (lm_head, tied or not). Matches the estimate used for BASELINE
@@ -828,10 +854,13 @@ def model_flops_per_token(cfg: ModelConfig) -> float:
     connected streams (``hc_mult``) count their maps' projection, two a
     layer; their mixing is elementwise and bound by bytes, not counted.
     Learned sparse attention (``dsa_topk``) has a count of its own,
-    :func:`_sparse_attention_flops_per_token`."""
+    :func:`_sparse_attention_flops_per_token`, and so have layers of one
+    branch (``single_branch_layers``), :func:`_single_branch_flops_per_token`."""
     d, L, s, v = cfg.d_model, cfg.n_layers, cfg.max_seq_len, cfg.vocab_size
     if cfg.sparse_attention:
         return _sparse_attention_flops_per_token(cfg)
+    if cfg.single_branch_layers:
+        return _single_branch_flops_per_token(cfg)
     hidden = cfg.mlp_hidden_size or cfg.expansion_ratio * d
     if cfg.dropless_moe:
         experts = cfg.moe_top_k * cfg.experts_held / cfg.moe_num_experts

@@ -60,6 +60,12 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--hidden", type=int, default=1536)
     ap.add_argument("--experts", type=int, default=8)
     ap.add_argument("--caps", default=CAPS, help="tile caps, rows x contraction x output")
+    ap.add_argument("--ungated", action="store_true",
+                    help="two products and relu^2 between (nemotron_h's experts), not SwiGLU's three")
+    ap.add_argument("--ragged-tiles", default="",
+                    help="rungs in place of ops/moe._ragged_tile's choice for a width no "
+                         "multiple of 128 divides, comma-separated (0: the whole dimension as "
+                         "one tile); each under every --caps")
     ap.add_argument("--calls", type=int, default=5)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--interpret", action="store_true")
@@ -69,7 +75,7 @@ def main(argv: list[str] | None = None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from benchmark.costs import moe_grouped_matmul as cost
+    from benchmark.costs import moe_grouped_matmul, moe_grouped_matmul_ungated
     from benchmark.harness import load_peaks
     from photon_tpu.ops import moe
 
@@ -77,6 +83,7 @@ def main(argv: list[str] | None = None) -> int:
         print("moe_grouped_ladder: no accelerator (use --interpret for the "
               "control flow alone)", file=sys.stderr)
         return 2
+    cost = moe_grouped_matmul_ungated if args.ungated else moe_grouped_matmul
     peak = load_peaks(V5E if args.interpret else jax.devices()[0].device_kind)["flops_per_s_bf16"]
     m, d, f, e = args.static_rows, args.d_model, args.hidden, args.experts
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -85,10 +92,14 @@ def main(argv: list[str] | None = None) -> int:
     w_up = jax.random.normal(keys[2], (e, d, f), jnp.bfloat16) * 0.02
     w_down = jax.random.normal(keys[3], (e, f, d), jnp.bfloat16) * 0.02
 
-    def experts_fn(impl, caps):
+    def experts_fn(impl, caps, ragged=None):
         def forward(x, w_gate, w_up, w_down, sizes):
+            if ragged is not None:  # read where the products are traced
+                moe._ragged_tile = lambda dim, cap: ragged or dim
             mm = lambda a, b: moe.grouped_matmul(  # noqa: E731
                 a, b, sizes, impl=impl, tiling=caps, interpret=args.interpret)
+            if args.ungated:
+                return mm(jnp.square(jax.nn.relu(mm(x, w_up))), w_down)
             return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
 
         def loss(x, w_gate, w_up, w_down, sizes):
@@ -96,17 +107,19 @@ def main(argv: list[str] | None = None) -> int:
 
         return jax.jit(forward), jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))
 
-    variants = [("xla", (0, 0, 0))] + [
-        ("pallas", tuple(int(t) for t in caps.split("x"))) for caps in args.caps.split(",")]
+    raggeds = [int(t) for t in args.ragged_tiles.split(",")] if args.ragged_tiles else [None]
+    variants = [("xla", (0, 0, 0), None)] + [
+        ("pallas", tuple(int(t) for t in caps.split("x")), ragged)
+        for caps in args.caps.split(",") for ragged in raggeds]
     lines = []
-    for impl, caps in variants:
-        forward, backward = experts_fn(impl, caps)
+    for impl, caps, ragged in variants:
+        forward, backward = experts_fn(impl, caps, ragged)
         for rows in (int(r) for r in args.rows.split(",")):
             per = rows // e
             sizes = jnp.asarray([per] * e + [m - per * e], jnp.int32)
             operands = (x, w_gate, w_up, w_down, sizes)
             line = {"impl": impl, "caps": "x".join(map(str, caps)) if impl == "pallas" else "",
-                    "rows": per * e, "static_rows": m}
+                    "ragged_tile": ragged, "rows": per * e, "static_rows": m}
             try:
                 line["fwd_ms"] = _time(forward, operands, args.calls, args.rounds)
                 line["fwd_bwd_ms"] = _time(backward, operands, args.calls, args.rounds)

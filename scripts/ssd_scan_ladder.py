@@ -55,6 +55,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--shape", default="1x8192x64x64x128x256",
                     help="rows x positions x heads x head width x state x chunk")
     ap.add_argument("--rungs", default="8,16,32", help="heads a block, comma-separated")
+    ap.add_argument("--groups", type=int, default=1,
+                    help="groups of B and C (a block's heads are of one group: a rung over "
+                         "heads / groups runs at heads / groups)")
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--tiny", action="store_true")
@@ -65,7 +68,7 @@ def main(argv: list[str] | None = None) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.costs import ssd_scan as costs
+    from benchmark.costs import ssd_scan_grouped as costs
     from photon_tpu.ops import ssd
 
     device = jax.devices()[0]
@@ -76,12 +79,13 @@ def main(argv: list[str] | None = None) -> int:
     if args.tiny:
         args.shape, args.rungs, args.calls, args.rounds = "1x256x4x64x128x128", "2", 1, 1
     bsz, s, h, p, n, chunk = (int(v) for v in args.shape.split("x"))
+    g = args.groups
     flops, bw = PEAKS["TPU v5 lite" if args.tiny else device.device_kind]
     floors = {
-        "forward": max(costs.forward_flops(s, h, p, n, chunk, bsz) / flops,
-                       costs.forward_bytes(s, h, p, n, bsz) / bw) * 1e3,
-        "training": max(costs.training_flops(s, h, p, n, chunk, bsz) / flops,
-                        costs.training_bytes(s, h, p, n, bsz) / bw) * 1e3}
+        "forward": max(costs.forward_flops(s, h, g, p, n, chunk, bsz) / flops,
+                       costs.forward_bytes(s, h, g, p, n, bsz) / bw) * 1e3,
+        "training": max(costs.training_flops(s, h, g, p, n, chunk, bsz) / flops,
+                        costs.training_bytes(s, h, g, p, n, bsz) / bw) * 1e3}
     keys = jax.random.split(jax.random.PRNGKey(s + h), 7)
     dtype = jnp.bfloat16
     # x, y and y's cotangent as the mixer holds them, ``[B, S, H·P]``: the
@@ -92,8 +96,8 @@ def main(argv: list[str] | None = None) -> int:
         x=jax.random.normal(keys[0], (bsz, s, h * p), dtype),
         dt=jax.nn.softplus(jax.random.normal(keys[1], (bsz, s, h)) - 3.0),
         a_log=jnp.log(jax.random.uniform(keys[2], (h,), minval=1.0, maxval=16.0)),
-        b=jax.random.normal(keys[3], (bsz, s, n), dtype),
-        c=jax.random.normal(keys[4], (bsz, s, n), dtype),
+        b=jax.random.normal(keys[3], (bsz, s, g * n), dtype),
+        c=jax.random.normal(keys[4], (bsz, s, g * n), dtype),
         d=jnp.ones((h,), jnp.float32))
     w = jax.random.normal(keys[5], (bsz, s, h * p), jnp.float32)
     operands = tuple(inputs[k] for k in ARGS)
@@ -113,7 +117,8 @@ def main(argv: list[str] | None = None) -> int:
                 ssd.HEAD_BLOCK = rung  # read where the launches are traced
             x, *rest = a
             y = ssd.ssd_scan(x.reshape(bsz, s, h, p), *rest, chunk=chunk, compute_dtype=dtype,
-                             impl="xla" if rung is None else "pallas", interpret=args.tiny)
+                             impl="xla" if rung is None else "pallas", interpret=args.tiny,
+                             groups=g)
             return y.reshape(bsz, s, h * p)
         fwd, both, got = readings(scan)
         want = want or got
@@ -122,7 +127,7 @@ def main(argv: list[str] | None = None) -> int:
         gaps = {f"gap_{k}": float(np.max(np.abs(g - t)) / np.max(np.abs(t)))
                 for k, g, t in zip(("y", *ARGS), got, want)}
         lines.append({
-            "shape": args.shape, "rung": name, "forward_ms": forward_ms, "both_ms": both_ms,
+            "shape": args.shape, "groups": g, "rung": name, "forward_ms": forward_ms, "both_ms": both_ms,
             "forward_floor_ms": floors["forward"], "training_floor_ms": floors["training"],
             "forward_floor_share": floors["forward"] / forward_ms,
             "training_floor_share": floors["training"] / both_ms,

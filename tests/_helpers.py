@@ -167,6 +167,11 @@ TINY_PRESETS = {
         max_seq_len=32, vocab_size=96, rope_scaling_original_max_position=8,
         dense_mlp_hidden_size=48, mlp_hidden_size=16, moe_num_experts=16, moe_top_k=4,
         moe_experts_held=4),
+    "nemotron-3-nano-30b-a3b-ep16": dict(
+        d_model=32, n_heads=4, n_kv_heads=2, head_dim=8, max_seq_len=32, vocab_size=96,
+        mamba_n_heads=8, mamba_n_groups=2, mamba_d_head=8, mamba_d_state=8,
+        mamba_chunk_size=8, mlp_hidden_size=24, moe_shared_hidden_size=40,
+        moe_num_experts=16, moe_top_k=3, moe_experts_held=4),
 }
 
 
@@ -181,6 +186,14 @@ def tiny_preset(preset: str, batch: int = 2, microbatch: int = 2, **model):
         setattr(cfg.model, key, value)
     cfg.train.global_batch_size, cfg.train.device_microbatch_size = batch, microbatch
     return cfg.validate()
+
+
+def leaf_names(tree) -> list[str]:
+    """Every leaf's path, ``/``-joined, in the tree's own order."""
+    import jax
+
+    return ["/".join(str(getattr(k, "key", k)) for k in path)
+            for path, _ in jax.tree_util.tree_leaves_with_path(tree)]
 
 
 def recorded_spans(monkeypatch) -> list:

@@ -687,15 +687,17 @@ def test_trainer_tells_the_mamba_layers_and_chunks_on_its_span():
     # no scan takes ``ops/ssd``'s launches)
     told = lambda model: step_attrs(model, batch_rows=2).steps  # noqa: E731
     preset = load_preset(PRESET).model
-    assert told(preset) == {"mamba_layers": 9, "ssd_chunks": 32, "ssd_kernel_layers": 0}
+    assert told(preset) == {"mamba_layers": 9, "mamba_groups": 1, "ssd_chunks": 32,
+                            "ssd_kernel_layers": 0}
     # where a kernel can run (on the chip; here under the interpreter) the
     # preset's shapes take the launches, the tiny configuration's never do
     on_the_kernels_path = told(dataclasses.replace(preset, attn_interpret=True))
     assert on_the_kernels_path["ssd_kernel_layers"] == on_the_kernels_path["mamba_layers"] == 9
     tiny = tiny_cfg(attn_impl="pallas", attn_interpret=True).model
     assert {k: v for k, v in told(tiny).items() if "flash" not in k} == {
-        "mamba_layers": 3, "ssd_chunks": 4, "ssd_kernel_layers": 0}
-    assert told(tiny_cfg().model) == {"mamba_layers": 3, "ssd_chunks": 4, "ssd_kernel_layers": 0}
+        "mamba_layers": 3, "mamba_groups": 1, "ssd_chunks": 4, "ssd_kernel_layers": 0}
+    assert told(tiny_cfg().model) == {"mamba_layers": 3, "mamba_groups": 1, "ssd_chunks": 4,
+                                      "ssd_kernel_layers": 0}
     assert told(load_preset("mpt-125m").model) == {}
 
 

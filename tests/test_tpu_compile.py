@@ -891,6 +891,41 @@ def test_the_banded_flash_launches_compile_at_the_sliding_layers_shapes(one_chip
     assert "bf16[1,16384,8192]" in text and "bf16[1,16384,1024]" in text  # in place
 
 
+def test_the_one_branch_cells_step_compiles_and_fits_the_chip(topo_devices, monkeypatch):
+    """``nemotron-3-nano-30b-a3b-ep16`` at its cell's size (1 row x 8,192
+    tokens, one microbatch, ``remat``, 667 M parameters): the whole train step
+    for a described v5e. Each of the four Mamba-2 layers (a stack of its own)
+    holds the grouped scan's forward launch ONCE and its backward launch once,
+    all under ``mamba/scan``: a stack of one layer has no loop, and XLA merges
+    the block's recomputation under ``remat`` with its forward (granite's
+    stacks of four and five hold the forward launch twice); each of the four
+    expert layers its two grouped products and their transposes with the
+    1,856-wide dimension walked in tiles of 640 (as ONE tile it does not
+    compile: 16.61 MB of a 16 MB scoped VMEM in the down product's transpose:
+    PERF.md section 6, PR 52); the attention layer the causal flash launches.
+    The donated state + its temporaries by ``memory_analysis()`` count
+    temporaries that are never live together; the compiler's own report
+    (``XLA_FLAGS=--xla_dump_to``, ``*memory-usage-report.txt``) totals 15.55
+    GiB and the program fits the chip's 15.75 (~60 s)."""
+    from photon_tpu.config import load_preset
+
+    cfg = load_preset("nemotron-3-nano-30b-a3b-ep16")
+    compiled, state = _compile_train_step(cfg, topo_devices()[:1], monkeypatch)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params)) == 666_963_456
+    text = compiled.as_text()
+    launches = [ln for ln in text.splitlines() if KERNEL in ln and "ssd_scan_" in ln]
+    assert len(launches) == 8 and all("mamba/scan/" in ln for ln in launches)
+    assert sum("ssd_scan_bwd" in ln for ln in launches) == 4
+    # a launch's block is one group's 8 heads: B and C arrive as [1, 8192, 8 x 128]
+    assert all("bf16[1,8192,1024]" in ln for ln in launches)
+    experts = [ln for ln in text.splitlines() if KERNEL in ln and "moe/experts/" in ln]
+    assert len(experts) >= 4 * 6  # up, down, and two transposes each, a layer
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"{name}/multihead_attention" in text, name
+    print(f"live GiB {_live_gib(compiled):.3f}")
+    assert 10.0 < _live_gib(compiled) < 17.5
+
+
 def test_the_windowed_cells_step_compiles_and_fits_the_chip(topo_devices, monkeypatch):
     """``laguna-xs.2-ep8`` at its cell's size (1 row x 16,384 tokens, one
     microbatch, ``remat``, 692 M parameters): the whole train step for a

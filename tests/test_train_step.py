@@ -460,6 +460,7 @@ SOWN = {
     "xing4.0-29b-a4b-ep8":
         {k for k in COUNTERS if k.startswith(("moe_", "mhc_"))} - {"moe_aux"},
     "laguna-xs.2-ep8": {k for k in COUNTERS if k.startswith("moe_")} - {"moe_aux"},
+    "nemotron-3-nano-30b-a3b-ep16": {k for k in COUNTERS if k.startswith("moe_")} - {"moe_aux"},
 }
 
 
@@ -515,7 +516,7 @@ _MOE = {"rows_held": names.MOE_ROWS_HELD, "max_expert_load": names.MOE_MAX_EXPER
 PARENTS = {
     "mpt-125m": ({}, []),
     "granite-4.0-h-micro-stage1": (
-        {"mamba_layers": 3, "ssd_chunks": 4, "ssd_kernel_layers": 0}, []),
+        {"mamba_layers": 3, "mamba_groups": 1, "ssd_chunks": 4, "ssd_kernel_layers": 0}, []),
     "glm-4.7-flash-ep8": ({}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "lfm2-8b-a1b-ep4": ({"conv_layers": 4}, [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "xing4.0-29b-a4b-ep8": (
@@ -524,6 +525,10 @@ PARENTS = {
          (names.TRAINER_MHC_SPAN, {"sinkhorn_gap": names.MHC_SINKHORN_GAP})]),
     "laguna-xs.2-ep8": (
         {"swa_layers": 3, "sliding_window": 8, "head_gate_layers": 0},
+        [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
+    "nemotron-3-nano-30b-a3b-ep16": (
+        {"mamba_layers": 4, "mamba_groups": 2, "ssd_chunks": 4, "ssd_kernel_layers": 0,
+         "moe_layers": 4, "attention_layers": 1},
         [(names.TRAINER_MOE_LOAD_SPAN, _MOE)]),
     "keye-vl-2.0-30b-a3b-ep8": (
         {"dsa_layers": 2, "dsa_topk": 16},
