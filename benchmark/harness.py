@@ -11,6 +11,7 @@ from __future__ import annotations
 import contextlib
 import gc
 import json
+import math
 import pathlib
 import shutil
 import statistics
@@ -109,6 +110,7 @@ class Run:
         self.trace_dir: pathlib.Path | None = None
         self.trace_window: tuple[float, float] | None = None
         self._trace_stop_at: float | None = None
+        self._trace_stop_s = 0.0
 
     # -- spans, counters, samples ------------------------------------------
     @contextlib.contextmanager
@@ -175,6 +177,14 @@ class Run:
             jax.profiler.stop_trace()
             self.trace_window = (self.trace_window[0], now)
             self._trace_stop_at = None
+            self._trace_stop_s = time.monotonic() - now
+
+    def window_over(self, t0: float) -> bool:
+        """Whether ``--seconds`` have passed since ``t0``, the profiler's stop
+        apart (many seconds where the trace is long): a traced window holds
+        the units of work that a timed one holds, so what is compared for
+        ``correct`` is the same in both."""
+        return time.monotonic() - t0 - self._trace_stop_s >= self.seconds
 
     # -- correctness -------------------------------------------------------
     def check(self, name: str, value: float, limit: float, *,
@@ -186,6 +196,24 @@ class Run:
         self.checks.append({"check": name, "value": value, "limit": limit,
                             "rule": ">=" if at_least else "<=", "ok": ok})
         return ok
+
+    def check_loss_fell(self, before: float, losses: list[float], units: str) -> None:
+        """``loss_fell``: the loss before the window less the median loss of
+        the window's ``units`` (``fits``, ``rounds``) ``a`` to ``b - 1``,
+        where ``[a, b]`` is the traffic file's ``loss_fall_<units>``. On one
+        seed it is one number whatever else the window holds, and no one unit
+        decides it. A window that holds fewer than ``b`` fails
+        ``window_<units>`` and reads no ``loss_fell``: whatever it has
+        instead would be the accident of its length."""
+        a, b = self.traffic[f"loss_fall_{units}"]
+        if not 0 <= a <= b - 3:
+            raise ValueError(f"loss_fall_{units} {[a, b]}: a median needs three "
+                             "or more, [a, b] with 0 <= a <= b - 3")
+        print(json.dumps({"loss_before": before, f"window_{units}_losses": losses}),
+              flush=True)
+        if self.check(f"window_{units}", len(losses), b, at_least=True):
+            self.check("loss_fell", before - statistics.median(losses[a:b]),
+                       self.traffic["limits"]["loss_fall_min"], at_least=True)
 
     @property
     def correct(self) -> bool:
@@ -292,5 +320,13 @@ def finish(spec: Spec, parts: dict, run: Run, *, log=print,
                 result["metrics"][m.name] = {"value": float(value), "unit": m.unit}
     if not keep_work:
         shutil.rmtree(run.work_dir, ignore_errors=True)
+    # last in the line: what a record of a run that was not correct keeps
+    result["checks"] = {c["check"]: {"value": finite(c["value"]), "limit": c["limit"],
+                                     "ok": c["ok"]} for c in run.checks}
     return result
+
+
+def finite(value: float) -> float | str:
+    """A number as JSON can hold it: ``nan`` and ``inf`` by name."""
+    return value if math.isfinite(value) else repr(value)
 

@@ -6,7 +6,8 @@ Exits non-zero and prints no result without an accelerator, with fewer chips
 than the cell asks for, on a chip whose peaks are not in ``peaks.json``, and
 in a directory that lacks the program. The last line of standard output is
 the result object; every number compared for ``correct`` is printed beside
-its limit on the lines before it.
+its limit on the lines before it, as the last lines of standard error, and
+under the result's last key, ``checks``.
 """
 
 from __future__ import annotations
@@ -42,6 +43,9 @@ def main(argv: list[str] | None = None) -> int:
     except (NoAcceleratorError, SpecError) as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 2
+    for name, c in result["checks"].items():
+        print(f"{name} {c['value']} limit {c['limit']} "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

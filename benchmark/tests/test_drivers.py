@@ -17,8 +17,8 @@ TRAIN_TRAFFIC = {
     "kind": "train_steps", "why": "toy",
     "overrides": {"train.global_batch_size": 4, "train.device_microbatch_size": 2,
                   "dataset.synthetic": True},
-    "rows": 64, "zipf_a": 1.3, "steps_per_fit": 2, "warm_fits": 1,
-    "trace_seconds": 1, "reference_rows": 2, "control_matmul": "bfloat16",
+    "rows": 64, "zipf_a": 1.3, "steps_per_fit": 2, "loss_fall_fits": [0, 3],
+    "warm_fits": 1, "trace_seconds": 1, "reference_rows": 2, "control_matmul": "bfloat16",
     # from readings at this size over a few seeds: the float32 program stays
     # under 1e-6 / 2e-7 / 2e-5, the bfloat16 control reads 4e-4 and 6e-4 or more
     # on the two norms
@@ -34,8 +34,8 @@ FED_TRAFFIC = {
                   "train.global_batch_size": 4, "train.device_microbatch_size": 2,
                   "photon.checkpoint": True, "dataset.synthetic": True,
                   "dataset.shuffle": False},
-    "rows": 64, "zipf_a": 1.3, "warm_rounds": 1, "trace_seconds": 1,
-    "reference_rows": 2, "control_matmul": "bfloat16",
+    "rows": 64, "zipf_a": 1.3, "warm_rounds": 1, "loss_fall_rounds": [0, 3],
+    "trace_seconds": 1, "reference_rows": 2, "control_matmul": "bfloat16",
     # program 5e-7 / 5e-7 / 1.2e-6; the bfloat16 control 3e-4 or more on the
     # change of the global weights
     "limits": {"loss_fall_min": -1.0, "round_change_norm_gap": 1e-5, "round_loss_gap": 1e-5,
@@ -102,16 +102,64 @@ def checkout(tmp_path):
     return root
 
 
-def execute(root, workload, *, trace=False, seed=3, seconds=0.5):
-    from benchmark.harness import execute
+# the call that is one unit of a kind's window, and how many set-up makes
+UNITS = {"train_steps": ("fit_chunk", lambda t: t["warm_fits"]),
+         "fed_rounds": ("one_round", lambda t: 1 + t["warm_rounds"])}
+
+
+def hold_units(run, driver, n, raised=()):
+    """Make the window hold exactly ``n`` fits (rounds) on a machine of any
+    speed: it is over when the driver has made that many past set-up's own.
+    A fit whose place in the window is in ``raised`` returns its loss raised
+    by 10."""
+    name, in_setup = UNITS[run.traffic["kind"]]
+    real, in_setup, done = getattr(driver, name), in_setup(run.traffic), [0]
+
+    def counted(*args):
+        out = real(*args)
+        done[0] += 1
+        if done[0] - 1 - in_setup in raised:
+            out = dict(out, loss=out["loss"] + 10.0)
+        return out
+
+    # the module was loaded for this run alone: nothing to put back
+    setattr(driver, name, counted)
+    run.window_over = lambda t0: done[0] - in_setup >= n
+
+
+def execute(root, workload, *, trace=False, seed=3, seconds=0.5, units=None,
+            raised=()):
+    """One run through the harness; with ``units`` its window holds exactly
+    that many fits (rounds)."""
+    from benchmark.harness import finish, prepare
     from benchmark.spec import Spec
 
-    lines = []
-    result = execute(Spec(root), workload, seed, seconds, trace,
-                     t_process=time.monotonic(),
-                     devices_and_peaks=(jax.devices()[:1], toy.TOY_PEAKS),
-                     log=lines.append)
+    spec, lines = Spec(root), []
+    parts, run = prepare(spec, workload, seed, seconds, trace,
+                         t_process=time.monotonic(),
+                         devices_and_peaks=(jax.devices()[:1], toy.TOY_PEAKS))
+    if units is not None:
+        hold_units(run, parts["driver"], units, raised)
+    try:
+        parts["driver"].run(run)
+    finally:
+        run.clock.close()
+    result = finish(spec, parts, run, log=lines.append)
     return result, [json.loads(ln) for ln in lines]
+
+
+def edit_traffic(root, name, stretch, loss_fall_min):
+    """The toy's own file in the throw-away copy, with another stretch."""
+    path = root / "benchmark" / "traffic" / f"{name}.json"
+    traffic = json.loads(path.read_text())
+    key = next(k for k in traffic if k.startswith("loss_fall_"))
+    traffic[key] = stretch
+    traffic["limits"]["loss_fall_min"] = loss_fall_min
+    path.write_text(json.dumps(traffic))
+
+
+def values(checks):
+    return {c["check"]: c["value"] for c in checks}
 
 
 def test_train_steps_toy_run_is_correct(checkout):
@@ -143,9 +191,9 @@ def test_fed_rounds_server_that_keeps_its_weights_is_not_correct(checkout, monke
     assert not {c["check"]: c["ok"] for c in checks}["round_change_norm_gap"]
 
 
-def test_train_steps_step_that_keeps_its_state_is_not_correct(checkout, monkeypatch):
-    """The timed path broken underneath: a train step that returns its state
-    as it got it (the loss is still computed)."""
+def freeze_the_step(monkeypatch):
+    """A train step that returns its state as it got it (the loss is still
+    computed)."""
     import photon_tpu.train.trainer as trainer_mod
 
     real = trainer_mod.make_train_step
@@ -159,10 +207,95 @@ def test_train_steps_step_that_keeps_its_state_is_not_correct(checkout, monkeypa
         return keep_state
 
     monkeypatch.setattr(trainer_mod, "make_train_step", frozen)
+
+
+def test_train_steps_step_that_keeps_its_state_is_not_correct(checkout, monkeypatch):
+    """The timed path broken underneath."""
+    freeze_the_step(monkeypatch)
     result, checks = execute(checkout, "toy-train", seed=5)
     assert not result["correct"]
     ok = {c["check"]: c["ok"] for c in checks}
     assert not ok["param_change_norm_gap"]
+
+
+def test_train_steps_loss_fell_tells_a_step_that_learns_nothing(checkout, monkeypatch):
+    """Against a limit set between the toy's fall and none, as a cell's is:
+    over fits 10-15 toy-train reads 0.084-0.136 (seeds 3, 5, 11, 2**31 + 11)
+    and a step that learns nothing -0.012 to 0.031; 0.05 is their geometric
+    middle."""
+    edit_traffic(checkout, "toy-train", [10, 15], 0.05)
+    result, checks = execute(checkout, "toy-train", seed=5, units=16)
+    assert result["correct"] and values(checks)["loss_fell"] > 0.05, checks
+    freeze_the_step(monkeypatch)
+    result, checks = execute(checkout, "toy-train", seed=5, units=16)
+    ok = {c["check"]: c["ok"] for c in checks}
+    assert not result["correct"] and not ok["loss_fell"], checks
+    assert abs(values(checks)["loss_fell"]) < 0.05
+
+
+@pytest.mark.parametrize("cell,key", [("toy-train", "window_fits"),
+                                      ("toy-fed", "window_rounds")])
+def test_loss_fell_is_one_number_whatever_the_window_holds(checkout, cell, key):
+    """The stretch is the traffic file's (3 units here), so a window of 4
+    and one of 6 read the same ``loss_fell`` on one seed."""
+    (short, short_checks), (long, long_checks) = (
+        execute(checkout, cell, seed=11, units=n) for n in (4, 6))
+    assert short["correct"] and long["correct"], (short_checks, long_checks)
+    assert (short["attempted"], long["attempted"]) in {(4, 6), (8, 12)}  # rounds; steps, two a fit
+    assert (values(short_checks)[key], values(long_checks)[key]) == (4, 6)
+    assert values(short_checks)["loss_fell"] == values(long_checks)["loss_fell"]
+
+
+@pytest.mark.parametrize("cell,key", [("toy-train", "window_fits"),
+                                      ("toy-fed", "window_rounds")])
+def test_a_window_shorter_than_the_stretch_is_not_correct(checkout, cell, key):
+    """It fails a check of its own by name and reads no ``loss_fell``: what
+    it has instead would be the accident of its length."""
+    result, checks = execute(checkout, cell, seed=11, units=2)
+    assert not result["correct"]
+    failed = [c["check"] for c in checks if not c["ok"]]
+    assert failed == [key] and values(checks)[key] == 2
+    assert "loss_fell" not in values(checks)
+    assert result["checks"][key] == {"value": 2.0, "limit": 3, "ok": False}
+
+
+@pytest.mark.parametrize("raised,correct", [
+    ((), True), ((3,), True), ((1,), True), ((0, 2), False), ((0, 1, 2), False)])
+def test_one_fit_whose_loss_leaps_does_not_decide_loss_fell(checkout, raised, correct):
+    """A loss raised by 10 past the stretch changes nothing, one inside it
+    moves the median to a neighbour, a majority of the stretch fails."""
+    result, checks = execute(checkout, "toy-train", seed=11, units=4, raised=raised)
+    fell = values(checks)["loss_fell"]
+    assert result["correct"] == correct, checks
+    assert (abs(fell) < 0.2) if correct else (fell < -9.0)
+    if raised == (3,):
+        _, plain_checks = execute(checkout, "toy-train", seed=11, units=4)
+        assert fell == values(plain_checks)["loss_fell"]
+
+
+def test_the_profilers_stop_is_not_the_windows_time(checkout):
+    """A traced window holds the fits a timed one holds: the seconds the
+    profiler takes to stop (13 s after a 24 s trace on the chip) are not
+    counted against ``--seconds``."""
+    from benchmark.harness import prepare
+    from benchmark.spec import Spec
+
+    _, run = prepare(Spec(checkout), "toy-train", 3, 10.0, True,
+                     t_process=time.monotonic(),
+                     devices_and_peaks=(jax.devices()[:1], toy.TOY_PEAKS))
+    run.clock.close()
+    t0 = time.monotonic() - 12.0
+    assert run.window_over(t0)
+    run._trace_stop_s = 5.0
+    assert not run.window_over(t0)
+    run._trace_stop_s = 1.5
+    assert run.window_over(t0)
+
+
+def test_a_stretch_too_short_for_a_median_is_refused(checkout):
+    edit_traffic(checkout, "toy-train", [2, 4], -1.0)
+    with pytest.raises(ValueError, match="a median needs three"):
+        execute(checkout, "toy-train", seed=11, units=4)
 
 
 def test_serve_open_loop_toy_run_is_correct(checkout):

@@ -311,7 +311,8 @@ TOY_TRAFFIC = {
     "kind": "train_steps", "why": "toy",
     "overrides": {"train.global_batch_size": 2, "train.device_microbatch_size": 2,
                   "dataset.synthetic": True},
-    "rows": 64, "zipf_a": 1.01, "steps_per_fit": 2, "warm_fits": 1,
+    "rows": 64, "zipf_a": 1.01, "steps_per_fit": 2, "loss_fall_fits": [0, 3],
+    "warm_fits": 1,
     "trace_seconds": 1, "reference_rows": 1, "control_matmul": "bfloat16",
     # the float32 program reads 1e-6 or less on the losses and 1e-5 on the
     # norms (the order of summation alone differs); the bfloat16 control 1e-3

@@ -65,6 +65,22 @@ def test_every_committed_cell_resolves():
         assert parts["config"]["model"]["d_model"] > 0
 
 
+COMMITTED = Spec(toy.ROOT)
+STRETCH_KEYS = {"train_steps": "loss_fall_fits", "fed_rounds": "loss_fall_rounds"}
+
+
+@pytest.mark.parametrize("cell", sorted(COMMITTED.cells))
+def test_a_committed_cell_that_trains_names_its_stretch_for_loss_fell(cell):
+    """``[a, b]`` with three fits (rounds) or more for the median, a limit
+    beside it, and the readings that both were set from."""
+    traffic = COMMITTED.traffic_file(COMMITTED.cell(cell))
+    key = STRETCH_KEYS[traffic["kind"]]
+    a, b = traffic[key]
+    assert isinstance(a, int) and isinstance(b, int) and 0 <= a <= b - 3
+    assert isinstance(traffic["limits"]["loss_fall_min"], float)
+    assert key in traffic["limits_from"]
+
+
 def test_run_py_names_no_cell_configuration_kind_or_metric():
     spec = Spec(toy.ROOT)
     words = (set(spec.cells) | set(spec.configs)
