@@ -600,7 +600,8 @@ class MPTBlock(nn.Module):
                 jnp.float32) * (heads ** -0.5 * dim ** -0.5)
         with jax.named_scope(DSA_SELECT_SCOPE):
             mask = dsa.select_keys(q_idx, k_idx, w_idx, topk=cfg.dsa_topk,
-                                   chunk=cfg.dsa_chunk)
+                                   chunk=cfg.dsa_chunk, impl=cfg.attn_impl,
+                                   interpret=cfg.attn_interpret)
             # the mask's one pass outside the kernel: the picked pairs by
             # tile give the count and all three launches' live tiles
             tiles = plan_tiles(s, s)

@@ -129,7 +129,10 @@ def _selection_attrs(cfg: ModelConfig, batch_rows: int) -> dict[str, Any]:
     that hold one; which path makes the index loss's ``pbar`` (``ops/dsa.
     uses_kernel``: the Pallas launch or ``jax.numpy``), and the key tiles its
     launches of a step compute and skip (the skipped ones are dead by the
-    causal rule; every layer and row, twice under ``remat``)."""
+    causal rule; every layer and row, twice under ``remat``); which path
+    searches the selection's thresholds (``ops/dsa.selects_in_vmem``: the
+    launch of ``ops/index_select.py`` or ``jax.numpy``), and its launches of
+    a step: one a query chunk, of every layer and row, twice under ``remat``."""
     from photon_tpu.ops import dsa
     from photon_tpu.ops.flash_attention import live_tiles
     from photon_tpu.ops.masked_flash_attention import plan_tiles
@@ -139,11 +142,16 @@ def _selection_attrs(cfg: ModelConfig, batch_rows: int) -> dict[str, Any]:
     tiles, _ = live_tiles(s, s, *plan_tiles(s, s)[0])
     kernel = dsa.uses_kernel(cfg.attn_impl, cfg.attn_interpret)
     computed, skipped = dsa.index_loss_tiles(s, cfg.dsa_chunk)
-    launches = ((2 if cfg.remat else 1) if kernel else 0) * rows
+    passes = (2 if cfg.remat else 1) * rows
+    launches = passes if kernel else 0
+    select = dsa.selects_in_vmem(cfg.attn_impl, cfg.attn_interpret, s, cfg.dsa_chunk,
+                                 cfg.dsa_topk)
     return {"causal_pairs": float(rows * s * (s + 1) // 2),
             "tiles_causal": float(rows * tiles),
             "index_loss_kernel": kernel, "index_loss_tiles": launches * computed,
-            "index_loss_tiles_skipped": launches * skipped}
+            "index_loss_tiles_skipped": launches * skipped,
+            "select_kernel": select,
+            "select_launches": passes * (s // min(cfg.dsa_chunk, s)) if select else 0}
 
 
 def step_attrs(cfg: ModelConfig, batch_rows: int) -> StepAttrs:

@@ -10,9 +10,10 @@ sizes it (preset ``keye-vl-2.0-30b-a3b-ep8``). For one row of ``S`` positions,
 - :func:`select_keys` — ``tau_t`` = the ``topk``-th largest of ``I[t, :t+1]``
   (``-inf`` while ``t < topk``); the query sees ``{s <= t : I[t, s] >=
   tau_t}``, ties at the threshold all kept. Exact: the threshold comes from a
-  search over the float's bits (:func:`kth_largest`), not from an approximate
-  top-k. The result is an int8 mask by (query, key) for all heads, which
-  ``ops/masked_flash_attention.py`` takes; no gradient flows through it.
+  search over the float's bits (:func:`kth_largest`, or the same search from
+  VMEM: below), not from an approximate top-k. The result is an int8 mask by
+  (query, key) for all heads, which ``ops/masked_flash_attention.py`` takes;
+  no gradient flows through it.
 - :func:`index_loss` — ``L_I = (1/S) sum_t KL(pbar[t, .] || softmax_{S_t}(I[t,
   .]))`` where ``pbar`` is the attention's own probabilities over the picked
   keys, averaged over the heads and detached. Its gradient reaches the
@@ -26,13 +27,22 @@ Everything walks the queries in chunks of ``chunk`` (``sa_config``'s
 float32, the indexer heads' products ``[chunk, heads, S]``; nothing of
 ``[heads, S, S]`` is ever whole. The chunks go in ``_BANDS`` bands, each
 against the keys up to its own last query only. The chunk loops are ``lax.scan``s
-of ``jax.numpy`` but for one line: where the attention runs its Pallas kernel
-(``attn_impl: pallas``), the index loss takes ``pbar`` from a launch a chunk
-(``ops/index_pbar.py``) that keeps every head's ``[chunk, block_k]`` score
-tile in VMEM and writes ``[chunk, S]`` once; the ``xla`` path forms the
-heads' ``[H, chunk, S]`` float32 scores in HBM, and is what the CPU runs and
-what the tests hold the kernel to. PERF.md section 7 has what kernels for
-the selection and the index scores would save.
+of ``jax.numpy`` but for two lines, each a launch a chunk where the attention
+runs its Pallas kernel (``attn_impl: pallas``: :func:`uses_kernel`):
+
+- the index loss takes ``pbar`` from ``ops/index_pbar.py``, which keeps
+  every head's ``[chunk, block_k]`` score tile in VMEM and writes ``[chunk,
+  S]`` once; the ``xla`` path forms the heads' ``[H, chunk, S]`` float32
+  scores in HBM;
+- the selection takes its thresholds from ``ops/index_select.py``, which
+  holds a block of queries' ``[rows, n_keys]`` scores in VMEM and searches
+  them there a bit a pass; the ``xla`` path is :func:`kth_largest`'s eight
+  fused passes over the chunk in HBM, 15 compares an element each. The same
+  32 bits either way (:func:`selects_in_vmem` says which, by the shape too).
+
+The ``xla`` lines are what the CPU runs and what the tests hold the launches
+to. The index scores are ``jax.numpy`` on both paths; PERF.md section 7 has
+what forming them inside a launch would save.
 """
 
 from __future__ import annotations
@@ -42,6 +52,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from photon_tpu.ops import index_select
 from photon_tpu.ops.index_pbar import head_mean_probabilities, key_block, live_key_tiles
 
 #: bits of the threshold search decided a pass: 15 counts over the chunk in
@@ -102,8 +113,9 @@ def _bands(n_chunks: int) -> list[tuple[int, int]]:
     return [(b * n_chunks // n, (b + 1) * n_chunks // n) for b in range(n)]
 
 
-def _row_select(q_idx, k_idx, w, topk: int, chunk: int) -> jax.Array:
-    """One row's mask ``[S, S]`` int8."""
+def _row_select(q_idx, k_idx, w, topk: int, chunk: int, search) -> jax.Array:
+    """One row's mask ``[S, S]`` int8; ``search(scores, topk)`` is a chunk's
+    thresholds (:func:`kth_largest` or its launch)."""
     s = k_idx.shape[0]
     q_chunks, w_chunks = _chunked(q_idx, chunk), _chunked(w, chunk)
     masks = []
@@ -118,7 +130,7 @@ def _row_select(q_idx, k_idx, w, topk: int, chunk: int) -> jax.Array:
             # + 0.0: a negative zero becomes the positive one, as the
             # comparison below reads both
             scores = jnp.where(causal, index_scores(qc, k_idx[:n_keys], wc) + 0.0, -jnp.inf)
-            tau = jnp.where(t < topk, -jnp.inf, kth_largest(scores, topk))
+            tau = jnp.where(t < topk, -jnp.inf, search(scores, topk))
             return None, (causal & (scores >= tau[:, None])).astype(jnp.int8)
 
         _, mask = jax.lax.scan(
@@ -129,14 +141,22 @@ def _row_select(q_idx, k_idx, w, topk: int, chunk: int) -> jax.Array:
 
 
 def select_keys(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array, *, topk: int,
-                chunk: int) -> jax.Array:
+                chunk: int, impl: str = "xla", interpret: bool = False) -> jax.Array:
     """The keys every query attends to: ``q_idx [B, S, J, Di]``, ``k_idx [B,
     S, Di]``, ``w [B, S, J]`` -> ``mask [B, S, S]`` int8 (1 = picked), causal
-    by construction. Takes no gradient."""
+    by construction. Takes no gradient.
+
+    ``impl`` / ``interpret`` are the attention's own, as :func:`index_loss`
+    takes them, and choose where a chunk's thresholds are searched
+    (:func:`selects_in_vmem`): the mask is the same on every entry."""
     q_idx, k_idx, w = jax.lax.stop_gradient((q_idx, k_idx, w))
-    chunk = min(chunk, q_idx.shape[1])
+    s = q_idx.shape[1]
+    chunk = min(chunk, s)
+    search = kth_largest
+    if selects_in_vmem(impl, interpret, s, chunk, topk, q_idx):
+        search = functools.partial(index_select.kth_largest, interpret=bool(interpret))
     return jax.lax.map(
-        lambda a: _row_select(*a, topk=topk, chunk=chunk), (q_idx, k_idx, w))
+        lambda a: _row_select(*a, topk=topk, chunk=chunk, search=search), (q_idx, k_idx, w))
 
 
 # ---------------------------------------------------------------------------
@@ -145,15 +165,31 @@ def select_keys(q_idx: jax.Array, k_idx: jax.Array, w: jax.Array, *, topk: int,
 
 
 def uses_kernel(impl: str, interpret: bool = False, x: jax.Array | None = None) -> bool:
-    """Whether the index loss takes ``pbar`` from the Pallas launch, by
-    ``masked_multihead_attention``'s rule: ``pallas`` is the kernel on a TPU
-    (or anywhere under ``interpret``) and steps down on the CPU backend."""
+    """Whether the chunk loops take their Pallas launches (the index loss's
+    ``pbar``; the selection's thresholds where :func:`selects_in_vmem` admits
+    the shape), by ``masked_multihead_attention``'s rule: ``pallas`` is the
+    kernel on a TPU (or anywhere under ``interpret``) and steps down on the
+    CPU backend."""
     # looked up at the call: the offline compile check swaps the function
     from photon_tpu.ops import flash_attention
 
     if impl not in ("pallas", "xla"):
         raise ValueError(f"the index loss has no impl {impl!r}")
     return impl == "pallas" and (interpret or flash_attention.pallas_supported(x))
+
+
+def selects_in_vmem(impl: str, interpret: bool, s: int, chunk: int, topk: int,
+                    x: jax.Array | None = None) -> bool:
+    """Whether the thresholds of rows of ``s`` positions come from
+    ``ops/index_select.py``'s launch, one a chunk: where a kernel can run
+    (:func:`uses_kernel`) and every band's ``[chunk, n_keys]`` tile has a
+    row block there (whole lanes of keys, whole sublanes of queries, within
+    the VMEM budget) and ``topk`` keys to rank. :func:`kth_largest`'s
+    ``jax.numpy`` search everywhere else."""
+    chunk = min(chunk, s)
+    return uses_kernel(impl, interpret, x) and all(
+        topk <= hi * chunk and index_select.row_block(chunk, hi * chunk) > 0
+        for _, hi in _bands(s // chunk))
 
 
 def _grouped(q, k, lse, chunk: int):

@@ -198,7 +198,9 @@ MOE_DISPATCH_ROWS_STATIC = "moe/dispatch_rows_static"
 #: ``causal_pairs``, ``tiles_visited``, ``tiles_causal``, ``index_loss`` (the
 #: five counters below, of the fit's last step) and the static
 #: ``index_loss_kernel``, ``index_loss_tiles``, ``index_loss_tiles_skipped``
-#: (which path makes the index loss's ``pbar``, and its launches' key tiles)
+#: (which path makes the index loss's ``pbar``, and its launches' key tiles),
+#: ``select_kernel``, ``select_launches`` (which path searches the
+#: selection's thresholds, and its launches a step: one a query chunk)
 TRAINER_DSA_SPAN = "trainer/dsa"
 # -- learned sparse attention (models/mpt.py, ops/dsa.py): counters in the
 # train step's metrics, summed over the layers, fetched with the loss -------
@@ -215,8 +217,9 @@ DSA_INDEX_LOSS = "dsa/loss"
 # -- its ``jax.named_scope``s, in every operation's ``op_name`` ------------
 #: the indexer's three projections, its key norm and the rotation
 DSA_INDEXER_SCOPE = "dsa/indexer"
-#: the index scores by query chunk, each query's threshold, the mask and its
-#: tile counts
+#: the index scores by query chunk, each query's threshold (a launch a chunk
+#: under its own ``index_select`` where the attention runs its kernel), the
+#: mask and its tile counts
 DSA_SELECT_SCOPE = "dsa/select"
 #: the second pass over q.k for the heads' mean probabilities (a launch a
 #: chunk under its own ``index_pbar`` where the attention runs its kernel),

@@ -29,7 +29,7 @@ if str(ROOT) not in sys.path:  # `benchmark` is a sibling of `tests`, not instal
 from benchmark.reference import keye_sparse_moe as ref  # noqa: E402
 from photon_tpu.config import load_preset  # noqa: E402
 from photon_tpu.models import MPTModel  # noqa: E402
-from photon_tpu.ops import dsa, index_pbar, moe  # noqa: E402
+from photon_tpu.ops import dsa, index_pbar, index_select, moe  # noqa: E402
 from photon_tpu.ops import masked_flash_attention as mfa  # noqa: E402
 from photon_tpu.train.train_step import _make_loss_and_counters_fn, make_loss_fn  # noqa: E402
 from photon_tpu.utils.profiling import (  # noqa: E402
@@ -223,51 +223,116 @@ def _reference_mask(q_idx, k_idx, w, topk):
                       for q, k, ww in zip(q_idx, k_idx, w)])
 
 
-@pytest.mark.parametrize("chunk", [16, 64])
+def _selected(q_idx, k_idx, w, topk, chunk, launch):
+    """``select_keys``' mask; with ``launch`` through ``ops/index_select.py``
+    under the interpreter, which has to leave the ``jax.numpy`` path's mask
+    bit for bit."""
+    s = q_idx.shape[1]
+    assert dsa.selects_in_vmem("pallas", True, s, chunk, topk) is launch
+    with jax.default_matmul_precision("highest"):
+        mask = dsa.select_keys(q_idx, k_idx, w, topk=topk, chunk=chunk)
+        if launch:
+            select = lambda *a: dsa.select_keys(  # noqa: E731
+                *a, topk=topk, chunk=chunk, impl="pallas", interpret=True)
+            # one launch a band's chunk loop, and more than one row block in it
+            assert str(jax.make_jaxpr(select)(q_idx, k_idx, w)).count("pallas_call") == 4
+            assert index_select.row_block(min(chunk, s), s) < min(chunk, s)
+            np.testing.assert_array_equal(select(q_idx, k_idx, w), mask)
+    return mask
+
+
+# rows of 64 are under a lane's width and stay ``jax.numpy``; 512 in chunks
+# of 128 are four bands of whole column tiles, two row blocks a launch
+SELECT_SHAPES = [pytest.param(64, 16, False, id="s64-c16"), pytest.param(64, 64, False, id="s64-c64"),
+                 pytest.param(512, 128, True, id="s512-c128-launch")]
+
+
+@pytest.mark.parametrize("s, chunk, launch", SELECT_SHAPES)
 @pytest.mark.parametrize("topk", [1, 16, 33])
-def test_the_selection_is_the_references_set_on_every_row(topk, chunk):
+def test_the_selection_is_the_references_set_on_every_row(topk, s, chunk, launch):
     """Exactly: rows with fewer earlier keys than ``topk`` keep them all,
     every later row keeps its ``topk`` highest (more only where scores tie
     at the threshold: a query whose every product is cut by the ``relu``
     scores exactly 0 against several keys)."""
-    with jax.default_matmul_precision("highest"):
-        q_idx, k_idx, w = _indexer_inputs(0)
-        mask = dsa.select_keys(q_idx, k_idx, w, topk=topk, chunk=chunk)
+    q_idx, k_idx, w = _indexer_inputs(0, s=s)
+    mask = _selected(q_idx, k_idx, w, topk, chunk, launch)
     want = _reference_mask(q_idx, k_idx, w, topk)
     assert mask.dtype == jnp.int8 and bool(jnp.all((mask != 0) == want))
     per_row = np.asarray(jnp.sum(mask, axis=-1))
-    least = np.minimum(np.arange(64) + 1, topk)[None, :]
+    least = np.minimum(np.arange(s) + 1, topk)[None, :]
     assert (per_row >= least).all()
     if topk > 1:  # the 16th place is rarely an exact zero; the first often is
         assert (per_row == least).mean() > 0.95
 
 
-def test_ties_at_the_threshold_are_all_kept():
+@pytest.mark.parametrize("s, chunk, launch", [SELECT_SHAPES[0], SELECT_SHAPES[2]])
+def test_ties_at_the_threshold_are_all_kept(s, chunk, launch):
     """Keys with one and the same score straddle the threshold: the rule
     keeps every one of them, in the program as in the reference."""
-    with jax.default_matmul_precision("highest"):
-        q_idx, k_idx, w = _indexer_inputs(1)
-        # row 0: all keys alike, so every score of a query ties; row 1: keys
-        # 8..39 alike, a tie that the 16th place falls into for most queries
-        k_idx = k_idx.at[0].set(k_idx[0, 0]).at[1, 8:40].set(k_idx[1, 8])
-        mask = dsa.select_keys(q_idx, k_idx, w, topk=16, chunk=16)
+    q_idx, k_idx, w = _indexer_inputs(1, s=s)
+    # row 0: all keys alike, so every score of a query ties; row 1: keys
+    # 8..39 alike, a tie that the 16th place falls into for most queries
+    k_idx = k_idx.at[0].set(k_idx[0, 0]).at[1, 8:40].set(k_idx[1, 8])
+    mask = _selected(q_idx, k_idx, w, 16, chunk, launch)
     want = _reference_mask(q_idx, k_idx, w, 16)
     assert bool(jnp.all((mask != 0) == want))
-    assert bool(jnp.all((mask[0] != 0) == jnp.tril(jnp.ones((64, 64), bool))))
+    assert bool(jnp.all((mask[0] != 0) == jnp.tril(jnp.ones((s, s), bool))))
     assert int(jnp.max(jnp.sum(mask[1], axis=-1))) > 16
 
 
-@pytest.mark.parametrize("k", [1, 5, 64])
-def test_kth_largest_is_exact(k):
+def _planted_rows(k: int, n: int = 64) -> np.ndarray:
     rng = np.random.default_rng(k)
-    x = rng.normal(size=(8, 64)).astype(np.float32)
+    x = rng.normal(size=(16, n)).astype(np.float32)
     x[0, :10] = -np.inf  # masked entries count as smallest
     x[1] = np.round(x[1])  # many ties
     x[2, 3] = 0.0
     x[2, 4] = -0.0
     x[3] *= 1e-30  # tiny magnitudes, both signs
+    x[4] = 1.5  # one value, repeated
+    x[5, 3:] = -np.inf  # fewer finite entries than most ``k``
+    x[6] *= 1e-42  # subnormals of both signs: an integer compare does not flush them
+    x[7, ::2] = np.float32(1e-45) * rng.integers(-3, 4, size=n // 2)
+    return x
+
+
+@pytest.mark.parametrize("k", [1, 5, 64])
+def test_kth_largest_is_exact(k):
+    x = _planted_rows(k)
     got = dsa.kth_largest(jnp.asarray(x), k)
     np.testing.assert_array_equal(np.asarray(got), -np.sort(-x, axis=-1)[:, k - 1])
+
+
+@pytest.mark.parametrize("bits", [1, 2])
+@pytest.mark.parametrize("k", [1, 5, 384])
+def test_the_select_launch_is_kth_largest_to_the_bit(k, bits):
+    """``ops/index_select.py`` under the interpreter, three column tiles and
+    two row blocks, against the ``jax.numpy`` search (the same 32 bits: the
+    zero's sign too) and against a sort."""
+    x = _planted_rows(k, n=384)
+    got = np.asarray(index_select.kth_largest(jnp.asarray(x), k, interpret=True, rows=8,
+                                              bits=bits))
+    want = np.asarray(dsa.kth_largest(jnp.asarray(x), k))
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+    np.testing.assert_array_equal(got, -np.sort(-x, axis=-1)[:, k - 1])
+
+
+def test_the_select_launch_takes_the_shapes_it_can_hold():
+    """Whole lanes of keys, whole sublanes of queries, a tile within the
+    VMEM budget; a chunk loop with a band outside them stays ``jax.numpy``."""
+    assert index_select.row_block(512, 16384) == index_select.ROW_BLOCK
+    assert index_select.row_block(512, 4096) == index_select.ROW_BLOCK
+    assert index_select.row_block(24, 256) == 24 and index_select.row_block(40, 128) == 40
+    assert index_select.row_block(16, 64) == 0 and index_select.row_block(12, 128) == 0
+    rows = index_select.row_block(512, 2 ** 18)  # a narrower block where the keys are many
+    assert 0 < rows < index_select.ROW_BLOCK and 512 % rows == 0
+    assert index_select.row_block(512, 2 ** 22) == 0
+    assert dsa.selects_in_vmem("pallas", True, 16384, 512, 2048)
+    assert not dsa.selects_in_vmem("pallas", False, 16384, 512, 2048)  # the CPU backend
+    assert not dsa.selects_in_vmem("xla", True, 16384, 512, 2048)
+    assert not dsa.selects_in_vmem("pallas", True, 64, 16, 16)  # the tiny presets' rows
+    assert not dsa.selects_in_vmem("pallas", True, 512, 128, 129)  # a band under ``topk`` keys
+    with pytest.raises(ValueError, match="bad shapes"):
+        index_select.kth_largest(jnp.zeros((16, 64)), 1, interpret=True)
 
 
 def test_with_every_key_picked_the_sparse_branch_is_the_dense_one(seeded):
@@ -387,6 +452,98 @@ def test_the_model_through_the_kernel_in_the_interpreter(seeded, monkeypatch):
     assert abs(float(loss) - float(want)) < 1e-5
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(g_want)):
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the step as the cell configures it (ROADMAP S12, step 0)
+# ---------------------------------------------------------------------------
+
+#: ``keyevl2-train-16k``'s step at a tiny size: ``remat`` on, the kernels'
+#: path (under the interpreter), bfloat16 compute; rows of 512 in chunks of
+#: 128 are four bands, and with every launch's tile cut to 128 a chunk has
+#: more than one tile of each: the masked kernel 4 x 4, ``pbar`` 1 to 4 key
+#: tiles, the selection's search 1 to 4 column tiles in two row blocks
+AS_THE_CELL = dict(max_seq_len=512, dsa_chunk=128, dsa_topk=32, attn_impl="pallas",
+                   attn_interpret=True, remat=True, compute_dtype="bfloat16")
+#: the cell's own limit on the first gradient's worst leaf norm
+#: (``benchmark/traffic/ep8-share-1x16384.json``); this step reads 0.0114
+CELL_GRAD_NORM_GAP = 0.03
+
+
+class _Leaky:
+    """``real`` with some attributes replaced (a module seen through a
+    planted fault)."""
+
+    def __init__(self, real, **planted):
+        self._real, self._planted = real, planted
+
+    def __getattr__(self, name):
+        return self._planted[name] if name in self._planted else getattr(self._real, name)
+
+
+def _assert_the_cells_step_is_the_references(monkeypatch):
+    """The objective's gradient and every mask the step makes (the forward's
+    and the one ``remat`` makes again, of each layer) against the plain
+    reference: the gradient's leaves by the cell's own measure, the masks on
+    every entry, from the indexer's own bfloat16 inputs."""
+    monkeypatch.setattr(mfa, "TILE_CAPS", dict.fromkeys(mfa.TILE_CAPS, (128, 128)))
+    monkeypatch.setattr(index_pbar, "BLOCK_K_CAP", 128)
+    cfg = tiny_cfg(**AS_THE_CELL)
+    model, dims = cfg.model, dims_of(cfg)
+    assert dsa.selects_in_vmem(model.attn_impl, model.attn_interpret, 512, 128, 32)
+    params = ref.make_params(dims, 7)
+    tokens = np.random.default_rng(3).integers(0, 96, size=(1, 512)).astype(np.int32)
+    made, select_keys = [], dsa.select_keys
+
+    def recorded(q_idx, k_idx, w, **kwargs):
+        mask = select_keys(q_idx, k_idx, w, **kwargs)
+        jax.debug.callback(lambda *a: made.append([np.asarray(x) for x in a]),
+                           q_idx, k_idx, w, mask)
+        return mask
+
+    monkeypatch.setattr(dsa, "select_keys", recorded)
+    loss, grads = jax.jit(jax.value_and_grad(make_loss_fn(MPTModel(model), 16)))(params, tokens)
+    want_loss, want = jax.value_and_grad(
+        lambda p: reference_objective(p, dims, tokens))(params)
+    assert abs(float(loss) - float(want_loss)) < 3e-3
+    assert len(made) == 2 * model.n_layers  # a layer's forward, and again under ``remat``
+    for q_idx, k_idx, w, mask in made:
+        assert q_idx.dtype == jnp.bfloat16
+        picked = _reference_mask(*(jnp.asarray(a, jnp.float32) for a in (q_idx, k_idx, w)), 32)
+        assert np.array_equal(mask != 0, np.asarray(picked)), "a mask is not the reference's"
+    got, want = by_name(grads), by_name(want)
+    for name in INDEXER[:2] + INDEXER[3:]:  # moved by the index loss alone
+        assert np.any(np.asarray(got[f"blocks/block/{name}/kernel"]))
+    gap = ref.worst_leaf_gap(ref.leaf_norms(grads), ref.leaf_norms(want))
+    assert gap < CELL_GRAD_NORM_GAP, f"the gradient's worst leaf norm is off by {gap:.4f}"
+    for name in want:  # entry by entry: bfloat16, and a selection from bfloat16 scores
+        a, b = np.asarray(got[name], np.float64), np.asarray(want[name], np.float64)
+        assert np.linalg.norm(a - b) < 0.2 * np.linalg.norm(b), name
+
+
+def test_the_step_as_the_cell_configures_it_is_the_references(monkeypatch):
+    _assert_the_cells_step_is_the_references(monkeypatch)
+
+
+@pytest.mark.parametrize("fault", ["a_mask_from_rounded_scores", "a_gradient_past_its_stop"])
+def test_a_fault_a_remat_change_can_make_fails_the_comparison(monkeypatch, fault):
+    """The two planted faults: the masks made from index scores rounded
+    another way than the reference's rule reads them (here through bfloat16:
+    the gradient's norms still pass), and the indexer's gradient on the wrong
+    side of its ``stop_gradient`` (the index loss moves the block's input)."""
+    from photon_tpu.models import mpt
+
+    if fault == "a_mask_from_rounded_scores":
+        index_scores = dsa.index_scores
+        monkeypatch.setattr(dsa, "index_scores", lambda *a: index_scores(*a).astype(
+            jnp.bfloat16).astype(jnp.float32))
+        match = "a mask is not the reference's"
+    else:
+        monkeypatch.setattr(mpt, "jax", _Leaky(jax, lax=_Leaky(
+            jax.lax, stop_gradient=lambda x: x)))
+        match = "the gradient's worst leaf norm"
+    with pytest.raises(AssertionError, match=match):
+        _assert_the_cells_step_is_the_references(monkeypatch)
 
 
 # ---------------------------------------------------------------------------
@@ -653,22 +810,27 @@ def test_fit_returns_the_selection_counters_on_their_span():
     assert out[MOE_DISPATCH_ROWS_MOVED] == out[MOE_DISPATCH_ROWS_STATIC] == 2 * 512
 
 
-@pytest.mark.parametrize("impl, interpret, remat", [
-    ("xla", False, False), ("pallas", False, False), ("pallas", True, False),
-    ("pallas", True, True)])
-def test_the_dsa_span_says_which_path_made_pbar(impl, interpret, remat):
+@pytest.mark.parametrize("impl, interpret, remat, seq", [
+    ("xla", False, False, 64), ("pallas", False, False, 64), ("pallas", True, False, 64),
+    ("pallas", True, True, 64), ("pallas", True, True, 512), ("pallas", False, True, 512)])
+def test_the_dsa_span_says_which_path_made_pbar(impl, interpret, remat, seq):
     """``trainer/dsa`` carries the path the index loss took and the key tiles
-    its launches computed and skipped a step; on the CPU backend ``pallas``
-    without the interpreter steps down, as the attention does."""
+    its launches computed and skipped a step, and the path that searched the
+    selection's thresholds with its launches a step (one a chunk: rows of 64
+    have no shape for it); on the CPU backend ``pallas`` without the
+    interpreter steps down, as the attention does."""
     from photon_tpu.models.step import step_attrs
     from photon_tpu.utils.profiling import TRAINER_DSA_SPAN
 
-    model = tiny_cfg(attn_impl=impl, attn_interpret=interpret, remat=remat).model
+    model = tiny_cfg(attn_impl=impl, attn_interpret=interpret, remat=remat, max_seq_len=seq,
+                     dsa_chunk=seq // 4).model
     attrs = step_attrs(model, batch_rows=2).fence[TRAINER_DSA_SPAN]
     assert attrs["index_loss_kernel"] is (impl == "pallas" and interpret)
     launches = 2 * 2 * (2 if remat else 1) if interpret else 0  # layers x rows x passes
     assert attrs["index_loss_tiles"] == launches * 4
     assert attrs["index_loss_tiles_skipped"] == 0
+    assert attrs["select_kernel"] is (interpret and seq == 512)
+    assert attrs["select_launches"] == (launches * 4 if seq == 512 else 0)  # x chunks
 
 
 def test_every_new_parameter_has_a_sharding_rule():

@@ -584,22 +584,49 @@ def test_masked_flash_attention_compiles_at_the_sparse_cells_widths(one_chip):
                    for ln in launches), kernel
 
 
-def test_the_exact_selection_compiles_without_a_sort_or_a_whole_square(one_chip):
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_the_exact_selection_compiles_without_a_sort_or_a_whole_square(one_chip, monkeypatch,
+                                                                       impl):
     """``ops/dsa.select_keys`` at the cell's indexer widths (16 heads of 64,
     one key head, 2,048 keys a query, chunks of 512; 8,192 positions here):
     no sort in the program (the threshold is a search over the float's bits),
     and beside the int8 mask it returns it holds a few chunks' worth, far
-    under the 4.3 GB the 16 heads' ``[S, S]`` products would take."""
-    from photon_tpu.ops import dsa
+    under the 4.3 GB the 16 heads' ``[S, S]`` products would take. On the
+    kernel path the search is one launch a band's chunk loop
+    (``ops/index_select.py``), under ``dsa/select`` and a scope of its own,
+    not the names by which the attention's launches are found; at the cell's
+    widest band, ``[512, 16,384]``, the launch asks Mosaic for its row block
+    three times over and compiles."""
+    import photon_tpu.ops.flash_attention as fa
+    from photon_tpu.ops import dsa, index_select
+    from photon_tpu.utils.profiling import DSA_SELECT_SCOPE
 
+    monkeypatch.setattr(fa, "pallas_supported", lambda x: True)  # steered, in the test
     s = 8192
     q_idx = _abstract((1, s, 16, 64), jnp.bfloat16, one_chip)
     k_idx = _abstract((1, s, 64), jnp.bfloat16, one_chip)
     w = _abstract((1, s, 16), jnp.float32, one_chip)
-    compiled = jax.jit(lambda q, k, w: dsa.select_keys(
-        q, k, w, topk=2048, chunk=512)).lower(q_idx, k_idx, w).compile()
+
+    def select(q, k, w):
+        with jax.named_scope(DSA_SELECT_SCOPE):
+            return dsa.select_keys(q, k, w, topk=2048, chunk=512, impl=impl)
+
+    compiled = jax.jit(select).lower(q_idx, k_idx, w).compile()
     assert not re.search(r"\bsort\(", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+    launches = [ln.strip() for ln in compiled.as_text().splitlines() if KERNEL in ln]
+    if impl == "xla":
+        assert not launches
+        return
+    assert len(launches) == 4  # a launch a band, inside its chunk loop
+    for ln in launches:
+        name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert re.search(
+            rf"\b{DSA_SELECT_SCOPE}\b.*\b{index_select.INDEX_SELECT_SCOPE}/.*pallas_call", name)
+        assert not re.search(r"multihead_attention", name)
+    widest = _abstract((512, 16384), jnp.float32, one_chip)
+    text = jax.jit(lambda x: index_select.kth_largest(x, 2048)).lower(widest).compile().as_text()
+    assert KERNEL in text and index_select.row_block(512, 16384) == index_select.ROW_BLOCK
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])

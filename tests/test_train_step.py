@@ -537,7 +537,8 @@ PARENTS = {
              "picked_pairs": names.DSA_PICKED_PAIRS, "causal_pairs": names.DSA_CAUSAL_PAIRS,
              "tiles_visited": names.DSA_TILES_VISITED, "tiles_causal": names.DSA_TILES_CAUSAL,
              "index_loss": names.DSA_INDEX_LOSS, "index_loss_kernel": False,
-             "index_loss_tiles": 0, "index_loss_tiles_skipped": 0})]),
+             "index_loss_tiles": 0, "index_loss_tiles_skipped": 0,
+             "select_kernel": False, "select_launches": 0})]),
 }
 
 
